@@ -25,53 +25,6 @@ Layer map (mirrors the reference's Maven layering, reference SURVEY.md section 1
 
 __version__ = "0.2.0"
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # jax < 0.5 ships shard_map under experimental only; the codebase targets
-    # the public ``jax.shard_map`` spelling, so alias it for older jaxlibs.
-    # check_rep defaults off: without lax.pcast (below) the old rep-tracker
-    # cannot see variance annotations and rejects valid scan carries.
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def _shard_map(f, *args, **kwargs):
-        kwargs.setdefault("check_rep", False)
-        return _experimental_shard_map(f, *args, **kwargs)
-
-    _jax.shard_map = _shard_map
-
-if not hasattr(_jax.lax, "pcast"):
-    # jax < 0.6 has no varying-manual-axes tracking, so pcast (a variance
-    # annotation, not a computation) degrades to identity there.
-    _jax.lax.pcast = lambda x, axis_name, to=None: x
-
-try:
-    from jax.experimental import pallas as _pl
-    from jax.experimental.pallas import tpu as _pltpu
-
-    if not hasattr(_pltpu, "force_tpu_interpret_mode"):
-        # Older pallas has no global interpret switch; emulate it by forcing
-        # interpret=True on every pallas_call issued inside the context.
-        import contextlib as _contextlib
-
-        @_contextlib.contextmanager
-        def _force_tpu_interpret_mode():
-            orig = _pl.pallas_call
-
-            def _interpreted(*args, **kwargs):
-                kwargs["interpret"] = True
-                return orig(*args, **kwargs)
-
-            _pl.pallas_call = _interpreted
-            try:
-                yield
-            finally:
-                _pl.pallas_call = orig
-
-        _pltpu.force_tpu_interpret_mode = _force_tpu_interpret_mode
-except ImportError:  # jaxlib built without pallas
-    pass
-
 from flink_ml_tpu.api.core import AlgoOperator, Estimator, Model, Stage, Transformer
 from flink_ml_tpu.api.dataframe import DataFrame, Row
 

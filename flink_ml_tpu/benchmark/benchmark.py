@@ -179,6 +179,9 @@ def main(argv=None) -> int:
         "via observability.trace.xprof)",
     )
     args = parser.parse_args(argv)
+    from flink_ml_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     if args.trace:
         from flink_ml_tpu import trace
 

@@ -61,7 +61,11 @@ from flink_ml_tpu.api.dataframe import DataFrame
 from flink_ml_tpu.api.types import BasicType, DataTypes
 from flink_ml_tpu.config import Options, config
 from flink_ml_tpu.metrics import MLMetrics, metrics
-from flink_ml_tpu.servable.fusion import plan_recorder, resolve_fusion_tier
+from flink_ml_tpu.servable.fusion import (
+    fallback_recorder,
+    plan_recorder,
+    resolve_fusion_tier,
+)
 from flink_ml_tpu.servable.plancache import resolve_plan_cache
 from flink_ml_tpu.servable.precision import (
     PRECISION_GAUGE_VALUE,
@@ -155,6 +159,7 @@ class CompiledBatchPlan:
         # compiled load their serialized executables instead of compiling.
         self.plancache = resolve_plan_cache()
         self._on_plan = plan_recorder(scope)
+        self._on_mega_fallback = fallback_recorder(scope)
         n_fused = sum(len(s.specs) for s in segments if isinstance(s, FusedSegment))
         n_fallback = sum(1 for s in segments if isinstance(s, FallbackStage))
         metrics.gauge(scope, MLMetrics.BATCH_FUSED_STAGES, n_fused)
@@ -406,6 +411,7 @@ class CompiledBatchPlan:
                     replicated=replicated,
                     cache=self.plancache,
                     on_cache=on_cache if self.plancache is not None else None,
+                    on_mega_fallback=self._on_mega_fallback,
                 )
                 # The fusion tier this chunk's compiled chain runs at
                 # ("exact" / "fast" / "fast+mega") — goodput attribution

@@ -201,6 +201,9 @@ def main(argv=None) -> int:
     parser.add_argument("--load-version", type=int, default=None)
     parser.add_argument("--template", default=None)
     args = parser.parse_args(argv)
+    from flink_ml_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     return _Worker(args).run()
 
 

@@ -41,11 +41,13 @@ be replaced by dense one-hot algebra the MXU/VPU execute at full width:
 The crossings run two ways: a pure-XLA form (works on any backend;
 one-hots are materialized through HBM) and Pallas kernels (TPU only;
 one-hots are built tile-by-tile in VMEM and never touch HBM), selected by
-``use_pallas``. Measured on one v5e chip at the Criteo shape (2^22
-features, 39 nnz/row, batch 65536): 17-32 ms/step across runs — ~1.8-2.9x
-the scatter path it replaces, on both the resident and streamed routes;
-the remaining cost is crossing-bound (see docs/benchmarks.md for the
-roofline and the measured multi-chip scaling artifact).
+``use_pallas``. The design-time timings quoted in this module (ns/entry,
+"measured best of", speedup factors) were taken on a retired setup and
+another JAX and are not re-measured on the current toolchain; they explain
+why the code has this shape, they are not records (ROADMAP S1/S3). What IS
+re-observed on jax 0.9.0 / TPU v5e: every kernel here compiles under Mosaic
+as written and the fit matches the numpy step on the chip (``chip_smoke.py``),
+and the VMEM notes below reproduce to the digit (docs/kernels.md).
 """
 from __future__ import annotations
 
@@ -55,8 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from flink_ml_tpu.parallel.mesh import shape_dtype_struct as _sds
-from flink_ml_tpu.parallel.mesh import vma_of as _vma_of_shared
+from flink_ml_tpu.parallel.mesh import vma_of
 from flink_ml_tpu.utils.arrays import group_ranks, next_pow2
 
 __all__ = [
@@ -547,10 +548,11 @@ def dot_crossing_pallas(q, rhi, rlo, row_hi, interpret: bool = False):
             (1, 1, row_hi, _ROW_LO), lambda i, k: (i, k, 0, 0),
             memory_space=pltpu.VMEM,
         ),
-        out_shape=_sds(
-            (n_sub, ntiles, row_hi, _ROW_LO), jnp.float32, vma=_vma_of_shared(q)
+        out_shape=jax.ShapeDtypeStruct(
+            (n_sub, ntiles, row_hi, _ROW_LO), jnp.float32, vma=vma_of(q)
         ),
         interpret=interpret,
+        name="onehot_dot_crossing",
     )(rhi.reshape(-1), rlo.reshape(-1), q.reshape(-1))
     return jnp.sum(parts, axis=1)
 
@@ -604,10 +606,11 @@ def mult_crossing_pallas(mult3, rhi, rlo, row_hi, interpret: bool = False):
             row,
         ],
         out_specs=row,
-        out_shape=_sds(
-            (n_sub * (n + pad),), jnp.float32, vma=_vma_of_shared(rhi)
+        out_shape=jax.ShapeDtypeStruct(
+            (n_sub * (n + pad),), jnp.float32, vma=vma_of(rhi)
         ),
         interpret=interpret,
+        name="onehot_mult_crossing",
     )(mult3, rhi.reshape(-1), rlo.reshape(-1))
     return out.reshape(n_sub, n + pad)[:, :n]
 
@@ -761,10 +764,11 @@ def dot_crossing_premat_pallas(q, oh_hi, oh_lo, wi=0, interpret: bool = False):
                 (1, 1, row_hi, _ROW_LO), lambda i, k, wi_ref: (i, k, 0, 0)
             ),
         ),
-        out_shape=_sds(
-            (n_sub, ntiles, row_hi, _ROW_LO), jnp.float32, vma=_vma_of_shared(q)
+        out_shape=jax.ShapeDtypeStruct(
+            (n_sub, ntiles, row_hi, _ROW_LO), jnp.float32, vma=vma_of(q)
         ),
         interpret=interpret,
+        name="onehot_dot_crossing_premat",
     )(jnp.asarray(wi, jnp.int32).reshape(1), oh_hi, oh_lo, q.reshape(-1))
     return jnp.sum(parts, axis=1)
 
@@ -812,10 +816,11 @@ def mult_crossing_premat_pallas(mult3, oh_hi, oh_lo, wi=0, interpret: bool = Fal
                 (tile,), lambda i, k, wi_ref: (i * ntiles + k,)
             ),
         ),
-        out_shape=_sds(
-            (n_sub * n_pad,), jnp.float32, vma=_vma_of_shared(mult3)
+        out_shape=jax.ShapeDtypeStruct(
+            (n_sub * n_pad,), jnp.float32, vma=vma_of(mult3)
         ),
         interpret=interpret,
+        name="onehot_mult_crossing_premat",
     )(jnp.asarray(wi, jnp.int32).reshape(1), mult3, oh_hi, oh_lo)
     return out.reshape(n_sub, n_pad)
 

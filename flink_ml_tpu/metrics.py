@@ -167,6 +167,7 @@ class MLMetrics:
     FUSION_PROGRAMS_EXACT = "ml.fusion.programs.exact"  # exact-partition program compiles, counter
     FUSION_PROGRAMS_FUSED = "ml.fusion.programs.fused"  # cross-reduction XLA program compiles, counter
     FUSION_PROGRAMS_MEGAKERNEL = "ml.fusion.programs.megakernel"  # Pallas megakernel compiles, counter
+    FUSION_MEGAKERNEL_FALLBACKS = "ml.fusion.megakernel.fallbacks"  # megakernels the backend rejected at compile time (merged XLA program served instead), counter
     FUSION_PLAN_CHOICE = "ml.fusion.plan.choice"  # most aggressive tier last compiled: 0 exact / 1 fused / 2 megakernel, gauge
     FUSION_PLAN_SCORE = "ml.fusion.plan.score"  # cost-model score of the last compiled chain, gauge
 

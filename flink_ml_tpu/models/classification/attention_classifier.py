@@ -336,6 +336,7 @@ class SelfAttentionClassifier(Estimator, _AttnParams):
         nv = jnp.asarray(t_real, jnp.int32)
         offset = 0
         windows = {}  # (lo, offset) -> device tensors; the cycle is short
+        losses = []  # device scalars: fetched once after the loop, never per step
         for _ in range(self.get_max_iter()):
             # contiguous example window per epoch, cycling like SGD.java:265;
             # at the clamped tail, rows before the logical offset are re-reads
@@ -353,10 +354,13 @@ class SelfAttentionClassifier(Estimator, _AttnParams):
                     ),
                 )
             tok_w, y_w, w_w = windows[key]
-            params, opt_state, _loss = step(
+            params, opt_state, loss = step(
                 params, opt_state, tok_w, y_w, w_w, nv
             )
+            losses.append(loss)
             offset = 0 if offset + batch >= n else offset + batch
+        # per-step observability for callers, like LinearEstimatorBase.fit
+        self.loss_history = [float(x) for x in jax.device_get(losses)]
 
         model = SelfAttentionClassifierModel()
         update_existing_params(model, self)

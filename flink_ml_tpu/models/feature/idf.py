@@ -113,7 +113,8 @@ class IDFModel(ModelArraysMixin, Model, _IDFParams):
         gather-scale (``sparse_idf_scale_fn`` — the body the per-stage sparse
         path jits), structure (ids/nnz) passing through unchanged. No
         cross-entry accumulation, so the spec is elementwise and merges
-        bit-exactly; ``sparse_idf`` is in the megakernel vocabulary."""
+        bit-exactly. (``sparse_idf`` is NOT in the megakernel vocabulary:
+        Mosaic does not lower the table gather.)"""
         if self.idf is None:
             raise RuntimeError("set_model_data must be called before kernel_spec")
         in_col, out_col = self.get_input_col(), self.get_output_col()
@@ -139,7 +140,7 @@ class IDFModel(ModelArraysMixin, Model, _IDFParams):
             sparse_outputs={out_col: dim},
             sparse_input_dims={in_col: dim},
             elementwise=True,  # per-entry gather + multiply: no accumulation
-            fusion_op="sparse_idf",  # megakernel-safe
+            fusion_op="sparse_idf",  # table gather: merged XLA only
         )
 
     def kernel_spec(self):

@@ -89,8 +89,10 @@ class LinearEstimatorBase(
         dim = data.pop("dim", None) or data["features"].shape[1]
         optimizer = self._make_optimizer()
         coefficient = optimizer.optimize(np.zeros(dim, np.float32), data, self._LOSS)
-        # per-epoch observability for the benchmark harness / callers
+        # per-epoch observability for the benchmark harness / callers; the
+        # optimizer records which route the fit took (onehot_premat_active)
         self.loss_history = list(optimizer.loss_history)
+        self.optimizer = optimizer
         model = self._MODEL_CLASS()
         update_existing_params(model, self)
         model.coefficient = np.asarray(coefficient)

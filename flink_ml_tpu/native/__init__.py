@@ -7,12 +7,17 @@ spillable chunk store behind the capacity-tier data cache (datacache.cpp — the
 MemorySegment datacache analogue).
 
 The shared library is compiled on first use with the system toolchain and cached
-next to the source; ``native_available()`` reports whether the toolchain/binary
-is usable so callers can fall back to the pure-Python tier.
+next to the source under a name that carries the source's hash, so a binary
+built from any other source — a stale one copied along with the tree — is
+never loaded. ``native_available()`` reports whether the toolchain/binary is
+usable so callers can fall back to the pure-Python tier; a failed build is
+logged once, with the compiler's stderr.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 import threading
@@ -20,18 +25,28 @@ from typing import Optional
 
 __all__ = ["load_datacache_lib", "native_available", "NativeChunkStore"]
 
-_SRC = os.path.join(os.path.dirname(__file__), "datacache.cpp")
-_LIB = os.path.join(os.path.dirname(__file__), "_datacache.so")
+_DIR = os.path.dirname(__file__)
+_SRC = os.path.join(_DIR, "datacache.cpp")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_error: Optional[str] = None
+_log = logging.getLogger(__name__)
 
 
-def _build() -> None:
-    cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", _LIB]
+def lib_path() -> str:
+    """``_datacache-<source sha256, 12 hex>.so`` next to the source."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    return os.path.join(_DIR, f"_datacache-{digest}.so")
+
+
+def _build(lib: str) -> None:
+    tmp = f"{lib}.tmp.{os.getpid()}"
+    cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp]
     result = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
     if result.returncode != 0:
         raise RuntimeError(f"native build failed: {result.stderr[-1000:]}")
+    os.replace(tmp, lib)  # atomic: a concurrent loader never sees a torn file
 
 
 def load_datacache_lib() -> ctypes.CDLL:
@@ -43,11 +58,16 @@ def load_datacache_lib() -> ctypes.CDLL:
         if _build_error is not None:
             raise RuntimeError(_build_error)
         try:
-            if not os.path.exists(_LIB) or os.path.getmtime(_LIB) < os.path.getmtime(_SRC):
-                _build()
-            lib = ctypes.CDLL(_LIB)
+            path = lib_path()
+            if not os.path.exists(path):
+                _build(path)
+            lib = ctypes.CDLL(path)
         except Exception as e:  # remember the failure; don't retry every call
             _build_error = f"{type(e).__name__}: {e}"
+            _log.warning(
+                "native chunk store unavailable, the pure-Python store runs "
+                "instead: %s", _build_error,
+            )
             raise RuntimeError(_build_error) from e
         lib.dc_create.restype = ctypes.c_void_p
         lib.dc_create.argtypes = [ctypes.c_size_t, ctypes.c_char_p]

@@ -184,7 +184,7 @@ def _fold_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
         (1, TQ_TILE, D), lambda i, j, *_: (i, j, 0), memory_space=pltpu.VMEM
     )
     full3 = pl.BlockSpec((1, Tk, D), lambda i, j, *_: (i, 0, 0), memory_space=pltpu.VMEM)
-    from flink_ml_tpu.parallel.mesh import shape_dtype_struct, vma_of
+    from flink_ml_tpu.parallel.mesh import vma_of
 
     vma = vma_of(q)
     mo, lo, ao = pl.pallas_call(
@@ -196,11 +196,12 @@ def _fold_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
             out_specs=[tile2, tile2, tile3],
         ),
         out_shape=[
-            shape_dtype_struct((BH, Tq, 1), jnp.float32, vma=vma),
-            shape_dtype_struct((BH, Tq, 1), jnp.float32, vma=vma),
-            shape_dtype_struct((BH, Tq, D), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((BH, Tq, 1), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((BH, Tq, 1), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((BH, Tq, D), jnp.float32, vma=vma),
         ],
         interpret=interpret,
+        name="flash_fold_fwd",
     )(
         scalars,
         q.reshape(BH, Tq, D),
@@ -313,7 +314,7 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from flink_ml_tpu.parallel.mesh import shape_dtype_struct, vma_of
+    from flink_ml_tpu.parallel.mesh import vma_of
 
     B_, H, Tq, D = q.shape
     Tk = kb.shape[2]
@@ -453,7 +454,7 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
     fullk_mat = pl.BlockSpec((1, Tk, D), lambda i, j, *_: (i, 0, 0), memory_space=pltpu.VMEM)
 
     def sds(shape):
-        return shape_dtype_struct(shape, jnp.float32, vma=vma)
+        return jax.ShapeDtypeStruct(shape, jnp.float32, vma=vma)
 
     q4 = q.reshape(BH, Tq, D)
     k4 = kb.reshape(BH, Tk, D)
@@ -481,6 +482,7 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
             sds((BH, Tq, 1)),
         ],
         interpret=interpret,
+        name="flash_fold_bwd_dq",
     )(
         scalars, q4, k4, v4,
         m.reshape(BH, Tq, 1), l.reshape(BH, Tq, 1), acc.reshape(BH, Tq, D),
@@ -506,6 +508,7 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
         ),
         out_shape=[sds((BH, Tk, D)), sds((BH, Tk, D))],
         interpret=interpret,
+        name="flash_fold_bwd_dkv",
     )(scalars, k4, v4, q4, dacc4, dl4, safe_r, b_r, dbc_r)
 
     return (

@@ -176,25 +176,10 @@ def is_tpu_backend(devices) -> bool:
 
 
 def vma_of(x):
-    """Varying-mesh-axes of a traced value (shard_map tracks these; Pallas
-    out_shapes must declare them explicitly), or None outside shard_map."""
-    import jax
-
-    try:
-        return jax.typeof(x).vma or None
-    except Exception:
-        return None
-
-
-def shape_dtype_struct(shape, dtype, vma=None):
-    """``jax.ShapeDtypeStruct`` with the vma annotation when this jax supports
-    it (>= 0.6); older jaxlibs have no varying-axes tracking to annotate."""
-    import jax
-
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except TypeError:
-        return jax.ShapeDtypeStruct(shape, dtype)
+    """Varying-mesh-axes of a value (shard_map tracks these; Pallas
+    out_shapes must declare them explicitly), or None when it varies over
+    none — outside shard_map, or replicated inside it."""
+    return jax.typeof(x).vma or None
 
 
 def get_mesh_context() -> MeshContext:

@@ -50,6 +50,7 @@ __all__ = [
     "ULP_ENVELOPE",
     "FusionTier",
     "chain_score",
+    "fallback_recorder",
     "plan_recorder",
     "resolve_fusion_tier",
     "spec_flops_per_row",
@@ -94,9 +95,9 @@ ULP_ENVELOPE = {
     # Sparse IDF → logistic head (docs/sparse.md): the idf gather-scale fuses
     # with the gather-scale-segment-sum margin. The margin fold is a
     # sequential lax.scan — XLA cannot reassociate it — so the fused form
-    # measured 0 ulps at dims 8/64/256 and caps 1..64 on XLA CPU (interpret
-    # megakernel included); the bound carries the scale_logistic tail
-    # headroom because the contract is the envelope, not the measured order.
+    # measured 0 ulps at dims 8/64/256 and caps 1..64 on XLA CPU; the bound
+    # carries the scale_logistic tail headroom because the contract is the
+    # envelope, not the measured order.
     "sparse_idf_logistic": 32_768,
 }
 
@@ -199,12 +200,20 @@ class FusionTier:
     ) -> bool:
         """Whether the cost model marks this chain hot enough for the Pallas
         megakernel lowering at ``rows`` (fast mode only; the planner also
-        requires every spec to carry a megakernel-safe ``fusion_op``).
+        requires every spec to carry a megakernel-safe ``fusion_op``) AND its
+        operands fit the kernel's VMEM at that row count — a chain that
+        cannot fit is never chosen, so it can never fall back.
         ``precision`` feeds the bytes-moved traffic term of the score — a
         low-precision chain moves fewer bytes and clears the bar later."""
         if not (self.fast and self.megakernel):
             return False
-        return chain_score(specs, rows, width, nnz_cap, precision=precision) >= self.min_score
+        from flink_ml_tpu.servable.megakernels import fits_vmem
+
+        return (
+            fits_vmem(specs, rows, width)
+            and chain_score(specs, rows, width, nnz_cap, precision=precision)
+            >= self.min_score
+        )
 
     def __repr__(self) -> str:
         return (
@@ -254,6 +263,29 @@ def plan_recorder(scope: str):
         )
 
     return on_plan
+
+
+def fallback_recorder(scope: str):
+    """The ``on_mega_fallback`` callback both plan tiers hand to
+    ``planner.run_segment``: a megakernel the backend rejected at compile
+    time is counted (``ml.fusion.megakernel.fallbacks``) and journaled with
+    the compiler's message — the merged XLA program serves the chain, but
+    never silently."""
+    import flink_ml_tpu.telemetry as telemetry
+
+    def on_mega_fallback(ops: Sequence[str], rows: int, error: BaseException) -> None:
+        metrics.counter(scope, MLMetrics.FUSION_MEGAKERNEL_FALLBACKS)
+        telemetry.emit(
+            "fusion.megakernel.fallback",
+            scope,
+            {
+                "ops": list(ops),
+                "rows": int(rows),
+                "error": f"{type(error).__name__}: {error}"[:2000],
+            },
+        )
+
+    return on_mega_fallback
 
 
 def ulp_diff(a, b) -> int:

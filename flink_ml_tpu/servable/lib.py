@@ -14,6 +14,7 @@ executable serves both surfaces, so results are bit-identical by construction.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -159,7 +160,7 @@ class LogisticRegressionModelServable(
             kernel_fn=kernel_fn,
             input_kinds={features_col: "sparse"},
             sparse_input_dims={features_col: dim},
-            fusion_op="sparse_logistic",  # megakernel-safe sparse head
+            fusion_op="sparse_logistic",  # table gather: merged XLA only
         )
 
 
@@ -291,7 +292,19 @@ class MLPClassifierModelServable(
                 (model[f"W{i}"], model[f"b{i}"]) for i in range(n_layers)
             )
             pred_idx, probs = mlp_predict_fn(layers, cols[features_col])
-            pred = model["labels"][pred_idx.astype(jnp.int32)]
+            # labels[argmax] as a select-sum, not a gather: Mosaic lowers no
+            # 1-D gather ("Only 2D gather is supported"), and the body must
+            # lower inside the Pallas megakernel too. One nonzero term per
+            # row, so the sum is exact for any label values.
+            classes = jax.lax.broadcasted_iota(jnp.int32, probs.shape, 1)
+            pred = jnp.sum(
+                jnp.where(
+                    classes == pred_idx.astype(jnp.int32)[:, None],
+                    model["labels"][None, :],
+                    0.0,
+                ),
+                axis=1,
+            )
             return {
                 self.get_prediction_col(): pred,
                 self.get_raw_prediction_col(): probs,

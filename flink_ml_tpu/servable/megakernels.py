@@ -12,18 +12,20 @@ refs; model arrays ride along as full (untiled) operands.
 
 Safety vocabulary: a chain is megakernel-eligible only when EVERY spec names
 its body in the **megakernel-safe op set** via ``KernelSpec(fusion_op=...)``
-(:data:`MEGAKERNEL_OPS`) — ops verified to lower through Pallas (elementwise
-math, row-local reductions, matmuls, gathers). Anything else (``searchsorted``
+(:data:`MEGAKERNEL_OPS`) — ops Mosaic lowers (elementwise math, row-local
+reductions, matmuls). Anything else (table gathers, ``searchsorted``
 bucketizers, vmapped per-dim bins) stays on the merged-XLA fast path. The
 graftcheck ``fusion-tier`` rule pins the other direction: this module is the
 ONLY plan-tier module that may touch Pallas, and the planner may reach it only
 behind the fast tier.
 
-CPU fallback: on a non-TPU backend the kernel runs under ``interpret=True`` —
-the same ``pallas_call`` machinery, grid walk and body trace tier-1 exercises,
-executed by the interpreter instead of Mosaic. Interpreted numerics are the
-fused-XLA numerics of the tile body, inside the same documented ulp envelope
-(``servable/fusion.py``).
+Backends: on a TPU backend the kernel is compiled by Mosaic, never
+interpreted. Only the CPU backend (tests) runs it under ``interpret=True`` —
+the same ``pallas_call`` machinery, grid walk and body trace, executed by the
+interpreter, which enforces none of Mosaic's lowering rules. Eligibility
+(:func:`chain_eligible`, :func:`fits_vmem`) is therefore stated for the chip:
+the op vocabulary holds only bodies Mosaic lowers, and a chain whose
+operands cannot fit the scoped-VMEM limit is never built as a candidate.
 
 Precision: megakernels are **f32-only**. The low-precision tiers
 (``precision.mode=bf16|int8``, ``servable/precision.py``) apply their bf16
@@ -46,13 +48,20 @@ from jax.experimental import pallas as pl
 __all__ = [
     "MEGAKERNEL_OPS",
     "MAX_TILE_ROWS",
+    "VMEM_BUDGET_BYTES",
     "build_megakernel_fn",
     "chain_eligible",
+    "fits_vmem",
+    "vmem_bytes",
 ]
 
-#: Op ids (``KernelSpec.fusion_op``) whose kernel bodies are verified to
-#: lower through Pallas: per-element math, row-local reductions (norms,
-#: softmax, argmax/argmin), matmuls against model operands, and gathers.
+#: Op ids (``KernelSpec.fusion_op``) whose kernel bodies Mosaic lowers
+#: (compiled for TPU v5e under jax 0.9.0): per-element math, row-local
+#: reductions (norms, softmax, argmax/argmin) and matmuls against model
+#: operands. The sparse-convention bodies (``sparse_idf``,
+#: ``sparse_logistic``) are NOT here: their ``coef[indices]`` table gather
+#: fails Pallas TPU lowering ("Only 2D gather is supported"), so sparse
+#: fast-tier chains stay merged-XLA programs.
 #: docs/fusion.md documents the vocabulary next to the megakernel list.
 MEGAKERNEL_OPS = frozenset(
     {
@@ -65,24 +74,65 @@ MEGAKERNEL_OPS = frozenset(
         "logistic",  # dot + logistic_from_dots_fn head
         "kmeans",  # distance pairwise + argmin assignment
         "mlp",  # mlp_predict_fn: matmul/relu layers + softmax head
-        # Sparse calling convention (docs/sparse.md) — row-local gathers and
-        # the sequential segment-sum fold both lower through Pallas:
-        "sparse_idf",  # sparse_idf_scale_fn: gather + per-entry multiply
-        "sparse_logistic",  # sparse_dot_fn segment-sum + logistic head
     }
 )
 
 #: Upper bound on the megakernel row tile: serving buckets (≤ max batch, a
-#: power of two) run as one tile; batch chunks split into row tiles that keep
-#: per-tile VMEM residency (inputs + intermediates + outputs) well under the
-#: ~16 MB/core budget at the widths the cost model marks hot.
+#: power of two) run as one tile; batch chunks split into row tiles. Whether
+#: a tile of this many rows FITS is :func:`fits_vmem`'s call, per chain.
 MAX_TILE_ROWS = 4096
+
+#: What one kernel's operands and intermediates may claim of Mosaic's
+#: scoped-VMEM limit — 16 MiB per kernel on TPU v5e ("Scoped allocation with
+#: size 23.32M and limit 16.00M exceeded scoped vmem limit"), less headroom
+#: for the compiler's own scratch.
+VMEM_BUDGET_BYTES = 14 << 20
+
+
+def _padded_bytes(shape: Tuple[int, ...]) -> int:
+    """f32 bytes of an array in VMEM's (8, 128)-tiled layout."""
+    dims = list(shape) or [1]
+    dims[-1] = -(-dims[-1] // 128) * 128
+    if len(dims) >= 2:
+        dims[-2] = -(-dims[-2] // 8) * 8
+    return 4 * int(np.prod(dims))
+
+
+def vmem_bytes(specs: Sequence[Any], rows: int, width: int = 0) -> int:
+    """Estimated scoped-VMEM bytes of ``specs`` as one megakernel at
+    ``rows``, from operand shapes alone: every model array resident whole,
+    plus four row tiles as wide as the chain's widest operand (``width`` is
+    the widest ingest column) — the input, the output and two live
+    intermediates. Past one grid step Pallas double-buffers every operand.
+    An upper bound, not a fit: against the compiler's own allocation on
+    v5e it reads 33.8 MiB for scaler→MLP 256-512-512-8 at a 4096-row tile
+    (23.32 MiB allocated — over the limit either way) and 18.5 MiB for MLP
+    1024-2048-1024-8 at 64 rows (17.10 MiB allocated). A chain it declines
+    serves as the merged XLA program."""
+    shapes = [
+        np.shape(arr) for spec in specs for arr in spec.model_arrays.values()
+    ]
+    model = sum(_padded_bytes(s) for s in shapes)
+    widest = max([width] + [s[-1] for s in shapes if s])
+    tile = _row_tile(rows)
+    row_tile = _padded_bytes((tile, widest))
+    buffers = 2 if rows > tile else 1
+    return buffers * (model + 2 * row_tile) + 2 * row_tile
+
+
+def fits_vmem(specs: Sequence[Any], rows: int, width: int = 0) -> bool:
+    """Whether the chain's megakernel fits the scoped-VMEM budget at
+    ``rows`` — the per-key half of eligibility (``FusionTier.megakernel_hot``
+    asks before the cost model does)."""
+    return vmem_bytes(specs, rows, width) <= VMEM_BUDGET_BYTES
 
 
 def chain_eligible(specs: Sequence[Any]) -> bool:
     """Whether this spec run may lower as one megakernel: every spec's body
-    is in the safe op vocabulary, and every model operand has at least one
-    axis (0-d scalars would need an SMEM path the vocabulary doesn't)."""
+    is in the op vocabulary Mosaic lowers, every model operand has at least
+    one axis (0-d scalars would need an SMEM path the vocabulary doesn't),
+    and the model operands fit VMEM at the smallest row tile — a chain that
+    can fit at no row count is never built as a candidate."""
     if not specs:
         return False
     for spec in specs:
@@ -91,7 +141,7 @@ def chain_eligible(specs: Sequence[Any]) -> bool:
         for arr in spec.model_arrays.values():
             if np.asarray(arr).ndim == 0:
                 return False
-    return True
+    return fits_vmem(specs, rows=8)
 
 
 def _row_tile(rows: int) -> int:
@@ -190,6 +240,7 @@ def build_megakernel_fn(
                 for n in out_names
             ],
             interpret=interpret,
+            name="serving_megakernel",
         )
         results = call(*col_vals, *model_vals)
         return dict(zip(out_names, results))

@@ -16,12 +16,13 @@ chain executor's ``lower().compile()`` a **load-or-compile**:
   executable instead of compiling it — measured ~15-50× faster than the XLA
   compile on this backend, bit-identical by construction (the loaded
   executable IS the compiled artifact).
-- **Tier 2 — JAX's persistent compilation cache.** Activating a plan cache
-  also points ``jax_compilation_cache_dir`` at ``<dir>/xla`` (unless the
-  deployment already set one), so programs tier 1 cannot carry (fallback
-  stages' own jit kernels, executables whose serialization the backend
-  rejects) still skip the XLA backend work on a warm disk. Tier 2 is
-  governed by JAX's own knobs (min compile seconds, entry size).
+- **Tier 2 — JAX's persistent compilation cache.** Not this module's: the
+  process-wide cache the entry points place once
+  (``utils/compile_cache.py`` — ``JAX_COMPILATION_CACHE_DIR``, else
+  ``<checkout>/.jax_cache``). Programs tier 1 cannot carry (fallback stages'
+  own jit kernels, executables whose serialization the backend rejects)
+  skip the XLA backend work through it on a warm disk. Activating a plan
+  cache never moves it.
 
 **Key schema** (docs/plancache.md): the digest is a content fingerprint of
 the program's *lowered StableHLO text* — which bakes in the spec-chain
@@ -417,9 +418,7 @@ def resolve_plan_cache() -> Optional[PlanCache]:
     """The process's plan cache per the config tier (``plancache.enabled`` /
     ``plancache.dir`` / ``plancache.max.bytes``), or None when inactive —
     the default: with no directory configured every plan compiles live,
-    exactly the pre-cache behavior. First activation of a directory also
-    points JAX's persistent compilation cache (tier 2) at ``<dir>/xla``
-    unless the deployment already configured one."""
+    exactly the pre-cache behavior."""
     if not config.get(Options.PLANCACHE_ENABLED):
         return None
     directory = config.get(Options.PLANCACHE_DIR)
@@ -441,27 +440,5 @@ def resolve_plan_cache() -> Optional[PlanCache]:
         if cache is None:
             cache = candidate
             _CACHES[key] = cache
-            _enable_xla_cache_tier(key[0])
         return cache
 
-
-def _enable_xla_cache_tier(directory: str) -> None:
-    """Tier 2: JAX's persistent compilation cache under ``<dir>/xla`` — set
-    only when the deployment has not already chosen its own location, and
-    never fatal (an old jax without the option just skips the tier)."""
-    try:
-        current = jax.config.jax_compilation_cache_dir
-    except AttributeError:
-        return
-    if current:
-        return
-    try:
-        jax.config.update(
-            "jax_compilation_cache_dir", os.path.join(directory, "xla")
-        )
-    except Exception as e:  # noqa: BLE001 — tier 2 is best-effort by design
-        telemetry.emit(
-            "plancache.xla_tier",
-            SCOPE,
-            {"outcome": "unavailable", "error": type(e).__name__},
-        )

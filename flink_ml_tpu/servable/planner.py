@@ -65,6 +65,7 @@ tier applies belongs to the resolved ``FusionTier`` the caller passes.
 from __future__ import annotations
 
 import time
+import warnings
 from typing import Any, Callable, Dict, Hashable, List, Optional, Sequence, Tuple
 
 import jax
@@ -245,7 +246,9 @@ def _fast_megakernels(
         return {}
     from flink_ml_tpu.servable.megakernels import build_megakernel_fn, chain_eligible
 
-    interpret = jax.default_backend() != "tpu"
+    # Mosaic compiles the kernel on every accelerator backend; only the CPU
+    # backend (tests) has no Mosaic target and runs the interpreter.
+    interpret = jax.default_backend() == "cpu"
     out: Dict[int, _MegaProgram] = {}
     for idx, prog in enumerate(programs):
         if chain_eligible(prog.specs):
@@ -684,6 +687,9 @@ def run_segment(
     replicated: bool = False,
     cache: Optional[Any] = None,
     on_cache: Optional[Callable[[str, float], None]] = None,
+    on_mega_fallback: Optional[
+        Callable[[Sequence[str], int, BaseException], None]
+    ] = None,
 ) -> Dict[str, Any]:
     """Execute the segment's executable chain for ``key``: each program runs
     on the committed device model buffers and the (device-resident) outputs
@@ -694,7 +700,11 @@ def run_segment(
     choice the cost model made (exact / fused / megakernel — the
     ``ml.fusion.*`` accounting). On a fast-tier segment the choice is
     per-key: a run with a megakernel candidate lowers it only when the
-    cost-model score at this key's rows clears the tier's bar. On a sharded
+    cost-model score at this key's rows clears the tier's bar and the chain
+    fits the kernel's VMEM. A megakernel the backend still rejects at
+    compile time falls back to the merged XLA program LOUDLY: one warning,
+    and ``on_mega_fallback(ops, rows, error)`` so the caller can count and
+    journal it (``fusion.fallback_recorder``). On a sharded
     segment the chain lowers SPMD — batch rows split over the data axis, or
     fully ``replicated`` for a sub-floor ragged tail (the caller bakes the
     mode into ``key``: the two compile different executables).
@@ -739,29 +749,39 @@ def run_segment(
         cols: Dict[str, Any] = dict(inputs)
         for idx, xla_prog in enumerate(segment.programs):
             prog = xla_prog
-            mega = segment.mega.get(idx)
-            if mega is not None and segment.fusion.megakernel_hot(
-                prog.specs, rows, width, nnz_cap, precision=segment.precision
-            ):
-                prog = mega
             stage_inputs = {n: cols[n] for n in prog.inputs}
             structs = {
                 n: _lowering_struct(segment, a, replicated)
                 for n, a in stage_inputs.items()
             }
-            try:
-                compiled = _load_or_compile(
-                    prog, structs, segment, replicated, cache, on_cache,
-                    sparse_key=nnz_cap or None,
-                )
-            except Exception:
-                if prog is xla_prog:
-                    raise
-                # A megakernel the backend's Pallas lowering rejects (e.g.
-                # Mosaic tiling rules stricter than interpret mode) must not
-                # take the fast tier down — the merged XLA program computes
-                # the same chain inside the same ulp envelope.
-                prog = xla_prog
+            compiled = None
+            mega = segment.mega.get(idx)
+            if mega is not None and segment.fusion.megakernel_hot(
+                prog.specs, rows, width, nnz_cap, precision=segment.precision
+            ):
+                try:
+                    compiled = _load_or_compile(
+                        mega, structs, segment, replicated, cache, on_cache,
+                        sparse_key=nnz_cap or None,
+                    )
+                    prog = mega
+                except Exception as e:  # noqa: BLE001 — Pallas/Mosaic raise private types
+                    # A megakernel the backend's Pallas lowering rejects must
+                    # not take the fast tier down — the merged XLA program
+                    # computes the same chain inside the same ulp envelope —
+                    # but the chip must not hide behind it either.
+                    ops = [spec.fusion_op for spec in prog.specs]
+                    warnings.warn(
+                        f"megakernel {'+'.join(ops)} rejected by the "
+                        f"{jax.default_backend()} backend; serving the merged "
+                        f"XLA program instead: {type(e).__name__}: "
+                        f"{str(e)[:500]}",
+                        RuntimeWarning,
+                        stacklevel=2,
+                    )
+                    if on_mega_fallback is not None:
+                        on_mega_fallback(ops, rows, e)
+            if compiled is None:
                 compiled = _load_or_compile(
                     prog, structs, segment, replicated, cache, on_cache,
                     sparse_key=nnz_cap or None,

@@ -50,7 +50,11 @@ import numpy as np
 from flink_ml_tpu.api.dataframe import DataFrame
 from flink_ml_tpu.metrics import MLMetrics, metrics
 from flink_ml_tpu.servable.builder import PipelineModelServable
-from flink_ml_tpu.servable.fusion import plan_recorder, resolve_fusion_tier
+from flink_ml_tpu.servable.fusion import (
+    fallback_recorder,
+    plan_recorder,
+    resolve_fusion_tier,
+)
 from flink_ml_tpu.servable.plancache import resolve_plan_cache
 from flink_ml_tpu.servable.precision import (
     PRECISION_GAUGE_VALUE,
@@ -116,6 +120,7 @@ class CompiledServingPlan:
         #: server's swap telemetry reports it per version flip.
         self.last_warmup_cache: Optional[Dict[str, Any]] = None
         self._on_plan = plan_recorder(scope)
+        self._on_mega_fallback = fallback_recorder(scope)
         n_fused = sum(len(s.specs) for s in segments if isinstance(s, FusedSegment))
         n_fallback = sum(1 for s in segments if isinstance(s, FallbackStage))
         metrics.gauge(scope, MLMetrics.SERVING_FUSED_STAGES, n_fused)
@@ -257,6 +262,7 @@ class CompiledServingPlan:
                                 on_plan=self._on_plan,
                                 cache=self.plancache,
                                 on_cache=on_cache if self.plancache is not None else None,
+                                on_mega_fallback=self._on_mega_fallback,
                             )
                             # The cost model's per-bucket choice (may be
                             # "fast+mega") — goodput attribution splits
@@ -310,6 +316,7 @@ class CompiledServingPlan:
             ),
             on_plan=self._on_plan,
             cache=self.plancache,
+            on_mega_fallback=self._on_mega_fallback,
         )
 
     # -- the hot path ---------------------------------------------------------

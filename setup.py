@@ -7,6 +7,7 @@ library loaded through ctypes — not a Python extension module — so instead o
 still work: `flink_ml_tpu.native` falls back to lazy compilation on first use
 and, failing that, to the pure-Python cache tier.
 """
+import hashlib
 import subprocess
 from pathlib import Path
 
@@ -18,7 +19,9 @@ class BuildWithNative(build_py):
     def run(self):
         super().run()
         src = Path(__file__).parent / "flink_ml_tpu" / "native" / "datacache.cpp"
-        out = Path(self.build_lib) / "flink_ml_tpu" / "native" / "_datacache.so"
+        # the name flink_ml_tpu.native.lib_path() looks for: source hash in it
+        digest = hashlib.sha256(src.read_bytes()).hexdigest()[:12]
+        out = Path(self.build_lib) / "flink_ml_tpu" / "native" / f"_datacache-{digest}.so"
         if not out.parent.exists():
             return
         cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", str(src), "-o", str(out)]

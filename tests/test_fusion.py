@@ -327,6 +327,67 @@ def test_megakernel_lowering_failure_falls_back_to_fused_program():
     ]
 
 
+def test_megakernel_fallback_is_counted_journaled_and_warned(tmp_path, monkeypatch):
+    """The fallback keeps serving alive but is never silent: the new counter
+    moves, the flight recorder carries the compiler's message, and one
+    warning is raised (the chip must not hide behind the XLA program)."""
+    import flink_ml_tpu.servable.megakernels as megakernels
+    import flink_ml_tpu.telemetry as telemetry
+
+    def rejected_by_mosaic(specs, models, input_names, interpret):
+        def mega(model_seq, cols):
+            raise NotImplementedError("Only 2D gather is supported")
+
+        return mega
+
+    monkeypatch.setattr(megakernels, "build_megakernel_fn", rejected_by_mosaic)
+    scope = "t-mega-fallback"
+    rec = telemetry.configure(str(tmp_path))
+    try:
+        plan = CompiledServingPlan.build(
+            _scale_logistic_servable(16), scope=scope,
+            fusion=FusionTier("fast", min_score=1.0),
+        )
+        with pytest.warns(RuntimeWarning, match="Only 2D gather is supported"):
+            out = plan.execute(_vec_df(8, 16, col="features"))
+        assert rec.flush(10.0)
+        journaled = [
+            r for r in telemetry.read_journal(str(tmp_path))
+            if r["kind"] == "fusion.megakernel.fallback"
+        ]
+    finally:
+        telemetry.configure(None)
+    assert metrics.get(scope, MLMetrics.FUSION_MEGAKERNEL_FALLBACKS, 0) == 1
+    assert metrics.get(scope, MLMetrics.FUSION_PROGRAMS_MEGAKERNEL, 0) == 0
+    assert metrics.get(scope, MLMetrics.FUSION_PROGRAMS_FUSED, 0) == 1
+    (record,) = journaled
+    assert record["scope"] == scope
+    assert record["data"]["ops"] == ["scale", "logistic"]
+    assert record["data"]["rows"] == 8
+    assert "NotImplementedError: Only 2D gather is supported" in record["data"]["error"]
+    assert np.all(np.isfinite(np.asarray(out.column("rawPrediction"))))
+
+
+def test_megakernel_never_a_candidate_past_the_vmem_budget():
+    """Eligibility is stated for the chip: a chain whose model operands
+    cannot fit the kernel's VMEM is never built as a candidate, and a chain
+    that fits is declined at the row counts where its tiles do not."""
+    from flink_ml_tpu.servable.megakernels import VMEM_BUDGET_BYTES, fits_vmem, vmem_bytes
+
+    small = [s.kernel_spec() for s in _scale_mlp_servable().servables]
+    assert chain_eligible(small)
+    assert fits_vmem(small, rows=64, width=256)
+    # the repo's MLP training widths: 2048x4096 f32 is 32 MiB of weights alone
+    wide = [s.kernel_spec() for s in _scale_mlp_servable(d=2048, hidden=4096).servables]
+    assert vmem_bytes(wide, rows=8) > VMEM_BUDGET_BYTES
+    assert not chain_eligible(wide)
+    # monotone in rows, and the tier asks before the cost model does
+    tall = [s.kernel_spec() for s in _scale_mlp_servable(hidden=512).servables]
+    assert fits_vmem(tall, rows=64, width=256) and not fits_vmem(tall, rows=4096, width=256)
+    tier = FusionTier("fast", min_score=0.0)
+    assert tier.megakernel_hot(tall, 64, 256) and not tier.megakernel_hot(tall, 4096, 256)
+
+
 def test_megakernel_vocabulary_and_eligibility():
     assert {"scale", "logistic", "mlp", "normalize", "binarize"} <= MEGAKERNEL_OPS
     servable = _scale_logistic_servable(8)
