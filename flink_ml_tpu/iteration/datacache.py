@@ -29,7 +29,9 @@ import jax
 import numpy as np
 
 from flink_ml_tpu.faults import faults
+from flink_ml_tpu.metrics import MLMetrics, metrics
 from flink_ml_tpu.parallel.mesh import MeshContext, get_mesh_context
+from flink_ml_tpu.trace import CAT_INGEST, tracer
 
 __all__ = ["DeviceDataCache", "HostDataCache", "create_capacity_cache"]
 
@@ -108,7 +110,15 @@ class DeviceDataCache:
         duplicate in HBM. Trailing dims named by a mesh axis are zero-padded
         to that axis size."""
         self.ctx = ctx or get_mesh_context()
-        column_specs = column_specs or {}
+        with tracer.phase("train.cache_put", CAT_INGEST, columns=len(columns)) as phase:
+            self._put(columns, column_specs or {})
+            # what the host handed to device_put, padding and mask included; the
+            # phase times the host's part of the upload, not the transfer's end
+            nbytes = sum(a.nbytes for a in self.arrays.values())
+            phase.set_metadata(bytes=nbytes)
+        metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_H2D_BYTES, nbytes)
+
+    def _put(self, columns: Dict[str, np.ndarray], column_specs: Dict[str, tuple]) -> None:
         lengths = {np.asarray(c).shape[0] for c in columns.values()}
         if len(lengths) != 1:
             raise ValueError(f"inconsistent column lengths {lengths}")

@@ -58,6 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from flink_ml_tpu.parallel.mesh import vma_of
+from flink_ml_tpu.trace import CAT_INGEST, tracer
 from flink_ml_tpu.utils.arrays import group_ranks, next_pow2
 
 __all__ = [
@@ -304,57 +305,64 @@ class OneHotSparseLayout:
         f32 fits, but direct callers lose f64 precision here)."""
         from flink_ml_tpu.ops.schedule import offset_schedule
 
-        indices = np.asarray(indices, np.int64)
-        values = np.asarray(values)
-        n = indices.shape[0]
-        m = -(-n // n_shards)  # local rows per shard (cache pads to this)
-        local_batch = min(local_batch, m)
-        sub = min(sub_rows, local_batch)
-        n_sub = -(-local_batch // sub)
+        # Five phases (docs/observability.md, "The fit span tree"); every
+        # statement of the build lies in one of them.
+        with tracer.phase("train.layout.prepare", CAT_INGEST):
+            indices = np.asarray(indices, np.int64)
+            values = np.asarray(values)
+            n = indices.shape[0]
+            m = -(-n // n_shards)  # local rows per shard (cache pads to this)
+            local_batch = min(local_batch, m)
+            sub = min(sub_rows, local_batch)
+            n_sub = -(-local_batch // sub)
 
-        # Distinct windows, in first-visit order, from the canonical schedule.
-        starts, _ = offset_schedule(m, local_batch, max(1, -(-m // local_batch)))
-        window_starts = list(dict.fromkeys(int(s) for s in starts))
-        n_windows = len(window_starts)
+            # Distinct windows, in first-visit order, from the canonical schedule.
+            starts, _ = offset_schedule(m, local_batch, max(1, -(-m // local_batch)))
+            window_starts = list(dict.fromkeys(int(s) for s in starts))
+            n_windows = len(window_starts)
 
-        nblk = -(-dim // BLOCK)
-        validate_indices(indices, dim)
+            nblk = -(-dim // BLOCK)
+            validate_indices(indices, dim)
+            n_units = n_shards * n_windows * n_sub
 
         # Pass 1 (counting): per-block max entry count over every unit.
-        max_count = np.zeros(nblk, np.int64)
-        bounds = []  # unit -> (r0, r1) row range
-        for s in range(n_shards):
-            lo_s = s * m
-            for w0 in window_starts:
-                for b0 in range(0, local_batch, sub):
-                    r0 = lo_s + w0 + b0
-                    r1 = min(r0 + sub, lo_s + min(w0 + local_batch, m), n)
-                    np.maximum(
-                        max_count,
-                        block_counts(indices[r0:r1], values[r0:r1], nblk),
-                        out=max_count,
-                    )
-                    bounds.append((r0, r1))
+        with tracer.phase("train.layout.count", CAT_INGEST, units=n_units):
+            max_count = np.zeros(nblk, np.int64)
+            bounds = []  # unit -> (r0, r1) row range
+            for s in range(n_shards):
+                lo_s = s * m
+                for w0 in window_starts:
+                    for b0 in range(0, local_batch, sub):
+                        r0 = lo_s + w0 + b0
+                        r1 = min(r0 + sub, lo_s + min(w0 + local_batch, m), n)
+                        np.maximum(
+                            max_count,
+                            block_counts(indices[r0:r1], values[r0:r1], nblk),
+                            out=max_count,
+                        )
+                        bounds.append((r0, r1))
 
-        plan = OneHotSparsePlan.from_max_counts(max_count, dim, sub, n_model)
-        n_units = n_shards * n_windows * n_sub
-        if max_stack_bytes is not None and plan.stack_bytes(n_units) > max_stack_bytes:
-            return None
+        with tracer.phase("train.layout.plan", CAT_INGEST):
+            plan = OneHotSparsePlan.from_max_counts(max_count, dim, sub, n_model)
+            if max_stack_bytes is not None and plan.stack_bytes(n_units) > max_stack_bytes:
+                return None
 
-        shape = (n_shards, n_model, n_windows, n_sub, plan.n_flat)
-        lidx = np.zeros(shape, np.int8)
-        rowid = np.zeros(shape, np.int16)
-        lvals = np.zeros(shape, np.float32 if values.dtype.kind == "f" else values.dtype)
-        unit_iter = iter(bounds)
-        for s in range(n_shards):
-            for wi in range(n_windows):
-                for bi in range(n_sub):
-                    r0, r1 = next(unit_iter)
-                    plan.fill_unit(
-                        indices[r0:r1], values[r0:r1],
-                        lidx[s, :, wi, bi], rowid[s, :, wi, bi],
-                        lvals[s, :, wi, bi],
-                    )
+        with tracer.phase("train.layout.alloc", CAT_INGEST, bytes=plan.stack_bytes(n_units)):
+            shape = (n_shards, n_model, n_windows, n_sub, plan.n_flat)
+            lidx = np.zeros(shape, np.int8)
+            rowid = np.zeros(shape, np.int16)
+            lvals = np.zeros(shape, np.float32 if values.dtype.kind == "f" else values.dtype)
+        with tracer.phase("train.layout.fill", CAT_INGEST, units=n_units):
+            unit_iter = iter(bounds)
+            for s in range(n_shards):
+                for wi in range(n_windows):
+                    for bi in range(n_sub):
+                        r0, r1 = next(unit_iter)
+                        plan.fill_unit(
+                            indices[r0:r1], values[r0:r1],
+                            lidx[s, :, wi, bi], rowid[s, :, wi, bi],
+                            lvals[s, :, wi, bi],
+                        )
 
         return cls(
             plan=plan, dim=int(dim), n_shards=n_shards, n_windows=n_windows,

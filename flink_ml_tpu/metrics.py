@@ -47,6 +47,12 @@ class MLMetrics:
     TRAIN_SHARD_INGEST_ROWS = "ml.train.shard.ingest.rows"  # rows dealt onto the mesh, counter
     TRAIN_SHARD_PAD_ROWS = "ml.train.shard.pad.rows"  # zero-mask padding rows, counter
     TRAIN_SHARDED_FITS = "ml.train.sharded.fits"  # fits run on the deterministic tier, counter
+    # The fit prologue, counted where its train.* phases are opened (trace.py
+    # Tracer.phase; docs/observability.md "The fit span tree").
+    TRAIN_LAYOUT_BUILDS = "ml.train.layout.builds"  # one-hot layouts built on the host, counter
+    TRAIN_LAYOUT_REUSES = "ml.train.layout.reuses"  # fits answered by a cache's layout memo, counter
+    TRAIN_PREMAT_BUILDS = "ml.train.premat.builds"  # premat one-hot materializations on device, counter
+    TRAIN_H2D_BYTES = "ml.train.h2d.bytes"  # bytes handed to device_put by caches and layouts, counter
 
     # Online-serving runtime (scope = "ml.serving[<server name>]" — see
     # docs/serving.md for the full table).

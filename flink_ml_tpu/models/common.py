@@ -14,6 +14,7 @@ import numpy as np
 
 from flink_ml_tpu.api.dataframe import DataFrame
 from flink_ml_tpu.api.types import BasicType, DataTypes
+from flink_ml_tpu.trace import CAT_INGEST, tracer
 from flink_ml_tpu.utils import read_write as rw
 
 __all__ = ["extract_labeled_data", "ModelArraysMixin"]
@@ -37,22 +38,27 @@ def extract_labeled_data(
     padded-CSR layout instead — ``indices``/``values`` [n, K] plus ``dim`` —
     so wide sparse training (the SparseVector.java path) never densifies.
     """
-    if allow_sparse and df.is_sparse(features_col):
-        batch = df.sparse_batch(features_col)
-        out = {
-            "indices": batch.indices,
-            "values": batch.values.astype(dtype),
-            "dim": batch.dim,
-        }
-        n = batch.n
-    else:
-        out = {"features": df.vectors(features_col).astype(dtype)}
-        n = out["features"].shape[0]
-    if label_col:
-        out["labels"] = df.scalars(label_col, dtype)
-    out["weights"] = (
-        df.scalars(weight_col, dtype) if weight_col else np.ones(n, dtype)
-    )
+    sparse = allow_sparse and df.is_sparse(features_col)
+    with tracer.phase("train.pack", CAT_INGEST, rows=df.num_rows, sparse=int(sparse)) as phase:
+        if sparse:
+            batch = df.sparse_batch(features_col)
+            out = {
+                "indices": batch.indices,
+                "values": batch.values.astype(dtype),
+                "dim": batch.dim,
+            }
+            n = batch.n
+            nnz = int(batch.nnz.sum())  # stored entries, padding left out
+        else:
+            out = {"features": df.vectors(features_col).astype(dtype)}
+            n = out["features"].shape[0]
+            nnz = int(out["features"].size)
+        if label_col:
+            out["labels"] = df.scalars(label_col, dtype)
+        out["weights"] = (
+            df.scalars(weight_col, dtype) if weight_col else np.ones(n, dtype)
+        )
+        phase.set_metadata(nnz=nnz)
     return out
 
 

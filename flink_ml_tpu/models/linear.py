@@ -30,6 +30,7 @@ from flink_ml_tpu.params.shared import (
     HasTol,
     HasWeightCol,
 )
+from flink_ml_tpu.trace import CAT_PRODUCTIVE, tracer
 
 from flink_ml_tpu.ops.kernels import compute_dots  # canonical home: ops/kernels.py
 # (re-exported here for backward compatibility — the servable tier must reach
@@ -78,24 +79,26 @@ class LinearEstimatorBase(
 
     def fit(self, *inputs) -> LinearModelBase:
         (df,) = inputs
-        data = extract_labeled_data(
-            df,
-            self.get_features_col(),
-            self.get_label_col(),
-            self.get_weight_col(),
-            allow_sparse=True,
-        )
-        self._validate_labels(data["labels"])
-        dim = data.pop("dim", None) or data["features"].shape[1]
-        optimizer = self._make_optimizer()
-        coefficient = optimizer.optimize(np.zeros(dim, np.float32), data, self._LOSS)
-        # per-epoch observability for the benchmark harness / callers; the
-        # optimizer records which route the fit took (onehot_premat_active)
-        self.loss_history = list(optimizer.loss_history)
-        self.optimizer = optimizer
-        model = self._MODEL_CLASS()
-        update_existing_params(model, self)
-        model.coefficient = np.asarray(coefficient)
+        with tracer.phase("train.fit", CAT_PRODUCTIVE, rows=df.num_rows) as phase:
+            data = extract_labeled_data(
+                df,
+                self.get_features_col(),
+                self.get_label_col(),
+                self.get_weight_col(),
+                allow_sparse=True,
+            )
+            self._validate_labels(data["labels"])
+            dim = data.pop("dim", None) or data["features"].shape[1]
+            phase.set_metadata(dim=int(dim))
+            optimizer = self._make_optimizer()
+            coefficient = optimizer.optimize(np.zeros(dim, np.float32), data, self._LOSS)
+            # kept for callers: the per-epoch losses, and the optimizer, which
+            # records the route the fit took (onehot_premat_active)
+            self.loss_history = list(optimizer.loss_history)
+            self.optimizer = optimizer
+            model = self._MODEL_CLASS()
+            update_existing_params(model, self)
+            model.coefficient = np.asarray(coefficient)
         return model
 
     def _validate_labels(self, labels: np.ndarray) -> None:

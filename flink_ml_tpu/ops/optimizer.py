@@ -55,6 +55,7 @@ from flink_ml_tpu.iteration import (
     TerminateOnMaxIterOrTol,
     iterate_bounded_until_termination,
 )
+from flink_ml_tpu.metrics import MLMetrics, metrics
 from flink_ml_tpu.ops.lossfunc import LossFunc
 
 # Re-exported for the fused-trainer callers (models, iteration.streaming);
@@ -72,6 +73,13 @@ from flink_ml_tpu.parallel.mesh import (
 from flink_ml_tpu.parallel.train_sharding import (
     TrainSharding,
     resolve_train_sharding,
+)
+from flink_ml_tpu.trace import (
+    CAT_COMPILE,
+    CAT_INGEST,
+    CAT_PRODUCTIVE,
+    CAT_READBACK,
+    tracer,
 )
 
 __all__ = ["Optimizer", "SGD", "regularize"]
@@ -1085,19 +1093,22 @@ class SGD(Optimizer):
             # sparse epochs: the forward gather + the gradient scatter
             serial = 2 * local_batch * int(train_data["indices"].shape[-1]) if sparse else 0
             chunk = fused_chunk_len(self.max_iter, check_loss, serial)
-            program = _fused_sgd_program(
-                ctx,
-                loss_func,
-                local_batch,
-                chunk,
-                self.learning_rate,
-                self.reg,
-                self.elastic_net,
-                self.tol if check_loss else None,
-                self.dtype,
-                sparse=sparse,
-                model_sharded=model_sharded,
-            )
+            with tracer.phase("train.program", CAT_COMPILE) as phase:
+                known = tuple(_FUSED_CACHE.values())
+                program = _fused_sgd_program(
+                    ctx,
+                    loss_func,
+                    local_batch,
+                    chunk,
+                    self.learning_rate,
+                    self.reg,
+                    self.elastic_net,
+                    self.tol if check_loss else None,
+                    self.dtype,
+                    sparse=sparse,
+                    model_sharded=model_sharded,
+                )
+                phase.set_metadata(built=int(program not in known))
             starts, offsets = offset_schedule(train_data.local_rows, local_batch, self.max_iter)
             coef = self._place_coef(ctx, init_model, self.dtype, model_sharded)
             done = ctx.replicate(np.asarray(False))
@@ -1105,18 +1116,22 @@ class SGD(Optimizer):
             for starts_c, offsets_c, active_c, n_active in chunked_schedule(
                 starts, offsets, self.max_iter, chunk
             ):
-                coef, done, losses, n_exec = program(
-                    coef, done, starts_c, offsets_c, active_c, *data_args
-                )
+                with tracer.phase("train.dispatch", CAT_PRODUCTIVE, steps=n_active):
+                    coef, done, losses, n_exec = program(
+                        coef, done, starts_c, offsets_c, active_c, *data_args
+                    )
                 # Loss history is recorded unconditionally — the reference always
                 # streams loss through the feedback edge (SGD.java:137-143), tol
                 # or not. The losses buffer already comes back with the chunk, so
-                # this costs one fetch per chunk boundary.
-                got = _drain_losses(losses, n_exec)
+                # this costs one fetch per chunk boundary. The host waits in it
+                # while the chunk's steps run on the device: productive time.
+                with tracer.phase("train.drain", CAT_PRODUCTIVE, steps=n_active):
+                    got = _drain_losses(losses, n_exec)
                 self.loss_history.extend(got)
                 if check_loss and len(got) < n_active:  # done flipped mid-chunk
                     break
-            final = np.asarray(jax.device_get(coef))
+            with tracer.phase("train.readback", CAT_READBACK, bytes=int(coef.nbytes)):
+                final = np.asarray(jax.device_get(coef))
             return final[:dim] if model_sharded else final
 
         if sparse and self.sparse_kernel == "onehot":
@@ -1149,8 +1164,6 @@ class SGD(Optimizer):
         every shard — same dynamic_slice minibatching as the legacy path,
         same compiled program shape, one extra all_gather per epoch.
         """
-        from flink_ml_tpu.metrics import MLMetrics, metrics
-
         ctx = ts.ctx
         dim = int(np.asarray(init_model).shape[0])
         n = int(np.asarray(cols["labels"]).shape[0])
@@ -1283,33 +1296,34 @@ class SGD(Optimizer):
         the cache, ``del train_data._onehot_premat_memo``. Returns
         ``(premat, oh_stacks)`` with ``oh_stacks`` empty when the path is
         off."""
-        from flink_ml_tpu.linalg.onehot_sparse import (
-            premat_bytes,
-            premat_row_onehots,
-        )
+        from flink_ml_tpu.linalg.onehot_sparse import premat_bytes
 
-        if self.onehot_premat == "off":
-            self._drop_premat_memo(train_data)
-            return False, ()
         n_units = lay.n_windows * lay.n_sub
-        per_dev = premat_bytes(n_units, lay.n_flat, lay.row_hi) + 7 * n_units * lay.n_flat
-        if (
-            self.onehot_premat == "auto"
-            and per_dev > self._ONEHOT_PREMAT_HBM_FRACTION * _hbm_bytes_limit(ctx)
-        ):
-            self._drop_premat_memo(train_data)
-            return False, ()
+        oh_bytes = premat_bytes(n_units, lay.n_flat, lay.row_hi)
+        active = self.onehot_premat != "off" and (
+            self.onehot_premat == "on"
+            or oh_bytes + 7 * n_units * lay.n_flat
+            <= self._ONEHOT_PREMAT_HBM_FRACTION * _hbm_bytes_limit(ctx)
+        )
         key = (ctx.n_data, ctx.n_model, lay.dim, lay.local_batch, lay.row_hi)
         memo = getattr(train_data, "_onehot_premat_memo", None)
-        if memo is not None and memo[0] == key:
-            return True, memo[1]
-        if memo is not None:  # free the stale config's one-hots BEFORE
-            train_data._onehot_premat_memo = None  # allocating the new ones
-            memo = None  # the local ref would keep the buffers alive too
-        oh_stacks = _premat_materialize_jit(
-            ctx.sharding(ctx.data_axes, MODEL_AXIS)
-        )(stacks[1], lay.row_hi)
-        train_data._onehot_premat_memo = (key, oh_stacks)
+        reused = active and memo is not None and memo[0] == key
+        with tracer.phase(
+            "train.premat", CAT_INGEST, reused=int(reused), active=int(active), bytes=oh_bytes
+        ):
+            if not active:
+                self._drop_premat_memo(train_data)
+                return False, ()
+            if reused:
+                return True, memo[1]
+            if memo is not None:  # free the stale config's one-hots BEFORE
+                train_data._onehot_premat_memo = None  # allocating the new ones
+                memo = None  # the local ref would keep the buffers alive too
+            oh_stacks = _premat_materialize_jit(
+                ctx.sharding(ctx.data_axes, MODEL_AXIS)
+            )(stacks[1], lay.row_hi)
+            train_data._onehot_premat_memo = (key, oh_stacks)
+        metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_PREMAT_BUILDS)
         return True, oh_stacks
 
     @staticmethod
@@ -1351,36 +1365,51 @@ class SGD(Optimizer):
 
         key = (ctx.n_data, ctx.n_model, dim, local_batch)
         memo = getattr(train_data, "_onehot_memo", None)
-        if memo is not None and memo[0] == key and (memo[2] is not None or not force):
-            return memo[1], memo[2]
-        host = train_data.host_columns
-        # Stacks shard over the (data, model) axes — each device holds
-        # 1/(n_data*n_model) of the packed 7 B/slot total;
-        # budget the per-device slice. The bound is applied inside build()
-        # right after the counting pass, BEFORE any stack materializes — an
-        # oversized layout must not cost a multi-GiB transient host
-        # allocation just to be rejected.
-        budget = (
-            None
-            if force
-            else int(self._ONEHOT_HBM_FRACTION * _hbm_bytes_limit(ctx))
-            * ctx.n_data * ctx.n_model
-        )
-        lay = OneHotSparseLayout.build(
-            host["indices"], host["values"], dim, ctx.n_data, local_batch,
-            max_stack_bytes=budget, n_model=ctx.n_model,
-        )
+        reused = memo is not None and memo[0] == key and (memo[2] is not None or not force)
+        with tracer.phase(
+            "train.layout", CAT_INGEST, reused=int(reused), rows=train_data.n_valid
+        ) as phase:
+            if reused:
+                lay = memo[1]
+            else:
+                host = train_data.host_columns
+                # Stacks shard over the (data, model) axes — each device holds
+                # 1/(n_data*n_model) of the packed 7 B/slot total;
+                # budget the per-device slice. The bound is applied inside build()
+                # right after the counting pass, BEFORE any stack materializes — an
+                # oversized layout must not cost a multi-GiB transient host
+                # allocation just to be rejected.
+                budget = (
+                    None
+                    if force
+                    else int(self._ONEHOT_HBM_FRACTION * _hbm_bytes_limit(ctx))
+                    * ctx.n_data * ctx.n_model
+                )
+                lay = OneHotSparseLayout.build(
+                    host["indices"], host["values"], dim, ctx.n_data, local_batch,
+                    max_stack_bytes=budget, n_model=ctx.n_model,
+                )
+            phase.set_metadata(
+                units=lay.n_shards * lay.n_windows * lay.n_sub if lay is not None else 0
+            )
+        if reused:
+            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LAYOUT_REUSES)
+            return lay, memo[2]
+        metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LAYOUT_BUILDS)
         if lay is None:
             train_data._onehot_memo = (key, None, None)
             return None, None
         # Leading stack dim over (slice, data) jointly on multi-slice meshes:
         # stacks never cross DCN.
         sh = ctx.sharding(ctx.data_axes, MODEL_AXIS)
-        dev = (
-            jax.device_put(lay.lidx, sh),
-            jax.device_put(lay.rowid, sh),
-            jax.device_put(np.asarray(lay.lvals, np.float32), sh),
-        )
+        nbytes = lay.lidx.nbytes + lay.rowid.nbytes + 4 * lay.lvals.size
+        with tracer.phase("train.layout_put", CAT_INGEST, bytes=nbytes):
+            dev = (
+                jax.device_put(lay.lidx, sh),
+                jax.device_put(lay.rowid, sh),
+                jax.device_put(np.asarray(lay.lvals, np.float32), sh),
+            )
+        metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_H2D_BYTES, nbytes)
         train_data._onehot_memo = (key, lay, dev)
         return lay, dev
 
@@ -1402,11 +1431,14 @@ class SGD(Optimizer):
         # Crossing MACs bound the dispatch length (split-bf16 doubles them).
         flops = 4.0 * lay.n_sub * lay.n_flat * (lay.sub_batch + 2 * BLOCK)
         chunk = fused_chunk_len(self.max_iter, check_loss, 0, flops)
-        program = _fused_onehot_program(
-            ctx, loss_func, lay, chunk, self.learning_rate, self.reg,
-            self.elastic_net, self.tol if check_loss else None, use_pallas,
-            premat=premat,
-        )
+        with tracer.phase("train.program", CAT_COMPILE) as phase:
+            known = tuple(_FUSED_CACHE.values())
+            program = _fused_onehot_program(
+                ctx, loss_func, lay, chunk, self.learning_rate, self.reg,
+                self.elastic_net, self.tol if check_loss else None, use_pallas,
+                premat=premat,
+            )
+            phase.set_metadata(built=int(program not in known))
         starts, offsets = offset_schedule(
             train_data.local_rows, local_batch, self.max_iter
         )
@@ -1426,18 +1458,22 @@ class SGD(Optimizer):
         for win_c, offsets_c, active_c, n_active in chunked_schedule(
             win_idx, offsets, self.max_iter, chunk
         ):
-            coef, done, losses, n_exec = program(
-                coef, done, win_c, offsets_c, active_c, *stacks, *oh_stacks,
-                y, w, mask
-            )
-            got = _drain_losses(losses, n_exec)
+            with tracer.phase("train.dispatch", CAT_PRODUCTIVE, steps=n_active):
+                coef, done, losses, n_exec = program(
+                    coef, done, win_c, offsets_c, active_c, *stacks, *oh_stacks,
+                    y, w, mask
+                )
+            # the host waits here while the chunk's steps run on the device
+            with tracer.phase("train.drain", CAT_PRODUCTIVE, steps=n_active):
+                got = _drain_losses(losses, n_exec)
             self.loss_history.extend(got)
             if check_loss and len(got) < n_active:
                 break
         # Same caller-visible dtype as the scatter fused path (self.dtype —
         # f32 here, the only dtype this kernel admits): auto-selection must
         # not change the output dtype for a float64 init_model.
-        return lay.unpermute_coef(np.asarray(jax.device_get(coef)))
+        with tracer.phase("train.readback", CAT_READBACK, bytes=int(coef.nbytes)):
+            return lay.unpermute_coef(np.asarray(jax.device_get(coef)))
 
     def _pick_onehot_streamed(self, n_rows, K, dim) -> bool:
         """Whether a streamed sparse fit runs the one-hot matmul kernel.

@@ -21,6 +21,11 @@ Span model (docs/observability.md):
   it.
 - ``tracer.record`` retro-records a completed span from already-measured
   monotonic timestamps (the queue-wait span is known only at claim time).
+- ``tracer.phase(name, category, scope, **counts)`` is the form for sites that
+  run a bounded number of times per job (the phases of one ``Estimator.fit``):
+  it always enters a ``jax.profiler.TraceAnnotation`` carrying ``counts``, so
+  any profiler session sees the phase on the device trace's clock whether or
+  not the tracer records. Never on a hot site: that is ``tracer.span``'s.
 
 **Disabled is free**: ``tracer.enabled`` is a plain attribute, and every
 instrumented site either checks it or calls ``tracer.span(...)``, whose
@@ -66,6 +71,7 @@ __all__ = [
     "CAT_SWAP",
     "CAT_RECOVERY",
     "CAT_READBACK",
+    "CAT_INGEST",
     "CATEGORIES",
     "Span",
     "SpanRecorder",
@@ -86,6 +92,7 @@ CAT_COMPILE = "compile"  # trace/lower/compile + AOT warmup
 CAT_SWAP = "swap"  # version publish / flip / checkpoint persistence
 CAT_RECOVERY = "recovery"  # restart backoff, rollback, restore
 CAT_READBACK = "readback"  # blocking device->host readback
+CAT_INGEST = "ingest"  # host featurize, pack, layout, upload: data on its way to the device
 CATEGORIES = (
     CAT_PRODUCTIVE,
     CAT_QUEUE,
@@ -94,6 +101,7 @@ CATEGORIES = (
     CAT_SWAP,
     CAT_RECOVERY,
     CAT_READBACK,
+    CAT_INGEST,
 )
 
 #: Process-wide monotonically increasing span ids (itertools.count.__next__
@@ -155,6 +163,17 @@ class Span:
             self.attrs = {}
         self.attrs[key] = value
         return self
+
+    def set_metadata(self, **counts: Any) -> None:
+        """Counts a phase learns only from its own work (entries packed, bytes
+        placed). Same call as ``jax.profiler.TraceAnnotation``'s — what
+        ``tracer.phase`` hands back when the tracer is off — so a site writes
+        it once: attrs here, stats of the profiler's event while the phase's
+        annotation is open."""
+        for key, value in counts.items():
+            self.set_attr(key, value)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**counts)
 
     # -- stack-managed lifetime -----------------------------------------------
     def __enter__(self) -> "Span":
@@ -457,12 +476,14 @@ class Tracer:
 
     def _push(self, span: Span) -> None:
         self._stack().append(span)
-        if self.xprof:
-            span._annotation = _enter_annotation(span.name)
+        if span._annotation is None and self.xprof:
+            span._annotation = _annotation(span.name)
+        if span._annotation is not None:  # a phase span brings its own
+            span._annotation.__enter__()
 
     def _pop(self, span: Span) -> None:
         if span._annotation is not None:
-            _exit_annotation(span._annotation)
+            span._annotation.__exit__(None, None, None)
             span._annotation = None
         stack = self._stack()
         if stack and stack[-1] is span:
@@ -498,6 +519,33 @@ class Tracer:
         if not self.enabled:
             return _NOOP_SPAN
         return self._make(name, category, scope, parent)
+
+    def phase(self, name: str, category: str = CAT_PRODUCTIVE, scope: str = "ml.train", **counts):
+        """Context manager for one phase of a job, visible to ANY profiler
+        session without a switch: it always enters
+        ``jax.profiler.TraceAnnotation(name, **counts)``, whose events land on
+        the host plane of the profiler's trace, on the ``XLA Ops`` line's
+        clock, with ``counts`` as their stats. Tracer enabled: an ordinary
+        recorded :class:`Span` (parent from the thread's stack, ``counts`` as
+        its attrs) that holds the annotation open, whatever ``xprof`` says.
+        Tracer disabled: the bare annotation, a no-op outside a profiler
+        session (under a microsecond, but an allocation — unlike ``span``).
+
+        ONLY for sites that run a bounded number of times per job (the phases
+        of a fit, a chunk of steps): never per step, per row, per request or
+        per layout unit — hot sites use :meth:`span`. A site takes its
+        decision (reuse or build) first and opens the phase with it among
+        ``counts`` (ints); what only the phase's own work can count is added
+        before it closes with ``set_metadata(**counts)``, which both returned
+        forms have."""
+        annotation = _annotation(name, counts)
+        if not self.enabled:
+            return annotation
+        span = self._make(name, category, scope, None)
+        if counts:
+            span.attrs = counts
+        span._annotation = annotation
+        return span
 
     def begin(self, name: str, category: str = CAT_PRODUCTIVE, scope: str = "ml", parent: Optional[Span] = None) -> Optional[Span]:
         """Manual span: starts now, is NOT pushed on the thread stack, and
@@ -558,31 +606,14 @@ class Tracer:
         return self.recorder.goodput_report()
 
 
-def _enter_annotation(name: str):  # graftcheck: cold
-    """Open a jax.profiler.TraceAnnotation (spans nest inside XLA profiler
-    dumps when a profile is active). Import is lazy and failures are
-    swallowed — tracing must not require a working jax profiler."""
-    try:
-        from jax.profiler import TraceAnnotation
+def _annotation(name: str, counts: Optional[Dict[str, Any]] = None):  # graftcheck: cold
+    """A ``jax.profiler.TraceAnnotation``, not yet entered: a no-op outside a
+    profiler session, an event on the host plane of the profiler's trace
+    inside one (``counts`` become the event's stats). The import is lazy:
+    this module is imported by tiers that never touch the profiler."""
+    from jax.profiler import TraceAnnotation
 
-        annotation = TraceAnnotation(name)
-        annotation.__enter__()
-        return annotation
-    except Exception:
-        return None
-
-
-#: jax.profiler.TraceAnnotation failures (broken profiler build): counted,
-#: never raised — tracing must not take down the traced workload.
-_annotation_errors = 0
-
-
-def _exit_annotation(annotation) -> None:
-    global _annotation_errors
-    try:
-        annotation.__exit__(None, None, None)
-    except Exception:
-        _annotation_errors += 1
+    return TraceAnnotation(name, **(counts or {}))
 
 
 #: The process tracer. ``observability.trace`` (env:
