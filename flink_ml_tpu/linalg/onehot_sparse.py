@@ -59,7 +59,7 @@ import numpy as np
 
 from flink_ml_tpu.parallel.mesh import vma_of
 from flink_ml_tpu.trace import CAT_INGEST, tracer
-from flink_ml_tpu.utils.arrays import group_ranks, next_pow2
+from flink_ml_tpu.utils.arrays import next_pow2
 
 __all__ = [
     "OneHotSparseLayout", "OneHotSparsePlan", "onehot_batch_step",
@@ -68,6 +68,8 @@ __all__ = [
 ]
 
 BLOCK = 128  # feature-block width: the VPU lane count
+_BLOCK_SHIFT = 7  # idx >> 7 is the block, idx & 127 the lane, in the indices' own dtype
+assert 1 << _BLOCK_SHIFT == BLOCK
 SUB_ROWS = 16384  # sub-batch rows per crossing (gradient accumulation grain)
 _ROW_LO = 128  # row-id split minor width
 
@@ -81,8 +83,23 @@ def validate_indices(indices: np.ndarray, dim: int) -> None:
 def block_counts(indices: np.ndarray, values: np.ndarray, nblk: int) -> np.ndarray:
     """Per-feature-block nonzero-entry counts for one sub-batch unit
     (``[rows, K]`` padded-CSR slices; value 0 = padding)."""
-    blocks = np.asarray(indices, np.int64)[np.asarray(values) != 0.0] // BLOCK
-    return np.bincount(blocks, minlength=nblk)
+    blocks = (np.asarray(indices) >> _BLOCK_SHIFT).ravel()
+    nz = np.asarray(values).ravel() != 0.0
+    if not nz.all():
+        blocks[~nz] = nblk  # counted one past the last block, and dropped
+    return np.bincount(blocks, minlength=nblk + 1)[:nblk]
+
+
+def _stable_order(keys: np.ndarray) -> np.ndarray:
+    """Stable argsort of unsigned keys by numpy's radix sort, which it has
+    for 16-bit keys only (a wider stable sort is a merge sort, ten times
+    slower at a unit's size): 32-bit keys take two 16-bit passes, low half
+    then high half."""
+    if keys.dtype == np.uint16:
+        return np.argsort(keys, kind="stable")
+    by_low = np.argsort(keys.astype(np.uint16), kind="stable")
+    high = (keys >> 16).astype(np.uint16)
+    return by_low.take(np.argsort(high.take(by_low), kind="stable"))
 
 
 class OneHotSparsePlan:
@@ -112,15 +129,42 @@ class OneHotSparsePlan:
     block ids between original and class-major order.
     """
 
-    __slots__ = (
+    _FIELDS = (
         "dim", "nblk", "nblk_local", "n_model", "sub_batch", "n_flat",
         "class_meta", "perm", "inv_perm", "width_of_pos",
         "owner_of_pos", "base_of_pos", "local_block_of_pos",
     )
+    # fill_unit's tables, derived from the fields above: the sort key of a
+    # block, and per key its class width and first shard-local flat slot
+    __slots__ = _FIELDS + (
+        "key_of_block", "width_of_key", "base_of_key", "shard_first_key",
+    )
 
     def __init__(self, **kw):
-        for k in self.__slots__:
+        for k in self._FIELDS:
             setattr(self, k, kw[k])
+        # A block's sort key is its class-major position, shard-major: the
+        # position itself when n_model == 1. Sorted by it, a unit's entries
+        # lie shard after shard, each shard's in the order of its flat slots.
+        by_shard = np.argsort(self.owner_of_pos, kind="stable")  # key -> position
+        key_of_pos = np.empty(self.nblk, np.intp)
+        key_of_pos[by_shard] = np.arange(self.nblk)
+        # the key range holds one value past the positions: ``nblk`` itself,
+        # which fill_unit gives the zero-valued entries
+        self.key_of_block = key_of_pos[self.inv_perm].astype(
+            np.uint16 if self.nblk < 1 << 16 else np.uint32
+        )
+        self.width_of_key = self.width_of_pos[by_shard]
+        self.base_of_key = self.base_of_pos[by_shard].astype(np.intp)
+        self.shard_first_key = np.searchsorted(
+            self.owner_of_pos[by_shard], np.arange(self.n_model + 1)
+        )
+
+    @property
+    def key_bits(self) -> int:
+        """Width of fill_unit's sort keys: the narrower of 16 and 32 bits
+        that holds ``nblk`` (16 up to dim 2^23 - 128)."""
+        return 8 * self.key_of_block.dtype.itemsize
 
     @classmethod
     def from_max_counts(
@@ -191,40 +235,65 @@ class OneHotSparsePlan:
         model shards (int8 lane + int16 rowid + f32 value per flat slot)."""
         return 7 * n_units * self.n_model * self.n_flat
 
-    def fill_unit(self, idx_u, val_u, out_lidx, out_rowid, out_lvals) -> None:
+    def fill_unit(self, idx_u, val_u, out_lidx, out_rowid, out_lvals) -> bool:
         """Transpose one sub-batch unit ([rows <= sub_batch, K] padded-CSR)
         into its per-model-shard class-major stack slices (preallocated,
-        zeroed, shape [n_model, n_flat]). Raises if any block's entry count
-        exceeds its planned class width — a unit outside the plan's counting
-        pass must fail loudly, never corrupt a neighbouring block's slots.
+        zeroed, shape [n_model, n_flat]). Raises, before it writes anything,
+        if any block's entry count exceeds its planned class width — a unit
+        outside the plan's counting pass must fail loudly, never corrupt a
+        neighbouring block's slots. Returns whether the unit held a zero
+        value (padding or an explicit 0.0) and so took the mask.
+
+        A counting placement: the entries' blocks become narrow sort keys
+        (``key_of_block``; ``key_bits`` wide), one ``np.bincount`` of the
+        keys gives every block's count — hence the overflow check on
+        ``nblk`` numbers and each group's start in the sorted order — and a
+        stable radix argsort of the keys gives the order, row-major within
+        a block. An entry's slot is its block's base plus its rank in the
+        group, which is an ``arange`` plus a ``np.repeat`` of per-group
+        offsets; lanes, row ids and values follow the order in their stack
+        dtypes and are written through one flat index per model shard. The
+        mask, taken only where a value is zero, is one more key: zero-valued
+        entries get ``nblk``, sort behind every block and are cut off the
+        order. No int64 copy of the indices, no compression, no second
+        search.
 
         Stacks are packed for transfer/HBM (the streamed path ships them
         every window): ``lidx`` int8 (lane < 128), ``rowid`` int16 (the
         sub-batch-relative row, < SUB_ROWS = 16384); the program unpacks to
         int32 (hi, lo) = (rowid // 128, rowid % 128) on device. 7 B/slot
         vs the unpacked 16 — below even the padded-CSR 8 B/nnz."""
-        idx_u = np.asarray(idx_u, np.int64)
-        val_u = np.asarray(val_u)
-        nz = val_u != 0.0
-        rows_rel = np.repeat(
-            np.arange(idx_u.shape[0], dtype=np.int64), idx_u.shape[1]
-        ).reshape(idx_u.shape)[nz]
-        feats = idx_u[nz]
-        lanes = (feats % BLOCK).astype(np.int8)
-        pos = self.inv_perm[feats // BLOCK].astype(np.int64)
-        o2 = np.argsort(pos, kind="stable")
-        sp = pos[o2]
-        ranks = group_ranks(sp)
-        if sp.size and int(np.max(ranks - self.width_of_pos[sp])) >= 0:
+        idx_u = np.asarray(idx_u)
+        vals = np.asarray(val_u).ravel()
+        keys = self.key_of_block.take((idx_u >> _BLOCK_SHIFT).ravel())
+        nz = vals != 0.0
+        masked = not nz.all()
+        if masked:
+            keys[~nz] = self.nblk  # behind every block in the sorted order
+        counts = np.bincount(keys, minlength=self.nblk + 1)
+        kept = keys.size - counts[self.nblk]
+        counts = counts[: self.nblk]
+        if (counts > self.width_of_key).any():
             raise ValueError(
                 "sub-batch unit exceeds the plan's per-block occupancy — the "
                 "plan was built from a counting pass that did not cover this data"
             )
-        owner = self.owner_of_pos[sp]
-        slot = self.base_of_pos[sp] + ranks
-        out_lidx[owner, slot] = lanes[o2]
-        out_rowid[owner, slot] = rows_rel[o2].astype(np.int16)
-        out_lvals[owner, slot] = val_u[nz][o2]
+        order = _stable_order(keys)[:kept]  # the unit's row-major entry numbers
+        start = np.zeros(self.nblk + 1, np.intp)  # of each group, sorted order
+        np.cumsum(counts, out=start[1:])
+        # slot of sorted entry i of group g: base[g] + (i - start[g])
+        slot = np.repeat(self.base_of_key - start[:-1], counts)
+        slot += np.arange(kept)
+        lanes = (idx_u & (BLOCK - 1)).astype(np.int8).ravel().take(order)
+        rows_rel = (order // idx_u.shape[1]).astype(np.int16)
+        vals = vals.take(order)
+        shard_start = start[self.shard_first_key]
+        for o in range(self.n_model):
+            a, b = shard_start[o], shard_start[o + 1]
+            out_lidx[o][slot[a:b]] = lanes[a:b]
+            out_rowid[o][slot[a:b]] = rows_rel[a:b]
+            out_lvals[o][slot[a:b]] = vals[a:b]
+        return masked
 
     def permute_coef(self, coef: np.ndarray) -> np.ndarray:
         """Original [dim] coefficient -> shard-major class-major padded
@@ -308,7 +377,7 @@ class OneHotSparseLayout:
         # Five phases (docs/observability.md, "The fit span tree"); every
         # statement of the build lies in one of them.
         with tracer.phase("train.layout.prepare", CAT_INGEST):
-            indices = np.asarray(indices, np.int64)
+            indices = np.asarray(indices)
             values = np.asarray(values)
             n = indices.shape[0]
             m = -(-n // n_shards)  # local rows per shard (cache pads to this)
@@ -352,17 +421,21 @@ class OneHotSparseLayout:
             lidx = np.zeros(shape, np.int8)
             rowid = np.zeros(shape, np.int16)
             lvals = np.zeros(shape, np.float32 if values.dtype.kind == "f" else values.dtype)
-        with tracer.phase("train.layout.fill", CAT_INGEST, units=n_units):
+        with tracer.phase(
+            "train.layout.fill", CAT_INGEST, units=n_units, key_bits=plan.key_bits
+        ) as phase:
             unit_iter = iter(bounds)
+            masked = 0  # units that held a zero value and took the mask
             for s in range(n_shards):
                 for wi in range(n_windows):
                     for bi in range(n_sub):
                         r0, r1 = next(unit_iter)
-                        plan.fill_unit(
+                        masked += plan.fill_unit(
                             indices[r0:r1], values[r0:r1],
                             lidx[s, :, wi, bi], rowid[s, :, wi, bi],
                             lvals[s, :, wi, bi],
                         )
+            phase.set_metadata(masked=masked)
 
         return cls(
             plan=plan, dim=int(dim), n_shards=n_shards, n_windows=n_windows,

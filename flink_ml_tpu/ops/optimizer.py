@@ -671,7 +671,7 @@ def streamed_onehot_plan(cache, n_rows, n_data, window, local_batch, dim, n_mode
                 if r1 <= r0:
                     continue
                 got = cache.rows(r0, r1)
-                idx_mb = np.asarray(got["indices"], np.int64)
+                idx_mb = np.asarray(got["indices"])
                 val_mb = np.asarray(got["values"])
                 validate_indices(idx_mb, dim)
                 for s0 in range(0, r1 - r0, sub):

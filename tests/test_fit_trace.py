@@ -129,7 +129,10 @@ class TestSparseFitTree:
         layout = one["train.layout"]
         assert layout["reused"] == 0 and layout["rows"] == N and layout["units"] >= 1
         assert one["train.layout.count"] == {"units": layout["units"]}
-        assert one["train.layout.fill"] == {"units": layout["units"]}
+        # the fill's two choices: 16-bit sort keys (DIM / 128 blocks), no unit masked
+        assert one["train.layout.fill"] == {
+            "units": layout["units"], "key_bits": 16, "masked": 0,
+        }
         stack_bytes = one["train.layout.alloc"]["bytes"]
         assert stack_bytes % (7 * layout["units"]) == 0  # 7 B a slot: int8 + int16 + f32
         assert one["train.layout_put"] == {"bytes": stack_bytes}
@@ -139,15 +142,52 @@ class TestSparseFitTree:
         assert one["train.dispatch"] == {"steps": STEPS} == one["train.drain"]
         assert one["train.readback"]["bytes"] >= DIM * 4
 
-    def test_layout_children_cover_it_and_the_fit_has_little_self_time(self, sparse_fit):
+    def test_layout_children_cover_it_and_the_fit_has_little_self_time(self, sparse_fit, sparse_df):
         _, spans = sparse_fit
         by = _by_name(spans)
         (fit,), (layout,) = by["train.fit"], by["train.layout"]
-        assert _children_s(spans, layout) == pytest.approx(layout.duration, rel=0.10)
+        # the build lasts a few milliseconds here, so one descheduling between
+        # two children is a large share of it: the least gap of a few fits
+        gaps = [1 - _children_s(spans, layout) / layout.duration]
+        while gaps[-1] > 0.10 and len(gaps) < 5:
+            with trace.capture() as recorder:
+                _estimator().fit(sparse_df)
+            again = recorder.snapshot()
+            (lay,) = _by_name(again)["train.layout"]
+            gaps.append(1 - _children_s(again, lay) / lay.duration)
+        assert 0 <= min(gaps) <= 0.10
         assert fit.duration - _children_s(spans, fit) < 0.10 * fit.duration
         for s in spans:
             if s is not fit:
                 assert fit.start <= s.start and s.end <= fit.end
+
+    def test_a_fit_of_padded_rows_masks_every_unit_and_counts_one_build(self):
+        """Rows of unequal length reach the layout as padded CSR (value 0):
+        the fill then takes its mask, says so per unit, and the build is
+        still one, under the same five children."""
+        rng = np.random.default_rng(11)
+        n = 8192  # x K entries: the least the auto gate sends down the one-hot route
+        rows = [
+            np.sort(rng.choice(DIM, size=rng.integers(2, K + 1), replace=False))
+            for _ in range(n)
+        ]
+        df = DataFrame.from_dict({
+            "features": [SparseVector(DIM, r, np.ones(len(r))) for r in rows],
+            "label": (rng.random(n) > 0.5).astype(np.float64),
+        })
+        est = LogisticRegression().set_max_iter(2).set_global_batch_size(2048).set_tol(0.0)
+        builds = metrics.get(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LAYOUT_BUILDS) or 0
+        with trace.capture() as recorder:
+            est.fit(df)
+        spans = recorder.snapshot()
+        by = _by_name(spans)
+        (layout,), (fill,) = by["train.layout"], by["train.layout.fill"]
+        assert fill.attrs == {
+            "units": layout.attrs["units"], "key_bits": 16, "masked": layout.attrs["units"],
+        }
+        children = [s for s in spans if s.parent_id == layout.span_id]
+        assert sorted(s.name for s in children) == sorted(LAYOUT_CHILDREN)
+        assert metrics.get(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LAYOUT_BUILDS) == builds + 1
 
     def test_goodput_report_sums_to_the_fits_wall(self, sparse_fit):
         _, spans = sparse_fit
@@ -266,6 +306,10 @@ class TestPhaseContract:
         assert fit_stats == {"rows": N, "dim": DIM}
         assert events["train.pack"][0][2] == {"rows": N, "sparse": 1, "nnz": N * K}
         assert events["train.layout"][0][2]["reused"] == 0
+        units = events["train.layout"][0][2]["units"]
+        assert events["train.layout.fill"][0][2] == {
+            "units": units, "key_bits": 16, "masked": 0,
+        }
         for name, group in events.items():
             for a, b, _ in group:
                 assert f0 <= a and b <= f1, name

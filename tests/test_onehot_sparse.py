@@ -99,6 +99,128 @@ class TestLayout:
             OneHotSparseLayout.build(idx, val, 10, 1, 4)
 
 
+def _loop_stacks(idx, val, lay):
+    """The stacks by the plainest statement of the layout: per unit, walk the
+    rows, then each row's entries; skip value 0; the entry takes the next
+    free slot of its block, counted from the block's base in its shard."""
+    plan = lay.plan
+    lidx, rowid, lvals = (np.zeros_like(a) for a in (lay.lidx, lay.rowid, lay.lvals))
+    n = idx.shape[0]
+    m = -(-n // lay.n_shards)
+    for s in range(lay.n_shards):
+        for wi, w0 in enumerate(lay.window_starts):
+            for bi in range(lay.n_sub):
+                r0 = s * m + w0 + bi * lay.sub_batch
+                r1 = min(r0 + lay.sub_batch, s * m + min(w0 + lay.local_batch, m), n)
+                used = {}
+                for r in range(r0, r1):
+                    for e in range(idx.shape[1]):
+                        if val[r, e] == 0:
+                            continue
+                        pos = int(plan.inv_perm[int(idx[r, e]) // BLOCK])
+                        rank = used.get(pos, 0)
+                        used[pos] = rank + 1
+                        assert rank < plan.width_of_pos[pos]
+                        at = (s, int(plan.owner_of_pos[pos]), wi, bi,
+                              int(plan.base_of_pos[pos]) + rank)
+                        lidx[at] = int(idx[r, e]) % BLOCK
+                        rowid[at] = r - r0
+                        lvals[at] = val[r, e]
+    return lidx, rowid, lvals
+
+
+def _unit_rows(variant, rng, n, k, dim):
+    """[n, k] padded-CSR rows for one case of the loop comparison."""
+    idx = rng.integers(0, dim, size=(n, k)).astype(np.int32)
+    # a few crowded blocks, so that several occupancy classes exist
+    crowded = rng.random((n, k)) < 0.4
+    idx[crowded] = rng.integers(0, 3 * BLOCK, size=int(crowded.sum()))
+    val = rng.normal(size=(n, k)).astype(np.float32)
+    if variant == "padded":  # rows of unequal length: index 0, value 0 behind the row's end
+        behind = np.arange(k)[None, :] >= rng.integers(1, k + 1, size=n)[:, None]
+        idx[behind], val[behind] = 0, 0.0
+    elif variant == "explicit_zero":  # a stored 0.0 between two kept entries
+        val[::3, k // 2] = 0.0
+    elif variant == "repeated_id":  # one id twice in a row, the two values kept apart
+        idx[:, 1] = idx[:, 0]
+    elif variant == "int_values":
+        val = rng.integers(1, 5, size=(n, k)).astype(np.int32)
+    return idx, val
+
+
+class TestCountingPlacement:
+    """``fill_unit`` against the per-entry loop, element for element: the
+    stacks are what the compiled step and its f32 sums are keyed on."""
+
+    @pytest.mark.parametrize("n_model,n_shards", [(1, 1), (2, 1), (1, 4), (2, 4)])
+    @pytest.mark.parametrize(
+        "variant",
+        ["plain", "padded", "explicit_zero", "repeated_id", "short_last_unit",
+         "wide_dim", "int_values"],
+    )
+    def test_stacks_equal_the_per_entry_loop(self, variant, n_model, n_shards):
+        rng = np.random.default_rng(sum(map(ord, variant)) + 10 * n_model + n_shards)
+        dim = (1 << 23) + 40 * BLOCK if variant == "wide_dim" else 6000
+        # 96 rows a shard, minibatches of 32 in units of 16; the short case
+        # has minibatches of 40 (units of 16, 16 and 8) and ends 5 rows early
+        short = variant == "short_last_unit"
+        n = 96 * n_shards - (5 if short else 0)
+        idx, val = _unit_rows(variant, rng, n, 6, dim)
+        lay = OneHotSparseLayout.build(
+            idx, val, dim, n_shards, 40 if short else 32, sub_rows=16, n_model=n_model
+        )
+        assert lay.plan.key_bits == (32 if variant == "wide_dim" else 16)
+        assert lay.n_windows > 1 and lay.n_sub == (3 if short else 2)
+        want = _loop_stacks(idx, val, lay)
+        for got, ref in zip((lay.lidx, lay.rowid, lay.lvals), want):
+            assert got.dtype == ref.dtype
+            np.testing.assert_array_equal(got, ref)
+
+    @pytest.mark.parametrize(
+        "dim,bits", [(1 << 22, 16), ((1 << 23) - BLOCK, 16), (1 << 23, 32), (1 << 26, 32)]
+    )
+    def test_key_width_follows_the_block_count(self, dim, bits):
+        # 16 bits while the positions AND the zero-valued entries' key, nblk, fit
+        idx = np.array([[0, dim - 1]], np.int64)
+        plan = OneHotSparseLayout.build(idx, np.ones((1, 2), np.float32), dim, 1, 1).plan
+        assert plan.key_bits == bits and plan.key_of_block.max() == plan.nblk - 1
+        assert plan.nblk < 1 << bits
+
+    def test_wide_keys_sort_in_two_stable_passes(self):
+        from flink_ml_tpu.linalg.onehot_sparse import _stable_order
+
+        rng = np.random.default_rng(5)
+        keys = rng.integers(0, 1 << 18, size=5000).astype(np.uint32)
+        keys[::7] = keys[0]  # long runs of one key: their order must stay
+        np.testing.assert_array_equal(
+            _stable_order(keys), np.argsort(keys, kind="stable")
+        )
+        narrow = (keys & 0xFFFF).astype(np.uint16)
+        np.testing.assert_array_equal(
+            _stable_order(narrow), np.argsort(narrow, kind="stable")
+        )
+
+    def test_a_unit_outside_the_plan_raises_and_writes_nothing(self):
+        rng = np.random.default_rng(6)
+        idx = rng.integers(0, 4000, size=(64, 4)).astype(np.int32)
+        val = np.ones((64, 4), np.float32)
+        plan = OneHotSparseLayout.build(idx, val, 4000, 1, 64, n_model=2).plan
+        # a unit the counting pass never saw: every entry in the block of id 0
+        crowd = np.zeros((64, 4), np.int32)
+        assert 64 * 4 > plan.width_of_pos[plan.inv_perm[0]]
+        outs = [
+            np.full((2, plan.n_flat), 7, dt) for dt in (np.int8, np.int16, np.float32)
+        ]
+        with pytest.raises(ValueError, match="per-block occupancy"):
+            plan.fill_unit(crowd, val, *outs)
+        for out in outs:  # the neighbouring blocks' slots, and its own
+            assert (out == 7).all()
+        # the same plan still places a unit it covers
+        assert plan.fill_unit(idx, val, *outs) is False
+        val[3, 2] = 0.0
+        assert plan.fill_unit(idx, val, *outs) is True
+
+
 class TestBatchStep:
     @pytest.mark.parametrize("sub_rows", [64, 100, 512])
     def test_matches_scatter_reference(self, sub_rows):

@@ -227,3 +227,34 @@ def test_streamed_onehot_multislice_matches_streamed_scatter():
         np.testing.assert_allclose(
             coefs["onehot"], coefs["scatter"], rtol=1e-3, atol=1e-5
         )
+
+
+@pytest.mark.parametrize("n_data,n_model", [(1, 1), (2, 1), (2, 2)])
+def test_streamed_window_stacks_equal_the_resident_builds(n_data, n_model):
+    # The same rows through both routes: each shard's 128 rows are four
+    # minibatches of 32, streamed as two windows of two minibatches. The
+    # units are the same, so the global plan is the same, and window j's
+    # stacks are the resident build's windows 2j and 2j + 1.
+    import jax
+
+    from flink_ml_tpu.linalg.onehot_sparse import OneHotSparseLayout
+    from flink_ml_tpu.ops.optimizer import _OneHotWindowStream, streamed_onehot_plan
+
+    n, dim, b, W = 128 * n_data, 3000, 32, 64
+    cols = _sparse_data(n, dim, 6, seed=20 + n_data)
+    cache = _fill(HostDataCache(), cols)
+    resident = OneHotSparseLayout.build(
+        cols["indices"], cols["values"], dim, n_data, b, n_model=n_model
+    )
+    assert resident.window_starts == [0, 32, 64, 96] and resident.n_sub == 1
+    plan = streamed_onehot_plan(cache, n, n_data, W, b, dim, n_model)
+    assert plan.program_key() == resident.plan.program_key()
+    devices = jax.devices()[: n_data * n_model]
+    with mesh_context(MeshContext(devices=devices, n_data=n_data, n_model=n_model)) as ctx:
+        stream = _OneHotWindowStream(cache, ctx, plan, W, b, 1, 128, n)
+        for j in range(2):
+            got = stream.load(j)["stacks"]
+            for have, want in zip(got, (resident.lidx, resident.rowid, resident.lvals)):
+                np.testing.assert_array_equal(
+                    np.asarray(have), want[:, :, 2 * j : 2 * j + 2]
+                )
