@@ -1,28 +1,25 @@
-"""Expert-parallel mixture-of-experts dispatch over the device mesh (no
-reference analogue — completes the dp/tp/sp/ep parallelism vocabulary; see
-docs/distributed.md).
+"""Dropless top-k mixture-of-experts feed-forward (no reference analogue - the
+routed layer of ``models/lm``'s decoder; see docs/distributed.md).
 """
 import numpy as np
 
-from flink_ml_tpu.parallel import moe_ffn_sharded
-from flink_ml_tpu.parallel.mesh import get_mesh_context
+from flink_ml_tpu.parallel import moe_dropless
 
 
 def main():
-    ctx = get_mesh_context()
     rng = np.random.default_rng(0)
-    T, d, h = 64 * ctx.n_data, 16, 32
-    E = 2 * ctx.n_data  # two experts per shard
+    T, d, h, E, k = 512, 16, 32, 8, 2
     x = rng.standard_normal((T, d)).astype(np.float32)
     router = rng.standard_normal((d, E)).astype(np.float32)
-    w1 = (rng.standard_normal((E, d, h)) * 0.2).astype(np.float32)
-    w2 = (rng.standard_normal((E, h, d)) * 0.2).astype(np.float32)
+    w_gate = (rng.standard_normal((E, d, h)) * 0.2).astype(np.float32)
+    w_up = (rng.standard_normal((E, d, h)) * 0.2).astype(np.float32)
+    w_down = (rng.standard_normal((E, h, d)) * 0.2).astype(np.float32)
 
-    out = np.asarray(moe_ffn_sharded(x, router, w1, w2, capacity=T, ctx=ctx))
-    routed = (x @ router).argmax(axis=1)
-    print(f"{T} tokens routed across {E} experts on {ctx.n_data} shards")
-    print("tokens per expert:", np.bincount(routed, minlength=E).tolist())
-    print("output shape:", out.shape, "finite:", bool(np.isfinite(out).all()))
+    out, stats = moe_dropless(x, router, w_gate, w_up, w_down, k)
+    rows = np.asarray(stats["rows"])
+    print(f"{T} tokens x top-{k} routed across {E} experts, none dropped")
+    print("rows per expert:", rows.tolist(), "sum:", int(rows.sum()), "=", T * k)
+    print("output shape:", out.shape, "finite:", bool(np.isfinite(np.asarray(out)).all()))
 
 
 if __name__ == "__main__":

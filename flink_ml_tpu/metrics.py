@@ -53,6 +53,9 @@ class MLMetrics:
     TRAIN_LAYOUT_REUSES = "ml.train.layout.reuses"  # fits answered by a cache's layout memo, counter
     TRAIN_PREMAT_BUILDS = "ml.train.premat.builds"  # premat one-hot materializations on device, counter
     TRAIN_H2D_BYTES = "ml.train.h2d.bytes"  # bytes handed to device_put by caches and layouts, counter
+    # The decoder LM's fit (models/lm/decoder_lm.py), counted where train.drain closes.
+    TRAIN_LM_TOKENS = "ml.train.lm.tokens"  # tokens the fit's steps consumed, counter
+    TRAIN_MOE_ROWS = "ml.train.moe.rows"  # (token, expert) rows the experts ran, counter
 
     # Online-serving runtime (scope = "ml.serving[<server name>]" — see
     # docs/serving.md for the full table).

@@ -27,6 +27,9 @@ STAGE_REGISTRY = {
     "OnlineLogisticRegressionModel": "flink_ml_tpu.models.classification.online_logistic_regression.OnlineLogisticRegressionModel",
     "SelfAttentionClassifier": "flink_ml_tpu.models.classification.attention_classifier.SelfAttentionClassifier",
     "SelfAttentionClassifierModel": "flink_ml_tpu.models.classification.attention_classifier.SelfAttentionClassifierModel",
+    # language models
+    "DecoderLM": "flink_ml_tpu.models.lm.decoder_lm.DecoderLM",
+    "DecoderLMModel": "flink_ml_tpu.models.lm.decoder_lm.DecoderLMModel",
     # clustering
     "KMeans": "flink_ml_tpu.models.clustering.kmeans.KMeans",
     "KMeansModel": "flink_ml_tpu.models.clustering.kmeans.KMeansModel",

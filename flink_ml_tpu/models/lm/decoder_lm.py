@@ -1,0 +1,519 @@
+"""``DecoderLM`` - a decoder-only language-model stage that trains through
+``Estimator.fit``: pre-norm residual blocks of causal self-attention (RMSNorm,
+QK-norm, RoPE; the fused fold of ``parallel/flash.py`` forward and backward)
+and a dropless top-k mixture of SwiGLU experts (``parallel/moe.py``), an
+untied head, next-token cross-entropy plus the router's load-balancing loss.
+The block is OLMoE's (``reference.py`` carries each equation's origin).
+
+No analogue exists in the reference (SURVEY.md 2.9: no deep nets anywhere in
+the tree); the Stage contract is the reference's: ``fit`` returns a ``Model``
+(Estimator.java:31,38), the standard param plumbing, save/load and model-data
+access like every other algorithm here.
+
+One route. ``fit`` puts the token window on the device once, initialises
+every parameter ON the device from the seed (``init_params``: leaf ``i`` of
+``config.param_shapes`` is ``0.02 * normal(fold_in(key(seed), i))``, norm
+weights are ones), and runs the whole step - forward, backward, clip at global
+norm 1.0, AdamW - as ONE jitted program per minibatch with the parameters and
+the optimizer state donated. Minibatches cycle over the window like
+SGD.java:265, except that a tail which does not fill a batch is completed with
+the rows before it: a language-model step has a fixed token batch. Losses,
+gradient norms and expert loads stay on the device until the loop ends.
+
+Precision: ``computeType`` names the matmuls' input type (``bfloat16``: the
+MXU's native path, f32 accumulation); the router, the softmaxes, the norms,
+RoPE, the loss, the master weights and AdamW's state are float32 regardless.
+
+Memory (what lets 626 M parameters and 16,384 tokens a step share one 16 GB
+chip): the experts' backward recomputes their two hidden projections from
+the sorted rows (``parallel/moe.py``), the head's ``[tokens, vocabulary]``
+logits exist one token chunk at a time, forward and backward, and where there
+is more than one block each is rematerialised in the backward
+(``jax.checkpoint``).
+
+The fitted model keeps its parameters on the device; ``save`` and
+``get_model_data`` fetch them (2.5 GB at OLMoE's widths is seconds of
+device->host copy, which a fit that is followed by ``transform`` never needs).
+"""
+from __future__ import annotations
+
+import functools
+from typing import List, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+
+from flink_ml_tpu.api.core import Estimator, Model
+from flink_ml_tpu.api.types import DataTypes
+from flink_ml_tpu.metrics import MLMetrics, metrics
+from flink_ml_tpu.models.lm.config import LMConfig, num_params, param_shapes
+from flink_ml_tpu.params.param import (
+    FloatParam,
+    IntParam,
+    ParamValidators,
+    StringParam,
+    update_existing_params,
+)
+from flink_ml_tpu.params.shared import (
+    HasFeaturesCol,
+    HasGlobalBatchSize,
+    HasLearningRate,
+    HasMaxIter,
+    HasPredictionCol,
+    HasSeed,
+)
+from flink_ml_tpu.parallel.flash import TQ_TILE, flash_available, fused_fold
+from flink_ml_tpu.parallel.mesh import is_tpu_backend
+from flink_ml_tpu.parallel.moe import moe_dropless
+from flink_ml_tpu.trace import CAT_COMPILE, CAT_INGEST, CAT_PRODUCTIVE, CAT_READBACK, tracer
+from flink_ml_tpu.utils import read_write as rw
+
+__all__ = ["DecoderLM", "DecoderLMModel", "init_params"]
+
+#: AdamW as the OLMoE recipe sets it (arXiv:2409.02060), and its clipping.
+ADAM_B1, ADAM_B2, ADAM_EPS, WEIGHT_DECAY, CLIP_NORM = 0.9, 0.95, 1e-8, 0.1, 1.0
+INIT_STD = 0.02
+#: Token rows of the head's logits that exist at one time.
+_LOSS_CHUNK = 2048
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+class _LMParams(
+    HasFeaturesCol,
+    HasPredictionCol,
+    HasMaxIter,
+    HasLearningRate,
+    HasGlobalBatchSize,
+    HasSeed,
+):
+    NUM_LAYERS = IntParam("numLayers", "Decoder blocks.", 2, ParamValidators.gt(0))
+    HIDDEN_SIZE = IntParam("hiddenSize", "Width of the residual stream.", 128, ParamValidators.gt(0))
+    NUM_HEADS = IntParam(
+        "numHeads", "Attention heads; hiddenSize must divide evenly by it.", 4, ParamValidators.gt(0)
+    )
+    NUM_EXPERTS = IntParam("numExperts", "Experts per block.", 8, ParamValidators.gt(0))
+    EXPERTS_PER_TOKEN = IntParam(
+        "expertsPerToken", "Experts each token is routed to (top-k, not renormalised).", 2,
+        ParamValidators.gt(0),
+    )
+    EXPERT_WIDTH = IntParam("expertWidth", "Hidden width of one SwiGLU expert.", 64, ParamValidators.gt(0))
+    VOCAB_SIZE = IntParam(
+        "vocabSize", "Vocabulary size; 0 infers max(token) + 1 from the training data.", 0,
+        ParamValidators.gt_eq(0),
+    )
+    ROPE_THETA = FloatParam("ropeTheta", "Base of the rotary embedding.", 10000.0, ParamValidators.gt(0))
+    NORM_EPS = FloatParam("normEps", "Epsilon of every RMSNorm.", 1e-5, ParamValidators.gt(0))
+    AUX_LOSS_COEF = FloatParam(
+        "auxLossCoef", "Weight of the router's load-balancing loss.", 0.01, ParamValidators.gt_eq(0)
+    )
+    COMPUTE_TYPE = StringParam(
+        "computeType",
+        "Matmul input dtype: 'bfloat16' runs every matmul and the attention "
+        "fold on the MXU's bf16 path with float32 accumulation (router, "
+        "softmaxes, norms, loss, weights and AdamW state stay float32); "
+        "'float32' is exact.",
+        "float32",
+        ParamValidators.in_array(["float32", "bfloat16"]),
+    )
+
+    def lm_config(self, vocab: Optional[int] = None) -> LMConfig:
+        cfg = LMConfig(
+            n_layers=self.get(self.NUM_LAYERS), hidden=self.get(self.HIDDEN_SIZE),
+            n_heads=self.get(self.NUM_HEADS), n_experts=self.get(self.NUM_EXPERTS),
+            top_k=self.get(self.EXPERTS_PER_TOKEN), expert_width=self.get(self.EXPERT_WIDTH),
+            vocab=self.get(self.VOCAB_SIZE) if vocab is None else vocab,
+            rope_theta=self.get(self.ROPE_THETA), norm_eps=self.get(self.NORM_EPS),
+            aux_coef=self.get(self.AUX_LOSS_COEF),
+        )
+        if cfg.hidden % cfg.n_heads:
+            raise ValueError(f"hiddenSize {cfg.hidden} must divide evenly by numHeads {cfg.n_heads}")
+        if cfg.head_dim % 2:
+            raise ValueError(f"the rotary embedding needs an even head size, got {cfg.head_dim}")
+        if cfg.top_k > cfg.n_experts:
+            raise ValueError(f"expertsPerToken {cfg.top_k} > numExperts {cfg.n_experts}")
+        return cfg
+
+    def get_compute_type(self) -> str:
+        return self.get(self.COMPUTE_TYPE)
+
+    def set_compute_type(self, value: str):
+        return self.set(self.COMPUTE_TYPE, value)
+
+
+def _add_accessors(cls, names) -> None:
+    """``get_x``/``set_x`` for each architecture param, as every stage spells them."""
+    for attr, snake in names:
+        param = getattr(cls, attr)
+        setattr(cls, f"get_{snake}", lambda self, p=param: self.get(p))
+        setattr(cls, f"set_{snake}", lambda self, value, p=param: self.set(p, value))
+
+
+_add_accessors(_LMParams, (
+    ("NUM_LAYERS", "num_layers"), ("HIDDEN_SIZE", "hidden_size"), ("NUM_HEADS", "num_heads"),
+    ("NUM_EXPERTS", "num_experts"), ("EXPERTS_PER_TOKEN", "experts_per_token"),
+    ("EXPERT_WIDTH", "expert_width"), ("VOCAB_SIZE", "vocab_size"), ("ROPE_THETA", "rope_theta"),
+    ("NORM_EPS", "norm_eps"), ("AUX_LOSS_COEF", "aux_loss_coef"),
+))
+
+
+# -- parameters ----------------------------------------------------------------
+
+
+def _build_tree(cfg: LMConfig, leaves) -> dict:
+    tree = {"layers": [{} for _ in range(cfg.n_layers)]}
+    for (path, _, _), leaf in zip(param_shapes(cfg), leaves):
+        if path[0] == "layers":
+            tree["layers"][path[1]][path[2]] = leaf
+        else:
+            tree[path[0]] = leaf
+    return tree
+
+
+def _ordered(params: dict, cfg: LMConfig) -> list:
+    """The tree's leaves in ``param_shapes`` order."""
+    out = []
+    for path, _, _ in param_shapes(cfg):
+        node = params
+        for key in path:
+            node = node[key]
+        out.append(node)
+    return out
+
+
+def _flat_names(cfg: LMConfig) -> List[str]:
+    return [".".join(str(p) for p in path) for path, _, _ in param_shapes(cfg)]
+
+
+@functools.cache
+def _init_program(cfg: LMConfig):
+    def init(key):
+        leaves = []
+        for i, (_, shape, is_norm) in enumerate(param_shapes(cfg)):
+            if is_norm:
+                leaves.append(jnp.ones(shape, jnp.float32))
+            else:
+                leaves.append(INIT_STD * jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32))
+        return _build_tree(cfg, leaves)
+
+    return jax.jit(init)
+
+
+def init_params(cfg: LMConfig, seed: int) -> dict:
+    """The parameter tree, made on the device from ``seed``: leaf ``i`` of
+    ``param_shapes(cfg)`` is ``0.02 * normal(fold_in(key(seed), i))`` in
+    float32; norm weights are ones."""
+    return _init_program(cfg)(jax.random.key(seed))
+
+
+# -- the forward pass -------------------------------------------------------------
+
+
+def _rms_norm(x, w, eps):
+    x = x.astype(jnp.float32)
+    return w * (x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps))
+
+
+def _rope_tables(t: int, d: int, theta: float):
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    freqs = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    emb = jnp.concatenate([freqs, freqs], axis=-1)  # [T, D]
+    return jnp.cos(emb), jnp.sin(emb)
+
+
+def _rope(x, cos, sin):
+    """Rotate-half RoPE on ``x [B, H, T, D]``."""
+    half = x.shape[-1] // 2
+    rotated = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+    return x * cos + rotated * sin
+
+
+def _matmul(a, w, cd):
+    return jnp.dot(a.astype(cd), w.astype(cd), preferred_element_type=jnp.float32,
+                   precision=_HIGHEST if cd == jnp.float32 else None)
+
+
+def _attention(x, layer, cfg: LMConfig, cd, interpret: bool):
+    b, t, d = x.shape
+    h, hd = cfg.n_heads, cfg.head_dim
+    a = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+    q = _rms_norm(_matmul(a, layer["wq"], cd), layer["q_norm"], cfg.norm_eps)
+    k = _rms_norm(_matmul(a, layer["wk"], cd), layer["k_norm"], cfg.norm_eps)
+    v = _matmul(a, layer["wv"], cd)
+
+    def heads(z):  # [B, T, d] -> [B, H, T, D], the fold's layout
+        return jnp.transpose(z.reshape(b, t, h, hd), (0, 2, 1, 3))
+
+    cos, sin = _rope_tables(t, hd, cfg.rope_theta)
+    q, k, v = _rope(heads(q), cos, sin), _rope(heads(k), cos, sin), heads(v)
+    # a ring of one: the whole sequence is the resident KV block
+    m0 = jnp.full((b, h, t), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros((b, h, t), jnp.float32)
+    acc0 = jnp.zeros((b, h, t, hd), jnp.float32)
+    _, l, acc = fused_fold(q.astype(cd), k.astype(cd), v.astype(cd), m0, l0, acc0, jnp.int32(0),
+                           jnp.int32(0), True, False, jnp.int32(0), float(hd) ** -0.5, interpret)
+    o = acc / l[..., None]  # causal: every row attends at least to itself, l > 0
+    o = jnp.transpose(o, (0, 2, 1, 3)).reshape(b, t, d)
+    return _matmul(o, layer["wo"], cd)
+
+
+def _block(x, layer, cfg: LMConfig, cd, interpret: bool):
+    b, t, d = x.shape
+    x = x + _attention(x, layer, cfg, cd, interpret)
+    u = _rms_norm(x, layer["ffn_norm"], cfg.norm_eps).reshape(b * t, d)
+    y, stats = moe_dropless(u, layer["router"], layer["w_gate"], layer["w_up"], layer["w_down"],
+                            cfg.top_k, cd)
+    return x + y.reshape(b, t, d), stats
+
+
+def _hidden(params, tok, cfg: LMConfig, cd, interpret: bool):
+    """The final-normed hidden states ``[B, T, d]`` and each block's router
+    statistics."""
+    x = params["embed"][tok]
+    block = functools.partial(_block, cfg=cfg, cd=cd, interpret=interpret)
+    if cfg.n_layers > 1:
+        # a lone block's residuals are wanted as soon as the head's backward
+        # ends: holding them costs nothing at the peak, recomputing them a forward
+        block = jax.checkpoint(block)
+    routed = []
+    for layer in params["layers"]:
+        x, stats = block(x, layer)
+        routed.append(stats)
+    return _rms_norm(x, params["final_norm"], cfg.norm_eps), routed
+
+
+def _next_token_nll(h, lm_head, tok, cd):
+    """``[B, T]`` f32: minus the log-probability of token ``t + 1`` at position
+    ``t`` (0 at the last position, which has no target). The ``[chunk, V]``
+    logits exist one chunk of token rows at a time, here and - recomputed -
+    in the backward."""
+    b, t, d = h.shape
+    n = b * t
+    chunk = _LOSS_CHUNK if n % _LOSS_CHUNK == 0 else t
+    targets = jnp.concatenate([tok[:, 1:], jnp.zeros((b, 1), tok.dtype)], axis=1).reshape(n)
+    w = lm_head.astype(cd)
+
+    @jax.checkpoint
+    def one(args):
+        hc, tc = args
+        logits = jnp.dot(hc.astype(cd), w, preferred_element_type=jnp.float32,
+                         precision=_HIGHEST if cd == jnp.float32 else None)
+        picked = jnp.take_along_axis(logits, tc[:, None], axis=1)[:, 0]
+        return jax.nn.logsumexp(logits, axis=-1) - picked
+
+    nll = jax.lax.map(one, (h.reshape(n // chunk, chunk, d), targets.reshape(n // chunk, chunk)))
+    return nll.reshape(b, t).at[:, -1].set(0.0)
+
+
+def _load_balancing(routed, cfg: LMConfig):
+    """``E * sum_e f_e * P_e`` over all blocks' tokens together (equal token
+    counts per block, so the means over blocks are the means over tokens)."""
+    f = sum(s["f"] for s in routed) / len(routed)
+    p = sum(s["P"] for s in routed) / len(routed)
+    return cfg.n_experts * jnp.sum(f * p)
+
+
+def _loss(params, tok, cfg: LMConfig, cd, interpret: bool):
+    h, routed = _hidden(params, tok, cfg, cd, interpret)
+    nll = _next_token_nll(h, params["lm_head"], tok, cd)
+    ce = jnp.sum(nll) / (tok.shape[0] * (tok.shape[1] - 1))
+    loss = ce + cfg.aux_coef * _load_balancing(routed, cfg)
+    return loss, jnp.stack([s["rows"] for s in routed])
+
+
+def _optimizer(lr: float):
+    return optax.chain(
+        optax.clip_by_global_norm(CLIP_NORM),
+        optax.adamw(lr, b1=ADAM_B1, b2=ADAM_B2, eps=ADAM_EPS, weight_decay=WEIGHT_DECAY),
+    )
+
+
+@functools.cache
+def _train_program(cfg: LMConfig, compute_type: str, lr: float, batch: int, interpret: bool):
+    """``(optimizer, step)``; ``step(params, opt_state, window, lo)`` trains on
+    rows ``lo .. lo + batch`` of the device-resident window and returns the new
+    state, the loss, every parameter's gradient norm before clipping (in
+    ``param_shapes`` order) and the rows each expert of each block took."""
+    cd = jnp.dtype(compute_type)
+    optimizer = _optimizer(lr)
+
+    def step(params, opt_state, window, lo):
+        tok = jax.lax.dynamic_slice_in_dim(window, lo, batch, axis=0)
+        (loss, rows), grads = jax.value_and_grad(_loss, has_aux=True)(params, tok, cfg, cd, interpret)
+        norms = jnp.stack([jnp.sqrt(jnp.sum(g * g)) for g in _ordered(grads, cfg)])
+        updates, opt_state = optimizer.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss, norms, rows
+
+    return optimizer, jax.jit(step, donate_argnums=(0, 1))
+
+
+@functools.cache
+def _log_likelihood_program(cfg: LMConfig, compute_type: str, interpret: bool):
+    cd = jnp.dtype(compute_type)
+
+    def run(params, tok):
+        h, _ = _hidden(params, tok, cfg, cd, interpret)
+        nll = _next_token_nll(h, params["lm_head"], tok, cd)
+        return -jnp.sum(nll, axis=1) / (tok.shape[1] - 1)
+
+    return jax.jit(run)
+
+
+def _fold_mode(t: int, head_dim: int) -> bool:
+    """Whether the fused fold runs interpreted (off the TPU), after checking
+    that it can serve this sequence at all; there is no other attention path."""
+    if t % TQ_TILE:
+        raise ValueError(f"sequence length {t} must be a multiple of {TQ_TILE} (the fused fold's Q tile)")
+    on_tpu = is_tpu_backend(jax.devices())
+    if on_tpu and not flash_available(t, head_dim):
+        raise ValueError(
+            f"the fused attention fold does not admit T={t}, head size {head_dim} "
+            "(parallel/flash.py::flash_available); DecoderLM has no other attention path"
+        )
+    return not on_tpu
+
+
+def _token_matrix(df, col: str) -> np.ndarray:
+    tok = np.asarray(df.vectors(col))
+    if tok.ndim != 2 or tok.shape[1] < 2:
+        raise ValueError("the features column must hold equal-length token-id vectors of length >= 2")
+    if tok.size and tok.min() < 0:
+        raise ValueError("token ids must be non-negative")
+    return tok.astype(np.int32)
+
+
+class DecoderLMModel(Model, _LMParams):
+    """Serving side: per-row mean next-token log-likelihood through the same
+    forward. ``params`` holds device arrays after a fit, host arrays after
+    ``load``/``set_model_data``; either is placed once per call."""
+
+    def __init__(self):
+        super().__init__()
+        self.params: Optional[dict] = None
+
+    def transform(self, *inputs):
+        (df,) = inputs
+        cfg = self.lm_config()
+        tok = _token_matrix(df, self.get_features_col())
+        if tok.size and tok.max() >= cfg.vocab:
+            raise ValueError(f"token ids must be in [0, {cfg.vocab}); got up to {tok.max()}")
+        n, t = tok.shape
+        program = _log_likelihood_program(cfg, self.get_compute_type(), _fold_mode(t, cfg.head_dim))
+        params = jax.tree_util.tree_map(jnp.asarray, self.params)
+        batch = min(self.get_global_batch_size(), n)
+        out = np.empty(n, np.float64)
+        for lo in range(0, n, batch):
+            at = min(lo, n - batch)  # the tail re-reads the rows before it
+            out[at: at + batch] = np.asarray(program(params, jnp.asarray(tok[at: at + batch])))
+        result = df.clone()
+        result.add_column(self.get_prediction_col(), DataTypes.DOUBLE, out)
+        return result
+
+    # --- persistence ---------------------------------------------------------
+    def _host_leaves(self) -> dict:
+        cfg = self.lm_config()
+        leaves = jax.device_get(_ordered(self.params, cfg))
+        return {name: np.asarray(a) for name, a in zip(_flat_names(cfg), leaves)}
+
+    def save(self, path: str) -> None:
+        rw.save_metadata(self, path)
+        rw.save_model_arrays(path, self._host_leaves())
+
+    @classmethod
+    def load(cls, path: str):
+        metadata = rw.load_metadata(path, rw.stage_class_name(cls))
+        model = cls()
+        model.load_param_map_from_json(metadata["paramMap"])
+        arrays = rw.load_model_arrays(path)
+        cfg = model.lm_config()
+        model.params = _build_tree(cfg, [arrays[name] for name in _flat_names(cfg)])
+        return model
+
+    def get_model_data(self):
+        from flink_ml_tpu.api.dataframe import DataFrame
+
+        return [DataFrame(["params"], None, [[self._host_leaves()]])]
+
+    def set_model_data(self, *model_data):
+        arrays = model_data[0].column("params")[0]
+        cfg = self.lm_config()
+        self.params = _build_tree(cfg, [np.asarray(arrays[name]) for name in _flat_names(cfg)])
+        return self
+
+
+class DecoderLM(Estimator, _LMParams):
+    """AdamW training of a decoder-only MoE language model on token-id vectors.
+
+    The features column holds equal-length token-id vectors (length a
+    multiple of 256). ``globalBatchSize`` counts ROWS (sequences): tokens a step =
+    rows x length. After ``fit``, per step: ``loss_history``,
+    ``grad_norm_history`` (global, before clipping), ``param_grad_norm_history``
+    (``[steps, parameters]``, columns named by ``param_names``) and
+    ``expert_rows_history`` (``[steps, layers, experts]`` routed rows)."""
+
+    def fit(self, *inputs) -> DecoderLMModel:
+        (df,) = inputs
+        with tracer.phase("train.fit", CAT_PRODUCTIVE, rows=df.num_rows) as fit_phase:
+            return self._fit(df, fit_phase)
+
+    def _fit(self, df, fit_phase) -> DecoderLMModel:
+        with tracer.phase("train.tokens_put", CAT_INGEST, rows=df.num_rows) as phase:
+            tok = _token_matrix(df, self.get_features_col())
+            n, t = tok.shape
+            vocab = self.get(self.VOCAB_SIZE) or int(tok.max()) + 1
+            if tok.max() >= vocab:
+                raise ValueError(f"token id {tok.max()} >= vocabSize {vocab}")
+            window = jax.device_put(tok)
+            phase.set_metadata(tokens=n * t, bytes=int(tok.nbytes))
+        fit_phase.set_metadata(tokens=n * t)
+        cfg = self.lm_config(vocab)
+        interpret = _fold_mode(t, cfg.head_dim)
+        batch = min(self.get_global_batch_size(), n)
+        steps = self.get_max_iter()
+
+        with tracer.phase("train.init", CAT_COMPILE, params=num_params(cfg)) as phase:
+            params = init_params(cfg, self.get_seed())
+            phase.set_metadata(bytes=4 * num_params(cfg))
+        with tracer.phase("train.program", CAT_COMPILE) as phase:
+            misses = _train_program.cache_info().misses
+            optimizer, step = _train_program(
+                cfg, self.get_compute_type(), float(self.get_learning_rate()), batch, interpret
+            )
+            phase.set_metadata(built=int(_train_program.cache_info().misses > misses))
+            opt_state = optimizer.init(params)
+
+        losses, leaf_norms, loads = [], [], []  # device values, fetched once after the loop
+        with tracer.phase("train.dispatch", CAT_PRODUCTIVE, steps=steps):
+            offset = 0
+            for _ in range(steps):
+                lo = min(offset, n - batch)
+                params, opt_state, loss, norms, rows = step(params, opt_state, window, jnp.int32(lo))
+                losses.append(loss)
+                leaf_norms.append(norms)
+                loads.append(rows)
+                offset = 0 if offset + batch >= n else offset + batch
+        routed = steps * batch * t * cfg.top_k * cfg.n_layers
+        with tracer.phase("train.drain", CAT_PRODUCTIVE, steps=steps) as phase:
+            loads = np.asarray(jax.device_get(jnp.stack(loads)))  # [steps, layers, experts]
+            per_block = batch * t * cfg.top_k
+            phase.set_metadata(
+                tokens=steps * batch * t,
+                expert_rows_max=int(loads.max()),
+                expert_rows_mean=per_block // cfg.n_experts,
+                dropped=int(routed - loads.sum()),
+            )
+        with tracer.phase("train.readback", CAT_READBACK, bytes=4 * steps * (1 + len(param_shapes(cfg)))):
+            self.loss_history = [float(x) for x in jax.device_get(losses)]
+            self.param_grad_norm_history = np.asarray(jax.device_get(jnp.stack(leaf_norms)), np.float64)
+        self.param_names = _flat_names(cfg)
+        self.grad_norm_history = [float(x) for x in np.sqrt((self.param_grad_norm_history ** 2).sum(axis=1))]
+        self.expert_rows_history = loads
+        metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_TOKENS, steps * batch * t)
+        metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_MOE_ROWS, int(loads.sum()))
+
+        model = DecoderLMModel()
+        update_existing_params(model, self)
+        model.set(model.VOCAB_SIZE, vocab)
+        model.params = params
+        return model

@@ -31,7 +31,7 @@ from flink_ml_tpu.parallel.train_sharding import (
 )
 from flink_ml_tpu.parallel.quantile import QuantileSummary
 from flink_ml_tpu.parallel.ring import ring_attention, ring_attention_sharded
-from flink_ml_tpu.parallel.moe import moe_ffn, moe_ffn_sharded
+from flink_ml_tpu.parallel.moe import moe_dropless, route_top_k
 from flink_ml_tpu.parallel.datastream_utils import (
     aggregate,
     co_group,
@@ -46,8 +46,8 @@ from flink_ml_tpu.parallel.datastream_utils import (
 )
 
 __all__ = [
-    "moe_ffn",
-    "moe_ffn_sharded",
+    "moe_dropless",
+    "route_top_k",
     "ring_attention",
     "ring_attention_sharded",
     "DATA_AXIS",

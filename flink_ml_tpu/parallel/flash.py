@@ -69,25 +69,38 @@ def flash_available(T: int, D: int, devices=None) -> bool:
     return is_tpu_backend(devices if devices is not None else jax.devices())
 
 
-# Per-kernel-output VMEM envelope for the TRAINING (fwd+bwd) graph. Measured
-# on a v5e chip: when the backward pallas_call's [B*H, T, D]-shaped outputs
-# total near the 16 MB scoped-VMEM limit, XLA's latency optimizer places
-# them in VMEM (S(1)) and the compile fails with a scoped-vmem OOM —
-# observed failing at B*H*T*(D+2)*4 = 16.8-17.2 MB (B=1, T=8192, H=4,
-# D=128) and succeeding at 8.4 MB (B=1, T=4096); forward-only graphs place
-# the same outputs in HBM and compile fine up to flash_available's bounds.
-# 9 MB admits every shape verified good and rejects the untested band up to
-# the observed failures.
+# Scoped VMEM the three kernels may use. A grid cell stages one head's whole
+# resident K and V (double-buffered) beside its [tile, Tk] f32 score buffers:
+# at T=4096, D=128 that is 16.5 MB in the forward and 20.4 MB in the dkv
+# kernel, past Mosaic's default scoped limit of 16 MB whatever batch*heads is
+# (the compile fails "allocating on stack" for the kernel's custom call). A
+# v5e core has 128 MiB of VMEM; 96 MiB covers every shape ``flash_available``
+# admits and leaves XLA its own share.
+_VMEM_LIMIT_BYTES = 96 << 20
+
+
+def _compiler_params():
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT_BYTES)
+
+
+# The attention classifier's envelope for a TRAINING (fwd+bwd) graph, as
+# measured on a v5e chip before the kernels stated a VMEM limit: compile
+# failures at B*H*T*(D+2)*4 = 16.8-17.2 MB (B=1, T=8192, H=4, D=128), success
+# at 8.4 MB (B=1, T=4096). That note blamed XLA for placing the backward's
+# [B*H, T, D] outputs in VMEM; compiled for the chip at 4 x 16 x 4096 x 128
+# the failure is the kernels' own scoped allocation above, which depends on
+# (T, D) alone, and ``_VMEM_LIMIT_BYTES`` cures it (models/lm trains there on
+# the fused fold and does not ask this gate). The classifier keeps its tested
+# envelope until its larger shapes (T=8192) are run on a chip: ROADMAP S5.
 _TRAIN_OUT_VMEM_BUDGET = 9 << 20
 
 
 def flash_train_available(T: int, D: int, batch: int, n_heads: int, devices=None) -> bool:
-    """Whether the fused fold may serve a TRAINING step (fwd + the fused
-    backward). Stricter than ``flash_available``: the backward graph's
-    [batch*heads, T, D] pallas outputs must stay under
-    ``_TRAIN_OUT_VMEM_BUDGET`` or XLA's VMEM output placement blows the
-    scoped limit (see note above). Past the budget the jnp fold trains the
-    same numbers through HBM — slower, never a compile failure."""
+    """Whether the attention classifier's TRAINING step (fwd + the fused
+    backward) takes the fused fold: ``flash_available`` and the envelope
+    above. Past it the jnp fold trains the same numbers through HBM."""
     if not flash_available(T, D, devices):
         return False
     return batch * n_heads * T * (D + 2) * 4 <= _TRAIN_OUT_VMEM_BUDGET
@@ -166,8 +179,11 @@ def _fold_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
         correction = jnp.where(jnp.isneginf(mcol), 0.0, jnp.exp(mcol - safe_m))
         mo_ref[0] = new_m
         lo_ref[0] = l_ref[0] * correction + jnp.sum(p, axis=1, keepdims=True)
+        # bfloat16 q/k/v (models/lm) take the MXU's bf16 path in every dot:
+        # the f32 operand is rounded to the staged operand's type, a no-op
+        # for the f32 callers
         ao_ref[0] = acc_ref[0] * correction + jnp.dot(
-            p, v_ref[0], preferred_element_type=jnp.float32
+            p.astype(v_ref.dtype), v_ref[0], preferred_element_type=jnp.float32
         )
 
     scalars = jnp.stack(
@@ -201,6 +217,7 @@ def _fold_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
             jax.ShapeDtypeStruct((BH, Tq, D), jnp.float32, vma=vma),
         ],
         interpret=interpret,
+        compiler_params=_compiler_params(),
         name="flash_fold_fwd",
     )(
         scalars,
@@ -247,7 +264,8 @@ def _fused_fold_bwd(causal, has_n_valid, scale, interpret, res, g):
         interpret=interpret,
     )
     # integer position/count args carry no cotangent
-    return dq, dkb, dvb, dm_in, dl_in, dacc_in, None, None, None
+    return (dq.astype(q.dtype), dkb.astype(kb.dtype), dvb.astype(vb.dtype),
+            dm_in, dl_in, dacc_in, None, None, None)
 
 
 fused_fold.defvjp(_fused_fold_fwd, _fused_fold_bwd)
@@ -362,7 +380,7 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
 
         dlc = dl_ref[0]  # [TQ, 1]
         dP = dlc + jax.lax.dot_general(
-            dacc_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
+            dacc_ref[0].astype(v_ref.dtype), v_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # [TQ, Tk]
         dPP = dP * P
@@ -378,7 +396,7 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
         dbc = dB / cnt
         ds = dPP + is_max.astype(jnp.float32) * dbc
         dqo_ref[0] = jax.lax.dot_general(
-            ds, k_ref[0], (((1,), (0,)), ((), ())),
+            ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
         dmo_ref[0] = jnp.where(jnp.isneginf(mcol), 0.0, dcorr * corr) + dnew_m * take_m
@@ -413,18 +431,19 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
             s_col = jnp.where(keep, s_col, -jnp.inf)
         P_col = jnp.exp(s_col - safe_ref[0])
         P_col = jnp.where(jnp.isneginf(s_col), 0.0, P_col)
+        dacc_t = dacc_ref[0].astype(v_ref.dtype)
         dP_col = dl_ref[0] + jax.lax.dot_general(
-            dacc_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
+            dacc_t, v_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         is_max = (s_col == b_ref[0]) & ~jnp.isneginf(s_col)
         ds_col = dP_col * P_col + is_max.astype(jnp.float32) * dbc_ref[0]
         dk_part = jax.lax.dot_general(
-            ds_col, q_ref[0], (((0,), (0,)), ((), ())),
+            ds_col.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
         dv_part = jax.lax.dot_general(
-            P_col, dacc_ref[0], (((0,), (0,)), ((), ())),
+            P_col.astype(v_ref.dtype), dacc_t, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
@@ -482,6 +501,7 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
             sds((BH, Tq, 1)),
         ],
         interpret=interpret,
+        compiler_params=_compiler_params(),
         name="flash_fold_bwd_dq",
     )(
         scalars, q4, k4, v4,
@@ -508,6 +528,7 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
         ),
         out_shape=[sds((BH, Tk, D)), sds((BH, Tk, D))],
         interpret=interpret,
+        compiler_params=_compiler_params(),
         name="flash_fold_bwd_dkv",
     )(scalars, k4, v4, q4, dacc4, dl4, safe_r, b_r, dbc_r)
 

@@ -1,24 +1,34 @@
-"""Expert-parallel mixture-of-experts dispatch — the ``ep`` axis primitive.
+"""Dropless top-k mixture-of-experts feed-forward — the routed layer of a
+decoder LM (``models/lm``).
 
 No analogue exists in the reference (its models are single coefficient
-vectors); this completes the framework's parallelism vocabulary alongside
-data (dp), model/tensor (tp), and sequence (sp, ``parallel/ring.py``)
-sharding. The design is the standard switch-routing schedule:
+vectors). One routing path, and no token is ever dropped:
 
-- experts shard over the mesh axis (each shard owns ``E / n_shards``
-  expert FFNs), tokens shard over the same axis;
-- each shard routes its tokens top-1 (router logits → expert, gate prob),
-  packs them into fixed-capacity per-expert slots (static shapes — tokens
-  past an expert's capacity are dropped, the Switch-Transformer overflow
-  rule, and their output contribution is zero);
-- ONE ``all_to_all`` carries every slot to the shard owning its expert,
-  the owner runs its experts' FFNs as one batched matmul pair, and the
-  reverse ``all_to_all`` returns outputs to the token's home shard, where
-  they combine scaled by the gate probability.
+- the router runs in float32 whatever the compute type: logits ``x @ router``,
+  a softmax over ALL experts, the ``k`` largest probabilities chosen and used
+  as they are (not renormalised - OLMoE's ``norm_topk_prob`` false);
+- the ``tokens x k`` (token, expert) rows are sorted by expert (a stable
+  argsort of the chosen expert ids), so each expert's rows are one contiguous
+  group whose size is whatever the router made it - there is no capacity;
+- the experts run as three grouped matmuls over those ragged groups
+  (``jax.lax.ragged_dot``: SwiGLU, ``down(silu(gate(x)) * up(x))``), which
+  XLA lowers for the TPU to its own grouped-matmul kernel (``ragged-dot`` on
+  the device trace); the hand-written VJP recomputes the two hidden
+  projections and runs its own grouped matmuls (rows against ``W^T``, and the
+  ragged-contraction ``dW`` in f32), so neither direction holds a ``[tokens,
+  experts, ...]`` tensor;
+- rows return to token order by the inverse permutation and are summed over
+  the ``k`` slots weighted by the router's probabilities. Both permutations
+  are gathers in the forward AND in the backward (a custom VJP swaps the
+  permutation for its inverse instead of transposing a gather to a scatter).
 
-Per-step traffic is two all-to-alls of the capacity buffers — the exact
-collective the task's "all-to-all" parallelism calls for — and every shape
-is static, so the whole thing jits into one SPMD program.
+The router's statistics come back with the output, for the load-balancing
+loss: ``f`` (per expert, the (token, slot) choices that fell on it over the
+number of tokens - it sums to ``k``), ``P`` (the mean router probability) and
+``rows`` (the group sizes; their sum is ``tokens x k``, always).
+
+Expert parallelism over chips (the all-to-all exchange of the rows) is not
+here: it returns with the four-chip cell it can be measured in (ROADMAP R3).
 """
 from __future__ import annotations
 
@@ -26,99 +36,137 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.sharding import PartitionSpec as P
 
-from flink_ml_tpu.parallel.mesh import DATA_AXIS, MeshContext, get_mesh_context
+__all__ = ["moe_dropless", "route_top_k"]
 
-__all__ = ["moe_ffn", "moe_ffn_sharded"]
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def moe_ffn(x, router, w1, w2, axis_name: str, capacity: int):
-    """Top-1 expert-parallel FFN inside a ``shard_map``.
+def route_top_k(x, router, k: int):
+    """Float32 routing of ``x [t, d]`` through ``router [d, E]``: the softmax
+    over all experts ``p [t, E]``, and the ``k`` largest of each row as
+    ``(top_p, top_e) [t, k]`` (ties go to the lower expert id)."""
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32), precision=_HIGHEST)
+    p = jax.nn.softmax(logits, axis=-1)
+    top_p, top_e = jax.lax.top_k(p, k)
+    return p, top_p, top_e
 
-    ``x [t, d]`` — this shard's tokens; ``router [d, E]`` replicated;
-    ``w1 [e_local, d, h]`` / ``w2 [e_local, h, d]`` — this shard's experts
-    (``E = e_local · n_shards``; expert ``e`` lives on shard ``e // e_local``).
-    ``capacity`` — max tokens any (shard → expert) pair may send per step.
-    Returns ``[t, d]`` with dropped-overflow tokens contributing zero.
+
+@jax.custom_vjp
+def _take_rows(a, index, inverse):
+    """``a[index]`` for a permutation ``index`` of the rows whose inverse is
+    ``inverse``: the cotangent is ``g[inverse]``, a gather too."""
+    del inverse
+    return a[index]
+
+
+def _take_rows_fwd(a, index, inverse):
+    return a[index], (index, inverse)
+
+
+def _take_rows_bwd(res, g):
+    index, inverse = res
+    return g[inverse], None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+_RDN = jax.lax.RaggedDotDimensionNumbers
+#: ``rows [r, a] x w [E, a, b] -> [r, b]`` (the forward's form; rows against
+#: ``W^T`` use it on ``swapaxes(W, 1, 2)``, which XLA folds into the bf16 cast -
+#: contracting ``W``'s last axis instead is NOT lowered to the TPU's grouped
+#: kernel but to a masked convolution, 24 ms where the kernel takes 4: chip
+#: run, PR 26), and ``x [r, a], g [r, b] -> [E, a, b]`` (``dW``: the ragged
+#: rows contracted).
+_ROWS = _RDN((([1], [1]), ([], [])), [0], [0])
+_DW = _RDN((([0], [0]), ([], [])), [0], [])
+
+
+def _grouped(a, w, group_sizes, dims, out_dtype, precision):
+    return jax.lax.ragged_dot_general(a, w, group_sizes, dims, precision=precision,
+                                      preferred_element_type=out_dtype)
+
+
+def _swiglu_hidden(xs, wg, wu, group_sizes, cd, precision):
+    gate = _grouped(xs, wg, group_sizes, _ROWS, cd, precision).astype(jnp.float32)
+    up = _grouped(xs, wu, group_sizes, _ROWS, cd, precision).astype(jnp.float32)
+    return gate, up
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _expert_swiglu(xs, w_gate, w_up, w_down, group_sizes, compute_dtype):
+    """``down(silu(gate(xs)) * up(xs))`` over rows ``xs [r, d]`` grouped by
+    expert, in and out in the compute type (the grouped matmuls accumulate in
+    f32). The weights come in as the f32 masters: the VJP recomputes ``gate``
+    and ``up`` from the sorted rows instead of holding three ``[r, width]``
+    tensors, and hands back each ``dW`` in f32 straight from the grouped
+    matmul that made it (AD through ``w.astype(bf16)`` would round it to
+    bfloat16 on the way)."""
+    cd = jnp.dtype(compute_dtype)
+    precision = _HIGHEST if cd == jnp.float32 else None
+    gate, up = _swiglu_hidden(xs, w_gate.astype(cd), w_up.astype(cd), group_sizes, cd, precision)
+    hidden = (jax.nn.silu(gate) * up).astype(cd)
+    return _grouped(hidden, w_down.astype(cd), group_sizes, _ROWS, cd, precision)
+
+
+def _expert_swiglu_fwd(xs, w_gate, w_up, w_down, group_sizes, compute_dtype):
+    out = _expert_swiglu(xs, w_gate, w_up, w_down, group_sizes, compute_dtype)
+    return out, (xs, w_gate, w_up, w_down, group_sizes)
+
+
+def _expert_swiglu_bwd(compute_dtype, res, dy):
+    xs, w_gate, w_up, w_down, group_sizes = res
+    cd = jnp.dtype(compute_dtype)
+    precision = _HIGHEST if cd == jnp.float32 else None
+    f32 = jnp.float32
+    wg, wu, wd = w_gate.astype(cd), w_up.astype(cd), w_down.astype(cd)
+    transposed = lambda w: jnp.swapaxes(w, 1, 2)  # noqa: E731
+    gate, up = _swiglu_hidden(xs, wg, wu, group_sizes, cd, precision)
+    sig = jax.nn.sigmoid(gate)
+    act = gate * sig  # silu(gate)
+    d_hidden = _grouped(dy, transposed(wd), group_sizes, _ROWS, f32, precision)
+    d_w_down = _grouped((act * up).astype(cd), dy, group_sizes, _DW, f32, precision)
+    d_up = (d_hidden * act).astype(cd)
+    d_gate = (d_hidden * up * (sig + act * (1.0 - sig))).astype(cd)
+    d_w_gate = _grouped(xs, d_gate, group_sizes, _DW, f32, precision)
+    d_w_up = _grouped(xs, d_up, group_sizes, _DW, f32, precision)
+    d_xs = (_grouped(d_gate, transposed(wg), group_sizes, _ROWS, f32, precision)
+            + _grouped(d_up, transposed(wu), group_sizes, _ROWS, f32, precision)).astype(xs.dtype)
+    return d_xs, d_w_gate, d_w_up, d_w_down, None
+
+
+_expert_swiglu.defvjp(_expert_swiglu_fwd, _expert_swiglu_bwd)
+
+
+def moe_dropless(x, router, w_gate, w_up, w_down, k: int, compute_dtype=jnp.float32):
+    """Top-``k`` SwiGLU experts for tokens ``x [t, d]``.
+
+    ``router [d, E]``; ``w_gate``/``w_up`` ``[E, d, h]``; ``w_down [E, h, d]``.
+    ``compute_dtype`` is the grouped matmuls' input type (accumulation is
+    f32); the router is f32 regardless. Returns ``(y [t, d] f32, stats)`` with
+    ``stats = {"f": [E], "P": [E], "rows": [E] int32}`` as the module
+    docstring defines them.
     """
-    n = jax.lax.psum(1, axis_name)
-    t, d = x.shape
-    e_local = w1.shape[0]
-    E = e_local * n
+    t, _ = x.shape
+    n_experts = router.shape[1]
+    p, top_p, top_e = route_top_k(x, router, k)
 
-    logits = x @ router  # [t, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    expert = jnp.argmax(probs, axis=-1)  # [t] top-1
-    gate = jnp.take_along_axis(probs, expert[:, None], axis=1)[:, 0]  # [t]
+    # row r of the flat (token, slot) list belongs to token r // k
+    flat_e = top_e.reshape(-1)
+    order = jnp.argsort(flat_e, stable=True)  # sorted position -> flat row
+    inverse = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0], dtype=order.dtype))
+    rows = jnp.zeros((n_experts,), jnp.int32).at[flat_e].add(1)
 
-    # Position of each token within its expert's send queue (stable order);
-    # tokens at position >= capacity overflow and are dropped.
-    one_hot = jax.nn.one_hot(expert, E, dtype=jnp.int32)  # [t, E]
-    pos = jnp.cumsum(one_hot, axis=0) - 1  # position among same-expert tokens
-    slot = jnp.sum(pos * one_hot, axis=1)  # [t]
-    keep = slot < capacity
+    x_rows = jnp.repeat(x.astype(compute_dtype), k, axis=0)  # [t*k, d], token-major
+    ys = _expert_swiglu(_take_rows(x_rows, order, inverse), w_gate, w_up, w_down,
+                        rows, jnp.dtype(compute_dtype).name)
+    y_rows = _take_rows(ys, inverse, order).reshape(t, k, -1)
+    y = jnp.sum(y_rows.astype(jnp.float32) * top_p[:, :, None], axis=1)
 
-    # Pack: buffers [E, capacity, d] (+ a validity mask), then reshape the
-    # leading axis to [n, e_local·capacity] rows for the all_to_all.
-    # Overflowing tokens write to the out-of-range slot ``capacity`` so
-    # mode="drop" discards them — routing them to slot 0 would race with the
-    # legitimate occupant of slot 0.
-    safe_slot = jnp.where(keep, slot, capacity)
-    buf = jnp.zeros((E, capacity, d), x.dtype)
-    buf = buf.at[expert, safe_slot].set(x, mode="drop")
-
-    # all_to_all: split the expert axis across shards; shard s receives, from
-    # every peer, the slots destined for ITS experts.
-    recv = jax.lax.all_to_all(
-        buf.reshape(n, e_local, capacity, d), axis_name, split_axis=0, concat_axis=0
-    )  # [n (source shard), e_local, capacity, d]
-    recv_tokens = recv.transpose(1, 0, 2, 3).reshape(e_local, n * capacity, d)
-
-    # Each local expert processes all its received slots as one matmul pair.
-    h = jax.nn.relu(jnp.einsum("ecd,edh->ech", recv_tokens, w1))
-    out_tokens = jnp.einsum("ech,ehd->ecd", h, w2)  # [e_local, n·capacity, d]
-
-    # Reverse all_to_all: route outputs back to each token's home shard.
-    back = out_tokens.reshape(e_local, n, capacity, d).transpose(1, 0, 2, 3)
-    returned = jax.lax.all_to_all(back, axis_name, split_axis=0, concat_axis=0)
-    returned = returned.reshape(E, capacity, d)  # [E, capacity, d], home slots
-
-    # Unpack: each kept token reads its slot and scales by its gate; slot
-    # occupancy is shard-local, so ``keep`` alone decides who was served.
-    gathered = returned[expert, jnp.where(keep, slot, 0)]  # [t, d]
-    return jnp.where(keep[:, None], gathered * gate[:, None], 0.0)
-
-
-@functools.cache
-def _sharded_program(mesh, capacity: int):
-    def per_shard(x, router, w1, w2):
-        return moe_ffn(x, router, w1, w2, DATA_AXIS, capacity)
-
-    tok = P(DATA_AXIS)
-    exp = P(DATA_AXIS)
-    return jax.jit(
-        jax.shard_map(
-            per_shard,
-            mesh=mesh,
-            in_specs=(tok, P(), exp, exp),
-            out_specs=tok,
-        )
-    )
-
-
-def moe_ffn_sharded(x, router, w1, w2, capacity: int, ctx: MeshContext = None):
-    """Expert-parallel FFN over the mesh: ``x [T, d]`` sharded over tokens,
-    ``w1 [E, d, h]`` / ``w2 [E, h, d]`` sharded over experts (both on the data
-    axis; ``T`` and ``E`` must divide by its size), ``router [d, E]``
-    replicated. ``capacity`` bounds tokens per (shard, expert) pair per step.
-    """
-    ctx = ctx or get_mesh_context()
-    T, E = np.shape(x)[0], np.shape(w1)[0]
-    if T % ctx.n_data or E % ctx.n_data:
-        raise ValueError(
-            f"tokens ({T}) and experts ({E}) must divide by the mesh axis "
-            f"({ctx.n_data})"
-        )
-    return _sharded_program(ctx.mesh, capacity)(x, router, w1, w2)
+    stats = {
+        "f": rows.astype(jnp.float32) / t,
+        "P": jnp.mean(p, axis=0),
+        "rows": rows,
+    }
+    return y, stats

@@ -1,0 +1,85 @@
+"""The span tree of one ``DecoderLM.fit`` (scope ``ml.train``) at toy size on
+the CPU: each phase once, under ``train.fit``, with the counts
+docs/observability.md ("The LM fit") promises, and the two registry counters
+at the same site."""
+import numpy as np
+import pytest
+
+from flink_ml_tpu import trace
+from flink_ml_tpu.api.dataframe import DataFrame
+from flink_ml_tpu.metrics import MLMetrics, metrics
+from flink_ml_tpu.models.lm import DecoderLM
+from flink_ml_tpu.models.lm.config import num_params, param_shapes
+from flink_ml_tpu.trace import CAT_COMPILE, CAT_INGEST, CAT_PRODUCTIVE, CAT_READBACK, tracer
+
+N, T, BATCH, STEPS, K, EXPERTS, LAYERS = 4, 256, 2, 3, 2, 4, 2
+TREE = [
+    ("train.fit", None, CAT_PRODUCTIVE),
+    ("train.tokens_put", "train.fit", CAT_INGEST),
+    ("train.init", "train.fit", CAT_COMPILE),
+    ("train.program", "train.fit", CAT_COMPILE),
+    ("train.dispatch", "train.fit", CAT_PRODUCTIVE),
+    ("train.drain", "train.fit", CAT_PRODUCTIVE),
+    ("train.readback", "train.fit", CAT_READBACK),
+]
+
+
+@pytest.fixture(autouse=True)
+def _tracer_off():
+    tracer.disable()
+    yield
+    tracer.disable()
+
+
+def _estimator():
+    return (
+        DecoderLM().set_num_layers(LAYERS).set_hidden_size(32).set_num_heads(2)
+        .set_num_experts(EXPERTS).set_experts_per_token(K).set_expert_width(16).set_vocab_size(64)
+        .set_max_iter(STEPS).set_global_batch_size(BATCH).set_seed(1)
+    )
+
+
+@pytest.fixture(scope="module")
+def traced_fit():
+    df = DataFrame.from_dict({"features": np.random.default_rng(0).integers(0, 64, (N, T))})
+    _estimator().fit(df)  # so that nothing compiles in the traced one
+    est = _estimator()
+    tokens0 = metrics.get(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_TOKENS) or 0
+    rows0 = metrics.get(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_MOE_ROWS) or 0
+    with trace.capture() as recorder:
+        est.fit(df)
+    counted = (
+        metrics.get(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_TOKENS) - tokens0,
+        metrics.get(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_MOE_ROWS) - rows0,
+    )
+    return est, sorted(recorder.snapshot(), key=lambda s: s.span_id), counted
+
+
+def test_each_phase_once_under_train_fit(traced_fit):
+    _, spans, _ = traced_fit
+    names = {s.span_id: s.name for s in spans}
+    assert [(s.name, names.get(s.parent_id), s.category) for s in spans] == TREE
+    assert {s.scope for s in spans} == {"ml.train"}
+
+
+def test_counts_are_what_the_job_says(traced_fit):
+    est, spans, _ = traced_fit
+    one = {s.name: s.attrs for s in spans}
+    cfg = est.lm_config()
+    assert one["train.fit"] == {"rows": N, "tokens": N * T}
+    assert one["train.tokens_put"] == {"rows": N, "tokens": N * T, "bytes": N * T * 4}
+    assert one["train.init"] == {"params": num_params(cfg), "bytes": 4 * num_params(cfg)}
+    assert one["train.program"] == {"built": 0}
+    assert one["train.dispatch"] == {"steps": STEPS}
+    drain = one["train.drain"]
+    assert drain["steps"] == STEPS and drain["tokens"] == STEPS * BATCH * T
+    assert drain["expert_rows_mean"] == BATCH * T * K // EXPERTS
+    assert drain["expert_rows_max"] == int(est.expert_rows_history.max()) >= drain["expert_rows_mean"]
+    assert drain["dropped"] == 0
+    assert one["train.readback"] == {"bytes": 4 * STEPS * (1 + len(param_shapes(cfg)))}
+
+
+def test_registry_counters_count_at_the_same_site(traced_fit):
+    _, _, (tokens, rows) = traced_fit
+    assert tokens == STEPS * BATCH * T
+    assert rows == STEPS * BATCH * T * K * LAYERS

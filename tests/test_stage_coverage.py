@@ -95,6 +95,11 @@ ESTIMATOR_CASES = {
         lambda: DataFrame.from_dict({"c": np.asarray([0.0, 1.0, 2.0, 1.0])}),
     ),
     "RobustScaler": (lambda c: c(), _vec_df),
+    "DecoderLM": (
+        lambda c: c().set_num_layers(1).set_hidden_size(32).set_num_heads(2).set_num_experts(4)
+        .set_expert_width(16).set_max_iter(2).set_global_batch_size(2),
+        lambda: DataFrame.from_dict({"features": RNG.integers(0, 32, (4, 256))}),
+    ),
     "SelfAttentionClassifier": (
         lambda c: c().set_max_iter(2).set_embedding_dim(8).set_num_heads(2).set_seed(1),
         lambda: DataFrame.from_dict(

@@ -27,6 +27,7 @@ FAMILY_TITLES = {
     "evaluation": "Evaluation",
     "feature_stateless": "Feature engineering — stateless transforms",
     "feature_fitted": "Feature engineering — fitted estimators",
+    "lm": "Language models",
     "online": "Online (streaming) algorithms",
     "recommendation": "Recommendation",
     "regression": "Regression",
