@@ -258,10 +258,12 @@ class DataFrame:  # graftcheck: serialized
         from flink_ml_tpu.linalg.sparse_batch import SparseBatch
 
         col = self.column(name)
-        if not (isinstance(col, list) and col and all(isinstance(v, Vector) for v in col)):
+        kinds = set(map(type, col)) if isinstance(col, list) else ()
+        if not kinds or not all(issubclass(t, Vector) for t in kinds):
             raise TypeError(f"column {name!r} is not a vector column")
-        vecs = [v if isinstance(v, SparseVector) else v.to_sparse() for v in col]
-        return SparseBatch.from_vectors(vecs)
+        if not all(issubclass(t, SparseVector) for t in kinds):
+            col = [v if isinstance(v, SparseVector) else v.to_sparse() for v in col]
+        return SparseBatch.from_vectors(col)
 
     def scalars(self, name: str, dtype=np.float64) -> np.ndarray:
         col = self.column(name)

@@ -18,13 +18,13 @@ tens of nnz per row) is ``n*K`` floats here vs ``n*dim`` densified.
 """
 from __future__ import annotations
 
-from typing import List, Optional, Sequence
+from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
 from flink_ml_tpu.linalg.vectors import SparseVector, Vector
 
-__all__ = ["SparseBatch", "ladder_cap"]
+__all__ = ["SparseBatch", "ladder_cap", "place_rows"]
 
 _LANE = 8  # pad K to a multiple of this (TPU sublane-friendly)
 
@@ -39,6 +39,25 @@ def ladder_cap(max_nnz: int) -> int:
     while cap < max(1, int(max_nnz)):
         cap *= 2
     return cap
+
+
+def place_rows(
+    flat_indices: np.ndarray, flat_values: np.ndarray, nnz: np.ndarray, K: int
+) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Rows laid end to end (``nnz[i]`` entries each, int32 / float32) into
+    the zero-padded ``[n, K]`` pair, and the stored counts. A row longer than
+    ``K`` keeps its leading ``K`` entries."""
+    if nnz.size and nnz.max() > K:
+        starts = np.cumsum(nnz) - nnz
+        keep = np.arange(flat_indices.size) - np.repeat(starts, nnz) < K
+        flat_indices, flat_values = flat_indices[keep], flat_values[keep]
+        nnz = np.minimum(nnz, np.int32(K))
+    stored = np.arange(K, dtype=np.int32) < nnz[:, None]  # row-major, as the flats are
+    indices = np.zeros((nnz.size, K), np.int32)
+    values = np.zeros((nnz.size, K), np.float32)
+    indices[stored] = flat_indices
+    values[stored] = flat_values
+    return indices, values, nnz
 
 
 class SparseBatch:
@@ -91,29 +110,49 @@ class SparseBatch:
 
     @classmethod
     def from_vectors(
-        cls, vectors: Sequence[Vector], dim: Optional[int] = None, pad_to: int = _LANE
+        cls,
+        vectors: Sequence[Vector],
+        dim: Optional[int] = None,
+        pad_to: int = _LANE,
+        width: Optional[int] = None,
+        truncate: bool = False,
     ) -> "SparseBatch":
-        """Pack SparseVectors (ref SparseVector.java invariants) into one batch."""
-        if not len(vectors):
+        """Pack SparseVectors (ref SparseVector.java invariants) into one batch.
+
+        The column is packed by whole-column numpy: the row objects are
+        touched once to collect their arrays, and everything after that is
+        one concatenate and one masked placement per output, with the casts
+        a per-row assignment would make (to int32 / float32, unchecked).
+        ``width`` forces K (the serving tier's nnz-cap rung); rows longer
+        than it are an error unless ``truncate`` clips them."""
+        n = len(vectors)
+        if not n:
             raise ValueError("empty batch")
-        dims = {v.size() for v in vectors}
+        dims = {v.n for v in vectors}
         if dim is None:
             if len(dims) != 1:
                 raise ValueError(f"inconsistent vector sizes {dims}")
             (dim,) = dims
         elif any(s != dim for s in dims):
             raise ValueError(f"vector sizes {dims} != requested dim {dim}")
-        max_nnz = max(1, max(len(v.indices) for v in vectors))
-        K = -(-max_nnz // pad_to) * pad_to
-        n = len(vectors)
-        indices = np.zeros((n, K), np.int32)
-        values = np.zeros((n, K), np.float32)
-        nnz = np.zeros(n, np.int32)
-        for i, v in enumerate(vectors):
-            k = len(v.indices)
-            indices[i, :k] = v.indices
-            values[i, :k] = v.values
-            nnz[i] = k
+        row_indices = [v.indices for v in vectors]
+        row_values = [v.values for v in vectors]
+        nnz = np.fromiter(map(len, row_indices), np.int32, n)
+        max_nnz = int(nnz.max())
+        if width is None:
+            K = -(-max(1, max_nnz) // pad_to) * pad_to
+        else:
+            K = int(width)
+            if max_nnz > K and not truncate:
+                raise ValueError(f"rows carry up to {max_nnz} entries > forced width {K}")
+        total = int(nnz.sum())
+        flat_indices = np.concatenate(
+            row_indices, out=np.empty(total, np.int32), casting="unsafe"
+        )
+        flat_values = np.concatenate(
+            row_values, out=np.empty(total, np.float32), casting="unsafe"
+        )
+        indices, values, nnz = place_rows(flat_indices, flat_values, nnz, K)
         return cls(dim, indices, values, nnz=nnz)
 
     def row(self, i: int) -> SparseVector:

@@ -146,5 +146,5 @@ class Knn(Estimator, _KnnParams, HasLabelCol):
         model = KnnModel()
         update_existing_params(model, self)
         model.model_features = data["features"]
-        model.model_labels = data["labels"]
+        model.model_labels = np.array(data["labels"])  # the model's own, not the column
         return model

@@ -44,21 +44,25 @@ def extract_labeled_data(
             batch = df.sparse_batch(features_col)
             out = {
                 "indices": batch.indices,
-                "values": batch.values.astype(dtype),
+                "values": batch.values.astype(dtype, copy=False),
                 "dim": batch.dim,
             }
             n = batch.n
-            nnz = int(batch.nnz.sum())  # stored entries, padding left out
+            counts = {
+                "nnz": int(batch.nnz.sum()),  # stored entries, padding left out
+                "width": batch.width,
+                "ragged_rows": int(np.count_nonzero(batch.nnz < batch.width)),
+            }
         else:
             out = {"features": df.vectors(features_col).astype(dtype)}
             n = out["features"].shape[0]
-            nnz = int(out["features"].size)
+            counts = {"nnz": int(out["features"].size)}
         if label_col:
-            out["labels"] = df.scalars(label_col, dtype)
+            out["labels"] = np.asarray(df.column(label_col), dtype=dtype)
         out["weights"] = (
             df.scalars(weight_col, dtype) if weight_col else np.ones(n, dtype)
         )
-        phase.set_metadata(nnz=nnz)
+        phase.set_metadata(**counts)
     return out
 
 

@@ -42,12 +42,13 @@ module owns the names, the packing/readback discipline, and the config.
 """
 from __future__ import annotations
 
+from itertools import chain
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from flink_ml_tpu.config import Options, config
-from flink_ml_tpu.linalg.sparse_batch import ladder_cap
+from flink_ml_tpu.linalg.sparse_batch import SparseBatch, ladder_cap, place_rows
 from flink_ml_tpu.linalg.vectors import SparseVector
 
 __all__ = [
@@ -172,19 +173,14 @@ def pack_sparse_column(
         raise ValueError(f"column {col!r} dims {dims} != expected {dim}")
     max_nnz = max((len(v.indices) for v in vecs), default=0)
     use = _resolve_cap(max_nnz, cap, cap_max, truncate)
-    n = len(vecs)
-    ids = np.zeros((n, use), np.int32)
-    values = np.zeros((n, use), np.float32)
-    nnz = np.zeros(n, np.int32)
-    total = 0
-    for i, v in enumerate(vecs):
-        k = min(len(v.indices), use)
-        ids[i, :k] = v.indices[:k]
-        values[i, :k] = v.values[:k]
-        nnz[i] = k
-        total += k
+    if vecs:
+        batch = SparseBatch.from_vectors(vecs, dim=dim, width=use, truncate=truncate)
+        ids, values, nnz = batch.indices, batch.values, batch.nnz
+    else:
+        ids, values = np.zeros((0, use), np.int32), np.zeros((0, use), np.float32)
+        nnz = np.zeros(0, np.int32)
     arrays = {values_name(col): values, ids_name(col): ids, nnz_name(col): nnz}
-    return arrays, use, dim, total
+    return arrays, use, dim, int(nnz.sum())
 
 
 def pack_entry_rows(
@@ -200,27 +196,22 @@ def pack_entry_rows(
     into the ``"entries"`` quadruple at a ladder cap — the shared tail of
     every host ingest (HashingTF term hashing, CountVectorizer vocabulary
     lookup, FeatureHasher row hashing). Returns ``(arrays, cap, nnz_total)``."""
-    max_nnz = max((len(r) for r in rows), default=0)
-    use = _resolve_cap(max_nnz, cap, cap_max, truncate)
-    n = len(rows)
-    ids = np.zeros((n, use), np.int32)
-    values = np.zeros((n, use), np.float32)
-    nnz = np.zeros(n, np.int32)
-    total = 0
-    for i, row in enumerate(rows):
-        k = min(len(row), use)
-        for j in range(k):
-            ids[i, j] = row[j][0]
-            values[i, j] = row[j][1]
-        nnz[i] = k
-        total += k
+    lens = np.fromiter(map(len, rows), np.int32, len(rows))
+    use = _resolve_cap(int(lens.max(initial=0)), cap, cap_max, truncate)
+    entries = int(lens.sum())
+    ids, values, nnz = place_rows(
+        np.fromiter((e[0] for e in chain.from_iterable(rows)), np.int32, entries),
+        np.fromiter((e[1] for e in chain.from_iterable(rows)), np.float32, entries),
+        lens,
+        use,
+    )
     arrays = {
         values_name(col): values,
         ids_name(col): ids,
         nnz_name(col): nnz,
         len_name(col): np.asarray(lengths, np.int32),
     }
-    return arrays, use, total
+    return arrays, use, int(nnz.sum())
 
 
 def resolve_sparse_hints(df: Optional[Any]) -> Optional[Dict[str, int]]:

@@ -123,7 +123,9 @@ class TestSparseFitTree:
         _, spans = sparse_fit
         one = {name: group[0].attrs for name, group in _by_name(spans).items()}
         assert one["train.fit"] == {"rows": N, "dim": DIM}
-        assert one["train.pack"] == {"rows": N, "sparse": 1, "nnz": N * K}
+        assert one["train.pack"] == {
+            "rows": N, "sparse": 1, "nnz": N * K, "width": K, "ragged_rows": 0,
+        }
         # indices int32 + values f32 [N, K], labels + weights + mask f32 [N]
         assert one["train.cache_put"] == {"columns": 4, "bytes": N * K * 8 + 3 * N * 4}
         layout = one["train.layout"]
@@ -304,7 +306,9 @@ class TestPhaseContract:
         assert set(events) == {name for name, _, _ in SPARSE_TREE}
         (f0, f1, fit_stats), = events["train.fit"]
         assert fit_stats == {"rows": N, "dim": DIM}
-        assert events["train.pack"][0][2] == {"rows": N, "sparse": 1, "nnz": N * K}
+        assert events["train.pack"][0][2] == {
+            "rows": N, "sparse": 1, "nnz": N * K, "width": K, "ragged_rows": 0,
+        }
         assert events["train.layout"][0][2]["reused"] == 0
         units = events["train.layout"][0][2]["units"]
         assert events["train.layout.fill"][0][2] == {

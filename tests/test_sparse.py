@@ -37,6 +37,125 @@ def _sparse_data(n, d, nnz, seed=0):
     return idx, vals, y
 
 
+def _raw(cls, n, indices, values):
+    """A row filled through ``__new__``, as the benchmark's builder fills its
+    rows: whatever arrays it is handed, no sort, no cast, no copy."""
+    v = cls.__new__(cls)
+    v.n, v.indices, v.values = n, indices, values
+    return v
+
+
+class _TaggedSparseVector(SparseVector):
+    __slots__ = ("tag",)
+
+
+def _loop_pack(vectors, pad_to=8, width=None):
+    """The row loop that ``from_vectors`` was until PR 27 (and, with a forced
+    ``width`` that clips, ``pack_sparse_column``'s): the oracle."""
+    max_nnz = max(1, max(len(v.indices) for v in vectors))
+    K = -(-max_nnz // pad_to) * pad_to if width is None else width
+    indices = np.zeros((len(vectors), K), np.int32)
+    values = np.zeros((len(vectors), K), np.float32)
+    nnz = np.zeros(len(vectors), np.int32)
+    for i, v in enumerate(vectors):
+        k = min(len(v.indices), K)
+        indices[i, :k] = v.indices[:k]
+        values[i, :k] = v.values[:k]
+        nnz[i] = k
+    return indices, values, nnz
+
+
+def _col_uniform39():
+    rng = np.random.default_rng(0)
+    idx = np.sort(rng.integers(0, 1 << 22, (64, 39)), axis=1)
+    ones = np.ones(39)  # one values object for every row, as the builder has it
+    return [_raw(SparseVector, 1 << 22, row, ones) for row in idx]
+
+
+def _col_ragged():
+    rng = np.random.default_rng(1)
+    return [
+        SparseVector(500, rng.choice(500, k, replace=False), rng.standard_normal(k))
+        for k in rng.integers(0, 21, 50)
+    ]
+
+
+def _col_no_entries():
+    return [SparseVector(7, [], []) for _ in range(5)]
+
+
+def _col_some_empty():
+    return [SparseVector(7, [], []), SparseVector(7, [2, 4], [1.0, -1.0]), SparseVector(7, [], [])]
+
+
+def _col_explicit_zeros():
+    return [SparseVector(10, [3, 5], [0.0, 2.0]), SparseVector(10, [0, 1, 9], [0.0, 0.0, 0.0])]
+
+
+def _col_one_row():
+    return [SparseVector(12, [1, 11], [0.5, -0.25])]
+
+
+def _col_straddles_pad_to():
+    rng = np.random.default_rng(2)
+    return [
+        SparseVector(64, np.arange(k), rng.standard_normal(k)) for k in (7, 8, 9, 8, 7, 1, 9, 8)
+    ]
+
+
+def _col_other_dtypes():
+    rng = np.random.default_rng(3)
+    f64 = rng.standard_normal(6) * 1e3 + 1e-9  # rounds on the way to float32
+    return [
+        _raw(SparseVector, 300, np.arange(6, dtype=np.int32), f64.astype(np.float32)),
+        _raw(SparseVector, 300, np.arange(6, dtype=np.uint8) * 40, f64.astype(np.float16)),
+        _raw(SparseVector, 300, np.arange(6, dtype=np.int16), np.arange(6, dtype=np.int64)),
+        _raw(SparseVector, 300, np.arange(6, dtype=np.float64) * 7.0, f64),
+        _raw(SparseVector, 300, np.array([1, 2, (1 << 32) + 5]), np.array([True, False, True])),
+        _raw(SparseVector, 300, [3, 4], [1.5, 2.5]),  # plain lists
+    ]
+
+
+def _col_non_contiguous():
+    rng = np.random.default_rng(4)
+    idx = np.sort(rng.integers(0, 1000, (6, 20)), axis=1)
+    val = rng.standard_normal((20, 6))
+    return [
+        _raw(SparseVector, 1000, idx[i, ::2], val[::2, i]) for i in range(6)
+    ] + [_raw(SparseVector, 1000, idx[0, ::-1][:5], val[::-3, 0][:5])]
+
+
+def _col_mixed_dense_sparse():
+    from flink_ml_tpu.linalg.vectors import DenseVector
+
+    return [
+        SparseVector(4, [0], [1.0]),
+        DenseVector([0.0, 1.0, 0.0, 2.0]),
+        SparseVector(4, [1, 3], [0.0, -1.0]),
+        DenseVector([0.0, 0.0, 0.0, 0.0]),
+    ]
+
+
+def _col_subclass():
+    rows = [_TaggedSparseVector(9, [1, 4], [1.0, 2.0]), SparseVector(9, [0], [3.0])]
+    rows[0].tag = "kept"
+    return rows
+
+
+_COLUMNS = [
+    _col_uniform39, _col_ragged, _col_no_entries, _col_some_empty, _col_explicit_zeros,
+    _col_one_row, _col_straddles_pad_to, _col_other_dtypes, _col_non_contiguous,
+    _col_mixed_dense_sparse, _col_subclass,
+]
+
+
+def _assert_same_bits(batch, want):
+    indices, values, nnz = want
+    for got, ref in ((batch.indices, indices), (batch.values, values), (batch.nnz, nnz)):
+        assert got.dtype == ref.dtype and got.shape == ref.shape
+        assert got.tobytes() == ref.tobytes()
+
+
 class TestSparseBatch:
     def test_from_vectors_pads_and_round_trips(self):
         vecs = [
@@ -72,6 +191,114 @@ class TestSparseBatch:
         np.testing.assert_array_equal(
             batch.densify(), [[1.0, 0, 0, 0], [0, 1.0, 0, 2.0]]
         )
+
+
+    # -- PR 27: the column becomes the batch by whole-column numpy; what the
+    # -- row loop gave, it gives, to the bit
+    @pytest.mark.parametrize("make", _COLUMNS, ids=lambda f: f.__name__[5:])
+    def test_equals_the_row_loop(self, make):
+        col = make()
+        rows = [v if isinstance(v, SparseVector) else v.to_sparse() for v in col]
+        want = _loop_pack(rows)
+        batch = DataFrame.from_dict({"f": col}).sparse_batch("f")
+        _assert_same_bits(batch, want)
+        assert batch.dim == rows[0].n and batch.width == want[0].shape[1]
+        assert batch.width % 8 == 0 and batch.n == len(col)
+        if all(isinstance(v, SparseVector) for v in col):  # straight in, too
+            _assert_same_bits(SparseBatch.from_vectors(col), want)
+            _assert_same_bits(SparseBatch.from_vectors(col, pad_to=5), _loop_pack(rows, 5))
+
+    @pytest.mark.parametrize("make", [_col_ragged, _col_straddles_pad_to, _col_some_empty],
+                             ids=lambda f: f.__name__[5:])
+    @pytest.mark.parametrize("width", [1, 8, 32])
+    def test_forced_width_clips_as_the_loop_did(self, make, width):
+        col = make()
+        longest = max(len(v.indices) for v in col)
+        if longest > width:
+            with pytest.raises(ValueError, match="forced width"):
+                SparseBatch.from_vectors(col, width=width)
+        batch = SparseBatch.from_vectors(col, width=width, truncate=True)
+        _assert_same_bits(batch, _loop_pack(col, width=width))
+        assert batch.width == width
+
+    @pytest.mark.parametrize(
+        "pack, error, match",
+        [
+            (lambda: SparseBatch.from_vectors([]), ValueError, "^empty batch$"),
+            (
+                lambda: SparseBatch.from_vectors(
+                    [SparseVector(5, [0], [1.0]), SparseVector(6, [0], [1.0])]
+                ),
+                ValueError,
+                r"^inconsistent vector sizes \{5, 6\}$",
+            ),
+            (
+                lambda: SparseBatch.from_vectors([SparseVector(5, [0], [1.0])], dim=6),
+                ValueError,
+                r"^vector sizes \{5\} != requested dim 6$",
+            ),
+            (
+                lambda: DataFrame.from_dict({"f": ["a", "b"]}).sparse_batch("f"),
+                TypeError,
+                "^column 'f' is not a vector column$",
+            ),
+            (
+                lambda: DataFrame.from_dict({"f": np.zeros((3, 2))}).sparse_batch("f"),
+                TypeError,
+                "^column 'f' is not a vector column$",
+            ),
+            (
+                lambda: DataFrame.from_dict(
+                    {"f": [SparseVector(3, [0], [1.0]), None]}
+                ).sparse_batch("f"),
+                TypeError,
+                "^column 'f' is not a vector column$",
+            ),
+        ],
+        ids=["empty", "inconsistent_sizes", "dim_mismatch", "strings", "array", "none_row"],
+    )
+    def test_errors_keep_type_and_message(self, pack, error, match):
+        with pytest.raises(error, match=match):
+            pack()
+
+    def test_pack_counts_say_which_shape_of_column(self):
+        from flink_ml_tpu import trace
+        from flink_ml_tpu.models.common import extract_labeled_data
+
+        col = _col_straddles_pad_to()
+        df = DataFrame.from_dict({"f": col, "y": np.zeros(len(col), np.float32)})
+        with trace.capture() as recorder:
+            data = extract_labeled_data(df, "f", "y", None, allow_sparse=True)
+        (span,) = [s for s in recorder.snapshot() if s.name == "train.pack"]
+        assert span.attrs == {
+            "rows": 8, "sparse": 1, "nnz": 57, "width": 16, "ragged_rows": 8,
+        }
+        # columns already in the asked dtype come through without a copy
+        assert data["labels"] is df.column("y")
+        assert data["values"].dtype == np.float32 and data["values"].base is None
+
+    def test_rows_filled_through_new_fit_the_same_coefficients(self):
+        """Two fits of the toy sparse LR, one on rows ``SparseVector(...)``
+        made (int64 / float64 copies), one on the same rows filled through
+        ``__new__`` with narrower arrays and views: the same batch, hence
+        the same coefficients, to the bit."""
+        from flink_ml_tpu.models.classification.logistic_regression import LogisticRegression
+
+        d = 256
+        idx, vals, y = _sparse_data(n=128, d=d, nnz=7, seed=21)
+        order = np.argsort(idx, axis=1)
+        idx = np.take_along_axis(idx, order, axis=1)
+        vals = np.take_along_axis(vals, order, axis=1)
+        label = y.astype(np.float64)
+        made = [SparseVector(d, r, v) for r, v in zip(idx, vals)]
+        wide = np.zeros((128, 14), np.float32)
+        wide[:, ::2] = vals
+        filled = [_raw(SparseVector, d, r, v) for r, v in zip(idx, wide[:, ::2])]
+        est = LogisticRegression().set_max_iter(12).set_global_batch_size(64).set_tol(0.0)
+        a = est.fit(DataFrame.from_dict({"features": made, "label": label}))
+        b = est.fit(DataFrame.from_dict({"features": filled, "label": label}))
+        assert np.any(a.coefficient != 0)
+        assert np.asarray(a.coefficient).tobytes() == np.asarray(b.coefficient).tobytes()
 
 
 class TestLossAndMult:

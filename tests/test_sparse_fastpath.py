@@ -39,6 +39,7 @@ from flink_ml_tpu.servable.planner import IneligibleBatch
 from flink_ml_tpu.servable.sharding import PlanSharding
 from flink_ml_tpu.servable.sparse import (
     OffLadderError,
+    pack_entry_rows,
     pack_sparse_column,
     resolve_warm_caps,
     sparse_names,
@@ -132,6 +133,14 @@ def _sparse_rows(n, dim, max_nnz, seed=11):
     return rows
 
 
+def _assert_same_bits(arrays, col, *want):
+    """The packed triple of ``col`` equals ``(values, ids, nnz)`` to the bit."""
+    for name, ref in zip(sparse_names(col), want):
+        got = arrays[name]
+        assert got.dtype == ref.dtype and got.shape == ref.shape
+        assert got.tobytes() == ref.tobytes()
+
+
 def _sparse_serving_pipe(dim, seed=13):
     rng = np.random.default_rng(seed)
     idf_m = IDFModel().set_input_col("features").set_output_col("scaled")
@@ -209,6 +218,64 @@ class TestLadder:
         df = DataFrame.from_dict({"f": _sparse_rows(4, 64, max_nnz=40, seed=2)})
         with pytest.raises(OffLadderError):
             pack_sparse_column(df, "f", cap_max=16)
+
+    @pytest.mark.parametrize(
+        "kwargs", [{}, {"cap": 4, "truncate": True}, {"cap": 16}, {"dim": 32, "cap_max": 8}],
+        ids=["natural", "forced_clipping", "forced_wide", "dim_given"],
+    )
+    def test_pack_equals_the_row_loop_it_was(self, kwargs):
+        """``pack_sparse_column`` now calls the one whole-column pack
+        (``SparseBatch.from_vectors``); its own row loop, kept here as the
+        oracle, gave these arrays to the bit."""
+        rows = _sparse_rows(9, 32, max_nnz=7, seed=5)
+        arrays, cap, dim, total = pack_sparse_column(
+            DataFrame.from_dict({"f": rows}), "f", **kwargs
+        )
+        ids = np.zeros((len(rows), cap), np.int32)
+        values = np.zeros((len(rows), cap), np.float32)
+        nnz = np.zeros(len(rows), np.int32)
+        for i, v in enumerate(rows):
+            k = min(len(v.indices), cap)
+            ids[i, :k], values[i, :k], nnz[i] = v.indices[:k], v.values[:k], k
+        _assert_same_bits(arrays, "f", values, ids, nnz)
+        assert dim == 32 and total == int(nnz.sum())
+        assert cap == kwargs.get("cap", ladder_cap(max(len(v.indices) for v in rows)))
+
+    def test_pack_errors_keep_type_and_message(self):
+        df = DataFrame.from_dict({"f": _sparse_rows(4, 32, max_nnz=5, seed=6)})
+        with pytest.raises(ValueError, match=r"^column 'f' dims \{32\} != expected 64$"):
+            pack_sparse_column(df, "f", dim=64)
+        with pytest.raises(OffLadderError, match="forced nnz cap 1$"):
+            pack_sparse_column(df, "f", cap=1)
+        mixed = DataFrame.from_dict({"f": [SparseVector(3, [0], [1.0]), SparseVector(4, [0], [1.0])]})
+        with pytest.raises(ValueError, match="^column 'f' has inconsistent dims"):
+            pack_sparse_column(mixed, "f")
+        # a column of no rows packs to no rows, as it did
+        arrays, cap, dim, total = pack_sparse_column(DataFrame.from_dict({"f": []}), "f", dim=32)
+        assert arrays["f!ids"].shape == (0, 1) and (cap, dim, total) == (1, 32, 0)
+
+    @pytest.mark.parametrize("kwargs", [{}, {"cap": 2, "truncate": True}],
+                             ids=["natural", "forced_clipping"])
+    def test_entry_rows_equal_the_entry_loop_they_were(self, kwargs):
+        rng = np.random.default_rng(8)
+        rows = [
+            [(int(rng.integers(0, 1 << 20)), float(rng.standard_normal())) for _ in range(k)]
+            for k in (3, 0, 5, 1, 5, 2)
+        ]
+        rows[2] = [(np.int64(7), np.float32(0.1)), (7, 2), (9, 0.0), (1, 1e-9), (0, -3.5)]
+        arrays, cap, total = pack_entry_rows("t", rows, [len(r) for r in rows], **kwargs)
+        ids = np.zeros((len(rows), cap), np.int32)
+        values = np.zeros((len(rows), cap), np.float32)
+        nnz = np.zeros(len(rows), np.int32)
+        for i, row in enumerate(rows):
+            nnz[i] = min(len(row), cap)
+            for j in range(nnz[i]):
+                ids[i, j], values[i, j] = row[j]
+        _assert_same_bits(arrays, "t", values, ids, nnz)
+        assert total == int(nnz.sum()) and cap == kwargs.get("cap", 8)
+        assert arrays["t!len"].tolist() == [3, 0, 5, 1, 5, 2]
+        empty, cap0, total0 = pack_entry_rows("t", [], [])
+        assert empty["t!ids"].shape == (0, 1) and cap0 == 1 and total0 == 0
 
     def test_warm_caps_default_full_ladder_and_override(self):
         config.set(Options.SPARSE_NNZ_CAP_MAX, 16)
