@@ -149,8 +149,8 @@ def make_criteo_shape(rng, n: int, dim: int, nnz: int):
 
 
 def reference_sgd(idx, vals, y, dim, n_shards, global_batch, steps, lr):
-    """The plain numpy minibatch-SGD step of ``bench.py``'s ``cpu_step``
-    (gather-dot, ``np.add.at`` scatter, full coefficient update), in float64,
+    """The plain numpy minibatch-SGD step (gather-dot, ``np.add.at``
+    scatter, full coefficient update), in float64,
     with the library's batch schedule: every data shard cycles through ITS
     rows by ``ceil(batch / shards)`` (SGD.java:246-285), short tail batch
     included. Independent of the code under test."""

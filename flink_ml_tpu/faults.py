@@ -26,8 +26,9 @@ Design:
   supervisor's error classifier, which is what lets recovery tests drive the
   restart machinery end-to-end.
 
-``tools/check_fault_points.py`` asserts every registered point is exercised by
-at least one test, so injection seams cannot silently rot.
+graftcheck's ``fault-points`` rule (``tools/graftcheck/rules/fault_points.py``)
+asserts every registered point is exercised by at least one test, so injection
+seams cannot silently rot.
 """
 from __future__ import annotations
 
@@ -44,7 +45,7 @@ __all__ = [
 
 
 #: The runtime's injection seams. Adding a point here without a ``trip`` call
-#: site AND a test exercising it fails ``tools/check_fault_points.py``.
+#: site AND a test exercising it fails graftcheck's ``fault-points`` rule.
 FAULT_POINTS: Dict[str, str] = {
     "iteration.epoch": (
         "Epoch boundary of both iteration drivers (iteration/iteration.py) — "

@@ -851,7 +851,7 @@ class SGD(Optimizer):
             )
         self.sparse_kernel = sparse_kernel
         self.onehot_premat = onehot_premat
-        self.onehot_premat_active = False  # set per fit; introspection/bench
+        self.onehot_premat_active = False  # set per fit; the smoke and perfbench read it
         self.max_iter = max_iter
         self.learning_rate = learning_rate
         self.global_batch_size = global_batch_size

@@ -5,9 +5,10 @@ programs are not bit-stable (XLA legally fuses one stage's elementwise math
 into the next stage's dot reduction and reorders the accumulation), so the
 exact tier compiles one program per reduction-bearing spec and merges only
 ``elementwise`` runs. That preserves bit-equality with the per-stage path but
-leaves the biggest single-device lever on the table — BENCH_r05's
-flash-attention rows showed 4.7× from keeping intermediates VMEM-resident
-across exactly such a boundary.
+leaves the biggest single-device lever on the table: keeping intermediates
+VMEM-resident across exactly such a boundary, as the fused attention fold
+(``parallel/flash.py``) does. What it gains for a serving chain is not
+measured on a chip (ROADMAP D2).
 
 ``fusion.mode`` names the trade:
 
@@ -60,10 +61,10 @@ __all__ = [
 FUSION_EXACT = "exact"
 FUSION_FAST = "fast"
 
-#: Documented fast-tier accuracy contract, in float32 ulps, per benched chain
+#: Documented fast-tier accuracy contract, in float32 ulps, per documented chain
 #: (docs/fusion.md has the table with the measured values behind each bound).
 #: Exact mode is bit-identical (0 ulps) by construction and is not listed.
-#: Keys are the chain names tests and bench rows use; values bound the max
+#: Keys are the chain names the tests use; values bound the max
 #: elementwise ulp distance between the fast-tier output and the exact-tier
 #: output of the same chain on the same input bits, both read back as the
 #: float32 the programs computed. The bounds hold for BOTH fast sub-tiers

@@ -6,7 +6,7 @@ Reference: ``flink-ml-servable-lib/.../LogisticRegressionModelServable.java:44``
 that any Model can have a runtime-free replica (SURVEY.md §2.6) — here the lib
 also covers the clustering and feature-scaling families.
 
-The L1 guarantee (enforced by ``tools/check_servable_imports.py``): nothing in
+The L1 guarantee (enforced by graftcheck's ``layer-deps`` rule): nothing in
 this module imports the training stack (``iteration/``, ``execution/``,
 ``builder/``, ``models/``). Numeric parity with the training-side Models comes
 from sharing the exact jit'd kernels in ``ops/kernels.py`` — the same compiled
@@ -218,7 +218,7 @@ class MLPClassifierModelServable(
     ModelServable, HasFeaturesCol, HasPredictionCol, HasRawPredictionCol
 ):
     """Runtime-free MLPClassifierModel replica — the weight-resident
-    throughput serving shape (BENCH `mlp_serving_throughput`): relu MLP
+    throughput serving shape: relu MLP
     forward + softmax head through the same ``mlp_predict_fn`` body the
     per-stage kernel jits, with every layer's weights device-resident at
     swap/build time on the fast path instead of re-uploaded per call.

@@ -4,8 +4,8 @@ The fast fusion tier (``fusion.mode=fast``, docs/fusion.md) merges a chain of
 kernel specs into a single XLA program; for the chains the cost model marks
 hottest it goes one level lower: the whole chain becomes **one Pallas kernel**
 with a row-tiled grid, so every inter-stage intermediate lives its entire life
-in VMEM — never written back to HBM between stages, the 4.7× lever BENCH_r05
-measured on flash attention. The kernel body composes the SAME
+in VMEM — never written back to HBM between stages, the lever the fused
+attention fold uses. The kernel body composes the SAME
 ``ops/kernels.py`` ``*_fn`` math the specs' ``kernel_fn``s are built from
 (the kernel-spec-consistency contract), on values read once from the tile's
 refs; model arrays ride along as full (untiled) operands.

@@ -19,7 +19,7 @@ Slots past a row's true result set carry row −1 / score ∓inf — the typed
 empty-result convention; a query with no history (or sharing no LSH bucket
 with any candidate) yields a fully −1 row instead of erroring.
 
-The L1 guarantee (``tools/check_servable_imports.py``, layer_deps): nothing
+The L1 guarantee (graftcheck's ``layer-deps`` rule): nothing
 here imports the training stack — the MinHash constants the LSH head needs are
 mirrored here and ``models/feature/lsh.py`` imports them FROM this module, so
 the two can never drift. Parity between the fused head and the per-stage

@@ -7,7 +7,7 @@ admission control with typed overload rejection, and ``ml.serving.*``
 observability. See docs/serving.md.
 
 Runtime-free like the servable tier it wraps: importing this package never
-pulls the training stack (enforced by tools/check_servable_imports.py).
+pulls the training stack (enforced by graftcheck's ``layer-deps`` rule).
 """
 from flink_ml_tpu.serving.batcher import MicroBatcher, bucket_for, pad_to, power_of_two_buckets
 from flink_ml_tpu.serving.controller import AdaptiveController, ControllerAction, GoodputLedger

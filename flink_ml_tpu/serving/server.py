@@ -17,7 +17,7 @@ Wraps any ``TransformerServable``/``ModelServable`` (or a whole
 This is the third pillar of the framework (train → supervise → serve): the
 inference half of the north star lives here, and it is runtime-free in the L1
 sense — importing it never pulls the training stack
-(tools/check_servable_imports.py enforces that).
+(graftcheck's ``layer-deps`` rule enforces that).
 """
 from __future__ import annotations
 

@@ -635,7 +635,7 @@ def disable() -> Tracer:
 @contextlib.contextmanager
 def capture(capacity: Optional[int] = None, xprof: Optional[bool] = None):
     """Trace a region into a fresh recorder and restore the previous tracer
-    state after — the test/bench/smoke harness entry point:
+    state after — the test and smoke harness entry point:
 
         with trace.capture() as recorder:
             server.predict(df)

@@ -7,8 +7,9 @@ that moves (a ``tempfile`` name) never hits. The placement therefore comes
 from OUTSIDE the program: ``JAX_COMPILATION_CACHE_DIR`` when the deployment
 sets it (JAX reads that variable itself), else one fixed directory inside the
 checkout. No library code sets the cache anywhere else; every entry point
-(``chip_smoke.py``, ``bench.py``, the benchmark CLI, the fleet worker) calls
-:func:`configure_compile_cache` once, before it compiles anything.
+(``chip_smoke.py``, the benchmark CLI, the fleet worker) calls
+:func:`configure_compile_cache` once, before it compiles anything
+(``perfbench/run.py`` applies the same rule with a fixed directory of its own).
 """
 from __future__ import annotations
 

@@ -45,66 +45,3 @@ def test_markdown_table_renders(rows):
     table = markdown_table(rows)
     assert "per-chip GFLOP/step" in table and table.count("|") > 20
 
-
-class TestWallClockCorroboration:
-    """Round-5 (VERDICT r4 next #7): the falloff must hold in wall-clock, not
-    just compiled FLOP counts — a memory-shaped crossing could in principle
-    fall in FLOPs while time stalls. The virtual mesh gives relative falloff
-    only (CPU ms are not TPU ms), so the band is generous."""
-
-    @pytest.fixture(scope="class")
-    def timed_rows(self):
-        return measure_scaling(
-            [1, 2, 4, 8], global_batch=8192, dim=1 << 16, nnz=8, K=8,
-            time_steps=3,
-        )
-
-    def test_time_columns_present_and_positive(self, timed_rows):
-        for r in timed_rows:
-            assert r["per_chip_ms"] > 0 and r["wall_ms_per_step"] > 0, r
-
-    def test_per_chip_time_falls_superlinearly(self, timed_rows):
-        # The same superlinearity contract as the FLOP column, loosened for
-        # host-timing noise: 8x the chips must cut per-chip TIME by >8x
-        # (quadratic predicts ~16-25x; sublinear or linear fails).
-        by_p = {r["p"]: r["per_chip_ms"] for r in timed_rows}
-        assert by_p[1] / by_p[8] > 8.0, by_p
-
-    @pytest.mark.slow
-    def test_time_falloff_tracks_flop_falloff(self, timed_rows):
-        # Tolerance band: measured time falloff within [1/3, 3]x of the
-        # FLOP-predicted falloff at every p — catches an XLA rewrite that
-        # changes the constants without failing on scheduler jitter.
-        #
-        # Gated behind -m slow (VERDICT r5): host timing on a contended
-        # 1-core CI box can land outside any honest band at the small-p
-        # steps, where one preempted slice dwarfs the measured ms. The
-        # deterministic directional contract stays in tier-1 below.
-        for r in timed_rows[1:]:
-            flop_fall = timed_rows[0]["flops_per_chip"] / r["flops_per_chip"]
-            time_fall = timed_rows[0]["per_chip_ms"] / r["per_chip_ms"]
-            assert flop_fall / 3 < time_fall < flop_fall * 3, (
-                f"p={r['p']}: time falloff {time_fall:.1f}x vs "
-                f"FLOP falloff {flop_fall:.1f}x"
-            )
-
-    def test_time_falloff_direction_tracks_flop_falloff(self, timed_rows):
-        # Deterministic tier-1 fallback for the banded check above: the
-        # FLOP-predicted falloff is exact (compiled cost analysis), and the
-        # measured time at the widest step (p=1 -> p=8, a predicted ~16-25x)
-        # must at least FALL. A regression that flattens the crossing term
-        # (time stalling while FLOPs drop) still fails; scheduler jitter,
-        # which perturbs constants but cannot turn a 16x drop into a rise,
-        # does not.
-        by_p = {r["p"]: r for r in timed_rows}
-        flop_fall = by_p[1]["flops_per_chip"] / by_p[8]["flops_per_chip"]
-        time_fall = by_p[1]["per_chip_ms"] / by_p[8]["per_chip_ms"]
-        assert flop_fall > 12.0, by_p  # exact: the superlinear FLOP contract
-        assert time_fall > 1.0, (
-            f"per-chip time did not fall at all across 1->8 chips "
-            f"(time {time_fall:.2f}x vs FLOPs {flop_fall:.1f}x)"
-        )
-
-    def test_timed_markdown_table_renders(self, timed_rows):
-        table = markdown_table(timed_rows)
-        assert "measured per-chip ms" in table and "time fall" in table

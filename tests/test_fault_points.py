@@ -1,27 +1,17 @@
-"""Tier-1 shim for ``tools/check_fault_points.py``.
+"""Tier-1 gate over graftcheck's ``fault-points`` rule.
 
 Every fault point registered in ``flink_ml_tpu.faults.FAULT_POINTS`` must
 have a runtime ``faults.trip`` call site AND a test exercising it — this test
 makes the tier-1 suite enforce that, so injection seams can't silently rot.
 """
-import importlib.util
 import os
 
-_TOOL = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "tools",
-    "check_fault_points.py",
-)
+from tools.graftcheck.rules import fault_points
 
-
-def _load_tool():
-    spec = importlib.util.spec_from_file_location("check_fault_points", _TOOL)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_every_fault_point_is_tripped_and_tested():
-    problems, trip_sites = _load_tool().check()
+    problems, trip_sites = fault_points.check(REPO_ROOT)
     assert not problems, "\n".join(problems)
     assert trip_sites, "no fault points found at all — the registry is empty?"

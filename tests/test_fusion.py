@@ -77,7 +77,7 @@ def _reset_fusion_config():
 
 
 def _feature6_stages(d, seed=9):
-    """The 6-stage feature chain of bench.py / docs/fusion.md."""
+    """The 6-stage feature chain of docs/fusion.md."""
     rng = np.random.default_rng(seed)
     scaler = StandardScalerModel().set_input_col("input").set_output_col("scaled")
     scaler.set_with_mean(True)

@@ -213,7 +213,7 @@ def test_layer_deps_flags_unmapped_package(tmp_path):
 
 
 def test_servable_shim_contract(tmp_path):
-    """The absorbed check_servable_imports semantics stay intact."""
+    """Lazy imports of the training stack are seen, file by file."""
     bad = tmp_path / "bad.py"
     bad.write_text(
         "def transform(df):\n"

@@ -1,4 +1,4 @@
-"""Tier-1 shim for ``tools/check_servable_imports.py``.
+"""Tier-1 gate over the serving slice of graftcheck's ``layer-deps`` rule.
 
 The L1 guarantee from the reference (SURVEY.md §2.6): the servable/serving
 tier is deployable without the training runtime. This test makes tier-1
@@ -6,26 +6,15 @@ enforce it — any import (even lazy, function-local) of ``iteration/``,
 ``execution/``, ``builder/`` or ``models/`` from ``flink_ml_tpu/servable/``
 or ``flink_ml_tpu/serving/`` fails the suite.
 """
-import importlib.util
 import os
 
-_TOOL = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "tools",
-    "check_servable_imports.py",
-)
+from tools.graftcheck.rules import layer_deps
 
-
-def _load_tool():
-    spec = importlib.util.spec_from_file_location("check_servable_imports", _TOOL)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_serving_tier_is_runtime_free():
-    tool = _load_tool()
-    problems, checked = tool.check()
+    problems, checked = layer_deps.servable_check(REPO_ROOT)
     assert not problems, "\n".join(problems)
     # Both packages must actually be present in the sweep — an empty check
     # passing would be the guard silently rotting.
@@ -35,7 +24,6 @@ def test_serving_tier_is_runtime_free():
 
 def test_checker_catches_lazy_imports(tmp_path):
     """The guard must see function-local imports, not just module top-level."""
-    tool = _load_tool()
     bad = tmp_path / "bad.py"
     bad.write_text(
         "def transform(df):\n"
@@ -44,7 +32,7 @@ def test_checker_catches_lazy_imports(tmp_path):
         "    from flink_ml_tpu import builder\n"
         "    return compute_dots\n"
     )
-    found = sorted(m for _, m in tool._violations_in_file(str(bad)))
+    found = sorted(m for _, m in layer_deps.servable_violations_in_file(str(bad)))
     assert found == [
         "flink_ml_tpu.builder",
         "flink_ml_tpu.iteration.datacache",
@@ -53,7 +41,6 @@ def test_checker_catches_lazy_imports(tmp_path):
 
 
 def test_checker_allows_runtime_free_imports(tmp_path):
-    tool = _load_tool()
     good = tmp_path / "good.py"
     good.write_text(
         "import numpy as np\n"
@@ -61,4 +48,4 @@ def test_checker_allows_runtime_free_imports(tmp_path):
         "from flink_ml_tpu.ops.kernels import compute_dots\n"
         "from flink_ml_tpu.checkpoint import scan_numbered_dirs\n"
     )
-    assert list(tool._violations_in_file(str(good))) == []
+    assert list(layer_deps.servable_violations_in_file(str(good))) == []

@@ -642,78 +642,3 @@ class TestTraceviewJson:
         assert payload["spans"] > 0
         assert "overall_goodput_fraction" in payload
 
-
-# ---------------------------------------------------------------------------
-# bench_trend (informational CI step)
-# ---------------------------------------------------------------------------
-
-
-class TestBenchTrend:
-    def _write_rounds(self, tmp_path, old_row, new_row):
-        (tmp_path / "BENCH_r01.json").write_text(
-            json.dumps({"workloads": [old_row]}), encoding="utf-8"
-        )
-        (tmp_path / "BENCH_r02.json").write_text(
-            json.dumps({"workloads": [new_row]}), encoding="utf-8"
-        )
-
-    def test_regression_warns_but_exits_zero(self, tmp_path, capsys):
-        import tools.bench_trend as bench_trend
-
-        self._write_rounds(
-            tmp_path,
-            {"name": "row", "latency_p50_ms": 1.0, "rows_per_sec": 1000.0},
-            {"name": "row", "latency_p50_ms": 1.5, "rows_per_sec": 800.0},
-        )
-        assert bench_trend.main(["--dir", str(tmp_path)]) == 0  # informational
-        out = capsys.readouterr().out
-        assert "REGRESSION" in out and "WARN" in out
-        assert "latency_p50_ms" in out and "rows_per_sec" in out
-
-    def test_strict_mode_fails_on_regression(self, tmp_path, capsys):
-        import tools.bench_trend as bench_trend
-
-        self._write_rounds(
-            tmp_path,
-            {"name": "row", "latency_p50_ms": 1.0},
-            {"name": "row", "latency_p50_ms": 2.0},
-        )
-        assert bench_trend.main(["--dir", str(tmp_path), "--strict"]) == 1
-
-    def test_within_threshold_is_quiet(self, tmp_path, capsys):
-        import tools.bench_trend as bench_trend
-
-        self._write_rounds(
-            tmp_path,
-            {"name": "row", "latency_p50_ms": 1.0, "rows_per_sec": 1000.0,
-             "sweep": [{"latency_p999_ms": 5.0}]},
-            {"name": "row", "latency_p50_ms": 1.05, "rows_per_sec": 980.0,
-             "sweep": [{"latency_p999_ms": 5.2}]},
-        )
-        assert bench_trend.main(["--dir", str(tmp_path), "--strict"]) == 0
-        assert "REGRESSION" not in capsys.readouterr().out
-
-    def test_fewer_than_two_rounds_is_a_noop(self, tmp_path):
-        import tools.bench_trend as bench_trend
-
-        assert bench_trend.main(["--dir", str(tmp_path)]) == 0
-
-    def test_new_metrics_and_rows_reported_informationally(self, tmp_path, capsys):
-        import tools.bench_trend as bench_trend
-
-        (tmp_path / "BENCH_r01.json").write_text(
-            json.dumps({"workloads": [{"name": "row", "latency_p50_ms": 1.0}]}),
-            encoding="utf-8",
-        )
-        (tmp_path / "BENCH_r02.json").write_text(
-            json.dumps({"workloads": [
-                {"name": "row", "latency_p50_ms": 1.0, "bf16_latency_p50_ms": 0.7},
-                {"name": "precision_sweep", "latency_p50_ms": 0.5},
-            ]}),
-            encoding="utf-8",
-        )
-        assert bench_trend.main(["--dir", str(tmp_path), "--strict"]) == 0
-        out = capsys.readouterr().out
-        assert "+ new row precision_sweep" in out
-        assert "bf16_latency_p50_ms" in out and "(NEW)" in out
-        assert "WARN" not in out and "REGRESSION" not in out

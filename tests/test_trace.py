@@ -1,8 +1,8 @@
 """graftscope (flink_ml_tpu/trace.py) — the tracing + goodput contract:
 
 - **disabled is free**: zero spans recorded, the shared no-op span, no
-  per-request span allocation on the serving path (the structural half of
-  bench.py's ``tracing_overhead`` row);
+  per-request span allocation on the serving path (structure and counts;
+  what tracing costs when it is on is a chip reading, ``PERF.md`` §6, PR 24);
 - **span model**: thread-local nesting, manual begin/end, retro recording,
   parent-ID integrity across the MicroBatcher thread handoff, ring-buffer
   wraparound under a multi-threaded soak;

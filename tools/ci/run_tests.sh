@@ -112,11 +112,4 @@ python tools/ci/retrieval_smoke.py
 echo "=== train smoke (sharded fit hard-kill -> cross-width resume) ==="
 python tools/ci/train_smoke.py
 
-# Bench trend (informational): diff the two newest BENCH_r*.json rounds and
-# warn on >10% p50 / rows-per-second movement — directional on shared CI
-# boxes, so the step never fails the build (tools/bench_trend.py --strict
-# exists for local perf work).
-echo "=== bench trend (informational) ==="
-python tools/bench_trend.py || true
-
 echo "CI OK"

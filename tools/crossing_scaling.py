@@ -29,26 +29,13 @@ import numpy as np
 __all__ = ["measure_scaling", "markdown_table"]
 
 
-def measure_scaling(p_list, global_batch, dim, nnz, K, seed=0, time_steps=0):
+def measure_scaling(p_list, global_batch, dim, nnz, K, seed=0):
     """Compile the fused one-hot SGD program at each DP width and return
     ``[{p, local_batch, sub_batch, n_flat, flops_per_chip, bytes_per_chip}]``.
 
     One window, one epoch per chunk (chunk_len=1): the numbers are one
     minibatch step's per-chip cost, the unit the scaling claim is about.
-
-    With ``time_steps > 0`` each row additionally carries wall-clock columns
-    from running the compiled program ``time_steps`` times (median of 3
-    loops, outputs chained back as inputs to respect buffer donation):
-    ``wall_ms_per_step`` and ``per_chip_ms`` — the latter estimated as
-    ``wall * min(cores, p) / p``, since on a host with fewer cores than
-    virtual devices the p shards serialize onto the cores (wall ≈ p × the
-    per-chip time), while with enough cores they run concurrently (wall ≈
-    the per-chip time). Relative falloff across p is the meaningful number;
-    absolute CPU milliseconds are not TPU milliseconds.
     """
-    import os
-    import time
-
     import jax
 
     from flink_ml_tpu.iteration import DeviceDataCache
@@ -103,7 +90,7 @@ def measure_scaling(p_list, global_batch, dim, nnz, K, seed=0, time_steps=0):
             cost = program.lower(*args).compile().cost_analysis()
             if isinstance(cost, (list, tuple)):  # some backends wrap in a list
                 cost = cost[0]
-            row = {
+            rows.append({
                 "p": p,
                 "local_batch": local_batch,
                 "sub_batch": lay.sub_batch,
@@ -113,57 +100,30 @@ def measure_scaling(p_list, global_batch, dim, nnz, K, seed=0, time_steps=0):
                 "bytes_per_chip": float(
                     cost.get("bytes accessed", float("nan"))
                 ),
-            }
-            if time_steps:
-                coef, done, *rest = args
-                coef, done, _, _ = program(coef, done, *rest)  # warmup compile
-                jax.block_until_ready(coef)
-                loops = []
-                for _ in range(3):
-                    t0 = time.perf_counter()
-                    for _ in range(time_steps):
-                        # chain outputs -> inputs: coef/done are donated
-                        coef, done, _, _ = program(coef, done, *rest)
-                    jax.block_until_ready(coef)
-                    loops.append((time.perf_counter() - t0) / time_steps)
-                wall_ms = sorted(loops)[1] * 1e3
-                cores = os.cpu_count() or 1
-                row["wall_ms_per_step"] = wall_ms
-                row["per_chip_ms"] = wall_ms * min(cores, p) / p
-            rows.append(row)
+            })
     return rows
 
 
 def markdown_table(rows) -> str:
-    timed = "per_chip_ms" in rows[0]
     head = (
         "| p (DP chips) | local batch | sub batch | n_flat/unit | "
         "per-chip GFLOP/step | x fall vs p=1 | p x fall (superlinear > 1/p) |"
-        + (" measured per-chip ms | time fall vs p=1 |" if timed else "")
-        + "\n|---|---|---|---|---|---|---|"
-        + ("---|---|" if timed else "")
-        + "\n"
+        "\n|---|---|---|---|---|---|---|\n"
     )
     base = rows[0]["flops_per_chip"]
-    t_base = rows[0].get("per_chip_ms")
     lines = []
     for r in rows:
         fall = base / r["flops_per_chip"] if r["flops_per_chip"] else float("nan")
-        line = (
+        lines.append(
             f"| {r['p']} | {r['local_batch']} | {r['sub_batch']} | {r['n_flat']} "
             f"| {r['flops_per_chip'] / 1e9:.2f} | {fall:.1f}x "
             f"| {fall / r['p']:.2f} |"
         )
-        if timed:
-            t_fall = t_base / r["per_chip_ms"] if r["per_chip_ms"] else float("nan")
-            line += f" {r['per_chip_ms']:.2f} | {t_fall:.1f}x |"
-        lines.append(line)
     return head + "\n".join(lines)
 
 
 if __name__ == "__main__":
     rows = measure_scaling(
         [1, 2, 4, 8], global_batch=65_536, dim=1 << 20, nnz=39, K=40,
-        time_steps=3,
     )
     print(markdown_table(rows))

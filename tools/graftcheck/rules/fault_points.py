@@ -1,7 +1,6 @@
 """fault-points: injection seams must stay tripped and tested.
 
-Absorbs ``tools/check_fault_points.py`` (PR 1) as a graftcheck rule. For every
-point in ``flink_ml_tpu.faults.FAULT_POINTS``:
+For every point in ``flink_ml_tpu.faults.FAULT_POINTS``:
 
 1. the runtime has at least one ``faults.trip("<name>", ...)`` call site under
    ``flink_ml_tpu/`` (a registered point nobody trips is dead),
@@ -122,7 +121,8 @@ def analyze(project: Project) -> Tuple[List[Tuple[str, str, int]], Dict[str, Lis
 
 
 def check(repo_root: str) -> Tuple[List[str], Dict[str, List[str]]]:
-    """The old ``tools/check_fault_points.py`` ``check()`` contract."""
+    """``(problems, trip_sites)`` with the problems as plain messages — what
+    ``tests/test_fault_points.py`` asserts on; an empty list means pass."""
     project = Project(repo_root, ["flink_ml_tpu"])
     problems, trip_sites, _ = analyze(project)
     return [p[0] for p in problems], trip_sites

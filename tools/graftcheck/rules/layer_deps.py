@@ -25,11 +25,11 @@ package's internal structure is its own business), and an import of an
 unmapped ``flink_ml_tpu`` subpackage is itself a finding so the map cannot
 silently rot.
 
-This rule generalizes and absorbs ``tools/check_servable_imports.py``: the L1
-runtime-free guarantee (servable/serving never import iteration / execution /
-builder / models, even lazily) is the ``layer(servable)=1 < layer(runtime)``
-special case. :func:`servable_violations_in_file` keeps the old tool's exact
-file-level contract for its shim and tests.
+The L1 runtime-free guarantee (servable/serving never import iteration /
+execution / builder / models, even lazily) is the
+``layer(servable)=1 < layer(runtime)`` special case of this rule.
+:func:`servable_violations_in_file` and :func:`servable_check` state it file
+by file for ``tests/test_servable_imports.py``.
 """
 from __future__ import annotations
 
@@ -135,7 +135,7 @@ MODULE_LAYERS = {
     "parallel.train_sharding": 1,
 }
 
-#: The absorbed check_servable_imports.py contract (see module docstring).
+#: The runtime-free slice of the layer map (see module docstring).
 RUNTIME_FREE_PACKAGES = ("flink_ml_tpu/servable", "flink_ml_tpu/serving")
 FORBIDDEN_PREFIXES = (
     "flink_ml_tpu.iteration",
@@ -259,7 +259,7 @@ class LayerDepsRule(Rule):
         return findings
 
 
-# -- check_servable_imports.py compatibility surface -------------------------
+# -- the runtime-free guarantee, file by file ---------------------------------
 
 
 def _forbidden(module: str) -> bool:
@@ -267,9 +267,9 @@ def _forbidden(module: str) -> bool:
 
 
 def servable_violations_in_file(path: str) -> Iterable[Tuple[int, str]]:
-    """The old tool's exact per-file semantics: (lineno, module) for every
-    import of a training-stack root, lazy (function-local) imports included;
-    relative imports skipped (the servable tier has no runtime subpackages)."""
+    """(lineno, module) for every import of a training-stack root, lazy
+    (function-local) imports included; relative imports skipped (the servable
+    tier has no runtime subpackages)."""
     with open(path, encoding="utf-8") as f:
         tree = ast.parse(f.read(), filename=path)
     for node in ast.walk(tree):
@@ -290,8 +290,8 @@ def servable_violations_in_file(path: str) -> Iterable[Tuple[int, str]]:
 
 
 def servable_check(repo_root: str) -> Tuple[List[str], List[str]]:
-    """(problems, checked_files) over the runtime-free packages — the body of
-    the old ``tools/check_servable_imports.py`` ``check()``."""
+    """(problems, checked_files) over the runtime-free packages; an empty
+    problems list means pass."""
     problems: List[str] = []
     checked: List[str] = []
     for package in RUNTIME_FREE_PACKAGES:
