@@ -14,13 +14,16 @@ be replaced by dense one-hot algebra the MXU/VPU execute at full width:
 
 - **Feature side (gather + scatter → blocked one-hot VPU sums).** The
   coefficient lives *permuted* during training as ``coef_perm [nblk, 128]``
-  (128-wide feature blocks, ordered by power-of-two occupancy class; blocks
-  of one class sit contiguously, so each per-class round slices — never
-  gathers — its coefficient rows). A batch entry with local lane ``l``
-  reads its coefficient as ``sum(onehot(l) * coef_block)`` and writes its
-  gradient through the transposed sum — both as f32 VPU broadcast-reduces
-  (~0.4-1 ns/entry measured; the equivalent einsum lowers to tiny batched
-  matvecs that run ~6x slower). Padding entries carry value 0.
+  (128-wide feature blocks, ordered by occupancy class: a power-of-two
+  width for a block with few entries, and one class of 64-slot chunks for
+  the heavy blocks; blocks of one class sit contiguously, so each per-class
+  round slices its coefficient rows, and the chunked class copies one whole
+  row per chunk — entries are never gathered). A batch entry with local
+  lane ``l`` reads its coefficient as ``sum(onehot(l) * coef_block)`` and
+  writes its gradient through the transposed sum — both as f32 VPU
+  broadcast-reduces (~0.4-1 ns/entry measured; the equivalent einsum lowers
+  to tiny batched matvecs that run ~6x slower). Padding entries carry
+  value 0.
 - **Row side (the crossing).** The forward dot needs per-entry values
   summed *by row*, and the backward pass needs the per-row loss multiplier
   broadcast *to entries* — an irreducible reindex between feature-grouped
@@ -35,7 +38,7 @@ be replaced by dense one-hot algebra the MXU/VPU execute at full width:
   with the crossing width (and its one-hot bytes) shrunk by
   ``batch / SUB_ROWS``. The sub size balances per-entry crossing cost
   (~sqrt of the sub's row space) against padding (fewer rows per sub means
-  sparser blocks and more pow2 padding); 16384 measured best of
+  sparser blocks and more padding up to a class width); 16384 measured best of
   {8192, 16384, 32768} at the Criteo shape.
 
 The crossings run two ways: a pure-XLA form (works on any backend;
@@ -51,7 +54,7 @@ and the VMEM notes below reproduce to the digit (docs/kernels.md).
 """
 from __future__ import annotations
 
-from typing import List, Optional, Tuple
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -71,6 +74,12 @@ BLOCK = 128  # feature-block width: the VPU lane count
 _BLOCK_SHIFT = 7  # idx >> 7 is the block, idx & 127 the lane, in the indices' own dtype
 assert 1 << _BLOCK_SHIFT == BLOCK
 SUB_ROWS = 16384  # sub-batch rows per crossing (gradient accumulation grain)
+# Slots of one chunk of a heavy feature block, and the entry count from which
+# a block is heavy (OneHotSparsePlan). A smaller chunk pads less and makes
+# more rows for the rounds: at the Criteo layout the whole step measured
+# 18.7 ms at 64 against 19.3 at 32 and 19.6 at 128, from 22.3 with no chunks
+# (TPU v5e; PERF.md, PR 29).
+CHUNK = 64
 _ROW_LO = 128  # row-id split minor width
 
 
@@ -114,6 +123,21 @@ class OneHotSparsePlan:
     that lets the streamed (larger-than-HBM) path run the one-hot kernel
     with ONE compilation serving every window.
 
+    **Widths.** A block whose largest count stays under ``CHUNK`` takes the
+    next power of two, and the blocks of one width form one occupancy class
+    (widths 1 to ``CHUNK``). A block that reaches ``CHUNK`` entries in some
+    unit is *heavy*: it takes ``ceil(count / CHUNK)`` chunks of ``CHUNK``
+    slots, one behind the other, and the chunks of all heavy blocks form ONE
+    class, the last, in the order of their blocks' chunk counts. Click-log
+    ids make such blocks (the popular values of small fields): a power of
+    two for each of them padded the Criteo layout by a seventh and split its
+    rounds into 15 classes (PERF.md, PR 29). Which side a block falls on is
+    read from the counting pass: data with no heavy block has no chunked
+    class, and its plan and compiled step are what they were before chunks
+    existed. Either way the plan is a function of the multiset of the
+    blocks' counts, not of which block holds which, so data sets that differ
+    by a renaming of ids share one compiled program.
+
     **Tensor parallelism** (``n_model > 1``): each occupancy class's block
     count is padded to a multiple of ``n_model`` and its blocks dealt
     round-robin to model shards, so every shard carries the SAME local
@@ -122,11 +146,17 @@ class OneHotSparsePlan:
     describe ONE shard's local layout; the coefficient lives shard-major
     (``[n_model, nblk_local * BLOCK]`` flattened) and the row-crossing dot
     assembles with a psum over the model axis (the gradient stays
-    block-local by construction).
+    block-local by construction). The shards' heavy blocks hold different
+    numbers of chunks: the chunked class is padded to the shard with the
+    most, and the rounds pick their shard's chunk-to-block map by its index
+    on the model axis.
 
-    ``class_meta``: tuple of ``(n_blocks_local, width, flat_offset,
-    block_offset)`` per pow2 occupancy class; ``perm``/``inv_perm`` map
-    block ids between original and class-major order.
+    ``class_meta``: per power-of-two class ``(n_blocks_local, width,
+    flat_offset, block_offset)``; for the chunked class, where there is one,
+    ``(n_chunks_local, CHUNK, flat_offset, block_offset, chunks_of_block)``
+    with ``chunks_of_block[shard][i]`` the chunk count of the shard's
+    ``i``-th heavy block (0 for one that pads the deal). ``perm``/
+    ``inv_perm`` map block ids between original and class-major order.
     """
 
     _FIELDS = (
@@ -166,6 +196,18 @@ class OneHotSparsePlan:
         that holds ``nblk`` (16 up to dim 2^23 - 128)."""
         return 8 * self.key_of_block.dtype.itemsize
 
+    @property
+    def chunks_of_block(self) -> tuple:
+        """Per model shard, the chunk counts of its heavy blocks; ``()`` for
+        a plan with no chunked class."""
+        last = self.class_meta[-1]
+        return last[4] if len(last) > 4 else ()
+
+    @property
+    def n_chunks(self) -> int:
+        """Chunks of heavy blocks, over all model shards (padding left out)."""
+        return sum(map(sum, self.chunks_of_block))
+
     @classmethod
     def from_max_counts(
         cls, max_count: np.ndarray, dim: int, sub_batch: int, n_model: int = 1
@@ -177,16 +219,24 @@ class OneHotSparsePlan:
                 f"({np.iinfo(np.int16).max}); use sub_rows <= 32767"
             )
         nblk = -(-dim // BLOCK)
-        occ = next_pow2(np.maximum(np.asarray(max_count, np.int64), 0))
-        occ[np.asarray(max_count) == 0] = 0  # empty blocks: zero slots
-        # (argsort puts them first in class-major order; they own no range)
-        order = np.argsort(occ, kind="stable")
+        max_count = np.maximum(np.asarray(max_count, np.int64), 0)
+        heavy = max_count >= CHUNK
+        # a light block takes the next power of two, an empty one no slot,
+        # a heavy one whole chunks
+        width = np.where(heavy, -(-max_count // CHUNK) * CHUNK, next_pow2(max_count))
+        width[max_count == 0] = 0
+        # class-major: the light classes by width (the empty blocks first;
+        # they own no range), then the heavy blocks by theirs, so that the
+        # plan is a function of the widths' multiset, whichever block holds
+        # which: data sets that differ by a renaming of ids share a program
+        order = np.argsort(width + heavy, kind="stable")
         perm = order.astype(np.int32)  # class position -> original block id
         inv_perm = np.empty(nblk, np.int32)
         inv_perm[order] = np.arange(nblk, dtype=np.int32)
-        occ_sorted = occ[order]
+        width_sorted = width[order]
+        n_light = nblk - int(heavy.sum())
 
-        class_meta: List[Tuple[int, int, int, int]] = []
+        class_meta: List[tuple] = []
         # Per class-major position p: which model shard owns the block, the
         # shard-local flat slot of its first entry, and its shard-local
         # block index. Round-robin within the class keeps every shard's
@@ -196,15 +246,36 @@ class OneHotSparsePlan:
         local_block_of_pos = np.zeros(nblk, np.int64)
         flat_off = 0  # shard-LOCAL flat offset
         block_off = 0  # shard-LOCAL block offset
-        widths, first = np.unique(occ_sorted, return_index=True)
-        ends = np.append(first[1:], nblk)
-        for wdt, p0, p1 in zip(widths, first, ends):
+        widths, first = np.unique(width_sorted[:n_light], return_index=True)
+        classes = list(zip(widths, first, np.append(first[1:], n_light)))
+        if n_light < nblk:
+            classes.append((None, n_light, nblk))  # the chunked class
+        for wdt, p0, p1 in classes:
             f_c = int(p1 - p0)
             local_f = -(-f_c // n_model)  # padded: same local count per shard
             rel = np.arange(f_c, dtype=np.int64)
             owner_of_pos[p0:p1] = (rel % n_model).astype(np.int32)
             local_block_of_pos[p0:p1] = block_off + rel // n_model
-            if wdt > 0:
+            if wdt is None:
+                # The chunked class: a shard's heavy blocks lie one behind
+                # the other, each over its own chunks, and the shards are
+                # padded to the one with the most.
+                chunks = width_sorted[p0:p1] // CHUNK
+                chunks_of_block = []
+                for o in range(n_model):
+                    mine = chunks[o::n_model]
+                    base_of_pos[p0 + o : p1 : n_model] = (
+                        flat_off + (np.cumsum(mine) - mine) * CHUNK
+                    )
+                    chunks_of_block.append(
+                        tuple(mine.tolist()) + (0,) * (local_f - len(mine))
+                    )
+                n_chunks = max(map(sum, chunks_of_block))
+                class_meta.append(
+                    (n_chunks, CHUNK, flat_off, block_off, tuple(chunks_of_block))
+                )
+                flat_off += n_chunks * CHUNK
+            elif wdt > 0:
                 # Empty (zero-width) classes own coefficient blocks but no
                 # flat slots and no class_meta round: their coefficients
                 # still live on the mesh (round-trip + regularization apply
@@ -220,7 +291,7 @@ class OneHotSparsePlan:
             dim=int(dim), nblk=nblk, nblk_local=block_off, n_model=int(n_model),
             sub_batch=int(sub_batch), n_flat=flat_off,
             class_meta=tuple(class_meta), perm=perm, inv_perm=inv_perm,
-            width_of_pos=occ_sorted.astype(np.int64),
+            width_of_pos=width_sorted,
             owner_of_pos=owner_of_pos, base_of_pos=base_of_pos,
             local_block_of_pos=local_block_of_pos,
         )
@@ -329,7 +400,7 @@ class OneHotSparsePlan:
         return (
             f"OneHotSparsePlan(dim={self.dim}, sub={self.sub_batch}, "
             f"flat={self.n_flat}, n_model={self.n_model}, "
-            f"classes={[(f, w) for f, w, _, _ in self.class_meta]})"
+            f"classes={[m[:2] for m in self.class_meta]})"
         )
 
 
@@ -411,8 +482,15 @@ class OneHotSparseLayout:
                         )
                         bounds.append((r0, r1))
 
-        with tracer.phase("train.layout.plan", CAT_INGEST):
+        with tracer.phase("train.layout.plan", CAT_INGEST) as phase:
             plan = OneHotSparsePlan.from_max_counts(max_count, dim, sub, n_model)
+            # max_sum is the floor of any width rule: n_flat over it is padding
+            phase.set_metadata(
+                classes=len(plan.class_meta),
+                chunked_blocks=sum(map(np.count_nonzero, plan.chunks_of_block)),
+                chunks=plan.n_chunks,
+                n_flat=plan.n_flat, max_sum=int(max_count.sum()),
+            )
             if max_stack_bytes is not None and plan.stack_bytes(n_units) > max_stack_bytes:
                 return None
 
@@ -469,7 +547,7 @@ class OneHotSparseLayout:
         return (
             f"OneHotSparseLayout(dim={self.dim}, shards={self.n_shards}, "
             f"windows={self.n_windows}, sub={self.n_sub}x{self.sub_batch}, "
-            f"flat={self.n_flat}, classes={[(f, w) for f, w, _, _ in self.class_meta]})"
+            f"flat={self.n_flat}, classes={[m[:2] for m in self.class_meta]})"
         )
 
 
@@ -485,7 +563,27 @@ def _lane_onehot(ids, width, dtype=jnp.bfloat16):
     return (ids[..., None] == iota).astype(dtype)
 
 
-def gather_round(coef_perm, lidx, class_meta):
+def _block_of_chunk(chunks_of_block, n_chunks: int, model_axis):
+    """``[n_chunks]`` int32: the heavy block, counted within the chunked
+    class, that each chunk of this model shard belongs to. Static and sorted;
+    a chunk that only pads the shard up to the fullest one points at the last
+    block, and holds nothing but zero-valued slots. The rounds use it on
+    whole 128-lane coefficient rows, a few thousand of them — never on
+    entries, which is the per-element memory operation this module exists to
+    avoid."""
+    ids = np.empty((len(chunks_of_block), n_chunks), np.int32)
+    for mine, counts in zip(ids, chunks_of_block):
+        mine[:] = len(counts) - 1
+        mine[: sum(counts)] = np.repeat(np.arange(len(counts)), counts)
+    if model_axis is None:
+        return jnp.asarray(ids[0])
+    # one traced program for all shards: each looks its own row up
+    return jax.lax.dynamic_index_in_dim(
+        jnp.asarray(ids), jax.lax.axis_index(model_axis), keepdims=False
+    )
+
+
+def gather_round(coef_perm, lidx, class_meta, model_axis=None):
     """Per-entry coefficient read, g[e] = coef_perm[block(e)*BLOCK + lidx[e]],
     for every sub-batch at once (``lidx`` [n_sub, n_flat] -> [n_sub, n_flat]).
 
@@ -494,13 +592,22 @@ def gather_round(coef_perm, lidx, class_meta):
     precisely so this is never a gather), reduced on the VPU in f32. The
     VPU broadcast-sum form matters: the same contraction as an einsum
     lowers to width-``wdt`` batched matvecs that run ~6x slower (measured),
-    and the VPU form is exact f32 — no bf16 split needed.
+    and the VPU form is exact f32 — no bf16 split needed. The chunked class
+    is one more such class whose rows are chunks: each reads its block's
+    coefficient row, a static sorted gather of whole rows.
     """
     parts = []
     c2 = coef_perm.reshape(-1, BLOCK)
     n_sub = lidx.shape[0]
-    for f_c, wdt, off, b0 in class_meta:
-        rows = jax.lax.slice_in_dim(c2, b0, b0 + f_c)  # [f_c, BLOCK]
+    for f_c, wdt, off, b0, *chunked in class_meta:
+        if chunked:  # [heavy blocks, BLOCK] -> [f_c chunks, BLOCK]
+            rows = jnp.take(
+                jax.lax.slice_in_dim(c2, b0, b0 + len(chunked[0][0])),
+                _block_of_chunk(chunked[0], f_c, model_axis),
+                axis=0, indices_are_sorted=True, mode="clip",
+            )
+        else:
+            rows = jax.lax.slice_in_dim(c2, b0, b0 + f_c)  # [f_c, BLOCK]
         ids = jax.lax.slice_in_dim(lidx, off, off + f_c * wdt, axis=1).reshape(
             n_sub, f_c, wdt
         )
@@ -511,14 +618,16 @@ def gather_round(coef_perm, lidx, class_meta):
     return jnp.concatenate(parts, axis=1)
 
 
-def scatter_round(u, lidx, class_meta, nblk):
+def scatter_round(u, lidx, class_meta, nblk, model_axis=None):
     """Transposed gather_round: per-entry values summed into the permuted
     gradient across every sub-batch (``u``/``lidx`` [n_sub, n_flat] ->
     [nblk * BLOCK]) — the same exact-f32 VPU broadcast-sum form, reduced
-    over the sub and width dims (the gradient accumulation)."""
+    over the sub and width dims (the gradient accumulation). The chunked
+    class's per-chunk row sums are added block by block, a sorted segment
+    sum of whole rows."""
     c2 = jnp.zeros((nblk, BLOCK), jnp.float32)
     n_sub = u.shape[0]
-    for f_c, wdt, off, b0 in class_meta:
+    for f_c, wdt, off, b0, *chunked in class_meta:
         ids = jax.lax.slice_in_dim(lidx, off, off + f_c * wdt, axis=1).reshape(
             n_sub, f_c, wdt
         )
@@ -526,9 +635,14 @@ def scatter_round(u, lidx, class_meta, nblk):
             n_sub, f_c, wdt
         )
         oh = _lane_onehot(ids, BLOCK, jnp.float32)
-        c2 = jax.lax.dynamic_update_slice(
-            c2, jnp.sum(oh * vals[..., None], axis=(0, 2)), (b0, 0)
-        )
+        sums = jnp.sum(oh * vals[..., None], axis=(0, 2))  # [f_c, BLOCK]
+        if chunked:  # [f_c chunks, BLOCK] -> [heavy blocks, BLOCK]
+            sums = jax.ops.segment_sum(
+                sums, _block_of_chunk(chunked[0], f_c, model_axis),
+                num_segments=len(chunked[0][0]),
+                indices_are_sorted=True, mode="promise_in_bounds",
+            )
+        c2 = jax.lax.dynamic_update_slice(c2, sums, (b0, 0))
     return c2.reshape(-1)
 
 
@@ -958,7 +1072,7 @@ def onehot_batch_step(
     # Every stage processes ALL sub-batches in one invocation (the sub axis
     # is just a leading batch dim) — per-invocation floors, not per-entry
     # work, dominated the per-sub form (measured).
-    g = gather_round(coef_perm, lidx_w, class_meta)  # [n_sub, n_flat]
+    g = gather_round(coef_perm, lidx_w, class_meta, model_axis)  # [n_sub, n_flat]
     q = lvals_w * g
     if premat is not None:
         oh_hi_w, oh_lo_w, wi = premat
@@ -986,5 +1100,5 @@ def onehot_batch_step(
     else:
         back = mult_cross(mult3, rhi_w, rlo_w, row_hi)
     u = lvals_w * back
-    grad = scatter_round(u, lidx_w, class_meta, nblk)
+    grad = scatter_round(u, lidx_w, class_meta, nblk, model_axis)
     return grad, loss_sum, jnp.sum(wb)

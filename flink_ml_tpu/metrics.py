@@ -51,6 +51,7 @@ class MLMetrics:
     # Tracer.phase; docs/observability.md "The fit span tree").
     TRAIN_LAYOUT_BUILDS = "ml.train.layout.builds"  # one-hot layouts built on the host, counter
     TRAIN_LAYOUT_REUSES = "ml.train.layout.reuses"  # fits answered by a cache's layout memo, counter
+    TRAIN_LAYOUT_CHUNKS = "ml.train.layout.chunks"  # chunks of heavy feature blocks in the layouts built, counter
     TRAIN_PREMAT_BUILDS = "ml.train.premat.builds"  # premat one-hot materializations on device, counter
     TRAIN_H2D_BYTES = "ml.train.h2d.bytes"  # bytes handed to device_put by caches and layouts, counter
     # The decoder LM's fit (models/lm/decoder_lm.py), counted where train.drain closes.

@@ -1399,6 +1399,9 @@ class SGD(Optimizer):
         if lay is None:
             train_data._onehot_memo = (key, None, None)
             return None, None
+        metrics.counter(
+            MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LAYOUT_CHUNKS, lay.plan.n_chunks
+        )
         # Leading stack dim over (slice, data) jointly on multi-slice meshes:
         # stacks never cross DCN.
         sh = ctx.sharding(ctx.data_axes, MODEL_AXIS)

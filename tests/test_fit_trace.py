@@ -137,6 +137,11 @@ class TestSparseFitTree:
         }
         stack_bytes = one["train.layout.alloc"]["bytes"]
         assert stack_bytes % (7 * layout["units"]) == 0  # 7 B a slot: int8 + int16 + f32
+        # the plan of uniform ids: power-of-two classes alone, slots over the floor
+        plan = one["train.layout.plan"]
+        assert plan["classes"] >= 1 and plan["chunked_blocks"] == plan["chunks"] == 0
+        assert plan["n_flat"] == stack_bytes // (7 * layout["units"])
+        assert N * K // layout["units"] <= plan["max_sum"] <= plan["n_flat"]
         assert one["train.layout_put"] == {"bytes": stack_bytes}
         assert one["train.premat"]["reused"] == 0 and one["train.premat"]["active"] == 1
         assert one["train.premat"]["bytes"] > stack_bytes
@@ -190,6 +195,33 @@ class TestSparseFitTree:
         children = [s for s in spans if s.parent_id == layout.span_id]
         assert sorted(s.name for s in children) == sorted(LAYOUT_CHILDREN)
         assert metrics.get(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LAYOUT_BUILDS) == builds + 1
+
+    def test_a_fit_with_a_crowded_block_counts_its_chunks(self, sparse_rows):
+        """A third of the entries in one block of 128 ids: the plan chunks it,
+        says so on ``train.layout.plan``, and the registry counts the chunks."""
+        from flink_ml_tpu.linalg.onehot_sparse import CHUNK
+
+        idx, y = sparse_rows
+        idx = idx.copy()
+        idx[::3, 0] = np.arange(len(idx[::3])) % 128  # ids 0..127, ahead of the row's others
+        idx[::3, 1:] = np.maximum(idx[::3, 1:], 128)
+        idx.sort(axis=1)
+        df = DataFrame.from_dict({
+            "features": [SparseVector(DIM, row, np.ones(K)) for row in idx], "label": y,
+        })
+        chunks = metrics.get(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LAYOUT_CHUNKS) or 0
+        with trace.capture() as recorder:
+            _estimator().fit(df)
+        by = _by_name(recorder.snapshot())
+        (plan,), (layout,) = by["train.layout.plan"], by["train.layout"]
+        per_unit = N // layout.attrs["units"] // 3  # a unit's rows that hold an id of the block
+        assert plan.attrs["chunked_blocks"] >= 1
+        assert plan.attrs["chunks"] >= -(-per_unit // CHUNK)
+        assert plan.attrs["max_sum"] <= plan.attrs["n_flat"] < plan.attrs["max_sum"] * 2
+        assert (
+            metrics.get(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LAYOUT_CHUNKS)
+            == chunks + plan.attrs["chunks"]
+        )
 
     def test_goodput_report_sums_to_the_fits_wall(self, sparse_fit):
         _, spans = sparse_fit

@@ -13,7 +13,9 @@ import pytest
 from flink_ml_tpu.iteration import DeviceDataCache
 from flink_ml_tpu.linalg.onehot_sparse import (
     BLOCK,
+    CHUNK,
     OneHotSparseLayout,
+    OneHotSparsePlan,
     dot_crossing_pallas,
     dot_crossing_premat_pallas,
     dot_crossing_premat_xla,
@@ -219,6 +221,229 @@ class TestCountingPlacement:
         assert plan.fill_unit(idx, val, *outs) is False
         val[3, 2] = 0.0
         assert plan.fill_unit(idx, val, *outs) is True
+
+
+# A unit's crowded blocks for the chunked-class cases: block id -> entry count,
+# one under CHUNK, the others heavy: at it, past it and far beyond it.
+HEAVY_COUNTS = {3: CHUNK - 1, 5: CHUNK, 7: CHUNK + 1, 9: 300, 11: 8377}
+HEAVY_CHUNKS = tuple(-(-c // CHUNK) for c in HEAVY_COUNTS.values() if c >= CHUNK)
+
+
+def _pow2_class_meta(max_count, n_model=1):
+    """``class_meta`` as a plan of power-of-two widths alone lays it out: the
+    whole of the plan before heavy blocks were chunked."""
+    from flink_ml_tpu.utils.arrays import next_pow2
+
+    occ = np.where(max_count > 0, next_pow2(max_count), 0)
+    meta, flat_off, block_off = [], 0, 0
+    for wdt in np.unique(occ):
+        local_f = -(-int((occ == wdt).sum()) // n_model)
+        if wdt:
+            meta.append((local_f, int(wdt), flat_off, block_off))
+            flat_off += local_f * int(wdt)
+        block_off += local_f
+    return tuple(meta), flat_off, block_off
+
+
+def _heavy_rows(rng, n_units, unit_rows, k, dim, counts=HEAVY_COUNTS):
+    """``[n_units * unit_rows, k]`` rows in which every unit of ``unit_rows``
+    rows holds exactly ``counts[b]`` entries of block ``b``; the other entries
+    spread thinly over the blocks behind the heavy ones."""
+    per_unit = unit_rows * k
+    assert sum(counts.values()) <= per_unit
+    first_light = (max(counts) + 1) * BLOCK
+    idx = np.empty((n_units, per_unit), np.int64)
+    for unit in idx:
+        ids = [b * BLOCK + rng.integers(0, BLOCK, size=c) for b, c in counts.items()]
+        rest = per_unit - sum(counts.values())
+        ids.append(rng.integers(first_light, dim, size=rest))
+        unit[:] = rng.permutation(np.concatenate(ids))
+    return idx.reshape(n_units * unit_rows, k).astype(np.int32)
+
+
+class TestChunkedClass:
+    """Blocks that reach CHUNK entries in some unit leave the power-of-two
+    classes for one class of CHUNK-slot chunks (OneHotSparsePlan)."""
+
+    @pytest.mark.parametrize("n_model", [1, 2])
+    def test_heavy_blocks_take_whole_chunks(self, n_model):
+        max_count = np.zeros(64, np.int64)
+        for b, c in HEAVY_COUNTS.items():
+            max_count[b] = c
+        light_counts = [1, 2, 3, 5, 9, 17, 33, 0, CHUNK // 2 + 1]
+        max_count[20:29] = light_counts
+        plan = OneHotSparsePlan.from_max_counts(max_count, 64 * BLOCK, 16384, n_model)
+        *light, chunked = plan.class_meta
+        # CHUNK - 1 and CHUNK / 2 + 1 round up to CHUNK and stay a power-of-two class
+        assert [m[1] for m in light] == [1 << i for i in range(CHUNK.bit_length())]
+        assert all(len(m) == 4 for m in light) and chunked[1] == CHUNK
+        by_shard = chunked[4]
+        # dealt to the shards in the order of their chunk counts
+        want = (HEAVY_CHUNKS,) if n_model == 1 else (HEAVY_CHUNKS[::2], HEAVY_CHUNKS[1::2])
+        assert by_shard == want and plan.chunks_of_block == want
+        assert chunked[0] == max(map(sum, by_shard))  # padded to the fullest shard
+        if n_model == 1:  # the light slots by hand, and whole chunks
+            light_flat = sum(1 << (c - 1).bit_length() for c in light_counts if c) + CHUNK
+            assert plan.n_flat == light_flat + sum(HEAVY_CHUNKS) * CHUNK
+        assert plan.n_flat < _pow2_class_meta(max_count, n_model)[1]
+        # which block holds which count is not in the key the program is compiled on
+        renamed = OneHotSparsePlan.from_max_counts(
+            np.random.default_rng(0).permutation(max_count), 64 * BLOCK, 16384, n_model
+        )
+        assert renamed.program_key() == plan.program_key()
+        heavy = [int(plan.inv_perm[b]) for b, c in HEAVY_COUNTS.items() if c >= CHUNK]
+        assert min(heavy) == 64 - len(heavy)  # last in class-major order
+        np.testing.assert_array_equal(
+            plan.width_of_pos[heavy], np.multiply(HEAVY_CHUNKS, CHUNK)
+        )
+
+    @pytest.mark.parametrize("n_model", [1, 2])
+    @pytest.mark.parametrize("top", [1, CHUNK // 2, CHUNK - 1])
+    def test_no_heavy_block_leaves_the_pow2_plan(self, top, n_model):
+        # the bypass: the program a plan compiles is keyed on program_key()
+        rng = np.random.default_rng(top)
+        max_count = rng.integers(0, top + 1, size=300)
+        max_count[17] = top
+        plan = OneHotSparsePlan.from_max_counts(max_count, 300 * BLOCK, 4096, n_model)
+        meta, n_flat, nblk_local = _pow2_class_meta(max_count, n_model)
+        assert plan.chunks_of_block == ()
+        assert plan.program_key() == (
+            300 * BLOCK, 300, nblk_local, n_model, 4096, n_flat, meta
+        )
+
+    @pytest.mark.parametrize("n_model", [1, 2])
+    def test_a_heavy_block_beyond_its_chunks_raises_and_writes_nothing(self, n_model):
+        rng = np.random.default_rng(9)
+        dim = 40 * BLOCK
+        idx = _heavy_rows(rng, 1, 2048, 6, dim)
+        val = np.ones(idx.shape, np.float32)
+        plan = OneHotSparseLayout.build(idx, val, dim, 1, 2048, n_model=n_model).plan
+        assert plan.width_of_pos[plan.inv_perm[9]] == -(-300 // CHUNK) * CHUNK
+        crowd = idx.copy()
+        crowd[crowd // BLOCK == 11] = 9 * BLOCK  # 8,377 more into block 9
+        outs = [
+            np.full((n_model, plan.n_flat), 7, dt) for dt in (np.int8, np.int16, np.float32)
+        ]
+        with pytest.raises(ValueError, match="per-block occupancy"):
+            plan.fill_unit(crowd, val, *outs)
+        assert all((out == 7).all() for out in outs)
+        assert plan.fill_unit(idx, val, *outs) is False
+
+    @pytest.mark.parametrize("n_model,n_shards", [(1, 1), (2, 1), (1, 2), (2, 2)])
+    def test_stacks_equal_the_per_entry_loop(self, n_model, n_shards):
+        rng = np.random.default_rng(10 * n_model + n_shards)
+        dim = 30 * BLOCK
+        counts = {b: c for b, c in HEAVY_COUNTS.items() if c <= 300}
+        idx = _heavy_rows(rng, 4 * n_shards, 128, 8, dim, counts)
+        val = rng.normal(size=idx.shape).astype(np.float32)
+        val[rng.random(idx.shape) < 0.1] = 0.0  # so a unit's counts differ
+        lay = OneHotSparseLayout.build(
+            idx, val, dim, n_shards, 256, sub_rows=128, n_model=n_model
+        )
+        assert lay.n_windows == 2 and lay.n_sub == 2 and lay.plan.chunks_of_block
+        for got, ref in zip((lay.lidx, lay.rowid, lay.lvals), _loop_stacks(idx, val, lay)):
+            np.testing.assert_array_equal(got, ref)
+        # every kept entry once, whatever the deal
+        assert np.count_nonzero(lay.lvals) == np.count_nonzero(val)
+
+    @pytest.mark.parametrize("n_sub", [1, 4])
+    @pytest.mark.parametrize("premat", [False, True])
+    def test_batch_step_matches_scatter_reference(self, premat, n_sub):
+        rng = np.random.default_rng(11 + n_sub)
+        unit_rows, k, dim = 2048, 6, 200 * BLOCK
+        idx = _heavy_rows(rng, n_sub, unit_rows, k, dim)
+        n = idx.shape[0]
+        val = rng.normal(size=(n, k)).astype(np.float32)
+        y = (rng.random(n) > 0.5).astype(np.float32)
+        w = rng.random(n).astype(np.float32)
+        lay = OneHotSparseLayout.build(idx, val, dim, 1, n, sub_rows=unit_rows)
+        assert lay.n_sub == n_sub and lay.class_meta[-1][4] == (HEAVY_CHUNKS,)
+        coef = rng.normal(size=dim).astype(np.float32)
+        rowid = jnp.asarray(lay.rowid[0, 0, 0])
+        oh = premat_row_onehots(rowid, lay.row_hi) + (0,) if premat else None
+        grad_p, ls, ws = jax.jit(
+            lambda cp, lidx, rid, lv, yb, wb: onehot_batch_step(
+                cp, lidx, rid, lv, yb, wb, BinaryLogisticLoss.INSTANCE,
+                lay.class_meta, lay.nblk_local, lay.sub_batch, lay.row_hi,
+                use_pallas=False, premat=oh,
+            )
+        )(
+            jnp.asarray(lay.permute_coef(coef)), jnp.asarray(lay.lidx[0, 0, 0]), rowid,
+            jnp.asarray(lay.lvals[0, 0, 0]), jnp.asarray(y), jnp.asarray(w),
+        )
+        ref_grad, ref_loss = _scatter_reference(idx, val, coef, y, w)
+        np.testing.assert_allclose(
+            lay.unpermute_coef(np.asarray(grad_p)), ref_grad, rtol=2e-4, atol=2e-4
+        )
+        np.testing.assert_allclose(float(ls), ref_loss, rtol=1e-4)
+        np.testing.assert_allclose(float(ws), w.sum(), rtol=1e-5)
+
+    def test_step_moves_rows_not_elements(self):
+        # the chunk map applies to whole 128-lane coefficient rows: the one
+        # gather and the one scatter-add of the step each move a row per index
+        rng = np.random.default_rng(12)
+        dim = 40 * BLOCK
+        idx = _heavy_rows(rng, 1, 2048, 6, dim)
+        lay = OneHotSparseLayout.build(idx, np.ones(idx.shape, np.float32), dim, 1, 2048)
+        n, n_chunks = idx.shape[0], lay.class_meta[-1][0]
+        jaxpr = jax.make_jaxpr(
+            lambda cp, lidx, rid, lv, yb, wb: onehot_batch_step(
+                cp, lidx, rid, lv, yb, wb, BinaryLogisticLoss.INSTANCE,
+                lay.class_meta, lay.nblk_local, lay.sub_batch, lay.row_hi,
+                use_pallas=False,
+            )
+        )(
+            jnp.zeros(lay.nblk_local * BLOCK), jnp.asarray(lay.lidx[0, 0, 0]),
+            jnp.asarray(lay.rowid[0, 0, 0]), jnp.asarray(lay.lvals[0, 0, 0]),
+            jnp.zeros(n), jnp.ones(n),
+        )
+
+        def indexed(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name in ("gather", "scatter", "scatter-add"):
+                    yield eqn
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from indexed(sub)
+
+        found = {e.primitive.name: e for e in indexed(jaxpr.jaxpr)}
+        assert sorted(found) == ["gather", "scatter-add"]
+        assert found["gather"].params["slice_sizes"] == (1, BLOCK)
+        assert found["gather"].outvars[0].aval.shape == (n_chunks, BLOCK)
+        updates = found["scatter-add"].invars[2].aval
+        assert updates.shape == (n_chunks, BLOCK)
+        assert found["scatter-add"].params["dimension_numbers"].update_window_dims == (1,)
+
+    @pytest.mark.parametrize("premat", ["on", "off"])
+    def test_tp_fit_equals_the_unsharded_fit(self, premat):
+        # 128 rows a unit, 8 entries a row: blocks 1 and 2 take about 200
+        # entries a unit each, block 0 about 30, the rest next to none
+        rng = np.random.default_rng(13)
+        n, dim = 512, 2000
+        idx = rng.integers(0, dim, size=(n, 8)).astype(np.int32)
+        crowded = rng.random(idx.shape) < 0.4
+        idx[crowded] = rng.integers(100, 3 * BLOCK, size=int(crowded.sum()))
+        cols = {
+            "indices": idx, "values": rng.normal(size=idx.shape).astype(np.float32),
+            "labels": (rng.random(n) > 0.5).astype(np.float32),
+            "weights": np.ones(n, np.float32),
+        }
+        fits = {}
+        for n_model in (1, 2):
+            with mesh_context(MeshContext(n_data=2, n_model=n_model)) as ctx:
+                sgd = SGD(
+                    max_iter=8, global_batch_size=256, tol=0.0, learning_rate=0.3,
+                    reg=0.01, elastic_net=0.5, ctx=ctx, sparse_kernel="onehot",
+                    onehot_premat=premat,
+                )
+                cache = DeviceDataCache(dict(cols), ctx=ctx)
+                coef = sgd.optimize(
+                    np.zeros(dim, np.float32), cache, BinaryLogisticLoss.INSTANCE
+                )
+                chunks = cache._onehot_memo[1].plan.chunks_of_block
+                assert len(chunks) == n_model and all(map(sum, chunks))
+                fits[n_model] = (coef, sgd.loss_history)
+        np.testing.assert_allclose(fits[2][0], fits[1][0], rtol=1e-4, atol=1e-6)
+        np.testing.assert_allclose(fits[2][1], fits[1][1], rtol=1e-5)
 
 
 class TestBatchStep:

@@ -23,6 +23,17 @@ def _sparse_data(n, d, K, seed=0):
     return {"indices": idx, "values": val, "labels": y}
 
 
+def _heavy_sparse_data(n, d, K, seed=0):
+    """``_sparse_data`` with nine entries in ten sent to the block of ids
+    256..383: a unit of 192 entries, 15% of them padding, then holds about
+    147 of them, so the block is heavy (OneHotSparsePlan) and takes chunks."""
+    cols = _sparse_data(n, d, K, seed)
+    rng = np.random.default_rng(seed + 1000)
+    crowded = rng.random((n, K)) < 0.9
+    cols["indices"][crowded] = rng.integers(256, 384, size=int(crowded.sum()))
+    return cols
+
+
 def _fill(cache, cols, chunk=40):
     n = len(cols["labels"])
     for a in range(0, n, chunk):
@@ -52,10 +63,13 @@ def test_streamed_onehot_matches_streamed_scatter(tmp_path):
     np.testing.assert_allclose(hists["onehot"], hists["scatter"], rtol=1e-3)
 
 
-def test_streamed_onehot_matches_resident_onehot():
+@pytest.mark.parametrize("make_cols,K", [(_sparse_data, 6), (_heavy_sparse_data, 16)])
+def test_streamed_onehot_matches_resident_onehot(make_cols, K):
     # 512 rows / 8 devices -> m=64; local batch 16 divides m evenly, so the
-    # streamed epochs consume exactly the resident rows and weights.
-    cols = _sparse_data(512, 2000, 6, seed=2)
+    # streamed epochs consume exactly the resident rows and weights: two
+    # windows of 32 rows a shard. The heavy rows put a chunked class into
+    # the plan both routes build.
+    cols = make_cols(512, 2000, K, seed=2)
     resident = SGD(sparse_kernel="onehot", **KW)
     want = resident.optimize(
         np.zeros(2000, np.float32), dict(cols), BinaryLogisticLoss.INSTANCE
@@ -69,6 +83,10 @@ def test_streamed_onehot_matches_resident_onehot():
     np.testing.assert_allclose(
         streamed.loss_history, resident.loss_history, rtol=1e-4
     )
+    if make_cols is _heavy_sparse_data:
+        from flink_ml_tpu.ops.optimizer import streamed_onehot_plan
+
+        assert streamed_onehot_plan(cache, 512, 8, 32, 16, 2000).chunks_of_block
 
 
 def test_streamed_onehot_ragged_tail_matches_scatter(tmp_path):
@@ -229,8 +247,9 @@ def test_streamed_onehot_multislice_matches_streamed_scatter():
         )
 
 
+@pytest.mark.parametrize("make_cols", [_sparse_data, _heavy_sparse_data])
 @pytest.mark.parametrize("n_data,n_model", [(1, 1), (2, 1), (2, 2)])
-def test_streamed_window_stacks_equal_the_resident_builds(n_data, n_model):
+def test_streamed_window_stacks_equal_the_resident_builds(n_data, n_model, make_cols):
     # The same rows through both routes: each shard's 128 rows are four
     # minibatches of 32, streamed as two windows of two minibatches. The
     # units are the same, so the global plan is the same, and window j's
@@ -241,11 +260,12 @@ def test_streamed_window_stacks_equal_the_resident_builds(n_data, n_model):
     from flink_ml_tpu.ops.optimizer import _OneHotWindowStream, streamed_onehot_plan
 
     n, dim, b, W = 128 * n_data, 3000, 32, 64
-    cols = _sparse_data(n, dim, 6, seed=20 + n_data)
+    cols = make_cols(n, dim, 6, seed=20 + n_data)
     cache = _fill(HostDataCache(), cols)
     resident = OneHotSparseLayout.build(
         cols["indices"], cols["values"], dim, n_data, b, n_model=n_model
     )
+    assert bool(resident.plan.chunks_of_block) == (make_cols is _heavy_sparse_data)
     assert resident.window_starts == [0, 32, 64, 96] and resident.n_sub == 1
     plan = streamed_onehot_plan(cache, n, n_data, W, b, dim, n_model)
     assert plan.program_key() == resident.plan.program_key()
