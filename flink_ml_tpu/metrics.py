@@ -56,7 +56,8 @@ class MLMetrics:
     TRAIN_H2D_BYTES = "ml.train.h2d.bytes"  # bytes handed to device_put by caches and layouts, counter
     # The decoder LM's fit (models/lm/decoder_lm.py), counted where train.drain closes.
     TRAIN_LM_TOKENS = "ml.train.lm.tokens"  # tokens the fit's steps consumed, counter
-    TRAIN_MOE_ROWS = "ml.train.moe.rows"  # (token, expert) rows the experts ran, counter
+    TRAIN_MOE_ROWS = "ml.train.moe.rows"  # (token, expert) rows the experts held here ran, counter
+    TRAIN_MOE_ROWS_ABSENT = "ml.train.moe.rows_absent"  # rows routed to experts held elsewhere, counter
 
     # Online-serving runtime (scope = "ml.serving[<server name>]" — see
     # docs/serving.md for the full table).

@@ -1,18 +1,48 @@
 """The decoder LM's sizes and its parameter tree, shared by the stage
-(``decoder_lm.py``) and the plain reference (``reference.py``) so that one set
-of weights can be handed to both.
+(``decoder_lm.py``) and the plain references (``reference.py``,
+``reference_zaya.py``) so that one set of weights can be handed to both.
 
-The tree: ``{"embed": [V, d], "layers": [layer, ...], "final_norm": [d],
-"lm_head": [d, V]}`` with ``layer = {"attn_norm": [d], "wq"/"wk"/"wv"/"wo":
-[d, d], "q_norm"/"k_norm": [d], "ffn_norm": [d], "router": [d, E],
-"w_gate"/"w_up": [E, d, h], "w_down": [E, h, d]}``. A matrix maps ``x @ W``
-(``[in, out]``: the transpose of a ``torch.nn.Linear`` weight).
+One stack, two kinds of block (``LMConfig.block``). The tree: ``{"embed": [V,
+d], "layers": [layer, ...], "final_norm": [d], "lm_head": [d, V]}``; a tied
+head (``LMConfig.tied``) has no ``lm_head``: the head is ``embed`` transposed.
+A matrix maps ``x @ W`` (``[in, out]``: the transpose of a ``torch.nn.Linear``
+weight). ``H`` below is the number of experts HELD here (``experts_held``, or
+all ``n_experts``): experts ``first_held .. first_held + H`` of the ``E`` the
+router chooses among.
+
+``olmoe``: ``layer = {"attn_norm": [d], "wq"/"wk"/"wv"/"wo": [d, d],
+"q_norm"/"k_norm": [d], "ffn_norm": [d], "router": [d, E], "w_gate"/"w_up":
+[H, d, h], "w_down": [H, h, d]}``.
+
+``zaya`` (compressed convolutional attention and an MLP router; the equations
+are in ``reference_zaya.py``), with ``a = n_heads * head_dim`` the attention
+latent, ``c = n_kv_heads * head_dim``, ``g = n_heads + n_kv_heads`` and ``r =
+router_width``: per sublayer ``s`` in ``attn``, ``ffn`` the norm ``s_norm [d]``
+and the residual scaling ``s_res_scale``, ``s_res_bias``, ``s_out_scale``,
+``s_out_bias`` ``[d]``; ``"wq": [d, a], "wk": [d, c], "wv1"/"wv2": [d,
+head_dim], "conv0_w": [2, a + c], "conv0_b": [a + c], "conv1_w": [2, g,
+head_dim, head_dim], "conv1_b": [g, head_dim], "k_temp": [n_kv_heads], "wo":
+[a, d]``; ``"router_in": [d, r], "router_gamma": [r]`` (layers past the first),
+``"router_norm": [r], "router_w1"/"router_w2": [r, r], "router_w3": [r, E]``;
+the experts as above.
 """
 from __future__ import annotations
 
 from typing import List, NamedTuple, Tuple
 
-__all__ = ["LMConfig", "param_shapes", "num_params"]
+__all__ = ["LMConfig", "BLOCKS", "param_shapes", "num_params", "ONES", "ZEROS", "NORMAL", "SMALL", "SMALL_SCALE"]
+
+BLOCKS = ("olmoe", "zaya")
+#: How a leaf starts: at one (norm weights, scales), at zero (biases, the
+#: router's depth-averaging weight), at ``init_std * normal``, or - the zaya
+#: block's attention output projection - at ``SMALL_SCALE * init_std * normal``.
+#: At the full width the attention sublayer's output (a causal running mean of
+#: the values, alike from one position to the next) is 30 times the 0.02-wide
+#: embedding; six blocks on, nearly every token's router input is that mean and
+#: one expert takes 5 to 6.5 times its share at step 1 (chip runs, PERF.md PR
+#: 30). At a fiftieth the two are level and what is left is the data's own skew.
+ONES, ZEROS, NORMAL, SMALL = "ones", "zeros", "normal", "small"
+SMALL_SCALE = 0.02
 
 
 class LMConfig(NamedTuple):
@@ -26,30 +56,79 @@ class LMConfig(NamedTuple):
     rope_theta: float = 10000.0
     norm_eps: float = 1e-5
     aux_coef: float = 0.01
+    block: str = "olmoe"
+    tied: bool = False
+    experts_held: int = 0  # 0: all of them
+    first_held: int = 0
+    # the zaya block's own sizes
+    n_kv_heads: int = 0  # 0: as many as query heads
+    head_size: int = 0  # 0: hidden / n_heads
+    rope_fraction: float = 1.0  # share of each head's channels RoPE turns
+    router_width: int = 0
 
     @property
     def head_dim(self) -> int:
-        return self.hidden // self.n_heads
+        return self.head_size or self.hidden // self.n_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.n_experts
 
 
-_LAYER_NORMS = ("attn_norm", "q_norm", "k_norm", "ffn_norm")
+def _expert_leaves(cfg: LMConfig):
+    d, e, h = cfg.hidden, cfg.held, cfg.expert_width
+    return (("w_gate", (e, d, h), NORMAL), ("w_up", (e, d, h), NORMAL), ("w_down", (e, h, d), NORMAL))
 
 
-def param_shapes(cfg: LMConfig) -> List[Tuple[tuple, tuple, bool]]:
-    """Every leaf as ``(path, shape, is_norm)``, in the one order the
-    initialiser numbers them by. ``path`` indexes the tree: ``("layers", 0,
-    "wq")``."""
-    d, e, h = cfg.hidden, cfg.n_experts, cfg.expert_width
-    out = [(("embed",), (cfg.vocab, d), False)]
+def _olmoe_leaves(cfg: LMConfig, i: int):
+    d = cfg.hidden
+    return (
+        ("attn_norm", (d,), ONES), ("wq", (d, d), NORMAL), ("wk", (d, d), NORMAL), ("wv", (d, d), NORMAL),
+        ("wo", (d, d), NORMAL), ("q_norm", (d,), ONES), ("k_norm", (d,), ONES), ("ffn_norm", (d,), ONES),
+        ("router", (d, cfg.n_experts), NORMAL),
+    ) + _expert_leaves(cfg)
+
+
+def _residual_scaling(sub: str, d: int):
+    return ((f"{sub}_res_scale", (d,), ONES), (f"{sub}_res_bias", (d,), ZEROS),
+            (f"{sub}_out_scale", (d,), ONES), (f"{sub}_out_bias", (d,), ZEROS))
+
+
+def _zaya_leaves(cfg: LMConfig, i: int):
+    d, hd, r = cfg.hidden, cfg.head_dim, cfg.router_width
+    a, c, g = cfg.n_heads * hd, cfg.kv_heads * hd, cfg.n_heads + cfg.kv_heads
+    gamma = (("router_gamma", (r,), ZEROS),) if i else ()  # the first layer has no layer before it
+    return (
+        (("attn_norm", (d,), ONES),) + _residual_scaling("attn", d) + (
+            ("wq", (d, a), NORMAL), ("wk", (d, c), NORMAL), ("wv1", (d, hd), NORMAL), ("wv2", (d, hd), NORMAL),
+            ("conv0_w", (2, a + c), NORMAL), ("conv0_b", (a + c,), ZEROS),
+            ("conv1_w", (2, g, hd, hd), NORMAL), ("conv1_b", (g, hd), ZEROS),
+            ("k_temp", (cfg.kv_heads,), ONES), ("wo", (a, d), SMALL),
+            ("ffn_norm", (d,), ONES),
+        ) + _residual_scaling("ffn", d) + (("router_in", (d, r), NORMAL),) + gamma + (
+            ("router_norm", (r,), ONES), ("router_w1", (r, r), NORMAL), ("router_w2", (r, r), NORMAL),
+            ("router_w3", (r, cfg.n_experts), NORMAL),
+        ) + _expert_leaves(cfg)
+    )
+
+
+_LEAVES = {"olmoe": _olmoe_leaves, "zaya": _zaya_leaves}
+
+
+def param_shapes(cfg: LMConfig) -> List[Tuple[tuple, tuple, str]]:
+    """Every leaf as ``(path, shape, init)``, in the one order the initialiser
+    numbers them by. ``path`` indexes the tree: ``("layers", 0, "wq")``;
+    ``init`` is ``ONES``, ``ZEROS``, ``NORMAL`` or ``SMALL``."""
+    out = [(("embed",), (cfg.vocab, cfg.hidden), NORMAL)]
     for i in range(cfg.n_layers):
-        for name, shape in (
-            ("attn_norm", (d,)), ("wq", (d, d)), ("wk", (d, d)), ("wv", (d, d)), ("wo", (d, d)),
-            ("q_norm", (d,)), ("k_norm", (d,)), ("ffn_norm", (d,)), ("router", (d, e)),
-            ("w_gate", (e, d, h)), ("w_up", (e, d, h)), ("w_down", (e, h, d)),
-        ):
-            out.append((("layers", i, name), shape, name in _LAYER_NORMS))
-    out.append((("final_norm",), (d,), True))
-    out.append((("lm_head",), (d, cfg.vocab), False))
+        out += [(("layers", i, name), shape, init) for name, shape, init in _LEAVES[cfg.block](cfg, i)]
+    out.append((("final_norm",), (cfg.hidden,), ONES))
+    if not cfg.tied:
+        out.append((("lm_head",), (cfg.hidden, cfg.vocab), NORMAL))
     return out
 
 

@@ -1,9 +1,24 @@
 """``DecoderLM`` - a decoder-only language-model stage that trains through
-``Estimator.fit``: pre-norm residual blocks of causal self-attention (RMSNorm,
-QK-norm, RoPE; the fused fold of ``parallel/flash.py`` forward and backward)
-and a dropless top-k mixture of SwiGLU experts (``parallel/moe.py``), an
-untied head, next-token cross-entropy plus the router's load-balancing loss.
-The block is OLMoE's (``reference.py`` carries each equation's origin).
+``Estimator.fit``: a stack of pre-norm residual blocks of causal
+self-attention (the fused fold of ``parallel/flash.py`` forward and backward)
+and a dropless mixture of SwiGLU experts (``parallel/moe.py``), a head,
+next-token cross-entropy. ``blockKind`` chooses the block; the fit loop, the
+head, the loss's chunking, the clip and the AdamW program are one:
+
+- ``olmoe`` (OLMoE's; ``reference.py`` carries each equation's origin):
+  multi-head attention with QK-norm and RoPE, a linear router with top-k
+  probabilities kept as they are, the router's load-balancing loss.
+- ``zaya`` (ZAYA1-8B's; ``reference_zaya.py``): compressed convolutional
+  attention - queries, keys and values in a latent of ``numHeads`` query heads
+  on 2 key/value heads, q and k mixed by two short causal convolutions, RoPE
+  on part of each head, the fold on grouped queries - and a router that is an
+  MLP whose hidden state each block hands the next; learned residual scaling.
+
+Either kind may tie the head to the embedding (``tieEmbeddings``: one leaf)
+and hold a range of each block's experts (``expertsHeld``,
+``firstExpertHeld``: one chip's share of an expert-parallel layer; tokens
+routed elsewhere get nothing from the block, and ``vocabSize`` is then the
+slice of the vocabulary held here).
 
 No analogue exists in the reference (SURVEY.md 2.9: no deep nets anywhere in
 the tree); the Stage contract is the reference's: ``fit`` returns a ``Model``
@@ -18,11 +33,15 @@ norm 1.0, AdamW - as ONE jitted program per minibatch with the parameters and
 the optimizer state donated. Minibatches cycle over the window like
 SGD.java:265, except that a tail which does not fill a batch is completed with
 the rows before it: a language-model step has a fixed token batch. Losses,
-gradient norms and expert loads stay on the device until the loop ends.
+gradient norms and expert loads stay on the device until the loop ends. Leaves
+that do not start at ``0.02 * normal`` start at one (norm weights, scales), at
+zero (biases) or, the zaya block's ``wo``, at a fiftieth of it: ``config.py``
+says which and why.
 
 Precision: ``computeType`` names the matmuls' input type (``bfloat16``: the
 MXU's native path, f32 accumulation); the router, the softmaxes, the norms,
-RoPE, the loss, the master weights and AdamW's state are float32 regardless.
+RoPE, the convolutions' depthwise pass, the loss, the master weights and
+AdamW's state are float32 regardless.
 
 Memory (what lets 626 M parameters and 16,384 tokens a step share one 16 GB
 chip): the experts' backward recomputes their two hidden projections from
@@ -48,8 +67,11 @@ import optax
 from flink_ml_tpu.api.core import Estimator, Model
 from flink_ml_tpu.api.types import DataTypes
 from flink_ml_tpu.metrics import MLMetrics, metrics
-from flink_ml_tpu.models.lm.config import LMConfig, num_params, param_shapes
+from flink_ml_tpu.models.lm.config import (
+    BLOCKS, NORMAL, ONES, SMALL, SMALL_SCALE, LMConfig, num_params, param_shapes,
+)
 from flink_ml_tpu.params.param import (
+    BoolParam,
     FloatParam,
     IntParam,
     ParamValidators,
@@ -106,8 +128,35 @@ class _LMParams(
     ROPE_THETA = FloatParam("ropeTheta", "Base of the rotary embedding.", 10000.0, ParamValidators.gt(0))
     NORM_EPS = FloatParam("normEps", "Epsilon of every RMSNorm.", 1e-5, ParamValidators.gt(0))
     AUX_LOSS_COEF = FloatParam(
-        "auxLossCoef", "Weight of the router's load-balancing loss.", 0.01, ParamValidators.gt_eq(0)
+        "auxLossCoef", "Weight of the router's load-balancing loss ('olmoe'; 'zaya' has none).", 0.01,
+        ParamValidators.gt_eq(0)
     )
+    BLOCK_KIND = StringParam(
+        "blockKind",
+        "The decoder block: 'olmoe' (multi-head attention with QK-norm, a linear router) or "
+        "'zaya' (compressed convolutional attention on grouped queries, an MLP router).",
+        "olmoe", ParamValidators.in_array(list(BLOCKS)),
+    )
+    TIE_EMBEDDINGS = BoolParam("tieEmbeddings", "The head is the embedding table transposed.", False)
+    EXPERTS_HELD = IntParam(
+        "expertsHeld",
+        "Experts of each block held here, a contiguous range of numExperts; the router still "
+        "chooses among all and tokens routed elsewhere get nothing from this block. 0 holds all.",
+        0, ParamValidators.gt_eq(0),
+    )
+    FIRST_EXPERT_HELD = IntParam("firstExpertHeld", "First expert of the held range.", 0,
+                                 ParamValidators.gt_eq(0))
+    NUM_KV_HEADS = IntParam(
+        "numKvHeads", "Key/value heads ('zaya'; numHeads divides evenly over them). 0: numHeads.", 0,
+        ParamValidators.gt_eq(0),
+    )
+    HEAD_SIZE = IntParam("headSize", "Channels per head ('zaya'). 0: hiddenSize / numHeads.", 0,
+                         ParamValidators.gt_eq(0))
+    ROPE_FRACTION = FloatParam(
+        "ropeFraction", "Share of each head's channels the rotary embedding turns ('zaya').", 1.0,
+        ParamValidators.in_range(0.0, 1.0, lower_inclusive=False),
+    )
+    ROUTER_WIDTH = IntParam("routerWidth", "Width of the router MLP ('zaya').", 256, ParamValidators.gt(0))
     COMPUTE_TYPE = StringParam(
         "computeType",
         "Matmul input dtype: 'bfloat16' runs every matmul and the attention "
@@ -125,14 +174,34 @@ class _LMParams(
             top_k=self.get(self.EXPERTS_PER_TOKEN), expert_width=self.get(self.EXPERT_WIDTH),
             vocab=self.get(self.VOCAB_SIZE) if vocab is None else vocab,
             rope_theta=self.get(self.ROPE_THETA), norm_eps=self.get(self.NORM_EPS),
-            aux_coef=self.get(self.AUX_LOSS_COEF),
+            aux_coef=self.get(self.AUX_LOSS_COEF), block=self.get(self.BLOCK_KIND),
+            tied=self.get(self.TIE_EMBEDDINGS), experts_held=self.get(self.EXPERTS_HELD),
+            first_held=self.get(self.FIRST_EXPERT_HELD),
         )
-        if cfg.hidden % cfg.n_heads:
+        if cfg.block == "zaya":
+            cfg = cfg._replace(
+                n_kv_heads=self.get(self.NUM_KV_HEADS), head_size=self.get(self.HEAD_SIZE),
+                rope_fraction=self.get(self.ROPE_FRACTION), router_width=self.get(self.ROUTER_WIDTH),
+                aux_coef=0.0,  # its balancing is a bias rule outside the gradient (reference_zaya.py)
+            )
+            if cfg.kv_heads != 2 or cfg.n_heads % 2:
+                raise ValueError(f"the zaya block's value shift makes two key/value heads (one of this "
+                                 f"position, one of the position before) under an even numHeads; got "
+                                 f"numKvHeads {cfg.kv_heads}, numHeads {cfg.n_heads}")
+            if int(cfg.head_dim * cfg.rope_fraction) % 2:
+                raise ValueError(f"the rotary embedding needs an even number of channels, got "
+                                 f"{cfg.rope_fraction} of {cfg.head_dim}")
+        elif self.get(self.NUM_KV_HEADS) or self.get(self.HEAD_SIZE) or self.get(self.ROPE_FRACTION) != 1.0:
+            raise ValueError("numKvHeads, headSize and ropeFraction belong to blockKind 'zaya'")
+        if not cfg.head_size and cfg.hidden % cfg.n_heads:
             raise ValueError(f"hiddenSize {cfg.hidden} must divide evenly by numHeads {cfg.n_heads}")
         if cfg.head_dim % 2:
             raise ValueError(f"the rotary embedding needs an even head size, got {cfg.head_dim}")
         if cfg.top_k > cfg.n_experts:
             raise ValueError(f"expertsPerToken {cfg.top_k} > numExperts {cfg.n_experts}")
+        if cfg.first_held + cfg.held > cfg.n_experts:
+            raise ValueError(f"experts {cfg.first_held}..{cfg.first_held + cfg.held} are not among "
+                             f"numExperts {cfg.n_experts}")
         return cfg
 
     def get_compute_type(self) -> str:
@@ -154,7 +223,10 @@ _add_accessors(_LMParams, (
     ("NUM_LAYERS", "num_layers"), ("HIDDEN_SIZE", "hidden_size"), ("NUM_HEADS", "num_heads"),
     ("NUM_EXPERTS", "num_experts"), ("EXPERTS_PER_TOKEN", "experts_per_token"),
     ("EXPERT_WIDTH", "expert_width"), ("VOCAB_SIZE", "vocab_size"), ("ROPE_THETA", "rope_theta"),
-    ("NORM_EPS", "norm_eps"), ("AUX_LOSS_COEF", "aux_loss_coef"),
+    ("NORM_EPS", "norm_eps"), ("AUX_LOSS_COEF", "aux_loss_coef"), ("BLOCK_KIND", "block_kind"),
+    ("TIE_EMBEDDINGS", "tie_embeddings"), ("EXPERTS_HELD", "experts_held"),
+    ("FIRST_EXPERT_HELD", "first_expert_held"), ("NUM_KV_HEADS", "num_kv_heads"), ("HEAD_SIZE", "head_size"),
+    ("ROPE_FRACTION", "rope_fraction"), ("ROUTER_WIDTH", "router_width"),
 ))
 
 
@@ -190,11 +262,12 @@ def _flat_names(cfg: LMConfig) -> List[str]:
 def _init_program(cfg: LMConfig):
     def init(key):
         leaves = []
-        for i, (_, shape, is_norm) in enumerate(param_shapes(cfg)):
-            if is_norm:
-                leaves.append(jnp.ones(shape, jnp.float32))
+        for i, (_, shape, kind) in enumerate(param_shapes(cfg)):
+            if kind in (NORMAL, SMALL):
+                std = INIT_STD * (SMALL_SCALE if kind == SMALL else 1.0)
+                leaves.append(std * jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32))
             else:
-                leaves.append(INIT_STD * jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32))
+                leaves.append((jnp.ones if kind == ONES else jnp.zeros)(shape, jnp.float32))
         return _build_tree(cfg, leaves)
 
     return jax.jit(init)
@@ -203,7 +276,8 @@ def _init_program(cfg: LMConfig):
 def init_params(cfg: LMConfig, seed: int) -> dict:
     """The parameter tree, made on the device from ``seed``: leaf ``i`` of
     ``param_shapes(cfg)`` is ``0.02 * normal(fold_in(key(seed), i))`` in
-    float32; norm weights are ones."""
+    float32 (the zaya block's ``wo`` a fiftieth of that); norm weights and
+    scales are ones, biases zeros (``config.py``)."""
     return _init_program(cfg)(jax.random.key(seed))
 
 
@@ -234,6 +308,24 @@ def _matmul(a, w, cd):
                    precision=_HIGHEST if cd == jnp.float32 else None)
 
 
+def _fold(q, k, v, cd, interpret: bool):
+    """Causal softmax attention of ``q [B, H, T, D]`` on ``k``, ``v`` ``[B,
+    H_kv, T, D]`` at scale ``D^-1/2`` through the fused fold: a ring of one,
+    the whole sequence is the resident KV block."""
+    b, h, t, hd = q.shape
+    m0 = jnp.full((b, h, t), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros((b, h, t), jnp.float32)
+    acc0 = jnp.zeros((b, h, t, hd), jnp.float32)
+    _, l, acc = fused_fold(q.astype(cd), k.astype(cd), v.astype(cd), m0, l0, acc0, jnp.int32(0),
+                           jnp.int32(0), True, False, jnp.int32(0), float(hd) ** -0.5, interpret)
+    return acc / l[..., None]  # causal: every row attends at least to itself, l > 0
+
+
+def _heads(z, n: int):
+    """``[B, T, n * D]`` (or ``[B, T, n, D]``) ``-> [B, n, T, D]``, the fold's layout."""
+    return jnp.transpose(z.reshape(*z.shape[:2], n, -1), (0, 2, 1, 3))
+
+
 def _attention(x, layer, cfg: LMConfig, cd, interpret: bool):
     b, t, d = x.shape
     h, hd = cfg.n_heads, cfg.head_dim
@@ -241,46 +333,131 @@ def _attention(x, layer, cfg: LMConfig, cd, interpret: bool):
     q = _rms_norm(_matmul(a, layer["wq"], cd), layer["q_norm"], cfg.norm_eps)
     k = _rms_norm(_matmul(a, layer["wk"], cd), layer["k_norm"], cfg.norm_eps)
     v = _matmul(a, layer["wv"], cd)
-
-    def heads(z):  # [B, T, d] -> [B, H, T, D], the fold's layout
-        return jnp.transpose(z.reshape(b, t, h, hd), (0, 2, 1, 3))
-
     cos, sin = _rope_tables(t, hd, cfg.rope_theta)
-    q, k, v = _rope(heads(q), cos, sin), _rope(heads(k), cos, sin), heads(v)
-    # a ring of one: the whole sequence is the resident KV block
-    m0 = jnp.full((b, h, t), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((b, h, t), jnp.float32)
-    acc0 = jnp.zeros((b, h, t, hd), jnp.float32)
-    _, l, acc = fused_fold(q.astype(cd), k.astype(cd), v.astype(cd), m0, l0, acc0, jnp.int32(0),
-                           jnp.int32(0), True, False, jnp.int32(0), float(hd) ** -0.5, interpret)
-    o = acc / l[..., None]  # causal: every row attends at least to itself, l > 0
+    o = _fold(_rope(_heads(q, h), cos, sin), _rope(_heads(k, h), cos, sin), _heads(v, h), cd, interpret)
     o = jnp.transpose(o, (0, 2, 1, 3)).reshape(b, t, d)
     return _matmul(o, layer["wo"], cd)
 
 
-def _block(x, layer, cfg: LMConfig, cd, interpret: bool):
+def _olmoe_block(x, carry, layer, cfg: LMConfig, cd, interpret: bool):
     b, t, d = x.shape
     x = x + _attention(x, layer, cfg, cd, interpret)
     u = _rms_norm(x, layer["ffn_norm"], cfg.norm_eps).reshape(b * t, d)
     y, stats = moe_dropless(u, layer["router"], layer["w_gate"], layer["w_up"], layer["w_down"],
-                            cfg.top_k, cd)
-    return x + y.reshape(b, t, d), stats
+                            cfg.top_k, cd, cfg.first_held)
+    return x + y.reshape(b, t, d), carry, stats
+
+
+# -- the zaya block (reference_zaya.py carries each equation's origin) ----------
+
+
+def _before(z):
+    """``z [B, T, ...]`` one position earlier: ``out[t] = z[t - 1]``, zero at 0."""
+    return jnp.pad(z[:, :-1], ((0, 0), (1, 0)) + ((0, 0),) * (z.ndim - 2))
+
+
+def _unit_heads(z, eps):
+    """Each head of ``z [..., D]`` at length ``sqrt(D)``."""
+    return z * jax.lax.rsqrt(jnp.mean(z * z, axis=-1, keepdims=True) + eps)
+
+
+def _rope_part(x, cos, sin):
+    """RoPE on the first ``cos.shape[-1]`` channels of each head of ``x [B, H, T, D]``."""
+    rot = cos.shape[-1]
+    return x if rot == 0 else jnp.concatenate([_rope(x[..., :rot], cos, sin), x[..., rot:]], axis=-1)
+
+
+def _cca(x, layer, cfg: LMConfig, cd, interpret: bool):
+    """Compressed convolutional attention: queries, keys and values projected
+    into a latent of ``n_heads`` (``kv_heads``) heads, q and k mixed there by
+    two short causal convolutions, attention and its output in the latent, one
+    projection back."""
+    b, t, _ = x.shape
+    h, kv, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    group = h // kv
+    a = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+    q0 = _matmul(a, layer["wq"], cd).reshape(b, t, kv, group, hd)
+    k0 = _matmul(a, layer["wk"], cd).reshape(b, t, kv, 1, hd)
+    # the two convolutions over the sequence, kernel 2: per channel, then per head
+    z = jnp.concatenate([q0.reshape(b, t, -1), k0.reshape(b, t, -1)], axis=-1)
+    w0, w1 = layer["conv0_w"], layer["conv1_w"]
+    z1 = (w0[0] * _before(z) + w0[1] * z + layer["conv0_b"]).reshape(b, t, h + kv, hd)
+
+    def per_head(zz, u):  # heads lead: the one batched form every backend's dot takes in bfloat16
+        zz = jnp.moveaxis(zz.reshape(b * t, h + kv, hd), 1, 0)
+        out = jnp.einsum("gni,gio->gno", zz.astype(cd), u.astype(cd), preferred_element_type=jnp.float32,
+                         precision=_HIGHEST if cd == jnp.float32 else None)
+        return jnp.moveaxis(out, 0, 1).reshape(b, t, h + kv, hd)
+
+    z2 = per_head(_before(z1), w1[0]) + per_head(z1, w1[1]) + layer["conv1_b"]
+    # the q-k mean: each query head with its key head, each key head with its query heads' mean
+    q = z2[:, :, :h] + ((q0 + k0) / 2).reshape(b, t, h, hd)
+    k = z2[:, :, h:] + ((jnp.mean(q0, axis=3, keepdims=True) + k0) / 2).reshape(b, t, kv, hd)
+    q = _unit_heads(q, cfg.norm_eps)
+    k = _unit_heads(k, cfg.norm_eps) * layer["k_temp"][:, None]
+    v = jnp.stack([_matmul(a, layer["wv1"], cd), _matmul(_before(a), layer["wv2"], cd)], axis=2)
+    cos, sin = _rope_tables(t, int(hd * cfg.rope_fraction), cfg.rope_theta)
+    o = _fold(_rope_part(_heads(q, h), cos, sin), _rope_part(_heads(k, kv), cos, sin), _heads(v, kv),
+              cd, interpret)
+    return _matmul(jnp.transpose(o, (0, 2, 1, 3)).reshape(b, t, h * hd), layer["wo"], cd)
+
+
+def _router_state(u, layer, carry):
+    """The router's hidden state ``[N, r]`` in float32: this block's projection
+    plus ``router_gamma`` times the block before's (depth averaging)."""
+    r = jnp.dot(u, layer["router_in"], precision=_HIGHEST)
+    return r if carry is None else r + layer["router_gamma"] * carry
+
+
+def _router_logits(r, layer, eps):
+    n = _rms_norm(r, layer["router_norm"], eps)
+    for w in ("router_w1", "router_w2"):
+        n = jax.nn.gelu(jnp.dot(n, layer[w], precision=_HIGHEST), approximate=False)
+    return jnp.dot(n, layer["router_w3"], precision=_HIGHEST)
+
+
+def _scaled(x, y, layer, sub: str):
+    """The learned residual scaling: a per-channel scale and bias on the
+    residual and on the sublayer's output."""
+    return (layer[f"{sub}_res_scale"] * x + layer[f"{sub}_res_bias"]
+            + layer[f"{sub}_out_scale"] * y + layer[f"{sub}_out_bias"])
+
+
+def _zaya_block(x, carry, layer, cfg: LMConfig, cd, interpret: bool):
+    b, t, d = x.shape
+    x = _scaled(x, _cca(x, layer, cfg, cd, interpret), layer, "attn")
+    u = _rms_norm(x, layer["ffn_norm"], cfg.norm_eps).reshape(b * t, d)
+    carry = _router_state(u, layer, carry)  # handed on to the next block's router
+    y, stats = moe_dropless(u, lambda _: _router_logits(carry, layer, cfg.norm_eps), layer["w_gate"],
+                            layer["w_up"], layer["w_down"], cfg.top_k, cd, cfg.first_held)
+    return _scaled(x, y.reshape(b, t, d), layer, "ffn"), carry, stats
+
+
+_BLOCKS = {"olmoe": _olmoe_block, "zaya": _zaya_block}
 
 
 def _hidden(params, tok, cfg: LMConfig, cd, interpret: bool):
     """The final-normed hidden states ``[B, T, d]`` and each block's router
-    statistics."""
+    statistics. ``carry`` is what a block hands the next beside the residual
+    stream: nothing (``olmoe``), the router's hidden state (``zaya``)."""
     x = params["embed"][tok]
-    block = functools.partial(_block, cfg=cfg, cd=cd, interpret=interpret)
+    block = functools.partial(_BLOCKS[cfg.block], cfg=cfg, cd=cd, interpret=interpret)
     if cfg.n_layers > 1:
         # a lone block's residuals are wanted as soon as the head's backward
         # ends: holding them costs nothing at the peak, recomputing them a forward
         block = jax.checkpoint(block)
-    routed = []
+    routed, carry = [], None
     for layer in params["layers"]:
-        x, stats = block(x, layer)
+        x, carry, stats = block(x, carry, layer)
         routed.append(stats)
     return _rms_norm(x, params["final_norm"], cfg.norm_eps), routed
+
+
+def _head(params, cfg: LMConfig):
+    """The head ``[d, V]``: its own matrix, or the embedding table transposed
+    (one leaf, whose gradient is then the lookup's scatter plus the head's
+    matmuls)."""
+    return params["embed"].T if cfg.tied else params["lm_head"]
 
 
 def _next_token_nll(h, lm_head, tok, cd):
@@ -316,9 +493,10 @@ def _load_balancing(routed, cfg: LMConfig):
 
 def _loss(params, tok, cfg: LMConfig, cd, interpret: bool):
     h, routed = _hidden(params, tok, cfg, cd, interpret)
-    nll = _next_token_nll(h, params["lm_head"], tok, cd)
-    ce = jnp.sum(nll) / (tok.shape[0] * (tok.shape[1] - 1))
-    loss = ce + cfg.aux_coef * _load_balancing(routed, cfg)
+    nll = _next_token_nll(h, _head(params, cfg), tok, cd)
+    loss = jnp.sum(nll) / (tok.shape[0] * (tok.shape[1] - 1))
+    if cfg.aux_coef:
+        loss = loss + cfg.aux_coef * _load_balancing(routed, cfg)
     return loss, jnp.stack([s["rows"] for s in routed])
 
 
@@ -354,7 +532,7 @@ def _log_likelihood_program(cfg: LMConfig, compute_type: str, interpret: bool):
 
     def run(params, tok):
         h, _ = _hidden(params, tok, cfg, cd, interpret)
-        nll = _next_token_nll(h, params["lm_head"], tok, cd)
+        nll = _next_token_nll(h, _head(params, cfg), tok, cd)
         return -jnp.sum(nll, axis=1) / (tok.shape[1] - 1)
 
     return jax.jit(run)
@@ -496,12 +674,19 @@ class DecoderLM(Estimator, _LMParams):
         routed = steps * batch * t * cfg.top_k * cfg.n_layers
         with tracer.phase("train.drain", CAT_PRODUCTIVE, steps=steps) as phase:
             loads = np.asarray(jax.device_get(jnp.stack(loads)))  # [steps, layers, experts]
+            held = loads[:, :, cfg.first_held: cfg.first_held + cfg.held]
+            rows_held = int(held.sum())
+            rows_absent = int(loads.sum()) - rows_held
             per_block = batch * t * cfg.top_k
             phase.set_metadata(
                 tokens=steps * batch * t,
                 expert_rows_max=int(loads.max()),
                 expert_rows_mean=per_block // cfg.n_experts,
                 dropped=int(routed - loads.sum()),
+                rows_held=rows_held,
+                rows_absent=rows_absent,
+                held_rows_max=int(held.max()),
+                held_rows_mean=float(held.mean()),
             )
         with tracer.phase("train.readback", CAT_READBACK, bytes=4 * steps * (1 + len(param_shapes(cfg)))):
             self.loss_history = [float(x) for x in jax.device_get(losses)]
@@ -510,7 +695,8 @@ class DecoderLM(Estimator, _LMParams):
         self.grad_norm_history = [float(x) for x in np.sqrt((self.param_grad_norm_history ** 2).sum(axis=1))]
         self.expert_rows_history = loads
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_TOKENS, steps * batch * t)
-        metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_MOE_ROWS, int(loads.sum()))
+        metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_MOE_ROWS, rows_held)
+        metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_MOE_ROWS_ABSENT, rows_absent)
 
         model = DecoderLMModel()
         update_existing_params(model, self)

@@ -24,6 +24,16 @@ edges) run as two Pallas kernels: a dq-kernel owning full score rows
 owning score columns with Q-axis grid accumulation. ``jax.grad`` through
 ring attention is therefore exact and never materializes scores in HBM.
 
+Grouped queries: ``q`` is ``[B, H, T, D]``; ``kb``/``vb`` are ``[B, H_kv, T,
+D]`` with ``H`` a multiple of ``H_kv`` (equal for multi-head attention). Query
+head ``h`` reads key/value block ``h // (H / H_kv)`` through the kernels'
+index maps, so K and V are never repeated in HBM, consecutive grid cells of
+one group reuse the block they staged, and the dkv-kernel sums ``dk``, ``dv``
+over the group's query heads on its accumulation axis: they come out ``[B,
+H_kv, Tk, D]``. With ``H_kv = H`` the index maps and the grid are what they
+were. ``reference_fold`` keeps equal head counts: a grouped caller is tested
+against it on K and V repeated.
+
 Availability: TPU compiled, or any backend under ``interpret=True``. The
 caller (``ring.py``) falls back to the jnp fold when the local length does
 not tile or the devices have no Mosaic backend.
@@ -48,7 +58,10 @@ TQ_TILE = 256  # Q rows per grid cell
 
 _KV_VMEM_BUDGET = 1 << 20  # Tk*D f32 elements the kernel may stage per head
 # T=8192 (with D=128, so T*D == _KV_VMEM_BUDGET) is the largest shape whose
-# Mosaic compilation is verified on hardware; every admitted (T, D) then has
+# Mosaic compilation is verified on hardware: the forward there since the ring
+# tests, all three kernels in a TRAINING graph since models/lm trained ZAYA1-8B's
+# block on the chip at 2 x 8 query heads on 2 key/value heads x 8,192 x 128 in
+# bfloat16 (PERF.md, PR 30). Every admitted (T, D) then has
 # score-buffer and KV footprints <= that shape's in all three kernels. 16384
 # admitted shapes (e.g. T=16384, D=64) stage [TQ_TILE, 16384] f32 scores plus
 # full KV — past the scoped-VMEM limit on paper and never compile-checked on
@@ -73,8 +86,12 @@ def flash_available(T: int, D: int, devices=None) -> bool:
 # resident K and V (double-buffered) beside its [tile, Tk] f32 score buffers:
 # at T=4096, D=128 that is 16.5 MB in the forward and 20.4 MB in the dkv
 # kernel, past Mosaic's default scoped limit of 16 MB whatever batch*heads is
-# (the compile fails "allocating on stack" for the kernel's custom call). A
-# v5e core has 128 MiB of VMEM; 96 MiB covers every shape ``flash_available``
+# (the compile fails "allocating on stack" for the kernel's custom call). At
+# T=8192, D=128 in bfloat16 the training graph's three kernels compile for the
+# chip with the limit at 24 MB and not at 16 (K and V are 2 MB each, twice for
+# the double buffer; the forward's [256, 8192] f32 scores are 8 MB a buffer, the
+# dq kernel's [64, 8192] 2 MB, the dkv kernel's [2048, 256] 2 MB whatever T is).
+# A v5e core has 128 MiB of VMEM; 96 MiB covers every shape ``flash_available``
 # admits and leaves XLA its own share.
 _VMEM_LIMIT_BYTES = 96 << 20
 
@@ -92,8 +109,9 @@ def _compiler_params():
 # [B*H, T, D] outputs in VMEM; compiled for the chip at 4 x 16 x 4096 x 128
 # the failure is the kernels' own scoped allocation above, which depends on
 # (T, D) alone, and ``_VMEM_LIMIT_BYTES`` cures it (models/lm trains there on
-# the fused fold and does not ask this gate). The classifier keeps its tested
-# envelope until its larger shapes (T=8192) are run on a chip: ROADMAP S5.
+# the fused fold and does not ask this gate, at T=4096 and since PR 30 at
+# T=8192). The classifier keeps its tested envelope until its own larger
+# shapes are run on a chip: ROADMAP S5.
 _TRAIN_OUT_VMEM_BUDGET = 9 << 20
 
 
@@ -106,11 +124,23 @@ def flash_train_available(T: int, D: int, batch: int, n_heads: int, devices=None
     return batch * n_heads * T * (D + 2) * 4 <= _TRAIN_OUT_VMEM_BUDGET
 
 
+def _kv_block_of(n_heads: int, n_kv_heads: int):
+    """Grouped queries: which row of the flattened ``[B * H_kv, Tk, D]`` keys a
+    row ``i`` of the flattened ``[B * H, ...]`` queries reads. Query head ``h``
+    reads key/value head ``h // group``, and ``B * H`` is whole groups, so it is
+    ``i // group``; one head a group is the identity, left out of the index map."""
+    if n_heads % n_kv_heads:
+        raise ValueError(f"{n_heads} query heads do not divide over {n_kv_heads} key/value heads")
+    group = n_heads // n_kv_heads
+    return (lambda i: i) if group == 1 else (lambda i: i // group)
+
+
 def reference_fold(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale):
     """The jnp fold in [B, H, ...] layout (ring.py numerics) — the source of
     truth the kernel is tested against and the backward recomputes through.
 
-    ``q`` [B, H, Tq, D]; ``kb``/``vb`` [B, H, Tk, D]; ``m``/``l`` [B, H, Tq];
+    ``q`` [B, H, Tq, D]; ``kb``/``vb`` [B, H, Tk, D] (equal head counts here;
+    the kernels also take ``[B, H_kv, Tk, D]``); ``m``/``l`` [B, H, Tq];
     ``acc`` [B, H, Tq, D]. ``q_pos0``/``k_pos0`` are the global positions of
     query/key 0 (traced scalars); ``n_valid`` masks keys at global positions
     >= it (None = unmasked).
@@ -143,8 +173,9 @@ def _fold_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, Tq, D = q.shape
-    Tk = kb.shape[2]
+    Hkv, Tk = kb.shape[1], kb.shape[2]
     BH = B * H
+    kv_of = _kv_block_of(H, Hkv)
     masked = n_valid is not None
 
     def kernel(scalars_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
@@ -199,7 +230,7 @@ def _fold_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
     tile3 = pl.BlockSpec(
         (1, TQ_TILE, D), lambda i, j, *_: (i, j, 0), memory_space=pltpu.VMEM
     )
-    full3 = pl.BlockSpec((1, Tk, D), lambda i, j, *_: (i, 0, 0), memory_space=pltpu.VMEM)
+    full3 = pl.BlockSpec((1, Tk, D), lambda i, j, *_: (kv_of(i), 0, 0), memory_space=pltpu.VMEM)
     from flink_ml_tpu.parallel.mesh import vma_of
 
     vma = vma_of(q)
@@ -222,8 +253,8 @@ def _fold_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
     )(
         scalars,
         q.reshape(BH, Tq, D),
-        kb.reshape(BH, Tk, D),
-        vb.reshape(BH, Tk, D),
+        kb.reshape(B * Hkv, Tk, D),
+        vb.reshape(B * Hkv, Tk, D),
         m.reshape(BH, Tq, 1),
         l.reshape(BH, Tq, 1),
         acc.reshape(BH, Tq, D),
@@ -234,8 +265,9 @@ def _fold_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 11, 12))
 def fused_fold(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, has_n_valid,
                n_valid, scale, interpret=False):
-    """One ring-attention fold, fused. Same contract as ``reference_fold``
-    (``n_valid`` is a traced scalar consumed only when ``has_n_valid``);
+    """One ring-attention fold, fused. Same contract as ``reference_fold``,
+    and ``kb``/``vb`` may carry fewer heads than ``q`` (grouped queries: the
+    module docstring) (``n_valid`` is a traced scalar consumed only when ``has_n_valid``);
     the primal runs the Pallas forward kernel and gradients run the fused
     backward kernels (``_fold_bwd_pallas``, AD-exact).
     ``causal``/``has_n_valid``/``scale``/``interpret`` are static.
@@ -335,14 +367,17 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
     from flink_ml_tpu.parallel.mesh import vma_of
 
     B_, H, Tq, D = q.shape
-    Tk = kb.shape[2]
-    BH = B_ * H
+    Hkv, Tk = kb.shape[1], kb.shape[2]
+    BH, BHkv = B_ * H, B_ * Hkv
+    group = H // Hkv
+    kv_of = _kv_block_of(H, Hkv)
     masked = n_valid is not None
     # tiles clamp to the largest 256-aligned divisor of the actual dims
     # (flash_available guarantees T % 256 == 0, so these always divide)
     tq_bwd = min(_TQ_BWD, Tq)
     tk_bwd = min(_TK_BWD, Tk)
     tq_dkv = next(c for c in (_TQ_DKV, 1024, 512, 256, Tq) if Tq % c == 0)
+    n_q_dkv = Tq // tq_dkv
 
     def mask_of(q_pos, k_pos):
         keep = jnp.ones(q_pos.shape, bool)
@@ -408,10 +443,14 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
 
     def dkv_kernel(scalars_ref, k_ref, v_ref, q_ref, dacc_ref, dl_ref,
                    safe_ref, b_ref, dbc_ref, dko_ref, dvo_ref):
-        # grid (BH, ktiles, qtiles): the q axis is the innermost accumulation
+        # grid (B*H_kv, ktiles, group*qtiles): the innermost axis walks the q
+        # tiles of each query head of the group in turn; it is the accumulation
         # dim — dk/dv blocks are revisited across it and accumulated in VMEM.
         jk = pl.program_id(1)
         jq = pl.program_id(2)
+        first = jq == 0
+        if group > 1:
+            jq = jq % n_q_dkv
         s_col = jax.lax.dot_general(
             q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -447,7 +486,7 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
             preferred_element_type=jnp.float32,
         )
 
-        @pl.when(jq == 0)
+        @pl.when(first)
         def _():
             dko_ref[0] = jnp.zeros_like(dko_ref[0])
             dvo_ref[0] = jnp.zeros_like(dvo_ref[0])
@@ -470,14 +509,14 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
     def mat(tile):
         return pl.BlockSpec((1, tile, D), lambda i, j, *_: (i, j, 0), memory_space=pltpu.VMEM)
 
-    fullk_mat = pl.BlockSpec((1, Tk, D), lambda i, j, *_: (i, 0, 0), memory_space=pltpu.VMEM)
+    fullk_mat = pl.BlockSpec((1, Tk, D), lambda i, j, *_: (kv_of(i), 0, 0), memory_space=pltpu.VMEM)
 
     def sds(shape):
         return jax.ShapeDtypeStruct(shape, jnp.float32, vma=vma)
 
     q4 = q.reshape(BH, Tq, D)
-    k4 = kb.reshape(BH, Tk, D)
-    v4 = vb.reshape(BH, Tk, D)
+    k4 = kb.reshape(BHkv, Tk, D)
+    v4 = vb.reshape(BHkv, Tk, D)
     dacc4 = dacc.reshape(BH, Tq, D)
     dl4 = dl.reshape(BH, Tq, 1)
     dq_o, dm_o, dl_o, dacc_o, safe_r, b_r, dbc_r = pl.pallas_call(
@@ -512,21 +551,24 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
     kmat = pl.BlockSpec(
         (1, tk_bwd, D), lambda i, jk, jq, *_: (i, jk, 0), memory_space=pltpu.VMEM
     )
-    qmat = pl.BlockSpec(
-        (1, tq_dkv, D), lambda i, jk, jq, *_: (i, jq, 0), memory_space=pltpu.VMEM
-    )
-    qcol = pl.BlockSpec(
-        (1, tq_dkv, 1), lambda i, jk, jq, *_: (i, jq, 0), memory_space=pltpu.VMEM
-    )
+    if group == 1:
+        def q_block(i, jk, jq, *_):
+            return (i, jq, 0)
+    else:
+        def q_block(i, jk, jq, *_):  # the group's query heads one after another
+            return (i * group + jq // n_q_dkv, jq % n_q_dkv, 0)
+
+    qmat = pl.BlockSpec((1, tq_dkv, D), q_block, memory_space=pltpu.VMEM)
+    qcol = pl.BlockSpec((1, tq_dkv, 1), q_block, memory_space=pltpu.VMEM)
     dk_o, dv_o = pl.pallas_call(
         dkv_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(BH, Tk // tk_bwd, Tq // tq_dkv),
+            grid=(BHkv, Tk // tk_bwd, group * n_q_dkv),
             in_specs=[kmat, kmat, qmat, qmat, qcol, qcol, qcol, qcol],
             out_specs=[kmat, kmat],
         ),
-        out_shape=[sds((BH, Tk, D)), sds((BH, Tk, D))],
+        out_shape=[sds((BHkv, Tk, D)), sds((BHkv, Tk, D))],
         interpret=interpret,
         compiler_params=_compiler_params(),
         name="flash_fold_bwd_dkv",
@@ -534,8 +576,8 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
 
     return (
         dq_o.reshape(B_, H, Tq, D),
-        dk_o.reshape(B_, H, Tk, D),
-        dv_o.reshape(B_, H, Tk, D),
+        dk_o.reshape(B_, Hkv, Tk, D),
+        dv_o.reshape(B_, Hkv, Tk, D),
         dm_o.reshape(B_, H, Tq),
         dl_o.reshape(B_, H, Tq),
         dacc_o.reshape(B_, H, Tq, D),

@@ -27,8 +27,24 @@ loss: ``f`` (per expert, the (token, slot) choices that fell on it over the
 number of tokens - it sums to ``k``), ``P`` (the mean router probability) and
 ``rows`` (the group sizes; their sum is ``tokens x k``, always).
 
-Expert parallelism over chips (the all-to-all exchange of the rows) is not
-here: it returns with the four-chip cell it can be measured in (ROADMAP R3).
+The router is handed in: a matrix ``[d, E]`` (OLMoE's) or a function from the
+tokens to their logits (ZAYA's MLP, closed over the state the block before
+handed it); top-1 is ``k = 1``, the winner's probability the gate.
+
+The held range, what expert parallelism asks of this layer anyway: told which
+experts it holds (``w_gate``/``w_up``/``w_down`` carry ``H`` experts,
+``first_held .. first_held + H`` of the router's ``E``), it routes over all
+``E`` and computes the part of the result that its own experts give. Rows
+routed elsewhere sort past the last group and give, and are given, exactly
+zero (``_in_a_group`` guards every grouped matmul's result, whatever the
+kernel leaves past its groups); ``rows`` still counts all ``E`` experts, so
+``routed = held + absent``. With every expert held and a matrix router this
+is, operation for operation, the layer it was before it knew of a range. The
+parts that all the shares give add up to the uncut layer
+(``tests/test_decoder_lm_zaya.py``). The exchange itself (the all-to-all that
+brings the other chips' rows here and takes these rows' results back) is not
+here: on one chip the layer runs without it, and nothing stands in for the
+absent chips (ROADMAP, Reach queue).
 """
 from __future__ import annotations
 
@@ -43,10 +59,14 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def route_top_k(x, router, k: int):
-    """Float32 routing of ``x [t, d]`` through ``router [d, E]``: the softmax
-    over all experts ``p [t, E]``, and the ``k`` largest of each row as
-    ``(top_p, top_e) [t, k]`` (ties go to the lower expert id)."""
-    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32), precision=_HIGHEST)
+    """Float32 routing of ``x [t, d]``: the softmax over all experts ``p [t,
+    E]``, and the ``k`` largest of each row as ``(top_p, top_e) [t, k]`` (ties
+    go to the lower expert id). ``router`` is a matrix ``[d, E]`` (logits ``x @
+    router``) or a function ``x -> logits [t, E]`` in float32."""
+    if callable(router):
+        logits = router(x)
+    else:
+        logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32), precision=_HIGHEST)
     p = jax.nn.softmax(logits, axis=-1)
     top_p, top_e = jax.lax.top_k(p, k)
     return p, top_p, top_e
@@ -94,73 +114,109 @@ def _swiglu_hidden(xs, wg, wu, group_sizes, cd, precision):
     return gate, up
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _expert_swiglu(xs, w_gate, w_up, w_down, group_sizes, compute_dtype):
+def _in_a_group(a, group_sizes):
+    """``a`` with the rows past the last group set to zero: where the groups do
+    not cover the rows (some experts are held elsewhere) a grouped matmul
+    leaves those rows to chance."""
+    covered = jnp.arange(a.shape[0], dtype=jnp.int32) < jnp.sum(group_sizes)
+    return jnp.where(covered[:, None], a, jnp.zeros((), a.dtype))
+
+
+def _row_guard(group_sizes, covered: bool):
+    if covered:
+        return lambda a: a
+    return functools.partial(_in_a_group, group_sizes=group_sizes)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _expert_swiglu(xs, w_gate, w_up, w_down, group_sizes, compute_dtype, covered=True):
     """``down(silu(gate(xs)) * up(xs))`` over rows ``xs [r, d]`` grouped by
     expert, in and out in the compute type (the grouped matmuls accumulate in
     f32). The weights come in as the f32 masters: the VJP recomputes ``gate``
     and ``up`` from the sorted rows instead of holding three ``[r, width]``
     tensors, and hands back each ``dW`` in f32 straight from the grouped
     matmul that made it (AD through ``w.astype(bf16)`` would round it to
-    bfloat16 on the way)."""
+    bfloat16 on the way). ``covered`` false: the groups may end before the
+    rows do (the rows of experts held elsewhere, sorted last); every grouped
+    matmul's result is then zeroed past the groups, so those rows give and are
+    given exactly zero whatever the kernel leaves there."""
     cd = jnp.dtype(compute_dtype)
     precision = _HIGHEST if cd == jnp.float32 else None
+    guard = _row_guard(group_sizes, covered)
     gate, up = _swiglu_hidden(xs, w_gate.astype(cd), w_up.astype(cd), group_sizes, cd, precision)
-    hidden = (jax.nn.silu(gate) * up).astype(cd)
-    return _grouped(hidden, w_down.astype(cd), group_sizes, _ROWS, cd, precision)
+    hidden = (jax.nn.silu(guard(gate)) * guard(up)).astype(cd)
+    return guard(_grouped(hidden, w_down.astype(cd), group_sizes, _ROWS, cd, precision))
 
 
-def _expert_swiglu_fwd(xs, w_gate, w_up, w_down, group_sizes, compute_dtype):
-    out = _expert_swiglu(xs, w_gate, w_up, w_down, group_sizes, compute_dtype)
+def _expert_swiglu_fwd(xs, w_gate, w_up, w_down, group_sizes, compute_dtype, covered=True):
+    out = _expert_swiglu(xs, w_gate, w_up, w_down, group_sizes, compute_dtype, covered)
     return out, (xs, w_gate, w_up, w_down, group_sizes)
 
 
-def _expert_swiglu_bwd(compute_dtype, res, dy):
+def _expert_swiglu_bwd(compute_dtype, covered, res, dy):
     xs, w_gate, w_up, w_down, group_sizes = res
     cd = jnp.dtype(compute_dtype)
     precision = _HIGHEST if cd == jnp.float32 else None
     f32 = jnp.float32
+    guard = _row_guard(group_sizes, covered)
     wg, wu, wd = w_gate.astype(cd), w_up.astype(cd), w_down.astype(cd)
     transposed = lambda w: jnp.swapaxes(w, 1, 2)  # noqa: E731
+    dy = guard(dy)
     gate, up = _swiglu_hidden(xs, wg, wu, group_sizes, cd, precision)
+    gate, up = guard(gate), guard(up)
     sig = jax.nn.sigmoid(gate)
     act = gate * sig  # silu(gate)
-    d_hidden = _grouped(dy, transposed(wd), group_sizes, _ROWS, f32, precision)
+    d_hidden = guard(_grouped(dy, transposed(wd), group_sizes, _ROWS, f32, precision))
     d_w_down = _grouped((act * up).astype(cd), dy, group_sizes, _DW, f32, precision)
     d_up = (d_hidden * act).astype(cd)
     d_gate = (d_hidden * up * (sig + act * (1.0 - sig))).astype(cd)
     d_w_gate = _grouped(xs, d_gate, group_sizes, _DW, f32, precision)
     d_w_up = _grouped(xs, d_up, group_sizes, _DW, f32, precision)
-    d_xs = (_grouped(d_gate, transposed(wg), group_sizes, _ROWS, f32, precision)
-            + _grouped(d_up, transposed(wu), group_sizes, _ROWS, f32, precision)).astype(xs.dtype)
+    d_xs = guard(_grouped(d_gate, transposed(wg), group_sizes, _ROWS, f32, precision)
+                 + _grouped(d_up, transposed(wu), group_sizes, _ROWS, f32, precision)).astype(xs.dtype)
     return d_xs, d_w_gate, d_w_up, d_w_down, None
 
 
 _expert_swiglu.defvjp(_expert_swiglu_fwd, _expert_swiglu_bwd)
 
 
-def moe_dropless(x, router, w_gate, w_up, w_down, k: int, compute_dtype=jnp.float32):
+def moe_dropless(x, router, w_gate, w_up, w_down, k: int, compute_dtype=jnp.float32,
+                 first_held: int = 0):
     """Top-``k`` SwiGLU experts for tokens ``x [t, d]``.
 
-    ``router [d, E]``; ``w_gate``/``w_up`` ``[E, d, h]``; ``w_down [E, h, d]``.
-    ``compute_dtype`` is the grouped matmuls' input type (accumulation is
-    f32); the router is f32 regardless. Returns ``(y [t, d] f32, stats)`` with
-    ``stats = {"f": [E], "P": [E], "rows": [E] int32}`` as the module
-    docstring defines them.
+    ``router`` is a matrix ``[d, E]`` or a function ``x -> logits [t, E]``
+    (float32); ``w_gate``/``w_up`` ``[H, d, h]`` and ``w_down [H, h, d]`` are
+    the ``H`` experts held here, experts ``first_held .. first_held + H`` of
+    the ``E`` the router chooses among. ``compute_dtype`` is the grouped
+    matmuls' input type (accumulation is f32); the router is f32 regardless.
+    Returns ``(y [t, d] f32, stats)`` with ``stats = {"f": [E], "P": [E],
+    "rows": [E] int32}`` as the module docstring defines them: ``y`` is the
+    part of the layer's result that the held experts give.
     """
     t, _ = x.shape
-    n_experts = router.shape[1]
+    n_held = w_gate.shape[0]
     p, top_p, top_e = route_top_k(x, router, k)
+    n_experts = p.shape[1]
+    covered = n_held == n_experts
+    if not 0 <= first_held <= n_experts - n_held:
+        raise ValueError(f"experts {first_held}..{first_held + n_held} are not among the router's {n_experts}")
 
     # row r of the flat (token, slot) list belongs to token r // k
     flat_e = top_e.reshape(-1)
-    order = jnp.argsort(flat_e, stable=True)  # sorted position -> flat row
+    sort_key = flat_e
+    if not covered:  # the rows of experts held elsewhere sort last, past every group
+        local = flat_e - first_held
+        here = (local >= 0) & (local < n_held)
+        sort_key = jnp.where(here, local, n_held)
+        top_p = jnp.where(here.reshape(top_p.shape), top_p, 0.0)
+    order = jnp.argsort(sort_key, stable=True)  # sorted position -> flat row
     inverse = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0], dtype=order.dtype))
     rows = jnp.zeros((n_experts,), jnp.int32).at[flat_e].add(1)
+    held_rows = rows if covered else jax.lax.dynamic_slice_in_dim(rows, first_held, n_held)
 
     x_rows = jnp.repeat(x.astype(compute_dtype), k, axis=0)  # [t*k, d], token-major
     ys = _expert_swiglu(_take_rows(x_rows, order, inverse), w_gate, w_up, w_down,
-                        rows, jnp.dtype(compute_dtype).name)
+                        held_rows, jnp.dtype(compute_dtype).name, covered)
     y_rows = _take_rows(ys, inverse, order).reshape(t, k, -1)
     y = jnp.sum(y_rows.astype(jnp.float32) * top_p[:, :, None], axis=1)
 

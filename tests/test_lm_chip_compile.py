@@ -1,5 +1,5 @@
 """The LM step's kernels compiled for the real chip at OLMoE's published
-shape, without the chip: libtpu's compiler runs here against a described
+shape, and the whole step program at ZAYA1-8B's cut, without the chip: libtpu's compiler runs here against a described
 v5e (docs and recipe: the ``on-chip-measurement`` guide, section 2). It
 catches what interpret mode cannot - Mosaic's lowering rules and the
 scoped-VMEM limit - at no chip time. Nothing runs; no time is measured.
@@ -41,29 +41,46 @@ def _compile(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile()
 
 
-@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16", "float32"])
-def test_fused_fold_trains_at_4x16x4096x128(one_chip, dtype):
-    """Forward and both backward kernels in one training graph: the shape
-    ``flash_train_available``'s 9 MB envelope refused and the kernels' stated
-    VMEM limit admits."""
+def _fold_grads(b, h, t):
     from flink_ml_tpu.parallel.flash import fused_fold
 
     def grads(q, k, v):
         def loss(q, k, v):
-            m0 = jnp.full((B, H, T), -jnp.inf, jnp.float32)
-            l0 = jnp.zeros((B, H, T), jnp.float32)
-            acc0 = jnp.zeros((B, H, T, D), jnp.float32)
+            m0 = jnp.full((b, h, t), -jnp.inf, jnp.float32)
+            l0 = jnp.zeros((b, h, t), jnp.float32)
+            acc0 = jnp.zeros((b, h, t, D), jnp.float32)
             zero = jnp.int32(0)
             _, l, acc = fused_fold(q, k, v, m0, l0, acc0, zero, zero, True, False, zero, D ** -0.5)
             return jnp.sum(acc / l[..., None])
 
         return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
-    shape = jax.ShapeDtypeStruct((B, H, T, D), dtype, sharding=one_chip)
-    text = _compile(grads, shape, shape, shape).as_text()
+    return grads
+
+
+#: ``(batch, query heads, key/value heads, T)``: four packed sequences of
+#: OLMoE-1B-7B; two of ZAYA1-8B, 8 query heads on 2 key/value heads at 8,192.
+FOLDS = {"olmoe_4x16x4096": (B, H, H, T), "zaya_2x8on2x8192": (2, 8, 2, 8192)}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("fold", sorted(FOLDS))
+def test_fused_fold_trains_at_the_cells_shapes(one_chip, dtype, fold):
+    """Forward and both backward kernels in one training graph: the shape
+    ``flash_train_available``'s 9 MB envelope refused and the kernels' stated
+    VMEM limit admits, and the grouped-query fold at ``H_kv`` 2, T 8,192, whose
+    K and V enter at their own ``[B, H_kv, T, D]`` (no repeated copy)."""
+    b, h, h_kv, t = FOLDS[fold]
+    q = jax.ShapeDtypeStruct((b, h, t, D), dtype, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, h_kv, t, D), dtype, sharding=one_chip)
+    compiled = _compile(_fold_grads(b, h, t), q, kv, kv)
+    text = compiled.as_text()
     for kernel in ("flash_fold_fwd", "flash_fold_bwd_dq", "flash_fold_bwd_dkv"):
         assert kernel in text
-    assert f"f32[{B},{H},{T},{T}]" not in text and f"f32[{B * H},{T},{T}]" not in text  # no score tensor
+    assert f"f32[{b},{h},{t},{t}]" not in text and f"f32[{b * h},{t},{t}]" not in text  # no score tensor
+    # dk and dv come out per key/value head: the group's query heads are summed in the kernel
+    _, dk, dv = jax.eval_shape(_fold_grads(b, h, t), q, kv, kv)
+    assert dk.shape == dv.shape == (b, h_kv, t, D)
 
 
 def test_expert_matmuls_are_the_grouped_kernel_in_both_directions(one_chip):
@@ -92,3 +109,54 @@ def test_expert_matmuls_are_the_grouped_kernel_in_both_directions(one_chip):
     assert len(kernels) >= 8
     assert "convolution_select_fusion" not in text
     assert f"[{ROWS},{EXPERTS}," not in text  # no [rows, experts, ...] tensor
+
+
+def test_the_zaya_step_program_at_the_cells_shapes(one_chip):
+    """The whole jitted step (forward, backward, clip, AdamW; six rematerialised
+    blocks) of the ``zaya1_8b`` configuration at 2 x 8,192 tokens: it fits the
+    chip (XLA's analysis, which counts what ``peak_bytes_in_use`` does not), the
+    held range's grouped matmuls are the grouped kernel in both directions and
+    the three fold kernels are there at ``H_kv`` 2, T 8,192."""
+    import json
+    import os
+
+    from flink_ml_tpu.models.lm import decoder_lm
+    from flink_ml_tpu.models.lm.config import LMConfig, num_params
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perfbench", "configs", "zaya1_8b.json"), encoding="utf-8") as f:
+        c = json.load(f)
+    cfg = LMConfig(
+        c["num_hidden_layers"], c["hidden_size"], c["num_attention_heads"], c["num_experts_published"],
+        c["num_experts_per_tok"], c["moe_intermediate_size"], c["vocab_size"],
+        rope_theta=float(c["rope_parameters"]["hybrid"]["rope_theta"]), aux_coef=0.0, block="zaya",
+        tied=c["tie_word_embeddings"], experts_held=c["num_experts"], first_held=c["first_expert_held"],
+        n_kv_heads=c["num_key_value_heads"], head_size=c["head_dim"],
+        rope_fraction=c["partial_rotary_factor"], router_width=c["router_hidden_size"])
+    assert 16 * num_params(cfg) > 11e9  # the fullest device holds at least 11 GB of f32 state
+    batch, t = c["global_batch_size"], c["sequence_length"]
+    optimizer, step = decoder_lm._train_program(cfg, c["compute_dtype"], c["learning_rate"], batch, False)
+    params = jax.eval_shape(lambda: decoder_lm._init_program(cfg)(jax.random.key(0)))
+    state = jax.eval_shape(optimizer.init, params)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+    compiled = step.lower(
+        on_chip(params), on_chip(state),
+        jax.ShapeDtypeStruct((c["num_sequences"], t), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+    ).compile()
+    memory = compiled.memory_analysis()
+    live = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert 12 * num_params(cfg) <= memory.argument_size_in_bytes and live < 15.75e9, live
+    text = compiled.as_text()
+    kernels = [ln for ln in text.splitlines() if ln.lstrip().startswith("%ragged-dot") and "custom-call(" in ln]
+    assert len(kernels) >= 8 * cfg.n_layers
+    assert "convolution_select_fusion" not in text
+    for kernel in ("flash_fold_fwd", "flash_fold_bwd_dq", "flash_fold_bwd_dkv"):
+        assert kernel in text
+    kv = f"bf16[{batch * cfg.kv_heads},{t},{cfg.head_dim}]"
+    assert kv in text  # K and V enter the kernels once per key/value head
+    assert f"f32[{batch},{cfg.n_heads},{t},{t}]" not in text and f"f32[{batch * cfg.n_heads},{t},{t}]" not in text

@@ -76,6 +76,10 @@ def test_counts_are_what_the_job_says(traced_fit):
     assert drain["expert_rows_mean"] == BATCH * T * K // EXPERTS
     assert drain["expert_rows_max"] == int(est.expert_rows_history.max()) >= drain["expert_rows_mean"]
     assert drain["dropped"] == 0
+    # every expert is held here: the held counts are the whole routing's
+    assert drain["rows_held"] == STEPS * BATCH * T * K * LAYERS and drain["rows_absent"] == 0
+    assert drain["held_rows_max"] == drain["expert_rows_max"]
+    assert drain["held_rows_mean"] == pytest.approx(BATCH * T * K / EXPERTS)
     assert one["train.readback"] == {"bytes": 4 * STEPS * (1 + len(param_shapes(cfg)))}
 
 
