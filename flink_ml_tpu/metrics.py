@@ -56,6 +56,8 @@ class MLMetrics:
     TRAIN_H2D_BYTES = "ml.train.h2d.bytes"  # bytes handed to device_put by caches and layouts, counter
     # The decoder LM's fit (models/lm/decoder_lm.py), counted where train.drain closes.
     TRAIN_LM_TOKENS = "ml.train.lm.tokens"  # tokens the fit's steps consumed, counter
+    TRAIN_LM_FOLD_CHUNKS = "ml.train.lm.fold.chunks"  # (query tile, key chunk) pairs of the fit's causal folds, counter
+    TRAIN_LM_FOLD_CHUNKS_VISITED = "ml.train.lm.fold.chunks_visited"  # those the mask does not hide: the ones computed, counter
     TRAIN_MOE_ROWS = "ml.train.moe.rows"  # (token, expert) rows the experts held here ran, counter
     TRAIN_MOE_ROWS_ABSENT = "ml.train.moe.rows_absent"  # rows routed to experts held elsewhere, counter
 

@@ -86,7 +86,7 @@ from flink_ml_tpu.params.shared import (
     HasPredictionCol,
     HasSeed,
 )
-from flink_ml_tpu.parallel.flash import TQ_TILE, flash_available, fused_fold
+from flink_ml_tpu.parallel.flash import TQ_TILE, flash_available, fold_chunk_counts, fused_fold
 from flink_ml_tpu.parallel.mesh import is_tpu_backend
 from flink_ml_tpu.parallel.moe import moe_dropless
 from flink_ml_tpu.trace import CAT_COMPILE, CAT_INGEST, CAT_PRODUCTIVE, CAT_READBACK, tracer
@@ -658,7 +658,12 @@ class DecoderLM(Estimator, _LMParams):
             optimizer, step = _train_program(
                 cfg, self.get_compute_type(), float(self.get_learning_rate()), batch, interpret
             )
-            phase.set_metadata(built=int(_train_program.cache_info().misses > misses))
+            # what the causal fold's three kernels walk in one step, each counted once
+            # (a rematerialised forward not again), and what the mask lets them skip
+            visited, pairs = fold_chunk_counts(t, t, 0, True)
+            folds = cfg.n_layers * cfg.n_heads * batch
+            phase.set_metadata(built=int(_train_program.cache_info().misses > misses),
+                               fold_chunks=folds * pairs, fold_chunks_visited=folds * visited)
             opt_state = optimizer.init(params)
 
         losses, leaf_norms, loads = [], [], []  # device values, fetched once after the loop
@@ -695,6 +700,8 @@ class DecoderLM(Estimator, _LMParams):
         self.grad_norm_history = [float(x) for x in np.sqrt((self.param_grad_norm_history ** 2).sum(axis=1))]
         self.expert_rows_history = loads
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_TOKENS, steps * batch * t)
+        metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_CHUNKS, steps * folds * pairs)
+        metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_CHUNKS_VISITED, steps * folds * visited)
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_MOE_ROWS, rows_held)
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_MOE_ROWS_ABSENT, rows_absent)
 

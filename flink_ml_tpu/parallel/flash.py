@@ -8,13 +8,21 @@ at long local sequence lengths that traffic, not the matmuls, bounds the
 step.
 
 This module fuses one fold into a Pallas kernel: per ``(batch·head,
-Q-tile)`` grid cell, the scores for the whole resident KV block live only
-in VMEM — matmul, mask, streaming-softmax rescale and the ``p @ v``
-accumulation happen in one pass, and only the ``O(T·D)`` accumulator
-state touches HBM. The numerics replicate the jnp fold exactly: running
-max with ``-inf`` hygiene (rows with nothing attendable yet must not
-produce NaNs), masked positions dropped before the exponential, and the
-same correction factors.
+Q-tile)`` grid cell, one head's resident K and V are staged whole in VMEM and
+the tile's scores live only there — matmul, mask, streaming-softmax rescale
+and the ``p @ v`` accumulation happen in the cell, and only the ``O(T·D)``
+accumulator state touches HBM. Without ``causal``, and for a block of a single
+key chunk, a cell takes the whole block's scores in one piece and masks them
+there. Under ``causal`` a longer block is walked in key chunks, and a cell
+visits only those that hold an entry the mask keeps, from the two position
+scalars it prefetches: a ring step behind the diagonal runs without any mask
+work, the LM's fold (``q_pos0 = k_pos0``, ``Tq = Tk``) over a little more than
+half the square, a step ahead of the diagonal over nothing ("the causal fold's
+key chunks" below; ``fold_chunk_counts`` counts the pairs). The numerics
+replicate the jnp fold: running max with ``-inf`` hygiene (rows with nothing
+attendable yet must not produce NaNs), masked positions dropped before the
+exponential, and the same correction factors; a skipped entry is one the mask
+gives ``-inf``, so the walk changes the order of the sums and nothing else.
 
 Gradients: ``fused_fold`` carries a ``jax.custom_vjp`` whose backward is
 fused too — a hand-derived fold VJP (``reference_fold_bwd``, pinned
@@ -23,6 +31,10 @@ edges) run as two Pallas kernels: a dq-kernel owning full score rows
 (which also emits the row-level max/tie quantities) and a dkv-kernel
 owning score columns with Q-axis grid accumulation. ``jax.grad`` through
 ring attention is therefore exact and never materializes scores in HBM.
+Under ``causal`` the dq-kernel walks the visited key chunks three times (row
+max; ``dP * P`` with its row sum and the tie count; ``ds @ K``), scores and
+``dP * P`` waiting in VMEM so no matmul runs twice, and the dkv-kernel's grid
+skips the (key tile, query tile) pairs the mask hides.
 
 Grouped queries: ``q`` is ``[B, H, T, D]``; ``kb``/``vb`` are ``[B, H_kv, T,
 D]`` with ``H`` a multiple of ``H_kv`` (equal for multi-head attention). Query
@@ -50,6 +62,7 @@ __all__ = [
     "flash_available",
     "flash_train_available",
     "reference_fold",
+    "fold_chunk_counts",
     "TQ_TILE",
 ]
 
@@ -63,7 +76,7 @@ _KV_VMEM_BUDGET = 1 << 20  # Tk*D f32 elements the kernel may stage per head
 # block on the chip at 2 x 8 query heads on 2 key/value heads x 8,192 x 128 in
 # bfloat16 (PERF.md, PR 30). Every admitted (T, D) then has
 # score-buffer and KV footprints <= that shape's in all three kernels. 16384
-# admitted shapes (e.g. T=16384, D=64) stage [TQ_TILE, 16384] f32 scores plus
+# admitted shapes (e.g. T=16384, D=64) stage [tile, 16384] f32 scores plus
 # full KV — past the scoped-VMEM limit on paper and never compile-checked on
 # chip, so they are rejected until verified.
 _TK_MAX = 8192
@@ -71,10 +84,11 @@ _TK_MAX = 8192
 
 def flash_available(T: int, D: int, devices=None) -> bool:
     """Whether the fused fold applies: Q tiles must divide the local length,
-    one head's KV block AND the [TQ_TILE, Tk] score/probability buffers must
-    fit the kernel's VMEM staging (the fold brings the whole resident block
-    on-chip; past either budget the jnp fold's streamed HBM form is the
-    right tool), and the devices must be TPUs (Mosaic target)."""
+    one head's KV block AND a tile's ``[rows, Tk]`` score buffers (whole, or as
+    the visited chunks under ``causal``) must fit the kernel's VMEM staging
+    (the fold brings one head's whole resident K and V on-chip; past either
+    budget the jnp fold's streamed HBM form is the right tool), and the
+    devices must be TPUs (Mosaic target)."""
     from flink_ml_tpu.parallel.mesh import is_tpu_backend
 
     if T % TQ_TILE or T * D > _KV_VMEM_BUDGET or T > _TK_MAX:
@@ -83,16 +97,22 @@ def flash_available(T: int, D: int, devices=None) -> bool:
 
 
 # Scoped VMEM the three kernels may use. A grid cell stages one head's whole
-# resident K and V (double-buffered) beside its [tile, Tk] f32 score buffers:
-# at T=4096, D=128 that is 16.5 MB in the forward and 20.4 MB in the dkv
+# resident K and V (double-buffered) beside its f32 score buffers. The
+# whole-block kernels (``causal`` off) hold [tile, Tk] of them: at T=4096,
+# D=128 that is 16.5 MB in the forward ([256, Tk]) and 20.4 MB in the dkv
 # kernel, past Mosaic's default scoped limit of 16 MB whatever batch*heads is
-# (the compile fails "allocating on stack" for the kernel's custom call). At
-# T=8192, D=128 in bfloat16 the training graph's three kernels compile for the
-# chip with the limit at 24 MB and not at 16 (K and V are 2 MB each, twice for
-# the double buffer; the forward's [256, 8192] f32 scores are 8 MB a buffer, the
-# dq kernel's [64, 8192] 2 MB, the dkv kernel's [2048, 256] 2 MB whatever T is).
-# A v5e core has 128 MiB of VMEM; 96 MiB covers every shape ``flash_available``
-# admits and leaves XLA its own share.
+# (the compile fails "allocating on stack" for the kernel's custom call); at
+# T=8192 in bfloat16 they compile with the limit at 24 MB and not at 16. The
+# walking kernels (``causal`` on more than one chunk) park the visited chunks'
+# scores in a scratch the size of the tile's whole row block, [Tk / 1024, 512,
+# 1024] f32: at T=8192, D=128 that is
+# 16 MB in the forward and twice that in the dq kernel (scores and dP * P),
+# beside K and V (2 MB each in bfloat16, twice for the double buffer) and a
+# chunk's [512, 1024] temporaries; the dkv kernel's [1024, 1024] pair is 4 MB
+# a temporary whatever T is. Compiled here for the chip, that training graph
+# fits a limit of 48 MB and not 40 in bfloat16, 64 and not 48 in float32 (the
+# dq kernel is the largest). A v5e core has 128 MiB of VMEM; 96 MiB covers
+# every shape ``flash_available`` admits and leaves XLA its own share.
 _VMEM_LIMIT_BYTES = 96 << 20
 
 
@@ -133,6 +153,159 @@ def _kv_block_of(n_heads: int, n_kv_heads: int):
         raise ValueError(f"{n_heads} query heads do not divide over {n_kv_heads} key/value heads")
     group = n_heads // n_kv_heads
     return (lambda i: i) if group == 1 else (lambda i: i // group)
+
+
+# -- the causal fold's key chunks ---------------------------------------------
+#
+# Under ``causal`` a grid cell of each kernel walks a resident block of more
+# than one key chunk and sorts the chunks, from the two position scalars and
+# its tile index,
+# into *hidden* (the chunk's first key lies after the tile's last query:
+# nothing of it is computed), *diagonal* (the mask crosses it: iota, compare and
+# select as before) and *full* (its last key is at or before the tile's first
+# query: computed without the mask). A masked entry contributes ``-inf`` to the
+# row max and 0 to every sum, so skipping it changes the order of a summation
+# and nothing else: ``reference_fold`` over the whole block stays the truth.
+# Keys run left to right, so the full chunks come first, then the diagonal
+# ones, then the hidden: two counts describe a tile.
+
+_LANES = 128
+# Tiles of the causal kernels, the best of those run on the chip at both LM
+# cells' shapes (PERF.md, PR 31). A walk's cost is per chunk as well as per
+# entry (a loop with a traced bound is neither unrolled nor overlapped with the
+# next chunk's matmul), so chunks are long; the hidden share falls with the tile
+# (44% of the pairs at 512 x 1024 on T 8,192, 47% at 256 x 512) and the time
+# falls faster. The dkv pair is square: at 256 keys a pair the kernel ran at a
+# third of this speed whatever it skipped.
+_TQ_CAUSAL = 512  # Q rows per forward and per dq cell
+_KEY_CHUNK = 1024  # keys per chunk of their walks
+_DKV_CAUSAL = 1024  # Q rows and K rows of a dkv pair
+
+
+def _tile(T: int, most: int) -> int:
+    """The largest of ``most, most / 2, .. 256`` that divides ``T``
+    (``flash_available`` guarantees ``T % 256 == 0``), else ``T`` whole."""
+    c = most
+    while c >= 256:
+        if T % c == 0:
+            return c
+        c //= 2
+    return T
+
+
+def _fold_tiles(Tq: int, Tk: int, causal: bool):
+    """``(forward Q rows, dq Q rows, dkv Q rows, dkv K rows, key chunk)``: what a
+    cell of each kernel owns, from the lengths alone. The forward and the dq
+    kernel walk only where a chunk can be skipped: without ``causal``, or on a
+    block that is one chunk, they take the resident block in one piece (chunk
+    ``Tk``) at the tiles they always had, and park no scores in a scratch.
+    (That is also what keeps ``ring_attention(causal=True)`` running under the
+    TPU interpreter on the eight-device CPU mesh, at the 256 keys a shard of
+    its tests: there a scratch goes through a host callback on every device,
+    and eight of them at their barrier leave the CPU client no thread.)"""
+    kc = _tile(Tk, _KEY_CHUNK) if causal else Tk
+    rows = (_tile(Tq, _TQ_CAUSAL),) * 2 if kc < Tk else (TQ_TILE, min(_TQ_BWD, Tq))
+    pair = (_DKV_CAUSAL, _DKV_CAUSAL) if causal else (_TQ_DKV, _TK_BWD)
+    return (*rows, _tile(Tq, pair[0]), _tile(Tk, pair[1]), kc)
+
+
+def _chunks_upto(x, chunk: int, n_chunks: int):
+    """How many of ``n_chunks`` chunks of ``chunk`` keys START below offset
+    ``x + chunk`` (``x`` a traced int32 or an int, negative allowed), which is
+    also how many END at or below ``x``: ``clip(x, 0, all) // chunk``."""
+    if isinstance(x, int):
+        return min(max(x, 0), chunk * n_chunks) // chunk
+    return jax.lax.div(jnp.clip(x, 0, chunk * n_chunks), jnp.int32(chunk))
+
+
+def _least(a, b):
+    return min(a, b) if isinstance(a, int) and isinstance(b, int) else jnp.minimum(a, b)
+
+
+def _visible_chunks(q_first, n_rows: int, k_pos0, chunk: int, n_chunks: int, n_valid=None):
+    """``(n_full, n_vis)`` for query rows ``q_first .. q_first + n_rows - 1``
+    against ``n_chunks`` key chunks from global key ``k_pos0`` under the causal
+    mask (and ``n_valid`` when given): chunks ``[0, n_full)`` need no mask,
+    ``[n_full, n_vis)`` are crossed by it, the rest hold nothing kept. Works on
+    ints (``fold_chunk_counts``) and on the kernels' prefetched scalars."""
+    off = q_first - k_pos0
+    n_vis = _chunks_upto(off + n_rows - 1 + chunk, chunk, n_chunks)  # first key <= last query
+    n_full = _chunks_upto(off + 1, chunk, n_chunks)  # last key <= first query
+    if n_valid is not None:
+        n_vis = _least(n_vis, _chunks_upto(n_valid - k_pos0 + chunk - 1, chunk, n_chunks))
+        n_full = _least(n_full, _chunks_upto(n_valid - k_pos0, chunk, n_chunks))
+    return n_full, n_vis  # a chunk that needs no mask is visible: n_full <= n_vis
+
+
+def _mask_chunk(s, q_first, k_first, causal: bool, n_valid=None):
+    """``s [rows, keys]`` with ``-inf`` where the causal mask (when ``causal``)
+    or ``n_valid`` (when given; one of the two is) drops the entry;
+    ``q_first``/``k_first`` are the global positions of row 0 and key 0."""
+    q_pos = q_first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) if causal else None
+    k_pos = k_first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    keep = q_pos >= k_pos if causal else k_pos < n_valid
+    if causal and n_valid is not None:
+        keep &= k_pos < n_valid
+    return jnp.where(keep, s, -jnp.inf)
+
+
+def _fold_lanes(x, op):
+    """``x [rows, n * 128] -> [rows, 128]``: ``op`` over the lane tiles, which
+    leaves the one cross-lane reduction of a row to the end of the walk."""
+    out = x[:, :_LANES]
+    for i in range(_LANES, x.shape[1], _LANES):
+        out = op(out, x[:, i:i + _LANES])
+    return out
+
+
+def _key_rows(ref, c, kc: int):
+    """Chunk ``c`` of the ``kc``-row chunks of a staged ``[1, Tk, D]`` K or V block."""
+    from jax.experimental import pallas as pl
+
+    return ref[0, pl.ds(pl.multiple_of(c * kc, kc), kc), :]
+
+
+def _park_scores(qt, k_ref, s_scr, q_first, k_pos0, n_valid, scale, kc: int, n_full, n_vis):
+    """The first walk of a causal forward or dq cell: the scores of ``qt
+    [rows, D]`` on key chunks ``[0, n_vis)``, masked on ``[n_full, n_vis)``,
+    parked in ``s_scr [chunks, rows, kc]``. Returns their running row max,
+    still 128 lanes wide."""
+
+    def walk(on_diagonal):
+        def body(c, mx):
+            s = jax.lax.dot_general(
+                qt, _key_rows(k_ref, c, kc), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # [rows, kc]
+            if on_diagonal:
+                s = _mask_chunk(s, q_first, k_pos0 + c * kc, True, n_valid)
+            s_scr[c] = s
+            return jnp.maximum(mx, _fold_lanes(s, jnp.maximum))
+
+        return body
+
+    mx = jnp.full((qt.shape[0], _LANES), -jnp.inf, jnp.float32)
+    mx = jax.lax.fori_loop(0, n_full, walk(False), mx)
+    return jax.lax.fori_loop(n_full, n_vis, walk(True), mx)
+
+
+def fold_chunk_counts(Tq: int, Tk: int, q_off: int, causal: bool):
+    """``(visited, total)`` chunk pairs of ONE fold of ``Tq`` queries on ``Tk``
+    keys, one head, the three kernels together at the tiles they use: the
+    forward's and the dq kernel's (query tile, key chunk) pairs and the dkv
+    kernel's (key tile, query tile) pairs. ``q_off = q_pos0 - k_pos0``. A kernel
+    that takes the block in one piece visits every pair: all three without
+    ``causal``, the forward and the dq kernel on a block of one chunk."""
+    tq_fwd, tq_dq, tq_dkv, tk_dkv, kc = _fold_tiles(Tq, Tk, causal)
+    visited = total = 0
+    for rows, keys, skips in ((tq_fwd, kc, kc < Tk), (tq_dq, kc, kc < Tk), (tq_dkv, tk_dkv, causal)):
+        n_keys = Tk // keys
+        total += (Tq // rows) * n_keys
+        visited += sum(
+            _visible_chunks(q_off + j * rows, rows, 0, keys, n_keys)[1] if skips else n_keys
+            for j in range(Tq // rows)
+        )
+    return visited, total
 
 
 def reference_fold(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale):
@@ -177,29 +350,19 @@ def _fold_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
     BH = B * H
     kv_of = _kv_block_of(H, Hkv)
     masked = n_valid is not None
+    tq, _, _, _, kc = _fold_tiles(Tq, Tk, causal)
+    n_chunks = Tk // kc  # 1: the block in one piece
 
     def kernel(scalars_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
                mo_ref, lo_ref, ao_ref):
-        j = pl.program_id(1)
         qt = q_ref[0]  # [TQ, D]
         s = jax.lax.dot_general(
             qt, k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale  # [TQ, Tk]
         if causal or masked:
-            q_pos = (
-                scalars_ref[0] + j * TQ_TILE
-                + jax.lax.broadcasted_iota(jnp.int32, (TQ_TILE, Tk), 0)
-            )
-            k_pos = scalars_ref[1] + jax.lax.broadcasted_iota(
-                jnp.int32, (TQ_TILE, Tk), 1
-            )
-            keep = jnp.ones((TQ_TILE, Tk), bool)
-            if causal:
-                keep &= q_pos >= k_pos
-            if masked:
-                keep &= k_pos < scalars_ref[2]
-            s = jnp.where(keep, s, -jnp.inf)
+            s = _mask_chunk(s, scalars_ref[0] + pl.program_id(1) * tq, scalars_ref[1], causal,
+                            scalars_ref[2] if masked else None)
         # m/l ride as [TQ, 1] columns (Mosaic wants >= 2-D tiles with an
         # aligned or full trailing dim); all the math stays 2-D.
         mcol = m_ref[0]  # [TQ, 1]
@@ -217,6 +380,36 @@ def _fold_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
             p.astype(v_ref.dtype), v_ref[0], preferred_element_type=jnp.float32
         )
 
+    def walking_kernel(scalars_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
+                       mo_ref, lo_ref, ao_ref, s_scr):
+        # the same fold over the key chunks this tile's rows can see: scores
+        # of the visited chunks wait in ``s_scr`` for the row max, then feed
+        # the exponential and ``p @ v``; a hidden chunk is never touched
+        q_first = scalars_ref[0] + pl.program_id(1) * tq
+        nv = scalars_ref[2] if masked else None
+        n_full, n_vis = _visible_chunks(q_first, tq, scalars_ref[1], kc, n_chunks, nv)
+        mx = _park_scores(q_ref[0], k_ref, s_scr, q_first, scalars_ref[1], nv, scale, kc, n_full, n_vis)
+        mcol = m_ref[0]  # [TQ, 1]
+        new_m = jnp.maximum(mcol, jnp.max(mx, axis=1, keepdims=True))
+        safe_m = jnp.where(jnp.isneginf(new_m), 0.0, new_m)
+        correction = jnp.where(jnp.isneginf(mcol), 0.0, jnp.exp(mcol - safe_m))
+        mo_ref[0] = new_m
+        ao_ref[0] = acc_ref[0] * correction
+
+        def accumulate(c, l_lanes):
+            # safe_m is finite, so a masked score's exp(-inf) is the 0 the
+            # reference selects
+            p = jnp.exp(s_scr[c] - safe_m)
+            ao_ref[0] += jnp.dot(
+                p.astype(v_ref.dtype), _key_rows(v_ref, c, kc), preferred_element_type=jnp.float32
+            )
+            return l_lanes + _fold_lanes(p, jnp.add)
+
+        l_lanes = jax.lax.fori_loop(
+            0, n_vis, accumulate, jnp.zeros((tq, _LANES), jnp.float32)
+        )
+        lo_ref[0] = l_ref[0] * correction + jnp.sum(l_lanes, axis=1, keepdims=True)
+
     scalars = jnp.stack(
         [
             jnp.asarray(q_pos0, jnp.int32),
@@ -225,22 +418,25 @@ def _fold_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
         ]
     )
     tile2 = pl.BlockSpec(
-        (1, TQ_TILE, 1), lambda i, j, *_: (i, j, 0), memory_space=pltpu.VMEM
+        (1, tq, 1), lambda i, j, *_: (i, j, 0), memory_space=pltpu.VMEM
     )
     tile3 = pl.BlockSpec(
-        (1, TQ_TILE, D), lambda i, j, *_: (i, j, 0), memory_space=pltpu.VMEM
+        (1, tq, D), lambda i, j, *_: (i, j, 0), memory_space=pltpu.VMEM
     )
     full3 = pl.BlockSpec((1, Tk, D), lambda i, j, *_: (kv_of(i), 0, 0), memory_space=pltpu.VMEM)
     from flink_ml_tpu.parallel.mesh import vma_of
 
     vma = vma_of(q)
     mo, lo, ao = pl.pallas_call(
-        kernel,
+        walking_kernel if n_chunks > 1 else kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(BH, Tq // TQ_TILE),
+            grid=(BH, Tq // tq),
             in_specs=[tile3, full3, full3, tile2, tile2, tile3],
             out_specs=[tile2, tile2, tile3],
+            scratch_shapes=(
+                [pltpu.VMEM((n_chunks, tq, kc), jnp.float32)] if n_chunks > 1 else []
+            ),
         ),
         out_shape=[
             jax.ShapeDtypeStruct((BH, Tq, 1), jnp.float32, vma=vma),
@@ -311,6 +507,7 @@ fused_fold.defvjp(_fused_fold_fwd, _fused_fold_bwd)
 # them to the dkv-kernel, whose cells own score columns.
 # ---------------------------------------------------------------------------
 
+# the whole-block kernels' tiles (``causal`` off, or a block of one chunk); the walk's: _fold_tiles
 _TQ_BWD = 64  # Q rows per dq-kernel cell (3 [TQ, Tk] f32 buffers live at once)
 _TK_BWD = 256  # K rows per dkv-kernel cell
 _TQ_DKV = 2048  # Q rows per dkv accumulation step (third grid dim)
@@ -372,39 +569,20 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
     group = H // Hkv
     kv_of = _kv_block_of(H, Hkv)
     masked = n_valid is not None
-    # tiles clamp to the largest 256-aligned divisor of the actual dims
-    # (flash_available guarantees T % 256 == 0, so these always divide)
-    tq_bwd = min(_TQ_BWD, Tq)
-    tk_bwd = min(_TK_BWD, Tk)
-    tq_dkv = next(c for c in (_TQ_DKV, 1024, 512, 256, Tq) if Tq % c == 0)
-    n_q_dkv = Tq // tq_dkv
-
-    def mask_of(q_pos, k_pos):
-        keep = jnp.ones(q_pos.shape, bool)
-        if causal:
-            keep &= q_pos >= k_pos
-        return keep
+    _, tq_bwd, tq_dkv, tk_bwd, kc = _fold_tiles(Tq, Tk, causal)
+    n_q_dkv, n_k_dkv = Tq // tq_dkv, Tk // tk_bwd
+    n_chunks = Tk // kc
 
     def dq_kernel(scalars_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
                   dm_ref, dl_ref, dacc_ref,
                   dqo_ref, dmo_ref, dlo_ref, dao_ref, safe_ref, b_ref, dbc_ref):
-        j = pl.program_id(1)
         s = jax.lax.dot_general(
             q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale  # [TQ, Tk]
         if causal or masked:
-            q_pos = (
-                scalars_ref[0] + j * tq_bwd
-                + jax.lax.broadcasted_iota(jnp.int32, (tq_bwd, Tk), 0)
-            )
-            k_pos = scalars_ref[1] + jax.lax.broadcasted_iota(
-                jnp.int32, (tq_bwd, Tk), 1
-            )
-            keep = mask_of(q_pos, k_pos)
-            if masked:
-                keep &= k_pos < scalars_ref[2]
-            s = jnp.where(keep, s, -jnp.inf)
+            s = _mask_chunk(s, scalars_ref[0] + pl.program_id(1) * tq_bwd, scalars_ref[1], causal,
+                            scalars_ref[2] if masked else None)
         mcol = m_ref[0]  # [TQ, 1]
         Bcol = jnp.max(s, axis=1, keepdims=True)
         new_m = jnp.maximum(mcol, Bcol)
@@ -441,6 +619,73 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
         b_ref[0] = Bcol
         dbc_ref[0] = dbc
 
+    def dq_walking_kernel(scalars_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
+                          dm_ref, dl_ref, dacc_ref,
+                          dqo_ref, dmo_ref, dlo_ref, dao_ref, safe_ref, b_ref, dbc_ref,
+                          s_scr, dpp_scr):
+        # ``dq_kernel`` over the key chunks this tile's rows can see, in three
+        # walks of the same chunks, because each needs a whole-row quantity of
+        # the one before: the scores and their row max; ``dP * P`` with its row
+        # sum and the tie count; ``ds`` and ``ds @ K``. Scores and ``dP * P``
+        # wait in VMEM between the walks, so no matmul runs twice.
+        q_first = scalars_ref[0] + pl.program_id(1) * tq_bwd
+        nv = scalars_ref[2] if masked else None
+        n_full, n_vis = _visible_chunks(q_first, tq_bwd, scalars_ref[1], kc, n_chunks, nv)
+        mx = _park_scores(q_ref[0], k_ref, s_scr, q_first, scalars_ref[1], nv, scale, kc, n_full, n_vis)
+        mcol = m_ref[0]  # [TQ, 1]
+        Bcol = jnp.max(mx, axis=1, keepdims=True)
+        new_m = jnp.maximum(mcol, Bcol)
+        safe = jnp.where(jnp.isneginf(new_m), 0.0, new_m)
+        corr = jnp.where(jnp.isneginf(mcol), 0.0, jnp.exp(mcol - safe))
+        # a row with nothing kept has no max entry: +inf equals no score
+        hit = jnp.where(jnp.isneginf(Bcol), jnp.inf, Bcol)
+
+        dlc = dl_ref[0]  # [TQ, 1]
+        dacc_t = dacc_ref[0].astype(v_ref.dtype)
+
+        def products(c, sums):
+            sum_dpp, cnt = sums
+            s = s_scr[c]
+            P = jnp.exp(s - safe)  # safe is finite: exp(-inf) is the 0 the reference selects
+            dP = dlc + jax.lax.dot_general(
+                dacc_t, _key_rows(v_ref, c, kc), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [TQ, KC]
+            dPP = dP * P
+            dpp_scr[c] = dPP
+            return (sum_dpp + _fold_lanes(dPP, jnp.add),
+                    cnt + _fold_lanes((s == hit).astype(jnp.float32), jnp.add))
+
+        zeros = jnp.zeros((tq_bwd, _LANES), jnp.float32)
+        sum_dpp, cnt = jax.lax.fori_loop(0, n_vis, products, (zeros, zeros))
+        dcorr = dlc * l_ref[0] + jnp.sum(
+            dacc_ref[0] * acc_ref[0], axis=1, keepdims=True
+        )
+        dsafe = -jnp.sum(sum_dpp, axis=1, keepdims=True) - dcorr * corr
+        dnew_m = dm_ref[0] + jnp.where(jnp.isneginf(new_m), 0.0, dsafe)
+        take_m = jnp.where(mcol > Bcol, 1.0, jnp.where(mcol == Bcol, 0.5, 0.0))
+        dB = dnew_m * (1.0 - take_m)
+        dbc = dB / jnp.maximum(jnp.sum(cnt, axis=1, keepdims=True), 1.0)
+
+        dqo_ref[0] = jnp.zeros_like(dqo_ref[0])
+
+        def dq_of(c, carry):
+            ds = dpp_scr[c] + jnp.where(s_scr[c] == hit, dbc, 0.0)
+            dqo_ref[0] += jax.lax.dot_general(
+                ds.astype(k_ref.dtype), _key_rows(k_ref, c, kc), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            return carry
+
+        jax.lax.fori_loop(0, n_vis, dq_of, 0)
+        dqo_ref[0] *= scale
+        dmo_ref[0] = jnp.where(jnp.isneginf(mcol), 0.0, dcorr * corr) + dnew_m * take_m
+        dlo_ref[0] = dlc * corr
+        dao_ref[0] = dacc_ref[0] * corr
+        safe_ref[0] = safe
+        b_ref[0] = Bcol
+        dbc_ref[0] = dbc
+
     def dkv_kernel(scalars_ref, k_ref, v_ref, q_ref, dacc_ref, dl_ref,
                    safe_ref, b_ref, dbc_ref, dko_ref, dvo_ref):
         # grid (B*H_kv, ktiles, group*qtiles): the innermost axis walks the q
@@ -451,48 +696,52 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
         first = jq == 0
         if group > 1:
             jq = jq % n_q_dkv
-        s_col = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [TQ_DKV, TK]
-        if causal or masked:
-            q_pos = (
-                scalars_ref[0] + jq * tq_dkv
-                + jax.lax.broadcasted_iota(jnp.int32, (tq_dkv, tk_bwd), 0)
+        q_first = scalars_ref[0] + jq * tq_dkv
+        k_first = scalars_ref[1] + jk * tk_bwd
+        nv = scalars_ref[2] if masked else None
+
+        def accumulate(mask):
+            """One (k tile, q tile) pair into dk, dv; ``mask`` is whether an
+            entry of it can be dropped (else every score is finite)."""
+            s_col = jax.lax.dot_general(
+                q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # [TQ_DKV, TK]
+            if mask:
+                s_col = _mask_chunk(s_col, q_first, k_first, causal, nv)
+            P_col = jnp.exp(s_col - safe_ref[0])
+            is_max = s_col == b_ref[0]
+            if mask or not causal:  # a full pair's scores are finite; the whole-block kernel stays as it was
+                P_col = jnp.where(jnp.isneginf(s_col), 0.0, P_col)
+                is_max &= ~jnp.isneginf(s_col)
+            dacc_t = dacc_ref[0].astype(v_ref.dtype)
+            dP_col = dl_ref[0] + jax.lax.dot_general(
+                dacc_t, v_ref[0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
             )
-            k_pos = (
-                scalars_ref[1] + jk * tk_bwd
-                + jax.lax.broadcasted_iota(jnp.int32, (tq_dkv, tk_bwd), 1)
+            ds_col = dP_col * P_col + is_max.astype(jnp.float32) * dbc_ref[0]
+            dko_ref[0] += jax.lax.dot_general(
+                ds_col.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale
+            dvo_ref[0] += jax.lax.dot_general(
+                P_col.astype(v_ref.dtype), dacc_t, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
             )
-            keep = mask_of(q_pos, k_pos)
-            if masked:
-                keep &= k_pos < scalars_ref[2]
-            s_col = jnp.where(keep, s_col, -jnp.inf)
-        P_col = jnp.exp(s_col - safe_ref[0])
-        P_col = jnp.where(jnp.isneginf(s_col), 0.0, P_col)
-        dacc_t = dacc_ref[0].astype(v_ref.dtype)
-        dP_col = dl_ref[0] + jax.lax.dot_general(
-            dacc_t, v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        is_max = (s_col == b_ref[0]) & ~jnp.isneginf(s_col)
-        ds_col = dP_col * P_col + is_max.astype(jnp.float32) * dbc_ref[0]
-        dk_part = jax.lax.dot_general(
-            ds_col.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        dv_part = jax.lax.dot_general(
-            P_col.astype(v_ref.dtype), dacc_t, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
 
         @pl.when(first)
         def _():
             dko_ref[0] = jnp.zeros_like(dko_ref[0])
             dvo_ref[0] = jnp.zeros_like(dvo_ref[0])
 
-        dko_ref[0] += dk_part
-        dvo_ref[0] += dv_part
+        if causal:
+            # of this q tile's key tiles [0, n_full) need no mask, [n_full,
+            # n_vis) are crossed by it; a hidden pair adds nothing
+            n_full, n_vis = _visible_chunks(q_first, tq_dkv, scalars_ref[1], tk_bwd, n_k_dkv, nv)
+            pl.when(jk < n_full)(lambda: accumulate(False))
+            pl.when((jk >= n_full) & (jk < n_vis))(lambda: accumulate(True))
+        else:
+            accumulate(masked)
 
     scalars = jnp.stack(
         [
@@ -520,7 +769,7 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
     dacc4 = dacc.reshape(BH, Tq, D)
     dl4 = dl.reshape(BH, Tq, 1)
     dq_o, dm_o, dl_o, dacc_o, safe_r, b_r, dbc_r = pl.pallas_call(
-        dq_kernel,
+        dq_walking_kernel if n_chunks > 1 else dq_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(BH, Tq // tq_bwd),
@@ -533,6 +782,10 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
                 mat(tq_bwd), col(tq_bwd), col(tq_bwd), mat(tq_bwd),
                 col(tq_bwd), col(tq_bwd), col(tq_bwd),
             ],
+            # the visited chunks' scores and dP * P between the walks
+            scratch_shapes=(
+                [pltpu.VMEM((n_chunks, tq_bwd, kc), jnp.float32)] * 2 if n_chunks > 1 else []
+            ),
         ),
         out_shape=[
             sds((BH, Tq, D)), sds((BH, Tq, 1)), sds((BH, Tq, 1)),
@@ -551,12 +804,16 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
     kmat = pl.BlockSpec(
         (1, tk_bwd, D), lambda i, jk, jq, *_: (i, jk, 0), memory_space=pltpu.VMEM
     )
-    if group == 1:
-        def q_block(i, jk, jq, *_):
-            return (i, jq, 0)
-    else:
-        def q_block(i, jk, jq, *_):  # the group's query heads one after another
-            return (i * group + jq // n_q_dkv, jq % n_q_dkv, 0)
+    def q_block(i, jk, jq, scalars_ref):
+        head = i
+        if group > 1:  # the group's query heads one after another
+            head, jq = i * group + jq // n_q_dkv, jq % n_q_dkv
+        if causal:
+            # q tiles before the first that sees this k tile are hidden: they
+            # name that first tile's block, so nothing is fetched for them
+            first_seen = _chunks_upto(scalars_ref[1] + jk * tk_bwd - scalars_ref[0], tq_dkv, n_q_dkv - 1)
+            jq = jnp.maximum(jq, first_seen)
+        return (head, jq, 0)
 
     qmat = pl.BlockSpec((1, tq_dkv, D), q_block, memory_space=pltpu.VMEM)
     qcol = pl.BlockSpec((1, tq_dkv, 1), q_block, memory_space=pltpu.VMEM)

@@ -257,7 +257,7 @@ def test_grouped_query_fold_forward_and_backward(dtype, tol, monkeypatch):
     dkv kernel's accumulation axis walks two q tiles a head here."""
     from flink_ml_tpu.parallel import flash
 
-    monkeypatch.setattr(flash, "_TQ_DKV", 256)
+    monkeypatch.setattr(flash, "_DKV_CAUSAL", 256)  # 2 x 2 pairs a head, one of them hidden
     B, H, H_KV, Tq, D = 2, 8, 2, 512, 32
     rng = np.random.default_rng(4)
     q = jnp.asarray(rng.standard_normal((B, H, Tq, D)).astype(np.float32))

@@ -83,6 +83,34 @@ def test_fused_fold_trains_at_the_cells_shapes(one_chip, dtype, fold):
     assert dk.shape == dv.shape == (b, h_kv, t, D)
 
 
+def test_the_ring_fold_trains_at_the_largest_admitted_shape(one_chip):
+    """What ``ring_attention`` hands the fold, at the most ``flash_available``
+    admits (T 8,192 x D 128, float32): ``causal`` with TRACED positions, so
+    the walk's bounds are read on the device, and ``n_valid`` cutting the
+    block. The dq kernel's two score scratches are at their largest here."""
+    from flink_ml_tpu.parallel.flash import flash_available, fused_fold
+
+    b, h, t = 1, 4, 8192
+    assert t * D == 1 << 20 and not flash_available(2 * t, D // 2, list(one_chip.device_set))
+
+    def grads(q, k, v, q_pos0, k_pos0, n_valid):
+        def loss(q, k, v):
+            m0 = jnp.full((b, h, t), -jnp.inf, jnp.float32)
+            l0 = jnp.zeros((b, h, t), jnp.float32)
+            acc0 = jnp.zeros((b, h, t, D), jnp.float32)
+            _, l, acc = fused_fold(q, k, v, m0, l0, acc0, q_pos0, k_pos0, True, True, n_valid, D ** -0.5)
+            return jnp.sum(acc / l[..., None])
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    x = jax.ShapeDtypeStruct((b, h, t, D), jnp.float32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    text = _compile(grads, x, x, x, pos, pos, pos).as_text()
+    for kernel in ("flash_fold_fwd", "flash_fold_bwd_dq", "flash_fold_bwd_dkv"):
+        assert kernel in text
+    assert f"f32[{b * h},{t},{t}]" not in text
+
+
 def test_expert_matmuls_are_the_grouped_kernel_in_both_directions(one_chip):
     """131,072 rows over 64 experts: every grouped matmul of the forward and of
     the hand-written VJP lowers to XLA's ragged-dot kernel, none to the masked

@@ -69,7 +69,10 @@ def test_counts_are_what_the_job_says(traced_fit):
     assert one["train.fit"] == {"rows": N, "tokens": N * T}
     assert one["train.tokens_put"] == {"rows": N, "tokens": N * T, "bytes": N * T * 4}
     assert one["train.init"] == {"params": num_params(cfg), "bytes": 4 * num_params(cfg)}
-    assert one["train.program"] == {"built": 0}
+    # 256 keys are one chunk, taken whole: the forward's one tile, the dq kernel's four
+    # of 64 rows and the dkv kernel's one pair, nothing to hide
+    pairs = (1 + 4 + 1) * LAYERS * 2 * BATCH
+    assert one["train.program"] == {"built": 0, "fold_chunks": pairs, "fold_chunks_visited": pairs}
     assert one["train.dispatch"] == {"steps": STEPS}
     drain = one["train.drain"]
     assert drain["steps"] == STEPS and drain["tokens"] == STEPS * BATCH * T
@@ -87,3 +90,29 @@ def test_registry_counters_count_at_the_same_site(traced_fit):
     _, _, (tokens, rows) = traced_fit
     assert tokens == STEPS * BATCH * T
     assert rows == STEPS * BATCH * T * K * LAYERS
+
+
+def test_the_causal_fold_reports_the_chunks_it_skips():
+    """At a length of several chunks the mask hides part of every fold: the
+    counts on ``train.program`` are one step's, all layers, heads and sequences,
+    and the registry counters the whole fit's."""
+    from flink_ml_tpu.parallel.flash import fold_chunk_counts
+
+    t, steps, heads, layers = 2048, 1, 2, 1
+    df = DataFrame.from_dict({"features": np.random.default_rng(1).integers(0, 64, (1, t))})
+    est = (
+        DecoderLM().set_num_layers(layers).set_hidden_size(32).set_num_heads(heads)
+        .set_num_experts(2).set_experts_per_token(1).set_expert_width(16).set_vocab_size(64)
+        .set_max_iter(steps).set_global_batch_size(1).set_seed(1)
+    )
+    names = (MLMetrics.TRAIN_LM_FOLD_CHUNKS, MLMetrics.TRAIN_LM_FOLD_CHUNKS_VISITED)
+    before = [metrics.get(MLMetrics.TRAIN_GROUP, name) or 0 for name in names]
+    with trace.capture() as recorder:
+        est.fit(df)
+    (program,) = [s.attrs for s in recorder.snapshot() if s.name == "train.program"]
+    visited, pairs = fold_chunk_counts(t, t, 0, True)
+    assert 0 < program["fold_chunks_visited"] < program["fold_chunks"]
+    assert (program["fold_chunks_visited"], program["fold_chunks"]) == (layers * heads * visited, layers * heads * pairs)
+    counted = [metrics.get(MLMetrics.TRAIN_GROUP, name) - b for name, b in zip(names, before)]
+    assert counted == [steps * program["fold_chunks"], steps * program["fold_chunks_visited"]]
+    assert np.isfinite(est.loss_history).all()
