@@ -2,9 +2,11 @@
 (``decoder_lm.py``) and the plain references (``reference.py``,
 ``reference_zaya.py``) so that one set of weights can be handed to both.
 
-One stack, two kinds of block (``LMConfig.block``). The tree: ``{"embed": [V,
+One stack, three kinds of block (``LMConfig.block``). The tree: ``{"embed": [V,
 d], "layers": [layer, ...], "final_norm": [d], "lm_head": [d, V]}``; a tied
-head (``LMConfig.tied``) has no ``lm_head``: the head is ``embed`` transposed.
+head (``LMConfig.tied``) has no ``lm_head``: the head is ``embed`` transposed;
+the ``ouro`` kind adds the exit gate ``"exit_gate_w": [d, 1], "exit_gate_b":
+[1]`` after them.
 A matrix maps ``x @ W`` (``[in, out]``: the transpose of a ``torch.nn.Linear``
 weight). ``H`` below is the number of experts HELD here (``experts_held``, or
 all ``n_experts``): experts ``first_held .. first_held + H`` of the ``E`` the
@@ -25,6 +27,13 @@ head_dim, head_dim], "conv1_b": [g, head_dim], "k_temp": [n_kv_heads], "wo":
 [a, d]``; ``"router_in": [d, r], "router_gamma": [r]`` (layers past the first),
 ``"router_norm": [r], "router_w1"/"router_w2": [r, r], "router_w3": [r, E]``;
 the experts as above.
+
+``ouro`` (a dense sandwich-norm layer, run ``loops`` times over the same
+leaves; the equations are in ``reference_ouro.py``), with ``a = n_heads *
+head_dim`` and ``h = expert_width`` the width of the one dense SwiGLU:
+``"attn_norm": [d], "wq"/"wk"/"wv": [d, a], "wo": [a, d], "attn_out_norm":
+[d], "ffn_norm": [d], "w_gate"/"w_up": [d, h], "w_down": [h, d],
+"ffn_out_norm": [d]``. It has no experts: ``n_experts`` and ``top_k`` are 0.
 """
 from __future__ import annotations
 
@@ -32,7 +41,7 @@ from typing import List, NamedTuple, Tuple
 
 __all__ = ["LMConfig", "BLOCKS", "param_shapes", "num_params", "ONES", "ZEROS", "NORMAL", "SMALL", "SMALL_SCALE"]
 
-BLOCKS = ("olmoe", "zaya")
+BLOCKS = ("olmoe", "zaya", "ouro")
 #: How a leaf starts: at one (norm weights, scales), at zero (biases, the
 #: router's depth-averaging weight), at ``init_std * normal``, or - the zaya
 #: block's attention output projection - at ``SMALL_SCALE * init_std * normal``.
@@ -65,6 +74,10 @@ class LMConfig(NamedTuple):
     head_size: int = 0  # 0: hidden / n_heads
     rope_fraction: float = 1.0  # share of each head's channels RoPE turns
     router_width: int = 0
+    # the ouro block's own: passes of the stack over the same leaves, and the
+    # weight of the exit distribution's entropy in the loss
+    loops: int = 1
+    exit_beta: float = 0.0
 
     @property
     def head_dim(self) -> int:
@@ -116,7 +129,17 @@ def _zaya_leaves(cfg: LMConfig, i: int):
     )
 
 
-_LEAVES = {"olmoe": _olmoe_leaves, "zaya": _zaya_leaves}
+def _ouro_leaves(cfg: LMConfig, i: int):
+    d, a, h = cfg.hidden, cfg.n_heads * cfg.head_dim, cfg.expert_width
+    return (
+        ("attn_norm", (d,), ONES), ("wq", (d, a), NORMAL), ("wk", (d, a), NORMAL), ("wv", (d, a), NORMAL),
+        ("wo", (a, d), NORMAL), ("attn_out_norm", (d,), ONES), ("ffn_norm", (d,), ONES),
+        ("w_gate", (d, h), NORMAL), ("w_up", (d, h), NORMAL), ("w_down", (h, d), NORMAL),
+        ("ffn_out_norm", (d,), ONES),
+    )
+
+
+_LEAVES = {"olmoe": _olmoe_leaves, "zaya": _zaya_leaves, "ouro": _ouro_leaves}
 
 
 def param_shapes(cfg: LMConfig) -> List[Tuple[tuple, tuple, str]]:
@@ -129,6 +152,8 @@ def param_shapes(cfg: LMConfig) -> List[Tuple[tuple, tuple, str]]:
     out.append((("final_norm",), (cfg.hidden,), ONES))
     if not cfg.tied:
         out.append((("lm_head",), (cfg.hidden, cfg.vocab), NORMAL))
+    if cfg.block == "ouro":  # the exit gate every pass ends in: a linear with a bias
+        out += [(("exit_gate_w",), (cfg.hidden, 1), NORMAL), (("exit_gate_b",), (1,), ZEROS)]
     return out
 
 

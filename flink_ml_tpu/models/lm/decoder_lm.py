@@ -13,8 +13,15 @@ head, the loss's chunking, the clip and the AdamW program are one:
   on 2 key/value heads, q and k mixed by two short causal convolutions, RoPE
   on part of each head, the fold on grouped queries - and a router that is an
   MLP whose hidden state each block hands the next; learned residual scaling.
+- ``ouro`` (Ouro-2.6B's looped LM; ``reference_ouro.py``): a dense layer with
+  a norm before and after each sublayer (multi-head attention with RoPE, one
+  SwiGLU), the whole stack run ``numLoops`` times over the same leaves; every
+  pass ends in the final norm and an exit gate, the head reads every pass,
+  and the loss weights the passes' cross-entropies token by token with the
+  exit distribution the gates give, less ``exitEntropyCoef`` times its
+  entropy. ``transform`` scores the last pass.
 
-Either kind may tie the head to the embedding (``tieEmbeddings``: one leaf)
+Either expert kind may tie the head to the embedding (``tieEmbeddings``: one leaf)
 and hold a range of each block's experts (``expertsHeld``,
 ``firstExpertHeld``: one chip's share of an expert-parallel layer; tokens
 routed elsewhere get nothing from the block, and ``vocabSize`` is then the
@@ -48,7 +55,9 @@ chip): the experts' backward recomputes their two hidden projections from
 the sorted rows (``parallel/moe.py``), the head's ``[tokens, vocabulary]``
 logits exist one token chunk at a time, forward and backward, and where there
 is more than one block each is rematerialised in the backward
-(``jax.checkpoint``).
+(``jax.checkpoint``). The looped stack is a ``lax.scan`` over its passes (the
+traced program is one pass): what it holds for the backward is the input of
+each of its ``layers x loops`` block applications and each pass's output.
 
 The fitted model keeps its parameters on the device; ``save`` and
 ``get_model_data`` fetch them (2.5 GB at OLMoE's widths is seconds of
@@ -120,7 +129,10 @@ class _LMParams(
         "expertsPerToken", "Experts each token is routed to (top-k, not renormalised).", 2,
         ParamValidators.gt(0),
     )
-    EXPERT_WIDTH = IntParam("expertWidth", "Hidden width of one SwiGLU expert.", 64, ParamValidators.gt(0))
+    EXPERT_WIDTH = IntParam(
+        "expertWidth", "Hidden width of one SwiGLU expert ('ouro': of the block's one dense SwiGLU).", 64,
+        ParamValidators.gt(0),
+    )
     VOCAB_SIZE = IntParam(
         "vocabSize", "Vocabulary size; 0 infers max(token) + 1 from the training data.", 0,
         ParamValidators.gt_eq(0),
@@ -134,7 +146,8 @@ class _LMParams(
     BLOCK_KIND = StringParam(
         "blockKind",
         "The decoder block: 'olmoe' (multi-head attention with QK-norm, a linear router) or "
-        "'zaya' (compressed convolutional attention on grouped queries, an MLP router).",
+        "'zaya' (compressed convolutional attention on grouped queries, an MLP router) or "
+        "'ouro' (a dense sandwich-norm layer, the stack run numLoops times over the same weights).",
         "olmoe", ParamValidators.in_array(list(BLOCKS)),
     )
     TIE_EMBEDDINGS = BoolParam("tieEmbeddings", "The head is the embedding table transposed.", False)
@@ -157,6 +170,14 @@ class _LMParams(
         ParamValidators.in_range(0.0, 1.0, lower_inclusive=False),
     )
     ROUTER_WIDTH = IntParam("routerWidth", "Width of the router MLP ('zaya').", 256, ParamValidators.gt(0))
+    NUM_LOOPS = IntParam(
+        "numLoops", "Passes of the whole stack over the same weights, each ending in the final norm "
+        "and the exit gate ('ouro').", 1, ParamValidators.gt(0),
+    )
+    EXIT_ENTROPY_COEF = FloatParam(
+        "exitEntropyCoef", "Weight of the exit distribution's entropy, subtracted from the loss ('ouro').",
+        0.1, ParamValidators.gt_eq(0),
+    )
     COMPUTE_TYPE = StringParam(
         "computeType",
         "Matmul input dtype: 'bfloat16' runs every matmul and the attention "
@@ -193,6 +214,13 @@ class _LMParams(
                                  f"{cfg.rope_fraction} of {cfg.head_dim}")
         elif self.get(self.NUM_KV_HEADS) or self.get(self.HEAD_SIZE) or self.get(self.ROPE_FRACTION) != 1.0:
             raise ValueError("numKvHeads, headSize and ropeFraction belong to blockKind 'zaya'")
+        if cfg.block == "ouro":  # a dense block: no experts, no router, nothing to balance
+            cfg = cfg._replace(n_experts=0, top_k=0, aux_coef=0.0, loops=self.get(self.NUM_LOOPS),
+                               exit_beta=self.get(self.EXIT_ENTROPY_COEF))
+            if cfg.tied or cfg.experts_held or cfg.first_held:
+                raise ValueError("tieEmbeddings, expertsHeld and firstExpertHeld do not belong to blockKind 'ouro'")
+        elif self.get(self.NUM_LOOPS) != 1:
+            raise ValueError("numLoops belongs to blockKind 'ouro'")
         if not cfg.head_size and cfg.hidden % cfg.n_heads:
             raise ValueError(f"hiddenSize {cfg.hidden} must divide evenly by numHeads {cfg.n_heads}")
         if cfg.head_dim % 2:
@@ -226,7 +254,8 @@ _add_accessors(_LMParams, (
     ("NORM_EPS", "norm_eps"), ("AUX_LOSS_COEF", "aux_loss_coef"), ("BLOCK_KIND", "block_kind"),
     ("TIE_EMBEDDINGS", "tie_embeddings"), ("EXPERTS_HELD", "experts_held"),
     ("FIRST_EXPERT_HELD", "first_expert_held"), ("NUM_KV_HEADS", "num_kv_heads"), ("HEAD_SIZE", "head_size"),
-    ("ROPE_FRACTION", "rope_fraction"), ("ROUTER_WIDTH", "router_width"),
+    ("ROPE_FRACTION", "rope_fraction"), ("ROUTER_WIDTH", "router_width"), ("NUM_LOOPS", "num_loops"),
+    ("EXIT_ENTROPY_COEF", "exit_entropy_coef"),
 ))
 
 
@@ -433,24 +462,60 @@ def _zaya_block(x, carry, layer, cfg: LMConfig, cd, interpret: bool):
     return _scaled(x, y.reshape(b, t, d), layer, "ffn"), carry, stats
 
 
-_BLOCKS = {"olmoe": _olmoe_block, "zaya": _zaya_block}
+# -- the ouro block (reference_ouro.py carries each equation's origin) -----------
+
+
+def _ouro_block(x, carry, layer, cfg: LMConfig, cd, interpret: bool):
+    """A dense layer in sandwich norms: a norm before each sublayer and one on
+    its output, inside the residual branch. No experts: no statistics."""
+    b, t, _ = x.shape
+    h = cfg.n_heads
+    a = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+    cos, sin = _rope_tables(t, cfg.head_dim, cfg.rope_theta)
+    q, k, v = (_heads(_matmul(a, layer[w], cd), h) for w in ("wq", "wk", "wv"))
+    o = _fold(_rope(q, cos, sin), _rope(k, cos, sin), v, cd, interpret)
+    o = _matmul(jnp.transpose(o, (0, 2, 1, 3)).reshape(b, t, h * cfg.head_dim), layer["wo"], cd)
+    x = x + _rms_norm(o, layer["attn_out_norm"], cfg.norm_eps)
+    m = _rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
+    y = _matmul(jax.nn.silu(_matmul(m, layer["w_gate"], cd)) * _matmul(m, layer["w_up"], cd),
+                layer["w_down"], cd)
+    return x + _rms_norm(y, layer["ffn_out_norm"], cfg.norm_eps), carry, {}
+
+
+_BLOCKS = {"olmoe": _olmoe_block, "zaya": _zaya_block, "ouro": _ouro_block}
 
 
 def _hidden(params, tok, cfg: LMConfig, cd, interpret: bool):
-    """The final-normed hidden states ``[B, T, d]`` and each block's router
-    statistics. ``carry`` is what a block hands the next beside the residual
-    stream: nothing (``olmoe``), the router's hidden state (``zaya``)."""
+    """The final-normed hidden states, each block's router statistics and the
+    exit gate's logits. ``carry`` is what a block hands the next beside the
+    residual stream: nothing (``olmoe``, ``ouro``), the router's hidden state
+    (``zaya``). A tree without an exit gate passes its stack once: ``[B, T,
+    d]``, no logits. With one, the stack runs ``cfg.loops`` times over the
+    same leaves, each pass's normed state feeding the next: ``[R, B, T, d]``
+    and the gate's logits ``[R, B, T]``."""
     x = params["embed"][tok]
     block = functools.partial(_BLOCKS[cfg.block], cfg=cfg, cd=cd, interpret=interpret)
-    if cfg.n_layers > 1:
+    if cfg.n_layers * cfg.loops > 1:
         # a lone block's residuals are wanted as soon as the head's backward
         # ends: holding them costs nothing at the peak, recomputing them a forward
         block = jax.checkpoint(block)
-    routed, carry = [], None
-    for layer in params["layers"]:
-        x, carry, stats = block(x, carry, layer)
-        routed.append(stats)
-    return _rms_norm(x, params["final_norm"], cfg.norm_eps), routed
+
+    def stack(x):
+        routed, carry = [], None
+        for layer in params["layers"]:
+            x, carry, stats = block(x, carry, layer)
+            routed.append(stats)
+        return _rms_norm(x, params["final_norm"], cfg.norm_eps), routed
+
+    if "exit_gate_w" not in params:
+        return stack(x) + (None,)
+
+    def one_pass(h, _):
+        h, _ = stack(h)
+        return h, (h, jnp.sum(h * params["exit_gate_w"][:, 0], axis=-1) + params["exit_gate_b"][0])
+
+    _, (passes, gate) = jax.lax.scan(one_pass, x, None, length=cfg.loops)
+    return passes, [], gate
 
 
 def _head(params, cfg: LMConfig):
@@ -491,13 +556,49 @@ def _load_balancing(routed, cfg: LMConfig):
     return cfg.n_experts * jnp.sum(f * p)
 
 
+def _exit_distribution(gate):
+    """From the gate's logits ``z [R, B, T]`` the log of ``p_r = sigmoid(z_r)
+    prod_(j<r) (1 - sigmoid(z_j))`` for ``r < R`` and of ``p_R = prod_(j<R)
+    (1 - sigmoid(z_j))``: each token's distribution over the pass it exits at."""
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-gate), axis=0)  # log prod_(j<=r) (1 - lambda_j)
+    before = jnp.concatenate([jnp.zeros_like(gate[:1]), stay[:-1]], axis=0)
+    # the last pass takes what is left: its own gate is not asked
+    leave = jnp.concatenate([jax.nn.log_sigmoid(gate[:-1]), jnp.zeros_like(gate[:1])], axis=0)
+    return leave + before
+
+
+def _exit_loss(passes, gate, lm_head, tok, cfg: LMConfig, cd):
+    """The looped stack's objective: the mean over target positions of ``sum_r
+    p_r nll_r - beta H(p)``, every pass through the one head in its one chunked
+    loop; and what ``train.drain`` reports: each pass's own mean cross-entropy
+    (``trip_nll``) and, summed over the step's tokens, the expected exit pass,
+    the mass left to the last pass and the exit distribution's entropy."""
+    r, b, t, d = passes.shape
+    nll = _next_token_nll(passes.reshape(r * b, t, d), lm_head, jnp.tile(tok, (r, 1)), cd).reshape(r, b, t)
+    log_p = _exit_distribution(gate)
+    every = jnp.exp(log_p)
+    p = every.at[:, :, -1].set(0.0)  # the last position has no target
+    targets = b * (t - 1)
+    loss = (jnp.sum(p * nll) + cfg.exit_beta * jnp.sum(p * log_p)) / targets
+    trip = jnp.arange(1, r + 1, dtype=jnp.float32)[:, None, None]
+    return loss, {"trip_nll": jnp.sum(nll, axis=(1, 2)) / targets, "exit_trip_sum": jnp.sum(trip * every),
+                  "exit_last_mass": jnp.sum(every[-1]), "gate_entropy_sum": -jnp.sum(every * log_p)}
+
+
 def _loss(params, tok, cfg: LMConfig, cd, interpret: bool):
-    h, routed = _hidden(params, tok, cfg, cd, interpret)
-    nll = _next_token_nll(h, _head(params, cfg), tok, cd)
-    loss = jnp.sum(nll) / (tok.shape[0] * (tok.shape[1] - 1))
+    """``(loss, stats)``: ``stats`` holds what the blocks have to report - the
+    rows each expert took (``rows``), the exits' sums (``_exit_loss``)."""
+    h, routed, gate = _hidden(params, tok, cfg, cd, interpret)
+    if gate is None:
+        nll = _next_token_nll(h, _head(params, cfg), tok, cd)
+        loss, stats = jnp.sum(nll) / (tok.shape[0] * (tok.shape[1] - 1)), {}
+    else:
+        loss, stats = _exit_loss(h, gate, _head(params, cfg), tok, cfg, cd)
     if cfg.aux_coef:
         loss = loss + cfg.aux_coef * _load_balancing(routed, cfg)
-    return loss, jnp.stack([s["rows"] for s in routed])
+    if routed:
+        stats["rows"] = jnp.stack([s["rows"] for s in routed])
+    return loss, stats
 
 
 def _optimizer(lr: float):
@@ -512,16 +613,16 @@ def _train_program(cfg: LMConfig, compute_type: str, lr: float, batch: int, inte
     """``(optimizer, step)``; ``step(params, opt_state, window, lo)`` trains on
     rows ``lo .. lo + batch`` of the device-resident window and returns the new
     state, the loss, every parameter's gradient norm before clipping (in
-    ``param_shapes`` order) and the rows each expert of each block took."""
+    ``param_shapes`` order) and the step's statistics (``_loss``)."""
     cd = jnp.dtype(compute_type)
     optimizer = _optimizer(lr)
 
     def step(params, opt_state, window, lo):
         tok = jax.lax.dynamic_slice_in_dim(window, lo, batch, axis=0)
-        (loss, rows), grads = jax.value_and_grad(_loss, has_aux=True)(params, tok, cfg, cd, interpret)
+        (loss, stats), grads = jax.value_and_grad(_loss, has_aux=True)(params, tok, cfg, cd, interpret)
         norms = jnp.stack([jnp.sqrt(jnp.sum(g * g)) for g in _ordered(grads, cfg)])
         updates, opt_state = optimizer.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt_state, loss, norms, rows
+        return optax.apply_updates(params, updates), opt_state, loss, norms, stats
 
     return optimizer, jax.jit(step, donate_argnums=(0, 1))
 
@@ -531,7 +632,9 @@ def _log_likelihood_program(cfg: LMConfig, compute_type: str, interpret: bool):
     cd = jnp.dtype(compute_type)
 
     def run(params, tok):
-        h, _ = _hidden(params, tok, cfg, cd, interpret)
+        h, _, gate = _hidden(params, tok, cfg, cd, interpret)
+        if gate is not None:
+            h = h[-1]  # the exit threshold is 1: no token leaves before the last pass
         nll = _next_token_nll(h, _head(params, cfg), tok, cd)
         return -jnp.sum(nll, axis=1) / (tok.shape[1] - 1)
 
@@ -621,14 +724,16 @@ class DecoderLMModel(Model, _LMParams):
 
 
 class DecoderLM(Estimator, _LMParams):
-    """AdamW training of a decoder-only MoE language model on token-id vectors.
+    """AdamW training of a decoder-only language model on token-id vectors.
 
     The features column holds equal-length token-id vectors (length a
     multiple of 256). ``globalBatchSize`` counts ROWS (sequences): tokens a step =
     rows x length. After ``fit``, per step: ``loss_history``,
     ``grad_norm_history`` (global, before clipping), ``param_grad_norm_history``
-    (``[steps, parameters]``, columns named by ``param_names``) and
-    ``expert_rows_history`` (``[steps, layers, experts]`` routed rows)."""
+    (``[steps, parameters]``, columns named by ``param_names``),
+    ``expert_rows_history`` (``[steps, layers, experts]`` routed rows; no
+    experts, no columns) and ``trip_loss_history`` (``[steps, passes]``: each
+    pass's own mean cross-entropy; a stack passed once has no columns)."""
 
     def fit(self, *inputs) -> DecoderLMModel:
         (df,) = inputs
@@ -661,49 +766,64 @@ class DecoderLM(Estimator, _LMParams):
             # what the causal fold's three kernels walk in one step, each counted once
             # (a rematerialised forward not again), and what the mask lets them skip
             visited, pairs = fold_chunk_counts(t, t, 0, True)
-            folds = cfg.n_layers * cfg.n_heads * batch
+            applications = cfg.n_layers * cfg.loops
+            folds = applications * cfg.n_heads * batch
             phase.set_metadata(built=int(_train_program.cache_info().misses > misses),
-                               fold_chunks=folds * pairs, fold_chunks_visited=folds * visited)
+                               fold_chunks=folds * pairs, fold_chunks_visited=folds * visited,
+                               loop_trips=cfg.loops, layer_applications=applications)
             opt_state = optimizer.init(params)
 
-        losses, leaf_norms, loads = [], [], []  # device values, fetched once after the loop
+        losses, leaf_norms, stats = [], [], []  # device values, fetched once after the loop
         with tracer.phase("train.dispatch", CAT_PRODUCTIVE, steps=steps):
             offset = 0
             for _ in range(steps):
                 lo = min(offset, n - batch)
-                params, opt_state, loss, norms, rows = step(params, opt_state, window, jnp.int32(lo))
+                params, opt_state, loss, norms, step_stats = step(params, opt_state, window, jnp.int32(lo))
                 losses.append(loss)
                 leaf_norms.append(norms)
-                loads.append(rows)
+                stats.append(step_stats)
                 offset = 0 if offset + batch >= n else offset + batch
-        routed = steps * batch * t * cfg.top_k * cfg.n_layers
         with tracer.phase("train.drain", CAT_PRODUCTIVE, steps=steps) as phase:
-            loads = np.asarray(jax.device_get(jnp.stack(loads)))  # [steps, layers, experts]
-            held = loads[:, :, cfg.first_held: cfg.first_held + cfg.held]
-            rows_held = int(held.sum())
-            rows_absent = int(loads.sum()) - rows_held
-            per_block = batch * t * cfg.top_k
-            phase.set_metadata(
-                tokens=steps * batch * t,
-                expert_rows_max=int(loads.max()),
-                expert_rows_mean=per_block // cfg.n_experts,
-                dropped=int(routed - loads.sum()),
-                rows_held=rows_held,
-                rows_absent=rows_absent,
-                held_rows_max=int(held.max()),
-                held_rows_mean=float(held.mean()),
-            )
+            # what the blocks reported, by name, stacked over the steps on the device and fetched once
+            stats = jax.device_get({k: jnp.stack([s[k] for s in stats]) for k in stats[0]})
+            phase.set_metadata(tokens=steps * batch * t)
+            loads = np.asarray(stats.get("rows", np.zeros((steps, cfg.n_layers, 0), np.int32)))
+            if loads.size:  # [steps, layers, experts]
+                held = loads[:, :, cfg.first_held: cfg.first_held + cfg.held]
+                rows_held = int(held.sum())
+                rows_absent = int(loads.sum()) - rows_held
+                phase.set_metadata(
+                    expert_rows_max=int(loads.max()),
+                    expert_rows_mean=batch * t * cfg.top_k // cfg.n_experts,
+                    dropped=int(steps * batch * t * cfg.top_k * cfg.n_layers - loads.sum()),
+                    rows_held=rows_held,
+                    rows_absent=rows_absent,
+                    held_rows_max=int(held.max()),
+                    held_rows_mean=float(held.mean()),
+                )
+            trips = np.asarray(stats.get("trip_nll", np.zeros((steps, 0))), np.float64)
+            if trips.size:  # [steps, passes]
+                phase.set_metadata(
+                    exit_trip_sum=float(stats["exit_trip_sum"].sum()),
+                    exit_last_mass=float(stats["exit_last_mass"].sum()),
+                    gate_entropy_sum=float(stats["gate_entropy_sum"].sum()),
+                    trip_nll=[float(x) for x in trips.mean(axis=0)],
+                )
         with tracer.phase("train.readback", CAT_READBACK, bytes=4 * steps * (1 + len(param_shapes(cfg)))):
             self.loss_history = [float(x) for x in jax.device_get(losses)]
             self.param_grad_norm_history = np.asarray(jax.device_get(jnp.stack(leaf_norms)), np.float64)
         self.param_names = _flat_names(cfg)
         self.grad_norm_history = [float(x) for x in np.sqrt((self.param_grad_norm_history ** 2).sum(axis=1))]
         self.expert_rows_history = loads
+        self.trip_loss_history = trips
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_TOKENS, steps * batch * t)
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_CHUNKS, steps * folds * pairs)
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_CHUNKS_VISITED, steps * folds * visited)
-        metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_MOE_ROWS, rows_held)
-        metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_MOE_ROWS_ABSENT, rows_absent)
+        metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_LOOP_TRIPS, steps * cfg.loops)
+        metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_LOOP_LAYER_APPLICATIONS, steps * applications)
+        if loads.size:
+            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_MOE_ROWS, rows_held)
+            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_MOE_ROWS_ABSENT, rows_absent)
 
         model = DecoderLMModel()
         update_existing_params(model, self)

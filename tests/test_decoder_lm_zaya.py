@@ -140,10 +140,10 @@ def test_every_parameters_gradient(params, tokens, compute_type, leaf_tol, norm_
     of the plain reference."""
     tok = _batches(tokens)[0]
     want_loss, want = ref.loss_and_grads(params, tok, CFG)
-    (loss, rows), got = jax.value_and_grad(decoder_lm._loss, has_aux=True)(
+    (loss, stats), got = jax.value_and_grad(decoder_lm._loss, has_aux=True)(
         params, tok, CFG, jnp.dtype(compute_type), True)
     assert _rel(loss, want_loss) < (2e-6 if leaf_tol else 5e-3)
-    assert int(rows.sum()) == CFG.n_layers * BATCH * T
+    assert int(stats["rows"].sum()) == CFG.n_layers * BATCH * T
     for name, g, w in zip(_flat_names(CFG), _ordered(got, CFG), _ordered(want, CFG)):
         assert float(jnp.max(jnp.abs(w))) > 0, name  # no leaf's gradient is zero by construction
         if leaf_tol:

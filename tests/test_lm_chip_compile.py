@@ -188,3 +188,52 @@ def test_the_zaya_step_program_at_the_cells_shapes(one_chip):
     kv = f"bf16[{batch * cfg.kv_heads},{t},{cfg.head_dim}]"
     assert kv in text  # K and V enter the kernels once per key/value head
     assert f"f32[{batch},{cfg.n_heads},{t},{t}]" not in text and f"f32[{batch * cfg.n_heads},{t},{t}]" not in text
+
+
+def test_the_ouro_step_program_at_the_cells_shapes(one_chip):
+    """The whole jitted step of the ``ouro_2_6b`` configuration at 2 x 4,096
+    tokens: six rematerialised dense blocks inside one scanned pass run four
+    times, four passes of the head. It fits the chip (XLA's analysis), the
+    program holds ONE pass and not four (a pass's 24 kernel calls, the block
+    inputs stacked over the four trips), and the three fold kernels are there."""
+    import json
+    import os
+    import re
+
+    from flink_ml_tpu.models.lm import decoder_lm
+    from flink_ml_tpu.models.lm.config import LMConfig, num_params
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perfbench", "configs", "ouro_2_6b.json"), encoding="utf-8") as f:
+        c = json.load(f)
+    cfg = LMConfig(
+        c["num_hidden_layers"], c["hidden_size"], c["num_attention_heads"], 0, 0, c["intermediate_size"],
+        c["vocab_size"], rope_theta=float(c["rope_theta"]), norm_eps=float(c["rms_norm_eps"]), aux_coef=0.0,
+        block="ouro", loops=c["total_ut_steps"], exit_beta=c["exit_entropy_coef"])
+    assert num_params(cfg) == 509_661_185  # 8.15 GB of f32 state at 16 bytes a parameter: 47% of 16 GiB
+    batch, t = c["global_batch_size"], c["sequence_length"]
+    optimizer, step = decoder_lm._train_program(cfg, c["compute_dtype"], c["learning_rate"], batch, False)
+    params = jax.eval_shape(lambda: decoder_lm._init_program(cfg)(jax.random.key(0)))
+    state = jax.eval_shape(optimizer.init, params)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+    compiled = step.lower(
+        on_chip(params), on_chip(state),
+        jax.ShapeDtypeStruct((c["num_sequences"], t), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+    ).compile()
+    memory = compiled.memory_analysis()
+    live = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert 12 * num_params(cfg) <= memory.argument_size_in_bytes and live < 15.75e9, live
+    text = compiled.as_text()
+    for kernel in ("flash_fold_fwd", "flash_fold_bwd_dq", "flash_fold_bwd_dkv"):
+        assert kernel in text
+    calls = len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text))
+    # one pass's kernels (a layer's forward, recomputed forward, dq and dkv), not four passes'
+    assert 4 * cfg.n_layers <= calls < 2 * 4 * cfg.n_layers
+    stacked = f"f32[{cfg.loops},{batch},{t},{cfg.hidden}]"  # what the forward loop holds for the backward, per pass
+    assert stacked in text
+    assert f"f32[{batch},{cfg.n_heads},{t},{t}]" not in text and f"f32[{batch * cfg.n_heads},{t},{t}]" not in text

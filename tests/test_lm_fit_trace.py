@@ -72,7 +72,8 @@ def test_counts_are_what_the_job_says(traced_fit):
     # 256 keys are one chunk, taken whole: the forward's one tile, the dq kernel's four
     # of 64 rows and the dkv kernel's one pair, nothing to hide
     pairs = (1 + 4 + 1) * LAYERS * 2 * BATCH
-    assert one["train.program"] == {"built": 0, "fold_chunks": pairs, "fold_chunks_visited": pairs}
+    assert one["train.program"] == {"built": 0, "fold_chunks": pairs, "fold_chunks_visited": pairs,
+                                    "loop_trips": 1, "layer_applications": LAYERS}
     assert one["train.dispatch"] == {"steps": STEPS}
     drain = one["train.drain"]
     assert drain["steps"] == STEPS and drain["tokens"] == STEPS * BATCH * T
@@ -116,3 +117,41 @@ def test_the_causal_fold_reports_the_chunks_it_skips():
     counted = [metrics.get(MLMetrics.TRAIN_GROUP, name) - b for name, b in zip(names, before)]
     assert counted == [steps * program["fold_chunks"], steps * program["fold_chunks_visited"]]
     assert np.isfinite(est.loss_history).all()
+
+
+def test_a_looped_stack_reports_its_trips_and_its_exits():
+    """``blockKind`` ``ouro``: the fold's chunks and the two loop counters are
+    counted over ``layers x passes`` block applications; ``train.drain`` carries
+    the exits' sums and each pass's loss and, the block having no experts, no
+    expert count; nothing is counted on ``ml.train.moe.*``."""
+    layers, loops, heads, steps = 2, 3, 2, 2
+    df = DataFrame.from_dict({"features": np.random.default_rng(2).integers(0, 64, (N, T))})
+    est = (
+        DecoderLM().set_block_kind("ouro").set_num_layers(layers).set_hidden_size(32).set_num_heads(heads)
+        .set_expert_width(48).set_vocab_size(64).set_num_loops(loops)
+        .set_max_iter(steps).set_global_batch_size(BATCH).set_seed(1)
+    )
+    names = (MLMetrics.TRAIN_LM_LOOP_TRIPS, MLMetrics.TRAIN_LM_LOOP_LAYER_APPLICATIONS,
+             MLMetrics.TRAIN_LM_FOLD_CHUNKS, MLMetrics.TRAIN_MOE_ROWS, MLMetrics.TRAIN_MOE_ROWS_ABSENT)
+    before = [metrics.get(MLMetrics.TRAIN_GROUP, name) or 0 for name in names]
+    with trace.capture() as recorder:
+        est.fit(df)
+    spans = sorted(recorder.snapshot(), key=lambda s: s.span_id)
+    parents = {s.span_id: s.name for s in spans}
+    assert [(s.name, parents.get(s.parent_id), s.category) for s in spans] == TREE
+    one = {s.name: s.attrs for s in spans}
+    pairs = (1 + 4 + 1) * layers * loops * heads * BATCH  # T 256 is one chunk, as above
+    assert one["train.program"] == {"built": 1, "fold_chunks": pairs, "fold_chunks_visited": pairs,
+                                    "loop_trips": loops, "layer_applications": layers * loops}
+    drain = one["train.drain"]
+    assert set(drain) == {"steps", "tokens", "exit_trip_sum", "exit_last_mass", "gate_entropy_sum", "trip_nll"}
+    tokens = steps * BATCH * T
+    assert drain["tokens"] == tokens
+    # a gate two steps old (lambda still near 1/2): p near 1/2, 1/4, 1/4
+    assert 1.5 < drain["exit_trip_sum"] / tokens < 2.0
+    assert 0.15 < drain["exit_last_mass"] / tokens < 0.35
+    assert 0.9 < drain["gate_entropy_sum"] / tokens <= 1.5 * np.log(2) + 1e-6
+    assert drain["trip_nll"] == pytest.approx(list(est.trip_loss_history.mean(axis=0)))
+    assert est.trip_loss_history.shape == (steps, loops) and est.expert_rows_history.shape == (steps, layers, 0)
+    counted = [metrics.get(MLMetrics.TRAIN_GROUP, name) or 0 for name in names]
+    assert [c - b for c, b in zip(counted, before)] == [steps * loops, steps * layers * loops, steps * pairs, 0, 0]
