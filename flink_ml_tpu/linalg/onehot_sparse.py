@@ -825,12 +825,38 @@ def mult_crossing_pallas(mult3, rhi, rlo, row_hi, interpret: bool = False):
 # never SHIPS one-hots (73x the ingest) — instead each window's one-hots
 # are materialized ON DEVICE from the just-landed rowid stacks in the
 # prefetch gap, bounding storage at the two prefetch-live windows.
+#
+# Orientation: the ENTRIES lie on the lanes — ``[..., row_hi, n_pad]`` and
+# ``[..., 128, n_pad]`` — like every array around the kernels (q, u, the
+# packed stacks: ``[n_sub, n_flat]``). With the entries on the sublanes
+# (``[..., n_pad, row_hi]``, the build-form kernels' in-VMEM shape) the mult
+# crossing's per-entry result came out one value a sublane and had to be
+# laid out again as the lane-major output block: at the Criteo cell's shape
+# (4 x 956,753 entries, row_hi 128; chip runs, PR 34) that kernel took 6.36
+# ms whole, 5.98 without the lane reduction, and 2.64 with neither reduction
+# nor relayout — the relayout held 3.3 ms, the reduction 0.4. Here the
+# reduction runs over the 128 row-lows on the SUBLANES (vreg adds and one
+# fold) and its result is already the ``[1, tile]`` block the output takes;
+# the dot crossing's q broadcasts down the sublanes and its contraction over
+# entries is over the last axis of both operands (the ``q k^T`` form). Both
+# then stream their 1.96 GB in 2.68 ms (89% of the HBM rate), at any tile
+# from 4,096 to 16,384 and whether or not the cell is cut into chunks.
+#
+# Tile: at row_hi < 64 the old ``[tile, row_hi]`` block padded row_hi to 128
+# lanes and the full tile overran scoped VMEM, hence the halving. The
+# ``[row_hi, tile]`` block pads row_hi to the 16-sublane bf16 tile only and
+# the full tile compiles at every row_hi (4, 16, 64, 128 compiled for v5e).
+# The halving stays all the same: ``premat_bytes``' padding — what the
+# optimizer's HBM gate budgets — follows this tile. What it costs: at row_hi
+# 16 and the cell's entry count the mult crossing took 1.75 ms at the half
+# tile and 1.57 at the full one (chip runs, PR 34); at row_hi 128, where the
+# tile is full, 4,096 and 8,192 measured alike.
 # ---------------------------------------------------------------------------
 
 
 def _premat_tile(n: int, row_hi: int) -> int:
     """One tile policy for BOTH premat kernels (the storage pad must divide
-    evenly for each) — mirrors dot_crossing_pallas' row_hi < 64 halving."""
+    evenly for each)."""
     return min(_CROSS_TILE if row_hi >= 64 else _CROSS_TILE // 2, max(n, 1))
 
 
@@ -846,21 +872,31 @@ def premat_bytes(n_units: int, n_flat: int, row_hi: int) -> int:
     return 2 * n_units * _premat_pad(n_flat, row_hi) * (row_hi + _ROW_LO)
 
 
+def _sublane_onehot(ids, width, dtype=jnp.bfloat16):
+    """[..., n] int32 -> [..., width, n] one-hot: ``_lane_onehot`` with the
+    ids' axis left minor."""
+    shape = ids.shape[:-1] + (width, ids.shape[-1])
+    iota = jax.lax.broadcasted_iota(jnp.int32, shape, ids.ndim - 1)
+    return (ids[..., None, :] == iota).astype(dtype)
+
+
 def premat_row_onehots(rowid, row_hi: int):
     """Packed rowid stacks ``[..., n_flat]`` int16 -> materialized bf16 row
-    one-hots ``(oh_hi [..., n_pad, row_hi], oh_lo [..., n_pad, 128])``, the
-    entry axis padded to the premat crossing tile with all-zero oh rows
-    (padding contributes nothing to the dot crossing even if the caller's
-    padded q slots are garbage; the mult crossing's padded outputs are
-    sliced off). Built once per layout, outside the training scan."""
+    one-hots ``(oh_hi [..., row_hi, n_pad], oh_lo [..., 128, n_pad])``, the
+    entry axis (minor: entries on the lanes) padded to the premat crossing
+    tile with all-zero oh columns (padding contributes nothing to the dot
+    crossing even if the caller's padded q slots are garbage; the mult
+    crossing's padded outputs are sliced off). Built once per layout,
+    outside the training scan."""
     n = rowid.shape[-1]
     pad = _premat_pad(n, row_hi) - n
     rid = rowid.astype(jnp.int32)
-    oh_hi, oh_lo = _row_onehots(rid // _ROW_LO, rid % _ROW_LO, row_hi)
+    oh_hi = _sublane_onehot(rid // _ROW_LO, row_hi)
+    oh_lo = _sublane_onehot(rid % _ROW_LO, _ROW_LO)
     if pad:
-        width = [(0, 0)] * (rowid.ndim - 1)
-        oh_hi = jnp.pad(oh_hi, width + [(0, pad), (0, 0)])
-        oh_lo = jnp.pad(oh_lo, width + [(0, pad), (0, 0)])
+        width = [(0, 0)] * rowid.ndim + [(0, pad)]
+        oh_hi = jnp.pad(oh_hi, width)
+        oh_lo = jnp.pad(oh_lo, width)
     return oh_hi, oh_lo
 
 
@@ -875,19 +911,23 @@ def _premat_window(oh_hi, oh_lo, wi):
     return oh_hi, oh_lo
 
 
+def _pad_entries(q, n_pad):
+    """Zero q on the one-hots' padded slots: contributes nothing."""
+    if q.shape[1] < n_pad:
+        q = jnp.pad(q, ((0, 0), (0, n_pad - q.shape[1])))
+    return q
+
+
 def dot_crossing_premat_xla(q, oh_hi, oh_lo, wi=0):
     """``dot_crossing_xla`` with the one-hots supplied instead of built.
     ``q`` [n_sub, n] (n <= the one-hots' padded entry axis)."""
     oh_hi, oh_lo = _premat_window(oh_hi, oh_lo, wi)
-    n_pad = oh_hi.shape[1]
-    if q.shape[1] < n_pad:  # zero q on padded slots: contributes nothing
-        q = jnp.pad(q, ((0, 0), (0, n_pad - q.shape[1])))
-    q_hi, q_lo = _split_bf16(q)
-    dims = (((1,), (1,)), ((0,), (0,)))
+    q_hi, q_lo = _split_bf16(_pad_entries(q, oh_hi.shape[2]))
+    dims = (((2,), (2,)), ((0,), (0,)))  # contract entries, batch subs
     return jax.lax.dot_general(
-        oh_hi, oh_lo * q_hi[..., None], dims, preferred_element_type=jnp.float32
+        oh_hi, oh_lo * q_hi[:, None], dims, preferred_element_type=jnp.float32
     ) + jax.lax.dot_general(
-        oh_hi, oh_lo * q_lo[..., None], dims, preferred_element_type=jnp.float32
+        oh_hi, oh_lo * q_lo[:, None], dims, preferred_element_type=jnp.float32
     )
 
 
@@ -896,22 +936,42 @@ def mult_crossing_premat_xla(mult3, oh_hi, oh_lo, wi=0):
     entry axis; the caller slices to its n)."""
     oh_hi, oh_lo = _premat_window(oh_hi, oh_lo, wi)
     m_hi, m_lo = _split_bf16(mult3)
-    dims = (((2,), (1,)), ((0,), (0,)))
+    dims = (((1,), (1,)), ((0,), (0,)))  # contract row_hi, batch subs
     rowvecs = jax.lax.dot_general(
-        oh_hi, m_hi, dims, preferred_element_type=jnp.float32
+        m_hi, oh_hi, dims, preferred_element_type=jnp.float32
     ) + jax.lax.dot_general(
-        oh_hi, m_lo, dims, preferred_element_type=jnp.float32
+        m_lo, oh_hi, dims, preferred_element_type=jnp.float32
+    )  # [n_sub, 128, n_pad]
+    return jnp.sum(rowvecs * oh_lo.astype(jnp.float32), axis=1)
+
+
+def _premat_grid(oh_hi, oh_lo):
+    """The premat kernels' common frame: windowed stacks
+    ``[n_windows, n_sub, w, n_pad]``, the grid ``(n_sub, ntiles)`` and the
+    BlockSpecs of a ``[w, tile]`` one-hot tile (window ``wi_ref[0]``, chosen
+    in the index map) and of a ``[1, tile]`` tile of a per-entry
+    ``[n_sub, 1, n_pad]`` array."""
+    from jax.experimental import pallas as pl
+
+    if oh_hi.ndim == 3:
+        oh_hi, oh_lo = oh_hi[None], oh_lo[None]
+    _, n_sub, row_hi, n_pad = oh_hi.shape
+    tile = _premat_tile(n_pad, row_hi)
+    oh_spec = lambda w: pl.BlockSpec(
+        (1, 1, w, tile), lambda i, k, wi_ref: (wi_ref[0], i, 0, k)
     )
-    return jnp.sum(rowvecs * oh_lo.astype(jnp.float32), axis=2)
+    entry_spec = pl.BlockSpec((1, 1, tile), lambda i, k, wi_ref: (i, 0, k))
+    return oh_hi, oh_lo, (n_sub, n_pad // tile), oh_spec, entry_spec
 
 
 def dot_crossing_premat_pallas(q, oh_hi, oh_lo, wi=0, interpret: bool = False):
     """``dot_crossing_pallas`` minus the in-kernel one-hot build: tiles of
     the materialized one-hots stream from HBM into product+matmul-only
-    cells. Same contraction, same split-bf16 halves.
+    cells. Same contraction, same split-bf16 halves; ``q`` enters as a
+    ``[1, tile]`` row and broadcasts down the 128 row-lows on the sublanes.
 
     ``oh_hi/oh_lo`` may carry a leading window axis
-    (``[n_windows, n_sub, n_pad, w]``); ``wi`` (traced scalar ok) selects
+    (``[n_windows, n_sub, w, n_pad]``); ``wi`` (traced scalar ok) selects
     the window *inside the BlockSpec index map* via scalar prefetch, so the
     kernel DMAs tiles straight out of the full stack — a
     ``dynamic_index_in_dim`` outside would materialize a multi-GB window
@@ -920,103 +980,95 @@ def dot_crossing_premat_pallas(q, oh_hi, oh_lo, wi=0, interpret: bool = False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    if oh_hi.ndim == 3:
-        oh_hi, oh_lo = oh_hi[None], oh_lo[None]
-    n_windows, n_sub, n_pad, row_hi = oh_hi.shape
-    if q.shape[1] < n_pad:
-        q = jnp.pad(q, ((0, 0), (0, n_pad - q.shape[1])))
-    tile = _premat_tile(n_pad, row_hi)
-    ntiles = n_pad // tile
+    oh_hi, oh_lo, grid, oh_spec, entry_spec = _premat_grid(oh_hi, oh_lo)
+    n_sub, row_hi, n_pad = oh_hi.shape[1:]
 
     def kernel(wi_ref, hi_ref, lo_ref, q_ref, o_ref):
         del wi_ref
-        oh_hi_t = hi_ref[0, 0]  # [tile, row_hi] bf16
-        oh_lo_t = lo_ref[0, 0]  # [tile, 128] bf16
-        q2 = q_ref[:][:, None]  # split AFTER the [T, 1] reshape (see build form)
-        q_hi = q2.astype(jnp.bfloat16)
-        q_lo = (q2 - q_hi.astype(jnp.float32)).astype(jnp.bfloat16)
-        dims = (((0,), (0,)), ((), ()))
+        oh_hi_t = hi_ref[0, 0]  # [row_hi, tile] bf16
+        oh_lo_t = lo_ref[0, 0].astype(jnp.float32)  # [128, tile]
+        q2 = q_ref[0]  # [1, tile] f32
+        q_hi = q2.astype(jnp.bfloat16).astype(jnp.float32)
+        # a 0/1 one-hot times a bf16 value: exact in f32, exact back in bf16
+        p_hi = (oh_lo_t * q_hi).astype(jnp.bfloat16)
+        p_lo = (oh_lo_t * (q2 - q_hi)).astype(jnp.bfloat16)
+        dims = (((1,), (1,)), ((), ()))  # contract entries: the q k^T form
+        # separate matmuls per split half (summing bf16 rhs first would
+        # round the low half away)
         o_ref[0, 0] = jax.lax.dot_general(
-            oh_hi_t, oh_lo_t * q_hi, dims, preferred_element_type=jnp.float32
+            oh_hi_t, p_hi, dims, preferred_element_type=jnp.float32
         ) + jax.lax.dot_general(
-            oh_hi_t, oh_lo_t * q_lo, dims, preferred_element_type=jnp.float32
+            oh_hi_t, p_lo, dims, preferred_element_type=jnp.float32
         )
 
-    oh_spec = lambda w: pl.BlockSpec(
-        (1, 1, tile, w), lambda i, k, wi_ref: (wi_ref[0], i, k, 0)
-    )
     parts = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(n_sub, ntiles),
-            in_specs=[
-                oh_spec(row_hi),
-                oh_spec(_ROW_LO),
-                pl.BlockSpec((tile,), lambda i, k, wi_ref: (i * ntiles + k,)),
-            ],
+            grid=grid,
+            in_specs=[oh_spec(row_hi), oh_spec(_ROW_LO), entry_spec],
             out_specs=pl.BlockSpec(
                 (1, 1, row_hi, _ROW_LO), lambda i, k, wi_ref: (i, k, 0, 0)
             ),
         ),
         out_shape=jax.ShapeDtypeStruct(
-            (n_sub, ntiles, row_hi, _ROW_LO), jnp.float32, vma=vma_of(q)
+            grid + (row_hi, _ROW_LO), jnp.float32, vma=vma_of(q)
         ),
         interpret=interpret,
         name="onehot_dot_crossing_premat",
-    )(jnp.asarray(wi, jnp.int32).reshape(1), oh_hi, oh_lo, q.reshape(-1))
+    )(
+        jnp.asarray(wi, jnp.int32).reshape(1), oh_hi, oh_lo,
+        _pad_entries(q, n_pad).reshape(n_sub, 1, n_pad),
+    )
     return jnp.sum(parts, axis=1)
 
 
 def mult_crossing_premat_pallas(mult3, oh_hi, oh_lo, wi=0, interpret: bool = False):
     """``mult_crossing_pallas`` minus the in-kernel build (returns the padded
     entry axis; the caller slices to its n). Window selection as in
-    ``dot_crossing_premat_pallas``."""
+    ``dot_crossing_premat_pallas``. The multiplier enters transposed
+    (``[128, row_hi]``, 64 KB a sub-batch), so a cell is
+    ``m^T @ oh_hi -> [128, tile]``, masked by ``oh_lo`` and summed over the
+    sublanes into the ``[1, tile]`` output row."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    if oh_hi.ndim == 3:
-        oh_hi, oh_lo = oh_hi[None], oh_lo[None]
-    n_windows, n_sub, n_pad, row_hi = oh_hi.shape
-    tile = _premat_tile(n_pad, row_hi)
-    ntiles = n_pad // tile
+    oh_hi, oh_lo, grid, oh_spec, entry_spec = _premat_grid(oh_hi, oh_lo)
+    n_sub, row_hi, n_pad = oh_hi.shape[1:]
 
     def kernel(wi_ref, m_ref, hi_ref, lo_ref, o_ref):
         del wi_ref
-        oh_hi_t = hi_ref[0, 0]
-        m2 = m_ref[0]
+        oh_hi_t = hi_ref[0, 0]  # [row_hi, tile] bf16
+        m2 = m_ref[0]  # [128, row_hi] f32
         m_hi = m2.astype(jnp.bfloat16)
         m_lo = (m2 - m_hi.astype(jnp.float32)).astype(jnp.bfloat16)
         rowvecs = jnp.dot(
-            oh_hi_t, m_hi, preferred_element_type=jnp.float32
-        ) + jnp.dot(oh_hi_t, m_lo, preferred_element_type=jnp.float32)
-        o_ref[:] = jnp.sum(rowvecs * lo_ref[0, 0].astype(jnp.float32), axis=1)
+            m_hi, oh_hi_t, preferred_element_type=jnp.float32
+        ) + jnp.dot(m_lo, oh_hi_t, preferred_element_type=jnp.float32)
+        o_ref[0] = jnp.sum(
+            rowvecs * lo_ref[0, 0].astype(jnp.float32), axis=0, keepdims=True
+        )
 
-    oh_spec = lambda w: pl.BlockSpec(
-        (1, 1, tile, w), lambda i, k, wi_ref: (wi_ref[0], i, k, 0)
-    )
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(n_sub, ntiles),
+            grid=grid,
             in_specs=[
                 pl.BlockSpec(
-                    (1, row_hi, _ROW_LO), lambda i, k, wi_ref: (i, 0, 0)
+                    (1, _ROW_LO, row_hi), lambda i, k, wi_ref: (i, 0, 0)
                 ),
                 oh_spec(row_hi),
                 oh_spec(_ROW_LO),
             ],
-            out_specs=pl.BlockSpec(
-                (tile,), lambda i, k, wi_ref: (i * ntiles + k,)
-            ),
+            out_specs=entry_spec,
         ),
         out_shape=jax.ShapeDtypeStruct(
-            (n_sub * n_pad,), jnp.float32, vma=vma_of(mult3)
+            (n_sub, 1, n_pad), jnp.float32, vma=vma_of(mult3)
         ),
         interpret=interpret,
         name="onehot_mult_crossing_premat",
-    )(jnp.asarray(wi, jnp.int32).reshape(1), mult3, oh_hi, oh_lo)
+    )(jnp.asarray(wi, jnp.int32).reshape(1), jnp.swapaxes(mult3, 1, 2), oh_hi, oh_lo)
     return out.reshape(n_sub, n_pad)
 
 
@@ -1055,7 +1107,7 @@ def onehot_batch_step(
 
     ``premat``: the run's materialized row one-hots plus this minibatch's
     window index, ``(oh_hi, oh_lo, wi)`` (``premat_row_onehots``; stacks
-    may be windowed ``[n_windows, n_sub, n_pad, .]``) — when given, the
+    may be windowed ``[n_windows, n_sub, ., n_pad]``) — when given, the
     crossings run the product+matmul-only premat kernels, selecting the
     window via scalar-prefetch (Pallas) or a dynamic slice (XLA/test
     form), and ``rowid_w`` is never unpacked (the resident fast path; see

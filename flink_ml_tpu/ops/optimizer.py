@@ -529,7 +529,9 @@ def _fused_onehot_program(
 
     ``premat=True`` (resident fast path, HBM-gated by the caller): the
     program takes two extra stack args — this run's materialized bf16 row
-    one-hots (``premat_row_onehots``), sharded like the packed stacks —
+    one-hots (``premat_row_onehots``: ``[n_data, n_model, n_windows, n_sub,
+    row_hi | 128, n_pad]``, the entries minor like the packed stacks'),
+    sharded like the packed stacks over the two leading axes —
     and the crossings run product+matmul-only kernels instead of
     rebuilding the one-hots every minibatch (measured 1.86x on the
     crossings at the headline unit shape; docs/benchmarks.md).
@@ -561,7 +563,7 @@ def _fused_onehot_program(
         lidx, rowid, lvals = lidx[0, 0], rowid[0, 0], lvals[0, 0]
         if premat:
             oh_hi, oh_lo, y, w, mask = rest
-            oh_hi, oh_lo = oh_hi[0, 0], oh_lo[0, 0]
+            oh_hi, oh_lo = oh_hi[0, 0], oh_lo[0, 0]  # [n_windows, n_sub, ., n_pad]
         else:
             y, w, mask = rest
 
@@ -736,7 +738,8 @@ class _OneHotWindowStream:
     on the mesh. Drop-in for ``WindowedStream`` in ``run_windows``.
 
     With ``premat=True`` it additionally materializes the window's row
-    one-hots ON DEVICE from the just-landed rowid stacks (one elementwise
+    one-hots ON DEVICE from the just-landed rowid stacks (``win["oh"]``:
+    the stacks' leading axes, then ``[row_hi | 128, n_pad]``; one elementwise
     jit pass, queued in the prefetch gap so it hides behind the previous
     window's compute). Nothing extra rides ingest — the host still ships
     7 B/slot packed stacks; storage stays bounded at the two prefetch-live

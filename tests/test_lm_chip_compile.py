@@ -237,3 +237,46 @@ def test_the_ouro_step_program_at_the_cells_shapes(one_chip):
     stacked = f"f32[{cfg.loops},{batch},{t},{cfg.hidden}]"  # what the forward loop holds for the backward, per pass
     assert stacked in text
     assert f"f32[{batch},{cfg.n_heads},{t},{t}]" not in text and f"f32[{batch * cfg.n_heads},{t},{t}]" not in text
+
+
+@pytest.mark.parametrize("row_hi", [128, 16])
+def test_the_premat_crossings_at_the_criteo_cells_shapes(one_chip, row_hi):
+    """The two premat crossing kernels of one ``criteo_lr.fit_resident`` step:
+    four sub-batches of 956,753 entries, three windows of one-hots held with
+    the entries on the lanes, the window picked by a TRACED index inside the
+    BlockSpec; and the same at ``row_hi`` 16, where a ``[row_hi, tile]`` block
+    is one bf16 sublane tile. Mosaic's verdict on the cells, and both kernels
+    by the names the benchmark's trace reducers look for."""
+    from flink_ml_tpu.linalg.onehot_sparse import (
+        _premat_pad,
+        dot_crossing_premat_pallas,
+        mult_crossing_premat_pallas,
+        premat_bytes,
+    )
+
+    n_windows, n_sub, n_flat = 3, 4, 956_753
+    n_pad = _premat_pad(n_flat, row_hi)
+    assert 2 * n_windows * n_sub * n_pad * (row_hi + 128) == premat_bytes(n_windows * n_sub, n_flat, row_hi)
+
+    def crossings(q, mult3, oh_hi, oh_lo, wi):
+        return (
+            dot_crossing_premat_pallas(q, oh_hi, oh_lo, wi),
+            mult_crossing_premat_pallas(mult3, oh_hi, oh_lo, wi)[:, :n_flat],
+        )
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = _compile(
+        crossings,
+        on_chip((n_sub, n_flat), jnp.float32),
+        on_chip((n_sub, row_hi, 128), jnp.float32),
+        on_chip((n_windows, n_sub, row_hi, n_pad), jnp.bfloat16),
+        on_chip((n_windows, n_sub, 128, n_pad), jnp.bfloat16),
+        on_chip((), jnp.int32),
+    )
+    text = compiled.as_text()
+    for kernel in ("onehot_dot_crossing_premat", "onehot_mult_crossing_premat"):
+        assert kernel in text
+    # the window is chosen in the index map: no window-sized copy of the one-hots beside the kernels
+    assert f"bf16[{n_sub},128,{n_pad}]" not in text

@@ -5,6 +5,8 @@ The path must reproduce the scatter gradient to split-bf16 precision
 tail-batch/window clamping semantics — only the execution strategy differs
 (dense one-hot algebra instead of serialized gather/scatter instructions).
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -505,7 +507,8 @@ class TestPrematCrossings:
     """The precomputed-one-hot (premat) crossing path: same contraction with
     the row one-hots materialized once instead of rebuilt per minibatch —
     output must match the build-form kernels (bit-identical on the XLA form
-    when no entry padding is involved)."""
+    when no entry padding is involved). The one-hots hold the entries on the
+    last axis: ``[..., row_hi, n_pad]`` and ``[..., 128, n_pad]``."""
 
     def _ids(self, rng, n_sub, n, row_hi):
         rhi = rng.integers(0, row_hi, (n_sub, n), dtype=np.int32)
@@ -521,7 +524,9 @@ class TestPrematCrossings:
         q = jnp.asarray(rng.normal(size=(n_sub, n)).astype(np.float32))
         m3 = jnp.asarray(rng.normal(size=(n_sub, row_hi, 128)).astype(np.float32))
         oh_hi, oh_lo = premat_row_onehots(rowid, row_hi)
-        assert oh_hi.shape[1] % min(4096, n) == 0  # padded to the tile
+        assert oh_hi.shape[:2] == (n_sub, row_hi) and oh_lo.shape[:2] == (n_sub, 128)
+        assert oh_hi.shape[2] == oh_lo.shape[2] >= n
+        assert oh_hi.shape[2] % min(4096, n) == 0  # padded to the tile
         np.testing.assert_allclose(
             np.asarray(dot_crossing_premat_xla(q, oh_hi, oh_lo)),
             np.asarray(dot_crossing_xla(q, rhi, rlo, row_hi)),
@@ -532,6 +537,16 @@ class TestPrematCrossings:
             np.asarray(mult_crossing_xla(m3, rhi, rlo, row_hi)),
             rtol=1e-6, atol=1e-6,
         )
+
+    def test_premat_onehots_are_the_build_forms_transposed(self):
+        rng = np.random.default_rng(43)
+        n_sub, n, row_hi = 2, 300, 16
+        rhi, rlo, rowid = self._ids(rng, n_sub, n, row_hi)
+        oh_hi, oh_lo = premat_row_onehots(rowid, row_hi)
+        for got, ids, width in ((oh_hi, rhi, row_hi), (oh_lo, rlo, 128)):
+            assert got.dtype == jnp.bfloat16 and got.shape == (n_sub, width, n)
+            want = np.asarray(ids)[:, None, :] == np.arange(width)[None, :, None]
+            np.testing.assert_array_equal(np.asarray(got, np.float32), want)
 
     def test_premat_pallas_interpret_matches_xla(self):
         rng = np.random.default_rng(41)
@@ -553,20 +568,71 @@ class TestPrematCrossings:
             rtol=1e-5, atol=1e-5,
         )
 
+    # ``n``: above and not a multiple of the tile at every ``row_hi`` (4,096
+    # under row_hi 64, 8,192 from there), and one whole small tile.
+    @pytest.mark.parametrize("n", [9000, 640])
+    @pytest.mark.parametrize("n_sub", [1, 4])
+    @pytest.mark.parametrize("row_hi", [4, 16, 64, 128])
+    def test_premat_pallas_kernels_against_xla_and_build_forms(self, row_hi, n_sub, n):
+        """The two Pallas premat kernels under the interpreter, on windowed
+        stacks with a TRACED window index, against the XLA premat forms and
+        the build forms: the mult crossing selects one f32 value per entry,
+        so it is bit-equal whatever the orientation; the dot crossing sums
+        over entries, at this file's tolerances."""
+        rng = np.random.default_rng(1000 * row_hi + 10 * n_sub + n % 7)
+        n_windows, wi = 3, 2
+        ids = [self._ids(rng, n_sub, n, row_hi) for _ in range(n_windows)]
+        rhi, rlo, _ = ids[wi]
+        oh_hi, oh_lo = premat_row_onehots(jnp.stack([r for _, _, r in ids]), row_hi)
+        n_pad = oh_hi.shape[-1]
+        assert oh_hi.shape == (n_windows, n_sub, row_hi, n_pad)
+        assert oh_lo.shape == (n_windows, n_sub, 128, n_pad)
+        assert (n_pad > n) == (n == 9000)
+        q = jnp.asarray(rng.normal(size=(n_sub, n)).astype(np.float32))
+        m3 = jnp.asarray(rng.normal(size=(n_sub, row_hi, 128)).astype(np.float32))
+
+        @jax.jit
+        def pallas(q, m3, oh_hi, oh_lo, wi):
+            return (
+                dot_crossing_premat_pallas(q, oh_hi, oh_lo, wi, interpret=True),
+                mult_crossing_premat_pallas(m3, oh_hi, oh_lo, wi, interpret=True),
+            )
+
+        dot3, u = pallas(q, m3, oh_hi, oh_lo, jnp.int32(wi))
+        assert dot3.shape == (n_sub, row_hi, 128) and u.shape == (n_sub, n_pad)
+        for want in (
+            dot_crossing_premat_xla(q, oh_hi, oh_lo, wi),
+            dot_crossing_xla(q, rhi, rlo, row_hi),
+        ):
+            np.testing.assert_allclose(
+                np.asarray(dot3), np.asarray(want), rtol=1e-5, atol=1e-5
+            )
+        np.testing.assert_array_equal(
+            np.asarray(u), np.asarray(mult_crossing_premat_xla(m3, oh_hi, oh_lo, wi))
+        )
+        np.testing.assert_array_equal(
+            np.asarray(u)[:, :n], np.asarray(mult_crossing_xla(m3, rhi, rlo, row_hi))
+        )
+        assert not np.asarray(u)[:, n:].any()  # all-zero padded one-hot columns
+
     def test_padded_entries_contribute_nothing(self):
-        # Padded oh rows are all-zero, so garbage q on the padded slots must
+        # Padded oh columns are all-zero, so garbage q on the padded slots must
         # not leak into the dot crossing.
         rng = np.random.default_rng(42)
         n_sub, n, row_hi = 1, 5000, 4
         rhi, rlo, rowid = self._ids(rng, n_sub, n, row_hi)
         oh_hi, oh_lo = premat_row_onehots(rowid, row_hi)
-        n_pad = oh_hi.shape[1]
+        n_pad = oh_hi.shape[2]
         q_pad = jnp.asarray(rng.normal(size=(n_sub, n_pad)).astype(np.float32))
         ref = dot_crossing_xla(q_pad[:, :n], rhi, rlo, row_hi)
-        np.testing.assert_allclose(
-            np.asarray(dot_crossing_premat_xla(q_pad, oh_hi, oh_lo)),
-            np.asarray(ref), rtol=1e-6, atol=1e-6,
-        )
+        for cross in (
+            dot_crossing_premat_xla,
+            functools.partial(dot_crossing_premat_pallas, interpret=True),
+        ):
+            np.testing.assert_allclose(
+                np.asarray(cross(q_pad, oh_hi, oh_lo)),
+                np.asarray(ref), rtol=1e-5, atol=1e-5,
+            )
 
     def test_premat_bytes_counts_padding(self):
         assert premat_bytes(2, 4096, 4) == 2 * 2 * 4096 * (4 + 128)
