@@ -59,6 +59,15 @@ is more than one block each is rematerialised in the backward
 traced program is one pass): what it holds for the backward is the input of
 each of its ``layers x loops`` block applications and each pass's output.
 
+Names: the step program's parts carry ``jax.named_scope``s (``lm.embed``,
+``lm.block`` with ``norm``, ``proj``, ``rope``, ``conv``, ``fold``, ``mix``,
+``ffn`` and the experts' ``route``, ``permute``, ``experts`` under it,
+``lm.final_norm``, ``lm.head``, ``lm.exit``, ``lm.aux``, ``lm.opt``), which
+reach each device operation's name beside what JAX's transformations write
+there, so a profile tells the parts, and forward from recomputed from
+backward, apart (docs/observability.md, "The step's scopes"). They are
+trace-time metadata: the jaxpr and the compiled program are what they were.
+
 The fitted model keeps its parameters on the device; ``save`` and
 ``get_model_data`` fetch them (2.5 GB at OLMoE's widths is seconds of
 device->host copy, which a fit that is followed by ``transform`` never needs).
@@ -314,22 +323,25 @@ def init_params(cfg: LMConfig, seed: int) -> dict:
 
 
 def _rms_norm(x, w, eps):
-    x = x.astype(jnp.float32)
-    return w * (x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps))
+    with jax.named_scope("norm"):
+        x = x.astype(jnp.float32)
+        return w * (x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps))
 
 
 def _rope_tables(t: int, d: int, theta: float):
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    freqs = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    emb = jnp.concatenate([freqs, freqs], axis=-1)  # [T, D]
-    return jnp.cos(emb), jnp.sin(emb)
+    with jax.named_scope("rope"):
+        inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+        freqs = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+        emb = jnp.concatenate([freqs, freqs], axis=-1)  # [T, D]
+        return jnp.cos(emb), jnp.sin(emb)
 
 
 def _rope(x, cos, sin):
     """Rotate-half RoPE on ``x [B, H, T, D]``."""
-    half = x.shape[-1] // 2
-    rotated = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
-    return x * cos + rotated * sin
+    with jax.named_scope("rope"):
+        half = x.shape[-1] // 2
+        rotated = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+        return x * cos + rotated * sin
 
 
 def _matmul(a, w, cd):
@@ -337,44 +349,60 @@ def _matmul(a, w, cd):
                    precision=_HIGHEST if cd == jnp.float32 else None)
 
 
+def _proj(a, w, cd):
+    """One of attention's dense projections."""
+    with jax.named_scope("proj"):
+        return _matmul(a, w, cd)
+
+
 def _fold(q, k, v, cd, interpret: bool):
     """Causal softmax attention of ``q [B, H, T, D]`` on ``k``, ``v`` ``[B,
     H_kv, T, D]`` at scale ``D^-1/2`` through the fused fold: a ring of one,
     the whole sequence is the resident KV block."""
-    b, h, t, hd = q.shape
-    m0 = jnp.full((b, h, t), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((b, h, t), jnp.float32)
-    acc0 = jnp.zeros((b, h, t, hd), jnp.float32)
-    _, l, acc = fused_fold(q.astype(cd), k.astype(cd), v.astype(cd), m0, l0, acc0, jnp.int32(0),
-                           jnp.int32(0), True, False, jnp.int32(0), float(hd) ** -0.5, interpret)
-    return acc / l[..., None]  # causal: every row attends at least to itself, l > 0
+    with jax.named_scope("fold"):
+        b, h, t, hd = q.shape
+        m0 = jnp.full((b, h, t), -jnp.inf, jnp.float32)
+        l0 = jnp.zeros((b, h, t), jnp.float32)
+        acc0 = jnp.zeros((b, h, t, hd), jnp.float32)
+        _, l, acc = fused_fold(q.astype(cd), k.astype(cd), v.astype(cd), m0, l0, acc0, jnp.int32(0),
+                               jnp.int32(0), True, False, jnp.int32(0), float(hd) ** -0.5, interpret)
+        return acc / l[..., None]  # causal: every row attends at least to itself, l > 0
 
 
 def _heads(z, n: int):
     """``[B, T, n * D]`` (or ``[B, T, n, D]``) ``-> [B, n, T, D]``, the fold's layout."""
-    return jnp.transpose(z.reshape(*z.shape[:2], n, -1), (0, 2, 1, 3))
+    with jax.named_scope("fold"):
+        return jnp.transpose(z.reshape(*z.shape[:2], n, -1), (0, 2, 1, 3))
+
+
+def _merged(o):
+    """The fold's ``[B, n, T, D]`` back as ``[B, T, n * D]``."""
+    with jax.named_scope("fold"):
+        return jnp.transpose(o, (0, 2, 1, 3)).reshape(o.shape[0], o.shape[2], -1)
 
 
 def _attention(x, layer, cfg: LMConfig, cd, interpret: bool):
-    b, t, d = x.shape
+    t = x.shape[1]
     h, hd = cfg.n_heads, cfg.head_dim
     a = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-    q = _rms_norm(_matmul(a, layer["wq"], cd), layer["q_norm"], cfg.norm_eps)
-    k = _rms_norm(_matmul(a, layer["wk"], cd), layer["k_norm"], cfg.norm_eps)
-    v = _matmul(a, layer["wv"], cd)
+    q = _rms_norm(_proj(a, layer["wq"], cd), layer["q_norm"], cfg.norm_eps)
+    k = _rms_norm(_proj(a, layer["wk"], cd), layer["k_norm"], cfg.norm_eps)
+    v = _proj(a, layer["wv"], cd)
     cos, sin = _rope_tables(t, hd, cfg.rope_theta)
     o = _fold(_rope(_heads(q, h), cos, sin), _rope(_heads(k, h), cos, sin), _heads(v, h), cd, interpret)
-    o = jnp.transpose(o, (0, 2, 1, 3)).reshape(b, t, d)
-    return _matmul(o, layer["wo"], cd)
+    return _proj(_merged(o), layer["wo"], cd)
 
 
 def _olmoe_block(x, carry, layer, cfg: LMConfig, cd, interpret: bool):
     b, t, d = x.shape
-    x = x + _attention(x, layer, cfg, cd, interpret)
+    a = _attention(x, layer, cfg, cd, interpret)
+    with jax.named_scope("mix"):
+        x = x + a
     u = _rms_norm(x, layer["ffn_norm"], cfg.norm_eps).reshape(b * t, d)
     y, stats = moe_dropless(u, layer["router"], layer["w_gate"], layer["w_up"], layer["w_down"],
                             cfg.top_k, cd, cfg.first_held)
-    return x + y.reshape(b, t, d), carry, stats
+    with jax.named_scope("mix"):
+        return x + y.reshape(b, t, d), carry, stats
 
 
 # -- the zaya block (reference_zaya.py carries each equation's origin) ----------
@@ -393,7 +421,13 @@ def _unit_heads(z, eps):
 def _rope_part(x, cos, sin):
     """RoPE on the first ``cos.shape[-1]`` channels of each head of ``x [B, H, T, D]``."""
     rot = cos.shape[-1]
-    return x if rot == 0 else jnp.concatenate([_rope(x[..., :rot], cos, sin), x[..., rot:]], axis=-1)
+    if rot == 0:
+        return x
+    with jax.named_scope("rope"):
+        turned = x[..., :rot]
+    turned = _rope(turned, cos, sin)
+    with jax.named_scope("rope"):
+        return jnp.concatenate([turned, x[..., rot:]], axis=-1)
 
 
 def _cca(x, layer, cfg: LMConfig, cd, interpret: bool):
@@ -405,37 +439,44 @@ def _cca(x, layer, cfg: LMConfig, cd, interpret: bool):
     h, kv, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     group = h // kv
     a = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-    q0 = _matmul(a, layer["wq"], cd).reshape(b, t, kv, group, hd)
-    k0 = _matmul(a, layer["wk"], cd).reshape(b, t, kv, 1, hd)
-    # the two convolutions over the sequence, kernel 2: per channel, then per head
-    z = jnp.concatenate([q0.reshape(b, t, -1), k0.reshape(b, t, -1)], axis=-1)
-    w0, w1 = layer["conv0_w"], layer["conv1_w"]
-    z1 = (w0[0] * _before(z) + w0[1] * z + layer["conv0_b"]).reshape(b, t, h + kv, hd)
+    q0 = _proj(a, layer["wq"], cd).reshape(b, t, kv, group, hd)
+    k0 = _proj(a, layer["wk"], cd).reshape(b, t, kv, 1, hd)
+    with jax.named_scope("conv"):
+        # the two convolutions over the sequence, kernel 2: per channel, then per head
+        z = jnp.concatenate([q0.reshape(b, t, -1), k0.reshape(b, t, -1)], axis=-1)
+        w0, w1 = layer["conv0_w"], layer["conv1_w"]
+        z1 = (w0[0] * _before(z) + w0[1] * z + layer["conv0_b"]).reshape(b, t, h + kv, hd)
 
-    def per_head(zz, u):  # heads lead: the one batched form every backend's dot takes in bfloat16
-        zz = jnp.moveaxis(zz.reshape(b * t, h + kv, hd), 1, 0)
-        out = jnp.einsum("gni,gio->gno", zz.astype(cd), u.astype(cd), preferred_element_type=jnp.float32,
-                         precision=_HIGHEST if cd == jnp.float32 else None)
-        return jnp.moveaxis(out, 0, 1).reshape(b, t, h + kv, hd)
+        def per_head(zz, u):  # heads lead: the one batched form every backend's dot takes in bfloat16
+            zz = jnp.moveaxis(zz.reshape(b * t, h + kv, hd), 1, 0)
+            out = jnp.einsum("gni,gio->gno", zz.astype(cd), u.astype(cd), preferred_element_type=jnp.float32,
+                             precision=_HIGHEST if cd == jnp.float32 else None)
+            return jnp.moveaxis(out, 0, 1).reshape(b, t, h + kv, hd)
 
-    z2 = per_head(_before(z1), w1[0]) + per_head(z1, w1[1]) + layer["conv1_b"]
-    # the q-k mean: each query head with its key head, each key head with its query heads' mean
-    q = z2[:, :, :h] + ((q0 + k0) / 2).reshape(b, t, h, hd)
-    k = z2[:, :, h:] + ((jnp.mean(q0, axis=3, keepdims=True) + k0) / 2).reshape(b, t, kv, hd)
-    q = _unit_heads(q, cfg.norm_eps)
-    k = _unit_heads(k, cfg.norm_eps) * layer["k_temp"][:, None]
-    v = jnp.stack([_matmul(a, layer["wv1"], cd), _matmul(_before(a), layer["wv2"], cd)], axis=2)
+        z2 = per_head(_before(z1), w1[0]) + per_head(z1, w1[1]) + layer["conv1_b"]
+        # the q-k mean: each query head with its key head, each key head with its query heads' mean
+        q = z2[:, :, :h] + ((q0 + k0) / 2).reshape(b, t, h, hd)
+        k = z2[:, :, h:] + ((jnp.mean(q0, axis=3, keepdims=True) + k0) / 2).reshape(b, t, kv, hd)
+    with jax.named_scope("norm"):
+        q = _unit_heads(q, cfg.norm_eps)
+        k = _unit_heads(k, cfg.norm_eps) * layer["k_temp"][:, None]
+    v1 = _proj(a, layer["wv1"], cd)
+    with jax.named_scope("mix"):
+        earlier = _before(a)  # the value shift
+    with jax.named_scope("proj"):
+        v = jnp.stack([v1, _matmul(earlier, layer["wv2"], cd)], axis=2)
     cos, sin = _rope_tables(t, int(hd * cfg.rope_fraction), cfg.rope_theta)
     o = _fold(_rope_part(_heads(q, h), cos, sin), _rope_part(_heads(k, kv), cos, sin), _heads(v, kv),
               cd, interpret)
-    return _matmul(jnp.transpose(o, (0, 2, 1, 3)).reshape(b, t, h * hd), layer["wo"], cd)
+    return _proj(_merged(o), layer["wo"], cd)
 
 
 def _router_state(u, layer, carry):
     """The router's hidden state ``[N, r]`` in float32: this block's projection
     plus ``router_gamma`` times the block before's (depth averaging)."""
-    r = jnp.dot(u, layer["router_in"], precision=_HIGHEST)
-    return r if carry is None else r + layer["router_gamma"] * carry
+    with jax.named_scope("route"):
+        r = jnp.dot(u, layer["router_in"], precision=_HIGHEST)
+        return r if carry is None else r + layer["router_gamma"] * carry
 
 
 def _router_logits(r, layer, eps):
@@ -448,8 +489,9 @@ def _router_logits(r, layer, eps):
 def _scaled(x, y, layer, sub: str):
     """The learned residual scaling: a per-channel scale and bias on the
     residual and on the sublayer's output."""
-    return (layer[f"{sub}_res_scale"] * x + layer[f"{sub}_res_bias"]
-            + layer[f"{sub}_out_scale"] * y + layer[f"{sub}_out_bias"])
+    with jax.named_scope("mix"):
+        return (layer[f"{sub}_res_scale"] * x + layer[f"{sub}_res_bias"]
+                + layer[f"{sub}_out_scale"] * y + layer[f"{sub}_out_bias"])
 
 
 def _zaya_block(x, carry, layer, cfg: LMConfig, cd, interpret: bool):
@@ -468,18 +510,22 @@ def _zaya_block(x, carry, layer, cfg: LMConfig, cd, interpret: bool):
 def _ouro_block(x, carry, layer, cfg: LMConfig, cd, interpret: bool):
     """A dense layer in sandwich norms: a norm before each sublayer and one on
     its output, inside the residual branch. No experts: no statistics."""
-    b, t, _ = x.shape
+    t = x.shape[1]
     h = cfg.n_heads
     a = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
     cos, sin = _rope_tables(t, cfg.head_dim, cfg.rope_theta)
-    q, k, v = (_heads(_matmul(a, layer[w], cd), h) for w in ("wq", "wk", "wv"))
+    q, k, v = (_heads(_proj(a, layer[w], cd), h) for w in ("wq", "wk", "wv"))
     o = _fold(_rope(q, cos, sin), _rope(k, cos, sin), v, cd, interpret)
-    o = _matmul(jnp.transpose(o, (0, 2, 1, 3)).reshape(b, t, h * cfg.head_dim), layer["wo"], cd)
-    x = x + _rms_norm(o, layer["attn_out_norm"], cfg.norm_eps)
+    o = _rms_norm(_proj(_merged(o), layer["wo"], cd), layer["attn_out_norm"], cfg.norm_eps)
+    with jax.named_scope("mix"):
+        x = x + o
     m = _rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
-    y = _matmul(jax.nn.silu(_matmul(m, layer["w_gate"], cd)) * _matmul(m, layer["w_up"], cd),
-                layer["w_down"], cd)
-    return x + _rms_norm(y, layer["ffn_out_norm"], cfg.norm_eps), carry, {}
+    with jax.named_scope("ffn"):
+        y = _matmul(jax.nn.silu(_matmul(m, layer["w_gate"], cd)) * _matmul(m, layer["w_up"], cd),
+                    layer["w_down"], cd)
+    y = _rms_norm(y, layer["ffn_out_norm"], cfg.norm_eps)
+    with jax.named_scope("mix"):
+        return x + y, carry, {}
 
 
 _BLOCKS = {"olmoe": _olmoe_block, "zaya": _zaya_block, "ouro": _ouro_block}
@@ -493,8 +539,13 @@ def _hidden(params, tok, cfg: LMConfig, cd, interpret: bool):
     d]``, no logits. With one, the stack runs ``cfg.loops`` times over the
     same leaves, each pass's normed state feeding the next: ``[R, B, T, d]``
     and the gate's logits ``[R, B, T]``."""
-    x = params["embed"][tok]
-    block = functools.partial(_BLOCKS[cfg.block], cfg=cfg, cd=cd, interpret=interpret)
+    with jax.named_scope("lm.embed"):
+        x = params["embed"][tok]
+
+    def block(x, carry, layer):  # the scope opens inside what is rematerialised
+        with jax.named_scope("lm.block"):
+            return _BLOCKS[cfg.block](x, carry, layer, cfg, cd, interpret)
+
     if cfg.n_layers * cfg.loops > 1:
         # a lone block's residuals are wanted as soon as the head's backward
         # ends: holding them costs nothing at the peak, recomputing them a forward
@@ -505,14 +556,16 @@ def _hidden(params, tok, cfg: LMConfig, cd, interpret: bool):
         for layer in params["layers"]:
             x, carry, stats = block(x, carry, layer)
             routed.append(stats)
-        return _rms_norm(x, params["final_norm"], cfg.norm_eps), routed
+        with jax.named_scope("lm.final_norm"):
+            return _rms_norm(x, params["final_norm"], cfg.norm_eps), routed
 
     if "exit_gate_w" not in params:
         return stack(x) + (None,)
 
     def one_pass(h, _):
         h, _ = stack(h)
-        return h, (h, jnp.sum(h * params["exit_gate_w"][:, 0], axis=-1) + params["exit_gate_b"][0])
+        with jax.named_scope("lm.exit"):
+            return h, (h, jnp.sum(h * params["exit_gate_w"][:, 0], axis=-1) + params["exit_gate_b"][0])
 
     _, (passes, gate) = jax.lax.scan(one_pass, x, None, length=cfg.loops)
     return passes, [], gate
@@ -522,7 +575,10 @@ def _head(params, cfg: LMConfig):
     """The head ``[d, V]``: its own matrix, or the embedding table transposed
     (one leaf, whose gradient is then the lookup's scatter plus the head's
     matmuls)."""
-    return params["embed"].T if cfg.tied else params["lm_head"]
+    if not cfg.tied:
+        return params["lm_head"]
+    with jax.named_scope("lm.head"):
+        return params["embed"].T
 
 
 def _next_token_nll(h, lm_head, tok, cd):
@@ -533,19 +589,20 @@ def _next_token_nll(h, lm_head, tok, cd):
     b, t, d = h.shape
     n = b * t
     chunk = _LOSS_CHUNK if n % _LOSS_CHUNK == 0 else t
-    targets = jnp.concatenate([tok[:, 1:], jnp.zeros((b, 1), tok.dtype)], axis=1).reshape(n)
-    w = lm_head.astype(cd)
+    with jax.named_scope("lm.head"):
+        targets = jnp.concatenate([tok[:, 1:], jnp.zeros((b, 1), tok.dtype)], axis=1).reshape(n)
+        w = lm_head.astype(cd)
 
-    @jax.checkpoint
-    def one(args):
-        hc, tc = args
-        logits = jnp.dot(hc.astype(cd), w, preferred_element_type=jnp.float32,
-                         precision=_HIGHEST if cd == jnp.float32 else None)
-        picked = jnp.take_along_axis(logits, tc[:, None], axis=1)[:, 0]
-        return jax.nn.logsumexp(logits, axis=-1) - picked
+        @jax.checkpoint
+        def one(args):
+            hc, tc = args
+            logits = jnp.dot(hc.astype(cd), w, preferred_element_type=jnp.float32,
+                             precision=_HIGHEST if cd == jnp.float32 else None)
+            picked = jnp.take_along_axis(logits, tc[:, None], axis=1)[:, 0]
+            return jax.nn.logsumexp(logits, axis=-1) - picked
 
-    nll = jax.lax.map(one, (h.reshape(n // chunk, chunk, d), targets.reshape(n // chunk, chunk)))
-    return nll.reshape(b, t).at[:, -1].set(0.0)
+        nll = jax.lax.map(one, (h.reshape(n // chunk, chunk, d), targets.reshape(n // chunk, chunk)))
+        return nll.reshape(b, t).at[:, -1].set(0.0)
 
 
 def _load_balancing(routed, cfg: LMConfig):
@@ -574,15 +631,19 @@ def _exit_loss(passes, gate, lm_head, tok, cfg: LMConfig, cd):
     (``trip_nll``) and, summed over the step's tokens, the expected exit pass,
     the mass left to the last pass and the exit distribution's entropy."""
     r, b, t, d = passes.shape
-    nll = _next_token_nll(passes.reshape(r * b, t, d), lm_head, jnp.tile(tok, (r, 1)), cd).reshape(r, b, t)
-    log_p = _exit_distribution(gate)
-    every = jnp.exp(log_p)
-    p = every.at[:, :, -1].set(0.0)  # the last position has no target
-    targets = b * (t - 1)
-    loss = (jnp.sum(p * nll) + cfg.exit_beta * jnp.sum(p * log_p)) / targets
-    trip = jnp.arange(1, r + 1, dtype=jnp.float32)[:, None, None]
-    return loss, {"trip_nll": jnp.sum(nll, axis=(1, 2)) / targets, "exit_trip_sum": jnp.sum(trip * every),
-                  "exit_last_mass": jnp.sum(every[-1]), "gate_entropy_sum": -jnp.sum(every * log_p)}
+    with jax.named_scope("lm.exit"):
+        flat, tiled = passes.reshape(r * b, t, d), jnp.tile(tok, (r, 1))
+    nll = _next_token_nll(flat, lm_head, tiled, cd)
+    with jax.named_scope("lm.exit"):
+        nll = nll.reshape(r, b, t)
+        log_p = _exit_distribution(gate)
+        every = jnp.exp(log_p)
+        p = every.at[:, :, -1].set(0.0)  # the last position has no target
+        targets = b * (t - 1)
+        loss = (jnp.sum(p * nll) + cfg.exit_beta * jnp.sum(p * log_p)) / targets
+        trip = jnp.arange(1, r + 1, dtype=jnp.float32)[:, None, None]
+        return loss, {"trip_nll": jnp.sum(nll, axis=(1, 2)) / targets, "exit_trip_sum": jnp.sum(trip * every),
+                      "exit_last_mass": jnp.sum(every[-1]), "gate_entropy_sum": -jnp.sum(every * log_p)}
 
 
 def _loss(params, tok, cfg: LMConfig, cd, interpret: bool):
@@ -591,11 +652,13 @@ def _loss(params, tok, cfg: LMConfig, cd, interpret: bool):
     h, routed, gate = _hidden(params, tok, cfg, cd, interpret)
     if gate is None:
         nll = _next_token_nll(h, _head(params, cfg), tok, cd)
-        loss, stats = jnp.sum(nll) / (tok.shape[0] * (tok.shape[1] - 1)), {}
+        with jax.named_scope("lm.head"):
+            loss, stats = jnp.sum(nll) / (tok.shape[0] * (tok.shape[1] - 1)), {}
     else:
         loss, stats = _exit_loss(h, gate, _head(params, cfg), tok, cfg, cd)
     if cfg.aux_coef:
-        loss = loss + cfg.aux_coef * _load_balancing(routed, cfg)
+        with jax.named_scope("lm.aux"):
+            loss = loss + cfg.aux_coef * _load_balancing(routed, cfg)
     if routed:
         stats["rows"] = jnp.stack([s["rows"] for s in routed])
     return loss, stats
@@ -620,9 +683,10 @@ def _train_program(cfg: LMConfig, compute_type: str, lr: float, batch: int, inte
     def step(params, opt_state, window, lo):
         tok = jax.lax.dynamic_slice_in_dim(window, lo, batch, axis=0)
         (loss, stats), grads = jax.value_and_grad(_loss, has_aux=True)(params, tok, cfg, cd, interpret)
-        norms = jnp.stack([jnp.sqrt(jnp.sum(g * g)) for g in _ordered(grads, cfg)])
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt_state, loss, norms, stats
+        with jax.named_scope("lm.opt"):
+            norms = jnp.stack([jnp.sqrt(jnp.sum(g * g)) for g in _ordered(grads, cfg)])
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            return optax.apply_updates(params, updates), opt_state, loss, norms, stats
 
     return optimizer, jax.jit(step, donate_argnums=(0, 1))
 

@@ -27,6 +27,11 @@ loss: ``f`` (per expert, the (token, slot) choices that fell on it over the
 number of tokens - it sums to ``k``), ``P`` (the mean router probability) and
 ``rows`` (the group sizes; their sum is ``tokens x k``, always).
 
+The layer names its three parts for a profiler with ``jax.named_scope``
+(``route``, ``permute``, ``experts``: trace-time metadata on each operation's
+name, nothing at run time). The names are relative: they nest under whatever
+scope the caller opened (docs/observability.md, "The step's scopes").
+
 The router is handed in: a matrix ``[d, E]`` (OLMoE's) or a function from the
 tokens to their logits (ZAYA's MLP, closed over the state the block before
 handed it); top-1 is ``k = 1``, the winner's probability the gate.
@@ -63,13 +68,14 @@ def route_top_k(x, router, k: int):
     E]``, and the ``k`` largest of each row as ``(top_p, top_e) [t, k]`` (ties
     go to the lower expert id). ``router`` is a matrix ``[d, E]`` (logits ``x @
     router``) or a function ``x -> logits [t, E]`` in float32."""
-    if callable(router):
-        logits = router(x)
-    else:
-        logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32), precision=_HIGHEST)
-    p = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(p, k)
-    return p, top_p, top_e
+    with jax.named_scope("route"):
+        if callable(router):
+            logits = router(x)
+        else:
+            logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32), precision=_HIGHEST)
+        p = jax.nn.softmax(logits, axis=-1)
+        top_p, top_e = jax.lax.top_k(p, k)
+        return p, top_p, top_e
 
 
 @jax.custom_vjp
@@ -201,28 +207,33 @@ def moe_dropless(x, router, w_gate, w_up, w_down, k: int, compute_dtype=jnp.floa
     if not 0 <= first_held <= n_experts - n_held:
         raise ValueError(f"experts {first_held}..{first_held + n_held} are not among the router's {n_experts}")
 
-    # row r of the flat (token, slot) list belongs to token r // k
-    flat_e = top_e.reshape(-1)
-    sort_key = flat_e
-    if not covered:  # the rows of experts held elsewhere sort last, past every group
-        local = flat_e - first_held
-        here = (local >= 0) & (local < n_held)
-        sort_key = jnp.where(here, local, n_held)
-        top_p = jnp.where(here.reshape(top_p.shape), top_p, 0.0)
-    order = jnp.argsort(sort_key, stable=True)  # sorted position -> flat row
-    inverse = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0], dtype=order.dtype))
-    rows = jnp.zeros((n_experts,), jnp.int32).at[flat_e].add(1)
-    held_rows = rows if covered else jax.lax.dynamic_slice_in_dim(rows, first_held, n_held)
+    with jax.named_scope("route"):
+        # row r of the flat (token, slot) list belongs to token r // k
+        flat_e = top_e.reshape(-1)
+        sort_key = flat_e
+        if not covered:  # the rows of experts held elsewhere sort last, past every group
+            local = flat_e - first_held
+            here = (local >= 0) & (local < n_held)
+            sort_key = jnp.where(here, local, n_held)
+            top_p = jnp.where(here.reshape(top_p.shape), top_p, 0.0)
+        order = jnp.argsort(sort_key, stable=True)  # sorted position -> flat row
+        inverse = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0], dtype=order.dtype))
+        rows = jnp.zeros((n_experts,), jnp.int32).at[flat_e].add(1)
+        held_rows = rows if covered else jax.lax.dynamic_slice_in_dim(rows, first_held, n_held)
 
-    x_rows = jnp.repeat(x.astype(compute_dtype), k, axis=0)  # [t*k, d], token-major
-    ys = _expert_swiglu(_take_rows(x_rows, order, inverse), w_gate, w_up, w_down,
-                        held_rows, jnp.dtype(compute_dtype).name, covered)
-    y_rows = _take_rows(ys, inverse, order).reshape(t, k, -1)
-    y = jnp.sum(y_rows.astype(jnp.float32) * top_p[:, :, None], axis=1)
+    with jax.named_scope("permute"):
+        x_rows = jnp.repeat(x.astype(compute_dtype), k, axis=0)  # [t*k, d], token-major
+        xs = _take_rows(x_rows, order, inverse)
+    with jax.named_scope("experts"):
+        ys = _expert_swiglu(xs, w_gate, w_up, w_down, held_rows, jnp.dtype(compute_dtype).name, covered)
+    with jax.named_scope("permute"):
+        y_rows = _take_rows(ys, inverse, order).reshape(t, k, -1)
+        y = jnp.sum(y_rows.astype(jnp.float32) * top_p[:, :, None], axis=1)
 
-    stats = {
-        "f": rows.astype(jnp.float32) / t,
-        "P": jnp.mean(p, axis=0),
-        "rows": rows,
-    }
+    with jax.named_scope("route"):
+        stats = {
+            "f": rows.astype(jnp.float32) / t,
+            "P": jnp.mean(p, axis=0),
+            "rows": rows,
+        }
     return y, stats

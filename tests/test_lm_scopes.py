@@ -1,0 +1,141 @@
+"""The LM step names its parts: ``jax.named_scope`` blocks in
+``models/lm/decoder_lm.py`` and ``parallel/moe.py`` reach every HLO
+instruction's ``metadata.op_name`` (docs/observability.md, "The step's
+scopes"). Toy sizes on the CPU, the fold interpreted: each block kind's step
+program is lowered and compiled, and the ``op_name``s of its text are read
+with the classification the benchmark's reader uses
+(``perfbench/op_scopes.py::classify``): which scopes are there, in which of
+the three directions, and how much of the program they cover."""
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from flink_ml_tpu.models.lm import decoder_lm
+from flink_ml_tpu.models.lm.config import LMConfig
+from perfbench.op_scopes import BWD, FWD, REMAT, classify
+
+ROOT = "lm."
+_OLMOE = LMConfig(n_layers=1, hidden=128, n_heads=4, n_experts=8, top_k=2, expert_width=64, vocab=512)
+#: kind -> (configuration, the block is checkpointed, the scopes only this kind has)
+KINDS = {
+    "olmoe": (_OLMOE, False, {"lm.block/route", "lm.block/permute", "lm.block/experts", "lm.aux"}),
+    "olmoe_stacked": (_OLMOE._replace(n_layers=2), True,
+                      {"lm.block/route", "lm.block/permute", "lm.block/experts", "lm.aux"}),
+    "zaya": (LMConfig(n_layers=2, hidden=128, n_heads=4, n_experts=8, top_k=1, expert_width=64, vocab=512,
+                      rope_theta=5e6, aux_coef=0.0, block="zaya", tied=True, experts_held=4, first_held=2,
+                      n_kv_heads=2, head_size=16, rope_fraction=0.5, router_width=32), True,
+             {"lm.block/conv", "lm.block/route", "lm.block/route/norm", "lm.block/permute", "lm.block/experts"}),
+    "ouro": (LMConfig(n_layers=2, hidden=128, n_heads=4, n_experts=0, top_k=0, expert_width=64, vocab=512,
+                      aux_coef=0.0, block="ouro", loops=3, exit_beta=0.1), True, {"lm.block/ffn", "lm.exit"}),
+}
+EVERY_KIND = {"lm.embed", "lm.block/norm", "lm.block/proj", "lm.block/rope", "lm.block/fold", "lm.block/mix",
+              "lm.final_norm/norm", "lm.head", "lm.opt"}
+#: ``%name = shape opcode(``: the opcode is the first word followed by a parenthesis
+INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = .*? ([a-z][\w\-]*)\(")
+HEAVY = {"dot", "fusion", "custom-call", "scatter", "sort", "reduce"}
+TOKENS = jax.ShapeDtypeStruct((4, 256), jnp.int32)
+
+
+def _instructions(text):
+    """``(opcode, op_name or None)`` of every instruction of an HLO text."""
+    for line in text.splitlines():
+        m = INSTRUCTION.match(line)
+        if m:
+            name = re.search(r'op_name="([^"]*)"', line)
+            yield m.group(1), name.group(1) if name else None
+
+
+@pytest.fixture(scope="module")
+def programs():
+    """kind -> (the step program's instructions, the scoring program's), compiled once."""
+    made = {}
+
+    def of(kind):
+        if kind not in made:
+            cfg = KINDS[kind][0]
+            optimizer, step = decoder_lm._train_program(cfg, "float32", 1e-3, 2, True)
+            params = jax.eval_shape(lambda: decoder_lm._init_program(cfg)(jax.random.key(0)))
+            state = jax.eval_shape(optimizer.init, params)
+            step_text = step.lower(params, state, TOKENS, jax.ShapeDtypeStruct((), jnp.int32)).compile().as_text()
+            score_text = decoder_lm._log_likelihood_program(cfg, "float32", True).lower(
+                params, jax.ShapeDtypeStruct((2, 256), jnp.int32)).compile().as_text()
+            made[kind] = list(_instructions(step_text)), list(_instructions(score_text))
+        return made[kind]
+
+    return of
+
+
+def _found(instructions):
+    """``{(scope path, direction)}`` of the instructions that carry a scope."""
+    out = set()
+    for _, op_name in instructions:
+        scope, direction = classify(op_name, ROOT)
+        if scope is not None:
+            out.add(("/".join(scope), direction))
+    return out
+
+
+@pytest.mark.parametrize("kind", sorted(KINDS))
+def test_every_scope_the_kind_has_appears(programs, kind):
+    step, _ = programs(kind)
+    scopes = {scope for scope, _ in _found(step)}
+    own = KINDS[kind][2]
+    assert (EVERY_KIND | own) <= scopes, sorted((EVERY_KIND | own) - scopes)
+    # and none another kind alone has
+    others = set().union(*(k[2] for k in KINDS.values())) - own
+    assert not others & scopes, sorted(others & scopes)
+
+
+@pytest.mark.parametrize("kind", sorted(KINDS))
+def test_the_head_runs_in_three_directions_and_a_block_is_recomputed_where_it_is_checkpointed(programs, kind):
+    step, _ = programs(kind)
+    found = _found(step)
+    assert {d for scope, d in found if scope == "lm.head"} == {FWD, REMAT, BWD}
+    block = {d for scope, d in found if scope.startswith("lm.block")}
+    assert block == ({FWD, REMAT, BWD} if KINDS[kind][1] else {FWD, BWD})
+    # a hand-written VJP's backward keeps the scope its forward was traced under
+    assert ("lm.block/fold", BWD) in found
+    if "lm.block/experts" in KINDS[kind][2]:
+        assert {("lm.block/experts", BWD), ("lm.block/permute", BWD)} <= found
+    # nothing of the loss is outside the gradient, nothing of the update inside it
+    assert {d for scope, d in found if scope == "lm.opt"} == {FWD}
+
+
+@pytest.mark.parametrize("kind", sorted(KINDS))
+def test_the_update_is_outside_the_gradient(programs, kind):
+    step, _ = programs(kind)
+    names = [n for _, n in step if n and "lm.opt" in n]
+    assert names
+    assert not [n for n in names if "jvp" in n or "transpose" in n]
+
+
+#: The share of a program's named heavy instructions that must carry a scope.
+#: A looped stack's ``lax.scan`` stacks each pass's outputs and sums the shared
+#: weights' cotangents over the passes (``dynamic_update_slice``, ``add_any``)
+#: in code that is JAX's own, under no scope of the program.
+COVERED = {"olmoe": 0.95, "olmoe_stacked": 0.95, "zaya": 0.95, "ouro": 0.90}
+
+
+@pytest.mark.parametrize("kind", sorted(KINDS))
+def test_the_scopes_cover_the_programs_heavy_instructions(programs, kind):
+    """Of the ``dot``, ``fusion``, ``custom-call``, ``scatter``, ``sort`` and
+    ``reduce`` instructions that carry a name at all (the compiler's own copies
+    and broadcasts carry none), the share under an ``lm.`` scope."""
+    step, _ = programs(kind)
+    named = [n for opcode, n in step if opcode in HEAVY and n]
+    scoped = [n for n in named if classify(n, ROOT)[0] is not None]
+    assert len(named) > 100
+    assert len(scoped) >= COVERED[kind] * len(named), (len(scoped), len(named))
+
+
+@pytest.mark.parametrize("kind", sorted(KINDS))
+def test_the_scoring_program_shares_the_scopes_and_has_no_backward(programs, kind):
+    _, score = programs(kind)
+    found = _found(score)
+    scopes = {scope for scope, _ in found}
+    assert {"lm.embed", "lm.block/norm", "lm.block/fold", "lm.final_norm/norm", "lm.head"} <= scopes
+    assert "lm.opt" not in scopes and "lm.aux" not in scopes
+    assert {d for _, d in found} == {FWD}
+    assert not [n for _, n in score if n and "transpose(" in n]
