@@ -676,7 +676,10 @@ def _train_program(cfg: LMConfig, compute_type: str, lr: float, batch: int, inte
     """``(optimizer, step)``; ``step(params, opt_state, window, lo)`` trains on
     rows ``lo .. lo + batch`` of the device-resident window and returns the new
     state, the loss, every parameter's gradient norm before clipping (in
-    ``param_shapes`` order) and the step's statistics (``_loss``)."""
+    ``param_shapes`` order) and the step's statistics (``_loss``).
+    ``optimizer.init`` is jitted: the state a fit starts from is one device
+    program's output however many leaves the tree has (eager, optax fills
+    ``mu`` and ``nu`` a leaf at a time, the device idle between the fills)."""
     cd = jnp.dtype(compute_type)
     optimizer = _optimizer(lr)
 
@@ -688,7 +691,7 @@ def _train_program(cfg: LMConfig, compute_type: str, lr: float, batch: int, inte
             updates, opt_state = optimizer.update(grads, opt_state, params)
             return optax.apply_updates(params, updates), opt_state, loss, norms, stats
 
-    return optimizer, jax.jit(step, donate_argnums=(0, 1))
+    return optimizer._replace(init=jax.jit(optimizer.init)), jax.jit(step, donate_argnums=(0, 1))
 
 
 @functools.cache
@@ -832,10 +835,12 @@ class DecoderLM(Estimator, _LMParams):
             visited, pairs = fold_chunk_counts(t, t, 0, True)
             applications = cfg.n_layers * cfg.loops
             folds = applications * cfg.n_heads * batch
+            opt_state = optimizer.init(params)  # one dispatch: fresh buffers, which the step donates
+            state = jax.tree_util.tree_leaves(opt_state)
             phase.set_metadata(built=int(_train_program.cache_info().misses > misses),
                                fold_chunks=folds * pairs, fold_chunks_visited=folds * visited,
-                               loop_trips=cfg.loops, layer_applications=applications)
-            opt_state = optimizer.init(params)
+                               loop_trips=cfg.loops, layer_applications=applications,
+                               state_leaves=len(state), state_bytes=sum(x.nbytes for x in state))
 
         losses, leaf_norms, stats = [], [], []  # device values, fetched once after the loop
         with tracer.phase("train.dispatch", CAT_PRODUCTIVE, steps=steps):

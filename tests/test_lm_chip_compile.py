@@ -139,28 +139,50 @@ def test_expert_matmuls_are_the_grouped_kernel_in_both_directions(one_chip):
     assert f"[{ROWS},{EXPERTS}," not in text  # no [rows, experts, ...] tensor
 
 
-def test_the_zaya_step_program_at_the_cells_shapes(one_chip):
-    """The whole jitted step (forward, backward, clip, AdamW; six rematerialised
-    blocks) of the ``zaya1_8b`` configuration at 2 x 8,192 tokens: it fits the
-    chip (XLA's analysis, which counts what ``peak_bytes_in_use`` does not), the
-    held range's grouped matmuls are the grouped kernel in both directions and
-    the three fold kernels are there at ``H_kv`` 2, T 8,192."""
+def _cell_config(name):
     import json
     import os
 
-    from flink_ml_tpu.models.lm import decoder_lm
-    from flink_ml_tpu.models.lm.config import LMConfig, num_params
-
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "perfbench", "configs", "zaya1_8b.json"), encoding="utf-8") as f:
-        c = json.load(f)
-    cfg = LMConfig(
+    with open(os.path.join(root, "perfbench", "configs", f"{name}.json"), encoding="utf-8") as f:
+        return json.load(f)
+
+
+def _zaya_cut():
+    """``(the benchmark's zaya1_8b configuration, its LMConfig)``."""
+    from flink_ml_tpu.models.lm.config import LMConfig
+
+    c = _cell_config("zaya1_8b")
+    return c, LMConfig(
         c["num_hidden_layers"], c["hidden_size"], c["num_attention_heads"], c["num_experts_published"],
         c["num_experts_per_tok"], c["moe_intermediate_size"], c["vocab_size"],
         rope_theta=float(c["rope_parameters"]["hybrid"]["rope_theta"]), aux_coef=0.0, block="zaya",
         tied=c["tie_word_embeddings"], experts_held=c["num_experts"], first_held=c["first_expert_held"],
         n_kv_heads=c["num_key_value_heads"], head_size=c["head_dim"],
         rope_fraction=c["partial_rotary_factor"], router_width=c["router_hidden_size"])
+
+
+def _ouro_cut():
+    """``(the benchmark's ouro_2_6b configuration, its LMConfig)``."""
+    from flink_ml_tpu.models.lm.config import LMConfig
+
+    c = _cell_config("ouro_2_6b")
+    return c, LMConfig(
+        c["num_hidden_layers"], c["hidden_size"], c["num_attention_heads"], 0, 0, c["intermediate_size"],
+        c["vocab_size"], rope_theta=float(c["rope_theta"]), norm_eps=float(c["rms_norm_eps"]), aux_coef=0.0,
+        block="ouro", loops=c["total_ut_steps"], exit_beta=c["exit_entropy_coef"])
+
+
+def test_the_zaya_step_program_at_the_cells_shapes(one_chip):
+    """The whole jitted step (forward, backward, clip, AdamW; six rematerialised
+    blocks) of the ``zaya1_8b`` configuration at 2 x 8,192 tokens: it fits the
+    chip (XLA's analysis, which counts what ``peak_bytes_in_use`` does not), the
+    held range's grouped matmuls are the grouped kernel in both directions and
+    the three fold kernels are there at ``H_kv`` 2, T 8,192."""
+    from flink_ml_tpu.models.lm import decoder_lm
+    from flink_ml_tpu.models.lm.config import num_params
+
+    c, cfg = _zaya_cut()
     assert 16 * num_params(cfg) > 11e9  # the fullest device holds at least 11 GB of f32 state
     batch, t = c["global_batch_size"], c["sequence_length"]
     optimizer, step = decoder_lm._train_program(cfg, c["compute_dtype"], c["learning_rate"], batch, False)
@@ -196,20 +218,12 @@ def test_the_ouro_step_program_at_the_cells_shapes(one_chip):
     times, four passes of the head. It fits the chip (XLA's analysis), the
     program holds ONE pass and not four (a pass's 24 kernel calls, the block
     inputs stacked over the four trips), and the three fold kernels are there."""
-    import json
-    import os
     import re
 
     from flink_ml_tpu.models.lm import decoder_lm
-    from flink_ml_tpu.models.lm.config import LMConfig, num_params
+    from flink_ml_tpu.models.lm.config import num_params
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "perfbench", "configs", "ouro_2_6b.json"), encoding="utf-8") as f:
-        c = json.load(f)
-    cfg = LMConfig(
-        c["num_hidden_layers"], c["hidden_size"], c["num_attention_heads"], 0, 0, c["intermediate_size"],
-        c["vocab_size"], rope_theta=float(c["rope_theta"]), norm_eps=float(c["rms_norm_eps"]), aux_coef=0.0,
-        block="ouro", loops=c["total_ut_steps"], exit_beta=c["exit_entropy_coef"])
+    c, cfg = _ouro_cut()
     assert num_params(cfg) == 509_661_185  # 8.15 GB of f32 state at 16 bytes a parameter: 47% of 16 GiB
     batch, t = c["global_batch_size"], c["sequence_length"]
     optimizer, step = decoder_lm._train_program(cfg, c["compute_dtype"], c["learning_rate"], batch, False)
@@ -237,6 +251,26 @@ def test_the_ouro_step_program_at_the_cells_shapes(one_chip):
     stacked = f"f32[{cfg.loops},{batch},{t},{cfg.hidden}]"  # what the forward loop holds for the backward, per pass
     assert stacked in text
     assert f"f32[{batch},{cfg.n_heads},{t},{t}]" not in text and f"f32[{batch * cfg.n_heads},{t},{t}]" not in text
+
+
+@pytest.mark.parametrize("cut", [_zaya_cut, _ouro_cut], ids=["zaya", "ouro"])
+def test_the_state_program_at_the_cells_shapes(one_chip, cut):
+    """AdamW's state as ``DecoderLM._fit`` makes it, ``optimizer.init`` jitted,
+    at the cells' parameter trees: one program whose outputs are the whole
+    state (``mu`` and ``nu`` a float32 leaf a parameter each, and the count),
+    which reads no argument and holds nothing beside its outputs."""
+    from flink_ml_tpu.models.lm import decoder_lm
+    from flink_ml_tpu.models.lm.config import num_params, param_shapes
+
+    c, cfg = cut()
+    optimizer, _ = decoder_lm._train_program(cfg, c["compute_dtype"], c["learning_rate"], c["global_batch_size"], False)
+    params = jax.eval_shape(lambda: decoder_lm._init_program(cfg)(jax.random.key(0)))
+    compiled = optimizer.init.lower(jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), params)).compile()
+    assert compiled.out_tree.num_leaves == 2 * len(param_shapes(cfg)) + 1
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == pytest.approx(8 * num_params(cfg) + 4, rel=1e-3)  # padding of small leaves
+    assert memory.argument_size_in_bytes == 0 and memory.temp_size_in_bytes == 0
 
 
 @pytest.mark.parametrize("row_hi", [128, 16])

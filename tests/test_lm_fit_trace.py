@@ -72,8 +72,11 @@ def test_counts_are_what_the_job_says(traced_fit):
     # 256 keys are one chunk, taken whole: the forward's one tile, the dq kernel's four
     # of 64 rows and the dkv kernel's one pair, nothing to hide
     pairs = (1 + 4 + 1) * LAYERS * 2 * BATCH
+    # AdamW's state: mu and nu, a float32 leaf a parameter each, and the int32 count
     assert one["train.program"] == {"built": 0, "fold_chunks": pairs, "fold_chunks_visited": pairs,
-                                    "loop_trips": 1, "layer_applications": LAYERS}
+                                    "loop_trips": 1, "layer_applications": LAYERS,
+                                    "state_leaves": 2 * len(param_shapes(cfg)) + 1,
+                                    "state_bytes": 8 * num_params(cfg) + 4}
     assert one["train.dispatch"] == {"steps": STEPS}
     drain = one["train.drain"]
     assert drain["steps"] == STEPS and drain["tokens"] == STEPS * BATCH * T
@@ -141,8 +144,11 @@ def test_a_looped_stack_reports_its_trips_and_its_exits():
     assert [(s.name, parents.get(s.parent_id), s.category) for s in spans] == TREE
     one = {s.name: s.attrs for s in spans}
     pairs = (1 + 4 + 1) * layers * loops * heads * BATCH  # T 256 is one chunk, as above
+    cfg = est.lm_config()
     assert one["train.program"] == {"built": 1, "fold_chunks": pairs, "fold_chunks_visited": pairs,
-                                    "loop_trips": loops, "layer_applications": layers * loops}
+                                    "loop_trips": loops, "layer_applications": layers * loops,
+                                    "state_leaves": 2 * len(param_shapes(cfg)) + 1,
+                                    "state_bytes": 8 * num_params(cfg) + 4}
     drain = one["train.drain"]
     assert set(drain) == {"steps", "tokens", "exit_trip_sum", "exit_last_mass", "gate_entropy_sum", "trip_nll"}
     tokens = steps * BATCH * T
