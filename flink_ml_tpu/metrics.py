@@ -58,6 +58,8 @@ class MLMetrics:
     TRAIN_LM_TOKENS = "ml.train.lm.tokens"  # tokens the fit's steps consumed, counter
     TRAIN_LM_FOLD_CHUNKS = "ml.train.lm.fold.chunks"  # (query tile, key chunk) pairs of the fit's causal folds, counter
     TRAIN_LM_FOLD_CHUNKS_VISITED = "ml.train.lm.fold.chunks_visited"  # those the mask does not hide: the ones computed, counter
+    TRAIN_LM_FOLD_WIN_CHUNKS = "ml.train.lm.fold.win_chunks"  # the windowed layers' share of lm.fold.chunks, counter
+    TRAIN_LM_FOLD_WIN_CHUNKS_VISITED = "ml.train.lm.fold.win_chunks_visited"  # those neither the mask nor the window hides, counter
     TRAIN_LM_LOOP_TRIPS = "ml.train.lm.loop.trips"  # passes of the whole stack the fit's steps ran (steps x numLoops), counter
     TRAIN_LM_LOOP_LAYER_APPLICATIONS = "ml.train.lm.loop.layer_applications"  # block applications (layers x passes x steps), counter
     TRAIN_MOE_ROWS = "ml.train.moe.rows"  # (token, expert) rows the experts held here ran, counter

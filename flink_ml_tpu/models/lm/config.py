@@ -1,8 +1,9 @@
 """The decoder LM's sizes and its parameter tree, shared by the stage
 (``decoder_lm.py``) and the plain references (``reference.py``,
-``reference_zaya.py``) so that one set of weights can be handed to both.
+``reference_zaya.py``, ``reference_ouro.py``, ``reference_laguna.py``) so that
+one set of weights can be handed to both.
 
-One stack, three kinds of block (``LMConfig.block``). The tree: ``{"embed": [V,
+One stack, four kinds of block (``LMConfig.block``). The tree: ``{"embed": [V,
 d], "layers": [layer, ...], "final_norm": [d], "lm_head": [d, V]}``; a tied
 head (``LMConfig.tied``) has no ``lm_head``: the head is ``embed`` transposed;
 the ``ouro`` kind adds the exit gate ``"exit_gate_w": [d, 1], "exit_gate_b":
@@ -34,6 +35,19 @@ head_dim`` and ``h = expert_width`` the width of the one dense SwiGLU:
 ``"attn_norm": [d], "wq"/"wk"/"wv": [d, a], "wo": [a, d], "attn_out_norm":
 [d], "ffn_norm": [d], "w_gate"/"w_up": [d, h], "w_down": [h, d],
 "ffn_out_norm": [d]``. It has no experts: ``n_experts`` and ``top_k`` are 0.
+
+``laguna`` (layers that differ inside one stack; the equations are in
+``reference_laguna.py``): layer ``i`` has ``H_i = layer_heads[i]`` query heads
+on ``n_kv_heads`` key/value heads, ``a_i = H_i * head_dim``, ``c = n_kv_heads *
+head_dim``: ``"attn_norm": [d], "wq": [d, a_i], "wk"/"wv": [d, c],
+"head_gate": [d, H_i], "wo": [a_i, d], "ffn_norm": [d]``; then, in the first
+``n_dense`` layers, one dense SwiGLU ``"w_gate"/"w_up": [d, dense_width],
+"w_down": [dense_width, d]``, and in the others ``"router": [d, E],
+"router_bias": [E]`` (added to the scores where the experts are CHOSEN and
+nowhere else: no gradient reaches it), the shared expert ``"shared_gate"/
+"shared_up": [d, shared_width], "shared_down": [shared_width, d]`` and the
+held experts as above. Which layers attend through a sliding window
+(``layer_windows[i]`` keys, 0: full attention) changes no leaf.
 """
 from __future__ import annotations
 
@@ -41,7 +55,7 @@ from typing import List, NamedTuple, Tuple
 
 __all__ = ["LMConfig", "BLOCKS", "param_shapes", "num_params", "ONES", "ZEROS", "NORMAL", "SMALL", "SMALL_SCALE"]
 
-BLOCKS = ("olmoe", "zaya", "ouro")
+BLOCKS = ("olmoe", "zaya", "ouro", "laguna")
 #: How a leaf starts: at one (norm weights, scales), at zero (biases, the
 #: router's depth-averaging weight), at ``init_std * normal``, or - the zaya
 #: block's attention output projection - at ``SMALL_SCALE * init_std * normal``.
@@ -78,6 +92,20 @@ class LMConfig(NamedTuple):
     # weight of the exit distribution's entropy in the loss
     loops: int = 1
     exit_beta: float = 0.0
+    # the laguna block's own: query heads and sliding window (keys; 0: full
+    # attention) layer by layer, the leading dense layers and their width, the
+    # shared expert's width, the scale of the renormalised sigmoid gates, the
+    # windowed layers' RoPE base (all channels; the full layers turn
+    # ``rope_fraction`` of them at ``rope_theta``) and the full layers' YaRN:
+    # ``(factor, original length, beta_fast, beta_slow, attention_factor)``
+    layer_heads: Tuple[int, ...] = ()
+    layer_windows: Tuple[int, ...] = ()
+    n_dense: int = 0
+    dense_width: int = 0
+    shared_width: int = 0
+    routed_scale: float = 0.0
+    window_rope_theta: float = 10000.0
+    yarn: Tuple[float, ...] = ()
 
     @property
     def head_dim(self) -> int:
@@ -139,7 +167,24 @@ def _ouro_leaves(cfg: LMConfig, i: int):
     )
 
 
-_LEAVES = {"olmoe": _olmoe_leaves, "zaya": _zaya_leaves, "ouro": _ouro_leaves}
+def _laguna_leaves(cfg: LMConfig, i: int):
+    d, hd, heads = cfg.hidden, cfg.head_dim, cfg.layer_heads[i]
+    a, c = heads * hd, cfg.kv_heads * hd
+    attention = (
+        ("attn_norm", (d,), ONES), ("wq", (d, a), NORMAL), ("wk", (d, c), NORMAL), ("wv", (d, c), NORMAL),
+        ("head_gate", (d, heads), NORMAL), ("wo", (a, d), NORMAL), ("ffn_norm", (d,), ONES),
+    )
+    if i < cfg.n_dense:
+        h = cfg.dense_width
+        return attention + (("w_gate", (d, h), NORMAL), ("w_up", (d, h), NORMAL), ("w_down", (h, d), NORMAL))
+    s = cfg.shared_width
+    return attention + (
+        ("router", (d, cfg.n_experts), NORMAL), ("router_bias", (cfg.n_experts,), ZEROS),
+        ("shared_gate", (d, s), NORMAL), ("shared_up", (d, s), NORMAL), ("shared_down", (s, d), NORMAL),
+    ) + _expert_leaves(cfg)
+
+
+_LEAVES = {"olmoe": _olmoe_leaves, "zaya": _zaya_leaves, "ouro": _ouro_leaves, "laguna": _laguna_leaves}
 
 
 def param_shapes(cfg: LMConfig) -> List[Tuple[tuple, tuple, str]]:

@@ -20,8 +20,19 @@ head, the loss's chunking, the clip and the AdamW program are one:
   and the loss weights the passes' cross-entropies token by token with the
   exit distribution the gates give, less ``exitEntropyCoef`` times its
   entropy. ``transform`` scores the last pass.
+- ``laguna`` (Laguna-XS.2's; ``reference_laguna.py``): layers that differ
+  inside one stack. Layer ``i`` has ``numHeadsPerLayer[i]`` query heads on
+  ``numKvHeads`` key/value heads and attends causally, or through a sliding
+  window of ``windowPerLayer[i]`` keys (the fold skips the key chunks below
+  the window); windowed layers turn every channel by RoPE at
+  ``windowRopeTheta``, full layers ``ropeFraction`` of them at ``ropeTheta``
+  stretched by YaRN (``ropeYarn``); a sigmoid gate per head on the attention
+  output; the first ``denseLayers`` layers feed forward through one dense
+  SwiGLU, the others through sigmoid-gated experts (the chosen
+  ``expertsPerToken`` renormalised and scaled by ``routedScale``) beside a
+  shared expert every token passes.
 
-Either expert kind may tie the head to the embedding (``tieEmbeddings``: one leaf)
+Any expert kind may tie the head to the embedding (``tieEmbeddings``: one leaf)
 and hold a range of each block's experts (``expertsHeld``,
 ``firstExpertHeld``: one chip's share of an expert-parallel layer; tokens
 routed elsewhere get nothing from the block, and ``vocabSize`` is then the
@@ -61,7 +72,7 @@ each of its ``layers x loops`` block applications and each pass's output.
 
 Names: the step program's parts carry ``jax.named_scope``s (``lm.embed``,
 ``lm.block`` with ``norm``, ``proj``, ``rope``, ``conv``, ``fold``, ``mix``,
-``ffn`` and the experts' ``route``, ``permute``, ``experts`` under it,
+``ffn``, ``gate``, ``shared`` and the experts' ``route``, ``permute``, ``experts`` under it,
 ``lm.final_norm``, ``lm.head``, ``lm.exit``, ``lm.aux``, ``lm.opt``), which
 reach each device operation's name beside what JAX's transformations write
 there, so a profile tells the parts, and forward from recomputed from
@@ -75,6 +86,7 @@ device->host copy, which a fit that is followed by ``transform`` never needs).
 from __future__ import annotations
 
 import functools
+import math
 from typing import List, Optional
 
 import jax
@@ -90,7 +102,9 @@ from flink_ml_tpu.models.lm.config import (
 )
 from flink_ml_tpu.params.param import (
     BoolParam,
+    FloatArrayParam,
     FloatParam,
+    IntArrayParam,
     IntParam,
     ParamValidators,
     StringParam,
@@ -106,7 +120,7 @@ from flink_ml_tpu.params.shared import (
 )
 from flink_ml_tpu.parallel.flash import TQ_TILE, flash_available, fold_chunk_counts, fused_fold
 from flink_ml_tpu.parallel.mesh import is_tpu_backend
-from flink_ml_tpu.parallel.moe import moe_dropless
+from flink_ml_tpu.parallel.moe import dense_swiglu, moe_dropless
 from flink_ml_tpu.trace import CAT_COMPILE, CAT_INGEST, CAT_PRODUCTIVE, CAT_READBACK, tracer
 from flink_ml_tpu.utils import read_write as rw
 
@@ -156,7 +170,9 @@ class _LMParams(
         "blockKind",
         "The decoder block: 'olmoe' (multi-head attention with QK-norm, a linear router) or "
         "'zaya' (compressed convolutional attention on grouped queries, an MLP router) or "
-        "'ouro' (a dense sandwich-norm layer, the stack run numLoops times over the same weights).",
+        "'ouro' (a dense sandwich-norm layer, the stack run numLoops times over the same weights) or "
+        "'laguna' (windowed and full attention layers of different head counts, a per-head output "
+        "gate, leading dense layers, sigmoid-gated experts beside a shared one).",
         "olmoe", ParamValidators.in_array(list(BLOCKS)),
     )
     TIE_EMBEDDINGS = BoolParam("tieEmbeddings", "The head is the embedding table transposed.", False)
@@ -169,13 +185,14 @@ class _LMParams(
     FIRST_EXPERT_HELD = IntParam("firstExpertHeld", "First expert of the held range.", 0,
                                  ParamValidators.gt_eq(0))
     NUM_KV_HEADS = IntParam(
-        "numKvHeads", "Key/value heads ('zaya'; numHeads divides evenly over them). 0: numHeads.", 0,
+        "numKvHeads", "Key/value heads ('zaya', 'laguna'; the query heads divide evenly over them). 0: numHeads.", 0,
         ParamValidators.gt_eq(0),
     )
-    HEAD_SIZE = IntParam("headSize", "Channels per head ('zaya'). 0: hiddenSize / numHeads.", 0,
+    HEAD_SIZE = IntParam("headSize", "Channels per head ('zaya', 'laguna'). 0: hiddenSize / numHeads.", 0,
                          ParamValidators.gt_eq(0))
     ROPE_FRACTION = FloatParam(
-        "ropeFraction", "Share of each head's channels the rotary embedding turns ('zaya').", 1.0,
+        "ropeFraction", "Share of each head's channels the rotary embedding turns ('zaya'; 'laguna': in "
+        "its full-attention layers).", 1.0,
         ParamValidators.in_range(0.0, 1.0, lower_inclusive=False),
     )
     ROUTER_WIDTH = IntParam("routerWidth", "Width of the router MLP ('zaya').", 256, ParamValidators.gt(0))
@@ -187,6 +204,27 @@ class _LMParams(
         "exitEntropyCoef", "Weight of the exit distribution's entropy, subtracted from the loss ('ouro').",
         0.1, ParamValidators.gt_eq(0),
     )
+    NUM_HEADS_PER_LAYER = IntArrayParam(
+        "numHeadsPerLayer", "Query heads of each layer, numLayers of them ('laguna').", [])
+    WINDOW_PER_LAYER = IntArrayParam(
+        "windowPerLayer", "Each layer's sliding window in keys, the query's own among them; 0: full causal "
+        "attention ('laguna').", [])
+    DENSE_LAYERS = IntParam(
+        "denseLayers", "Leading layers whose feed-forward is one dense SwiGLU of denseWidth ('laguna').", 0,
+        ParamValidators.gt_eq(0))
+    DENSE_WIDTH = IntParam("denseWidth", "Hidden width of a dense layer's SwiGLU ('laguna').", 0,
+                           ParamValidators.gt_eq(0))
+    SHARED_EXPERT_WIDTH = IntParam(
+        "sharedExpertWidth", "Hidden width of the expert every token passes beside the routed ones ('laguna').", 0,
+        ParamValidators.gt_eq(0))
+    ROUTED_SCALE = FloatParam(
+        "routedScale", "The sigmoid gates of the chosen experts are renormalised to sum to one and scaled by "
+        "this ('laguna').", 1.0, ParamValidators.gt(0))
+    WINDOW_ROPE_THETA = FloatParam("windowRopeTheta", "Base of the rotary embedding in windowed layers, which "
+                                   "turn every channel ('laguna').", 10000.0, ParamValidators.gt(0))
+    ROPE_YARN = FloatArrayParam(
+        "ropeYarn", "YaRN on the full layers' rotary embedding ('laguna'): factor, original length, beta_fast, "
+        "beta_slow, attention factor; empty: none.", [])
     COMPUTE_TYPE = StringParam(
         "computeType",
         "Matmul input dtype: 'bfloat16' runs every matmul and the attention "
@@ -221,8 +259,36 @@ class _LMParams(
             if int(cfg.head_dim * cfg.rope_fraction) % 2:
                 raise ValueError(f"the rotary embedding needs an even number of channels, got "
                                  f"{cfg.rope_fraction} of {cfg.head_dim}")
+        elif cfg.block == "laguna":
+            cfg = cfg._replace(
+                n_kv_heads=self.get(self.NUM_KV_HEADS), head_size=self.get(self.HEAD_SIZE),
+                rope_fraction=self.get(self.ROPE_FRACTION), aux_coef=0.0,  # balanced by a bias rule, as 'zaya'
+                layer_heads=tuple(self.get(self.NUM_HEADS_PER_LAYER)),
+                layer_windows=tuple(self.get(self.WINDOW_PER_LAYER)),
+                n_dense=self.get(self.DENSE_LAYERS), dense_width=self.get(self.DENSE_WIDTH),
+                shared_width=self.get(self.SHARED_EXPERT_WIDTH), routed_scale=self.get(self.ROUTED_SCALE),
+                window_rope_theta=self.get(self.WINDOW_ROPE_THETA), yarn=tuple(self.get(self.ROPE_YARN)),
+            )
+            if not len(cfg.layer_heads) == len(cfg.layer_windows) == cfg.n_layers:
+                raise ValueError(f"numHeadsPerLayer ({len(cfg.layer_heads)}) and windowPerLayer "
+                                 f"({len(cfg.layer_windows)}) name each of the {cfg.n_layers} layers")
+            if not cfg.head_size or any(h <= 0 or h % cfg.kv_heads for h in cfg.layer_heads):
+                raise ValueError(f"every layer's query heads {cfg.layer_heads} divide evenly over numKvHeads "
+                                 f"{cfg.kv_heads}, at a stated headSize")
+            if min(cfg.layer_windows) < 0 or cfg.n_dense > cfg.n_layers:
+                raise ValueError(f"windowPerLayer {cfg.layer_windows} counts keys and denseLayers {cfg.n_dense} "
+                                 f"is at most numLayers {cfg.n_layers}")
+            if (cfg.n_dense and not cfg.dense_width) or (cfg.n_dense < cfg.n_layers and not cfg.shared_width):
+                raise ValueError("a dense layer needs denseWidth and an expert layer sharedExpertWidth")
+            if int(cfg.head_dim * cfg.rope_fraction) % 2 or len(cfg.yarn) not in (0, 5):
+                raise ValueError(f"the rotary embedding needs an even number of channels, got {cfg.rope_fraction} "
+                                 f"of {cfg.head_dim}; ropeYarn has five numbers or none, got {len(cfg.yarn)}")
         elif self.get(self.NUM_KV_HEADS) or self.get(self.HEAD_SIZE) or self.get(self.ROPE_FRACTION) != 1.0:
-            raise ValueError("numKvHeads, headSize and ropeFraction belong to blockKind 'zaya'")
+            raise ValueError("numKvHeads, headSize and ropeFraction belong to blockKind 'zaya' or 'laguna'")
+        if cfg.block != "laguna" and (self.get(self.NUM_HEADS_PER_LAYER) or self.get(self.WINDOW_PER_LAYER)
+                                      or self.get(self.DENSE_LAYERS) or self.get(self.SHARED_EXPERT_WIDTH)):
+            raise ValueError("numHeadsPerLayer, windowPerLayer, denseLayers and sharedExpertWidth belong to "
+                             "blockKind 'laguna'")
         if cfg.block == "ouro":  # a dense block: no experts, no router, nothing to balance
             cfg = cfg._replace(n_experts=0, top_k=0, aux_coef=0.0, loops=self.get(self.NUM_LOOPS),
                                exit_beta=self.get(self.EXIT_ENTROPY_COEF))
@@ -264,7 +330,10 @@ _add_accessors(_LMParams, (
     ("TIE_EMBEDDINGS", "tie_embeddings"), ("EXPERTS_HELD", "experts_held"),
     ("FIRST_EXPERT_HELD", "first_expert_held"), ("NUM_KV_HEADS", "num_kv_heads"), ("HEAD_SIZE", "head_size"),
     ("ROPE_FRACTION", "rope_fraction"), ("ROUTER_WIDTH", "router_width"), ("NUM_LOOPS", "num_loops"),
-    ("EXIT_ENTROPY_COEF", "exit_entropy_coef"),
+    ("EXIT_ENTROPY_COEF", "exit_entropy_coef"), ("NUM_HEADS_PER_LAYER", "num_heads_per_layer"),
+    ("WINDOW_PER_LAYER", "window_per_layer"), ("DENSE_LAYERS", "dense_layers"), ("DENSE_WIDTH", "dense_width"),
+    ("SHARED_EXPERT_WIDTH", "shared_expert_width"), ("ROUTED_SCALE", "routed_scale"),
+    ("WINDOW_ROPE_THETA", "window_rope_theta"), ("ROPE_YARN", "rope_yarn"),
 ))
 
 
@@ -355,17 +424,18 @@ def _proj(a, w, cd):
         return _matmul(a, w, cd)
 
 
-def _fold(q, k, v, cd, interpret: bool):
+def _fold(q, k, v, cd, interpret: bool, window: Optional[int] = None):
     """Causal softmax attention of ``q [B, H, T, D]`` on ``k``, ``v`` ``[B,
     H_kv, T, D]`` at scale ``D^-1/2`` through the fused fold: a ring of one,
-    the whole sequence is the resident KV block."""
+    the whole sequence is the resident KV block. Under a ``window`` each query
+    keeps the ``window`` keys that end at itself."""
     with jax.named_scope("fold"):
         b, h, t, hd = q.shape
         m0 = jnp.full((b, h, t), -jnp.inf, jnp.float32)
         l0 = jnp.zeros((b, h, t), jnp.float32)
         acc0 = jnp.zeros((b, h, t, hd), jnp.float32)
         _, l, acc = fused_fold(q.astype(cd), k.astype(cd), v.astype(cd), m0, l0, acc0, jnp.int32(0),
-                               jnp.int32(0), True, False, jnp.int32(0), float(hd) ** -0.5, interpret)
+                               jnp.int32(0), True, False, jnp.int32(0), float(hd) ** -0.5, interpret, window)
         return acc / l[..., None]  # causal: every row attends at least to itself, l > 0
 
 
@@ -528,7 +598,85 @@ def _ouro_block(x, carry, layer, cfg: LMConfig, cd, interpret: bool):
         return x + y, carry, {}
 
 
+# -- the laguna block (reference_laguna.py carries each equation's origin) --------
+
+
+def _yarn_tables(t: int, rot: int, theta: float, yarn):
+    """``cos, sin [T, rot]`` of RoPE at base ``theta`` stretched by YaRN
+    (``yarn = (factor, original length, beta_fast, beta_slow, attention
+    factor)``): each frequency a blend of itself and itself over ``factor``, by
+    a linear ramp over the channel pairs between the two correction dimensions
+    (the pairs that turn ``beta_fast`` and ``beta_slow`` times within the
+    original length); both tables times the attention factor. No ``yarn``:
+    ``_rope_tables``."""
+    if not yarn:
+        return _rope_tables(t, rot, theta)
+    factor, original, beta_fast, beta_slow, attention_factor = yarn
+    with jax.named_scope("rope"):
+        pos_freqs = theta ** (np.arange(0, rot, 2, dtype=np.float64) / rot)
+
+        def correction_dim(rotations):
+            return rot * math.log(original / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+        low = max(math.floor(correction_dim(beta_fast)), 0)
+        high = min(math.ceil(correction_dim(beta_slow)), rot - 1)
+        ramp = np.clip((np.arange(rot // 2) - low) / max(high - low, 1e-3), 0.0, 1.0)
+        inv_freq = jnp.asarray(ramp / (factor * pos_freqs) + (1.0 - ramp) / pos_freqs, jnp.float32)
+        freqs = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+        emb = jnp.concatenate([freqs, freqs], axis=-1)  # [T, rot]
+        return jnp.float32(attention_factor) * jnp.cos(emb), jnp.float32(attention_factor) * jnp.sin(emb)
+
+
+def _laguna_block(x, carry, layer, cfg: LMConfig, cd, interpret: bool, window: int):
+    """One layer of a stack whose layers differ: as many query heads as its
+    leaves say, on the shared key/value heads; full (``window`` 0) or windowed
+    attention with the position encoding that goes with it; a sigmoid gate per
+    head on the attention output; then one dense SwiGLU (a leading layer: it
+    has no router) or sigmoid-gated experts beside a shared one."""
+    b, t, d = x.shape
+    heads, kv, hd = layer["head_gate"].shape[1], cfg.kv_heads, cfg.head_dim
+    a = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+    q, k, v = (_heads(_proj(a, layer[w], cd), n) for w, n in (("wq", heads), ("wk", kv), ("wv", kv)))
+    if window:
+        cos, sin = _rope_tables(t, hd, cfg.window_rope_theta)
+        q, k = _rope(q, cos, sin), _rope(k, cos, sin)
+    else:
+        cos, sin = _yarn_tables(t, int(hd * cfg.rope_fraction), cfg.rope_theta, cfg.yarn)
+        q, k = _rope_part(q, cos, sin), _rope_part(k, cos, sin)
+    o = _fold(q, k, v, cd, interpret, window or None)
+    with jax.named_scope("gate"):
+        g = jax.nn.sigmoid(_matmul(a, layer["head_gate"], cd))  # [B, T, H]
+        o = o * jnp.transpose(g, (0, 2, 1))[..., None]
+    o = _proj(_merged(o), layer["wo"], cd)
+    with jax.named_scope("mix"):
+        x = x + o
+    u = _rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
+    if "router" not in layer:  # a leading dense layer
+        with jax.named_scope("ffn"):
+            y, stats = dense_swiglu(u, layer["w_gate"], layer["w_up"], layer["w_down"], cd), {}
+    else:
+        u = u.reshape(b * t, d)
+        y, stats = moe_dropless(u, layer["router"], layer["w_gate"], layer["w_up"], layer["w_down"], cfg.top_k,
+                                cd, cfg.first_held, cfg.routed_scale, layer["router_bias"])
+        with jax.named_scope("shared"):
+            y = (y + dense_swiglu(u, layer["shared_gate"], layer["shared_up"], layer["shared_down"], cd)
+                 ).reshape(b, t, d)
+    with jax.named_scope("mix"):
+        return x + y, carry, stats
+
+
 _BLOCKS = {"olmoe": _olmoe_block, "zaya": _zaya_block, "ouro": _ouro_block}
+
+
+def _layer_blocks(cfg: LMConfig) -> list:
+    """Each layer's block function ``(x, carry, layer, cfg, cd, interpret)``.
+    A stack that repeats one block names ONE function (its layers then trace
+    to one shared sub-program where their leaves agree); the laguna stack one
+    for each window among its layers."""
+    if cfg.block != "laguna":
+        return [_BLOCKS[cfg.block]] * cfg.n_layers
+    by_window = {w: functools.partial(_laguna_block, window=w) for w in set(cfg.layer_windows)}
+    return [by_window[w] for w in cfg.layer_windows]
 
 
 def _hidden(params, tok, cfg: LMConfig, cd, interpret: bool):
@@ -542,20 +690,22 @@ def _hidden(params, tok, cfg: LMConfig, cd, interpret: bool):
     with jax.named_scope("lm.embed"):
         x = params["embed"][tok]
 
-    def block(x, carry, layer):  # the scope opens inside what is rematerialised
-        with jax.named_scope("lm.block"):
-            return _BLOCKS[cfg.block](x, carry, layer, cfg, cd, interpret)
+    @functools.cache
+    def scoped(kind):
+        def block(x, carry, layer):  # the scope opens inside what is rematerialised
+            with jax.named_scope("lm.block"):
+                return kind(x, carry, layer, cfg, cd, interpret)
 
-    if cfg.n_layers * cfg.loops > 1:
         # a lone block's residuals are wanted as soon as the head's backward
         # ends: holding them costs nothing at the peak, recomputing them a forward
-        block = jax.checkpoint(block)
+        return jax.checkpoint(block) if cfg.n_layers * cfg.loops > 1 else block
 
     def stack(x):
         routed, carry = [], None
-        for layer in params["layers"]:
-            x, carry, stats = block(x, carry, layer)
-            routed.append(stats)
+        for kind, layer in zip(_layer_blocks(cfg), params["layers"]):
+            x, carry, stats = scoped(kind)(x, carry, layer)
+            if stats:  # a layer without experts has nothing to report
+                routed.append(stats)
         with jax.named_scope("lm.final_norm"):
             return _rms_norm(x, params["final_norm"], cfg.norm_eps), routed
 
@@ -798,8 +948,8 @@ class DecoderLM(Estimator, _LMParams):
     rows x length. After ``fit``, per step: ``loss_history``,
     ``grad_norm_history`` (global, before clipping), ``param_grad_norm_history``
     (``[steps, parameters]``, columns named by ``param_names``),
-    ``expert_rows_history`` (``[steps, layers, experts]`` routed rows; no
-    experts, no columns) and ``trip_loss_history`` (``[steps, passes]``: each
+    ``expert_rows_history`` (``[steps, layers with experts, experts]`` routed
+    rows; no experts, no columns) and ``trip_loss_history`` (``[steps, passes]``: each
     pass's own mean cross-entropy; a stack passed once has no columns)."""
 
     def fit(self, *inputs) -> DecoderLMModel:
@@ -831,16 +981,25 @@ class DecoderLM(Estimator, _LMParams):
                 cfg, self.get_compute_type(), float(self.get_learning_rate()), batch, interpret
             )
             # what the causal fold's three kernels walk in one step, each counted once
-            # (a rematerialised forward not again), and what the mask lets them skip
-            visited, pairs = fold_chunk_counts(t, t, 0, True)
+            # (a rematerialised forward not again), and what the mask lets them skip;
+            # the windowed layers' share of both beside them
             applications = cfg.n_layers * cfg.loops
-            folds = applications * cfg.n_heads * batch
+            heads = cfg.layer_heads or (cfg.n_heads,) * cfg.n_layers
+            windows = cfg.layer_windows or (0,) * cfg.n_layers
+            one_head = {w: np.asarray(fold_chunk_counts(t, t, 0, True, w or None)) for w in set(windows)}
+            chunks = np.zeros((2, 2), np.int64)  # [full, windowed] x [visited, all]
+            for h, w in zip(heads, windows):
+                chunks[int(w > 0)] += cfg.loops * h * batch * one_head[w]
             opt_state = optimizer.init(params)  # one dispatch: fresh buffers, which the step donates
             state = jax.tree_util.tree_leaves(opt_state)
             phase.set_metadata(built=int(_train_program.cache_info().misses > misses),
-                               fold_chunks=folds * pairs, fold_chunks_visited=folds * visited,
+                               fold_chunks=int(chunks[:, 1].sum()), fold_chunks_visited=int(chunks[:, 0].sum()),
                                loop_trips=cfg.loops, layer_applications=applications,
                                state_leaves=len(state), state_bytes=sum(x.nbytes for x in state))
+            if any(windows):
+                phase.set_metadata(layers_windowed=sum(w > 0 for w in windows),
+                                   layers_full=sum(w == 0 for w in windows),
+                                   fold_win_chunks=int(chunks[1, 1]), fold_win_chunks_visited=int(chunks[1, 0]))
 
         losses, leaf_norms, stats = [], [], []  # device values, fetched once after the loop
         with tracer.phase("train.dispatch", CAT_PRODUCTIVE, steps=steps):
@@ -857,14 +1016,14 @@ class DecoderLM(Estimator, _LMParams):
             stats = jax.device_get({k: jnp.stack([s[k] for s in stats]) for k in stats[0]})
             phase.set_metadata(tokens=steps * batch * t)
             loads = np.asarray(stats.get("rows", np.zeros((steps, cfg.n_layers, 0), np.int32)))
-            if loads.size:  # [steps, layers, experts]
+            if loads.size:  # [steps, layers with experts, experts]
                 held = loads[:, :, cfg.first_held: cfg.first_held + cfg.held]
                 rows_held = int(held.sum())
                 rows_absent = int(loads.sum()) - rows_held
                 phase.set_metadata(
                     expert_rows_max=int(loads.max()),
                     expert_rows_mean=batch * t * cfg.top_k // cfg.n_experts,
-                    dropped=int(steps * batch * t * cfg.top_k * cfg.n_layers - loads.sum()),
+                    dropped=int(steps * batch * t * cfg.top_k * loads.shape[1] - loads.sum()),
                     rows_held=rows_held,
                     rows_absent=rows_absent,
                     held_rows_max=int(held.max()),
@@ -886,8 +1045,12 @@ class DecoderLM(Estimator, _LMParams):
         self.expert_rows_history = loads
         self.trip_loss_history = trips
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_TOKENS, steps * batch * t)
-        metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_CHUNKS, steps * folds * pairs)
-        metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_CHUNKS_VISITED, steps * folds * visited)
+        metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_CHUNKS, steps * int(chunks[:, 1].sum()))
+        metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_CHUNKS_VISITED, steps * int(chunks[:, 0].sum()))
+        if any(windows):
+            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_WIN_CHUNKS, steps * int(chunks[1, 1]))
+            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_WIN_CHUNKS_VISITED,
+                            steps * int(chunks[1, 0]))
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_LOOP_TRIPS, steps * cfg.loops)
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_LOOP_LAYER_APPLICATIONS, steps * applications)
         if loads.size:

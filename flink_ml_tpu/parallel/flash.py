@@ -46,6 +46,15 @@ H_kv, Tk, D]``. With ``H_kv = H`` the index maps and the grid are what they
 were. ``reference_fold`` keeps equal head counts: a grouped caller is tested
 against it on K and V repeated.
 
+A sliding window: ``window`` (static, with ``causal``) keeps of each query's
+keys the ``window`` that end at the query, ``t - window < j <= t``. It is a
+second edge on the same walk: a cell also skips the chunks whose last key is
+too old for its first query, and masks the chunk the window's edge crosses as
+it masks the diagonal's (``_window_chunks``); the dkv-kernel's grid skips the
+pairs on either side. The kernels of a windowed fold are named
+``flash_fold_win_*``; without a window every kernel is traced as it was before
+the fold knew of one.
+
 Availability: TPU compiled, or any backend under ``interpret=True``. The
 caller (``ring.py``) falls back to the jnp fold when the local length does
 not tile or the devices have no Mosaic backend.
@@ -237,15 +246,39 @@ def _visible_chunks(q_first, n_rows: int, k_pos0, chunk: int, n_chunks: int, n_v
     return n_full, n_vis  # a chunk that needs no mask is visible: n_full <= n_vis
 
 
-def _mask_chunk(s, q_first, k_first, causal: bool, n_valid=None):
-    """``s [rows, keys]`` with ``-inf`` where the causal mask (when ``causal``)
-    or ``n_valid`` (when given; one of the two is) drops the entry;
-    ``q_first``/``k_first`` are the global positions of row 0 and key 0."""
+def _most(a, b):
+    return max(a, b) if isinstance(a, int) and isinstance(b, int) else jnp.maximum(a, b)
+
+
+def _window_chunks(q_first, n_rows: int, k_pos0, chunk: int, n_chunks: int, window: int, n_full, n_vis):
+    """The lower edge a sliding ``window`` adds to ``_visible_chunks``' two
+    counts (query ``t`` keeps keys ``t - window < j <= t``): ``(n_lo, lo_end,
+    n_full)`` with chunks ``[0, n_lo)`` hidden below the window (their last key
+    is too old for the tile's first query), ``[n_lo, lo_end)`` crossed by the
+    window's edge, ``[lo_end, n_full)`` kept whole and ``[n_full, n_vis)``
+    crossed by the diagonal as before (a chunk both edges cross is masked once,
+    by both). ``n_lo <= lo_end <= n_full <= n_vis``."""
+    off = q_first - k_pos0
+    n_lo = _least(_chunks_upto(off - window + 1, chunk, n_chunks), n_vis)  # last key <= first query - window
+    # chunks that start at or below the last query's oldest kept key less one: the edge crosses them
+    crossed = _chunks_upto(off + n_rows - 1 - window + chunk, chunk, n_chunks)
+    lo_end = _least(_most(crossed, n_lo), n_vis)
+    return n_lo, lo_end, _least(_most(n_full, lo_end), n_vis)
+
+
+def _mask_chunk(s, q_first, k_first, causal: bool, n_valid=None, window=None):
+    """``s [rows, keys]`` with ``-inf`` where the causal mask (when ``causal``),
+    the sliding ``window`` under it (when given: a query keeps the ``window``
+    keys ending at itself) or ``n_valid`` (when given; ``causal`` or it is)
+    drops the entry; ``q_first``/``k_first`` are the global positions of row 0
+    and key 0."""
     q_pos = q_first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) if causal else None
     k_pos = k_first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     keep = q_pos >= k_pos if causal else k_pos < n_valid
     if causal and n_valid is not None:
         keep &= k_pos < n_valid
+    if window is not None:
+        keep &= k_pos > q_pos - window
     return jnp.where(keep, s, -jnp.inf)
 
 
@@ -265,11 +298,13 @@ def _key_rows(ref, c, kc: int):
     return ref[0, pl.ds(pl.multiple_of(c * kc, kc), kc), :]
 
 
-def _park_scores(qt, k_ref, s_scr, q_first, k_pos0, n_valid, scale, kc: int, n_full, n_vis):
+def _park_scores(qt, k_ref, s_scr, q_first, k_pos0, n_valid, scale, kc: int, n_full, n_vis, window=None,
+                 n_lo=0, lo_end=0):
     """The first walk of a causal forward or dq cell: the scores of ``qt
     [rows, D]`` on key chunks ``[0, n_vis)``, masked on ``[n_full, n_vis)``,
-    parked in ``s_scr [chunks, rows, kc]``. Returns their running row max,
-    still 128 lanes wide."""
+    parked in ``s_scr [chunks, rows, kc]``. Under a ``window`` the walk starts
+    at chunk ``n_lo`` and ``[n_lo, lo_end)`` are masked too
+    (``_window_chunks``). Returns their running row max, still 128 lanes wide."""
 
     def walk(on_diagonal):
         def body(c, mx):
@@ -278,37 +313,60 @@ def _park_scores(qt, k_ref, s_scr, q_first, k_pos0, n_valid, scale, kc: int, n_f
                 preferred_element_type=jnp.float32,
             ) * scale  # [rows, kc]
             if on_diagonal:
-                s = _mask_chunk(s, q_first, k_pos0 + c * kc, True, n_valid)
+                s = _mask_chunk(s, q_first, k_pos0 + c * kc, True, n_valid, window)
             s_scr[c] = s
             return jnp.maximum(mx, _fold_lanes(s, jnp.maximum))
 
         return body
 
     mx = jnp.full((qt.shape[0], _LANES), -jnp.inf, jnp.float32)
-    mx = jax.lax.fori_loop(0, n_full, walk(False), mx)
+    if window is not None:  # the window's edge first; "diagonal" there means masked, by both edges
+        mx = jax.lax.fori_loop(n_lo, lo_end, walk(True), mx)
+        n_lo = lo_end
+    mx = jax.lax.fori_loop(n_lo, n_full, walk(False), mx)
     return jax.lax.fori_loop(n_full, n_vis, walk(True), mx)
 
 
-def fold_chunk_counts(Tq: int, Tk: int, q_off: int, causal: bool):
+def fold_chunk_counts(Tq: int, Tk: int, q_off: int, causal: bool, window=None):
     """``(visited, total)`` chunk pairs of ONE fold of ``Tq`` queries on ``Tk``
     keys, one head, the three kernels together at the tiles they use: the
     forward's and the dq kernel's (query tile, key chunk) pairs and the dkv
     kernel's (key tile, query tile) pairs. ``q_off = q_pos0 - k_pos0``. A kernel
     that takes the block in one piece visits every pair: all three without
-    ``causal``, the forward and the dq kernel on a block of one chunk."""
+    ``causal``, the forward and the dq kernel on a block of one chunk. Under a
+    sliding ``window`` (``causal`` with it) a walk also skips the chunks below
+    the window's edge."""
     tq_fwd, tq_dq, tq_dkv, tk_dkv, kc = _fold_tiles(Tq, Tk, causal)
     visited = total = 0
+
+    def seen(q_first, rows, keys, n_keys):
+        n_full, n_vis = _visible_chunks(q_first, rows, 0, keys, n_keys)
+        if window is None:
+            return n_vis
+        return n_vis - _window_chunks(q_first, rows, 0, keys, n_keys, window, n_full, n_vis)[0]
+
     for rows, keys, skips in ((tq_fwd, kc, kc < Tk), (tq_dq, kc, kc < Tk), (tq_dkv, tk_dkv, causal)):
         n_keys = Tk // keys
         total += (Tq // rows) * n_keys
-        visited += sum(
-            _visible_chunks(q_off + j * rows, rows, 0, keys, n_keys)[1] if skips else n_keys
-            for j in range(Tq // rows)
-        )
+        visited += sum(seen(q_off + j * rows, rows, keys, n_keys) if skips else n_keys for j in range(Tq // rows))
     return visited, total
 
 
-def reference_fold(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale):
+def _kept(Tq: int, Tk: int, q_pos0, k_pos0, causal, n_valid, window):
+    """The references' mask ``[Tq, Tk]``: which (query, key) entries are kept."""
+    q_pos = q_pos0 + jnp.arange(Tq)
+    k_pos = k_pos0 + jnp.arange(Tk)
+    keep = jnp.ones((Tq, Tk), bool)
+    if causal:
+        keep &= q_pos[:, None] >= k_pos[None, :]
+    if n_valid is not None:
+        keep &= (k_pos < jnp.asarray(n_valid))[None, :]
+    if window is not None:
+        keep &= k_pos[None, :] > q_pos[:, None] - window
+    return keep
+
+
+def reference_fold(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale, window=None):
     """The jnp fold in [B, H, ...] layout (ring.py numerics) — the source of
     truth the kernel is tested against and the backward recomputes through.
 
@@ -316,18 +374,13 @@ def reference_fold(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale)
     the kernels also take ``[B, H_kv, Tk, D]``); ``m``/``l`` [B, H, Tq];
     ``acc`` [B, H, Tq, D]. ``q_pos0``/``k_pos0`` are the global positions of
     query/key 0 (traced scalars); ``n_valid`` masks keys at global positions
-    >= it (None = unmasked).
+    >= it (None = unmasked); ``window`` (with ``causal``) keeps of each
+    query's keys the ``window`` that end at it.
     """
     Tq, Tk = q.shape[2], kb.shape[2]
     s = jnp.einsum("bhqd,bhkd->bhqk", q, kb) * scale
     if causal or n_valid is not None:
-        q_pos = q_pos0 + jnp.arange(Tq)
-        k_pos = k_pos0 + jnp.arange(Tk)
-        mask = jnp.ones((Tq, Tk), bool)
-        if causal:
-            mask &= q_pos[:, None] >= k_pos[None, :]
-        if n_valid is not None:
-            mask &= (k_pos < jnp.asarray(n_valid))[None, :]
+        mask = _kept(Tq, Tk, q_pos0, k_pos0, causal, n_valid, window)
         s = jnp.where(mask[None, None, :, :], s, -jnp.inf)
     block_max = jnp.max(s, axis=-1)
     new_m = jnp.maximum(m, block_max)
@@ -340,11 +393,19 @@ def reference_fold(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale)
     return new_m, new_l, new_acc
 
 
+def _kernel_name(part: str, window) -> str:
+    """A windowed fold's kernels carry names of their own, so that a device
+    trace tells a stack's windowed layers from its full ones."""
+    return f"flash_fold_{part}" if window is None else f"flash_fold_win_{part}"
+
+
 def _fold_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
-                 interpret=False):
+                 interpret=False, window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if window is not None and not (causal and window > 0):
+        raise ValueError(f"a sliding window ({window}) lies under the causal mask and holds at least the query")
     B, H, Tq, D = q.shape
     Hkv, Tk = kb.shape[1], kb.shape[2]
     BH = B * H
@@ -362,7 +423,7 @@ def _fold_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
         ) * scale  # [TQ, Tk]
         if causal or masked:
             s = _mask_chunk(s, scalars_ref[0] + pl.program_id(1) * tq, scalars_ref[1], causal,
-                            scalars_ref[2] if masked else None)
+                            scalars_ref[2] if masked else None, window)
         # m/l ride as [TQ, 1] columns (Mosaic wants >= 2-D tiles with an
         # aligned or full trailing dim); all the math stays 2-D.
         mcol = m_ref[0]  # [TQ, 1]
@@ -388,7 +449,11 @@ def _fold_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
         q_first = scalars_ref[0] + pl.program_id(1) * tq
         nv = scalars_ref[2] if masked else None
         n_full, n_vis = _visible_chunks(q_first, tq, scalars_ref[1], kc, n_chunks, nv)
-        mx = _park_scores(q_ref[0], k_ref, s_scr, q_first, scalars_ref[1], nv, scale, kc, n_full, n_vis)
+        n_lo = lo_end = 0
+        if window is not None:
+            n_lo, lo_end, n_full = _window_chunks(q_first, tq, scalars_ref[1], kc, n_chunks, window, n_full, n_vis)
+        mx = _park_scores(q_ref[0], k_ref, s_scr, q_first, scalars_ref[1], nv, scale, kc, n_full, n_vis,
+                          window, n_lo, lo_end)
         mcol = m_ref[0]  # [TQ, 1]
         new_m = jnp.maximum(mcol, jnp.max(mx, axis=1, keepdims=True))
         safe_m = jnp.where(jnp.isneginf(new_m), 0.0, new_m)
@@ -406,7 +471,7 @@ def _fold_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
             return l_lanes + _fold_lanes(p, jnp.add)
 
         l_lanes = jax.lax.fori_loop(
-            0, n_vis, accumulate, jnp.zeros((tq, _LANES), jnp.float32)
+            n_lo, n_vis, accumulate, jnp.zeros((tq, _LANES), jnp.float32)
         )
         lo_ref[0] = l_ref[0] * correction + jnp.sum(l_lanes, axis=1, keepdims=True)
 
@@ -445,7 +510,7 @@ def _fold_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
         ],
         interpret=interpret,
         compiler_params=_compiler_params(),
-        name="flash_fold_fwd",
+        name=_kernel_name("fwd", window),
     )(
         scalars,
         q.reshape(BH, Tq, D),
@@ -458,38 +523,41 @@ def _fold_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
     return mo.reshape(B, H, Tq), lo.reshape(B, H, Tq), ao.reshape(B, H, Tq, D)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 11, 12))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 11, 12, 13))
 def fused_fold(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, has_n_valid,
-               n_valid, scale, interpret=False):
+               n_valid, scale, interpret=False, window=None):
     """One ring-attention fold, fused. Same contract as ``reference_fold``,
     and ``kb``/``vb`` may carry fewer heads than ``q`` (grouped queries: the
     module docstring) (``n_valid`` is a traced scalar consumed only when ``has_n_valid``);
     the primal runs the Pallas forward kernel and gradients run the fused
     backward kernels (``_fold_bwd_pallas``, AD-exact).
-    ``causal``/``has_n_valid``/``scale``/``interpret`` are static.
+    ``causal``/``has_n_valid``/``scale``/``interpret``/``window`` are static;
+    ``window`` (an int, with ``causal``) keeps of each query's keys the
+    ``window`` that end at the query ("a sliding window" in the module
+    docstring), ``None`` traces the kernels as they were without one.
     """
     return _fold_pallas(
         q, kb, vb, m, l, acc, q_pos0, k_pos0, causal,
-        n_valid if has_n_valid else None, scale, interpret=interpret,
+        n_valid if has_n_valid else None, scale, interpret=interpret, window=window,
     )
 
 
 def _fused_fold_fwd(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, has_n_valid,
-                    n_valid, scale, interpret=False):
+                    n_valid, scale, interpret=False, window=None):
     out = fused_fold(
         q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, has_n_valid, n_valid,
-        scale, interpret,
+        scale, interpret, window,
     )
     return out, (q, kb, vb, m, l, acc, q_pos0, k_pos0, n_valid)
 
 
-def _fused_fold_bwd(causal, has_n_valid, scale, interpret, res, g):
+def _fused_fold_bwd(causal, has_n_valid, scale, interpret, window, res, g):
     q, kb, vb, m, l, acc, q_pos0, k_pos0, n_valid = res
     dm, dl, dacc = g
     dq, dkb, dvb, dm_in, dl_in, dacc_in = _fold_bwd_pallas(
         q, kb, vb, m, l, acc, q_pos0, k_pos0, causal,
         n_valid if has_n_valid else None, scale, dm, dl, dacc,
-        interpret=interpret,
+        interpret=interpret, window=window,
     )
     # integer position/count args carry no cotangent
     return (dq.astype(q.dtype), dkb.astype(kb.dtype), dvb.astype(vb.dtype),
@@ -514,20 +582,14 @@ _TQ_DKV = 2048  # Q rows per dkv accumulation step (third grid dim)
 
 
 def reference_fold_bwd(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
-                       scale, dm, dl, dacc):
+                       scale, dm, dl, dacc, window=None):
     """Hand-derived VJP of ``reference_fold`` — AD-equivalent (max ties split
     0.5/0.5 like ``jnp.maximum``; reduce-max ties spread evenly). The jnp
     source of truth the Pallas backward kernels are tested against."""
     Tq, Tk = q.shape[2], kb.shape[2]
     s = jnp.einsum("bhqd,bhkd->bhqk", q, kb) * scale
     if causal or n_valid is not None:
-        q_pos = q_pos0 + jnp.arange(Tq)
-        k_pos = k_pos0 + jnp.arange(Tk)
-        keep = jnp.ones((Tq, Tk), bool)
-        if causal:
-            keep &= q_pos[:, None] >= k_pos[None, :]
-        if n_valid is not None:
-            keep &= (k_pos < jnp.asarray(n_valid))[None, :]
+        keep = _kept(Tq, Tk, q_pos0, k_pos0, causal, n_valid, window)
         s = jnp.where(keep[None, None], s, -jnp.inf)
     B = jnp.max(s, axis=-1)
     new_m = jnp.maximum(m, B)
@@ -557,7 +619,7 @@ def reference_fold_bwd(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
 
 
 def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
-                     scale, dm, dl, dacc, interpret=False):
+                     scale, dm, dl, dacc, interpret=False, window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -582,7 +644,7 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
         ) * scale  # [TQ, Tk]
         if causal or masked:
             s = _mask_chunk(s, scalars_ref[0] + pl.program_id(1) * tq_bwd, scalars_ref[1], causal,
-                            scalars_ref[2] if masked else None)
+                            scalars_ref[2] if masked else None, window)
         mcol = m_ref[0]  # [TQ, 1]
         Bcol = jnp.max(s, axis=1, keepdims=True)
         new_m = jnp.maximum(mcol, Bcol)
@@ -631,7 +693,11 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
         q_first = scalars_ref[0] + pl.program_id(1) * tq_bwd
         nv = scalars_ref[2] if masked else None
         n_full, n_vis = _visible_chunks(q_first, tq_bwd, scalars_ref[1], kc, n_chunks, nv)
-        mx = _park_scores(q_ref[0], k_ref, s_scr, q_first, scalars_ref[1], nv, scale, kc, n_full, n_vis)
+        n_lo = lo_end = 0
+        if window is not None:
+            n_lo, lo_end, n_full = _window_chunks(q_first, tq_bwd, scalars_ref[1], kc, n_chunks, window, n_full, n_vis)
+        mx = _park_scores(q_ref[0], k_ref, s_scr, q_first, scalars_ref[1], nv, scale, kc, n_full, n_vis,
+                          window, n_lo, lo_end)
         mcol = m_ref[0]  # [TQ, 1]
         Bcol = jnp.max(mx, axis=1, keepdims=True)
         new_m = jnp.maximum(mcol, Bcol)
@@ -657,7 +723,7 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
                     cnt + _fold_lanes((s == hit).astype(jnp.float32), jnp.add))
 
         zeros = jnp.zeros((tq_bwd, _LANES), jnp.float32)
-        sum_dpp, cnt = jax.lax.fori_loop(0, n_vis, products, (zeros, zeros))
+        sum_dpp, cnt = jax.lax.fori_loop(n_lo, n_vis, products, (zeros, zeros))
         dcorr = dlc * l_ref[0] + jnp.sum(
             dacc_ref[0] * acc_ref[0], axis=1, keepdims=True
         )
@@ -677,7 +743,7 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
             )
             return carry
 
-        jax.lax.fori_loop(0, n_vis, dq_of, 0)
+        jax.lax.fori_loop(n_lo, n_vis, dq_of, 0)
         dqo_ref[0] *= scale
         dmo_ref[0] = jnp.where(jnp.isneginf(mcol), 0.0, dcorr * corr) + dnew_m * take_m
         dlo_ref[0] = dlc * corr
@@ -708,7 +774,7 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
                 preferred_element_type=jnp.float32,
             ) * scale  # [TQ_DKV, TK]
             if mask:
-                s_col = _mask_chunk(s_col, q_first, k_first, causal, nv)
+                s_col = _mask_chunk(s_col, q_first, k_first, causal, nv, window)
             P_col = jnp.exp(s_col - safe_ref[0])
             is_max = s_col == b_ref[0]
             if mask or not causal:  # a full pair's scores are finite; the whole-block kernel stays as it was
@@ -738,8 +804,14 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
             # of this q tile's key tiles [0, n_full) need no mask, [n_full,
             # n_vis) are crossed by it; a hidden pair adds nothing
             n_full, n_vis = _visible_chunks(q_first, tq_dkv, scalars_ref[1], tk_bwd, n_k_dkv, nv)
-            pl.when(jk < n_full)(lambda: accumulate(False))
-            pl.when((jk >= n_full) & (jk < n_vis))(lambda: accumulate(True))
+            if window is None:
+                pl.when(jk < n_full)(lambda: accumulate(False))
+                pl.when((jk >= n_full) & (jk < n_vis))(lambda: accumulate(True))
+            else:  # below the window nothing; the pairs either edge crosses masked, by both
+                n_lo, lo_end, n_full = _window_chunks(q_first, tq_dkv, scalars_ref[1], tk_bwd, n_k_dkv, window,
+                                                      n_full, n_vis)
+                pl.when((jk >= lo_end) & (jk < n_full))(lambda: accumulate(False))
+                pl.when(((jk >= n_lo) & (jk < lo_end)) | ((jk >= n_full) & (jk < n_vis)))(lambda: accumulate(True))
         else:
             accumulate(masked)
 
@@ -794,7 +866,7 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
         ],
         interpret=interpret,
         compiler_params=_compiler_params(),
-        name="flash_fold_bwd_dq",
+        name=_kernel_name("bwd_dq", window),
     )(
         scalars, q4, k4, v4,
         m.reshape(BH, Tq, 1), l.reshape(BH, Tq, 1), acc.reshape(BH, Tq, D),
@@ -813,6 +885,11 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
             # name that first tile's block, so nothing is fetched for them
             first_seen = _chunks_upto(scalars_ref[1] + jk * tk_bwd - scalars_ref[0], tq_dkv, n_q_dkv - 1)
             jq = jnp.maximum(jq, first_seen)
+            if window is not None:
+                # and those past the last that sees it (its last key's last query) name that last tile's
+                last_seen = _chunks_upto(scalars_ref[1] + (jk + 1) * tk_bwd + window - 2 - scalars_ref[0],
+                                         tq_dkv, n_q_dkv - 1)
+                jq = jnp.minimum(jq, last_seen)
         return (head, jq, 0)
 
     qmat = pl.BlockSpec((1, tq_dkv, D), q_block, memory_space=pltpu.VMEM)
@@ -828,7 +905,7 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
         out_shape=[sds((BHkv, Tk, D)), sds((BHkv, Tk, D))],
         interpret=interpret,
         compiler_params=_compiler_params(),
-        name="flash_fold_bwd_dkv",
+        name=_kernel_name("bwd_dkv", window),
     )(scalars, k4, v4, q4, dacc4, dl4, safe_r, b_r, dbc_r)
 
     return (

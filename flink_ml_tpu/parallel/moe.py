@@ -36,6 +36,15 @@ The router is handed in: a matrix ``[d, E]`` (OLMoE's) or a function from the
 tokens to their logits (ZAYA's MLP, closed over the state the block before
 handed it); top-1 is ``k = 1``, the winner's probability the gate.
 
+Sigmoid gates (``routed_scale`` > 0, ``route_sigmoid_top_k``: the family whose
+router scores each expert by itself): ``s = sigmoid(logits)`` over all experts, the ``k`` largest
+of ``s + select_bias`` chosen (the bias, a balancing rule's handle outside the
+gradient, enters the choice and nothing else), and the chosen scores
+renormalised to sum to one and scaled: ``w = routed_scale * s_sel /
+sum(s_sel)``. A shared expert that every token passes beside the routed ones
+is no part of this layer's routing: ``dense_swiglu`` computes it, and the
+caller adds it once (each chip of an expert-parallel group computes it alike).
+
 The held range, what expert parallelism asks of this layer anyway: told which
 experts it holds (``w_gate``/``w_up``/``w_down`` carry ``H`` experts,
 ``first_held .. first_held + H`` of the router's ``E``), it routes over all
@@ -58,9 +67,15 @@ import functools
 import jax
 import jax.numpy as jnp
 
-__all__ = ["moe_dropless", "route_top_k"]
+__all__ = ["moe_dropless", "route_top_k", "route_sigmoid_top_k", "dense_swiglu"]
 
 _HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _router_logits(x, router):
+    if callable(router):
+        return router(x)
+    return jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32), precision=_HIGHEST)
 
 
 def route_top_k(x, router, k: int):
@@ -69,13 +84,36 @@ def route_top_k(x, router, k: int):
     go to the lower expert id). ``router`` is a matrix ``[d, E]`` (logits ``x @
     router``) or a function ``x -> logits [t, E]`` in float32."""
     with jax.named_scope("route"):
-        if callable(router):
-            logits = router(x)
-        else:
-            logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32), precision=_HIGHEST)
-        p = jax.nn.softmax(logits, axis=-1)
+        p = jax.nn.softmax(_router_logits(x, router), axis=-1)
         top_p, top_e = jax.lax.top_k(p, k)
         return p, top_p, top_e
+
+
+def route_sigmoid_top_k(x, router, k: int, routed_scale: float, select_bias=None):
+    """Float32 routing of ``x [t, d]`` by sigmoid gates: the scores ``p =
+    sigmoid(logits) [t, E]``, the ``k`` largest of ``p + select_bias`` in each
+    row chosen (ties go to the lower expert id) and ``(top_p, top_e) [t, k]``
+    with ``top_p`` the chosen scores renormalised to sum to one, times
+    ``routed_scale``. ``router`` as for ``route_top_k``."""
+    with jax.named_scope("route"):
+        p = jax.nn.sigmoid(_router_logits(x, router))
+        _, top_e = jax.lax.top_k(p if select_bias is None else p + select_bias, k)
+        chosen = jnp.take_along_axis(p, top_e, axis=1)
+        return p, routed_scale * chosen / jnp.sum(chosen, axis=1, keepdims=True), top_e
+
+
+def dense_swiglu(x, w_gate, w_up, w_down, compute_dtype=jnp.float32):
+    """``down(silu(gate(x)) * up(x))`` of one SwiGLU ``[d, h]``, ``[d, h]``,
+    ``[h, d]`` on every row of ``x [..., d]``: matmul inputs in the compute
+    type, f32 accumulation and result. A dense layer's feed-forward, or the
+    expert every token passes beside the routed ones."""
+    cd = jnp.dtype(compute_dtype)
+    precision = _HIGHEST if cd == jnp.float32 else None
+
+    def dot(a, w):
+        return jnp.dot(a.astype(cd), w.astype(cd), preferred_element_type=jnp.float32, precision=precision)
+
+    return dot(jax.nn.silu(dot(x, w_gate)) * dot(x, w_up), w_down)
 
 
 @jax.custom_vjp
@@ -187,7 +225,7 @@ _expert_swiglu.defvjp(_expert_swiglu_fwd, _expert_swiglu_bwd)
 
 
 def moe_dropless(x, router, w_gate, w_up, w_down, k: int, compute_dtype=jnp.float32,
-                 first_held: int = 0):
+                 first_held: int = 0, routed_scale: float = 0.0, select_bias=None):
     """Top-``k`` SwiGLU experts for tokens ``x [t, d]``.
 
     ``router`` is a matrix ``[d, E]`` or a function ``x -> logits [t, E]``
@@ -195,13 +233,18 @@ def moe_dropless(x, router, w_gate, w_up, w_down, k: int, compute_dtype=jnp.floa
     the ``H`` experts held here, experts ``first_held .. first_held + H`` of
     the ``E`` the router chooses among. ``compute_dtype`` is the grouped
     matmuls' input type (accumulation is f32); the router is f32 regardless.
+    ``routed_scale`` > 0 asks for sigmoid gates, chosen with ``select_bias``,
+    renormalised and scaled (``route_sigmoid_top_k``).
     Returns ``(y [t, d] f32, stats)`` with ``stats = {"f": [E], "P": [E],
     "rows": [E] int32}`` as the module docstring defines them: ``y`` is the
     part of the layer's result that the held experts give.
     """
     t, _ = x.shape
     n_held = w_gate.shape[0]
-    p, top_p, top_e = route_top_k(x, router, k)
+    if routed_scale:
+        p, top_p, top_e = route_sigmoid_top_k(x, router, k, routed_scale, select_bias)
+    else:
+        p, top_p, top_e = route_top_k(x, router, k)
     n_experts = p.shape[1]
     covered = n_held == n_experts
     if not 0 <= first_held <= n_experts - n_held:

@@ -41,7 +41,7 @@ def _compile(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile()
 
 
-def _fold_grads(b, h, t):
+def _fold_grads(b, h, t, window=None):
     from flink_ml_tpu.parallel.flash import fused_fold
 
     def grads(q, k, v):
@@ -50,7 +50,7 @@ def _fold_grads(b, h, t):
             l0 = jnp.zeros((b, h, t), jnp.float32)
             acc0 = jnp.zeros((b, h, t, D), jnp.float32)
             zero = jnp.int32(0)
-            _, l, acc = fused_fold(q, k, v, m0, l0, acc0, zero, zero, True, False, zero, D ** -0.5)
+            _, l, acc = fused_fold(q, k, v, m0, l0, acc0, zero, zero, True, False, zero, D ** -0.5, False, window)
             return jnp.sum(acc / l[..., None])
 
         return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
@@ -58,9 +58,11 @@ def _fold_grads(b, h, t):
     return grads
 
 
-#: ``(batch, query heads, key/value heads, T)``: four packed sequences of
-#: OLMoE-1B-7B; two of ZAYA1-8B, 8 query heads on 2 key/value heads at 8,192.
-FOLDS = {"olmoe_4x16x4096": (B, H, H, T), "zaya_2x8on2x8192": (2, 8, 2, 8192)}
+#: ``(batch, query heads, key/value heads, T, window)``: four packed sequences of
+#: OLMoE-1B-7B; two of ZAYA1-8B, 8 query heads on 2 key/value heads at 8,192; two
+#: of Laguna-XS.2's windowed layers, 64 query heads on 8 at 4,096 through 512 keys.
+FOLDS = {"olmoe_4x16x4096": (B, H, H, T, None), "zaya_2x8on2x8192": (2, 8, 2, 8192, None),
+         "laguna_2x64on8x4096_w512": (2, 64, 8, 4096, 512)}
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16", "float32"])
@@ -70,13 +72,15 @@ def test_fused_fold_trains_at_the_cells_shapes(one_chip, dtype, fold):
     ``flash_train_available``'s 9 MB envelope refused and the kernels' stated
     VMEM limit admits, and the grouped-query fold at ``H_kv`` 2, T 8,192, whose
     K and V enter at their own ``[B, H_kv, T, D]`` (no repeated copy)."""
-    b, h, h_kv, t = FOLDS[fold]
+    b, h, h_kv, t, window = FOLDS[fold]
     q = jax.ShapeDtypeStruct((b, h, t, D), dtype, sharding=one_chip)
     kv = jax.ShapeDtypeStruct((b, h_kv, t, D), dtype, sharding=one_chip)
-    compiled = _compile(_fold_grads(b, h, t), q, kv, kv)
+    compiled = _compile(_fold_grads(b, h, t, window), q, kv, kv)
     text = compiled.as_text()
-    for kernel in ("flash_fold_fwd", "flash_fold_bwd_dq", "flash_fold_bwd_dkv"):
-        assert kernel in text
+    named = "flash_fold_win_" if window else "flash_fold_"  # a windowed fold's kernels have names of their own
+    for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
+        assert named + kernel in text
+    assert window is None or "flash_fold_fwd" not in text
     assert f"f32[{b},{h},{t},{t}]" not in text and f"f32[{b * h},{t},{t}]" not in text  # no score tensor
     # dk and dv come out per key/value head: the group's query heads are summed in the kernel
     _, dk, dv = jax.eval_shape(_fold_grads(b, h, t), q, kv, kv)
@@ -212,6 +216,56 @@ def test_the_zaya_step_program_at_the_cells_shapes(one_chip):
     assert f"f32[{batch},{cfg.n_heads},{t},{t}]" not in text and f"f32[{batch * cfg.n_heads},{t},{t}]" not in text
 
 
+def _laguna_cut():
+    """``(the benchmark's laguna_xs2 configuration, its LMConfig)``."""
+    from perfbench.systems import laguna_lm_fit
+
+    c = _cell_config("laguna_xs2")
+    return c, laguna_lm_fit.lm_config(c)
+
+
+def test_the_laguna_step_program_at_the_cells_shapes(one_chip):
+    """The whole jitted step of the ``laguna_xs2`` configuration at 2 x 4,096
+    tokens: five rematerialised layers of three kinds (full attention on 48
+    heads with the dense SwiGLU; windowed on 64 with the experts; full on 48
+    with the experts). It fits the chip (XLA's analysis); the windowed layers'
+    three kernels are there under their own names beside the full layers';
+    K and V enter once per key/value head; the 32 held experts' grouped
+    matmuls are the grouped kernel in both directions."""
+    from flink_ml_tpu.models.lm import decoder_lm
+    from flink_ml_tpu.models.lm.config import num_params
+
+    c, cfg = _laguna_cut()
+    assert num_params(cfg) == 691_624_960  # 11.07 GB of f32 state at 16 bytes a parameter: 69% of 16 GB
+    batch, t = c["global_batch_size"], c["sequence_length"]
+    optimizer, step = decoder_lm._train_program(cfg, c["compute_dtype"], c["learning_rate"], batch, False)
+    params = jax.eval_shape(lambda: decoder_lm._init_program(cfg)(jax.random.key(0)))
+    state = jax.eval_shape(optimizer.init, params)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+    compiled = step.lower(
+        on_chip(params), on_chip(state),
+        jax.ShapeDtypeStruct((c["num_sequences"], t), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+    ).compile()
+    memory = compiled.memory_analysis()
+    live = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert 12 * num_params(cfg) <= memory.argument_size_in_bytes and live < 15.75e9, live
+    text = compiled.as_text()
+    for kernel in ("flash_fold_fwd", "flash_fold_bwd_dq", "flash_fold_bwd_dkv",
+                   "flash_fold_win_fwd", "flash_fold_win_bwd_dq", "flash_fold_win_bwd_dkv"):
+        assert kernel in text
+    kernels = [ln for ln in text.splitlines() if ln.lstrip().startswith("%ragged-dot") and "custom-call(" in ln]
+    assert len(kernels) >= 8 * (cfg.n_layers - cfg.n_dense)
+    assert "convolution_select_fusion" not in text
+    assert f"bf16[{batch * cfg.kv_heads},{t},{cfg.head_dim}]" in text  # K and V once per key/value head
+    for heads in set(cfg.layer_heads):
+        assert f"f32[{batch},{heads},{t},{t}]" not in text and f"f32[{batch * heads},{t},{t}]" not in text
+
+
 def test_the_ouro_step_program_at_the_cells_shapes(one_chip):
     """The whole jitted step of the ``ouro_2_6b`` configuration at 2 x 4,096
     tokens: six rematerialised dense blocks inside one scanned pass run four
@@ -253,7 +307,7 @@ def test_the_ouro_step_program_at_the_cells_shapes(one_chip):
     assert f"f32[{batch},{cfg.n_heads},{t},{t}]" not in text and f"f32[{batch * cfg.n_heads},{t},{t}]" not in text
 
 
-@pytest.mark.parametrize("cut", [_zaya_cut, _ouro_cut], ids=["zaya", "ouro"])
+@pytest.mark.parametrize("cut", [_zaya_cut, _ouro_cut, _laguna_cut], ids=["zaya", "ouro", "laguna"])
 def test_the_state_program_at_the_cells_shapes(one_chip, cut):
     """AdamW's state as ``DecoderLM._fit`` makes it, ``optimizer.init`` jitted,
     at the cells' parameter trees: one program whose outputs are the whole

@@ -29,6 +29,13 @@ KINDS = {
              {"lm.block/conv", "lm.block/route", "lm.block/route/norm", "lm.block/permute", "lm.block/experts"}),
     "ouro": (LMConfig(n_layers=2, hidden=128, n_heads=4, n_experts=0, top_k=0, expert_width=64, vocab=512,
                       aux_coef=0.0, block="ouro", loops=3, exit_beta=0.1), True, {"lm.block/ffn", "lm.exit"}),
+    "laguna": (LMConfig(n_layers=3, hidden=64, n_heads=4, n_experts=8, top_k=2, expert_width=32, vocab=512,
+                        rope_theta=5e5, norm_eps=1e-6, aux_coef=0.0, block="laguna", experts_held=4, first_held=2,
+                        n_kv_heads=2, head_size=16, rope_fraction=0.5, layer_heads=(4, 8, 4),
+                        layer_windows=(0, 96, 0), n_dense=1, dense_width=96, shared_width=32, routed_scale=2.5,
+                        yarn=(4.0, 64.0, 8.0, 1.0, 1.1386)), True,
+               {"lm.block/ffn", "lm.block/route", "lm.block/permute", "lm.block/experts", "lm.block/gate",
+                "lm.block/shared"}),
 }
 EVERY_KIND = {"lm.embed", "lm.block/norm", "lm.block/proj", "lm.block/rope", "lm.block/fold", "lm.block/mix",
               "lm.final_norm/norm", "lm.head", "lm.opt"}
@@ -99,6 +106,9 @@ def test_the_head_runs_in_three_directions_and_a_block_is_recomputed_where_it_is
     assert ("lm.block/fold", BWD) in found
     if "lm.block/experts" in KINDS[kind][2]:
         assert {("lm.block/experts", BWD), ("lm.block/permute", BWD)} <= found
+    if kind == "laguna":  # the windowed layer's kernels under names of their own, beside the full layers'
+        names = {n.split("/fold/")[1].split("/")[0] for _, n in step if n and "/fold/flash_fold" in n}
+        assert names == {f"flash_fold_{w}{k}" for w in ("", "win_") for k in ("fwd", "bwd_dq", "bwd_dkv")}
     # nothing of the loss is outside the gradient, nothing of the update inside it
     assert {d for scope, d in found if scope == "lm.opt"} == {FWD}
 
@@ -115,7 +125,7 @@ def test_the_update_is_outside_the_gradient(programs, kind):
 #: A looped stack's ``lax.scan`` stacks each pass's outputs and sums the shared
 #: weights' cotangents over the passes (``dynamic_update_slice``, ``add_any``)
 #: in code that is JAX's own, under no scope of the program.
-COVERED = {"olmoe": 0.95, "olmoe_stacked": 0.95, "zaya": 0.95, "ouro": 0.90}
+COVERED = {"olmoe": 0.95, "olmoe_stacked": 0.95, "zaya": 0.95, "ouro": 0.90, "laguna": 0.95}
 
 
 @pytest.mark.parametrize("kind", sorted(KINDS))
