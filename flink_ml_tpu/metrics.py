@@ -64,6 +64,9 @@ class MLMetrics:
     TRAIN_LM_LOOP_LAYER_APPLICATIONS = "ml.train.lm.loop.layer_applications"  # block applications (layers x passes x steps), counter
     TRAIN_MOE_ROWS = "ml.train.moe.rows"  # (token, expert) rows the experts held here ran, counter
     TRAIN_MOE_ROWS_ABSENT = "ml.train.moe.rows_absent"  # rows routed to experts held elsewhere, counter
+    TRAIN_MOE_LAYER_STEPS = "ml.train.moe.layer_steps"  # expert layers x steps of fits that take the routed rows in windows, counter
+    TRAIN_MOE_LAYER_STEPS_COMPACT = "ml.train.moe.layer_steps_compact"  # those that carried fewer rows than were routed, counter
+    TRAIN_MOE_ROWS_CARRIED = "ml.train.moe.rows_carried"  # rows those layer-steps' windows took through the experts, counter
 
     # Online-serving runtime (scope = "ml.serving[<server name>]" — see
     # docs/serving.md for the full table).

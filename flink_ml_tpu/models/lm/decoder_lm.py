@@ -179,7 +179,10 @@ class _LMParams(
     EXPERTS_HELD = IntParam(
         "expertsHeld",
         "Experts of each block held here, a contiguous range of numExperts; the router still "
-        "chooses among all and tokens routed elsewhere get nothing from this block. 0 holds all.",
+        "chooses among all and tokens routed elsewhere get nothing from this block. 0 holds all. "
+        "Under a half of them held, the rows routed here pass the experts a window of sorted rows at a "
+        "time (twice the held experts' share under uniform routing) for as many windows as hold one: "
+        "none is dropped, and train.drain counts what was carried.",
         0, ParamValidators.gt_eq(0),
     )
     FIRST_EXPERT_HELD = IntParam("firstExpertHeld", "First expert of the held range.", 0,
@@ -798,7 +801,8 @@ def _exit_loss(passes, gate, lm_head, tok, cfg: LMConfig, cd):
 
 def _loss(params, tok, cfg: LMConfig, cd, interpret: bool):
     """``(loss, stats)``: ``stats`` holds what the blocks have to report - the
-    rows each expert took (``rows``), the exits' sums (``_exit_loss``)."""
+    rows each expert took (``rows``), the rows each expert layer carried
+    (``carried``), the exits' sums (``_exit_loss``)."""
     h, routed, gate = _hidden(params, tok, cfg, cd, interpret)
     if gate is None:
         nll = _next_token_nll(h, _head(params, cfg), tok, cd)
@@ -811,6 +815,8 @@ def _loss(params, tok, cfg: LMConfig, cd, interpret: bool):
             loss = loss + cfg.aux_coef * _load_balancing(routed, cfg)
     if routed:
         stats["rows"] = jnp.stack([s["rows"] for s in routed])
+        if "carried" in routed[0]:  # the experts take their rows a window at a time (parallel/moe.py)
+            stats["carried"] = jnp.stack([s["carried"] for s in routed])
     return loss, stats
 
 
@@ -1029,6 +1035,16 @@ class DecoderLM(Estimator, _LMParams):
                     held_rows_max=int(held.max()),
                     held_rows_mean=float(held.mean()),
                 )
+            carried = stats.get("carried")
+            if carried is not None:  # [steps, layers with experts]: the rows each layer-step's windows took
+                routed = batch * t * cfg.top_k
+                layer_steps_compact, rows_carried = int((carried < routed).sum()), int(carried.sum())
+                phase.set_metadata(
+                    moe_layer_steps=carried.size,
+                    moe_layer_steps_compact=layer_steps_compact,
+                    moe_rows_carried=rows_carried,
+                    moe_rows_routed=routed * carried.size,
+                )
             trips = np.asarray(stats.get("trip_nll", np.zeros((steps, 0))), np.float64)
             if trips.size:  # [steps, passes]
                 phase.set_metadata(
@@ -1056,6 +1072,10 @@ class DecoderLM(Estimator, _LMParams):
         if loads.size:
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_MOE_ROWS, rows_held)
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_MOE_ROWS_ABSENT, rows_absent)
+        if carried is not None:
+            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_MOE_LAYER_STEPS, carried.size)
+            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_MOE_LAYER_STEPS_COMPACT, layer_steps_compact)
+            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_MOE_ROWS_CARRIED, rows_carried)
 
         model = DecoderLMModel()
         update_existing_params(model, self)

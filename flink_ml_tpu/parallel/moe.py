@@ -59,6 +59,26 @@ parts that all the shares give add up to the uncut layer
 brings the other chips' rows here and takes these rows' results back) is not
 here: on one chip the layer runs without it, and nothing stands in for the
 absent chips (ROADMAP, Reach queue).
+
+The held range in windows. The sort puts every held row before every absent
+one, so a layer that holds a strict part of the router's experts need not
+carry the absent rows through its row passes. Where it holds under a half of
+them (``_window_rows``: twice the held experts' share under uniform routing,
+``2 x t x k x H / E`` in whole 512-row tiles, is then smaller than ``t x
+k``), it takes the sorted rows through the experts ``c`` at a time
+(``_in_windows``): a window is ONE gather of ``c`` rows straight from the
+tokens (no ``repeat``), the grouped matmuls over the part of each group that
+lies in the window, and a scatter-add of ``c`` weighted rows into the tokens;
+and it takes as many windows as hold a held row, ``ceil(held / c)``, a count
+the step reads on the device (``lax.fori_loop`` to a traced bound: nothing
+visits the host). Nothing is dropped whatever the router does: a step whose
+held experts are popular takes more windows, up to all ``t x k`` rows; one
+whose held experts nobody chose takes none. ``stats["carried"]`` is ``windows
+x c``. The hand-written backward walks the same windows (``_in_windows_bwd``
+says why the three ``dW`` matmuls run after the loop and not in it). Where
+the windows do not exist - every expert held (OLMoE), a half of them (ZAYA's
+8 of 16) - the function traces, operation for operation, what it traced
+before it knew of windows: the whole range at once, and no ``carried``. Which it is hangs on the shapes alone; no option chooses.
 """
 from __future__ import annotations
 
@@ -224,6 +244,133 @@ def _expert_swiglu_bwd(compute_dtype, covered, res, dy):
 _expert_swiglu.defvjp(_expert_swiglu_fwd, _expert_swiglu_bwd)
 
 
+#: The grouped kernel's row tile (XLA's TPU expansion of ``ragged_dot_general``
+#: at these widths: ``ragged_dot_tiling="512,512,512"`` in the step compiled
+#: for the chip); a window of sorted rows is a whole number of them.
+_ROW_TILE = 512
+
+
+def _window_rows(rows: int, n_held: int, n_experts: int) -> int:
+    """How many sorted rows a layer that holds ``n_held`` of the router's
+    ``n_experts`` takes through its experts at a time: twice the share that
+    uniform routing gives the held experts, in whole row tiles. 0 where that
+    is not under ``rows`` (every expert held; a half or more of them held):
+    the layer then takes all ``rows`` at once."""
+    c = -(-2 * rows * n_held // (n_experts * _ROW_TILE)) * _ROW_TILE
+    return c if c < rows else 0
+
+
+def _sorted_rows(top_p, order, held_rows, c):
+    """What every window reads: the flat weights with a zero past their end,
+    the sorted order filled up to whole windows with rows that point at it,
+    and the groups' ends in sorted order."""
+    rows = order.shape[0]
+    with jax.named_scope("route"):
+        return (jnp.concatenate([top_p.reshape(-1), jnp.zeros((1,), top_p.dtype)]),
+                jnp.concatenate([order, jnp.full((-rows % c,), rows, order.dtype)]),
+                jnp.cumsum(held_rows))
+
+
+def _window(x, weights, order, ends, lo, k, compute_dtype, c):
+    """Of the sorted rows ``lo .. lo + c``: the flat (token, slot) row each is,
+    its token, its weight, the part of every group that lies among them, and
+    the tokens' rows in the compute type (ONE gather of ``c`` rows straight
+    from the tokens). Rows past the groups are absent ones or padding (which
+    reads the last token): weight 0, and every grouped result guarded."""
+    with jax.named_scope("permute"):
+        head = jax.lax.dynamic_slice_in_dim(order, lo, c)
+        token = jnp.minimum(head // k, x.shape[0] - 1)
+        xs = x[token].astype(compute_dtype)
+    with jax.named_scope("route"):
+        sizes = jnp.diff(jnp.clip(ends - lo, 0, c), prepend=0)
+        return head, token, weights[head], sizes, xs
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+def _in_windows(x, top_p, w_gate, w_up, w_down, order, held_rows, trips, k, compute_dtype, c):
+    """The held experts' part of the result, the sorted rows taken ``c`` at a
+    time through the first ``trips`` windows (a traced count: the windows
+    that hold a held row; the rest hold absent rows alone and give nothing).
+    A window is a gather of ``c`` rows from the tokens, the grouped matmuls
+    over them, and a scatter-add of ``c`` weighted rows into the tokens:
+    nothing in the loop, forward or backward, has ``t x k`` rows of width ``d``."""
+    weights, order, ends = _sorted_rows(top_p, order, held_rows, c)
+
+    def window(i, y):
+        _, token, w, sizes, xs = _window(x, weights, order, ends, i * c, k, compute_dtype, c)
+        with jax.named_scope("experts"):
+            ys = _expert_swiglu(xs, w_gate, w_up, w_down, sizes, compute_dtype, False)
+        with jax.named_scope("permute"):
+            return y.at[token].add(ys.astype(jnp.float32) * w[:, None])
+
+    return jax.lax.fori_loop(0, trips, window, jnp.zeros(x.shape, jnp.float32))
+
+
+def _in_windows_fwd(x, top_p, w_gate, w_up, w_down, order, held_rows, trips, k, compute_dtype, c):
+    y = _in_windows(x, top_p, w_gate, w_up, w_down, order, held_rows, trips, k, compute_dtype, c)
+    return y, (x, top_p, w_gate, w_up, w_down, order, held_rows, trips)
+
+
+def _in_windows_bwd(k, compute_dtype, c, res, dy):
+    """A loop of a traced length has no transpose, so the backward is written
+    out: it walks the forward's windows, recomputes each one's hidden
+    projections (what the block's ``jax.checkpoint`` did for the whole range),
+    scatter-adds the tokens' cotangents and parks what the three ``dW`` need,
+    in sorted order, in buffers the loop carries; the three ragged-contraction
+    matmuls then run ONCE over the parked rows, after the loop, each straight
+    into its gradient (a ``dW`` summed over the windows inside the loop kept a
+    second copy of every expert leaf's gradient alive). ``dy . down(hidden)``,
+    the weight's cotangent, is taken as ``hidden . (dy @ W_down^T)``: the
+    backward runs no ``down`` matmul."""
+    x, top_p, w_gate, w_up, w_down, order, held_rows, trips = res
+    cd, f32 = jnp.dtype(compute_dtype), jnp.float32
+    precision = _HIGHEST if cd == f32 else None
+    weights, order, ends = _sorted_rows(top_p, order, held_rows, c)
+    with jax.named_scope("experts"):
+        wg, wu, wd = (w.astype(cd) for w in (w_gate, w_up, w_down))
+        wg_t, wu_t, wd_t = (jnp.swapaxes(w, 1, 2) for w in (wg, wu, wd))  # as _expert_swiglu_bwd: _ROWS' form
+
+    def window(i, carry):
+        (dx, d_weights), parked = carry
+        lo = i * c
+        head, token, w, sizes, xs = _window(x, weights, order, ends, lo, k, compute_dtype, c)
+        guard = functools.partial(_in_a_group, group_sizes=sizes)
+        with jax.named_scope("permute"):
+            g = dy[token].astype(cd)
+        with jax.named_scope("experts"):
+            gate, up = _swiglu_hidden(xs, wg, wu, sizes, cd, precision)
+            gate, up = guard(gate), guard(up)
+            sig = jax.nn.sigmoid(gate)
+            act = gate * sig  # silu(gate)
+            hidden = act * up
+            through_down = guard(_grouped(g, wd_t, sizes, _ROWS, f32, precision))  # dy @ W_down^T, unweighted
+            d_hidden = through_down * w[:, None]
+            d_up = (d_hidden * act).astype(cd)
+            d_gate = (d_hidden * up * (sig + act * (1.0 - sig))).astype(cd)
+            d_xs = guard(_grouped(d_gate, wg_t, sizes, _ROWS, f32, precision)
+                         + _grouped(d_up, wu_t, sizes, _ROWS, f32, precision))
+            now = (xs, g, (hidden * w[:, None]).astype(cd), d_gate, d_up)
+            parked = tuple(jax.lax.dynamic_update_slice_in_dim(all_, a, lo, 0) for all_, a in zip(parked, now))
+        with jax.named_scope("permute"):
+            dx = dx.at[token].add(d_xs)
+            d_weights = d_weights.at[head].add(jnp.sum(hidden * through_down, axis=1))
+        return (dx, d_weights), parked
+
+    rows, d, h = order.shape[0], x.shape[1], w_gate.shape[2]
+    with jax.named_scope("experts"):
+        parked = tuple(jnp.zeros((rows, width), cd) for width in (d, d, h, h, h))
+    (dx, d_weights), (xs, g, weighted_hidden, d_gate, d_up) = jax.lax.fori_loop(
+        0, trips, window, ((jnp.zeros_like(x), jnp.zeros_like(weights)), parked))
+    with jax.named_scope("experts"):
+        d_w_gate = _grouped(xs, d_gate, held_rows, _DW, f32, precision)
+        d_w_up = _grouped(xs, d_up, held_rows, _DW, f32, precision)
+        d_w_down = _grouped(weighted_hidden, g, held_rows, _DW, f32, precision)
+    return dx, d_weights[:-1].reshape(top_p.shape), d_w_gate, d_w_up, d_w_down, None, None, None
+
+
+_in_windows.defvjp(_in_windows_fwd, _in_windows_bwd)
+
+
 def moe_dropless(x, router, w_gate, w_up, w_down, k: int, compute_dtype=jnp.float32,
                  first_held: int = 0, routed_scale: float = 0.0, select_bias=None):
     """Top-``k`` SwiGLU experts for tokens ``x [t, d]``.
@@ -237,7 +384,9 @@ def moe_dropless(x, router, w_gate, w_up, w_down, k: int, compute_dtype=jnp.floa
     renormalised and scaled (``route_sigmoid_top_k``).
     Returns ``(y [t, d] f32, stats)`` with ``stats = {"f": [E], "P": [E],
     "rows": [E] int32}`` as the module docstring defines them: ``y`` is the
-    part of the layer's result that the held experts give.
+    part of the layer's result that the held experts give. Where the rows go
+    through the experts a window at a time (``_window_rows``), ``stats`` also
+    holds ``carried``: the rows this call's windows took.
     """
     t, _ = x.shape
     n_held = w_gate.shape[0]
@@ -249,6 +398,7 @@ def moe_dropless(x, router, w_gate, w_up, w_down, k: int, compute_dtype=jnp.floa
     covered = n_held == n_experts
     if not 0 <= first_held <= n_experts - n_held:
         raise ValueError(f"experts {first_held}..{first_held + n_held} are not among the router's {n_experts}")
+    window = _window_rows(t * k, n_held, n_experts)
 
     with jax.named_scope("route"):
         # row r of the flat (token, slot) list belongs to token r // k
@@ -260,18 +410,25 @@ def moe_dropless(x, router, w_gate, w_up, w_down, k: int, compute_dtype=jnp.floa
             sort_key = jnp.where(here, local, n_held)
             top_p = jnp.where(here.reshape(top_p.shape), top_p, 0.0)
         order = jnp.argsort(sort_key, stable=True)  # sorted position -> flat row
-        inverse = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0], dtype=order.dtype))
+        if not window:
+            inverse = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0], dtype=order.dtype))
         rows = jnp.zeros((n_experts,), jnp.int32).at[flat_e].add(1)
         held_rows = rows if covered else jax.lax.dynamic_slice_in_dim(rows, first_held, n_held)
+        if window:  # the held rows sort first: the windows that hold one
+            trips = (jnp.sum(held_rows) + window - 1) // window
 
-    with jax.named_scope("permute"):
-        x_rows = jnp.repeat(x.astype(compute_dtype), k, axis=0)  # [t*k, d], token-major
-        xs = _take_rows(x_rows, order, inverse)
-    with jax.named_scope("experts"):
-        ys = _expert_swiglu(xs, w_gate, w_up, w_down, held_rows, jnp.dtype(compute_dtype).name, covered)
-    with jax.named_scope("permute"):
-        y_rows = _take_rows(ys, inverse, order).reshape(t, k, -1)
-        y = jnp.sum(y_rows.astype(jnp.float32) * top_p[:, :, None], axis=1)
+    if window:
+        y = _in_windows(x, top_p, w_gate, w_up, w_down, order, held_rows, trips, k,
+                        jnp.dtype(compute_dtype).name, window)
+    else:
+        with jax.named_scope("permute"):
+            x_rows = jnp.repeat(x.astype(compute_dtype), k, axis=0)  # [t*k, d], token-major
+            xs = _take_rows(x_rows, order, inverse)
+        with jax.named_scope("experts"):
+            ys = _expert_swiglu(xs, w_gate, w_up, w_down, held_rows, jnp.dtype(compute_dtype).name, covered)
+        with jax.named_scope("permute"):
+            y_rows = _take_rows(ys, inverse, order).reshape(t, k, -1)
+            y = jnp.sum(y_rows.astype(jnp.float32) * top_p[:, :, None], axis=1)
 
     with jax.named_scope("route"):
         stats = {
@@ -279,4 +436,6 @@ def moe_dropless(x, router, w_gate, w_up, w_down, k: int, compute_dtype=jnp.floa
             "P": jnp.mean(p, axis=0),
             "rows": rows,
         }
+        if window:
+            stats["carried"] = jnp.minimum(trips * window, t * k)
     return y, stats

@@ -4,13 +4,15 @@ leading dense layer, sigmoid-gated experts beside a shared one) against its
 plain reference (models/lm/reference_laguna.py) on seeded random weights at
 toy size: 4 layers (full + dense, windowed, windowed, full), hidden 64, 4 or 8
 query heads of 16 on 2 key/value heads, a window of 96 keys, YaRN on the full
-layers' 8 turned channels, a dense SwiGLU of width 96, 8 experts of width 32
-(top-2; experts 2..5 held) beside a shared one of width 32, an untied
-vocabulary of 512, T 256, batch 2, 2 steps. The same fit loop, head, loss
-chunking, clip and AdamW program as the other kinds, chosen by a stage
-parameter. And the windowed fold itself, forward and both backward kernels
-(interpreted), against ``reference_fold`` / ``reference_fold_bwd`` with the
-same window at lengths of several key chunks.
+layers' 8 turned channels, a dense SwiGLU of width 96, 16 experts of width 32
+(top-2; experts 2..3 held, an eighth as in the cell: the expert layers take
+their 1,024 routed rows through the experts in windows of 512,
+``parallel/moe.py``) beside a shared one of width 32, an untied vocabulary of
+512, T 256, batch 2, 2 steps. The same fit loop, head, loss chunking, clip and
+AdamW program as the other kinds, chosen by a stage parameter. And the
+windowed fold itself, forward and both backward kernels (interpreted), against
+``reference_fold`` / ``reference_fold_bwd`` with the same window at lengths of
+several key chunks.
 
 Tolerances. float32: stage and reference compute the same mathematics in
 different orders, so they differ by float32 rounding; read here the loss by
@@ -35,8 +37,8 @@ from flink_ml_tpu.parallel import flash
 from flink_ml_tpu.utils.read_write import load_stage
 
 YARN = (4.0, 64.0, 8.0, 1.0, 1.1386)
-CFG = LMConfig(n_layers=4, hidden=64, n_heads=4, n_experts=8, top_k=2, expert_width=32, vocab=512,
-               rope_theta=5e5, norm_eps=1e-6, aux_coef=0.0, block="laguna", experts_held=4, first_held=2,
+CFG = LMConfig(n_layers=4, hidden=64, n_heads=4, n_experts=16, top_k=2, expert_width=32, vocab=512,
+               rope_theta=5e5, norm_eps=1e-6, aux_coef=0.0, block="laguna", experts_held=2, first_held=2,
                n_kv_heads=2, head_size=16, rope_fraction=0.5, layer_heads=(4, 8, 8, 4),
                layer_windows=(0, 96, 96, 0), n_dense=1, dense_width=96, shared_width=32, routed_scale=2.5,
                window_rope_theta=1e4, yarn=YARN)
@@ -188,6 +190,8 @@ def test_every_parameters_gradient(params, tokens, compute_type, leaf_tol, norm_
         params, tok, CFG, jnp.dtype(compute_type), True)
     assert _rel(loss, want_loss) < (1e-5 if leaf_tol else 2e-3)
     assert stats["rows"].shape == (CFG.n_layers - CFG.n_dense, CFG.n_experts)
+    # every expert layer took one window of its sorted rows through the experts, not all 1,024 of them
+    assert stats["carried"].tolist() == [512] * (CFG.n_layers - CFG.n_dense)
     for name, g, w in zip(_flat_names(CFG), _ordered(got, CFG), _ordered(want, CFG)):
         if name.endswith("router_bias"):
             assert float(jnp.max(jnp.abs(g))) == 0.0 == float(jnp.max(jnp.abs(w))), name
@@ -227,9 +231,9 @@ def test_fits_scores_saves_and_loads(fitted, df, tokens, tmp_path):
     np.testing.assert_array_equal(np.asarray(other.transform(df).scalars("prediction")), got)
 
 
-def test_the_fit_counts_its_windowed_layers_and_its_held_rows(fitted):
+def test_the_fit_counts_its_windowed_layers_and_its_held_rows(fitted, df):
     """``train.program``'s counts beside the fold's chunks, ``train.drain``'s
-    held and absent rows over the three expert layers, and the two counters."""
+    held and absent rows and what the three expert layers carried, and the counters."""
     est, _, spans = fitted
     program, drain = spans["train.program"], spans["train.drain"]
     full = np.asarray(flash.fold_chunk_counts(T, T, 0, True))
@@ -242,10 +246,16 @@ def test_the_fit_counts_its_windowed_layers_and_its_held_rows(fitted):
     assert drain["dropped"] == 0 and drain["rows_held"] == int(held.sum())
     assert drain["rows_held"] + drain["rows_absent"] == STEPS * BATCH * T * CFG.top_k * layers
     assert drain["held_rows_max"] == int(held.max()) and drain["held_rows_mean"] == pytest.approx(held.mean())
-    before = metrics.get(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_WIN_CHUNKS_VISITED)
-    _estimator().set_max_iter(1).fit(DataFrame.from_dict({"features": np.zeros((BATCH, T), np.int64)}))
-    assert metrics.get(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_WIN_CHUNKS_VISITED) - before == \
-        program["fold_win_chunks_visited"]
+    # what the expert layers carried: a window of 512 sorted rows where 1,024 were routed, every layer-step
+    assert drain["moe_layer_steps"] == STEPS * layers == drain["moe_layer_steps_compact"]
+    assert drain["moe_rows_routed"] == drain["rows_held"] + drain["rows_absent"]
+    assert drain["rows_held"] <= drain["moe_rows_carried"] == 512 * STEPS * layers
+    counters = (MLMetrics.TRAIN_LM_FOLD_WIN_CHUNKS_VISITED, MLMetrics.TRAIN_MOE_LAYER_STEPS,
+                MLMetrics.TRAIN_MOE_LAYER_STEPS_COMPACT, MLMetrics.TRAIN_MOE_ROWS_CARRIED)
+    before = [metrics.get(MLMetrics.TRAIN_GROUP, name) for name in counters]
+    _estimator().set_max_iter(1).fit(df)
+    assert [metrics.get(MLMetrics.TRAIN_GROUP, name) - was for name, was in zip(counters, before)] == \
+        [program["fold_win_chunks_visited"], layers, layers, 512 * layers]
 
 
 def test_yarn_tables_against_the_formula_in_numpy():
@@ -395,6 +405,7 @@ def test_the_eight_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer()
         held = dict(w, **{name: w[name][first: first + 2] for name in ("w_gate", "w_up", "w_down")})
         out, _, stats = decoder_lm._laguna_block(x, None, held, share, F32, True, window=96)
         assert int(stats["rows"].sum()) == BATCH * T * CFG.top_k  # routed = held + absent, whatever is held
+        assert int(stats["rows"][first: first + 2].sum()) <= int(stats["carried"]) == 512  # one window of the 1,024
         total = total + out
     assert float(jnp.max(jnp.abs(want - after - shared))) > 0.01  # the routed part is not nothing
     np.testing.assert_allclose(np.asarray(total - 7 * (after + shared)), np.asarray(want), rtol=2e-4, atol=2e-5)

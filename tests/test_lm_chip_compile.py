@@ -228,10 +228,14 @@ def test_the_laguna_step_program_at_the_cells_shapes(one_chip):
     """The whole jitted step of the ``laguna_xs2`` configuration at 2 x 4,096
     tokens: five rematerialised layers of three kinds (full attention on 48
     heads with the dense SwiGLU; windowed on 64 with the experts; full on 48
-    with the experts). It fits the chip (XLA's analysis); the windowed layers'
-    three kernels are there under their own names beside the full layers';
-    K and V enter once per key/value head; the 32 held experts' grouped
-    matmuls are the grouped kernel in both directions."""
+    with the experts). It fits the chip (XLA's analysis) in no more than it
+    took when the expert layers carried all their 65,536 routed rows at once
+    (6.30 GB of temporaries: what is live in the attention backward, not in
+    the experts); the windowed layers' three kernels are there under their own
+    names beside the full layers'; K and V enter once per key/value head; the
+    32 held experts' grouped matmuls are the grouped kernel in both
+    directions, over a window of 16,384 sorted rows at a time: no float32
+    array has the 65,536 routed rows' count."""
     from flink_ml_tpu.models.lm import decoder_lm
     from flink_ml_tpu.models.lm.config import num_params
 
@@ -254,6 +258,7 @@ def test_the_laguna_step_program_at_the_cells_shapes(one_chip):
     memory = compiled.memory_analysis()
     live = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     assert 12 * num_params(cfg) <= memory.argument_size_in_bytes and live < 15.75e9, live
+    assert memory.temp_size_in_bytes < 6.31e9, memory.temp_size_in_bytes
     text = compiled.as_text()
     for kernel in ("flash_fold_fwd", "flash_fold_bwd_dq", "flash_fold_bwd_dkv",
                    "flash_fold_win_fwd", "flash_fold_win_bwd_dq", "flash_fold_win_bwd_dkv"):
@@ -261,6 +266,8 @@ def test_the_laguna_step_program_at_the_cells_shapes(one_chip):
     kernels = [ln for ln in text.splitlines() if ln.lstrip().startswith("%ragged-dot") and "custom-call(" in ln]
     assert len(kernels) >= 8 * (cfg.n_layers - cfg.n_dense)
     assert "convolution_select_fusion" not in text
+    routed = batch * t * cfg.top_k  # a window at a time; only what the dW matmuls read is parked at full length
+    assert f"f32[{routed},{cfg.hidden}]" not in text and f"bf16[{routed // 4},{cfg.hidden}]" in text
     assert f"bf16[{batch * cfg.kv_heads},{t},{cfg.head_dim}]" in text  # K and V once per key/value head
     for heads in set(cfg.layer_heads):
         assert f"f32[{batch},{heads},{t},{t}]" not in text and f"f32[{batch * heads},{t},{t}]" not in text

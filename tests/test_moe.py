@@ -101,3 +101,126 @@ def test_bfloat16_compute_keeps_the_router_exact():
     np.testing.assert_array_equal(np.asarray(stats32["rows"]), np.asarray(stats16["rows"]))
     err = float(jnp.max(jnp.abs(rough - exact)) / jnp.max(jnp.abs(exact)))
     assert 1e-4 < err < 3e-2
+
+
+# -- the held range in windows ---------------------------------------------------
+
+from flink_ml_tpu.parallel import moe  # noqa: E402
+
+#: ``(experts, held, k, compute type, the router's lean towards the held
+#: experts)``: an eighth held under top-2 and under top-8 (Laguna's 32 of 256
+#: in small) at one window and, the router leaning, at three; a sixteenth;
+#: three eighths, whose second window is part padding
+HELD_RANGES = [(16, 2, 2, "float32", 0.0), (16, 2, 2, "bfloat16", 0.0), (16, 2, 2, "float32", 6.0),
+               (16, 2, 2, "bfloat16", 6.0), (64, 8, 8, "float32", 0.0), (64, 8, 8, "bfloat16", 0.0),
+               (64, 8, 8, "float32", 3.0), (32, 2, 4, "float32", 4.0), (8, 3, 2, "float32", 0.0),
+               (8, 3, 2, "float32", 8.0), (8, 3, 2, "bfloat16", 8.0)]
+FIRST_HELD = 3
+
+
+def _held_setup(E, H, T=2048, d=16, h=24, seed=0):
+    x, router, w_gate, w_up, w_down = _setup(T=T, d=d, h=h, E=E, seed=seed)
+    return x, router, w_gate[:H], w_up[:H], w_down[:H]
+
+
+def _layer_and_cotangents(args, k, dtype, lean):
+    x, router, *experts = args
+    held = slice(FIRST_HELD, FIRST_HELD + experts[0].shape[0])
+    probe = jnp.asarray(np.random.default_rng(1).standard_normal(x.shape).astype(np.float32))
+
+    def scalar(x, router, *experts):
+        def logits(x):
+            return jnp.dot(x, router, precision="highest").at[:, held].add(lean)
+
+        y, stats = moe_dropless(x, logits, *experts, k, jnp.dtype(dtype), FIRST_HELD)
+        return jnp.sum(y * probe), (y, stats)
+
+    (_, (y, stats)), grads = jax.value_and_grad(scalar, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
+    return y, stats, grads
+
+
+@pytest.mark.parametrize("E,H,k,dtype,lean", HELD_RANGES)
+def test_the_windows_are_the_whole_range(E, H, k, dtype, lean, monkeypatch):
+    """The layer a window of sorted rows at a time against the layer through
+    all of them at once, same inputs: the result, the rows counted and every
+    cotangent (tokens, router, the three expert leaves)."""
+    args = _held_setup(E, H, seed=E + k)
+    rows = args[0].shape[0] * k
+    window = moe._window_rows(rows, H, E)
+    y, stats, got = _layer_and_cotangents(args, k, dtype, lean)
+    monkeypatch.setattr(moe, "_window_rows", lambda *_: 0)
+    want_y, want_stats, want = _layer_and_cotangents(args, k, dtype, lean)
+    assert "carried" not in want_stats
+    held = int(np.asarray(stats["rows"])[FIRST_HELD: FIRST_HELD + H].sum())
+    assert int(stats["carried"]) == min(-(-held // window) * window, rows)
+    assert (held > window) == bool(lean), "the lean is there to fill more than one window"
+    np.testing.assert_array_equal(np.asarray(stats["rows"]), np.asarray(want_stats["rows"]))
+    assert int(stats["rows"].sum()) == rows, "a row was dropped"
+    # float32: another order of the same sums. bfloat16: the same rounded rows through the same forward
+    # matmuls; backward, the weight is applied after the matmul through W_down^T where the whole range
+    # rounds the weighted cotangent before it, and a token's cotangent rows are summed in f32
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    scale = float(jnp.max(jnp.abs(want_y)))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want_y), rtol=0, atol=2e-6 * scale)
+    for name, g, w in zip(("x", "router", "w_gate", "w_up", "w_down"), got, want):
+        assert float(jnp.max(jnp.abs(w))) > 0, name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=0, atol=tol * float(jnp.max(jnp.abs(w))),
+                                   err_msg=name)
+
+
+def _forced_logits(T, E, held):
+    """Logits that put exactly ``held`` of the ``T x 2`` rows on the held
+    experts ``FIRST_HELD, FIRST_HELD + 1``: tokens choose both, then one of
+    them beside expert 0, then experts 0 and 1."""
+    both, one = divmod(held, 2)
+    logits = np.full((T, E), -10.0, np.float32)
+    logits[:both, FIRST_HELD], logits[:both, FIRST_HELD + 1] = 10.0, 9.0
+    logits[both: both + one, FIRST_HELD + 1], logits[both: both + one, 0] = 10.0, 9.0
+    logits[both + one:, 0], logits[both + one:, 1] = 10.0, 9.0
+    return jnp.asarray(logits)
+
+
+@pytest.mark.parametrize("held,windows", [(0, 0), (1, 1), (1024, 1), (1025, 2), (2048, 2), (2049, 3), (3073, 4),
+                                          (4096, 4)])
+def test_the_windows_follow_the_held_rows(held, windows, monkeypatch):
+    """A router forced to put exactly so many rows on held experts (a window,
+    a window and a row, two, two and a row, ... every row) takes so many
+    windows of 1,024 of its 4,096 sorted rows; nothing is dropped on any
+    count, and the result is the whole range's."""
+    E, H, k, T = 16, 2, 2, 2048
+    x, _, w_gate, w_up, w_down = _held_setup(E, H, T=T)
+    assert moe._window_rows(T * k, H, E) == 1024
+    logits = _forced_logits(T, E, held)
+    y, stats = moe_dropless(x, lambda _: logits, w_gate, w_up, w_down, k, first_held=FIRST_HELD)
+    rows = np.asarray(stats["rows"])
+    assert rows[FIRST_HELD: FIRST_HELD + H].sum() == held and rows.sum() == T * k
+    assert int(stats["carried"]) == 1024 * windows
+    monkeypatch.setattr(moe, "_window_rows", lambda *_: 0)
+    want, _ = moe_dropless(x, lambda _: logits, w_gate, w_up, w_down, k, first_held=FIRST_HELD)
+    assert (float(jnp.max(jnp.abs(want))) > 0) == (held > 0)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=0, atol=2e-6 * float(jnp.max(jnp.abs(want))))
+
+
+@pytest.mark.parametrize("E,H,window", [(8, 8, 0), (64, 64, 0), (16, 8, 0), (8, 5, 0), (8, 3, 3072),
+                                        (16, 2, 1024), (256, 32, 1024)])
+def test_the_windows_exist_only_under_half_of_the_experts(E, H, window):
+    """With every expert held, or a half or more of them, the layer traces no
+    loop (and is then the program it was); under a half it traces one a
+    direction."""
+    k, T = 2, 2048
+    assert moe._window_rows(T * k, H, E) == window
+    args = _held_setup(E, H)
+    first = min(FIRST_HELD, E - H)
+    text = str(jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(moe_dropless(*a, k, first_held=first)[0])))(*args))
+    assert ("while[" in text) == bool(window) and "cond[" not in text
+    _, stats = moe_dropless(*args, k, first_held=first)
+    assert ("carried" in stats) == bool(window)
+
+
+def test_the_cells_windows():
+    """Laguna's 32 of 256 under top-8 at 8,192 tokens: 16,384 of 65,536 rows
+    at a time; ZAYA's 8 of 16 and OLMoE's 64 of 64: all of them at once."""
+    assert moe._window_rows(8192 * 8, 32, 256) == 16384
+    assert moe._window_rows(16384 * 1, 8, 16) == 0 == moe._window_rows(16384 * 8, 64, 64)
+    assert moe._window_rows(10000, 1, 16) == 1536  # whole row tiles: 1,250 rounded up
+    assert moe._window_rows(1024, 2, 16) == 512
