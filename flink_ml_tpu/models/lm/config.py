@@ -1,9 +1,9 @@
 """The decoder LM's sizes and its parameter tree, shared by the stage
 (``decoder_lm.py``) and the plain references (``reference.py``,
-``reference_zaya.py``, ``reference_ouro.py``, ``reference_laguna.py``) so that
-one set of weights can be handed to both.
+``reference_zaya.py``, ``reference_ouro.py``, ``reference_laguna.py``,
+``reference_nemotron.py``) so that one set of weights can be handed to both.
 
-One stack, four kinds of block (``LMConfig.block``). The tree: ``{"embed": [V,
+One stack, five kinds of block (``LMConfig.block``). The tree: ``{"embed": [V,
 d], "layers": [layer, ...], "final_norm": [d], "lm_head": [d, V]}``; a tied
 head (``LMConfig.tied``) has no ``lm_head``: the head is ``embed`` transposed;
 the ``ouro`` kind adds the exit gate ``"exit_gate_w": [d, 1], "exit_gate_b":
@@ -48,14 +48,33 @@ nowhere else: no gradient reaches it), the shared expert ``"shared_gate"/
 "shared_up": [d, shared_width], "shared_down": [shared_width, d]`` and the
 held experts as above. Which layers attend through a sliding window
 (``layer_windows[i]`` keys, 0: full attention) changes no leaf.
+
+``nemotron_h`` (a layer is ONE mixer behind one norm, its kind
+``layer_kinds[i]``; the equations are in ``reference_nemotron.py``): every
+layer has ``"norm": [d]``; then a Mamba-2 layer (``M``), with ``i = ssm_heads *
+ssm_head_dim`` its inner width and ``c = i + 2 * ssm_groups * ssm_state`` the
+convolved channels: ``"in_proj": [d, i + c + ssm_heads]`` (gate ``z``, then
+``x``, ``B``, ``C``, then the step size), ``"conv_w": [conv_kernel, c]`` (tap
+``conv_kernel - 1`` reads the position itself), ``"conv_b": [c]``,
+``"dt_bias"``, ``"A_log"``, ``"D"``: ``[ssm_heads]``, ``"gate_norm": [i]``,
+``"out_proj": [i, d]``; an attention layer (``*``), ``n_heads`` query heads on
+``n_kv_heads`` of ``head_dim``: ``"wq": [d, a], "wk"/"wv": [d, c], "wo": [a,
+d]``; an expert layer (``E``): ``"router": [d, E], "router_bias": [E]`` (as
+``laguna``'s), the shared expert ``"shared_up": [d, shared_width],
+"shared_down": [shared_width, d]`` and the held experts ``"w_up": [H, d, h],
+"w_down": [H, h, d]``: two matrices an expert, ``down(relu(up(x))^2)``.
 """
 from __future__ import annotations
 
 from typing import List, NamedTuple, Tuple
 
-__all__ = ["LMConfig", "BLOCKS", "param_shapes", "num_params", "ONES", "ZEROS", "NORMAL", "SMALL", "SMALL_SCALE"]
+__all__ = ["LMConfig", "BLOCKS", "MIXERS", "param_shapes", "num_params", "ONES", "ZEROS", "NORMAL", "SMALL",
+           "SMALL_SCALE", "DT_BIAS", "A_LOG", "DT_RANGE", "DT_FLOOR", "A_RANGE"]
 
-BLOCKS = ("olmoe", "zaya", "ouro", "laguna")
+BLOCKS = ("olmoe", "zaya", "ouro", "laguna", "nemotron_h")
+#: The ``nemotron_h`` stack's layer kinds, as its published pattern spells them:
+#: a Mamba-2 scan, attention without a position encoding, relu² experts.
+MIXERS = ("M", "*", "E")
 #: How a leaf starts: at one (norm weights, scales), at zero (biases, the
 #: router's depth-averaging weight), at ``init_std * normal``, or - the zaya
 #: block's attention output projection - at ``SMALL_SCALE * init_std * normal``.
@@ -66,6 +85,13 @@ BLOCKS = ("olmoe", "zaya", "ouro", "laguna")
 #: 30). At a fiftieth the two are level and what is left is the data's own skew.
 ONES, ZEROS, NORMAL, SMALL = "ones", "zeros", "normal", "small"
 SMALL_SCALE = 0.02
+#: A Mamba-2 layer's two leaves that start from a uniform draw ``u`` of the
+#: leaf's own stream: the step size's bias at the inverse softplus of ``dt =
+#: max(exp(log DT_RANGE[0] + u log(DT_RANGE[1] / DT_RANGE[0])), DT_FLOOR)``
+#: (``time_step_min``, ``_max``, ``_floor`` of the published file), and ``A_log``
+#: at ``log(A_RANGE[0] + u (A_RANGE[1] - A_RANGE[0]))``.
+DT_BIAS, A_LOG = "dt_bias", "a_log"
+DT_RANGE, DT_FLOOR, A_RANGE = (1e-3, 1e-1), 1e-4, (1.0, 16.0)
 
 
 class LMConfig(NamedTuple):
@@ -106,6 +132,16 @@ class LMConfig(NamedTuple):
     routed_scale: float = 0.0
     window_rope_theta: float = 10000.0
     yarn: Tuple[float, ...] = ()
+    # the nemotron_h block's own: each layer's one mixer (``MIXERS``), and the
+    # Mamba-2 layers' sizes: heads, channels a head, groups that share one B and
+    # C, the state's width, the convolution's taps, the scan's chunk
+    layer_kinds: Tuple[str, ...] = ()
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 0
+    ssm_state: int = 0
+    conv_kernel: int = 0
+    chunk: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -184,13 +220,36 @@ def _laguna_leaves(cfg: LMConfig, i: int):
     ) + _expert_leaves(cfg)
 
 
-_LEAVES = {"olmoe": _olmoe_leaves, "zaya": _zaya_leaves, "ouro": _ouro_leaves, "laguna": _laguna_leaves}
+def _nemotron_leaves(cfg: LMConfig, i: int):
+    d, kind = cfg.hidden, cfg.layer_kinds[i]
+    norm = (("norm", (d,), ONES),)
+    if kind == "M":
+        heads, inner = cfg.ssm_heads, cfg.ssm_heads * cfg.ssm_head_dim
+        conv = inner + 2 * cfg.ssm_groups * cfg.ssm_state
+        return norm + (
+            ("in_proj", (d, inner + conv + heads), NORMAL), ("conv_w", (cfg.conv_kernel, conv), NORMAL),
+            ("conv_b", (conv,), ZEROS), ("dt_bias", (heads,), DT_BIAS), ("A_log", (heads,), A_LOG),
+            ("D", (heads,), ONES), ("gate_norm", (inner,), ONES), ("out_proj", (inner, d), NORMAL),
+        )
+    if kind == "*":
+        a, c = cfg.n_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
+        return norm + (("wq", (d, a), NORMAL), ("wk", (d, c), NORMAL), ("wv", (d, c), NORMAL), ("wo", (a, d), NORMAL))
+    e, h, s = cfg.held, cfg.expert_width, cfg.shared_width
+    return norm + (
+        ("router", (d, cfg.n_experts), NORMAL), ("router_bias", (cfg.n_experts,), ZEROS),
+        ("shared_up", (d, s), NORMAL), ("shared_down", (s, d), NORMAL),
+        ("w_up", (e, d, h), NORMAL), ("w_down", (e, h, d), NORMAL),
+    )
+
+
+_LEAVES = {"olmoe": _olmoe_leaves, "zaya": _zaya_leaves, "ouro": _ouro_leaves, "laguna": _laguna_leaves,
+           "nemotron_h": _nemotron_leaves}
 
 
 def param_shapes(cfg: LMConfig) -> List[Tuple[tuple, tuple, str]]:
     """Every leaf as ``(path, shape, init)``, in the one order the initialiser
     numbers them by. ``path`` indexes the tree: ``("layers", 0, "wq")``;
-    ``init`` is ``ONES``, ``ZEROS``, ``NORMAL`` or ``SMALL``."""
+    ``init`` is ``ONES``, ``ZEROS``, ``NORMAL``, ``SMALL``, ``DT_BIAS`` or ``A_LOG``."""
     out = [(("embed",), (cfg.vocab, cfg.hidden), NORMAL)]
     for i in range(cfg.n_layers):
         out += [(("layers", i, name), shape, init) for name, shape, init in _LEAVES[cfg.block](cfg, i)]

@@ -31,6 +31,15 @@ head, the loss's chunking, the clip and the AdamW program are one:
   SwiGLU, the others through sigmoid-gated experts (the chosen
   ``expertsPerToken`` renormalised and scaled by ``routedScale``) beside a
   shared expert every token passes.
+- ``nemotron_h`` (Nemotron-3-Nano's hybrid stack; ``reference_nemotron.py``):
+  a layer is ONE mixer behind one norm, ``x + mixer(norm(x))``, its kind the
+  layer's letter in ``layerPattern``: ``M`` a Mamba-2 layer (one projection
+  into a gate, the scan's ``x``, ``B``, ``C`` and step sizes; a short causal
+  depthwise convolution and SiLU over ``x``, ``B``, ``C``; the selective
+  state-space scan in chunks, ``parallel/ssd.py``; a grouped RMSNorm gated by
+  ``silu(z)``; one projection back), ``*`` causal attention on grouped queries
+  with NO position encoding, ``E`` sigmoid-gated experts as ``laguna``'s
+  beside a shared one, each ``down(relu(up(x))^2)`` on two matrices.
 
 Any expert kind may tie the head to the embedding (``tieEmbeddings``: one leaf)
 and hold a range of each block's experts (``expertsHeld``,
@@ -72,7 +81,8 @@ each of its ``layers x loops`` block applications and each pass's output.
 
 Names: the step program's parts carry ``jax.named_scope``s (``lm.embed``,
 ``lm.block`` with ``norm``, ``proj``, ``rope``, ``conv``, ``fold``, ``mix``,
-``ffn``, ``gate``, ``shared`` and the experts' ``route``, ``permute``, ``experts`` under it,
+``ffn``, ``gate``, ``shared``, a Mamba-2 layer's ``scan`` and ``gnorm``, and the
+experts' ``route``, ``permute``, ``experts`` under it,
 ``lm.final_norm``, ``lm.head``, ``lm.exit``, ``lm.aux``, ``lm.opt``), which
 reach each device operation's name beside what JAX's transformations write
 there, so a profile tells the parts, and forward from recomputed from
@@ -98,7 +108,8 @@ from flink_ml_tpu.api.core import Estimator, Model
 from flink_ml_tpu.api.types import DataTypes
 from flink_ml_tpu.metrics import MLMetrics, metrics
 from flink_ml_tpu.models.lm.config import (
-    BLOCKS, NORMAL, ONES, SMALL, SMALL_SCALE, LMConfig, num_params, param_shapes,
+    A_LOG, A_RANGE, BLOCKS, DT_BIAS, DT_FLOOR, DT_RANGE, MIXERS, NORMAL, ONES, SMALL, SMALL_SCALE, LMConfig,
+    num_params, param_shapes,
 )
 from flink_ml_tpu.params.param import (
     BoolParam,
@@ -121,6 +132,7 @@ from flink_ml_tpu.params.shared import (
 from flink_ml_tpu.parallel.flash import TQ_TILE, flash_available, fold_chunk_counts, fused_fold
 from flink_ml_tpu.parallel.mesh import is_tpu_backend
 from flink_ml_tpu.parallel.moe import dense_swiglu, moe_dropless
+from flink_ml_tpu.parallel.ssd import ssd_scan
 from flink_ml_tpu.trace import CAT_COMPILE, CAT_INGEST, CAT_PRODUCTIVE, CAT_READBACK, tracer
 from flink_ml_tpu.utils import read_write as rw
 
@@ -172,7 +184,9 @@ class _LMParams(
         "'zaya' (compressed convolutional attention on grouped queries, an MLP router) or "
         "'ouro' (a dense sandwich-norm layer, the stack run numLoops times over the same weights) or "
         "'laguna' (windowed and full attention layers of different head counts, a per-head output "
-        "gate, leading dense layers, sigmoid-gated experts beside a shared one).",
+        "gate, leading dense layers, sigmoid-gated experts beside a shared one) or "
+        "'nemotron_h' (each layer one mixer, by layerPattern: a Mamba-2 scan, attention without a "
+        "position encoding, or relu² experts beside a shared one).",
         "olmoe", ParamValidators.in_array(list(BLOCKS)),
     )
     TIE_EMBEDDINGS = BoolParam("tieEmbeddings", "The head is the embedding table transposed.", False)
@@ -188,10 +202,11 @@ class _LMParams(
     FIRST_EXPERT_HELD = IntParam("firstExpertHeld", "First expert of the held range.", 0,
                                  ParamValidators.gt_eq(0))
     NUM_KV_HEADS = IntParam(
-        "numKvHeads", "Key/value heads ('zaya', 'laguna'; the query heads divide evenly over them). 0: numHeads.", 0,
+        "numKvHeads", "Key/value heads ('zaya', 'laguna', 'nemotron_h'; the query heads divide evenly over them). "
+        "0: numHeads.", 0,
         ParamValidators.gt_eq(0),
     )
-    HEAD_SIZE = IntParam("headSize", "Channels per head ('zaya', 'laguna'). 0: hiddenSize / numHeads.", 0,
+    HEAD_SIZE = IntParam("headSize", "Channels per head ('zaya', 'laguna', 'nemotron_h'). 0: hiddenSize / numHeads.", 0,
                          ParamValidators.gt_eq(0))
     ROPE_FRACTION = FloatParam(
         "ropeFraction", "Share of each head's channels the rotary embedding turns ('zaya'; 'laguna': in "
@@ -218,16 +233,31 @@ class _LMParams(
     DENSE_WIDTH = IntParam("denseWidth", "Hidden width of a dense layer's SwiGLU ('laguna').", 0,
                            ParamValidators.gt_eq(0))
     SHARED_EXPERT_WIDTH = IntParam(
-        "sharedExpertWidth", "Hidden width of the expert every token passes beside the routed ones ('laguna').", 0,
+        "sharedExpertWidth", "Hidden width of the expert every token passes beside the routed ones ('laguna', "
+        "'nemotron_h').", 0,
         ParamValidators.gt_eq(0))
     ROUTED_SCALE = FloatParam(
         "routedScale", "The sigmoid gates of the chosen experts are renormalised to sum to one and scaled by "
-        "this ('laguna').", 1.0, ParamValidators.gt(0))
+        "this ('laguna', 'nemotron_h').", 1.0, ParamValidators.gt(0))
     WINDOW_ROPE_THETA = FloatParam("windowRopeTheta", "Base of the rotary embedding in windowed layers, which "
                                    "turn every channel ('laguna').", 10000.0, ParamValidators.gt(0))
     ROPE_YARN = FloatArrayParam(
         "ropeYarn", "YaRN on the full layers' rotary embedding ('laguna'): factor, original length, beta_fast, "
         "beta_slow, attention factor; empty: none.", [])
+    LAYER_PATTERN = StringParam(
+        "layerPattern", "Each layer's one mixer, a letter a layer ('nemotron_h'): M a Mamba-2 scan, * attention "
+        "without a position encoding, E experts.", "")
+    SSM_NUM_HEADS = IntParam("ssmNumHeads", "Heads of a Mamba-2 layer's scan ('nemotron_h').", 0,
+                             ParamValidators.gt_eq(0))
+    SSM_HEAD_SIZE = IntParam("ssmHeadSize", "Channels per scan head ('nemotron_h').", 0, ParamValidators.gt_eq(0))
+    SSM_NUM_GROUPS = IntParam("ssmNumGroups", "Groups of scan heads that share one B and C, and the groups of the "
+                              "gated norm ('nemotron_h').", 0, ParamValidators.gt_eq(0))
+    SSM_STATE_SIZE = IntParam("ssmStateSize", "Width of a scan head's state ('nemotron_h').", 0,
+                              ParamValidators.gt_eq(0))
+    SSM_CONV_KERNEL = IntParam("ssmConvKernel", "Taps of the causal depthwise convolution before the scan "
+                               "('nemotron_h').", 4, ParamValidators.gt(0))
+    SSM_CHUNK_SIZE = IntParam("ssmChunkSize", "Positions a chunk of the scan; the sequence length is a multiple "
+                              "('nemotron_h').", 128, ParamValidators.gt(0))
     COMPUTE_TYPE = StringParam(
         "computeType",
         "Matmul input dtype: 'bfloat16' runs every matmul and the attention "
@@ -286,12 +316,41 @@ class _LMParams(
             if int(cfg.head_dim * cfg.rope_fraction) % 2 or len(cfg.yarn) not in (0, 5):
                 raise ValueError(f"the rotary embedding needs an even number of channels, got {cfg.rope_fraction} "
                                  f"of {cfg.head_dim}; ropeYarn has five numbers or none, got {len(cfg.yarn)}")
+        elif cfg.block == "nemotron_h":
+            cfg = cfg._replace(
+                n_kv_heads=self.get(self.NUM_KV_HEADS), head_size=self.get(self.HEAD_SIZE), aux_coef=0.0,
+                shared_width=self.get(self.SHARED_EXPERT_WIDTH), routed_scale=self.get(self.ROUTED_SCALE),
+                layer_kinds=tuple(self.get(self.LAYER_PATTERN)), ssm_heads=self.get(self.SSM_NUM_HEADS),
+                ssm_head_dim=self.get(self.SSM_HEAD_SIZE), ssm_groups=self.get(self.SSM_NUM_GROUPS),
+                ssm_state=self.get(self.SSM_STATE_SIZE), conv_kernel=self.get(self.SSM_CONV_KERNEL),
+                chunk=self.get(self.SSM_CHUNK_SIZE),
+            )
+            if len(cfg.layer_kinds) != cfg.n_layers or set(cfg.layer_kinds) - set(MIXERS):
+                raise ValueError(f"layerPattern names each of the {cfg.n_layers} layers by one of {MIXERS}; "
+                                 f"got {self.get(self.LAYER_PATTERN)!r}")
+            if "M" in cfg.layer_kinds and not (
+                    cfg.ssm_heads and cfg.ssm_head_dim and cfg.ssm_state and cfg.ssm_groups
+                    and cfg.ssm_heads % cfg.ssm_groups == 0):
+                raise ValueError(f"a Mamba-2 layer needs ssmNumHeads ({cfg.ssm_heads}) in whole ssmNumGroups "
+                                 f"({cfg.ssm_groups}), ssmHeadSize ({cfg.ssm_head_dim}) and ssmStateSize "
+                                 f"({cfg.ssm_state})")
+            if "*" in cfg.layer_kinds and (not cfg.head_size or cfg.n_heads % cfg.kv_heads):
+                raise ValueError(f"an attention layer's numHeads {cfg.n_heads} divide evenly over numKvHeads "
+                                 f"{cfg.kv_heads}, at a stated headSize")
+            if "E" in cfg.layer_kinds and not cfg.shared_width:
+                raise ValueError("an expert layer needs sharedExpertWidth")
+            if cfg.tied:
+                raise ValueError("tieEmbeddings does not belong to blockKind 'nemotron_h'")
         elif self.get(self.NUM_KV_HEADS) or self.get(self.HEAD_SIZE) or self.get(self.ROPE_FRACTION) != 1.0:
-            raise ValueError("numKvHeads, headSize and ropeFraction belong to blockKind 'zaya' or 'laguna'")
+            raise ValueError("numKvHeads, headSize and ropeFraction belong to blockKind 'zaya', 'laguna' or "
+                             "'nemotron_h'")
         if cfg.block != "laguna" and (self.get(self.NUM_HEADS_PER_LAYER) or self.get(self.WINDOW_PER_LAYER)
-                                      or self.get(self.DENSE_LAYERS) or self.get(self.SHARED_EXPERT_WIDTH)):
-            raise ValueError("numHeadsPerLayer, windowPerLayer, denseLayers and sharedExpertWidth belong to "
-                             "blockKind 'laguna'")
+                                      or self.get(self.DENSE_LAYERS)):
+            raise ValueError("numHeadsPerLayer, windowPerLayer and denseLayers belong to blockKind 'laguna'")
+        if cfg.block not in ("laguna", "nemotron_h") and self.get(self.SHARED_EXPERT_WIDTH):
+            raise ValueError("sharedExpertWidth belongs to blockKind 'laguna' or 'nemotron_h'")
+        if cfg.block != "nemotron_h" and (self.get(self.LAYER_PATTERN) or self.get(self.SSM_NUM_HEADS)):
+            raise ValueError("layerPattern and the ssm sizes belong to blockKind 'nemotron_h'")
         if cfg.block == "ouro":  # a dense block: no experts, no router, nothing to balance
             cfg = cfg._replace(n_experts=0, top_k=0, aux_coef=0.0, loops=self.get(self.NUM_LOOPS),
                                exit_beta=self.get(self.EXIT_ENTROPY_COEF))
@@ -336,7 +395,10 @@ _add_accessors(_LMParams, (
     ("EXIT_ENTROPY_COEF", "exit_entropy_coef"), ("NUM_HEADS_PER_LAYER", "num_heads_per_layer"),
     ("WINDOW_PER_LAYER", "window_per_layer"), ("DENSE_LAYERS", "dense_layers"), ("DENSE_WIDTH", "dense_width"),
     ("SHARED_EXPERT_WIDTH", "shared_expert_width"), ("ROUTED_SCALE", "routed_scale"),
-    ("WINDOW_ROPE_THETA", "window_rope_theta"), ("ROPE_YARN", "rope_yarn"),
+    ("WINDOW_ROPE_THETA", "window_rope_theta"), ("ROPE_YARN", "rope_yarn"), ("LAYER_PATTERN", "layer_pattern"),
+    ("SSM_NUM_HEADS", "ssm_num_heads"), ("SSM_HEAD_SIZE", "ssm_head_size"), ("SSM_NUM_GROUPS", "ssm_num_groups"),
+    ("SSM_STATE_SIZE", "ssm_state_size"), ("SSM_CONV_KERNEL", "ssm_conv_kernel"),
+    ("SSM_CHUNK_SIZE", "ssm_chunk_size"),
 ))
 
 
@@ -376,6 +438,14 @@ def _init_program(cfg: LMConfig):
             if kind in (NORMAL, SMALL):
                 std = INIT_STD * (SMALL_SCALE if kind == SMALL else 1.0)
                 leaves.append(std * jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32))
+            elif kind in (DT_BIAS, A_LOG):  # a Mamba-2 layer's step sizes and decay rates (config.py)
+                u = jax.random.uniform(jax.random.fold_in(key, i), shape, jnp.float32)
+                if kind == A_LOG:
+                    leaves.append(jnp.log(A_RANGE[0] + u * (A_RANGE[1] - A_RANGE[0])))
+                else:
+                    dt = jnp.maximum(jnp.exp(math.log(DT_RANGE[0]) + u * math.log(DT_RANGE[1] / DT_RANGE[0])),
+                                     DT_FLOOR)
+                    leaves.append(dt + jnp.log(-jnp.expm1(-dt)))  # softplus's inverse
             else:
                 leaves.append((jnp.ones if kind == ONES else jnp.zeros)(shape, jnp.float32))
         return _build_tree(cfg, leaves)
@@ -668,14 +738,73 @@ def _laguna_block(x, carry, layer, cfg: LMConfig, cd, interpret: bool, window: i
         return x + y, carry, stats
 
 
+# -- the nemotron_h block (reference_nemotron.py carries each equation's origin) --
+
+
+def _mamba_block(x, carry, layer, cfg: LMConfig, cd, interpret: bool):
+    """A Mamba-2 layer: one projection into the gate ``z``, the scan's ``x``,
+    ``B``, ``C`` and the step sizes; a causal depthwise convolution and SiLU
+    over ``x``, ``B``, ``C``; the selective scan in chunks; the grouped
+    RMSNorm of ``y * silu(z)``; one projection back. The step sizes, the decay
+    rates and the scan's state are float32 whatever ``cd`` is."""
+    b, t, _ = x.shape
+    heads, p, groups, n, taps = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state, cfg.conv_kernel
+    inner, bc = heads * p, groups * n
+    u = _proj(_rms_norm(x, layer["norm"], cfg.norm_eps), layer["in_proj"], cd)
+    with jax.named_scope("conv"):
+        z, xbc, dt = u[..., :inner], u[..., inner: 2 * inner + 2 * bc], u[..., 2 * inner + 2 * bc:]
+        earlier = jnp.pad(xbc, ((0, 0), (taps - 1, 0), (0, 0)))  # tap j reads position t - (taps - 1) + j
+        xbc = jax.nn.silu(sum(layer["conv_w"][j] * earlier[:, j: j + t] for j in range(taps)) + layer["conv_b"])
+    with jax.named_scope("scan"):
+        xs = xbc[..., :inner].reshape(b, t, heads, p)
+        y = ssd_scan(xs, jax.nn.softplus(dt + layer["dt_bias"]), -jnp.exp(layer["A_log"]),
+                     xbc[..., inner: inner + bc].reshape(b, t, groups, n),
+                     xbc[..., inner + bc:].reshape(b, t, groups, n), cfg.chunk, cd)
+        y = y + layer["D"][:, None] * xs
+    with jax.named_scope("gnorm"):
+        y = (y.reshape(b, t, inner) * jax.nn.silu(z)).reshape(b, t, groups, inner // groups)
+        y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + cfg.norm_eps)
+        y = y.reshape(b, t, inner) * layer["gate_norm"]
+    y = _proj(y, layer["out_proj"], cd)
+    with jax.named_scope("mix"):
+        return x + y, carry, {}
+
+
+def _nope_attention_block(x, carry, layer, cfg: LMConfig, cd, interpret: bool):
+    """Causal attention on grouped queries with no position encoding."""
+    a = _rms_norm(x, layer["norm"], cfg.norm_eps)
+    q, k, v = (_heads(_proj(a, layer[w], cd), n)
+               for w, n in (("wq", cfg.n_heads), ("wk", cfg.kv_heads), ("wv", cfg.kv_heads)))
+    o = _proj(_merged(_fold(q, k, v, cd, interpret)), layer["wo"], cd)
+    with jax.named_scope("mix"):
+        return x + o, carry, {}
+
+
+def _relu2_experts_block(x, carry, layer, cfg: LMConfig, cd, interpret: bool):
+    """Sigmoid-gated experts (chosen with the selection bias, renormalised,
+    scaled) beside a shared one, each ``down(relu(up(u))^2)``."""
+    b, t, d = x.shape
+    u = _rms_norm(x, layer["norm"], cfg.norm_eps).reshape(b * t, d)
+    y, stats = moe_dropless(u, layer["router"], None, layer["w_up"], layer["w_down"], cfg.top_k, cd,
+                            cfg.first_held, cfg.routed_scale, layer["router_bias"])
+    with jax.named_scope("shared"):
+        y = (y + dense_swiglu(u, None, layer["shared_up"], layer["shared_down"], cd)).reshape(b, t, d)
+    with jax.named_scope("mix"):
+        return x + y, carry, stats
+
+
 _BLOCKS = {"olmoe": _olmoe_block, "zaya": _zaya_block, "ouro": _ouro_block}
+_MIXERS = {"M": _mamba_block, "*": _nope_attention_block, "E": _relu2_experts_block}
 
 
 def _layer_blocks(cfg: LMConfig) -> list:
     """Each layer's block function ``(x, carry, layer, cfg, cd, interpret)``.
     A stack that repeats one block names ONE function (its layers then trace
     to one shared sub-program where their leaves agree); the laguna stack one
-    for each window among its layers."""
+    for each window among its layers, the nemotron_h stack one for each kind
+    of mixer."""
+    if cfg.block == "nemotron_h":
+        return [_MIXERS[kind] for kind in cfg.layer_kinds]
     if cfg.block != "laguna":
         return [_BLOCKS[cfg.block]] * cfg.n_layers
     by_window = {w: functools.partial(_laguna_block, window=w) for w in set(cfg.layer_windows)}
@@ -864,11 +993,13 @@ def _log_likelihood_program(cfg: LMConfig, compute_type: str, interpret: bool):
     return jax.jit(run)
 
 
-def _fold_mode(t: int, head_dim: int) -> bool:
+def _fold_mode(t: int, head_dim: int, chunk: int = 0) -> bool:
     """Whether the fused fold runs interpreted (off the TPU), after checking
-    that it can serve this sequence at all; there is no other attention path."""
-    if t % TQ_TILE:
-        raise ValueError(f"sequence length {t} must be a multiple of {TQ_TILE} (the fused fold's Q tile)")
+    that it (and a stack with Mamba-2 layers' scan, in chunks of ``chunk``)
+    can serve this sequence at all; there is no other attention path."""
+    if t % TQ_TILE or (chunk and t % chunk):
+        raise ValueError(f"sequence length {t} must be a multiple of {TQ_TILE} (the fused fold's Q tile)"
+                         + (f" and of {chunk} (the scan's chunk)" if chunk else ""))
     on_tpu = is_tpu_backend(jax.devices())
     if on_tpu and not flash_available(t, head_dim):
         raise ValueError(
@@ -903,7 +1034,7 @@ class DecoderLMModel(Model, _LMParams):
         if tok.size and tok.max() >= cfg.vocab:
             raise ValueError(f"token ids must be in [0, {cfg.vocab}); got up to {tok.max()}")
         n, t = tok.shape
-        program = _log_likelihood_program(cfg, self.get_compute_type(), _fold_mode(t, cfg.head_dim))
+        program = _log_likelihood_program(cfg, self.get_compute_type(), _fold_mode(t, cfg.head_dim, cfg.chunk))
         params = jax.tree_util.tree_map(jnp.asarray, self.params)
         batch = min(self.get_global_batch_size(), n)
         out = np.empty(n, np.float64)
@@ -974,7 +1105,7 @@ class DecoderLM(Estimator, _LMParams):
             phase.set_metadata(tokens=n * t, bytes=int(tok.nbytes))
         fit_phase.set_metadata(tokens=n * t)
         cfg = self.lm_config(vocab)
-        interpret = _fold_mode(t, cfg.head_dim)
+        interpret = _fold_mode(t, cfg.head_dim, cfg.chunk)
         batch = min(self.get_global_batch_size(), n)
         steps = self.get_max_iter()
 
@@ -991,6 +1122,10 @@ class DecoderLM(Estimator, _LMParams):
             # the windowed layers' share of both beside them
             applications = cfg.n_layers * cfg.loops
             heads = cfg.layer_heads or (cfg.n_heads,) * cfg.n_layers
+            if cfg.layer_kinds:  # one mixer a layer: only the attention layers fold
+                heads = tuple(cfg.n_heads * (kind == "*") for kind in cfg.layer_kinds)
+            layers_scan = cfg.layer_kinds.count("M")
+            scan_chunks = layers_scan * batch * cfg.ssm_heads * (t // cfg.chunk if cfg.chunk else 0)
             windows = cfg.layer_windows or (0,) * cfg.n_layers
             one_head = {w: np.asarray(fold_chunk_counts(t, t, 0, True, w or None)) for w in set(windows)}
             chunks = np.zeros((2, 2), np.int64)  # [full, windowed] x [visited, all]
@@ -1006,6 +1141,13 @@ class DecoderLM(Estimator, _LMParams):
                 phase.set_metadata(layers_windowed=sum(w > 0 for w in windows),
                                    layers_full=sum(w == 0 for w in windows),
                                    fold_win_chunks=int(chunks[1, 1]), fold_win_chunks_visited=int(chunks[1, 0]))
+            if cfg.layer_kinds:
+                # a step's chunks of the scan (chunks x heads x sequences, every Mamba-2 layer) and the
+                # float32 chunk states one layer's recurrence carries
+                phase.set_metadata(layers_scan=layers_scan, layers_attn=cfg.layer_kinds.count("*"),
+                                   layers_moe=cfg.layer_kinds.count("E"), scan_chunks=scan_chunks,
+                                   scan_state_bytes=4 * scan_chunks // max(layers_scan, 1)
+                                   * cfg.ssm_head_dim * cfg.ssm_state)
 
         losses, leaf_norms, stats = [], [], []  # device values, fetched once after the loop
         with tracer.phase("train.dispatch", CAT_PRODUCTIVE, steps=steps):
@@ -1067,6 +1209,9 @@ class DecoderLM(Estimator, _LMParams):
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_WIN_CHUNKS, steps * int(chunks[1, 1]))
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_WIN_CHUNKS_VISITED,
                             steps * int(chunks[1, 0]))
+        if layers_scan:
+            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_SCAN_CHUNKS, steps * scan_chunks)
+            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_SCAN_LAYERS, steps * layers_scan)
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_LOOP_TRIPS, steps * cfg.loops)
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_LOOP_LAYER_APPLICATIONS, steps * applications)
         if loads.size:

@@ -45,6 +45,13 @@ sum(s_sel)``. A shared expert that every token passes beside the routed ones
 is no part of this layer's routing: ``dense_swiglu`` computes it, and the
 caller adds it once (each chip of an expert-parallel group computes it alike).
 
+The expert function is what the layer is told: SwiGLU on three matrices, or,
+told no gate matrix (``w_gate`` None), the non-gated ``down(relu(up(x))^2)``
+on two (Nemotron-H's ``relu2``). Both go the one path below - the sort, the
+held range, the windows and their written backward, ``dense_swiglu`` for a
+shared expert - and a layer with a gate traces, operation for operation, what
+it traced before the other existed.
+
 The held range, what expert parallelism asks of this layer anyway: told which
 experts it holds (``w_gate``/``w_up``/``w_down`` carry ``H`` experts,
 ``first_held .. first_held + H`` of the router's ``E``), it routes over all
@@ -126,13 +133,16 @@ def dense_swiglu(x, w_gate, w_up, w_down, compute_dtype=jnp.float32):
     """``down(silu(gate(x)) * up(x))`` of one SwiGLU ``[d, h]``, ``[d, h]``,
     ``[h, d]`` on every row of ``x [..., d]``: matmul inputs in the compute
     type, f32 accumulation and result. A dense layer's feed-forward, or the
-    expert every token passes beside the routed ones."""
+    expert every token passes beside the routed ones. Without a gate matrix
+    (``w_gate`` None): ``down(relu(up(x))^2)``."""
     cd = jnp.dtype(compute_dtype)
     precision = _HIGHEST if cd == jnp.float32 else None
 
     def dot(a, w):
         return jnp.dot(a.astype(cd), w.astype(cd), preferred_element_type=jnp.float32, precision=precision)
 
+    if w_gate is None:
+        return dot(jnp.square(jax.nn.relu(dot(x, w_up))), w_down)
     return dot(jax.nn.silu(dot(x, w_gate)) * dot(x, w_up), w_down)
 
 
@@ -203,12 +213,17 @@ def _expert_swiglu(xs, w_gate, w_up, w_down, group_sizes, compute_dtype, covered
     bfloat16 on the way). ``covered`` false: the groups may end before the
     rows do (the rows of experts held elsewhere, sorted last); every grouped
     matmul's result is then zeroed past the groups, so those rows give and are
-    given exactly zero whatever the kernel leaves there."""
+    given exactly zero whatever the kernel leaves there. ``w_gate`` None: the
+    experts are ``down(relu(up(xs))^2)``, two matrices each."""
     cd = jnp.dtype(compute_dtype)
     precision = _HIGHEST if cd == jnp.float32 else None
     guard = _row_guard(group_sizes, covered)
-    gate, up = _swiglu_hidden(xs, w_gate.astype(cd), w_up.astype(cd), group_sizes, cd, precision)
-    hidden = (jax.nn.silu(guard(gate)) * guard(up)).astype(cd)
+    if w_gate is None:
+        up = _grouped(xs, w_up.astype(cd), group_sizes, _ROWS, cd, precision).astype(jnp.float32)
+        hidden = jnp.square(jax.nn.relu(guard(up))).astype(cd)
+    else:
+        gate, up = _swiglu_hidden(xs, w_gate.astype(cd), w_up.astype(cd), group_sizes, cd, precision)
+        hidden = (jax.nn.silu(guard(gate)) * guard(up)).astype(cd)
     return guard(_grouped(hidden, w_down.astype(cd), group_sizes, _ROWS, cd, precision))
 
 
@@ -223,8 +238,18 @@ def _expert_swiglu_bwd(compute_dtype, covered, res, dy):
     precision = _HIGHEST if cd == jnp.float32 else None
     f32 = jnp.float32
     guard = _row_guard(group_sizes, covered)
-    wg, wu, wd = w_gate.astype(cd), w_up.astype(cd), w_down.astype(cd)
     transposed = lambda w: jnp.swapaxes(w, 1, 2)  # noqa: E731
+    if w_gate is None:
+        wu, wd = w_up.astype(cd), w_down.astype(cd)
+        dy = guard(dy)
+        up = jax.nn.relu(guard(_grouped(xs, wu, group_sizes, _ROWS, cd, precision).astype(f32)))
+        d_hidden = guard(_grouped(dy, transposed(wd), group_sizes, _ROWS, f32, precision))
+        d_w_down = _grouped(jnp.square(up).astype(cd), dy, group_sizes, _DW, f32, precision)
+        d_up = (d_hidden * 2.0 * up).astype(cd)
+        d_w_up = _grouped(xs, d_up, group_sizes, _DW, f32, precision)
+        d_xs = guard(_grouped(d_up, transposed(wu), group_sizes, _ROWS, f32, precision)).astype(xs.dtype)
+        return d_xs, None, d_w_up, d_w_down, None
+    wg, wu, wd = w_gate.astype(cd), w_up.astype(cd), w_down.astype(cd)
     dy = guard(dy)
     gate, up = _swiglu_hidden(xs, wg, wu, group_sizes, cd, precision)
     gate, up = guard(gate), guard(up)
@@ -321,14 +346,17 @@ def _in_windows_bwd(k, compute_dtype, c, res, dy):
     into its gradient (a ``dW`` summed over the windows inside the loop kept a
     second copy of every expert leaf's gradient alive). ``dy . down(hidden)``,
     the weight's cotangent, is taken as ``hidden . (dy @ W_down^T)``: the
-    backward runs no ``down`` matmul."""
+    backward runs no ``down`` matmul. Experts without a gate matrix park one
+    hidden gradient, not two, and run two ``dW`` matmuls."""
     x, top_p, w_gate, w_up, w_down, order, held_rows, trips = res
     cd, f32 = jnp.dtype(compute_dtype), jnp.float32
     precision = _HIGHEST if cd == f32 else None
+    gated = w_gate is not None
     weights, order, ends = _sorted_rows(top_p, order, held_rows, c)
     with jax.named_scope("experts"):
-        wg, wu, wd = (w.astype(cd) for w in (w_gate, w_up, w_down))
-        wg_t, wu_t, wd_t = (jnp.swapaxes(w, 1, 2) for w in (wg, wu, wd))  # as _expert_swiglu_bwd: _ROWS' form
+        wg, wu, wd = (w if w is None else w.astype(cd) for w in (w_gate, w_up, w_down))
+        # as _expert_swiglu_bwd: _ROWS' form
+        wg_t, wu_t, wd_t = (w if w is None else jnp.swapaxes(w, 1, 2) for w in (wg, wu, wd))
 
     def window(i, carry):
         (dx, d_weights), parked = carry
@@ -338,31 +366,39 @@ def _in_windows_bwd(k, compute_dtype, c, res, dy):
         with jax.named_scope("permute"):
             g = dy[token].astype(cd)
         with jax.named_scope("experts"):
-            gate, up = _swiglu_hidden(xs, wg, wu, sizes, cd, precision)
-            gate, up = guard(gate), guard(up)
-            sig = jax.nn.sigmoid(gate)
-            act = gate * sig  # silu(gate)
-            hidden = act * up
-            through_down = guard(_grouped(g, wd_t, sizes, _ROWS, f32, precision))  # dy @ W_down^T, unweighted
-            d_hidden = through_down * w[:, None]
-            d_up = (d_hidden * act).astype(cd)
-            d_gate = (d_hidden * up * (sig + act * (1.0 - sig))).astype(cd)
-            d_xs = guard(_grouped(d_gate, wg_t, sizes, _ROWS, f32, precision)
-                         + _grouped(d_up, wu_t, sizes, _ROWS, f32, precision))
-            now = (xs, g, (hidden * w[:, None]).astype(cd), d_gate, d_up)
+            if gated:
+                gate, up = _swiglu_hidden(xs, wg, wu, sizes, cd, precision)
+                gate, up = guard(gate), guard(up)
+                sig = jax.nn.sigmoid(gate)
+                act = gate * sig  # silu(gate)
+                hidden = act * up
+                through_down = guard(_grouped(g, wd_t, sizes, _ROWS, f32, precision))  # dy @ W_down^T, unweighted
+                d_hidden = through_down * w[:, None]
+                d_up = (d_hidden * act).astype(cd)
+                d_gate = (d_hidden * up * (sig + act * (1.0 - sig))).astype(cd)
+                d_xs = guard(_grouped(d_gate, wg_t, sizes, _ROWS, f32, precision)
+                             + _grouped(d_up, wu_t, sizes, _ROWS, f32, precision))
+                now = (xs, g, (hidden * w[:, None]).astype(cd), d_gate, d_up)
+            else:
+                up = jax.nn.relu(guard(_grouped(xs, wu, sizes, _ROWS, cd, precision).astype(f32)))
+                hidden = jnp.square(up)
+                through_down = guard(_grouped(g, wd_t, sizes, _ROWS, f32, precision))
+                d_up = (through_down * w[:, None] * 2.0 * up).astype(cd)
+                d_xs = guard(_grouped(d_up, wu_t, sizes, _ROWS, f32, precision))
+                now = (xs, g, (hidden * w[:, None]).astype(cd), d_up)
             parked = tuple(jax.lax.dynamic_update_slice_in_dim(all_, a, lo, 0) for all_, a in zip(parked, now))
         with jax.named_scope("permute"):
             dx = dx.at[token].add(d_xs)
             d_weights = d_weights.at[head].add(jnp.sum(hidden * through_down, axis=1))
         return (dx, d_weights), parked
 
-    rows, d, h = order.shape[0], x.shape[1], w_gate.shape[2]
+    rows, d, h = order.shape[0], x.shape[1], w_up.shape[2]
     with jax.named_scope("experts"):
-        parked = tuple(jnp.zeros((rows, width), cd) for width in (d, d, h, h, h))
-    (dx, d_weights), (xs, g, weighted_hidden, d_gate, d_up) = jax.lax.fori_loop(
+        parked = tuple(jnp.zeros((rows, width), cd) for width in (d, d, h, h, h)[:4 + gated])
+    (dx, d_weights), (xs, g, weighted_hidden, *d_gate, d_up) = jax.lax.fori_loop(
         0, trips, window, ((jnp.zeros_like(x), jnp.zeros_like(weights)), parked))
     with jax.named_scope("experts"):
-        d_w_gate = _grouped(xs, d_gate, held_rows, _DW, f32, precision)
+        d_w_gate = _grouped(xs, d_gate[0], held_rows, _DW, f32, precision) if gated else None
         d_w_up = _grouped(xs, d_up, held_rows, _DW, f32, precision)
         d_w_down = _grouped(weighted_hidden, g, held_rows, _DW, f32, precision)
     return dx, d_weights[:-1].reshape(top_p.shape), d_w_gate, d_w_up, d_w_down, None, None, None
@@ -373,7 +409,8 @@ _in_windows.defvjp(_in_windows_fwd, _in_windows_bwd)
 
 def moe_dropless(x, router, w_gate, w_up, w_down, k: int, compute_dtype=jnp.float32,
                  first_held: int = 0, routed_scale: float = 0.0, select_bias=None):
-    """Top-``k`` SwiGLU experts for tokens ``x [t, d]``.
+    """Top-``k`` experts for tokens ``x [t, d]``: SwiGLU, or with ``w_gate``
+    None ``down(relu(up(x))^2)``.
 
     ``router`` is a matrix ``[d, E]`` or a function ``x -> logits [t, E]``
     (float32); ``w_gate``/``w_up`` ``[H, d, h]`` and ``w_down [H, h, d]`` are
@@ -389,7 +426,7 @@ def moe_dropless(x, router, w_gate, w_up, w_down, k: int, compute_dtype=jnp.floa
     holds ``carried``: the rows this call's windows took.
     """
     t, _ = x.shape
-    n_held = w_gate.shape[0]
+    n_held = w_up.shape[0]
     if routed_scale:
         p, top_p, top_e = route_sigmoid_top_k(x, router, k, routed_scale, select_bias)
     else:

@@ -1,5 +1,6 @@
 """The LM step's kernels compiled for the real chip at OLMoE's published
-shape, and the whole step program at ZAYA1-8B's cut, without the chip: libtpu's compiler runs here against a described
+shape, and the whole step program at ZAYA1-8B's, Ouro's, Laguna's and
+Nemotron-3-Nano's cuts, without the chip: libtpu's compiler runs here against a described
 v5e (docs and recipe: the ``on-chip-measurement`` guide, section 2). It
 catches what interpret mode cannot - Mosaic's lowering rules and the
 scoped-VMEM limit - at no chip time. Nothing runs; no time is measured.
@@ -177,19 +178,15 @@ def _ouro_cut():
         block="ouro", loops=c["total_ut_steps"], exit_beta=c["exit_entropy_coef"])
 
 
-def test_the_zaya_step_program_at_the_cells_shapes(one_chip):
-    """The whole jitted step (forward, backward, clip, AdamW; six rematerialised
-    blocks) of the ``zaya1_8b`` configuration at 2 x 8,192 tokens: it fits the
-    chip (XLA's analysis, which counts what ``peak_bytes_in_use`` does not), the
-    held range's grouped matmuls are the grouped kernel in both directions and
-    the three fold kernels are there at ``H_kv`` 2, T 8,192."""
+def _compiled_step(c, cfg, one_chip):
+    """The configuration's whole jitted step compiled for the chip, and XLA's
+    analysis of it: the arguments hold the f32 weights and AdamW's moments, and
+    arguments and temporaries together fit the 15.75e9 B a step is held to."""
     from flink_ml_tpu.models.lm import decoder_lm
     from flink_ml_tpu.models.lm.config import num_params
 
-    c, cfg = _zaya_cut()
-    assert 16 * num_params(cfg) > 11e9  # the fullest device holds at least 11 GB of f32 state
-    batch, t = c["global_batch_size"], c["sequence_length"]
-    optimizer, step = decoder_lm._train_program(cfg, c["compute_dtype"], c["learning_rate"], batch, False)
+    optimizer, step = decoder_lm._train_program(cfg, c["compute_dtype"], c["learning_rate"],
+                                                c["global_batch_size"], False)
     params = jax.eval_shape(lambda: decoder_lm._init_program(cfg)(jax.random.key(0)))
     state = jax.eval_shape(optimizer.init, params)
 
@@ -199,12 +196,27 @@ def test_the_zaya_step_program_at_the_cells_shapes(one_chip):
 
     compiled = step.lower(
         on_chip(params), on_chip(state),
-        jax.ShapeDtypeStruct((c["num_sequences"], t), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((c["num_sequences"], c["sequence_length"]), jnp.int32, sharding=one_chip),
         jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
     ).compile()
     memory = compiled.memory_analysis()
     live = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     assert 12 * num_params(cfg) <= memory.argument_size_in_bytes and live < 15.75e9, live
+    return compiled, memory
+
+
+def test_the_zaya_step_program_at_the_cells_shapes(one_chip):
+    """The whole jitted step (forward, backward, clip, AdamW; six rematerialised
+    blocks) of the ``zaya1_8b`` configuration at 2 x 8,192 tokens: it fits the
+    chip (XLA's analysis, which counts what ``peak_bytes_in_use`` does not), the
+    held range's grouped matmuls are the grouped kernel in both directions and
+    the three fold kernels are there at ``H_kv`` 2, T 8,192."""
+    from flink_ml_tpu.models.lm.config import num_params
+
+    c, cfg = _zaya_cut()
+    assert 16 * num_params(cfg) > 11e9  # the fullest device holds at least 11 GB of f32 state
+    batch, t = c["global_batch_size"], c["sequence_length"]
+    compiled, memory = _compiled_step(c, cfg, one_chip)
     text = compiled.as_text()
     kernels = [ln for ln in text.splitlines() if ln.lstrip().startswith("%ragged-dot") and "custom-call(" in ln]
     assert len(kernels) >= 8 * cfg.n_layers
@@ -236,28 +248,12 @@ def test_the_laguna_step_program_at_the_cells_shapes(one_chip):
     32 held experts' grouped matmuls are the grouped kernel in both
     directions, over a window of 16,384 sorted rows at a time: no float32
     array has the 65,536 routed rows' count."""
-    from flink_ml_tpu.models.lm import decoder_lm
     from flink_ml_tpu.models.lm.config import num_params
 
     c, cfg = _laguna_cut()
     assert num_params(cfg) == 691_624_960  # 11.07 GB of f32 state at 16 bytes a parameter: 69% of 16 GB
     batch, t = c["global_batch_size"], c["sequence_length"]
-    optimizer, step = decoder_lm._train_program(cfg, c["compute_dtype"], c["learning_rate"], batch, False)
-    params = jax.eval_shape(lambda: decoder_lm._init_program(cfg)(jax.random.key(0)))
-    state = jax.eval_shape(optimizer.init, params)
-
-    def on_chip(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
-
-    compiled = step.lower(
-        on_chip(params), on_chip(state),
-        jax.ShapeDtypeStruct((c["num_sequences"], t), jnp.int32, sharding=one_chip),
-        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
-    ).compile()
-    memory = compiled.memory_analysis()
-    live = memory.argument_size_in_bytes + memory.temp_size_in_bytes
-    assert 12 * num_params(cfg) <= memory.argument_size_in_bytes and live < 15.75e9, live
+    compiled, memory = _compiled_step(c, cfg, one_chip)
     assert memory.temp_size_in_bytes < 6.31e9, memory.temp_size_in_bytes
     text = compiled.as_text()
     for kernel in ("flash_fold_fwd", "flash_fold_bwd_dq", "flash_fold_bwd_dkv",
@@ -273,6 +269,51 @@ def test_the_laguna_step_program_at_the_cells_shapes(one_chip):
         assert f"f32[{batch},{heads},{t},{t}]" not in text and f"f32[{batch * heads},{t},{t}]" not in text
 
 
+def _nemotron_cut():
+    """``(the benchmark's nemotron3_nano_30b configuration, its LMConfig)``."""
+    from perfbench.systems import nemotron_lm_fit
+
+    c = _cell_config("nemotron3_nano_30b")
+    return c, nemotron_lm_fit.lm_config(c)
+
+
+def test_the_nemotron_step_program_at_the_cells_shapes(one_chip):
+    """The whole jitted step of the ``nemotron3_nano_30b`` configuration at 2 x
+    8,192 tokens: nine rematerialised layers of three kinds (four Mamba-2
+    layers, one attention layer on 32 query heads over 2 key/value heads, four
+    expert layers of 8 held relu² experts beside the shared one). It fits the
+    chip (XLA's analysis: 7.37 GB of temporaries beside 8.00 GB of arguments,
+    15.38e9 B in all; with the recurrence over the chunk states as a
+    ``lax.scan`` it needed 7.97 GB and did not); the scan is its chunked form
+    - the ``[chunk, chunk]`` decays of 64 chunks a head are there in float32,
+    no array has a state a POSITION - the fold's three kernels take K and V
+    once per key/value head; the held experts' grouped matmuls are the grouped
+    kernel in both directions (two matrices an expert: a forward, a recomputed
+    forward, two ``dX`` and two ``dW`` a layer at the least), over a window of
+    12,288 sorted rows at a time."""
+    from flink_ml_tpu.models.lm.config import num_params
+
+    c, cfg = _nemotron_cut()
+    assert num_params(cfg) == 666_963_456  # 10.67 GB of f32 state at 16 bytes a parameter: 67% of 16 GB
+    batch, t = c["global_batch_size"], c["sequence_length"]
+    compiled, memory = _compiled_step(c, cfg, one_chip)
+    assert memory.temp_size_in_bytes < 7.45e9, memory.temp_size_in_bytes
+    text = compiled.as_text()
+    for kernel in ("flash_fold_fwd", "flash_fold_bwd_dq", "flash_fold_bwd_dkv"):
+        assert kernel in text
+    assert "flash_fold_win_" not in text
+    assert f"bf16[{batch * cfg.kv_heads},{t},{cfg.head_dim}]" in text  # K and V once per key/value head
+    assert f"f32[{batch},{cfg.n_heads},{t},{t}]" not in text and f"f32[{batch * cfg.n_heads},{t},{t}]" not in text
+    kernels = [ln for ln in text.splitlines() if ln.lstrip().startswith("%ragged-dot") and "custom-call(" in ln]
+    assert len(kernels) >= 6 * cfg.layer_kinds.count("E")
+    assert "convolution_select_fusion" not in text
+    routed = batch * t * cfg.top_k  # 98,304 routed rows a layer, a window of an eighth of them at a time
+    assert f"f32[{routed},{cfg.hidden}]" not in text and f"bf16[{routed // 8},{cfg.hidden}]" in text
+    chunks, r = t // cfg.chunk, cfg.ssm_heads // cfg.ssm_groups
+    assert f"f32[{batch},{chunks},{cfg.ssm_groups},{r},{cfg.chunk},{cfg.chunk}]" in text  # the chunks' decays
+    assert f"[{batch},{t},{cfg.ssm_heads},{cfg.ssm_head_dim},{cfg.ssm_state}]" not in text  # no state a position
+
+
 def test_the_ouro_step_program_at_the_cells_shapes(one_chip):
     """The whole jitted step of the ``ouro_2_6b`` configuration at 2 x 4,096
     tokens: six rematerialised dense blocks inside one scanned pass run four
@@ -281,28 +322,12 @@ def test_the_ouro_step_program_at_the_cells_shapes(one_chip):
     inputs stacked over the four trips), and the three fold kernels are there."""
     import re
 
-    from flink_ml_tpu.models.lm import decoder_lm
     from flink_ml_tpu.models.lm.config import num_params
 
     c, cfg = _ouro_cut()
     assert num_params(cfg) == 509_661_185  # 8.15 GB of f32 state at 16 bytes a parameter: 47% of 16 GiB
     batch, t = c["global_batch_size"], c["sequence_length"]
-    optimizer, step = decoder_lm._train_program(cfg, c["compute_dtype"], c["learning_rate"], batch, False)
-    params = jax.eval_shape(lambda: decoder_lm._init_program(cfg)(jax.random.key(0)))
-    state = jax.eval_shape(optimizer.init, params)
-
-    def on_chip(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
-
-    compiled = step.lower(
-        on_chip(params), on_chip(state),
-        jax.ShapeDtypeStruct((c["num_sequences"], t), jnp.int32, sharding=one_chip),
-        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
-    ).compile()
-    memory = compiled.memory_analysis()
-    live = memory.argument_size_in_bytes + memory.temp_size_in_bytes
-    assert 12 * num_params(cfg) <= memory.argument_size_in_bytes and live < 15.75e9, live
+    compiled, memory = _compiled_step(c, cfg, one_chip)
     text = compiled.as_text()
     for kernel in ("flash_fold_fwd", "flash_fold_bwd_dq", "flash_fold_bwd_dkv"):
         assert kernel in text
@@ -314,7 +339,8 @@ def test_the_ouro_step_program_at_the_cells_shapes(one_chip):
     assert f"f32[{batch},{cfg.n_heads},{t},{t}]" not in text and f"f32[{batch * cfg.n_heads},{t},{t}]" not in text
 
 
-@pytest.mark.parametrize("cut", [_zaya_cut, _ouro_cut, _laguna_cut], ids=["zaya", "ouro", "laguna"])
+@pytest.mark.parametrize("cut", [_zaya_cut, _ouro_cut, _laguna_cut, _nemotron_cut],
+                         ids=["zaya", "ouro", "laguna", "nemotron"])
 def test_the_state_program_at_the_cells_shapes(one_chip, cut):
     """AdamW's state as ``DecoderLM._fit`` makes it, ``optimizer.init`` jitted,
     at the cells' parameter trees: one program whose outputs are the whole

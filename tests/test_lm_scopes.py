@@ -36,9 +36,18 @@ KINDS = {
                         yarn=(4.0, 64.0, 8.0, 1.0, 1.1386)), True,
                {"lm.block/ffn", "lm.block/route", "lm.block/permute", "lm.block/experts", "lm.block/gate",
                 "lm.block/shared"}),
+    "nemotron_h": (LMConfig(n_layers=3, hidden=64, n_heads=4, n_experts=8, top_k=2, expert_width=32, vocab=512,
+                            norm_eps=1e-5, aux_coef=0.0, block="nemotron_h", experts_held=2, first_held=2,
+                            n_kv_heads=2, head_size=16, shared_width=48, routed_scale=2.5,
+                            layer_kinds=tuple("M*E"), ssm_heads=8, ssm_head_dim=8, ssm_groups=2, ssm_state=16,
+                            conv_kernel=4, chunk=64), True,
+                   {"lm.block/scan", "lm.block/gnorm", "lm.block/conv", "lm.block/route", "lm.block/permute",
+                    "lm.block/experts", "lm.block/shared"}),
 }
-EVERY_KIND = {"lm.embed", "lm.block/norm", "lm.block/proj", "lm.block/rope", "lm.block/fold", "lm.block/mix",
+EVERY_KIND = {"lm.embed", "lm.block/norm", "lm.block/proj", "lm.block/fold", "lm.block/mix",
               "lm.final_norm/norm", "lm.head", "lm.opt"}
+#: every kind but ``nemotron_h``, whose attention has no position encoding
+ROPE = "lm.block/rope"
 #: ``%name = shape opcode(``: the opcode is the first word followed by a parenthesis
 INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = .*? ([a-z][\w\-]*)\(")
 HEAVY = {"dot", "fusion", "custom-call", "scatter", "sort", "reduce"}
@@ -90,6 +99,7 @@ def test_every_scope_the_kind_has_appears(programs, kind):
     scopes = {scope for scope, _ in _found(step)}
     own = KINDS[kind][2]
     assert (EVERY_KIND | own) <= scopes, sorted((EVERY_KIND | own) - scopes)
+    assert (ROPE in scopes) == (kind != "nemotron_h")
     # and none another kind alone has
     others = set().union(*(k[2] for k in KINDS.values())) - own
     assert not others & scopes, sorted(others & scopes)
@@ -106,6 +116,8 @@ def test_the_head_runs_in_three_directions_and_a_block_is_recomputed_where_it_is
     assert ("lm.block/fold", BWD) in found
     if "lm.block/experts" in KINDS[kind][2]:
         assert {("lm.block/experts", BWD), ("lm.block/permute", BWD)} <= found
+    if kind == "nemotron_h":  # the scan is AD's: each of its parts in all three directions
+        assert {d for scope, d in found if scope == "lm.block/scan"} == {FWD, REMAT, BWD}
     if kind == "laguna":  # the windowed layer's kernels under names of their own, beside the full layers'
         names = {n.split("/fold/")[1].split("/")[0] for _, n in step if n and "/fold/flash_fold" in n}
         assert names == {f"flash_fold_{w}{k}" for w in ("", "win_") for k in ("fwd", "bwd_dq", "bwd_dkv")}
@@ -125,7 +137,7 @@ def test_the_update_is_outside_the_gradient(programs, kind):
 #: A looped stack's ``lax.scan`` stacks each pass's outputs and sums the shared
 #: weights' cotangents over the passes (``dynamic_update_slice``, ``add_any``)
 #: in code that is JAX's own, under no scope of the program.
-COVERED = {"olmoe": 0.95, "olmoe_stacked": 0.95, "zaya": 0.95, "ouro": 0.90, "laguna": 0.95}
+COVERED = {"olmoe": 0.95, "olmoe_stacked": 0.95, "zaya": 0.95, "ouro": 0.90, "laguna": 0.95, "nemotron_h": 0.95}
 
 
 @pytest.mark.parametrize("kind", sorted(KINDS))

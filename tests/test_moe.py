@@ -224,3 +224,67 @@ def test_the_cells_windows():
     assert moe._window_rows(16384 * 1, 8, 16) == 0 == moe._window_rows(16384 * 8, 64, 64)
     assert moe._window_rows(10000, 1, 16) == 1536  # whole row tiles: 1,250 rounded up
     assert moe._window_rows(1024, 2, 16) == 512
+
+
+# -- the expert function the layer is told: relu² on two matrices ----------------
+
+
+def _dense_relu2(x, router, w_up, w_down, k):
+    """Masked-dense top-k experts ``down(relu(up(x))^2)``, routed as ``_dense``."""
+    with jax.default_matmul_precision("highest"):
+        p = jax.nn.softmax(x @ router, axis=-1)
+        _, top_e = jax.lax.top_k(p, k)
+        weight = p * jnp.sum(jax.nn.one_hot(top_e, router.shape[1], dtype=p.dtype), axis=1)
+        y = jnp.zeros_like(x)
+        for e in range(router.shape[1]):
+            y = y + weight[:, e: e + 1] * (jnp.square(jax.nn.relu(x @ w_up[e])) @ w_down[e])
+        return y
+
+
+@pytest.mark.parametrize("E,k", ROUTINGS)
+def test_relu2_experts_match_masked_dense_forward_and_backward(E, k):
+    """Told no gate matrix the layer computes ``down(relu(up(x))^2)``: the
+    result and every cotangent against the masked-dense form, and
+    ``dense_swiglu`` told the same is one such expert."""
+    x, router, _, w_up, w_down = _setup(E=E, seed=20 + E + k)
+    probe = jnp.asarray(np.random.default_rng(1).standard_normal(x.shape).astype(np.float32))
+    got = moe_dropless(x, router, None, w_up, w_down, k)[0]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(_dense_relu2(x, router, w_up, w_down, k)),
+                               rtol=2e-5, atol=2e-6)
+    grads = jax.grad(lambda x, r, u, dn: jnp.sum(moe_dropless(x, r, None, u, dn, k)[0] * probe), argnums=(0, 1, 2, 3))
+    want = jax.grad(lambda *a: jnp.sum(_dense_relu2(*a, k) * probe), argnums=(0, 1, 2, 3))(x, router, w_up, w_down)
+    for name, g, w in zip(("x", "router", "w_up", "w_down"), grads(x, router, w_up, w_down), want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4, atol=2e-6 * float(jnp.max(jnp.abs(w))),
+                                   err_msg=name)
+    with jax.default_matmul_precision("highest"):
+        one = jnp.square(jax.nn.relu(x @ w_up[0])) @ w_down[0]
+    np.testing.assert_allclose(np.asarray(moe.dense_swiglu(x, None, w_up[0], w_down[0])), np.asarray(one),
+                               rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("dtype,lean", [("float32", 0.0), ("float32", 6.0), ("bfloat16", 6.0)])
+def test_the_relu2_windows_are_the_whole_range(dtype, lean, monkeypatch):
+    """Experts without a gate matrix through the windows (one hidden gradient
+    parked, two ``dW`` matmuls), at one window and, the router leaning, at
+    three: the result and every cotangent against the whole range at once."""
+    E, H, k = 16, 2, 2
+    x, router, _, w_up, w_down = _held_setup(E, H, seed=5)
+    probe = jnp.asarray(np.random.default_rng(1).standard_normal(x.shape).astype(np.float32))
+    held = slice(FIRST_HELD, FIRST_HELD + H)
+
+    def scalar(x, router, w_up, w_down):
+        logits = lambda x: jnp.dot(x, router, precision="highest").at[:, held].add(lean)  # noqa: E731
+        y, stats = moe_dropless(x, logits, None, w_up, w_down, k, jnp.dtype(dtype), FIRST_HELD)
+        return jnp.sum(y * probe), stats
+
+    run = jax.value_and_grad(scalar, argnums=(0, 1, 2, 3), has_aux=True)
+    (value, stats), got = run(x, router, w_up, w_down)
+    assert int(stats["carried"]) == (3 if lean else 1) * moe._window_rows(x.shape[0] * k, H, E)
+    monkeypatch.setattr(moe, "_window_rows", lambda *_: 0)
+    (want_value, _), want = run(x, router, w_up, w_down)
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    assert abs(float(value - want_value)) <= tol * abs(float(want_value)) + 1e-4
+    for name, g, w in zip(("x", "router", "w_up", "w_down"), got, want):
+        assert float(jnp.max(jnp.abs(w))) > 0, name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=0, atol=tol * float(jnp.max(jnp.abs(w))),
+                                   err_msg=name)
