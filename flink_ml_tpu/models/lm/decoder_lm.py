@@ -78,6 +78,8 @@ is more than one block each is rematerialised in the backward
 (``jax.checkpoint``). The looped stack is a ``lax.scan`` over its passes (the
 traced program is one pass): what it holds for the backward is the input of
 each of its ``layers x loops`` block applications and each pass's output.
+On the TPU the step is compiled into a stated size (``STEP_HBM_MIB``): XLA
+rematerialises further, toward arguments and temporaries that fit it.
 
 Names: the step program's parts carry ``jax.named_scope``s (``lm.embed``,
 ``lm.block`` with ``norm``, ``proj``, ``rope``, ``conv``, ``fold``, ``mix``,
@@ -132,7 +134,7 @@ from flink_ml_tpu.params.shared import (
 from flink_ml_tpu.parallel.flash import TQ_TILE, flash_available, fold_chunk_counts, fused_fold
 from flink_ml_tpu.parallel.mesh import is_tpu_backend
 from flink_ml_tpu.parallel.moe import dense_swiglu, moe_dropless
-from flink_ml_tpu.parallel.ssd import ssd_scan
+from flink_ml_tpu.parallel.ssd import scan_kernel_chunks, ssd_scan
 from flink_ml_tpu.trace import CAT_COMPILE, CAT_INGEST, CAT_PRODUCTIVE, CAT_READBACK, tracer
 from flink_ml_tpu.utils import read_write as rw
 
@@ -143,6 +145,12 @@ ADAM_B1, ADAM_B2, ADAM_EPS, WEIGHT_DECAY, CLIP_NORM = 0.9, 0.95, 1e-8, 0.1, 1.0
 INIT_STD = 0.02
 #: Token rows of the head's logits that exist at one time.
 _LOSS_CHUNK = 2048
+#: The HBM a training step's program is compiled into on the TPU, in MiB: the 15.75e9 B that
+#: ``tests/test_lm_chip_compile.py`` holds every cell's step to. Told so, XLA rematerialises and schedules toward
+#: that size (and refuses a step far past it); left to its default it stops wherever the step first fits the chip
+#: (the Nemotron cut: 16.52e9 B of the chip's 16.91e9 so, 15.39e9 told). A step that fits anyway compiles to the
+#: same program with and without it.
+STEP_HBM_MIB = 15_020
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -754,12 +762,13 @@ def _mamba_block(x, carry, layer, cfg: LMConfig, cd, interpret: bool):
     with jax.named_scope("conv"):
         z, xbc, dt = u[..., :inner], u[..., inner: 2 * inner + 2 * bc], u[..., 2 * inner + 2 * bc:]
         earlier = jnp.pad(xbc, ((0, 0), (taps - 1, 0), (0, 0)))  # tap j reads position t - (taps - 1) + j
-        xbc = jax.nn.silu(sum(layer["conv_w"][j] * earlier[:, j: j + t] for j in range(taps)) + layer["conv_b"])
+        xbc = sum(layer["conv_w"][j] * earlier[:, j: j + t] for j in range(taps)) + layer["conv_b"]
+        # x, B and C leave the activation as arrays of their own, as the scan's kernels take them; cut out of ONE
+        # activated array each was a copy a pass (6.6 ms of a step on the chip, PR 41)
+        xs, bs, cs = (jax.nn.silu(xbc[..., lo: lo + k * width]).reshape(b, t, k, width)
+                      for lo, k, width in ((0, heads, p), (inner, groups, n), (inner + bc, groups, n)))
     with jax.named_scope("scan"):
-        xs = xbc[..., :inner].reshape(b, t, heads, p)
-        y = ssd_scan(xs, jax.nn.softplus(dt + layer["dt_bias"]), -jnp.exp(layer["A_log"]),
-                     xbc[..., inner: inner + bc].reshape(b, t, groups, n),
-                     xbc[..., inner + bc:].reshape(b, t, groups, n), cfg.chunk, cd)
+        y = ssd_scan(xs, jax.nn.softplus(dt + layer["dt_bias"]), -jnp.exp(layer["A_log"]), bs, cs, cfg.chunk, cd)
         y = y + layer["D"][:, None] * xs
     with jax.named_scope("gnorm"):
         y = (y.reshape(b, t, inner) * jax.nn.silu(z)).reshape(b, t, groups, inner // groups)
@@ -976,7 +985,9 @@ def _train_program(cfg: LMConfig, compute_type: str, lr: float, batch: int, inte
             updates, opt_state = optimizer.update(grads, opt_state, params)
             return optax.apply_updates(params, updates), opt_state, loss, norms, stats
 
-    return optimizer._replace(init=jax.jit(optimizer.init)), jax.jit(step, donate_argnums=(0, 1))
+    budget = None if interpret else {"xla_tpu_max_hbm_size_mib": STEP_HBM_MIB}
+    return (optimizer._replace(init=jax.jit(optimizer.init)),
+            jax.jit(step, donate_argnums=(0, 1), compiler_options=budget))
 
 
 @functools.cache
@@ -1126,6 +1137,9 @@ class DecoderLM(Estimator, _LMParams):
                 heads = tuple(cfg.n_heads * (kind == "*") for kind in cfg.layer_kinds)
             layers_scan = cfg.layer_kinds.count("M")
             scan_chunks = layers_scan * batch * cfg.ssm_heads * (t // cfg.chunk if cfg.chunk else 0)
+            # those of them the scan's kernel pair walks: its grid's cells x the heads of a cell
+            scan_chunks_kernel = (layers_scan * scan_kernel_chunks(batch, t, cfg.ssm_heads, cfg.ssm_groups, cfg.chunk)
+                                  if layers_scan else 0)
             windows = cfg.layer_windows or (0,) * cfg.n_layers
             one_head = {w: np.asarray(fold_chunk_counts(t, t, 0, True, w or None)) for w in set(windows)}
             chunks = np.zeros((2, 2), np.int64)  # [full, windowed] x [visited, all]
@@ -1146,6 +1160,7 @@ class DecoderLM(Estimator, _LMParams):
                 # float32 chunk states one layer's recurrence carries
                 phase.set_metadata(layers_scan=layers_scan, layers_attn=cfg.layer_kinds.count("*"),
                                    layers_moe=cfg.layer_kinds.count("E"), scan_chunks=scan_chunks,
+                                   scan_chunks_kernel=scan_chunks_kernel,
                                    scan_state_bytes=4 * scan_chunks // max(layers_scan, 1)
                                    * cfg.ssm_head_dim * cfg.ssm_state)
 
@@ -1211,6 +1226,7 @@ class DecoderLM(Estimator, _LMParams):
                             steps * int(chunks[1, 0]))
         if layers_scan:
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_SCAN_CHUNKS, steps * scan_chunks)
+            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_SCAN_KERNEL_CHUNKS, steps * scan_chunks_kernel)
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_SCAN_LAYERS, steps * layers_scan)
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_LOOP_TRIPS, steps * cfg.loops)
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_LOOP_LAYER_APPLICATIONS, steps * applications)

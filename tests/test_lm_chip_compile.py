@@ -178,12 +178,9 @@ def _ouro_cut():
         block="ouro", loops=c["total_ut_steps"], exit_beta=c["exit_entropy_coef"])
 
 
-def _compiled_step(c, cfg, one_chip):
-    """The configuration's whole jitted step compiled for the chip, and XLA's
-    analysis of it: the arguments hold the f32 weights and AdamW's moments, and
-    arguments and temporaries together fit the 15.75e9 B a step is held to."""
+def _step_and_shapes(c, cfg, one_chip):
+    """``(the configuration's jitted step, the shapes of its arguments on the chip)``."""
     from flink_ml_tpu.models.lm import decoder_lm
-    from flink_ml_tpu.models.lm.config import num_params
 
     optimizer, step = decoder_lm._train_program(cfg, c["compute_dtype"], c["learning_rate"],
                                                 c["global_batch_size"], False)
@@ -194,11 +191,21 @@ def _compiled_step(c, cfg, one_chip):
         return jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
 
-    compiled = step.lower(
-        on_chip(params), on_chip(state),
-        jax.ShapeDtypeStruct((c["num_sequences"], c["sequence_length"]), jnp.int32, sharding=one_chip),
-        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
-    ).compile()
+    return step, (on_chip(params), on_chip(state),
+                  jax.ShapeDtypeStruct((c["num_sequences"], c["sequence_length"]), jnp.int32, sharding=one_chip),
+                  jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
+
+
+def _compiled_step(c, cfg, one_chip):
+    """The configuration's whole jitted step compiled for the chip as ``fit``
+    compiles it (``_train_program`` states the HBM it may take,
+    ``decoder_lm.STEP_HBM_MIB``, where it jits the step), and XLA's analysis of
+    it: the arguments hold the f32 weights and AdamW's moments, and arguments
+    and temporaries together fit the 15.75e9 B a step is held to."""
+    from flink_ml_tpu.models.lm.config import num_params
+
+    step, shapes = _step_and_shapes(c, cfg, one_chip)
+    compiled = step.lower(*shapes).compile()
     memory = compiled.memory_analysis()
     live = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     assert 12 * num_params(cfg) <= memory.argument_size_in_bytes and live < 15.75e9, live
@@ -277,29 +284,40 @@ def _nemotron_cut():
     return c, nemotron_lm_fit.lm_config(c)
 
 
-def test_the_nemotron_step_program_at_the_cells_shapes(one_chip):
+def test_the_nemotron_step_program_at_the_cells_shapes(one_chip, monkeypatch):
     """The whole jitted step of the ``nemotron3_nano_30b`` configuration at 2 x
     8,192 tokens: nine rematerialised layers of three kinds (four Mamba-2
     layers, one attention layer on 32 query heads over 2 key/value heads, four
     expert layers of 8 held relu² experts beside the shared one). It fits the
-    chip (XLA's analysis: 7.37 GB of temporaries beside 8.00 GB of arguments,
-    15.38e9 B in all; with the recurrence over the chunk states as a
-    ``lax.scan`` it needed 7.97 GB and did not); the scan is its chunked form
-    - the ``[chunk, chunk]`` decays of 64 chunks a head are there in float32,
-    no array has a state a POSITION - the fold's three kernels take K and V
+    HBM ``fit`` compiles a step into (``decoder_lm.STEP_HBM_MIB``: 15,020 MiB,
+    the 15.75e9 B below): XLA's analysis reads 7.39 GB of temporaries beside
+    8.00 GB of arguments, 15.39e9 B in all, where the step whose scan was
+    ``jax.numpy`` read 7.98 GB told the same and 7.37 GB (15.38e9 B) told
+    nothing. Left to its default XLA stops rematerialising once the step fits
+    the chip and reads more - 8.51 GB here, 16.52e9 B of the chip's 16.91e9 -
+    which the second compile below holds
+    where it was measured. The scan is its two kernels by name: no ``[chunk,
+    chunk]`` block of decays of 64 chunks a head is an array of the program,
+    nor is a state a POSITION, and the state a chunk starts from is saved once
+    a Mamba-2 layer, for the backward; the fold's three kernels take K and V
     once per key/value head; the held experts' grouped matmuls are the grouped
     kernel in both directions (two matrices an expert: a forward, a recomputed
     forward, two ``dX`` and two ``dW`` a layer at the least), over a window of
     12,288 sorted rows at a time."""
     from flink_ml_tpu.models.lm.config import num_params
+    from flink_ml_tpu.parallel import ssd
 
     c, cfg = _nemotron_cut()
     assert num_params(cfg) == 666_963_456  # 10.67 GB of f32 state at 16 bytes a parameter: 67% of 16 GB
     batch, t = c["global_batch_size"], c["sequence_length"]
+    monkeypatch.setattr(ssd, "_interpreted", lambda: False)  # the backend here is the CPU; the target is the chip
     compiled, memory = _compiled_step(c, cfg, one_chip)
     assert memory.temp_size_in_bytes < 7.45e9, memory.temp_size_in_bytes
+    step, shapes = _step_and_shapes(c, cfg, one_chip)
+    unbudgeted = jax.jit(step.__wrapped__, donate_argnums=(0, 1)).lower(*shapes).compile().memory_analysis()
+    assert unbudgeted.argument_size_in_bytes + unbudgeted.temp_size_in_bytes < 16.69e9  # of the chip's 15.75 GiB
     text = compiled.as_text()
-    for kernel in ("flash_fold_fwd", "flash_fold_bwd_dq", "flash_fold_bwd_dkv"):
+    for kernel in ("flash_fold_fwd", "flash_fold_bwd_dq", "flash_fold_bwd_dkv", "ssd_scan_fwd", "ssd_scan_bwd"):
         assert kernel in text
     assert "flash_fold_win_" not in text
     assert f"bf16[{batch * cfg.kv_heads},{t},{cfg.head_dim}]" in text  # K and V once per key/value head
@@ -310,7 +328,8 @@ def test_the_nemotron_step_program_at_the_cells_shapes(one_chip):
     routed = batch * t * cfg.top_k  # 98,304 routed rows a layer, a window of an eighth of them at a time
     assert f"f32[{routed},{cfg.hidden}]" not in text and f"bf16[{routed // 8},{cfg.hidden}]" in text
     chunks, r = t // cfg.chunk, cfg.ssm_heads // cfg.ssm_groups
-    assert f"f32[{batch},{chunks},{cfg.ssm_groups},{r},{cfg.chunk},{cfg.chunk}]" in text  # the chunks' decays
+    assert f"{cfg.chunk},{cfg.chunk}]" not in text.replace(f"[{cfg.chunk},{cfg.chunk}]", "")  # no stack of chunk blocks
+    assert f"f32[{batch},{chunks},{cfg.ssm_groups},{cfg.ssm_state},{r * cfg.ssm_head_dim}]" in text  # the chunks' states
     assert f"[{batch},{t},{cfg.ssm_heads},{cfg.ssm_head_dim},{cfg.ssm_state}]" not in text  # no state a position
 
 

@@ -1,9 +1,11 @@
-"""The chunked state-space scan (``parallel/ssd.py::ssd_scan``) against the
-plain recurrence it has to agree with (``reference_scan``: one position at a
-time), forward and every gradient, at toy sizes on the CPU: a sequence of one
-chunk (no state is ever carried), of several (the chunk states' recurrence is
-real), two sequences a batch (nothing couples them), heads that share a
-group's ``B`` and ``C`` and heads with their own.
+"""The chunked state-space scan (``parallel/ssd.py::ssd_scan``: a Pallas kernel
+pair under a ``custom_vjp``, interpreted here on the CPU) against the plain
+recurrence it has to agree with (``reference_scan``: one position at a time),
+forward and every gradient, at toy sizes: a sequence of one chunk (no state is
+ever carried, ``dS`` stays zero), of several and of many (the forward walk
+carries the state first to last, the backward walk ``dS`` last to first, across
+every edge), two sequences a batch (nothing couples them), heads that share a
+group's ``B`` and ``C`` - two, three or all of them - and heads with their own.
 
 Tolerances. float32: the two compute one sum in different orders (a chunk's
 ``[chunk, chunk]`` decays against a running state), read here at 2e-6 of the
@@ -16,12 +18,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from flink_ml_tpu.parallel.ssd import reference_scan, ssd_scan
+from flink_ml_tpu.parallel import ssd
+from flink_ml_tpu.parallel.ssd import reference_scan, scan_kernel_chunks, ssd_scan
 
 NAMES = ("x", "dt", "a", "b", "c")
 #: ``(sequences, T, chunk, heads, groups)``
 SHAPES = {"one_chunk": (1, 32, 32, 4, 2), "four_chunks": (1, 128, 32, 4, 2), "two_sequences": (2, 96, 32, 4, 2),
-          "a_group_a_head": (1, 64, 16, 4, 4), "one_group": (2, 64, 32, 6, 1)}
+          "a_group_a_head": (1, 64, 16, 4, 4), "one_group": (2, 64, 32, 6, 1), "sixteen_chunks": (1, 256, 16, 4, 2),
+          "three_heads_a_group": (2, 64, 32, 6, 2)}
 P, N = 8, 16
 
 
@@ -105,6 +109,62 @@ def test_bfloat16_matmul_inputs_keep_the_decays_in_float32(shape):
     got = jax.grad(lambda *a: jnp.sum(ssd_scan(*a, chunk, jnp.bfloat16) * probe), argnums=range(5))(*args)
     for name, g, w in zip(NAMES, got, want):
         assert g.dtype == w.dtype == jnp.float32 and _worst(g, w) < 2e-2, name
+
+
+@pytest.mark.parametrize("shape", ["one_chunk", "four_chunks", "sixteen_chunks"])
+def test_the_backward_walk_carries_the_last_chunks_pull_to_the_first(shape):
+    """A loss that reads the LAST chunk's ``y`` alone: what it asks of the
+    first chunk's ``x``, ``B`` and step sizes reaches them only through ``dS``,
+    carried back over every edge between (none when the sequence is one chunk:
+    then the first chunk is the last, and nothing comes from past it)."""
+    args = _inputs(shape, seed=6)
+    _, t, chunk, _, _ = SHAPES[shape]
+    probe = jax.random.normal(jax.random.key(9), args[0].shape).at[:, : t - chunk].set(0.0)
+    want = jax.grad(lambda *a: jnp.sum(reference_scan(*a) * probe), argnums=range(5))(*args)
+    got = jax.grad(lambda *a: jnp.sum(ssd_scan(*a, chunk) * probe), argnums=range(5))(*args)
+    for name, g, w in zip(NAMES, got, want):
+        assert _worst(g, w) < 1e-4, name
+    for g in (got[0], got[1], got[3]):  # x, the step sizes, B: each position's own
+        assert float(jnp.max(jnp.abs(g[:, :chunk]))) > 0
+    if t > chunk:
+        assert float(jnp.max(jnp.abs(got[4][:, : t - chunk]))) == 0.0  # C is read where y is, nowhere earlier
+
+
+def test_no_chunk_by_chunk_block_leaves_the_kernels():
+    """Forward and backward are the two kernels by name, and nothing the
+    program holds outside them has a ``[chunk, chunk]`` face: the decays and
+    masked scores exist inside a grid cell alone. The forward on its own does
+    not write the chunk states; under differentiation it does, once."""
+    args = _inputs("four_chunks")
+    chunk, nc = 32, 4
+
+    def shapes(jaxpr):
+        return [tuple(v.aval.shape) for eqn in jaxpr.eqns for v in eqn.outvars]
+
+    forward = jax.make_jaxpr(lambda *a: ssd_scan(*a, chunk))(*args)
+    both = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(ssd_scan(*a, chunk)), argnums=range(5)))(*args)
+    assert "ssd_scan_fwd" in str(forward) and "ssd_scan_bwd" not in str(forward)
+    assert "ssd_scan_fwd" in str(both) and "ssd_scan_bwd" in str(both)
+    starts = (1, nc, 2, N, 2 * P)  # [B, chunks, G, N, r P]
+    for jaxpr, saved in ((forward, 0), (both, 1)):
+        # the custom_vjp's call holds the kernels: look inside it too
+        inner = [sub for eqn in jaxpr.jaxpr.eqns for sub in jax.core.jaxprs_in_params(eqn.params)]
+        found = shapes(jaxpr.jaxpr) + [s for sub in inner for s in shapes(sub)]
+        assert not [s for s in found if len(s) >= 2 and s[-2:] == (chunk, chunk) and s != (chunk, chunk)], found
+        assert found.count(starts) == saved
+
+
+def test_the_kernels_grid_covers_every_chunk_of_every_head():
+    for shape, (batch, t, chunk, heads, groups) in SHAPES.items():
+        assert scan_kernel_chunks(batch, t, heads, groups, chunk) == batch * heads * (t // chunk), shape
+
+
+def test_a_tpu_takes_only_shapes_that_tile(monkeypatch):
+    """Compiled for the chip the lane dimensions have to tile; the toy shapes
+    of this file run interpreted alone."""
+    monkeypatch.setattr(ssd, "_interpreted", lambda: False)
+    with pytest.raises(ValueError, match="on the TPU the scan's kernels take"):
+        ssd_scan(*_inputs("four_chunks"), 32)
 
 
 def test_sizes_that_do_not_divide_are_refused():
