@@ -64,6 +64,8 @@ class MLMetrics:
     TRAIN_LM_LOOP_LAYER_APPLICATIONS = "ml.train.lm.loop.layer_applications"  # block applications (layers x passes x steps), counter
     TRAIN_LM_SCAN_CHUNKS = "ml.train.lm.scan.chunks"  # chunks of the state-space scan (chunks x heads x sequences x Mamba-2 layers x steps), counter
     TRAIN_LM_SCAN_KERNEL_CHUNKS = "ml.train.lm.scan.kernel_chunks"  # those of them that passed through the scan's kernel pair (parallel/ssd.py), counter
+    TRAIN_LM_CONV_POSITIONS = "ml.train.lm.conv.positions"  # positions x channels of the Mamba-2 layers' causal convolutions (x layers x steps, forward), counter
+    TRAIN_LM_CONV_KERNEL_POSITIONS = "ml.train.lm.conv.kernel_positions"  # those of them that the convolution's kernel pair covered (parallel/causal_conv.py), counter
     TRAIN_LM_SCAN_LAYERS = "ml.train.lm.scan.layers"  # Mamba-2 layer applications (layers x steps), counter
     TRAIN_MOE_ROWS = "ml.train.moe.rows"  # (token, expert) rows the experts held here ran, counter
     TRAIN_MOE_ROWS_ABSENT = "ml.train.moe.rows_absent"  # rows routed to experts held elsewhere, counter

@@ -35,9 +35,11 @@ head, the loss's chunking, the clip and the AdamW program are one:
   a layer is ONE mixer behind one norm, ``x + mixer(norm(x))``, its kind the
   layer's letter in ``layerPattern``: ``M`` a Mamba-2 layer (one projection
   into a gate, the scan's ``x``, ``B``, ``C`` and step sizes; a short causal
-  depthwise convolution and SiLU over ``x``, ``B``, ``C``; the selective
-  state-space scan in chunks, ``parallel/ssd.py``; a grouped RMSNorm gated by
-  ``silu(z)``; one projection back), ``*`` causal attention on grouped queries
+  depthwise convolution and SiLU over ``x``, ``B``, ``C``, a kernel pair that
+  reads them where they lie in the projection, ``parallel/causal_conv.py``;
+  the selective state-space scan in chunks, ``parallel/ssd.py``; a grouped
+  RMSNorm gated by ``silu(z)``; one projection back), ``*`` causal attention
+  on grouped queries
   with NO position encoding, ``E`` sigmoid-gated experts as ``laguna``'s
   beside a shared one, each ``down(relu(up(x))^2)`` on two matrices.
 
@@ -67,8 +69,9 @@ says which and why.
 
 Precision: ``computeType`` names the matmuls' input type (``bfloat16``: the
 MXU's native path, f32 accumulation); the router, the softmaxes, the norms,
-RoPE, the convolutions' depthwise pass, the loss, the master weights and
-AdamW's state are float32 regardless.
+RoPE, the convolutions' depthwise pass (in ``jax.numpy`` or, a Mamba-2
+layer's, in its kernels), the loss, the master weights and AdamW's state are
+float32 regardless.
 
 Memory (what lets 626 M parameters and 16,384 tokens a step share one 16 GB
 chip): the experts' backward recomputes their two hidden projections from
@@ -83,8 +86,10 @@ rematerialises further, toward arguments and temporaries that fit it.
 
 Names: the step program's parts carry ``jax.named_scope``s (``lm.embed``,
 ``lm.block`` with ``norm``, ``proj``, ``rope``, ``conv``, ``fold``, ``mix``,
-``ffn``, ``gate``, ``shared``, a Mamba-2 layer's ``scan`` and ``gnorm``, and the
-experts' ``route``, ``permute``, ``experts`` under it,
+``ffn``, ``gate``, ``shared``, a Mamba-2 layer's ``scan`` and ``gnorm`` (its
+convolution's kernels are ``conv/causal_conv_fwd`` and ``_bwd``, its scan's
+``scan/ssd_scan_fwd`` and ``_bwd``), and the experts' ``route``, ``permute``,
+``experts`` under it,
 ``lm.final_norm``, ``lm.head``, ``lm.exit``, ``lm.aux``, ``lm.opt``), which
 reach each device operation's name beside what JAX's transformations write
 there, so a profile tells the parts, and forward from recomputed from
@@ -131,6 +136,7 @@ from flink_ml_tpu.params.shared import (
     HasPredictionCol,
     HasSeed,
 )
+from flink_ml_tpu.parallel.causal_conv import causal_conv, forward_positions
 from flink_ml_tpu.parallel.flash import TQ_TILE, flash_available, fold_chunk_counts, fused_fold
 from flink_ml_tpu.parallel.mesh import is_tpu_backend
 from flink_ml_tpu.parallel.moe import dense_swiglu, moe_dropless
@@ -756,17 +762,14 @@ def _mamba_block(x, carry, layer, cfg: LMConfig, cd, interpret: bool):
     RMSNorm of ``y * silu(z)``; one projection back. The step sizes, the decay
     rates and the scan's state are float32 whatever ``cd`` is."""
     b, t, _ = x.shape
-    heads, p, groups, n, taps = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state, cfg.conv_kernel
+    heads, p, groups, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
     inner, bc = heads * p, groups * n
     u = _proj(_rms_norm(x, layer["norm"], cfg.norm_eps), layer["in_proj"], cd)
     with jax.named_scope("conv"):
-        z, xbc, dt = u[..., :inner], u[..., inner: 2 * inner + 2 * bc], u[..., 2 * inner + 2 * bc:]
-        earlier = jnp.pad(xbc, ((0, 0), (taps - 1, 0), (0, 0)))  # tap j reads position t - (taps - 1) + j
-        xbc = sum(layer["conv_w"][j] * earlier[:, j: j + t] for j in range(taps)) + layer["conv_b"]
-        # x, B and C leave the activation as arrays of their own, as the scan's kernels take them; cut out of ONE
-        # activated array each was a copy a pass (6.6 ms of a step on the chip, PR 41)
-        xs, bs, cs = (jax.nn.silu(xbc[..., lo: lo + k * width]).reshape(b, t, k, width)
-                      for lo, k, width in ((0, heads, p), (inner, groups, n), (inner + bc, groups, n)))
+        # the convolution's kernels read x, B and C where they lie in u and write them activated as arrays of their
+        # own, as the scan's kernels take them; the gate and the step sizes leave u beside them (parallel/causal_conv.py)
+        z, (xs, bs, cs), dt = causal_conv(u, layer["conv_w"], layer["conv_b"], (inner, bc, bc), first=inner)
+        xs, bs, cs = xs.reshape(b, t, heads, p), bs.reshape(b, t, groups, n), cs.reshape(b, t, groups, n)
     with jax.named_scope("scan"):
         y = ssd_scan(xs, jax.nn.softplus(dt + layer["dt_bias"]), -jnp.exp(layer["A_log"]), bs, cs, cfg.chunk, cd)
         y = y + layer["D"][:, None] * xs
@@ -990,6 +993,21 @@ def _train_program(cfg: LMConfig, compute_type: str, lr: float, batch: int, inte
             jax.jit(step, donate_argnums=(0, 1), compiler_options=budget))
 
 
+#: What ``_conv_positions_kernel`` counted, by step program and window shape.
+_CONV_POSITIONS_KERNEL: dict = {}
+
+
+def _conv_positions_kernel(step, params, opt_state, window) -> int:
+    """The positions x channels that the convolution's forward kernels cover
+    in one ``step`` on ``window``, from the kernel calls of the step as traced
+    (``jit`` keeps the trace: the first step's call finds it): a Mamba-2 layer
+    that went around the kernels would not be counted."""
+    key = (step, window.shape)
+    if key not in _CONV_POSITIONS_KERNEL:
+        _CONV_POSITIONS_KERNEL[key] = forward_positions(step.trace(params, opt_state, window, jnp.int32(0)).jaxpr.jaxpr)
+    return _CONV_POSITIONS_KERNEL[key]
+
+
 @functools.cache
 def _log_likelihood_program(cfg: LMConfig, compute_type: str, interpret: bool):
     cd = jnp.dtype(compute_type)
@@ -1147,6 +1165,10 @@ class DecoderLM(Estimator, _LMParams):
                 chunks[int(w > 0)] += cfg.loops * h * batch * one_head[w]
             opt_state = optimizer.init(params)  # one dispatch: fresh buffers, which the step donates
             state = jax.tree_util.tree_leaves(opt_state)
+            # positions x channels of the Mamba-2 layers' convolutions in one step's forward, and those of them the
+            # convolution's kernels cover: their calls' grids in the step as traced
+            conv_positions = layers_scan * batch * t * (cfg.ssm_heads * cfg.ssm_head_dim + 2 * cfg.ssm_groups * cfg.ssm_state)
+            conv_positions_kernel = _conv_positions_kernel(step, params, opt_state, window) if layers_scan else 0
             phase.set_metadata(built=int(_train_program.cache_info().misses > misses),
                                fold_chunks=int(chunks[:, 1].sum()), fold_chunks_visited=int(chunks[:, 0].sum()),
                                loop_trips=cfg.loops, layer_applications=applications,
@@ -1160,7 +1182,8 @@ class DecoderLM(Estimator, _LMParams):
                 # float32 chunk states one layer's recurrence carries
                 phase.set_metadata(layers_scan=layers_scan, layers_attn=cfg.layer_kinds.count("*"),
                                    layers_moe=cfg.layer_kinds.count("E"), scan_chunks=scan_chunks,
-                                   scan_chunks_kernel=scan_chunks_kernel,
+                                   scan_chunks_kernel=scan_chunks_kernel, conv_positions=conv_positions,
+                                   conv_positions_kernel=conv_positions_kernel,
                                    scan_state_bytes=4 * scan_chunks // max(layers_scan, 1)
                                    * cfg.ssm_head_dim * cfg.ssm_state)
 
@@ -1228,6 +1251,9 @@ class DecoderLM(Estimator, _LMParams):
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_SCAN_CHUNKS, steps * scan_chunks)
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_SCAN_KERNEL_CHUNKS, steps * scan_chunks_kernel)
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_SCAN_LAYERS, steps * layers_scan)
+            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_CONV_POSITIONS, steps * conv_positions)
+            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_CONV_KERNEL_POSITIONS,
+                            steps * conv_positions_kernel)
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_LOOP_TRIPS, steps * cfg.loops)
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_LOOP_LAYER_APPLICATIONS, steps * applications)
         if loads.size:

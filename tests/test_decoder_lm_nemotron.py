@@ -277,6 +277,26 @@ def test_every_chunk_of_the_scan_passes_through_its_kernels(fitted, df):
         2 * program["scan_chunks_kernel"]
 
 
+def test_every_position_of_the_convolution_passes_through_its_kernels(fitted, df):
+    """``train.program``'s ``conv_positions_kernel`` - what the convolution's
+    forward kernels cover, each call's grid cells times its block, added up
+    over the kernel calls of the step as traced - is all of
+    ``conv_positions`` (positions x convolved channels, every Mamba-2 layer),
+    and the registry counts ``steps x`` both. A second fit finds the step
+    traced and reads the same count."""
+    program = fitted[2]["train.program"]
+    channels = CFG.ssm_heads * CFG.ssm_head_dim + 2 * CFG.ssm_groups * CFG.ssm_state
+    assert program["conv_positions_kernel"] == program["conv_positions"] == 4 * BATCH * T * channels > 0
+    counters = (MLMetrics.TRAIN_LM_CONV_POSITIONS, MLMetrics.TRAIN_LM_CONV_KERNEL_POSITIONS)
+    before = [metrics.get(MLMetrics.TRAIN_GROUP, name) for name in counters]
+    with trace.capture() as recorder:
+        _estimator().set_max_iter(2).fit(df)
+    again = {s.name: s.attrs for s in recorder.snapshot()}["train.program"]
+    assert again["conv_positions_kernel"] == program["conv_positions_kernel"] and not again["built"]
+    assert [metrics.get(MLMetrics.TRAIN_GROUP, name) - was for name, was in zip(counters, before)] == \
+        [2 * program["conv_positions"]] * 2
+
+
 def test_the_scan_leaves_start_where_the_published_ranges_say():
     """``dt_bias`` is the inverse softplus of a step size in ``time_step_min ..
     time_step_max`` (log-uniform, floored), ``A_log`` the log of a decay rate

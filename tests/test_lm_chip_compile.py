@@ -290,13 +290,17 @@ def test_the_nemotron_step_program_at_the_cells_shapes(one_chip, monkeypatch):
     layers, one attention layer on 32 query heads over 2 key/value heads, four
     expert layers of 8 held relu² experts beside the shared one). It fits the
     HBM ``fit`` compiles a step into (``decoder_lm.STEP_HBM_MIB``: 15,020 MiB,
-    the 15.75e9 B below): XLA's analysis reads 7.39 GB of temporaries beside
-    8.00 GB of arguments, 15.39e9 B in all, where the step whose scan was
-    ``jax.numpy`` read 7.98 GB told the same and 7.37 GB (15.38e9 B) told
-    nothing. Left to its default XLA stops rematerialising once the step fits
-    the chip and reads more - 8.51 GB here, 16.52e9 B of the chip's 16.91e9 -
-    which the second compile below holds
-    where it was measured. The scan is its two kernels by name: no ``[chunk,
+    the 15.75e9 B below): XLA's analysis reads 7.05 GB of temporaries beside
+    8.00 GB of arguments, 15.06e9 B in all (7.39 GB and 15.39e9 B while the
+    Mamba-2 layers' convolution was a padded copy and four shifted slices, PR
+    41; the step whose scan was ``jax.numpy`` too read 7.98 GB told the same
+    and 7.37 GB, 15.38e9 B, told nothing). Left to its default XLA stops
+    rematerialising once the step fits the chip and reads more - 7.70 GB
+    here, 15.70e9 B of the chip's 16.91e9 (8.51 GB, 16.52e9 B at PR 41) -
+    which the second compile below holds where it was measured. The
+    convolution is its two kernels by name, reading ``x``, ``B`` and ``C``
+    where they lie in the in-projection's output: no padded copy of them is an
+    array of the program. The scan is its two kernels by name: no ``[chunk,
     chunk]`` block of decays of 64 chunks a head is an array of the program,
     nor is a state a POSITION, and the state a chunk starts from is saved once
     a Mamba-2 layer, for the backward; the fold's three kernels take K and V
@@ -305,21 +309,26 @@ def test_the_nemotron_step_program_at_the_cells_shapes(one_chip, monkeypatch):
     forward, two ``dX`` and two ``dW`` a layer at the least), over a window of
     12,288 sorted rows at a time."""
     from flink_ml_tpu.models.lm.config import num_params
-    from flink_ml_tpu.parallel import ssd
+    from flink_ml_tpu.parallel import causal_conv, ssd
 
     c, cfg = _nemotron_cut()
     assert num_params(cfg) == 666_963_456  # 10.67 GB of f32 state at 16 bytes a parameter: 67% of 16 GB
     batch, t = c["global_batch_size"], c["sequence_length"]
-    monkeypatch.setattr(ssd, "_interpreted", lambda: False)  # the backend here is the CPU; the target is the chip
+    for module in (ssd, causal_conv):  # the backend here is the CPU; the target is the chip
+        monkeypatch.setattr(module, "_interpreted", lambda: False)
     compiled, memory = _compiled_step(c, cfg, one_chip)
-    assert memory.temp_size_in_bytes < 7.45e9, memory.temp_size_in_bytes
+    assert memory.temp_size_in_bytes < 7.13e9, memory.temp_size_in_bytes  # what it reads and 1%; 7.45e9 until PR 42
     step, shapes = _step_and_shapes(c, cfg, one_chip)
     unbudgeted = jax.jit(step.__wrapped__, donate_argnums=(0, 1)).lower(*shapes).compile().memory_analysis()
-    assert unbudgeted.argument_size_in_bytes + unbudgeted.temp_size_in_bytes < 16.69e9  # of the chip's 15.75 GiB
+    # of the chip's 15.75 GiB (16.91e9 B): what it reads and 1%; 16.69e9 until PR 42
+    assert unbudgeted.argument_size_in_bytes + unbudgeted.temp_size_in_bytes < 15.86e9
     text = compiled.as_text()
-    for kernel in ("flash_fold_fwd", "flash_fold_bwd_dq", "flash_fold_bwd_dkv", "ssd_scan_fwd", "ssd_scan_bwd"):
+    for kernel in ("flash_fold_fwd", "flash_fold_bwd_dq", "flash_fold_bwd_dkv", "ssd_scan_fwd", "ssd_scan_bwd",
+                   "causal_conv_fwd", "causal_conv_bwd"):
         assert kernel in text
     assert "flash_fold_win_" not in text
+    convolved = cfg.ssm_heads * cfg.ssm_head_dim + 2 * cfg.ssm_groups * cfg.ssm_state
+    assert f"f32[{batch},{t + cfg.conv_kernel - 1},{convolved}]" not in text  # no padded copy of x, B and C
     assert f"bf16[{batch * cfg.kv_heads},{t},{cfg.head_dim}]" in text  # K and V once per key/value head
     assert f"f32[{batch},{cfg.n_heads},{t},{t}]" not in text and f"f32[{batch * cfg.n_heads},{t},{t}]" not in text
     kernels = [ln for ln in text.splitlines() if ln.lstrip().startswith("%ragged-dot") and "custom-call(" in ln]
