@@ -1,77 +1,89 @@
-"""The decoder LM's sizes and its parameter tree, shared by the stage
-(``decoder_lm.py``) and the plain references (``reference.py``,
-``reference_zaya.py``, ``reference_ouro.py``, ``reference_laguna.py``,
-``reference_nemotron.py``) so that one set of weights can be handed to both.
+"""The decoder LM's sizes, what each layer of its stack IS, and its parameter
+tree, shared by the stage (``decoder_lm.py``) and the plain references
+(``reference.py``, ``reference_zaya.py``, ``reference_ouro.py``,
+``reference_laguna.py``, ``reference_nemotron.py``) so that one set of weights
+can be handed to both.
 
-One stack, five kinds of block (``LMConfig.block``). The tree: ``{"embed": [V,
-d], "layers": [layer, ...], "final_norm": [d], "lm_head": [d, V]}``; a tied
-head (``LMConfig.tied``) has no ``lm_head``: the head is ``embed`` transposed;
-the ``ouro`` kind adds the exit gate ``"exit_gate_w": [d, 1], "exit_gate_b":
-[1]`` after them.
-A matrix maps ``x @ W`` (``[in, out]``: the transpose of a ``torch.nn.Linear``
-weight). ``H`` below is the number of experts HELD here (``experts_held``, or
-all ``n_experts``): experts ``first_held .. first_held + H`` of the ``E`` the
-router chooses among.
+``layers(cfg)`` gives one hashable record a layer (``Layer``): a MIXER, a
+FEED-FORWARD (either may be absent), the stream's width and the norms'
+epsilon, and how a sublayer joins the residual: ``x + y``, or (``scaled``) a
+learned per-channel scale and bias on both. The parameter tree
+(``param_shapes``), the forward (``decoder_lm._layer``), the fit's counts and
+the stage's checks all read that record and nothing else about a layer.
 
-``olmoe``: ``layer = {"attn_norm": [d], "wq"/"wk"/"wv"/"wo": [d, d],
-"q_norm"/"k_norm": [d], "ffn_norm": [d], "router": [d, E], "w_gate"/"w_up":
-[H, d, h], "w_down": [H, h, d]}``.
+``LMConfig.block`` names one of five PRESETS over that description
+(``_PRESETS``: the only place that names a model), each a published stack:
 
-``zaya`` (compressed convolutional attention and an MLP router; the equations
-are in ``reference_zaya.py``), with ``a = n_heads * head_dim`` the attention
-latent, ``c = n_kv_heads * head_dim``, ``g = n_heads + n_kv_heads`` and ``r =
-router_width``: per sublayer ``s`` in ``attn``, ``ffn`` the norm ``s_norm [d]``
-and the residual scaling ``s_res_scale``, ``s_res_bias``, ``s_out_scale``,
-``s_out_bias`` ``[d]``; ``"wq": [d, a], "wk": [d, c], "wv1"/"wv2": [d,
-head_dim], "conv0_w": [2, a + c], "conv0_b": [a + c], "conv1_w": [2, g,
-head_dim, head_dim], "conv1_b": [g, head_dim], "k_temp": [n_kv_heads], "wo":
-[a, d]``; ``"router_in": [d, r], "router_gamma": [r]`` (layers past the first),
-``"router_norm": [r], "router_w1"/"router_w2": [r, r], "router_w3": [r, E]``;
-the experts as above.
+========== ================================================= ==========================================
+kind       mixer                                             feed-forward
+========== ================================================= ==========================================
+olmoe      attention, QK-norm, RoPE on the whole head        experts, linear router
+zaya       CCA, RoPE on part of each head; ``scaled`` joins  experts, MLP router with the carried state
+ouro       attention, RoPE, a norm on the output             dense, a norm on the output
+laguna     attention at ``layer_heads[i]`` heads with head   dense (the first ``n_dense`` layers), then
+           gates; windowed (``layer_windows[i]``) with RoPE  experts with sigmoid gates beside a shared
+           at ``window_rope_theta``, or full with part of    expert
+           each head turned under YaRN
+nemotron_h ONE sublayer a layer behind the norm ``norm``, by ``layer_kinds[i]``: ``M`` a Mamba-2 scan,
+           ``*`` attention without rotation, ``E`` ungated experts with sigmoid gates beside a shared one
+========== ================================================= ==========================================
 
-``ouro`` (a dense sandwich-norm layer, run ``loops`` times over the same
-leaves; the equations are in ``reference_ouro.py``), with ``a = n_heads *
-head_dim`` and ``h = expert_width`` the width of the one dense SwiGLU:
-``"attn_norm": [d], "wq"/"wk"/"wv": [d, a], "wo": [a, d], "attn_out_norm":
-[d], "ffn_norm": [d], "w_gate"/"w_up": [d, h], "w_down": [h, d],
-"ffn_out_norm": [d]``. It has no experts: ``n_experts`` and ``top_k`` are 0.
+The tree: ``{"embed": [V, d], "layers": [layer, ...], "final_norm": [d],
+"lm_head": [d, V]}``; a tied head (``LMConfig.tied``) has no ``lm_head``: the
+head is ``embed`` transposed; a stack whose passes end in an exit gate
+(``exit_gate``: ``ouro``, run ``loops`` times over the same leaves) adds
+``"exit_gate_w": [d, 1], "exit_gate_b": [1]`` after them. A matrix maps ``x @
+W`` (``[in, out]``: the transpose of a ``torch.nn.Linear`` weight).
 
-``laguna`` (layers that differ inside one stack; the equations are in
-``reference_laguna.py``): layer ``i`` has ``H_i = layer_heads[i]`` query heads
-on ``n_kv_heads`` key/value heads, ``a_i = H_i * head_dim``, ``c = n_kv_heads *
-head_dim``: ``"attn_norm": [d], "wq": [d, a_i], "wk"/"wv": [d, c],
-"head_gate": [d, H_i], "wo": [a_i, d], "ffn_norm": [d]``; then, in the first
-``n_dense`` layers, one dense SwiGLU ``"w_gate"/"w_up": [d, dense_width],
-"w_down": [dense_width, d]``, and in the others ``"router": [d, E],
-"router_bias": [E]`` (added to the scores where the experts are CHOSEN and
-nowhere else: no gradient reaches it), the shared expert ``"shared_gate"/
-"shared_up": [d, shared_width], "shared_down": [shared_width, d]`` and the
-held experts as above. Which layers attend through a sliding window
-(``layer_windows[i]`` keys, 0: full attention) changes no leaf.
+A layer's leaves (``leaves``), mixer first: each sublayer's norm ``[d]`` (its
+name is the record's: ``attn_norm``, ``ffn_norm``; ``norm`` in ``nemotron_h``),
+then under ``scaled`` joins ``s_res_scale``, ``s_res_bias``, ``s_out_scale``,
+``s_out_bias`` ``[d]`` (``s`` = ``attn``, ``ffn``), then the sublayer's own.
+The sublayers, with ``a = heads * head_dim`` and ``c = kv_heads * head_dim``:
 
-``nemotron_h`` (a layer is ONE mixer behind one norm, its kind
-``layer_kinds[i]``; the equations are in ``reference_nemotron.py``): every
-layer has ``"norm": [d]``; then a Mamba-2 layer (``M``), with ``i = ssm_heads *
-ssm_head_dim`` its inner width and ``c = i + 2 * ssm_groups * ssm_state`` the
-convolved channels: ``"in_proj": [d, i + c + ssm_heads]`` (gate ``z``, then
-``x``, ``B``, ``C``, then the step size), ``"conv_w": [conv_kernel, c]`` (tap
-``conv_kernel - 1`` reads the position itself), ``"conv_b": [c]``,
-``"dt_bias"``, ``"A_log"``, ``"D"``: ``[ssm_heads]``, ``"gate_norm": [i]``,
-``"out_proj": [i, d]``; an attention layer (``*``), ``n_heads`` query heads on
-``n_kv_heads`` of ``head_dim``: ``"wq": [d, a], "wk"/"wv": [d, c], "wo": [a,
-d]``; an expert layer (``E``): ``"router": [d, E], "router_bias": [E]`` (as
-``laguna``'s), the shared expert ``"shared_up": [d, shared_width],
-"shared_down": [shared_width, d]`` and the held experts ``"w_up": [H, d, h],
-"w_down": [H, h, d]``: two matrices an expert, ``down(relu(up(x))^2)``.
+- ``Attention`` (causal softmax attention of ``heads`` query heads on
+  ``kv_heads`` key/value heads through the fused fold; a ``Rotation`` of q and
+  k or none; a sliding ``window`` of keys or 0): ``"wq": [d, a], "wk"/"wv":
+  [d, c]``, ``"head_gate": [d, heads]`` under a sigmoid gate a head on the
+  output, ``"wo": [a, d]``, ``"q_norm": [a], "k_norm": [c]`` under a QK-norm, the
+  output's norm ``[d]`` if any (``attn_out_norm``).
+- ``CCA`` (ZAYA's compressed convolutional attention; ``reference_zaya.py`` has
+  the equations), ``g = heads + kv_heads``: ``"wq": [d, a], "wk": [d, c],
+  "wv1"/"wv2": [d, head_dim], "conv0_w": [2, a + c], "conv0_b": [a + c],
+  "conv1_w": [2, g, head_dim, head_dim], "conv1_b": [g, head_dim], "k_temp":
+  [kv_heads], "wo": [a, d]``.
+- ``Mamba2`` (a selective state-space scan behind a short causal convolution;
+  ``reference_nemotron.py``), ``i = heads * head_dim`` its inner width and ``c
+  = i + 2 * groups * state`` the convolved channels: ``"in_proj": [d, i + c +
+  heads]`` (gate ``z``, then ``x``, ``B``, ``C``, then the step size),
+  ``"conv_w": [conv_kernel, c]`` (tap ``conv_kernel - 1`` reads the position
+  itself), ``"conv_b": [c]``, ``"dt_bias"``, ``"A_log"``, ``"D"``: ``[heads]``,
+  ``"gate_norm": [i]``, ``"out_proj": [i, d]``.
+- ``Dense`` (one SwiGLU): ``"w_gate"/"w_up": [d, width], "w_down": [width,
+  d]``, the output's norm ``[d]`` if any (``ffn_out_norm``).
+- ``Experts`` (``top_k`` of ``E`` routed experts, ``H`` of them HELD here:
+  experts ``first_held .. first_held + H``; ``LMConfig.experts_held``, or all):
+  the router ``"router": [d, E]`` or, an MLP of width ``r`` whose hidden state
+  a layer hands the next, ``"router_in": [d, r], "router_gamma": [r]`` (where
+  there is a layer before), ``"router_norm": [r], "router_w1"/"router_w2": [r,
+  r], "router_w3": [r, E]``; the softmax probabilities of the chosen are kept
+  as they are or, under ``routed_scale``, sigmoid gates renormalised and scaled
+  with ``"router_bias": [E]`` added to the scores where the experts are CHOSEN
+  and nowhere else (no gradient reaches it); a shared expert every token
+  passes, ``"shared_gate"/"shared_up": [d, s], "shared_down": [s, d]``; the held
+  ones ``"w_gate"/"w_up": [H, d, h], "w_down": [H, h, d]``. Ungated, an expert
+  is ``down(relu(up(x))^2)``: the ``_gate`` matrices are not there.
 """
 from __future__ import annotations
 
-from typing import List, NamedTuple, Tuple
+import dataclasses
+import math
+from typing import List, NamedTuple, Optional, Tuple, Union
 
-__all__ = ["LMConfig", "BLOCKS", "MIXERS", "param_shapes", "num_params", "ONES", "ZEROS", "NORMAL", "SMALL",
+__all__ = ["LMConfig", "BLOCKS", "MIXERS", "Rotation", "Attention", "CCA", "Mamba2", "Dense", "Experts", "Layer",
+           "layers", "exit_gate", "leaves", "param_shapes", "num_params", "ONES", "ZEROS", "NORMAL", "SMALL",
            "SMALL_SCALE", "DT_BIAS", "A_LOG", "DT_RANGE", "DT_FLOOR", "A_RANGE"]
 
-BLOCKS = ("olmoe", "zaya", "ouro", "laguna", "nemotron_h")
 #: The ``nemotron_h`` stack's layer kinds, as its published pattern spells them:
 #: a Mamba-2 scan, attention without a position encoding, relu² experts.
 MIXERS = ("M", "*", "E")
@@ -156,94 +168,208 @@ class LMConfig(NamedTuple):
         return self.experts_held or self.n_experts
 
 
-def _expert_leaves(cfg: LMConfig):
-    d, e, h = cfg.hidden, cfg.held, cfg.expert_width
-    return (("w_gate", (e, d, h), NORMAL), ("w_up", (e, d, h), NORMAL), ("w_down", (e, h, d), NORMAL))
+# -- what a layer is ------------------------------------------------------------------
 
 
-def _olmoe_leaves(cfg: LMConfig, i: int):
-    d = cfg.hidden
-    return (
-        ("attn_norm", (d,), ONES), ("wq", (d, d), NORMAL), ("wk", (d, d), NORMAL), ("wv", (d, d), NORMAL),
-        ("wo", (d, d), NORMAL), ("q_norm", (d,), ONES), ("k_norm", (d,), ONES), ("ffn_norm", (d,), ONES),
-        ("router", (d, cfg.n_experts), NORMAL),
-    ) + _expert_leaves(cfg)
+@dataclasses.dataclass(frozen=True)
+class Rotation:
+    """RoPE on the first ``channels`` of each head (all of them: the whole
+    head) at base ``theta``, stretched by YaRN (``yarn``: factor, original
+    length, beta_fast, beta_slow, attention factor) or, empty, not."""
+    channels: int
+    theta: float
+    yarn: Tuple[float, ...] = ()
 
 
-def _residual_scaling(sub: str, d: int):
-    return ((f"{sub}_res_scale", (d,), ONES), (f"{sub}_res_bias", (d,), ZEROS),
-            (f"{sub}_out_scale", (d,), ONES), (f"{sub}_out_bias", (d,), ZEROS))
+@dataclasses.dataclass(frozen=True)
+class Attention:
+    heads: int
+    kv_heads: int
+    head_dim: int
+    rotation: Optional[Rotation] = None
+    window: int = 0  # keys each query keeps, its own among them; 0: all before it
+    qk_norm: bool = False
+    head_gate: bool = False
+    norm: str = "attn_norm"
+    out_norm: str = ""  # the leaf of the norm on the output; empty: none
 
 
-def _zaya_leaves(cfg: LMConfig, i: int):
-    d, hd, r = cfg.hidden, cfg.head_dim, cfg.router_width
-    a, c, g = cfg.n_heads * hd, cfg.kv_heads * hd, cfg.n_heads + cfg.kv_heads
-    gamma = (("router_gamma", (r,), ZEROS),) if i else ()  # the first layer has no layer before it
-    return (
-        (("attn_norm", (d,), ONES),) + _residual_scaling("attn", d) + (
-            ("wq", (d, a), NORMAL), ("wk", (d, c), NORMAL), ("wv1", (d, hd), NORMAL), ("wv2", (d, hd), NORMAL),
+@dataclasses.dataclass(frozen=True)
+class CCA:
+    heads: int
+    kv_heads: int
+    head_dim: int
+    rotation: Rotation
+    norm: str = "attn_norm"
+
+
+@dataclasses.dataclass(frozen=True)
+class Mamba2:
+    heads: int
+    head_dim: int
+    groups: int  # of heads that share one B and C, and of the gated norm
+    state: int
+    conv_kernel: int
+    chunk: int
+    norm: str = "norm"
+
+
+@dataclasses.dataclass(frozen=True)
+class Dense:
+    width: int
+    norm: str = "ffn_norm"
+    out_norm: str = ""
+
+
+@dataclasses.dataclass(frozen=True)
+class Experts:
+    n_experts: int
+    held: int
+    first_held: int
+    top_k: int
+    width: int
+    router_width: int = 0  # 0: a linear router; else the width of the MLP that routes
+    carried: bool = False  # the layer before hands the router its hidden state
+    routed_scale: float = 0.0  # 0: softmax probabilities kept as they are; else sigmoid gates renormalised, times this
+    gated: bool = True  # SwiGLU experts; ungated: down(relu(up(x))^2)
+    shared_width: Optional[int] = None  # None: no shared expert
+    norm: str = "ffn_norm"
+
+
+@dataclasses.dataclass(frozen=True)
+class Layer:
+    hidden: int
+    eps: float
+    mixer: Union[Attention, CCA, Mamba2, None]
+    ffn: Union[Dense, Experts, None]
+    scaled: bool = False  # a learned scale and bias on the residual and on each sublayer's output
+
+
+def _experts(cfg: LMConfig, **own) -> Experts:
+    return Experts(cfg.n_experts, cfg.held, cfg.first_held, cfg.top_k, cfg.expert_width, **own)
+
+
+def _olmoe(cfg: LMConfig):
+    mixer = Attention(cfg.n_heads, cfg.kv_heads, cfg.head_dim, Rotation(cfg.head_dim, cfg.rope_theta), qk_norm=True)
+    return (Layer(cfg.hidden, cfg.norm_eps, mixer, _experts(cfg)),) * cfg.n_layers
+
+
+def _zaya(cfg: LMConfig):
+    mixer = CCA(cfg.n_heads, cfg.kv_heads, cfg.head_dim,
+                Rotation(int(cfg.head_dim * cfg.rope_fraction), cfg.rope_theta))
+    return tuple(Layer(cfg.hidden, cfg.norm_eps, mixer,  # the first layer has no layer before it
+                       _experts(cfg, router_width=cfg.router_width, carried=i > 0), scaled=True)
+                 for i in range(cfg.n_layers))
+
+
+def _ouro(cfg: LMConfig):
+    mixer = Attention(cfg.n_heads, cfg.kv_heads, cfg.head_dim, Rotation(cfg.head_dim, cfg.rope_theta),
+                      out_norm="attn_out_norm")
+    return (Layer(cfg.hidden, cfg.norm_eps, mixer, Dense(cfg.expert_width, out_norm="ffn_out_norm")),) * cfg.n_layers
+
+
+def _laguna(cfg: LMConfig):
+    # the heads differ from layer to layer, so their size is the stated one (0: not stated)
+    full = Rotation(int(cfg.head_size * cfg.rope_fraction), cfg.rope_theta, cfg.yarn)
+    windowed = Rotation(cfg.head_size, cfg.window_rope_theta)
+    sparse = _experts(cfg, routed_scale=cfg.routed_scale, shared_width=cfg.shared_width)
+    return tuple(
+        Layer(cfg.hidden, cfg.norm_eps,
+              Attention(cfg.layer_heads[i], cfg.kv_heads, cfg.head_size,
+                        windowed if cfg.layer_windows[i] else full, cfg.layer_windows[i], head_gate=True),
+              Dense(cfg.dense_width) if i < cfg.n_dense else sparse)
+        for i in range(cfg.n_layers))
+
+
+def _nemotron_h(cfg: LMConfig):
+    by_letter = {  # MIXERS' letters: (mixer, feed-forward), one of them
+        "M": (Mamba2(cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state, cfg.conv_kernel, cfg.chunk), None),
+        "*": (Attention(cfg.n_heads, cfg.kv_heads, cfg.head_size, norm="norm"), None),
+        "E": (None, _experts(cfg, routed_scale=cfg.routed_scale, gated=False, shared_width=cfg.shared_width,
+                             norm="norm"))}
+    return tuple(Layer(cfg.hidden, cfg.norm_eps, *by_letter[cfg.layer_kinds[i]]) for i in range(cfg.n_layers))
+
+
+#: kind -> (its layers, whether every pass of the stack ends in an exit gate: a linear with a bias)
+_PRESETS = {"olmoe": (_olmoe, False), "zaya": (_zaya, False), "ouro": (_ouro, True), "laguna": (_laguna, False),
+            "nemotron_h": (_nemotron_h, False)}
+BLOCKS = tuple(_PRESETS)
+
+
+def layers(cfg: LMConfig) -> Tuple[Layer, ...]:
+    """What each layer of the stack is, first to last."""
+    return _PRESETS[cfg.block][0](cfg)
+
+
+def exit_gate(cfg: LMConfig) -> bool:
+    return _PRESETS[cfg.block][1]
+
+
+# -- the parameter tree ------------------------------------------------------------------
+
+
+def _matrices(prefix: str, lead: tuple, d: int, h: int, gated: bool):
+    """One feed-forward's matrices ``[d, h]``, ``[d, h]``, ``[h, d]`` (ungated: no ``gate``), ``lead`` of them."""
+    return tuple((f"{prefix}_{name}", lead + ((h, d) if name == "down" else (d, h)), NORMAL)
+                 for name in ("gate", "up", "down") if gated or name != "gate")
+
+
+def _attention_own(m: Attention, d: int):
+    a, c = m.heads * m.head_dim, m.kv_heads * m.head_dim
+    return ((("wq", (d, a), NORMAL), ("wk", (d, c), NORMAL), ("wv", (d, c), NORMAL))
+            + ((("head_gate", (d, m.heads), NORMAL),) if m.head_gate else ()) + (("wo", (a, d), NORMAL),)
+            + ((("q_norm", (a,), ONES), ("k_norm", (c,), ONES)) if m.qk_norm else ())
+            + (((m.out_norm, (d,), ONES),) if m.out_norm else ()))
+
+
+def _cca_own(m: CCA, d: int):
+    hd = m.head_dim
+    a, c, g = m.heads * hd, m.kv_heads * hd, m.heads + m.kv_heads
+    return (("wq", (d, a), NORMAL), ("wk", (d, c), NORMAL), ("wv1", (d, hd), NORMAL), ("wv2", (d, hd), NORMAL),
             ("conv0_w", (2, a + c), NORMAL), ("conv0_b", (a + c,), ZEROS),
             ("conv1_w", (2, g, hd, hd), NORMAL), ("conv1_b", (g, hd), ZEROS),
-            ("k_temp", (cfg.kv_heads,), ONES), ("wo", (a, d), SMALL),
-            ("ffn_norm", (d,), ONES),
-        ) + _residual_scaling("ffn", d) + (("router_in", (d, r), NORMAL),) + gamma + (
+            ("k_temp", (m.kv_heads,), ONES), ("wo", (a, d), SMALL))
+
+
+def _mamba2_own(m: Mamba2, d: int):
+    inner = m.heads * m.head_dim
+    conv = inner + 2 * m.groups * m.state
+    return (("in_proj", (d, inner + conv + m.heads), NORMAL), ("conv_w", (m.conv_kernel, conv), NORMAL),
+            ("conv_b", (conv,), ZEROS), ("dt_bias", (m.heads,), DT_BIAS), ("A_log", (m.heads,), A_LOG),
+            ("D", (m.heads,), ONES), ("gate_norm", (inner,), ONES), ("out_proj", (inner, d), NORMAL))
+
+
+def _dense_own(f: Dense, d: int):
+    return _matrices("w", (), d, f.width, True) + (((f.out_norm, (d,), ONES),) if f.out_norm else ())
+
+
+def _experts_own(f: Experts, d: int):
+    r = f.router_width
+    if r:
+        router = ((("router_in", (d, r), NORMAL),) + ((("router_gamma", (r,), ZEROS),) if f.carried else ()) + (
             ("router_norm", (r,), ONES), ("router_w1", (r, r), NORMAL), ("router_w2", (r, r), NORMAL),
-            ("router_w3", (r, cfg.n_experts), NORMAL),
-        ) + _expert_leaves(cfg)
-    )
+            ("router_w3", (r, f.n_experts), NORMAL)))
+    else:
+        router = (("router", (d, f.n_experts), NORMAL),)
+    return (router + ((("router_bias", (f.n_experts,), ZEROS),) if f.routed_scale else ())
+            + (() if f.shared_width is None else _matrices("shared", (), d, f.shared_width, f.gated))
+            + _matrices("w", (f.held,), d, f.width, f.gated))
 
 
-def _ouro_leaves(cfg: LMConfig, i: int):
-    d, a, h = cfg.hidden, cfg.n_heads * cfg.head_dim, cfg.expert_width
-    return (
-        ("attn_norm", (d,), ONES), ("wq", (d, a), NORMAL), ("wk", (d, a), NORMAL), ("wv", (d, a), NORMAL),
-        ("wo", (a, d), NORMAL), ("attn_out_norm", (d,), ONES), ("ffn_norm", (d,), ONES),
-        ("w_gate", (d, h), NORMAL), ("w_up", (d, h), NORMAL), ("w_down", (h, d), NORMAL),
-        ("ffn_out_norm", (d,), ONES),
-    )
+_OWN_LEAVES = {Attention: _attention_own, CCA: _cca_own, Mamba2: _mamba2_own, Dense: _dense_own,
+               Experts: _experts_own}
 
 
-def _laguna_leaves(cfg: LMConfig, i: int):
-    d, hd, heads = cfg.hidden, cfg.head_dim, cfg.layer_heads[i]
-    a, c = heads * hd, cfg.kv_heads * hd
-    attention = (
-        ("attn_norm", (d,), ONES), ("wq", (d, a), NORMAL), ("wk", (d, c), NORMAL), ("wv", (d, c), NORMAL),
-        ("head_gate", (d, heads), NORMAL), ("wo", (a, d), NORMAL), ("ffn_norm", (d,), ONES),
-    )
-    if i < cfg.n_dense:
-        h = cfg.dense_width
-        return attention + (("w_gate", (d, h), NORMAL), ("w_up", (d, h), NORMAL), ("w_down", (h, d), NORMAL))
-    s = cfg.shared_width
-    return attention + (
-        ("router", (d, cfg.n_experts), NORMAL), ("router_bias", (cfg.n_experts,), ZEROS),
-        ("shared_gate", (d, s), NORMAL), ("shared_up", (d, s), NORMAL), ("shared_down", (s, d), NORMAL),
-    ) + _expert_leaves(cfg)
-
-
-def _nemotron_leaves(cfg: LMConfig, i: int):
-    d, kind = cfg.hidden, cfg.layer_kinds[i]
-    norm = (("norm", (d,), ONES),)
-    if kind == "M":
-        heads, inner = cfg.ssm_heads, cfg.ssm_heads * cfg.ssm_head_dim
-        conv = inner + 2 * cfg.ssm_groups * cfg.ssm_state
-        return norm + (
-            ("in_proj", (d, inner + conv + heads), NORMAL), ("conv_w", (cfg.conv_kernel, conv), NORMAL),
-            ("conv_b", (conv,), ZEROS), ("dt_bias", (heads,), DT_BIAS), ("A_log", (heads,), A_LOG),
-            ("D", (heads,), ONES), ("gate_norm", (inner,), ONES), ("out_proj", (inner, d), NORMAL),
-        )
-    if kind == "*":
-        a, c = cfg.n_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
-        return norm + (("wq", (d, a), NORMAL), ("wk", (d, c), NORMAL), ("wv", (d, c), NORMAL), ("wo", (a, d), NORMAL))
-    e, h, s = cfg.held, cfg.expert_width, cfg.shared_width
-    return norm + (
-        ("router", (d, cfg.n_experts), NORMAL), ("router_bias", (cfg.n_experts,), ZEROS),
-        ("shared_up", (d, s), NORMAL), ("shared_down", (s, d), NORMAL),
-        ("w_up", (e, d, h), NORMAL), ("w_down", (e, h, d), NORMAL),
-    )
-
-
-_LEAVES = {"olmoe": _olmoe_leaves, "zaya": _zaya_leaves, "ouro": _ouro_leaves, "laguna": _laguna_leaves,
-           "nemotron_h": _nemotron_leaves}
+def leaves(layer: Layer):
+    """One layer's leaves ``(name, shape, init)`` in the tree's order: the mixer's, then the feed-forward's; of each
+    its norm, under ``scaled`` joins the residual scaling, then its own."""
+    d, out = layer.hidden, ()
+    for sub, part in (("attn", layer.mixer), ("ffn", layer.ffn)):
+        if part is not None:
+            scaling = tuple((f"{sub}_{name}", (d,), init) for name, init in (
+                ("res_scale", ONES), ("res_bias", ZEROS), ("out_scale", ONES), ("out_bias", ZEROS)))
+            out += ((part.norm, (d,), ONES),) + (scaling if layer.scaled else ()) + _OWN_LEAVES[type(part)](part, d)
+    return out
 
 
 def param_shapes(cfg: LMConfig) -> List[Tuple[tuple, tuple, str]]:
@@ -251,21 +377,15 @@ def param_shapes(cfg: LMConfig) -> List[Tuple[tuple, tuple, str]]:
     numbers them by. ``path`` indexes the tree: ``("layers", 0, "wq")``;
     ``init`` is ``ONES``, ``ZEROS``, ``NORMAL``, ``SMALL``, ``DT_BIAS`` or ``A_LOG``."""
     out = [(("embed",), (cfg.vocab, cfg.hidden), NORMAL)]
-    for i in range(cfg.n_layers):
-        out += [(("layers", i, name), shape, init) for name, shape, init in _LEAVES[cfg.block](cfg, i)]
+    for i, layer in enumerate(layers(cfg)):
+        out += [(("layers", i, name), shape, init) for name, shape, init in leaves(layer)]
     out.append((("final_norm",), (cfg.hidden,), ONES))
     if not cfg.tied:
         out.append((("lm_head",), (cfg.hidden, cfg.vocab), NORMAL))
-    if cfg.block == "ouro":  # the exit gate every pass ends in: a linear with a bias
+    if exit_gate(cfg):
         out += [(("exit_gate_w",), (cfg.hidden, 1), NORMAL), (("exit_gate_b",), (1,), ZEROS)]
     return out
 
 
 def num_params(cfg: LMConfig) -> int:
-    total = 0
-    for _, shape, _ in param_shapes(cfg):
-        n = 1
-        for s in shape:
-            n *= s
-        total += n
-    return total
+    return sum(math.prod(shape) for _, shape, _ in param_shapes(cfg))

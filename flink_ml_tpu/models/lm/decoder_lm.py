@@ -1,47 +1,27 @@
 """``DecoderLM`` - a decoder-only language-model stage that trains through
-``Estimator.fit``: a stack of pre-norm residual blocks of causal
-self-attention (the fused fold of ``parallel/flash.py`` forward and backward)
-and a dropless mixture of SwiGLU experts (``parallel/moe.py``), a head,
-next-token cross-entropy. ``blockKind`` chooses the block; the fit loop, the
-head, the loss's chunking, the clip and the AdamW program are one:
-
-- ``olmoe`` (OLMoE's; ``reference.py`` carries each equation's origin):
-  multi-head attention with QK-norm and RoPE, a linear router with top-k
-  probabilities kept as they are, the router's load-balancing loss.
-- ``zaya`` (ZAYA1-8B's; ``reference_zaya.py``): compressed convolutional
-  attention - queries, keys and values in a latent of ``numHeads`` query heads
-  on 2 key/value heads, q and k mixed by two short causal convolutions, RoPE
-  on part of each head, the fold on grouped queries - and a router that is an
-  MLP whose hidden state each block hands the next; learned residual scaling.
-- ``ouro`` (Ouro-2.6B's looped LM; ``reference_ouro.py``): a dense layer with
-  a norm before and after each sublayer (multi-head attention with RoPE, one
-  SwiGLU), the whole stack run ``numLoops`` times over the same leaves; every
-  pass ends in the final norm and an exit gate, the head reads every pass,
-  and the loss weights the passes' cross-entropies token by token with the
-  exit distribution the gates give, less ``exitEntropyCoef`` times its
-  entropy. ``transform`` scores the last pass.
-- ``laguna`` (Laguna-XS.2's; ``reference_laguna.py``): layers that differ
-  inside one stack. Layer ``i`` has ``numHeadsPerLayer[i]`` query heads on
-  ``numKvHeads`` key/value heads and attends causally, or through a sliding
-  window of ``windowPerLayer[i]`` keys (the fold skips the key chunks below
-  the window); windowed layers turn every channel by RoPE at
-  ``windowRopeTheta``, full layers ``ropeFraction`` of them at ``ropeTheta``
-  stretched by YaRN (``ropeYarn``); a sigmoid gate per head on the attention
-  output; the first ``denseLayers`` layers feed forward through one dense
-  SwiGLU, the others through sigmoid-gated experts (the chosen
-  ``expertsPerToken`` renormalised and scaled by ``routedScale``) beside a
-  shared expert every token passes.
-- ``nemotron_h`` (Nemotron-3-Nano's hybrid stack; ``reference_nemotron.py``):
-  a layer is ONE mixer behind one norm, ``x + mixer(norm(x))``, its kind the
-  layer's letter in ``layerPattern``: ``M`` a Mamba-2 layer (one projection
-  into a gate, the scan's ``x``, ``B``, ``C`` and step sizes; a short causal
-  depthwise convolution and SiLU over ``x``, ``B``, ``C``, a kernel pair that
-  reads them where they lie in the projection, ``parallel/causal_conv.py``;
-  the selective state-space scan in chunks, ``parallel/ssd.py``; a grouped
-  RMSNorm gated by ``silu(z)``; one projection back), ``*`` causal attention
-  on grouped queries
-  with NO position encoding, ``E`` sigmoid-gated experts as ``laguna``'s
-  beside a shared one, each ``down(relu(up(x))^2)`` on two matrices.
+``Estimator.fit``: an embedding, a stack of pre-norm residual layers, a head,
+next-token cross-entropy. What a layer IS is one record (``config.Layer``, from
+``config.layers``): a mixer - causal self-attention through the fused fold of
+``parallel/flash.py`` with the rotation, window, QK-norm, head gate and output
+norm its record names (``_attend``); ZAYA's compressed convolutional attention
+(``_cca``); a Mamba-2 scan behind its convolution (``_mamba2``:
+``parallel/causal_conv.py``, ``parallel/ssd.py``) - and a feed-forward - one
+dense SwiGLU or a dropless mixture of experts, ``parallel/moe.py``
+(``_feed_forward``) - either of which may be absent, each joined to the
+residual stream (``_layer``). ``blockKind`` names one of five presets over that
+description, each a published stack with its plain reference beside it
+(``config.py`` has the table and every leaf): ``olmoe`` (``reference.py``),
+``zaya`` (ZAYA1-8B, ``reference_zaya.py``), ``ouro`` (Ouro-2.6B's looped LM,
+``reference_ouro.py``: the whole stack run ``numLoops`` times over the same
+leaves; every pass ends in the final norm and an exit gate, the head reads
+every pass, and the loss weights the passes' cross-entropies token by token
+with the exit distribution the gates give, less ``exitEntropyCoef`` times its
+entropy; ``transform`` scores the last pass), ``laguna`` (Laguna-XS.2,
+``reference_laguna.py``: layers that differ inside one stack, by
+``numHeadsPerLayer``, ``windowPerLayer`` and ``denseLayers``) and ``nemotron_h``
+(Nemotron-3-Nano's hybrid stack, ``reference_nemotron.py``: a layer is ONE
+sublayer behind one norm, its kind the layer's letter in ``layerPattern``). The
+fit loop, the head, the loss's chunking, the clip and the AdamW program are one.
 
 Any expert kind may tie the head to the embedding (``tieEmbeddings``: one leaf)
 and hold a range of each block's experts (``expertsHeld``,
@@ -85,15 +65,11 @@ On the TPU the step is compiled into a stated size (``STEP_HBM_MIB``): XLA
 rematerialises further, toward arguments and temporaries that fit it.
 
 Names: the step program's parts carry ``jax.named_scope``s (``lm.embed``,
-``lm.block`` with ``norm``, ``proj``, ``rope``, ``conv``, ``fold``, ``mix``,
-``ffn``, ``gate``, ``shared``, a Mamba-2 layer's ``scan`` and ``gnorm`` (its
-convolution's kernels are ``conv/causal_conv_fwd`` and ``_bwd``, its scan's
-``scan/ssd_scan_fwd`` and ``_bwd``), and the experts' ``route``, ``permute``,
-``experts`` under it,
-``lm.final_norm``, ``lm.head``, ``lm.exit``, ``lm.aux``, ``lm.opt``), which
-reach each device operation's name beside what JAX's transformations write
-there, so a profile tells the parts, and forward from recomputed from
-backward, apart (docs/observability.md, "The step's scopes"). They are
+``lm.block`` with each sublayer's parts under it, ``lm.final_norm``,
+``lm.head``, ``lm.exit``, ``lm.aux``, ``lm.opt``; docs/observability.md, "The
+step's scopes", has every path and what opens it), which reach each device
+operation's name beside what JAX's transformations write there, so a profile
+tells the parts, and forward from recomputed from backward, apart. They are
 trace-time metadata: the jaxpr and the compiled program are what they were.
 
 The fitted model keeps its parameters on the device; ``save`` and
@@ -115,8 +91,8 @@ from flink_ml_tpu.api.core import Estimator, Model
 from flink_ml_tpu.api.types import DataTypes
 from flink_ml_tpu.metrics import MLMetrics, metrics
 from flink_ml_tpu.models.lm.config import (
-    A_LOG, A_RANGE, BLOCKS, DT_BIAS, DT_FLOOR, DT_RANGE, MIXERS, NORMAL, ONES, SMALL, SMALL_SCALE, LMConfig,
-    num_params, param_shapes,
+    A_LOG, A_RANGE, BLOCKS, CCA, DT_BIAS, DT_FLOOR, DT_RANGE, MIXERS, NORMAL, ONES, SMALL, SMALL_SCALE, Attention, Dense,
+    Experts, Layer, LMConfig, Mamba2, exit_gate, layers, num_params, param_shapes,
 )
 from flink_ml_tpu.params.param import (
     BoolParam,
@@ -124,6 +100,7 @@ from flink_ml_tpu.params.param import (
     FloatParam,
     IntArrayParam,
     IntParam,
+    Param,
     ParamValidators,
     StringParam,
     update_existing_params,
@@ -282,138 +259,115 @@ class _LMParams(
         ParamValidators.in_array(["float32", "bfloat16"]),
     )
 
+    #: The ``LMConfig`` fields that not every kind reads, by the kinds each belongs to, with the param it comes from.
+    #: The other kinds leave it at ``LMConfig``'s default (no experts; no balancing loss: a bias rule outside the
+    #: gradient balances every kind but 'olmoe', reference_zaya.py).
+    _OWN = {
+        ("olmoe", "zaya", "laguna", "nemotron_h"): (("n_experts", NUM_EXPERTS), ("top_k", EXPERTS_PER_TOKEN)),
+        ("olmoe",): (("aux_coef", AUX_LOSS_COEF),),
+        ("zaya", "laguna", "nemotron_h"): (("n_kv_heads", NUM_KV_HEADS), ("head_size", HEAD_SIZE)),
+        ("zaya", "laguna"): (("rope_fraction", ROPE_FRACTION),),
+        ("zaya",): (("router_width", ROUTER_WIDTH),),
+        ("ouro",): (("loops", NUM_LOOPS), ("exit_beta", EXIT_ENTROPY_COEF)),
+        ("laguna",): (("layer_heads", NUM_HEADS_PER_LAYER), ("layer_windows", WINDOW_PER_LAYER),
+                      ("n_dense", DENSE_LAYERS), ("dense_width", DENSE_WIDTH),
+                      ("window_rope_theta", WINDOW_ROPE_THETA), ("yarn", ROPE_YARN)),
+        ("laguna", "nemotron_h"): (("shared_width", SHARED_EXPERT_WIDTH), ("routed_scale", ROUTED_SCALE)),
+        ("nemotron_h",): (("layer_kinds", LAYER_PATTERN), ("ssm_heads", SSM_NUM_HEADS), ("ssm_head_dim", SSM_HEAD_SIZE),
+                          ("ssm_groups", SSM_NUM_GROUPS), ("ssm_state", SSM_STATE_SIZE),
+                          ("conv_kernel", SSM_CONV_KERNEL), ("chunk", SSM_CHUNK_SIZE)),
+    }
+    #: The params refused where they are given a value under a kind they do not belong to.
+    _REFUSED = (
+        ((NUM_KV_HEADS, HEAD_SIZE, ROPE_FRACTION), ("zaya", "laguna", "nemotron_h"),
+         "numKvHeads, headSize and ropeFraction belong to blockKind 'zaya', 'laguna' or 'nemotron_h'"),
+        ((NUM_HEADS_PER_LAYER, WINDOW_PER_LAYER, DENSE_LAYERS), ("laguna",),
+         "numHeadsPerLayer, windowPerLayer and denseLayers belong to blockKind 'laguna'"),
+        ((SHARED_EXPERT_WIDTH,), ("laguna", "nemotron_h"), "sharedExpertWidth belongs to blockKind 'laguna' or 'nemotron_h'"),
+        ((LAYER_PATTERN, SSM_NUM_HEADS), ("nemotron_h",), "layerPattern and the ssm sizes belong to blockKind 'nemotron_h'"),
+        ((NUM_LOOPS,), ("ouro",), "numLoops belongs to blockKind 'ouro'"),
+        ((TIE_EMBEDDINGS, EXPERTS_HELD, FIRST_EXPERT_HELD), ("olmoe", "zaya", "laguna", "nemotron_h"),
+         "tieEmbeddings, expertsHeld and firstExpertHeld do not belong to blockKind 'ouro'"),
+        ((TIE_EMBEDDINGS,), ("olmoe", "zaya", "ouro", "laguna"), "tieEmbeddings does not belong to blockKind 'nemotron_h'"),
+    )
+
     def lm_config(self, vocab: Optional[int] = None) -> LMConfig:
-        cfg = LMConfig(
-            n_layers=self.get(self.NUM_LAYERS), hidden=self.get(self.HIDDEN_SIZE),
-            n_heads=self.get(self.NUM_HEADS), n_experts=self.get(self.NUM_EXPERTS),
-            top_k=self.get(self.EXPERTS_PER_TOKEN), expert_width=self.get(self.EXPERT_WIDTH),
-            vocab=self.get(self.VOCAB_SIZE) if vocab is None else vocab,
-            rope_theta=self.get(self.ROPE_THETA), norm_eps=self.get(self.NORM_EPS),
-            aux_coef=self.get(self.AUX_LOSS_COEF), block=self.get(self.BLOCK_KIND),
-            tied=self.get(self.TIE_EMBEDDINGS), experts_held=self.get(self.EXPERTS_HELD),
-            first_held=self.get(self.FIRST_EXPERT_HELD),
-        )
-        if cfg.block == "zaya":
-            cfg = cfg._replace(
-                n_kv_heads=self.get(self.NUM_KV_HEADS), head_size=self.get(self.HEAD_SIZE),
-                rope_fraction=self.get(self.ROPE_FRACTION), router_width=self.get(self.ROUTER_WIDTH),
-                aux_coef=0.0,  # its balancing is a bias rule outside the gradient (reference_zaya.py)
-            )
-            if cfg.kv_heads != 2 or cfg.n_heads % 2:
-                raise ValueError(f"the zaya block's value shift makes two key/value heads (one of this "
-                                 f"position, one of the position before) under an even numHeads; got "
-                                 f"numKvHeads {cfg.kv_heads}, numHeads {cfg.n_heads}")
-            if int(cfg.head_dim * cfg.rope_fraction) % 2:
-                raise ValueError(f"the rotary embedding needs an even number of channels, got "
-                                 f"{cfg.rope_fraction} of {cfg.head_dim}")
-        elif cfg.block == "laguna":
-            cfg = cfg._replace(
-                n_kv_heads=self.get(self.NUM_KV_HEADS), head_size=self.get(self.HEAD_SIZE),
-                rope_fraction=self.get(self.ROPE_FRACTION), aux_coef=0.0,  # balanced by a bias rule, as 'zaya'
-                layer_heads=tuple(self.get(self.NUM_HEADS_PER_LAYER)),
-                layer_windows=tuple(self.get(self.WINDOW_PER_LAYER)),
-                n_dense=self.get(self.DENSE_LAYERS), dense_width=self.get(self.DENSE_WIDTH),
-                shared_width=self.get(self.SHARED_EXPERT_WIDTH), routed_scale=self.get(self.ROUTED_SCALE),
-                window_rope_theta=self.get(self.WINDOW_ROPE_THETA), yarn=tuple(self.get(self.ROPE_YARN)),
-            )
-            if not len(cfg.layer_heads) == len(cfg.layer_windows) == cfg.n_layers:
-                raise ValueError(f"numHeadsPerLayer ({len(cfg.layer_heads)}) and windowPerLayer "
-                                 f"({len(cfg.layer_windows)}) name each of the {cfg.n_layers} layers")
-            if not cfg.head_size or any(h <= 0 or h % cfg.kv_heads for h in cfg.layer_heads):
-                raise ValueError(f"every layer's query heads {cfg.layer_heads} divide evenly over numKvHeads "
-                                 f"{cfg.kv_heads}, at a stated headSize")
-            if min(cfg.layer_windows) < 0 or cfg.n_dense > cfg.n_layers:
-                raise ValueError(f"windowPerLayer {cfg.layer_windows} counts keys and denseLayers {cfg.n_dense} "
-                                 f"is at most numLayers {cfg.n_layers}")
-            if (cfg.n_dense and not cfg.dense_width) or (cfg.n_dense < cfg.n_layers and not cfg.shared_width):
-                raise ValueError("a dense layer needs denseWidth and an expert layer sharedExpertWidth")
-            if int(cfg.head_dim * cfg.rope_fraction) % 2 or len(cfg.yarn) not in (0, 5):
-                raise ValueError(f"the rotary embedding needs an even number of channels, got {cfg.rope_fraction} "
-                                 f"of {cfg.head_dim}; ropeYarn has five numbers or none, got {len(cfg.yarn)}")
-        elif cfg.block == "nemotron_h":
-            cfg = cfg._replace(
-                n_kv_heads=self.get(self.NUM_KV_HEADS), head_size=self.get(self.HEAD_SIZE), aux_coef=0.0,
-                shared_width=self.get(self.SHARED_EXPERT_WIDTH), routed_scale=self.get(self.ROUTED_SCALE),
-                layer_kinds=tuple(self.get(self.LAYER_PATTERN)), ssm_heads=self.get(self.SSM_NUM_HEADS),
-                ssm_head_dim=self.get(self.SSM_HEAD_SIZE), ssm_groups=self.get(self.SSM_NUM_GROUPS),
-                ssm_state=self.get(self.SSM_STATE_SIZE), conv_kernel=self.get(self.SSM_CONV_KERNEL),
-                chunk=self.get(self.SSM_CHUNK_SIZE),
-            )
-            if len(cfg.layer_kinds) != cfg.n_layers or set(cfg.layer_kinds) - set(MIXERS):
-                raise ValueError(f"layerPattern names each of the {cfg.n_layers} layers by one of {MIXERS}; "
-                                 f"got {self.get(self.LAYER_PATTERN)!r}")
-            if "M" in cfg.layer_kinds and not (
-                    cfg.ssm_heads and cfg.ssm_head_dim and cfg.ssm_state and cfg.ssm_groups
-                    and cfg.ssm_heads % cfg.ssm_groups == 0):
-                raise ValueError(f"a Mamba-2 layer needs ssmNumHeads ({cfg.ssm_heads}) in whole ssmNumGroups "
-                                 f"({cfg.ssm_groups}), ssmHeadSize ({cfg.ssm_head_dim}) and ssmStateSize "
-                                 f"({cfg.ssm_state})")
-            if "*" in cfg.layer_kinds and (not cfg.head_size or cfg.n_heads % cfg.kv_heads):
-                raise ValueError(f"an attention layer's numHeads {cfg.n_heads} divide evenly over numKvHeads "
-                                 f"{cfg.kv_heads}, at a stated headSize")
-            if "E" in cfg.layer_kinds and not cfg.shared_width:
-                raise ValueError("an expert layer needs sharedExpertWidth")
-            if cfg.tied:
-                raise ValueError("tieEmbeddings does not belong to blockKind 'nemotron_h'")
-        elif self.get(self.NUM_KV_HEADS) or self.get(self.HEAD_SIZE) or self.get(self.ROPE_FRACTION) != 1.0:
-            raise ValueError("numKvHeads, headSize and ropeFraction belong to blockKind 'zaya', 'laguna' or "
-                             "'nemotron_h'")
-        if cfg.block != "laguna" and (self.get(self.NUM_HEADS_PER_LAYER) or self.get(self.WINDOW_PER_LAYER)
-                                      or self.get(self.DENSE_LAYERS)):
-            raise ValueError("numHeadsPerLayer, windowPerLayer and denseLayers belong to blockKind 'laguna'")
-        if cfg.block not in ("laguna", "nemotron_h") and self.get(self.SHARED_EXPERT_WIDTH):
-            raise ValueError("sharedExpertWidth belongs to blockKind 'laguna' or 'nemotron_h'")
-        if cfg.block != "nemotron_h" and (self.get(self.LAYER_PATTERN) or self.get(self.SSM_NUM_HEADS)):
-            raise ValueError("layerPattern and the ssm sizes belong to blockKind 'nemotron_h'")
-        if cfg.block == "ouro":  # a dense block: no experts, no router, nothing to balance
-            cfg = cfg._replace(n_experts=0, top_k=0, aux_coef=0.0, loops=self.get(self.NUM_LOOPS),
-                               exit_beta=self.get(self.EXIT_ENTROPY_COEF))
-            if cfg.tied or cfg.experts_held or cfg.first_held:
-                raise ValueError("tieEmbeddings, expertsHeld and firstExpertHeld do not belong to blockKind 'ouro'")
-        elif self.get(self.NUM_LOOPS) != 1:
-            raise ValueError("numLoops belongs to blockKind 'ouro'")
+        kind = self.get(self.BLOCK_KIND)
+        for params, kinds, message in self._REFUSED:
+            if kind not in kinds and any(self.get(p) != p.default_value for p in params):
+                raise ValueError(message)
+        own = [pair for kinds, pairs in self._OWN.items() if kind in kinds for pair in pairs]
+        values = {field: self.get(p) for field, p in own}
+        cfg = LMConfig(**{
+            "n_layers": self.get(self.NUM_LAYERS), "hidden": self.get(self.HIDDEN_SIZE),
+            "n_heads": self.get(self.NUM_HEADS), "n_experts": 0, "top_k": 0,
+            "expert_width": self.get(self.EXPERT_WIDTH), "vocab": self.get(self.VOCAB_SIZE) if vocab is None else vocab,
+            "rope_theta": self.get(self.ROPE_THETA), "norm_eps": self.get(self.NORM_EPS), "aux_coef": 0.0,
+            "block": kind, "tied": self.get(self.TIE_EMBEDDINGS), "experts_held": self.get(self.EXPERTS_HELD),
+            "first_held": self.get(self.FIRST_EXPERT_HELD),
+            **{field: tuple(v) if isinstance(v, (list, str)) else v for field, v in values.items()}})
+        # the stack: every per-layer param names every layer, in letters the stack knows
+        per_layer = [(p.name, len(values[field])) for field, p in own if field.startswith("layer_")]
+        if any(n != cfg.n_layers for _, n in per_layer):
+            raise ValueError(" and ".join(f"{name} ({n})" for name, n in per_layer)
+                             + f" name{'s' * (len(per_layer) == 1)} each of the {cfg.n_layers} layers")
+        if set(cfg.layer_kinds) - set(MIXERS):
+            raise ValueError(f"layerPattern names each of the {cfg.n_layers} layers by one of {MIXERS}; got "
+                             f"{''.join(cfg.layer_kinds)!r}")
+        if cfg.n_dense > cfg.n_layers:
+            raise ValueError(f"denseLayers {cfg.n_dense} is at most numLayers {cfg.n_layers}")
         if not cfg.head_size and cfg.hidden % cfg.n_heads:
             raise ValueError(f"hiddenSize {cfg.hidden} must divide evenly by numHeads {cfg.n_heads}")
-        if cfg.head_dim % 2:
-            raise ValueError(f"the rotary embedding needs an even head size, got {cfg.head_dim}")
-        if cfg.top_k > cfg.n_experts:
-            raise ValueError(f"expertsPerToken {cfg.top_k} > numExperts {cfg.n_experts}")
-        if cfg.first_held + cfg.held > cfg.n_experts:
-            raise ValueError(f"experts {cfg.first_held}..{cfg.first_held + cfg.held} are not among "
-                             f"numExperts {cfg.n_experts}")
+        for spec in dict.fromkeys(layers(cfg)):  # each distinct layer once: its mixer, then its feed-forward
+            _check_mixer(spec.mixer)
+            _check_feed_forward(spec.ffn)
         return cfg
 
-    def get_compute_type(self) -> str:
-        return self.get(self.COMPUTE_TYPE)
 
-    def set_compute_type(self, value: str):
-        return self.set(self.COMPUTE_TYPE, value)
+def _check_mixer(m) -> None:
+    """Refuse sizes the mixer cannot run at, in the stage params' names."""
+    if isinstance(m, Attention) and (m.head_dim <= 0 or m.heads <= 0 or m.heads % m.kv_heads):
+        raise ValueError(f"an attention layer's query heads ({m.heads}) divide evenly over numKvHeads {m.kv_heads}, at a "
+                         f"stated headSize")
+    if isinstance(m, Attention) and m.window < 0:
+        raise ValueError(f"windowPerLayer counts keys, got {m.window}")
+    if isinstance(m, CCA) and (m.kv_heads != 2 or m.heads % 2):
+        raise ValueError(f"compressed convolutional attention's value shift makes two key/value heads (one of this "
+                         f"position, one of the position before) under an even numHeads; got numKvHeads "
+                         f"{m.kv_heads}, numHeads {m.heads}")
+    if isinstance(m, Mamba2) and not (m.heads and m.head_dim and m.state and m.groups and m.heads % m.groups == 0):
+        raise ValueError(f"a Mamba-2 layer needs ssmNumHeads ({m.heads}) in whole ssmNumGroups ({m.groups}), "
+                         f"ssmHeadSize ({m.head_dim}) and ssmStateSize ({m.state})")
+    rotation = getattr(m, "rotation", None)
+    if rotation is not None and rotation.channels % 2:
+        raise ValueError(f"the rotary embedding turns an even number of channels, got {rotation.channels} of "
+                         f"{m.head_dim}")
+    if rotation is not None and len(rotation.yarn) not in (0, 5):
+        raise ValueError(f"ropeYarn has five numbers or none, got {len(rotation.yarn)}")
 
 
-def _add_accessors(cls, names) -> None:
-    """``get_x``/``set_x`` for each architecture param, as every stage spells them."""
-    for attr, snake in names:
-        param = getattr(cls, attr)
-        setattr(cls, f"get_{snake}", lambda self, p=param: self.get(p))
-        setattr(cls, f"set_{snake}", lambda self, value, p=param: self.set(p, value))
+def _check_feed_forward(f) -> None:
+    if isinstance(f, Dense) and not f.width:
+        raise ValueError("a dense layer needs denseWidth")
+    if isinstance(f, Experts):
+        if f.shared_width == 0:
+            raise ValueError("an expert layer beside a shared expert needs sharedExpertWidth")
+        if f.top_k > f.n_experts:
+            raise ValueError(f"expertsPerToken {f.top_k} > numExperts {f.n_experts}")
+        if f.first_held + f.held > f.n_experts:
+            raise ValueError(f"experts {f.first_held}..{f.first_held + f.held} are not among numExperts {f.n_experts}")
 
 
-_add_accessors(_LMParams, (
-    ("NUM_LAYERS", "num_layers"), ("HIDDEN_SIZE", "hidden_size"), ("NUM_HEADS", "num_heads"),
-    ("NUM_EXPERTS", "num_experts"), ("EXPERTS_PER_TOKEN", "experts_per_token"),
-    ("EXPERT_WIDTH", "expert_width"), ("VOCAB_SIZE", "vocab_size"), ("ROPE_THETA", "rope_theta"),
-    ("NORM_EPS", "norm_eps"), ("AUX_LOSS_COEF", "aux_loss_coef"), ("BLOCK_KIND", "block_kind"),
-    ("TIE_EMBEDDINGS", "tie_embeddings"), ("EXPERTS_HELD", "experts_held"),
-    ("FIRST_EXPERT_HELD", "first_expert_held"), ("NUM_KV_HEADS", "num_kv_heads"), ("HEAD_SIZE", "head_size"),
-    ("ROPE_FRACTION", "rope_fraction"), ("ROUTER_WIDTH", "router_width"), ("NUM_LOOPS", "num_loops"),
-    ("EXIT_ENTROPY_COEF", "exit_entropy_coef"), ("NUM_HEADS_PER_LAYER", "num_heads_per_layer"),
-    ("WINDOW_PER_LAYER", "window_per_layer"), ("DENSE_LAYERS", "dense_layers"), ("DENSE_WIDTH", "dense_width"),
-    ("SHARED_EXPERT_WIDTH", "shared_expert_width"), ("ROUTED_SCALE", "routed_scale"),
-    ("WINDOW_ROPE_THETA", "window_rope_theta"), ("ROPE_YARN", "rope_yarn"), ("LAYER_PATTERN", "layer_pattern"),
-    ("SSM_NUM_HEADS", "ssm_num_heads"), ("SSM_HEAD_SIZE", "ssm_head_size"), ("SSM_NUM_GROUPS", "ssm_num_groups"),
-    ("SSM_STATE_SIZE", "ssm_state_size"), ("SSM_CONV_KERNEL", "ssm_conv_kernel"),
-    ("SSM_CHUNK_SIZE", "ssm_chunk_size"),
-))
+def _add_accessors(cls) -> None:
+    """``get_x``/``set_x`` for each param the class declares (``NUM_LAYERS``: ``get_num_layers``), as every stage
+    spells them."""
+    for attr, param in list(vars(cls).items()):
+        if isinstance(param, Param):
+            setattr(cls, f"get_{attr.lower()}", lambda self, p=param: self.get(p))
+            setattr(cls, f"set_{attr.lower()}", lambda self, value, p=param: self.set(p, value))
+
+
+_add_accessors(_LMParams)
 
 
 # -- parameters ----------------------------------------------------------------
@@ -538,31 +492,7 @@ def _merged(o):
         return jnp.transpose(o, (0, 2, 1, 3)).reshape(o.shape[0], o.shape[2], -1)
 
 
-def _attention(x, layer, cfg: LMConfig, cd, interpret: bool):
-    t = x.shape[1]
-    h, hd = cfg.n_heads, cfg.head_dim
-    a = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-    q = _rms_norm(_proj(a, layer["wq"], cd), layer["q_norm"], cfg.norm_eps)
-    k = _rms_norm(_proj(a, layer["wk"], cd), layer["k_norm"], cfg.norm_eps)
-    v = _proj(a, layer["wv"], cd)
-    cos, sin = _rope_tables(t, hd, cfg.rope_theta)
-    o = _fold(_rope(_heads(q, h), cos, sin), _rope(_heads(k, h), cos, sin), _heads(v, h), cd, interpret)
-    return _proj(_merged(o), layer["wo"], cd)
-
-
-def _olmoe_block(x, carry, layer, cfg: LMConfig, cd, interpret: bool):
-    b, t, d = x.shape
-    a = _attention(x, layer, cfg, cd, interpret)
-    with jax.named_scope("mix"):
-        x = x + a
-    u = _rms_norm(x, layer["ffn_norm"], cfg.norm_eps).reshape(b * t, d)
-    y, stats = moe_dropless(u, layer["router"], layer["w_gate"], layer["w_up"], layer["w_down"],
-                            cfg.top_k, cd, cfg.first_held)
-    with jax.named_scope("mix"):
-        return x + y.reshape(b, t, d), carry, stats
-
-
-# -- the zaya block (reference_zaya.py carries each equation's origin) ----------
+# -- the mixers and the feed-forward (the references carry each equation's origin) --
 
 
 def _before(z):
@@ -576,10 +506,12 @@ def _unit_heads(z, eps):
 
 
 def _rope_part(x, cos, sin):
-    """RoPE on the first ``cos.shape[-1]`` channels of each head of ``x [B, H, T, D]``."""
+    """RoPE on the first ``cos.shape[-1]`` channels of each head of ``x [B, H, T, D]``: none, some or all."""
     rot = cos.shape[-1]
     if rot == 0:
         return x
+    if rot == x.shape[-1]:
+        return _rope(x, cos, sin)
     with jax.named_scope("rope"):
         turned = x[..., :rot]
     turned = _rope(turned, cos, sin)
@@ -587,15 +519,15 @@ def _rope_part(x, cos, sin):
         return jnp.concatenate([turned, x[..., rot:]], axis=-1)
 
 
-def _cca(x, layer, cfg: LMConfig, cd, interpret: bool):
+def _cca(x, layer, m: CCA, eps: float, cd, interpret: bool):
     """Compressed convolutional attention: queries, keys and values projected
-    into a latent of ``n_heads`` (``kv_heads``) heads, q and k mixed there by
+    into a latent of ``heads`` (``kv_heads``) heads, q and k mixed there by
     two short causal convolutions, attention and its output in the latent, one
     projection back."""
     b, t, _ = x.shape
-    h, kv, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    h, kv, hd = m.heads, m.kv_heads, m.head_dim
     group = h // kv
-    a = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+    a = _rms_norm(x, layer[m.norm], eps)
     q0 = _proj(a, layer["wq"], cd).reshape(b, t, kv, group, hd)
     k0 = _proj(a, layer["wk"], cd).reshape(b, t, kv, 1, hd)
     with jax.named_scope("conv"):
@@ -615,14 +547,14 @@ def _cca(x, layer, cfg: LMConfig, cd, interpret: bool):
         q = z2[:, :, :h] + ((q0 + k0) / 2).reshape(b, t, h, hd)
         k = z2[:, :, h:] + ((jnp.mean(q0, axis=3, keepdims=True) + k0) / 2).reshape(b, t, kv, hd)
     with jax.named_scope("norm"):
-        q = _unit_heads(q, cfg.norm_eps)
-        k = _unit_heads(k, cfg.norm_eps) * layer["k_temp"][:, None]
+        q = _unit_heads(q, eps)
+        k = _unit_heads(k, eps) * layer["k_temp"][:, None]
     v1 = _proj(a, layer["wv1"], cd)
     with jax.named_scope("mix"):
         earlier = _before(a)  # the value shift
     with jax.named_scope("proj"):
         v = jnp.stack([v1, _matmul(earlier, layer["wv2"], cd)], axis=2)
-    cos, sin = _rope_tables(t, int(hd * cfg.rope_fraction), cfg.rope_theta)
+    cos, sin = _yarn_tables(t, m.rotation.channels, m.rotation.theta, m.rotation.yarn)
     o = _fold(_rope_part(_heads(q, h), cos, sin), _rope_part(_heads(k, kv), cos, sin), _heads(v, kv),
               cd, interpret)
     return _proj(_merged(o), layer["wo"], cd)
@@ -651,43 +583,6 @@ def _scaled(x, y, layer, sub: str):
                 + layer[f"{sub}_out_scale"] * y + layer[f"{sub}_out_bias"])
 
 
-def _zaya_block(x, carry, layer, cfg: LMConfig, cd, interpret: bool):
-    b, t, d = x.shape
-    x = _scaled(x, _cca(x, layer, cfg, cd, interpret), layer, "attn")
-    u = _rms_norm(x, layer["ffn_norm"], cfg.norm_eps).reshape(b * t, d)
-    carry = _router_state(u, layer, carry)  # handed on to the next block's router
-    y, stats = moe_dropless(u, lambda _: _router_logits(carry, layer, cfg.norm_eps), layer["w_gate"],
-                            layer["w_up"], layer["w_down"], cfg.top_k, cd, cfg.first_held)
-    return _scaled(x, y.reshape(b, t, d), layer, "ffn"), carry, stats
-
-
-# -- the ouro block (reference_ouro.py carries each equation's origin) -----------
-
-
-def _ouro_block(x, carry, layer, cfg: LMConfig, cd, interpret: bool):
-    """A dense layer in sandwich norms: a norm before each sublayer and one on
-    its output, inside the residual branch. No experts: no statistics."""
-    t = x.shape[1]
-    h = cfg.n_heads
-    a = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-    cos, sin = _rope_tables(t, cfg.head_dim, cfg.rope_theta)
-    q, k, v = (_heads(_proj(a, layer[w], cd), h) for w in ("wq", "wk", "wv"))
-    o = _fold(_rope(q, cos, sin), _rope(k, cos, sin), v, cd, interpret)
-    o = _rms_norm(_proj(_merged(o), layer["wo"], cd), layer["attn_out_norm"], cfg.norm_eps)
-    with jax.named_scope("mix"):
-        x = x + o
-    m = _rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
-    with jax.named_scope("ffn"):
-        y = _matmul(jax.nn.silu(_matmul(m, layer["w_gate"], cd)) * _matmul(m, layer["w_up"], cd),
-                    layer["w_down"], cd)
-    y = _rms_norm(y, layer["ffn_out_norm"], cfg.norm_eps)
-    with jax.named_scope("mix"):
-        return x + y, carry, {}
-
-
-# -- the laguna block (reference_laguna.py carries each equation's origin) --------
-
-
 def _yarn_tables(t: int, rot: int, theta: float, yarn):
     """``cos, sin [T, rot]`` of RoPE at base ``theta`` stretched by YaRN
     (``yarn = (factor, original length, beta_fast, beta_slow, attention
@@ -714,131 +609,118 @@ def _yarn_tables(t: int, rot: int, theta: float, yarn):
         return jnp.float32(attention_factor) * jnp.cos(emb), jnp.float32(attention_factor) * jnp.sin(emb)
 
 
-def _laguna_block(x, carry, layer, cfg: LMConfig, cd, interpret: bool, window: int):
-    """One layer of a stack whose layers differ: as many query heads as its
-    leaves say, on the shared key/value heads; full (``window`` 0) or windowed
-    attention with the position encoding that goes with it; a sigmoid gate per
-    head on the attention output; then one dense SwiGLU (a leading layer: it
-    has no router) or sigmoid-gated experts beside a shared one."""
-    b, t, d = x.shape
-    heads, kv, hd = layer["head_gate"].shape[1], cfg.kv_heads, cfg.head_dim
-    a = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-    q, k, v = (_heads(_proj(a, layer[w], cd), n) for w, n in (("wq", heads), ("wk", kv), ("wv", kv)))
-    if window:
-        cos, sin = _rope_tables(t, hd, cfg.window_rope_theta)
-        q, k = _rope(q, cos, sin), _rope(k, cos, sin)
-    else:
-        cos, sin = _yarn_tables(t, int(hd * cfg.rope_fraction), cfg.rope_theta, cfg.yarn)
-        q, k = _rope_part(q, cos, sin), _rope_part(k, cos, sin)
-    o = _fold(q, k, v, cd, interpret, window or None)
-    with jax.named_scope("gate"):
-        g = jax.nn.sigmoid(_matmul(a, layer["head_gate"], cd))  # [B, T, H]
-        o = o * jnp.transpose(g, (0, 2, 1))[..., None]
-    o = _proj(_merged(o), layer["wo"], cd)
-    with jax.named_scope("mix"):
-        x = x + o
-    u = _rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
-    if "router" not in layer:  # a leading dense layer
-        with jax.named_scope("ffn"):
-            y, stats = dense_swiglu(u, layer["w_gate"], layer["w_up"], layer["w_down"], cd), {}
-    else:
-        u = u.reshape(b * t, d)
-        y, stats = moe_dropless(u, layer["router"], layer["w_gate"], layer["w_up"], layer["w_down"], cfg.top_k,
-                                cd, cfg.first_held, cfg.routed_scale, layer["router_bias"])
-        with jax.named_scope("shared"):
-            y = (y + dense_swiglu(u, layer["shared_gate"], layer["shared_up"], layer["shared_down"], cd)
-                 ).reshape(b, t, d)
-    with jax.named_scope("mix"):
-        return x + y, carry, stats
-
-
-# -- the nemotron_h block (reference_nemotron.py carries each equation's origin) --
-
-
-def _mamba_block(x, carry, layer, cfg: LMConfig, cd, interpret: bool):
+def _mamba2(x, layer, m: Mamba2, eps: float, cd, interpret: bool):
     """A Mamba-2 layer: one projection into the gate ``z``, the scan's ``x``,
     ``B``, ``C`` and the step sizes; a causal depthwise convolution and SiLU
     over ``x``, ``B``, ``C``; the selective scan in chunks; the grouped
     RMSNorm of ``y * silu(z)``; one projection back. The step sizes, the decay
     rates and the scan's state are float32 whatever ``cd`` is."""
     b, t, _ = x.shape
-    heads, p, groups, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
+    heads, p, groups, n = m.heads, m.head_dim, m.groups, m.state
     inner, bc = heads * p, groups * n
-    u = _proj(_rms_norm(x, layer["norm"], cfg.norm_eps), layer["in_proj"], cd)
+    u = _proj(_rms_norm(x, layer[m.norm], eps), layer["in_proj"], cd)
     with jax.named_scope("conv"):
         # the convolution's kernels read x, B and C where they lie in u and write them activated as arrays of their
         # own, as the scan's kernels take them; the gate and the step sizes leave u beside them (parallel/causal_conv.py)
         z, (xs, bs, cs), dt = causal_conv(u, layer["conv_w"], layer["conv_b"], (inner, bc, bc), first=inner)
         xs, bs, cs = xs.reshape(b, t, heads, p), bs.reshape(b, t, groups, n), cs.reshape(b, t, groups, n)
     with jax.named_scope("scan"):
-        y = ssd_scan(xs, jax.nn.softplus(dt + layer["dt_bias"]), -jnp.exp(layer["A_log"]), bs, cs, cfg.chunk, cd)
+        y = ssd_scan(xs, jax.nn.softplus(dt + layer["dt_bias"]), -jnp.exp(layer["A_log"]), bs, cs, m.chunk, cd)
         y = y + layer["D"][:, None] * xs
     with jax.named_scope("gnorm"):
         y = (y.reshape(b, t, inner) * jax.nn.silu(z)).reshape(b, t, groups, inner // groups)
-        y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + cfg.norm_eps)
+        y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + eps)
         y = y.reshape(b, t, inner) * layer["gate_norm"]
-    y = _proj(y, layer["out_proj"], cd)
-    with jax.named_scope("mix"):
-        return x + y, carry, {}
+    return _proj(y, layer["out_proj"], cd)
 
 
-def _nope_attention_block(x, carry, layer, cfg: LMConfig, cd, interpret: bool):
-    """Causal attention on grouped queries with no position encoding."""
-    a = _rms_norm(x, layer["norm"], cfg.norm_eps)
-    q, k, v = (_heads(_proj(a, layer[w], cd), n)
-               for w, n in (("wq", cfg.n_heads), ("wk", cfg.kv_heads), ("wv", cfg.kv_heads)))
-    o = _proj(_merged(_fold(q, k, v, cd, interpret)), layer["wo"], cd)
-    with jax.named_scope("mix"):
-        return x + o, carry, {}
+def _attend(x, layer, m: Attention, eps: float, cd, interpret: bool):
+    """Causal attention on (grouped) queries through the fused fold, with what
+    the record asks for around it: a QK-norm on the projections, a rotation of
+    q and k, a window, a sigmoid gate per head on the output, a norm on the
+    projection back."""
+    a = _rms_norm(x, layer[m.norm], eps)
+
+    def head(w: str, n: int, norm: str):
+        z = _proj(a, layer[w], cd)
+        return _heads(_rms_norm(z, layer[norm], eps) if m.qk_norm and norm else z, n)
+
+    q, k, v = head("wq", m.heads, "q_norm"), head("wk", m.kv_heads, "k_norm"), head("wv", m.kv_heads, "")
+    if m.rotation is not None:
+        cos, sin = _yarn_tables(x.shape[1], m.rotation.channels, m.rotation.theta, m.rotation.yarn)
+        q, k = _rope_part(q, cos, sin), _rope_part(k, cos, sin)
+    o = _fold(q, k, v, cd, interpret, m.window or None)
+    if m.head_gate:
+        with jax.named_scope("gate"):
+            g = jax.nn.sigmoid(_matmul(a, layer["head_gate"], cd))  # [B, T, H]
+            o = o * jnp.transpose(g, (0, 2, 1))[..., None]
+    o = _proj(_merged(o), layer["wo"], cd)
+    return _rms_norm(o, layer[m.out_norm], eps) if m.out_norm else o
 
 
-def _relu2_experts_block(x, carry, layer, cfg: LMConfig, cd, interpret: bool):
-    """Sigmoid-gated experts (chosen with the selection bias, renormalised,
-    scaled) beside a shared one, each ``down(relu(up(u))^2)``."""
+_MIX = {Attention: _attend, CCA: _cca, Mamba2: _mamba2}
+
+
+def _feed_forward(x, carry, layer, f, eps: float, cd):
+    """``(y, carry, stats)``: one dense SwiGLU (no statistics), or the routed
+    experts' part of the result (``parallel/moe.py``) plus the shared expert's
+    where there is one. An MLP router's hidden state is the ``carry`` handed
+    to the next layer's router."""
     b, t, d = x.shape
-    u = _rms_norm(x, layer["norm"], cfg.norm_eps).reshape(b * t, d)
-    y, stats = moe_dropless(u, layer["router"], None, layer["w_up"], layer["w_down"], cfg.top_k, cd,
-                            cfg.first_held, cfg.routed_scale, layer["router_bias"])
-    with jax.named_scope("shared"):
-        y = (y + dense_swiglu(u, None, layer["shared_up"], layer["shared_down"], cd)).reshape(b, t, d)
-    with jax.named_scope("mix"):
-        return x + y, carry, stats
+    u = _rms_norm(x, layer[f.norm], eps)
+    if isinstance(f, Dense):
+        with jax.named_scope("ffn"):
+            y = dense_swiglu(u, layer["w_gate"], layer["w_up"], layer["w_down"], cd)
+        return (_rms_norm(y, layer[f.out_norm], eps) if f.out_norm else y), carry, {}
+    u = u.reshape(b * t, d)
+    if f.router_width:
+        carry = _router_state(u, layer, carry)
+        router = lambda _: _router_logits(carry, layer, eps)  # noqa: E731
+    else:
+        router = layer["router"]
+    y, stats = moe_dropless(u, router, layer["w_gate"] if f.gated else None, layer["w_up"], layer["w_down"], f.top_k,
+                            cd, f.first_held, f.routed_scale, layer["router_bias"] if f.routed_scale else None)
+    if f.shared_width is not None:
+        with jax.named_scope("shared"):
+            y = y + dense_swiglu(u, layer["shared_gate"] if f.gated else None, layer["shared_up"],
+                                 layer["shared_down"], cd)
+    return y.reshape(b, t, d), carry, stats
 
 
-_BLOCKS = {"olmoe": _olmoe_block, "zaya": _zaya_block, "ouro": _ouro_block}
-_MIXERS = {"M": _mamba_block, "*": _nope_attention_block, "E": _relu2_experts_block}
+def _layer(x, carry, layer, spec: Layer, cd, interpret: bool):
+    """One layer of any stack, as its record (``config.layers``) says: the
+    mixer, then the feed-forward, whichever are there, each joined to the
+    residual stream. ``carry`` is what a layer hands the next beside the
+    stream (an MLP router's hidden state); the statistics are the experts'."""
+    def join(x, y, sub: str):
+        if spec.scaled:
+            return _scaled(x, y, layer, sub)
+        with jax.named_scope("mix"):
+            return x + y
 
-
-def _layer_blocks(cfg: LMConfig) -> list:
-    """Each layer's block function ``(x, carry, layer, cfg, cd, interpret)``.
-    A stack that repeats one block names ONE function (its layers then trace
-    to one shared sub-program where their leaves agree); the laguna stack one
-    for each window among its layers, the nemotron_h stack one for each kind
-    of mixer."""
-    if cfg.block == "nemotron_h":
-        return [_MIXERS[kind] for kind in cfg.layer_kinds]
-    if cfg.block != "laguna":
-        return [_BLOCKS[cfg.block]] * cfg.n_layers
-    by_window = {w: functools.partial(_laguna_block, window=w) for w in set(cfg.layer_windows)}
-    return [by_window[w] for w in cfg.layer_windows]
+    stats = {}
+    if spec.mixer is not None:
+        x = join(x, _MIX[type(spec.mixer)](x, layer, spec.mixer, spec.eps, cd, interpret), "attn")
+    if spec.ffn is not None:
+        y, carry, stats = _feed_forward(x, carry, layer, spec.ffn, spec.eps, cd)
+        x = join(x, y, "ffn")
+    return x, carry, stats
 
 
 def _hidden(params, tok, cfg: LMConfig, cd, interpret: bool):
-    """The final-normed hidden states, each block's router statistics and the
-    exit gate's logits. ``carry`` is what a block hands the next beside the
-    residual stream: nothing (``olmoe``, ``ouro``), the router's hidden state
-    (``zaya``). A tree without an exit gate passes its stack once: ``[B, T,
-    d]``, no logits. With one, the stack runs ``cfg.loops`` times over the
-    same leaves, each pass's normed state feeding the next: ``[R, B, T, d]``
-    and the gate's logits ``[R, B, T]``."""
+    """The final-normed hidden states, each expert layer's router statistics
+    and the exit gate's logits. A stack without an exit gate passes once:
+    ``[B, T, d]``, no logits. With one, the stack runs ``cfg.loops`` times over
+    the same leaves, each pass's normed state feeding the next: ``[R, B, T,
+    d]`` and the gate's logits ``[R, B, T]``."""
     with jax.named_scope("lm.embed"):
         x = params["embed"][tok]
 
     @functools.cache
-    def scoped(kind):
+    def scoped(spec):  # layers of one record trace to one shared sub-program where their leaves agree
         def block(x, carry, layer):  # the scope opens inside what is rematerialised
             with jax.named_scope("lm.block"):
-                return kind(x, carry, layer, cfg, cd, interpret)
+                return _layer(x, carry, layer, spec, cd, interpret)
 
         # a lone block's residuals are wanted as soon as the head's backward
         # ends: holding them costs nothing at the peak, recomputing them a forward
@@ -846,14 +728,14 @@ def _hidden(params, tok, cfg: LMConfig, cd, interpret: bool):
 
     def stack(x):
         routed, carry = [], None
-        for kind, layer in zip(_layer_blocks(cfg), params["layers"]):
-            x, carry, stats = scoped(kind)(x, carry, layer)
+        for spec, layer in zip(layers(cfg), params["layers"]):
+            x, carry, stats = scoped(spec)(x, carry, layer)
             if stats:  # a layer without experts has nothing to report
                 routed.append(stats)
         with jax.named_scope("lm.final_norm"):
             return _rms_norm(x, params["final_norm"], cfg.norm_eps), routed
 
-    if "exit_gate_w" not in params:
+    if not exit_gate(cfg):
         return stack(x) + (None,)
 
     def one_pass(h, _):
@@ -1150,42 +1032,39 @@ class DecoderLM(Estimator, _LMParams):
             # (a rematerialised forward not again), and what the mask lets them skip;
             # the windowed layers' share of both beside them
             applications = cfg.n_layers * cfg.loops
-            heads = cfg.layer_heads or (cfg.n_heads,) * cfg.n_layers
-            if cfg.layer_kinds:  # one mixer a layer: only the attention layers fold
-                heads = tuple(cfg.n_heads * (kind == "*") for kind in cfg.layer_kinds)
-            layers_scan = cfg.layer_kinds.count("M")
-            scan_chunks = layers_scan * batch * cfg.ssm_heads * (t // cfg.chunk if cfg.chunk else 0)
-            # those of them the scan's kernel pair walks: its grid's cells x the heads of a cell
-            scan_chunks_kernel = (layers_scan * scan_kernel_chunks(batch, t, cfg.ssm_heads, cfg.ssm_groups, cfg.chunk)
-                                  if layers_scan else 0)
-            windows = cfg.layer_windows or (0,) * cfg.n_layers
-            one_head = {w: np.asarray(fold_chunk_counts(t, t, 0, True, w or None)) for w in set(windows)}
+            specs = layers(cfg)
+            mixers = [spec.mixer for spec in specs]
+            folds = [(m.heads, getattr(m, "window", 0)) for m in mixers if isinstance(m, (Attention, CCA))]
+            scans = [m for m in mixers if isinstance(m, Mamba2)]
+            # a step's chunks of the scan (chunks x heads x sequences, every Mamba-2 layer), and those of them the
+            # scan's kernel pair walks: its grid's cells x the heads of a cell
+            scan_chunks = sum(batch * m.heads * (t // m.chunk) for m in scans)
+            scan_chunks_kernel = sum(scan_kernel_chunks(batch, t, m.heads, m.groups, m.chunk) for m in scans)
+            one_head = {w: np.asarray(fold_chunk_counts(t, t, 0, True, w or None)) for w in {w for _, w in folds}}
             chunks = np.zeros((2, 2), np.int64)  # [full, windowed] x [visited, all]
-            for h, w in zip(heads, windows):
+            for h, w in folds:
                 chunks[int(w > 0)] += cfg.loops * h * batch * one_head[w]
             opt_state = optimizer.init(params)  # one dispatch: fresh buffers, which the step donates
             state = jax.tree_util.tree_leaves(opt_state)
             # positions x channels of the Mamba-2 layers' convolutions in one step's forward, and those of them the
             # convolution's kernels cover: their calls' grids in the step as traced
-            conv_positions = layers_scan * batch * t * (cfg.ssm_heads * cfg.ssm_head_dim + 2 * cfg.ssm_groups * cfg.ssm_state)
-            conv_positions_kernel = _conv_positions_kernel(step, params, opt_state, window) if layers_scan else 0
+            conv_positions = sum(batch * t * (m.heads * m.head_dim + 2 * m.groups * m.state) for m in scans)
+            conv_positions_kernel = _conv_positions_kernel(step, params, opt_state, window) if scans else 0
             phase.set_metadata(built=int(_train_program.cache_info().misses > misses),
                                fold_chunks=int(chunks[:, 1].sum()), fold_chunks_visited=int(chunks[:, 0].sum()),
                                loop_trips=cfg.loops, layer_applications=applications,
                                state_leaves=len(state), state_bytes=sum(x.nbytes for x in state))
-            if any(windows):
-                phase.set_metadata(layers_windowed=sum(w > 0 for w in windows),
-                                   layers_full=sum(w == 0 for w in windows),
+            if any(w for _, w in folds):
+                phase.set_metadata(layers_windowed=sum(w > 0 for _, w in folds),
+                                   layers_full=sum(w == 0 for _, w in folds),
                                    fold_win_chunks=int(chunks[1, 1]), fold_win_chunks_visited=int(chunks[1, 0]))
-            if cfg.layer_kinds:
-                # a step's chunks of the scan (chunks x heads x sequences, every Mamba-2 layer) and the
-                # float32 chunk states one layer's recurrence carries
-                phase.set_metadata(layers_scan=layers_scan, layers_attn=cfg.layer_kinds.count("*"),
-                                   layers_moe=cfg.layer_kinds.count("E"), scan_chunks=scan_chunks,
-                                   scan_chunks_kernel=scan_chunks_kernel, conv_positions=conv_positions,
-                                   conv_positions_kernel=conv_positions_kernel,
-                                   scan_state_bytes=4 * scan_chunks // max(layers_scan, 1)
-                                   * cfg.ssm_head_dim * cfg.ssm_state)
+            if scans:  # beside the counts, the float32 chunk states one layer's recurrence carries
+                phase.set_metadata(layers_scan=len(scans), layers_attn=sum(isinstance(m, Attention) for m in mixers),
+                                   layers_moe=sum(isinstance(spec.ffn, Experts) for spec in specs),
+                                   scan_chunks=scan_chunks, scan_chunks_kernel=scan_chunks_kernel,
+                                   conv_positions=conv_positions, conv_positions_kernel=conv_positions_kernel,
+                                   scan_state_bytes=max(4 * batch * m.heads * (t // m.chunk) * m.head_dim * m.state
+                                                        for m in scans))
 
         losses, leaf_norms, stats = [], [], []  # device values, fetched once after the loop
         with tracer.phase("train.dispatch", CAT_PRODUCTIVE, steps=steps):
@@ -1243,14 +1122,14 @@ class DecoderLM(Estimator, _LMParams):
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_TOKENS, steps * batch * t)
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_CHUNKS, steps * int(chunks[:, 1].sum()))
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_CHUNKS_VISITED, steps * int(chunks[:, 0].sum()))
-        if any(windows):
+        if chunks[1, 1]:
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_WIN_CHUNKS, steps * int(chunks[1, 1]))
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_WIN_CHUNKS_VISITED,
                             steps * int(chunks[1, 0]))
-        if layers_scan:
+        if scans:
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_SCAN_CHUNKS, steps * scan_chunks)
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_SCAN_KERNEL_CHUNKS, steps * scan_chunks_kernel)
-            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_SCAN_LAYERS, steps * layers_scan)
+            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_SCAN_LAYERS, steps * len(scans))
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_CONV_POSITIONS, steps * conv_positions)
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_CONV_KERNEL_POSITIONS,
                             steps * conv_positions_kernel)

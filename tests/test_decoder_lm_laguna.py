@@ -31,7 +31,7 @@ from flink_ml_tpu.api.dataframe import DataFrame
 from flink_ml_tpu.metrics import MLMetrics, metrics
 from flink_ml_tpu.models.lm import DecoderLM, DecoderLMModel, decoder_lm
 from flink_ml_tpu.models.lm import reference_laguna as ref
-from flink_ml_tpu.models.lm.config import LMConfig, num_params, param_shapes
+from flink_ml_tpu.models.lm.config import LMConfig, layers, num_params, param_shapes
 from flink_ml_tpu.models.lm.decoder_lm import _flat_names, _ordered, init_params
 from flink_ml_tpu.parallel import flash
 from flink_ml_tpu.utils.read_write import load_stage
@@ -403,7 +403,7 @@ def test_the_eight_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer()
     for first in range(0, 16, 2):
         share = uncut._replace(experts_held=2, first_held=first)
         held = dict(w, **{name: w[name][first: first + 2] for name in ("w_gate", "w_up", "w_down")})
-        out, _, stats = decoder_lm._laguna_block(x, None, held, share, F32, True, window=96)
+        out, _, stats = decoder_lm._layer(x, None, held, layers(share)[1], F32, True)
         assert int(stats["rows"].sum()) == BATCH * T * CFG.top_k  # routed = held + absent, whatever is held
         assert int(stats["rows"][first: first + 2].sum()) <= int(stats["carried"]) == 512  # one window of the 1,024
         total = total + out

@@ -29,7 +29,7 @@ from flink_ml_tpu.api.dataframe import DataFrame
 from flink_ml_tpu.metrics import MLMetrics, metrics
 from flink_ml_tpu.models.lm import DecoderLM, DecoderLMModel, decoder_lm
 from flink_ml_tpu.models.lm import reference_nemotron as ref
-from flink_ml_tpu.models.lm.config import A_RANGE, DT_FLOOR, DT_RANGE, LMConfig, num_params, param_shapes
+from flink_ml_tpu.models.lm.config import A_RANGE, DT_FLOOR, DT_RANGE, LMConfig, layers, num_params, param_shapes
 from flink_ml_tpu.models.lm.decoder_lm import _flat_names, _ordered, init_params
 from flink_ml_tpu.parallel import flash
 from flink_ml_tpu.utils.read_write import load_stage
@@ -333,7 +333,7 @@ def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
     for first in range(0, 16, 4):
         share = uncut._replace(experts_held=4, first_held=first)
         held = dict(w, **{name: w[name][first: first + 4] for name in ("w_up", "w_down")})
-        out, _, stats = decoder_lm._relu2_experts_block(x, None, held, share, F32, True)
+        out, _, stats = decoder_lm._layer(x, None, held, layers(share)[0], F32, True)
         assert int(stats["rows"].sum()) == BATCH * T * CFG.top_k  # routed = held + absent, whatever is held
         assert int(stats["rows"][first: first + 4].sum()) <= int(stats["carried"]) <= 1024
         total = total + out

@@ -297,40 +297,47 @@ def test_bad_sizes_are_refused(df):
         _estimator().set_num_heads(3).fit(df)
 
 
-#: sha256 of ``str(make_jaxpr(step))`` with addresses blanked, taken at the commit
-#: before the ``ouro`` kind (7f7c451): the other two kinds' step programs are,
-#: character for character, what the benchmark's cells measured.
+#: kind -> (configuration, compute type, sha256 of ``str(make_jaxpr(step))`` with addresses blanked, its ``while``
+#: loops). ``zaya``, ``laguna`` and ``nemotron_h`` read what they read at 512ebfa (PR 42), ``zaya`` what it read at
+#: 7f7c451, before the ``ouro`` kind: character for character what the benchmark's cells measured. The two ``olmoe``
+#: cases and ``ouro`` were re-pinned at PR 43, where one attention function took over from one a kind: the same
+#: equations (primitive, shapes, params: the multiset is 512ebfa's) with the RoPE tables built after the head split
+#: (82124bb1..., 3409e783... and 75253e70... at 512ebfa; CHANGES.md, PR 43).
 STEP_JAXPRS = {
     "olmoe": (LMConfig(n_layers=2, hidden=128, n_heads=4, n_experts=8, top_k=2, expert_width=64, vocab=512),
-              "float32", "82124bb1073222c52f442682063caab2a34d7d4eeaa635927a5ff92530a1f35f"),
+              "float32", "4c267466e4f5cae48873208bfaa17ed0faa1fe45508ba236a64019ab27e65177", 0),
     "olmoe_one_layer_bfloat16": (
         LMConfig(n_layers=1, hidden=128, n_heads=4, n_experts=8, top_k=2, expert_width=64, vocab=512),
-        "bfloat16", "3409e783a2122ff3fe9b2112db9d7127dd21e38dc9180561d51b9d5c68ca7f6d"),
+        "bfloat16", "80df8b8718a2db6575604b5510919120715111898b0a3f59f4dad000374e338a", 0),
     "zaya": (LMConfig(n_layers=2, hidden=128, n_heads=4, n_experts=8, top_k=1, expert_width=64, vocab=512,
                       rope_theta=5e6, aux_coef=0.0, block="zaya", tied=True, experts_held=4, first_held=2,
                       n_kv_heads=2, head_size=16, rope_fraction=0.5, router_width=32),
-             "float32", "b3660134986d9ce005017cb9f3518e18637f02f10bad3d632f90016477897064"),
-    # no digest: an eighth of the experts held, so the expert layers take their sorted rows a window at a
-    # time, a loop of a traced length a direction (``parallel/moe.py``), which the three above, with every
-    # expert or a half of them held, must not hold
+             "float32", "b3660134986d9ce005017cb9f3518e18637f02f10bad3d632f90016477897064", 0),
+    "ouro": (CFG, "float32", "a9c8d0abb4ce0b165dfb859f1c123e052db094be8f3674daee1b7b88e371f23f", 0),
+    # an eighth of the experts held, so the expert layer takes its sorted rows a window at a time, a loop of a
+    # traced length a direction (``parallel/moe.py``; the recomputed forward's is unused, and gone), which the
+    # kinds above, with every expert or a half of them held, must not hold
     "laguna": (LMConfig(n_layers=2, hidden=128, n_heads=4, n_experts=16, top_k=2, expert_width=64, vocab=512,
                         aux_coef=0.0, block="laguna", experts_held=2, first_held=2, n_kv_heads=2, head_size=16,
                         rope_fraction=0.5, layer_heads=(4, 8), layer_windows=(0, 96), n_dense=1, dense_width=96,
                         shared_width=32, routed_scale=2.5, window_rope_theta=1e4),
-               "float32", None),
+               "float32", "be04a0a41f46a62eb0ac22f998295b8ecb6142816e0e88ec98e2e0b344d7251f", 2),
+    # its four expert layers take their rows in windows too: 5 ``while``s in the text, as at 512ebfa
+    "nemotron_h": (LMConfig(n_layers=9, hidden=64, n_heads=4, n_experts=16, top_k=2, expert_width=32, vocab=512,
+                            norm_eps=1e-5, aux_coef=0.0, block="nemotron_h", experts_held=2, first_held=2,
+                            n_kv_heads=2, head_size=16, shared_width=48, routed_scale=2.5,
+                            layer_kinds=tuple("MEMEM*EME"), ssm_heads=8, ssm_head_dim=8, ssm_groups=2, ssm_state=16,
+                            conv_kernel=4, chunk=64),
+                   "float32", "e594fb9b409624b91e6a244778d05063cae4919e10924461c82f5b9524372967", 5),
 }
 
 
 @pytest.mark.parametrize("kind", sorted(STEP_JAXPRS))
-def test_the_other_kinds_step_programs_are_unchanged(kind):
-    cfg, compute_type, digest = STEP_JAXPRS[kind]
+def test_every_kinds_step_program_is_the_pinned_one(kind):
+    cfg, compute_type, digest, loops = STEP_JAXPRS[kind]
     optimizer, step = decoder_lm._train_program(cfg, compute_type, 1e-3, 2, True)
     shapes = jax.eval_shape(lambda: decoder_lm._init_program(cfg)(jax.random.key(0)))
     text = str(jax.make_jaxpr(step)(shapes, jax.eval_shape(optimizer.init, shapes),
                                     jax.ShapeDtypeStruct((4, 256), jnp.int32), jax.ShapeDtypeStruct((), jnp.int32)))
-    loops = len(re.findall(r"\bwhile\[", text))
-    if digest is None:
-        assert loops == 2  # one sparse layer's windows forward and backward: the recomputed forward's are unused, and gone
-    else:
-        assert loops == 0
-        assert hashlib.sha256(re.sub(r"0x[0-9a-f]+", "0x", text).encode()).hexdigest() == digest
+    assert len(re.findall(r"\bwhile\[", text)) == loops
+    assert hashlib.sha256(re.sub(r"0x[0-9a-f]+", "0x", text).encode()).hexdigest() == digest
