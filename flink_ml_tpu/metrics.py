@@ -67,6 +67,8 @@ class MLMetrics:
     TRAIN_LM_CONV_POSITIONS = "ml.train.lm.conv.positions"  # positions x channels of the Mamba-2 layers' causal convolutions (x layers x steps, forward), counter
     TRAIN_LM_CONV_KERNEL_POSITIONS = "ml.train.lm.conv.kernel_positions"  # those of them that the convolution's kernel pair covered (parallel/causal_conv.py), counter
     TRAIN_LM_SCAN_LAYERS = "ml.train.lm.scan.layers"  # Mamba-2 layer applications (layers x steps), counter
+    TRAIN_LM_MLA_LAYERS = "ml.train.lm.mla.layers"  # latent-attention layer applications (layers, a multi-token-prediction module's among them, x steps), counter
+    TRAIN_LM_MTP_TARGETS = "ml.train.lm.mtp.targets"  # positions the multi-token-prediction module scored (sequences x (length - 2) x steps), counter
     TRAIN_MOE_ROWS = "ml.train.moe.rows"  # (token, expert) rows the experts held here ran, counter
     TRAIN_MOE_ROWS_ABSENT = "ml.train.moe.rows_absent"  # rows routed to experts held elsewhere, counter
     TRAIN_MOE_LAYER_STEPS = "ml.train.moe.layer_steps"  # expert layers x steps of fits that take the routed rows in windows, counter

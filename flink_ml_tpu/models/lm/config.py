@@ -1,8 +1,8 @@
 """The decoder LM's sizes, what each layer of its stack IS, and its parameter
 tree, shared by the stage (``decoder_lm.py``) and the plain references
 (``reference.py``, ``reference_zaya.py``, ``reference_ouro.py``,
-``reference_laguna.py``, ``reference_nemotron.py``) so that one set of weights
-can be handed to both.
+``reference_laguna.py``, ``reference_nemotron.py``, ``reference_joyai.py``) so
+that one set of weights can be handed to both.
 
 ``layers(cfg)`` gives one hashable record a layer (``Layer``): a MIXER, a
 FEED-FORWARD (either may be absent), the stream's width and the norms'
@@ -11,7 +11,7 @@ learned per-channel scale and bias on both. The parameter tree
 (``param_shapes``), the forward (``decoder_lm._layer``), the fit's counts and
 the stage's checks all read that record and nothing else about a layer.
 
-``LMConfig.block`` names one of five PRESETS over that description
+``LMConfig.block`` names one of six PRESETS over that description
 (``_PRESETS``: the only place that names a model), each a published stack:
 
 ========== ================================================= ==========================================
@@ -26,13 +26,21 @@ laguna     attention at ``layer_heads[i]`` heads with head   dense (the first ``
            each head turned under YaRN
 nemotron_h ONE sublayer a layer behind the norm ``norm``, by ``layer_kinds[i]``: ``M`` a Mamba-2 scan,
            ``*`` attention without rotation, ``E`` ungated experts with sigmoid gates beside a shared one
+joyai      latent attention: queries, keys and values      dense (the first ``n_dense`` layers), then
+           rebuilt from low-rank latents, one rotary key a   experts with sigmoid gates beside a shared
+           token under every head, RoPE on interleaved pairs expert (laguna's record)
 ========== ================================================= ==========================================
 
 The tree: ``{"embed": [V, d], "layers": [layer, ...], "final_norm": [d],
 "lm_head": [d, V]}``; a tied head (``LMConfig.tied``) has no ``lm_head``: the
 head is ``embed`` transposed; a stack whose passes end in an exit gate
 (``exit_gate``: ``ouro``, run ``loops`` times over the same leaves) adds
-``"exit_gate_w": [d, 1], "exit_gate_b": [1]`` after them. A matrix maps ``x @
+``"exit_gate_w": [d, 1], "exit_gate_b": [1]`` after them; a multi-token-
+prediction module (``LMConfig.mtp_depth`` 1: a training objective behind the
+stack, ``reference_joyai.py``; ``mtp_layer``) is ONE more entry after those,
+``"mtp": {"enorm": [d], "hnorm": [d], "eh_proj": [2 d, d], "layer": {one more
+layer's leaves, the record of the stack's last}, "norm": [d]}``: it is no
+layer of the stack, and shares ``embed`` and the head. A matrix maps ``x @
 W`` (``[in, out]``: the transpose of a ``torch.nn.Linear`` weight).
 
 A layer's leaves (``leaves``), mixer first: each sublayer's norm ``[d]`` (its
@@ -47,6 +55,14 @@ The sublayers, with ``a = heads * head_dim`` and ``c = kv_heads * head_dim``:
   [d, c]``, ``"head_gate": [d, heads]`` under a sigmoid gate a head on the
   output, ``"wo": [a, d]``, ``"q_norm": [a], "k_norm": [c]`` under a QK-norm, the
   output's norm ``[d]`` if any (``attn_out_norm``).
+- ``LatentAttention`` (multi-head latent attention, ``reference_joyai.py``:
+  ``heads`` heads whose queries and keys are ``nope_dim + rope_dim`` wide and
+  whose values ``v_dim``, through the same fold), ``r = rope_dim``: ``"wq_a":
+  [d, q_rank], "q_a_norm": [q_rank], "wq_b": [q_rank, heads * (nope_dim + r)],
+  "wkv_a": [d, kv_rank + r]`` (the latent, then the one rotary key a token),
+  ``"kv_a_norm": [kv_rank], "wkv_b": [kv_rank, heads * (nope_dim + v_dim)]``
+  (a head's keys without position, then its values), ``"wo": [heads * v_dim,
+  d]``.
 - ``CCA`` (ZAYA's compressed convolutional attention; ``reference_zaya.py`` has
   the equations), ``g = heads + kv_heads``: ``"wq": [d, a], "wk": [d, c],
   "wv1"/"wv2": [d, head_dim], "conv0_w": [2, a + c], "conv0_b": [a + c],
@@ -80,8 +96,9 @@ import dataclasses
 import math
 from typing import List, NamedTuple, Optional, Tuple, Union
 
-__all__ = ["LMConfig", "BLOCKS", "MIXERS", "Rotation", "Attention", "CCA", "Mamba2", "Dense", "Experts", "Layer",
-           "layers", "exit_gate", "leaves", "param_shapes", "num_params", "ONES", "ZEROS", "NORMAL", "SMALL",
+__all__ = ["LMConfig", "BLOCKS", "MIXERS", "Rotation", "Attention", "LatentAttention", "CCA", "Mamba2", "Dense",
+           "Experts", "Layer", "layers", "exit_gate", "mtp_layer", "leaves", "param_shapes", "num_params", "ONES",
+           "ZEROS", "NORMAL", "SMALL",
            "SMALL_SCALE", "DT_BIAS", "A_LOG", "DT_RANGE", "DT_FLOOR", "A_RANGE"]
 
 #: The ``nemotron_h`` stack's layer kinds, as its published pattern spells them:
@@ -154,6 +171,16 @@ class LMConfig(NamedTuple):
     ssm_state: int = 0
     conv_kernel: int = 0
     chunk: int = 0
+    # the joyai block's own: latent attention's sizes (the queries' and the keys-and-values' latent widths, a
+    # head's channels without position, its rotary channels, its value channels), and the multi-token-prediction
+    # module behind the stack: how many (0 or 1), and the weight of its loss
+    q_rank: int = 0
+    kv_rank: int = 0
+    nope_dim: int = 0
+    rope_dim: int = 0
+    v_dim: int = 0
+    mtp_depth: int = 0
+    mtp_coef: float = 0.0
 
     @property
     def head_dim(self) -> int:
@@ -175,10 +202,13 @@ class LMConfig(NamedTuple):
 class Rotation:
     """RoPE on the first ``channels`` of each head (all of them: the whole
     head) at base ``theta``, stretched by YaRN (``yarn``: factor, original
-    length, beta_fast, beta_slow, attention factor) or, empty, not."""
+    length, beta_fast, beta_slow, attention factor) or, empty, not. Pair ``j``
+    is channels ``j`` and ``j + channels / 2`` (rotate-half) or, ``interleaved``,
+    ``2 j`` and ``2 j + 1``."""
     channels: int
     theta: float
     yarn: Tuple[float, ...] = ()
+    interleaved: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -192,6 +222,22 @@ class Attention:
     head_gate: bool = False
     norm: str = "attn_norm"
     out_norm: str = ""  # the leaf of the norm on the output; empty: none
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentAttention:
+    heads: int
+    q_rank: int  # the queries' latent
+    kv_rank: int  # the latent a token's keys and values are rebuilt from
+    nope_dim: int  # a head's query and key channels without position
+    rope_dim: int  # its rotary channels: the key's are one tensor a token, under every head
+    v_dim: int
+    rotation: Rotation  # of the ``rope_dim`` channels, which are their own tensor
+    norm: str = "attn_norm"
+
+    @property
+    def head_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -240,7 +286,7 @@ class Experts:
 class Layer:
     hidden: int
     eps: float
-    mixer: Union[Attention, CCA, Mamba2, None]
+    mixer: Union[Attention, LatentAttention, CCA, Mamba2, None]
     ffn: Union[Dense, Experts, None]
     scaled: bool = False  # a learned scale and bias on the residual and on each sublayer's output
 
@@ -290,9 +336,17 @@ def _nemotron_h(cfg: LMConfig):
     return tuple(Layer(cfg.hidden, cfg.norm_eps, *by_letter[cfg.layer_kinds[i]]) for i in range(cfg.n_layers))
 
 
+def _joyai(cfg: LMConfig):
+    mixer = LatentAttention(cfg.n_heads, cfg.q_rank, cfg.kv_rank, cfg.nope_dim, cfg.rope_dim, cfg.v_dim,
+                            Rotation(cfg.rope_dim, cfg.rope_theta, interleaved=True))
+    sparse = _experts(cfg, routed_scale=cfg.routed_scale, shared_width=cfg.shared_width)
+    return tuple(Layer(cfg.hidden, cfg.norm_eps, mixer, Dense(cfg.dense_width) if i < cfg.n_dense else sparse)
+                 for i in range(cfg.n_layers))
+
+
 #: kind -> (its layers, whether every pass of the stack ends in an exit gate: a linear with a bias)
 _PRESETS = {"olmoe": (_olmoe, False), "zaya": (_zaya, False), "ouro": (_ouro, True), "laguna": (_laguna, False),
-            "nemotron_h": (_nemotron_h, False)}
+            "nemotron_h": (_nemotron_h, False), "joyai": (_joyai, False)}
 BLOCKS = tuple(_PRESETS)
 
 
@@ -303,6 +357,12 @@ def layers(cfg: LMConfig) -> Tuple[Layer, ...]:
 
 def exit_gate(cfg: LMConfig) -> bool:
     return _PRESETS[cfg.block][1]
+
+
+def mtp_layer(cfg: LMConfig) -> Optional[Layer]:
+    """The one layer of the multi-token-prediction module behind the stack (``mtp_depth`` 1), or None: the record of
+    the stack's last layer, with leaves of its own."""
+    return layers(cfg)[-1] if cfg.mtp_depth else None
 
 
 # -- the parameter tree ------------------------------------------------------------------
@@ -320,6 +380,13 @@ def _attention_own(m: Attention, d: int):
             + ((("head_gate", (d, m.heads), NORMAL),) if m.head_gate else ()) + (("wo", (a, d), NORMAL),)
             + ((("q_norm", (a,), ONES), ("k_norm", (c,), ONES)) if m.qk_norm else ())
             + (((m.out_norm, (d,), ONES),) if m.out_norm else ()))
+
+
+def _latent_own(m: LatentAttention, d: int):
+    return (("wq_a", (d, m.q_rank), NORMAL), ("q_a_norm", (m.q_rank,), ONES),
+            ("wq_b", (m.q_rank, m.heads * (m.nope_dim + m.rope_dim)), NORMAL),
+            ("wkv_a", (d, m.kv_rank + m.rope_dim), NORMAL), ("kv_a_norm", (m.kv_rank,), ONES),
+            ("wkv_b", (m.kv_rank, m.heads * (m.nope_dim + m.v_dim)), NORMAL), ("wo", (m.heads * m.v_dim, d), NORMAL))
 
 
 def _cca_own(m: CCA, d: int):
@@ -356,7 +423,8 @@ def _experts_own(f: Experts, d: int):
             + _matrices("w", (f.held,), d, f.width, f.gated))
 
 
-_OWN_LEAVES = {Attention: _attention_own, CCA: _cca_own, Mamba2: _mamba2_own, Dense: _dense_own,
+_OWN_LEAVES = {Attention: _attention_own, LatentAttention: _latent_own, CCA: _cca_own, Mamba2: _mamba2_own,
+               Dense: _dense_own,
                Experts: _experts_own}
 
 
@@ -375,7 +443,8 @@ def leaves(layer: Layer):
 def param_shapes(cfg: LMConfig) -> List[Tuple[tuple, tuple, str]]:
     """Every leaf as ``(path, shape, init)``, in the one order the initialiser
     numbers them by. ``path`` indexes the tree: ``("layers", 0, "wq")``;
-    ``init`` is ``ONES``, ``ZEROS``, ``NORMAL``, ``SMALL``, ``DT_BIAS`` or ``A_LOG``."""
+    ``init`` is ``ONES``, ``ZEROS``, ``NORMAL``, ``SMALL``, ``DT_BIAS`` or ``A_LOG``. A node below the root is a
+    dict, but ``layers``, a list."""
     out = [(("embed",), (cfg.vocab, cfg.hidden), NORMAL)]
     for i, layer in enumerate(layers(cfg)):
         out += [(("layers", i, name), shape, init) for name, shape, init in leaves(layer)]
@@ -384,6 +453,13 @@ def param_shapes(cfg: LMConfig) -> List[Tuple[tuple, tuple, str]]:
         out.append((("lm_head",), (cfg.hidden, cfg.vocab), NORMAL))
     if exit_gate(cfg):
         out += [(("exit_gate_w",), (cfg.hidden, 1), NORMAL), (("exit_gate_b",), (1,), ZEROS)]
+    module = mtp_layer(cfg)
+    if module is not None:
+        d = cfg.hidden
+        own = [("enorm", (d,), ONES), ("hnorm", (d,), ONES), ("eh_proj", (2 * d, d), NORMAL)]
+        out += ([(("mtp", name), shape, init) for name, shape, init in own]
+                + [(("mtp", "layer", name), shape, init) for name, shape, init in leaves(module)]
+                + [(("mtp", "norm"), (d,), ONES)])
     return out
 
 

@@ -3,12 +3,14 @@
 next-token cross-entropy. What a layer IS is one record (``config.Layer``, from
 ``config.layers``): a mixer - causal self-attention through the fused fold of
 ``parallel/flash.py`` with the rotation, window, QK-norm, head gate and output
-norm its record names (``_attend``); ZAYA's compressed convolutional attention
-(``_cca``); a Mamba-2 scan behind its convolution (``_mamba2``:
+norm its record names (``_attend``); latent attention, whose queries, keys and
+values are rebuilt from low-rank latents and whose heads are wider in their
+keys than in their values (``_latent_attend``); ZAYA's compressed convolutional
+attention (``_cca``); a Mamba-2 scan behind its convolution (``_mamba2``:
 ``parallel/causal_conv.py``, ``parallel/ssd.py``) - and a feed-forward - one
 dense SwiGLU or a dropless mixture of experts, ``parallel/moe.py``
 (``_feed_forward``) - either of which may be absent, each joined to the
-residual stream (``_layer``). ``blockKind`` names one of five presets over that
+residual stream (``_layer``). ``blockKind`` names one of six presets over that
 description, each a published stack with its plain reference beside it
 (``config.py`` has the table and every leaf): ``olmoe`` (``reference.py``),
 ``zaya`` (ZAYA1-8B, ``reference_zaya.py``), ``ouro`` (Ouro-2.6B's looped LM,
@@ -20,8 +22,16 @@ entropy; ``transform`` scores the last pass), ``laguna`` (Laguna-XS.2,
 ``reference_laguna.py``: layers that differ inside one stack, by
 ``numHeadsPerLayer``, ``windowPerLayer`` and ``denseLayers``) and ``nemotron_h``
 (Nemotron-3-Nano's hybrid stack, ``reference_nemotron.py``: a layer is ONE
-sublayer behind one norm, its kind the layer's letter in ``layerPattern``). The
-fit loop, the head, the loss's chunking, the clip and the AdamW program are one.
+sublayer behind one norm, its kind the layer's letter in ``layerPattern``) and
+``joyai`` (JoyAI-LLM-Flash, ``reference_joyai.py``: latent attention in every
+layer, ``denseLayers`` leading dense layers and then sigmoid-gated experts
+beside a shared one, and behind the stack a multi-token-prediction module,
+``mtpDepth`` 1: one more layer that reads the stack's output beside the next
+token's embedding and predicts the token after it through the same head; the
+loss is the next-token cross-entropy plus ``mtpLossCoef`` times the module's;
+``transform`` scores with the main head alone, the module is a training
+objective). The fit loop, the head, the loss's chunking, the clip and the AdamW
+program are one.
 
 Any expert kind may tie the head to the embedding (``tieEmbeddings``: one leaf)
 and hold a range of each block's experts (``expertsHeld``,
@@ -66,7 +76,7 @@ rematerialises further, toward arguments and temporaries that fit it.
 
 Names: the step program's parts carry ``jax.named_scope``s (``lm.embed``,
 ``lm.block`` with each sublayer's parts under it, ``lm.final_norm``,
-``lm.head``, ``lm.exit``, ``lm.aux``, ``lm.opt``; docs/observability.md, "The
+``lm.head``, ``lm.mtp``, ``lm.exit``, ``lm.aux``, ``lm.opt``; docs/observability.md, "The
 step's scopes", has every path and what opens it), which reach each device
 operation's name beside what JAX's transformations write there, so a profile
 tells the parts, and forward from recomputed from backward, apart. They are
@@ -91,8 +101,8 @@ from flink_ml_tpu.api.core import Estimator, Model
 from flink_ml_tpu.api.types import DataTypes
 from flink_ml_tpu.metrics import MLMetrics, metrics
 from flink_ml_tpu.models.lm.config import (
-    A_LOG, A_RANGE, BLOCKS, CCA, DT_BIAS, DT_FLOOR, DT_RANGE, MIXERS, NORMAL, ONES, SMALL, SMALL_SCALE, Attention, Dense,
-    Experts, Layer, LMConfig, Mamba2, exit_gate, layers, num_params, param_shapes,
+    A_LOG, A_RANGE, BLOCKS, CCA, DT_BIAS, DT_FLOOR, DT_RANGE, MIXERS, NORMAL, ONES, SMALL, SMALL_SCALE, Attention,
+    Dense, Experts, LatentAttention, Layer, LMConfig, Mamba2, exit_gate, layers, mtp_layer, num_params, param_shapes,
 )
 from flink_ml_tpu.params.param import (
     BoolParam,
@@ -177,7 +187,9 @@ class _LMParams(
         "'laguna' (windowed and full attention layers of different head counts, a per-head output "
         "gate, leading dense layers, sigmoid-gated experts beside a shared one) or "
         "'nemotron_h' (each layer one mixer, by layerPattern: a Mamba-2 scan, attention without a "
-        "position encoding, or relu² experts beside a shared one).",
+        "position encoding, or relu² experts beside a shared one) or "
+        "'joyai' (latent attention on low-rank queries, keys and values, leading dense layers, sigmoid-gated "
+        "experts beside a shared one, a multi-token-prediction module behind the stack).",
         "olmoe", ParamValidators.in_array(list(BLOCKS)),
     )
     TIE_EMBEDDINGS = BoolParam("tieEmbeddings", "The head is the embedding table transposed.", False)
@@ -219,17 +231,17 @@ class _LMParams(
         "windowPerLayer", "Each layer's sliding window in keys, the query's own among them; 0: full causal "
         "attention ('laguna').", [])
     DENSE_LAYERS = IntParam(
-        "denseLayers", "Leading layers whose feed-forward is one dense SwiGLU of denseWidth ('laguna').", 0,
+        "denseLayers", "Leading layers whose feed-forward is one dense SwiGLU of denseWidth ('laguna', 'joyai').", 0,
         ParamValidators.gt_eq(0))
-    DENSE_WIDTH = IntParam("denseWidth", "Hidden width of a dense layer's SwiGLU ('laguna').", 0,
+    DENSE_WIDTH = IntParam("denseWidth", "Hidden width of a dense layer's SwiGLU ('laguna', 'joyai').", 0,
                            ParamValidators.gt_eq(0))
     SHARED_EXPERT_WIDTH = IntParam(
         "sharedExpertWidth", "Hidden width of the expert every token passes beside the routed ones ('laguna', "
-        "'nemotron_h').", 0,
+        "'nemotron_h', 'joyai').", 0,
         ParamValidators.gt_eq(0))
     ROUTED_SCALE = FloatParam(
         "routedScale", "The sigmoid gates of the chosen experts are renormalised to sum to one and scaled by "
-        "this ('laguna', 'nemotron_h').", 1.0, ParamValidators.gt(0))
+        "this ('laguna', 'nemotron_h', 'joyai').", 1.0, ParamValidators.gt(0))
     WINDOW_ROPE_THETA = FloatParam("windowRopeTheta", "Base of the rotary embedding in windowed layers, which "
                                    "turn every channel ('laguna').", 10000.0, ParamValidators.gt(0))
     ROPE_YARN = FloatArrayParam(
@@ -249,6 +261,20 @@ class _LMParams(
                                "('nemotron_h').", 4, ParamValidators.gt(0))
     SSM_CHUNK_SIZE = IntParam("ssmChunkSize", "Positions a chunk of the scan; the sequence length is a multiple "
                               "('nemotron_h').", 128, ParamValidators.gt(0))
+    Q_LORA_RANK = IntParam("qLoraRank", "Width of the latent the queries are rebuilt from ('joyai').", 0,
+                           ParamValidators.gt_eq(0))
+    KV_LORA_RANK = IntParam("kvLoraRank", "Width of the latent a token's keys and values are rebuilt from ('joyai').",
+                            0, ParamValidators.gt_eq(0))
+    QK_NOPE_HEAD_SIZE = IntParam("qkNopeHeadSize", "A head's query and key channels without position ('joyai').", 0,
+                                 ParamValidators.gt_eq(0))
+    QK_ROPE_HEAD_SIZE = IntParam("qkRopeHeadSize", "A head's rotary query channels, and the channels of the one "
+                                 "rotary key a token that every head reads ('joyai').", 0, ParamValidators.gt_eq(0))
+    V_HEAD_SIZE = IntParam("vHeadSize", "A head's value channels ('joyai').", 0, ParamValidators.gt_eq(0))
+    MTP_DEPTH = IntParam("mtpDepth", "Multi-token-prediction modules behind the stack, 0 or 1 ('joyai'): one more "
+                         "layer that predicts the token after next through the same head. A training objective: "
+                         "transform does not run it.", 0, ParamValidators.in_array([0, 1]))
+    MTP_LOSS_COEF = FloatParam("mtpLossCoef", "Weight of the multi-token-prediction module's loss ('joyai').", 0.3,
+                               ParamValidators.gt_eq(0))
     COMPUTE_TYPE = StringParam(
         "computeType",
         "Matmul input dtype: 'bfloat16' runs every matmul and the attention "
@@ -263,16 +289,19 @@ class _LMParams(
     #: The other kinds leave it at ``LMConfig``'s default (no experts; no balancing loss: a bias rule outside the
     #: gradient balances every kind but 'olmoe', reference_zaya.py).
     _OWN = {
-        ("olmoe", "zaya", "laguna", "nemotron_h"): (("n_experts", NUM_EXPERTS), ("top_k", EXPERTS_PER_TOKEN)),
+        ("olmoe", "zaya", "laguna", "nemotron_h", "joyai"): (("n_experts", NUM_EXPERTS), ("top_k", EXPERTS_PER_TOKEN)),
         ("olmoe",): (("aux_coef", AUX_LOSS_COEF),),
         ("zaya", "laguna", "nemotron_h"): (("n_kv_heads", NUM_KV_HEADS), ("head_size", HEAD_SIZE)),
         ("zaya", "laguna"): (("rope_fraction", ROPE_FRACTION),),
         ("zaya",): (("router_width", ROUTER_WIDTH),),
         ("ouro",): (("loops", NUM_LOOPS), ("exit_beta", EXIT_ENTROPY_COEF)),
         ("laguna",): (("layer_heads", NUM_HEADS_PER_LAYER), ("layer_windows", WINDOW_PER_LAYER),
-                      ("n_dense", DENSE_LAYERS), ("dense_width", DENSE_WIDTH),
                       ("window_rope_theta", WINDOW_ROPE_THETA), ("yarn", ROPE_YARN)),
-        ("laguna", "nemotron_h"): (("shared_width", SHARED_EXPERT_WIDTH), ("routed_scale", ROUTED_SCALE)),
+        ("laguna", "joyai"): (("n_dense", DENSE_LAYERS), ("dense_width", DENSE_WIDTH)),
+        ("laguna", "nemotron_h", "joyai"): (("shared_width", SHARED_EXPERT_WIDTH), ("routed_scale", ROUTED_SCALE)),
+        ("joyai",): (("q_rank", Q_LORA_RANK), ("kv_rank", KV_LORA_RANK), ("nope_dim", QK_NOPE_HEAD_SIZE),
+                     ("rope_dim", QK_ROPE_HEAD_SIZE), ("v_dim", V_HEAD_SIZE), ("mtp_depth", MTP_DEPTH),
+                     ("mtp_coef", MTP_LOSS_COEF)),
         ("nemotron_h",): (("layer_kinds", LAYER_PATTERN), ("ssm_heads", SSM_NUM_HEADS), ("ssm_head_dim", SSM_HEAD_SIZE),
                           ("ssm_groups", SSM_NUM_GROUPS), ("ssm_state", SSM_STATE_SIZE),
                           ("conv_kernel", SSM_CONV_KERNEL), ("chunk", SSM_CHUNK_SIZE)),
@@ -281,14 +310,19 @@ class _LMParams(
     _REFUSED = (
         ((NUM_KV_HEADS, HEAD_SIZE, ROPE_FRACTION), ("zaya", "laguna", "nemotron_h"),
          "numKvHeads, headSize and ropeFraction belong to blockKind 'zaya', 'laguna' or 'nemotron_h'"),
-        ((NUM_HEADS_PER_LAYER, WINDOW_PER_LAYER, DENSE_LAYERS), ("laguna",),
-         "numHeadsPerLayer, windowPerLayer and denseLayers belong to blockKind 'laguna'"),
-        ((SHARED_EXPERT_WIDTH,), ("laguna", "nemotron_h"), "sharedExpertWidth belongs to blockKind 'laguna' or 'nemotron_h'"),
+        ((NUM_HEADS_PER_LAYER, WINDOW_PER_LAYER), ("laguna",),
+         "numHeadsPerLayer and windowPerLayer belong to blockKind 'laguna'"),
+        ((DENSE_LAYERS,), ("laguna", "joyai"), "denseLayers belongs to blockKind 'laguna' or 'joyai'"),
+        ((SHARED_EXPERT_WIDTH,), ("laguna", "nemotron_h", "joyai"),
+         "sharedExpertWidth belongs to blockKind 'laguna', 'nemotron_h' or 'joyai'"),
+        ((Q_LORA_RANK, KV_LORA_RANK, QK_NOPE_HEAD_SIZE, QK_ROPE_HEAD_SIZE, V_HEAD_SIZE, MTP_DEPTH), ("joyai",),
+         "qLoraRank, kvLoraRank, qkNopeHeadSize, qkRopeHeadSize, vHeadSize and mtpDepth belong to blockKind 'joyai'"),
         ((LAYER_PATTERN, SSM_NUM_HEADS), ("nemotron_h",), "layerPattern and the ssm sizes belong to blockKind 'nemotron_h'"),
         ((NUM_LOOPS,), ("ouro",), "numLoops belongs to blockKind 'ouro'"),
-        ((TIE_EMBEDDINGS, EXPERTS_HELD, FIRST_EXPERT_HELD), ("olmoe", "zaya", "laguna", "nemotron_h"),
+        ((TIE_EMBEDDINGS, EXPERTS_HELD, FIRST_EXPERT_HELD), ("olmoe", "zaya", "laguna", "nemotron_h", "joyai"),
          "tieEmbeddings, expertsHeld and firstExpertHeld do not belong to blockKind 'ouro'"),
-        ((TIE_EMBEDDINGS,), ("olmoe", "zaya", "ouro", "laguna"), "tieEmbeddings does not belong to blockKind 'nemotron_h'"),
+        ((TIE_EMBEDDINGS,), ("olmoe", "zaya", "ouro", "laguna", "joyai"),
+         "tieEmbeddings does not belong to blockKind 'nemotron_h'"),
     )
 
     def lm_config(self, vocab: Optional[int] = None) -> LMConfig:
@@ -331,6 +365,10 @@ def _check_mixer(m) -> None:
                          f"stated headSize")
     if isinstance(m, Attention) and m.window < 0:
         raise ValueError(f"windowPerLayer counts keys, got {m.window}")
+    if isinstance(m, LatentAttention) and not (m.heads > 0 and m.q_rank and m.kv_rank and m.nope_dim and m.rope_dim
+                                               and m.v_dim):
+        raise ValueError(f"latent attention needs qLoraRank ({m.q_rank}), kvLoraRank ({m.kv_rank}), qkNopeHeadSize "
+                         f"({m.nope_dim}), qkRopeHeadSize ({m.rope_dim}) and vHeadSize ({m.v_dim})")
     if isinstance(m, CCA) and (m.kv_heads != 2 or m.heads % 2):
         raise ValueError(f"compressed convolutional attention's value shift makes two key/value heads (one of this "
                          f"position, one of the position before) under an even numHeads; got numKvHeads "
@@ -339,6 +377,9 @@ def _check_mixer(m) -> None:
         raise ValueError(f"a Mamba-2 layer needs ssmNumHeads ({m.heads}) in whole ssmNumGroups ({m.groups}), "
                          f"ssmHeadSize ({m.head_dim}) and ssmStateSize ({m.state})")
     rotation = getattr(m, "rotation", None)
+    if rotation is not None and rotation.interleaved != isinstance(m, LatentAttention):
+        raise ValueError("latent attention turns interleaved pairs of its rotary channels, the other mixers the two "
+                         "halves of theirs")
     if rotation is not None and rotation.channels % 2:
         raise ValueError(f"the rotary embedding turns an even number of channels, got {rotation.channels} of "
                          f"{m.head_dim}")
@@ -376,10 +417,10 @@ _add_accessors(_LMParams)
 def _build_tree(cfg: LMConfig, leaves) -> dict:
     tree = {"layers": [{} for _ in range(cfg.n_layers)]}
     for (path, _, _), leaf in zip(param_shapes(cfg), leaves):
-        if path[0] == "layers":
-            tree["layers"][path[1]][path[2]] = leaf
-        else:
-            tree[path[0]] = leaf
+        node = tree
+        for key in path[:-1]:  # a node below the root is a dict, but ``layers``, the list made above
+            node = node[key] if isinstance(node, list) else node.setdefault(key, {})
+        node[path[-1]] = leaf
     return tree
 
 
@@ -454,6 +495,28 @@ def _rope(x, cos, sin):
         return x * cos + rotated * sin
 
 
+def _pair_tables(t: int, d: int, theta: float, lead: int = 0):
+    """``cos, sin [T, lead + d]`` of RoPE on INTERLEAVED pairs: channels ``lead + 2 j`` and ``lead + 2 j + 1`` turn
+    by ``pos * theta^(-2 j / d)``, each angle's cosine on both channels of its pair, its sine negative on the even
+    one (``_rope_pairs``); the ``lead`` channels before them are not turned (cosine 1, sine 0)."""
+    with jax.named_scope("rope"):
+        inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+        freqs = jnp.repeat(jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :], 2, axis=-1)  # [T, d]
+        sign = jnp.tile(jnp.asarray([-1.0, 1.0], jnp.float32), d // 2)
+        return (jnp.pad(jnp.cos(freqs), ((0, 0), (lead, 0)), constant_values=1.0),
+                jnp.pad(sign * jnp.sin(freqs), ((0, 0), (lead, 0))))
+
+
+def _rope_pairs(x, cos, sin):
+    """RoPE on interleaved pairs of ``x [..., T, D]`` by ``_pair_tables``' ``cos, sin [T, D]``: ``out[2 j] = x[2 j]
+    c_j - x[2 j + 1] s_j``, ``out[2 j + 1] = x[2 j + 1] c_j + x[2 j] s_j``; a channel's partner is its neighbour, to
+    the right of an even channel and to the left of an odd one (the tables' unturned lead starts on an even one)."""
+    with jax.named_scope("rope"):
+        even = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1) % 2 == 0
+        partner = jnp.where(even, jnp.roll(x, -1, axis=-1), jnp.roll(x, 1, axis=-1))
+        return x * cos + partner * sin
+
+
 def _matmul(a, w, cd):
     return jnp.dot(a.astype(cd), w.astype(cd), preferred_element_type=jnp.float32,
                    precision=_HIGHEST if cd == jnp.float32 else None)
@@ -466,15 +529,16 @@ def _proj(a, w, cd):
 
 
 def _fold(q, k, v, cd, interpret: bool, window: Optional[int] = None):
-    """Causal softmax attention of ``q [B, H, T, D]`` on ``k``, ``v`` ``[B,
-    H_kv, T, D]`` at scale ``D^-1/2`` through the fused fold: a ring of one,
-    the whole sequence is the resident KV block. Under a ``window`` each query
-    keeps the ``window`` keys that end at itself."""
+    """Causal softmax attention of ``q [B, H, T, D]`` on ``k [B, H_kv, T, D]``
+    and ``v [B, H_kv, T, D_v]`` (``[B, H, T, D_v]`` out; ``D_v`` is ``D``
+    but under latent attention) at scale ``D^-1/2`` through the fused fold: a
+    ring of one, the whole sequence is the resident KV block. Under a
+    ``window`` each query keeps the ``window`` keys that end at itself."""
     with jax.named_scope("fold"):
         b, h, t, hd = q.shape
         m0 = jnp.full((b, h, t), -jnp.inf, jnp.float32)
         l0 = jnp.zeros((b, h, t), jnp.float32)
-        acc0 = jnp.zeros((b, h, t, hd), jnp.float32)
+        acc0 = jnp.zeros((b, h, t, v.shape[-1]), jnp.float32)
         _, l, acc = fused_fold(q.astype(cd), k.astype(cd), v.astype(cd), m0, l0, acc0, jnp.int32(0),
                                jnp.int32(0), True, False, jnp.int32(0), float(hd) ** -0.5, interpret, window)
         return acc / l[..., None]  # causal: every row attends at least to itself, l > 0
@@ -658,7 +722,33 @@ def _attend(x, layer, m: Attention, eps: float, cd, interpret: bool):
     return _rms_norm(o, layer[m.out_norm], eps) if m.out_norm else o
 
 
-_MIX = {Attention: _attend, CCA: _cca, Mamba2: _mamba2}
+def _latent_attend(x, layer, m: LatentAttention, eps: float, cd, interpret: bool):
+    """Latent attention: the queries through a ``q_rank``-wide normed latent;
+    a token's keys and values rebuilt from ONE ``kv_rank``-wide normed latent,
+    a head's ``nope_dim`` key channels without position and its ``v_dim``
+    values; beside the latent, one rotary key of ``rope_dim`` channels a token,
+    which every head reads behind its own keys. RoPE (interleaved pairs) turns
+    the last ``rope_dim`` channels of every query head and that one key. The
+    fold contracts ``nope_dim + rope_dim`` channels and hands back ``v_dim``."""
+    b, t, _ = x.shape
+    h, nope, rope = m.heads, m.nope_dim, m.rope_dim
+    a = _rms_norm(x, layer[m.norm], eps)
+    with jax.named_scope("latent"):
+        q = _matmul(_rms_norm(_matmul(a, layer["wq_a"], cd), layer["q_a_norm"], eps), layer["wq_b"], cd)
+        down = _matmul(a, layer["wkv_a"], cd)  # [B, T, kv_rank + rope]: the latent, then the rotary key
+        up = _matmul(_rms_norm(down[..., :m.kv_rank], layer["kv_a_norm"], eps), layer["wkv_b"], cd)
+    q, up = _heads(q, h), _heads(up, h)  # [B, H, T, nope + rope], [B, H, T, nope + v]
+    cos, sin = _pair_tables(t, rope, m.rotation.theta, lead=nope)
+    q = _rope_pairs(q, cos, sin)
+    k_rope = _rope_pairs(down[..., m.kv_rank:], cos[:, nope:], sin[:, nope:])  # [B, T, rope]
+    with jax.named_scope("rope"):  # the assembly of the heads' keys: a head's own channels, then the shared key
+        k = jnp.concatenate([up[..., :nope], jnp.broadcast_to(k_rope[:, None], (b, h, t, rope))], axis=-1)
+    with jax.named_scope("fold"):
+        v = up[..., nope:]
+    return _proj(_merged(_fold(q, k, v, cd, interpret)), layer["wo"], cd)
+
+
+_MIX = {Attention: _attend, LatentAttention: _latent_attend, CCA: _cca, Mamba2: _mamba2}
 
 
 def _feed_forward(x, carry, layer, f, eps: float, cd):
@@ -707,19 +797,22 @@ def _layer(x, carry, layer, spec: Layer, cd, interpret: bool):
     return x, carry, stats
 
 
-def _hidden(params, tok, cfg: LMConfig, cd, interpret: bool):
+def _hidden(params, tok, cfg: LMConfig, cd, interpret: bool, mtp: bool = False):
     """The final-normed hidden states, each expert layer's router statistics
     and the exit gate's logits. A stack without an exit gate passes once:
     ``[B, T, d]``, no logits. With one, the stack runs ``cfg.loops`` times over
     the same leaves, each pass's normed state feeding the next: ``[R, B, T,
-    d]`` and the gate's logits ``[R, B, T]``."""
+    d]`` and the gate's logits ``[R, B, T]``. Last, under ``mtp`` (a stack
+    with a multi-token-prediction module, in training: else None), the
+    module's normed hidden states ``[B, T, d]``, position ``i``'s the state
+    that predicts token ``i + 2``; its layer's statistics follow the stack's."""
     with jax.named_scope("lm.embed"):
         x = params["embed"][tok]
 
     @functools.cache
-    def scoped(spec):  # layers of one record trace to one shared sub-program where their leaves agree
+    def scoped(spec, scope="lm.block"):  # layers of one record trace to one shared sub-program where their leaves agree
         def block(x, carry, layer):  # the scope opens inside what is rematerialised
-            with jax.named_scope("lm.block"):
+            with jax.named_scope(scope):
                 return _layer(x, carry, layer, spec, cd, interpret)
 
         # a lone block's residuals are wanted as soon as the head's backward
@@ -733,18 +826,33 @@ def _hidden(params, tok, cfg: LMConfig, cd, interpret: bool):
             if stats:  # a layer without experts has nothing to report
                 routed.append(stats)
         with jax.named_scope("lm.final_norm"):
-            return _rms_norm(x, params["final_norm"], cfg.norm_eps), routed
+            h = _rms_norm(x, params["final_norm"], cfg.norm_eps)
+        if not mtp:
+            return h, routed, None
+        # position i's stream beside the embedding of token i + 1, through one more layer: the state that predicts
+        # token i + 2. The last position has no next token: it takes the first's as a filler, which causality keeps
+        # from every other position and no loss reads (its routed rows ride the dropless experts and are counted).
+        module = params["mtp"]
+        with jax.named_scope("lm.mtp/proj"):
+            nxt = params["embed"][jnp.roll(tok, -1, axis=1)]
+            both = jnp.concatenate([_rms_norm(x, module["hnorm"], cfg.norm_eps),
+                                    _rms_norm(nxt, module["enorm"], cfg.norm_eps)], axis=-1)
+            y = _matmul(both, module["eh_proj"], cd)
+        y, _, stats = scoped(mtp_layer(cfg), "lm.mtp/lm.block")(y, None, module["layer"])
+        with jax.named_scope("lm.mtp"):
+            return h, routed + ([stats] if stats else []), _rms_norm(y, module["norm"], cfg.norm_eps)
 
     if not exit_gate(cfg):
-        return stack(x) + (None,)
+        h, routed, ahead = stack(x)
+        return h, routed, None, ahead
 
     def one_pass(h, _):
-        h, _ = stack(h)
+        h, _, _ = stack(h)
         with jax.named_scope("lm.exit"):
             return h, (h, jnp.sum(h * params["exit_gate_w"][:, 0], axis=-1) + params["exit_gate_b"][0])
 
     _, (passes, gate) = jax.lax.scan(one_pass, x, None, length=cfg.loops)
-    return passes, [], gate
+    return passes, [], gate, None
 
 
 def _head(params, cfg: LMConfig):
@@ -825,14 +933,24 @@ def _exit_loss(passes, gate, lm_head, tok, cfg: LMConfig, cd):
 def _loss(params, tok, cfg: LMConfig, cd, interpret: bool):
     """``(loss, stats)``: ``stats`` holds what the blocks have to report - the
     rows each expert took (``rows``), the rows each expert layer carried
-    (``carried``), the exits' sums (``_exit_loss``)."""
-    h, routed, gate = _hidden(params, tok, cfg, cd, interpret)
+    (``carried``), the exits' sums (``_exit_loss``), the multi-token-prediction
+    module's summed cross-entropy and the positions it scored (``mtp_nll_sum``,
+    ``mtp_targets``). The module's term is ``mtp_coef`` times its mean."""
+    h, routed, gate, ahead = _hidden(params, tok, cfg, cd, interpret, mtp=bool(cfg.mtp_depth))
     if gate is None:
         nll = _next_token_nll(h, _head(params, cfg), tok, cd)
         with jax.named_scope("lm.head"):
             loss, stats = jnp.sum(nll) / (tok.shape[0] * (tok.shape[1] - 1)), {}
     else:
         loss, stats = _exit_loss(h, gate, _head(params, cfg), tok, cfg, cd)
+    if ahead is not None:
+        with jax.named_scope("lm.mtp"):
+            # the same head over targets one further on: position i scores token i + 2, the last two nothing
+            nll = _next_token_nll(ahead, _head(params, cfg), jnp.roll(tok, -1, axis=1), cd)
+            scored = jnp.arange(tok.shape[1]) < tok.shape[1] - 2
+            stats["mtp_nll_sum"] = jnp.sum(jnp.where(scored, nll, 0.0))
+            stats["mtp_targets"] = tok.shape[0] * jnp.sum(scored.astype(jnp.int32))
+            loss = loss + cfg.mtp_coef * stats["mtp_nll_sum"] / stats["mtp_targets"]
     if cfg.aux_coef:
         with jax.named_scope("lm.aux"):
             loss = loss + cfg.aux_coef * _load_balancing(routed, cfg)
@@ -895,7 +1013,7 @@ def _log_likelihood_program(cfg: LMConfig, compute_type: str, interpret: bool):
     cd = jnp.dtype(compute_type)
 
     def run(params, tok):
-        h, _, gate = _hidden(params, tok, cfg, cd, interpret)
+        h, _, gate, _ = _hidden(params, tok, cfg, cd, interpret)  # no module: it is a training objective
         if gate is not None:
             h = h[-1]  # the exit threshold is 1: no token leaves before the last pass
         nll = _next_token_nll(h, _head(params, cfg), tok, cd)
@@ -904,20 +1022,26 @@ def _log_likelihood_program(cfg: LMConfig, compute_type: str, interpret: bool):
     return jax.jit(run)
 
 
-def _fold_mode(t: int, head_dim: int, chunk: int = 0) -> bool:
+def _fold_mode(t: int, cfg: LMConfig) -> bool:
     """Whether the fused fold runs interpreted (off the TPU), after checking
-    that it (and a stack with Mamba-2 layers' scan, in chunks of ``chunk``)
-    can serve this sequence at all; there is no other attention path."""
+    that it (and a stack with Mamba-2 layers' scan, in chunks of ``cfg.chunk``)
+    can serve this sequence at every layer's head sizes; there is no other
+    attention path."""
+    chunk = cfg.chunk
     if t % TQ_TILE or (chunk and t % chunk):
         raise ValueError(f"sequence length {t} must be a multiple of {TQ_TILE} (the fused fold's Q tile)"
                          + (f" and of {chunk} (the scan's chunk)" if chunk else ""))
-    on_tpu = is_tpu_backend(jax.devices())
-    if on_tpu and not flash_available(t, head_dim):
-        raise ValueError(
-            f"the fused attention fold does not admit T={t}, head size {head_dim} "
-            "(parallel/flash.py::flash_available); DecoderLM has no other attention path"
-        )
-    return not on_tpu
+    if not is_tpu_backend(jax.devices()):
+        return True
+    for m in {spec.mixer for spec in layers(cfg)}:
+        if isinstance(m, (Attention, LatentAttention, CCA)):
+            d_v = getattr(m, "v_dim", m.head_dim)
+            if not flash_available(t, m.head_dim, Dv=d_v):
+                raise ValueError(
+                    f"the fused attention fold does not admit T={t} at {m.head_dim} query and key channels and {d_v} "
+                    "value channels a head (parallel/flash.py::flash_available); DecoderLM has no other attention path"
+                )
+    return False
 
 
 def _token_matrix(df, col: str) -> np.ndarray:
@@ -931,7 +1055,9 @@ def _token_matrix(df, col: str) -> np.ndarray:
 
 class DecoderLMModel(Model, _LMParams):
     """Serving side: per-row mean next-token log-likelihood through the same
-    forward. ``params`` holds device arrays after a fit, host arrays after
+    forward, by the main head alone: a multi-token-prediction module is a
+    training objective, saved and loaded with the tree and not run here.
+    ``params`` holds device arrays after a fit, host arrays after
     ``load``/``set_model_data``; either is placed once per call."""
 
     def __init__(self):
@@ -945,7 +1071,7 @@ class DecoderLMModel(Model, _LMParams):
         if tok.size and tok.max() >= cfg.vocab:
             raise ValueError(f"token ids must be in [0, {cfg.vocab}); got up to {tok.max()}")
         n, t = tok.shape
-        program = _log_likelihood_program(cfg, self.get_compute_type(), _fold_mode(t, cfg.head_dim, cfg.chunk))
+        program = _log_likelihood_program(cfg, self.get_compute_type(), _fold_mode(t, cfg))
         params = jax.tree_util.tree_map(jnp.asarray, self.params)
         batch = min(self.get_global_batch_size(), n)
         out = np.empty(n, np.float64)
@@ -997,8 +1123,11 @@ class DecoderLM(Estimator, _LMParams):
     ``grad_norm_history`` (global, before clipping), ``param_grad_norm_history``
     (``[steps, parameters]``, columns named by ``param_names``),
     ``expert_rows_history`` (``[steps, layers with experts, experts]`` routed
-    rows; no experts, no columns) and ``trip_loss_history`` (``[steps, passes]``: each
-    pass's own mean cross-entropy; a stack passed once has no columns)."""
+    rows; no experts, no columns; a multi-token-prediction module's layer follows
+    the stack's), ``trip_loss_history`` (``[steps, passes]``: each pass's own mean
+    cross-entropy; a stack passed once has no columns) and ``mtp_loss_history``
+    (the module's own mean cross-entropy; no module, empty; ``loss_history`` holds
+    the whole objective)."""
 
     def fit(self, *inputs) -> DecoderLMModel:
         (df,) = inputs
@@ -1016,7 +1145,7 @@ class DecoderLM(Estimator, _LMParams):
             phase.set_metadata(tokens=n * t, bytes=int(tok.nbytes))
         fit_phase.set_metadata(tokens=n * t)
         cfg = self.lm_config(vocab)
-        interpret = _fold_mode(t, cfg.head_dim, cfg.chunk)
+        interpret = _fold_mode(t, cfg)
         batch = min(self.get_global_batch_size(), n)
         steps = self.get_max_iter()
 
@@ -1032,9 +1161,11 @@ class DecoderLM(Estimator, _LMParams):
             # (a rematerialised forward not again), and what the mask lets them skip;
             # the windowed layers' share of both beside them
             applications = cfg.n_layers * cfg.loops
-            specs = layers(cfg)
+            specs = layers(cfg) + ((mtp_layer(cfg),) if cfg.mtp_depth else ())  # the module's layer folds too
             mixers = [spec.mixer for spec in specs]
-            folds = [(m.heads, getattr(m, "window", 0)) for m in mixers if isinstance(m, (Attention, CCA))]
+            latents = [m for m in mixers if isinstance(m, LatentAttention)]
+            folds = [(m.heads, getattr(m, "window", 0)) for m in mixers
+                     if isinstance(m, (Attention, LatentAttention, CCA))]
             scans = [m for m in mixers if isinstance(m, Mamba2)]
             # a step's chunks of the scan (chunks x heads x sequences, every Mamba-2 layer), and those of them the
             # scan's kernel pair walks: its grid's cells x the heads of a cell
@@ -1058,6 +1189,9 @@ class DecoderLM(Estimator, _LMParams):
                 phase.set_metadata(layers_windowed=sum(w > 0 for _, w in folds),
                                    layers_full=sum(w == 0 for _, w in folds),
                                    fold_win_chunks=int(chunks[1, 1]), fold_win_chunks_visited=int(chunks[1, 0]))
+            if latents:  # beside the count, the float32 latents and rotary keys a step's layers rebuild k and v from
+                phase.set_metadata(layers_latent=len(latents), mtp_depth=cfg.mtp_depth,
+                                   latent_bytes=sum(4 * batch * t * (m.kv_rank + m.rope_dim) for m in latents))
             if scans:  # beside the counts, the float32 chunk states one layer's recurrence carries
                 phase.set_metadata(layers_scan=len(scans), layers_attn=sum(isinstance(m, Attention) for m in mixers),
                                    layers_moe=sum(isinstance(spec.ffn, Experts) for spec in specs),
@@ -1112,6 +1246,10 @@ class DecoderLM(Estimator, _LMParams):
                     gate_entropy_sum=float(stats["gate_entropy_sum"].sum()),
                     trip_nll=[float(x) for x in trips.mean(axis=0)],
                 )
+            ahead = np.asarray(stats.get("mtp_nll_sum", np.zeros(0)), np.float64)
+            if ahead.size:  # [steps]: the module's summed cross-entropy over the positions it scored
+                mtp_targets = int(stats["mtp_targets"].sum())
+                phase.set_metadata(mtp_targets=mtp_targets, mtp_nll_sum=float(ahead.sum()))
         with tracer.phase("train.readback", CAT_READBACK, bytes=4 * steps * (1 + len(param_shapes(cfg)))):
             self.loss_history = [float(x) for x in jax.device_get(losses)]
             self.param_grad_norm_history = np.asarray(jax.device_get(jnp.stack(leaf_norms)), np.float64)
@@ -1119,6 +1257,7 @@ class DecoderLM(Estimator, _LMParams):
         self.grad_norm_history = [float(x) for x in np.sqrt((self.param_grad_norm_history ** 2).sum(axis=1))]
         self.expert_rows_history = loads
         self.trip_loss_history = trips
+        self.mtp_loss_history = [float(x) for x in ahead / stats["mtp_targets"]] if ahead.size else []
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_TOKENS, steps * batch * t)
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_CHUNKS, steps * int(chunks[:, 1].sum()))
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_CHUNKS_VISITED, steps * int(chunks[:, 0].sum()))
@@ -1133,6 +1272,10 @@ class DecoderLM(Estimator, _LMParams):
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_CONV_POSITIONS, steps * conv_positions)
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_CONV_KERNEL_POSITIONS,
                             steps * conv_positions_kernel)
+        if latents:
+            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_MLA_LAYERS, steps * len(latents))
+        if ahead.size:
+            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_MTP_TARGETS, mtp_targets)
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_LOOP_TRIPS, steps * cfg.loops)
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_LOOP_LAYER_APPLICATIONS, steps * applications)
         if loads.size:

@@ -46,6 +46,14 @@ H_kv, Tk, D]``. With ``H_kv = H`` the index maps and the grid are what they
 were. ``reference_fold`` keeps equal head counts: a grouped caller is tested
 against it on K and V repeated.
 
+A value head of its own size: the scores contract ``q`` and ``kb`` over their
+``D`` channels and the output is as wide as ``vb``'s heads, ``D_v``: ``vb``
+``[B, H_kv, T, D_v]``, ``acc`` and its gradient ``[B, H, T, D_v]``, ``dk`` at
+``D`` and ``dv`` at ``D_v`` (latent attention's heads: 192-wide queries and
+keys, 128-wide values). Each operand is staged and tiled at its own width and
+the kernels' bodies are the same; with ``D_v = D`` every block spec is what it
+was.
+
 A sliding window: ``window`` (static, with ``causal``) keeps of each query's
 keys the ``window`` that end at the query, ``t - window < j <= t``. It is a
 second edge on the same walk: a cell also skips the chunks whose last key is
@@ -78,29 +86,33 @@ __all__ = [
 TQ_TILE = 256  # Q rows per grid cell
 
 
-_KV_VMEM_BUDGET = 1 << 20  # Tk*D f32 elements the kernel may stage per head
-# T=8192 (with D=128, so T*D == _KV_VMEM_BUDGET) is the largest shape whose
-# Mosaic compilation is verified on hardware: the forward there since the ring
-# tests, all three kernels in a TRAINING graph since models/lm trained ZAYA1-8B's
-# block on the chip at 2 x 8 query heads on 2 key/value heads x 8,192 x 128 in
-# bfloat16 (PERF.md, PR 30). Every admitted (T, D) then has
-# score-buffer and KV footprints <= that shape's in all three kernels. 16384
+_KV_VMEM_BUDGET = 8192 * (192 + 128)  # Tk*(D + D_v) elements of K and V a cell may stage per head
+# T=8192 with 192-wide keys and 128-wide values (so T*(D + D_v) ==
+# _KV_VMEM_BUDGET) is the largest shape whose Mosaic compilation is verified
+# on hardware: the forward at D = D_v = 128 there since the ring tests, all
+# three kernels in a TRAINING graph since models/lm trained ZAYA1-8B's block on
+# the chip at 2 x 8 query heads on 2 key/value heads x 8,192 x 128 in bfloat16
+# (PERF.md, PR 30), and at 2 x 32 heads x 8,192 x 192 / 128 since it trained a
+# latent-attention stack there (PERF.md, PR 44). Every admitted (T, D, D_v)
+# then has score-buffer and KV footprints <= that shape's in all three
+# kernels. 16384
 # admitted shapes (e.g. T=16384, D=64) stage [tile, 16384] f32 scores plus
 # full KV — past the scoped-VMEM limit on paper and never compile-checked on
 # chip, so they are rejected until verified.
 _TK_MAX = 8192
 
 
-def flash_available(T: int, D: int, devices=None) -> bool:
+def flash_available(T: int, D: int, devices=None, Dv=None) -> bool:
     """Whether the fused fold applies: Q tiles must divide the local length,
-    one head's KV block AND a tile's ``[rows, Tk]`` score buffers (whole, or as
-    the visited chunks under ``causal``) must fit the kernel's VMEM staging
-    (the fold brings one head's whole resident K and V on-chip; past either
-    budget the jnp fold's streamed HBM form is the right tool), and the
-    devices must be TPUs (Mosaic target)."""
+    one head's KV block (K at ``D`` channels plus V at ``Dv``; ``None``: ``D``)
+    AND a tile's ``[rows, Tk]`` score buffers (whole, or as the visited chunks
+    under ``causal``) must fit the kernel's VMEM staging (the fold brings one
+    head's whole resident K and V on-chip; past either budget the jnp fold's
+    streamed HBM form is the right tool), and the devices must be TPUs (Mosaic
+    target)."""
     from flink_ml_tpu.parallel.mesh import is_tpu_backend
 
-    if T % TQ_TILE or T * D > _KV_VMEM_BUDGET or T > _TK_MAX:
+    if T % TQ_TILE or T * (D + (D if Dv is None else Dv)) > _KV_VMEM_BUDGET or T > _TK_MAX:
         return False
     return is_tpu_backend(devices if devices is not None else jax.devices())
 
@@ -116,7 +128,8 @@ def flash_available(T: int, D: int, devices=None) -> bool:
 # scores in a scratch the size of the tile's whole row block, [Tk / 1024, 512,
 # 1024] f32: at T=8192, D=128 that is
 # 16 MB in the forward and twice that in the dq kernel (scores and dP * P),
-# beside K and V (2 MB each in bfloat16, twice for the double buffer) and a
+# beside K and V (2 MB each in bfloat16, twice for the double buffer; K at 192
+# channels 3 MB, 4 as Mosaic pads it to 256 lanes) and a
 # chunk's [512, 1024] temporaries; the dkv kernel's [1024, 1024] pair is 4 MB
 # a temporary whatever T is. Compiled here for the chip, that training graph
 # fits a limit of 48 MB and not 40 in bfloat16, 64 and not 48 in float32 (the
@@ -370,9 +383,9 @@ def reference_fold(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
     """The jnp fold in [B, H, ...] layout (ring.py numerics) — the source of
     truth the kernel is tested against and the backward recomputes through.
 
-    ``q`` [B, H, Tq, D]; ``kb``/``vb`` [B, H, Tk, D] (equal head counts here;
-    the kernels also take ``[B, H_kv, Tk, D]``); ``m``/``l`` [B, H, Tq];
-    ``acc`` [B, H, Tq, D]. ``q_pos0``/``k_pos0`` are the global positions of
+    ``q`` [B, H, Tq, D]; ``kb`` [B, H, Tk, D], ``vb`` [B, H, Tk, D_v] (equal
+    head counts here; the kernels also take ``[B, H_kv, Tk, .]``); ``m``/``l``
+    [B, H, Tq]; ``acc`` [B, H, Tq, D_v]. ``q_pos0``/``k_pos0`` are the global positions of
     query/key 0 (traced scalars); ``n_valid`` masks keys at global positions
     >= it (None = unmasked); ``window`` (with ``causal``) keeps of each
     query's keys the ``window`` that end at it.
@@ -407,7 +420,7 @@ def _fold_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
     if window is not None and not (causal and window > 0):
         raise ValueError(f"a sliding window ({window}) lies under the causal mask and holds at least the query")
     B, H, Tq, D = q.shape
-    Hkv, Tk = kb.shape[1], kb.shape[2]
+    Hkv, Tk, Dv = kb.shape[1], kb.shape[2], vb.shape[3]
     BH = B * H
     kv_of = _kv_block_of(H, Hkv)
     masked = n_valid is not None
@@ -485,10 +498,12 @@ def _fold_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
     tile2 = pl.BlockSpec(
         (1, tq, 1), lambda i, j, *_: (i, j, 0), memory_space=pltpu.VMEM
     )
-    tile3 = pl.BlockSpec(
-        (1, tq, D), lambda i, j, *_: (i, j, 0), memory_space=pltpu.VMEM
-    )
-    full3 = pl.BlockSpec((1, Tk, D), lambda i, j, *_: (kv_of(i), 0, 0), memory_space=pltpu.VMEM)
+    def tile3(width):
+        return pl.BlockSpec((1, tq, width), lambda i, j, *_: (i, j, 0), memory_space=pltpu.VMEM)
+
+    def full3(width):
+        return pl.BlockSpec((1, Tk, width), lambda i, j, *_: (kv_of(i), 0, 0), memory_space=pltpu.VMEM)
+
     from flink_ml_tpu.parallel.mesh import vma_of
 
     vma = vma_of(q)
@@ -497,8 +512,8 @@ def _fold_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(BH, Tq // tq),
-            in_specs=[tile3, full3, full3, tile2, tile2, tile3],
-            out_specs=[tile2, tile2, tile3],
+            in_specs=[tile3(D), full3(D), full3(Dv), tile2, tile2, tile3(Dv)],
+            out_specs=[tile2, tile2, tile3(Dv)],
             scratch_shapes=(
                 [pltpu.VMEM((n_chunks, tq, kc), jnp.float32)] if n_chunks > 1 else []
             ),
@@ -506,7 +521,7 @@ def _fold_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
         out_shape=[
             jax.ShapeDtypeStruct((BH, Tq, 1), jnp.float32, vma=vma),
             jax.ShapeDtypeStruct((BH, Tq, 1), jnp.float32, vma=vma),
-            jax.ShapeDtypeStruct((BH, Tq, D), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((BH, Tq, Dv), jnp.float32, vma=vma),
         ],
         interpret=interpret,
         compiler_params=_compiler_params(),
@@ -515,12 +530,12 @@ def _fold_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
         scalars,
         q.reshape(BH, Tq, D),
         kb.reshape(B * Hkv, Tk, D),
-        vb.reshape(B * Hkv, Tk, D),
+        vb.reshape(B * Hkv, Tk, Dv),
         m.reshape(BH, Tq, 1),
         l.reshape(BH, Tq, 1),
-        acc.reshape(BH, Tq, D),
+        acc.reshape(BH, Tq, Dv),
     )
-    return mo.reshape(B, H, Tq), lo.reshape(B, H, Tq), ao.reshape(B, H, Tq, D)
+    return mo.reshape(B, H, Tq), lo.reshape(B, H, Tq), ao.reshape(B, H, Tq, Dv)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 11, 12, 13))
@@ -528,7 +543,8 @@ def fused_fold(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, has_n_valid,
                n_valid, scale, interpret=False, window=None):
     """One ring-attention fold, fused. Same contract as ``reference_fold``,
     and ``kb``/``vb`` may carry fewer heads than ``q`` (grouped queries: the
-    module docstring) (``n_valid`` is a traced scalar consumed only when ``has_n_valid``);
+    module docstring) and ``vb`` heads of another width than ``kb``'s (``acc``
+    is then as wide as ``vb``'s) (``n_valid`` is a traced scalar consumed only when ``has_n_valid``);
     the primal runs the Pallas forward kernel and gradients run the fused
     backward kernels (``_fold_bwd_pallas``, AD-exact).
     ``causal``/``has_n_valid``/``scale``/``interpret``/``window`` are static;
@@ -626,7 +642,7 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
     from flink_ml_tpu.parallel.mesh import vma_of
 
     B_, H, Tq, D = q.shape
-    Hkv, Tk = kb.shape[1], kb.shape[2]
+    Hkv, Tk, Dv = kb.shape[1], kb.shape[2], vb.shape[3]
     BH, BHkv = B_ * H, B_ * Hkv
     group = H // Hkv
     kv_of = _kv_block_of(H, Hkv)
@@ -827,18 +843,19 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
     def col(tile):
         return pl.BlockSpec((1, tile, 1), lambda i, j, *_: (i, j, 0), memory_space=pltpu.VMEM)
 
-    def mat(tile):
-        return pl.BlockSpec((1, tile, D), lambda i, j, *_: (i, j, 0), memory_space=pltpu.VMEM)
+    def mat(tile, width=D):
+        return pl.BlockSpec((1, tile, width), lambda i, j, *_: (i, j, 0), memory_space=pltpu.VMEM)
 
-    fullk_mat = pl.BlockSpec((1, Tk, D), lambda i, j, *_: (kv_of(i), 0, 0), memory_space=pltpu.VMEM)
+    def fullk_mat(width):
+        return pl.BlockSpec((1, Tk, width), lambda i, j, *_: (kv_of(i), 0, 0), memory_space=pltpu.VMEM)
 
     def sds(shape):
         return jax.ShapeDtypeStruct(shape, jnp.float32, vma=vma)
 
     q4 = q.reshape(BH, Tq, D)
     k4 = kb.reshape(BHkv, Tk, D)
-    v4 = vb.reshape(BHkv, Tk, D)
-    dacc4 = dacc.reshape(BH, Tq, D)
+    v4 = vb.reshape(BHkv, Tk, Dv)
+    dacc4 = dacc.reshape(BH, Tq, Dv)
     dl4 = dl.reshape(BH, Tq, 1)
     dq_o, dm_o, dl_o, dacc_o, safe_r, b_r, dbc_r = pl.pallas_call(
         dq_walking_kernel if n_chunks > 1 else dq_kernel,
@@ -846,12 +863,12 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
             num_scalar_prefetch=1,
             grid=(BH, Tq // tq_bwd),
             in_specs=[
-                mat(tq_bwd), fullk_mat, fullk_mat,
-                col(tq_bwd), col(tq_bwd), mat(tq_bwd),
-                col(tq_bwd), col(tq_bwd), mat(tq_bwd),
+                mat(tq_bwd), fullk_mat(D), fullk_mat(Dv),
+                col(tq_bwd), col(tq_bwd), mat(tq_bwd, Dv),
+                col(tq_bwd), col(tq_bwd), mat(tq_bwd, Dv),
             ],
             out_specs=[
-                mat(tq_bwd), col(tq_bwd), col(tq_bwd), mat(tq_bwd),
+                mat(tq_bwd), col(tq_bwd), col(tq_bwd), mat(tq_bwd, Dv),
                 col(tq_bwd), col(tq_bwd), col(tq_bwd),
             ],
             # the visited chunks' scores and dP * P between the walks
@@ -861,7 +878,7 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
         ),
         out_shape=[
             sds((BH, Tq, D)), sds((BH, Tq, 1)), sds((BH, Tq, 1)),
-            sds((BH, Tq, D)), sds((BH, Tq, 1)), sds((BH, Tq, 1)),
+            sds((BH, Tq, Dv)), sds((BH, Tq, 1)), sds((BH, Tq, 1)),
             sds((BH, Tq, 1)),
         ],
         interpret=interpret,
@@ -869,13 +886,13 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
         name=_kernel_name("bwd_dq", window),
     )(
         scalars, q4, k4, v4,
-        m.reshape(BH, Tq, 1), l.reshape(BH, Tq, 1), acc.reshape(BH, Tq, D),
+        m.reshape(BH, Tq, 1), l.reshape(BH, Tq, 1), acc.reshape(BH, Tq, Dv),
         dm.reshape(BH, Tq, 1), dl4, dacc4,
     )
 
-    kmat = pl.BlockSpec(
-        (1, tk_bwd, D), lambda i, jk, jq, *_: (i, jk, 0), memory_space=pltpu.VMEM
-    )
+    def kmat(width):
+        return pl.BlockSpec((1, tk_bwd, width), lambda i, jk, jq, *_: (i, jk, 0), memory_space=pltpu.VMEM)
+
     def q_block(i, jk, jq, scalars_ref):
         head = i
         if group > 1:  # the group's query heads one after another
@@ -892,17 +909,19 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
                 jq = jnp.minimum(jq, last_seen)
         return (head, jq, 0)
 
-    qmat = pl.BlockSpec((1, tq_dkv, D), q_block, memory_space=pltpu.VMEM)
+    def qmat(width):
+        return pl.BlockSpec((1, tq_dkv, width), q_block, memory_space=pltpu.VMEM)
+
     qcol = pl.BlockSpec((1, tq_dkv, 1), q_block, memory_space=pltpu.VMEM)
     dk_o, dv_o = pl.pallas_call(
         dkv_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(BHkv, Tk // tk_bwd, group * n_q_dkv),
-            in_specs=[kmat, kmat, qmat, qmat, qcol, qcol, qcol, qcol],
-            out_specs=[kmat, kmat],
+            in_specs=[kmat(D), kmat(Dv), qmat(D), qmat(Dv), qcol, qcol, qcol, qcol],
+            out_specs=[kmat(D), kmat(Dv)],
         ),
-        out_shape=[sds((BHkv, Tk, D)), sds((BHkv, Tk, D))],
+        out_shape=[sds((BHkv, Tk, D)), sds((BHkv, Tk, Dv))],
         interpret=interpret,
         compiler_params=_compiler_params(),
         name=_kernel_name("bwd_dkv", window),
@@ -911,8 +930,8 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
     return (
         dq_o.reshape(B_, H, Tq, D),
         dk_o.reshape(B_, Hkv, Tk, D),
-        dv_o.reshape(B_, Hkv, Tk, D),
+        dv_o.reshape(B_, Hkv, Tk, Dv),
         dm_o.reshape(B_, H, Tq),
         dl_o.reshape(B_, H, Tq),
-        dacc_o.reshape(B_, H, Tq, D),
+        dacc_o.reshape(B_, H, Tq, Dv),
     )

@@ -1,6 +1,6 @@
 """The LM step's kernels compiled for the real chip at OLMoE's published
 shape, and the whole step program at ZAYA1-8B's, Ouro's, Laguna's and
-Nemotron-3-Nano's cuts, without the chip: libtpu's compiler runs here against a described
+Nemotron-3-Nano's and JoyAI-LLM-Flash's cuts, without the chip: libtpu's compiler runs here against a described
 v5e (docs and recipe: the ``on-chip-measurement`` guide, section 2). It
 catches what interpret mode cannot - Mosaic's lowering rules and the
 scoped-VMEM limit - at no chip time. Nothing runs; no time is measured.
@@ -64,6 +64,8 @@ def _fold_grads(b, h, t, window=None):
 #: of Laguna-XS.2's windowed layers, 64 query heads on 8 at 4,096 through 512 keys.
 FOLDS = {"olmoe_4x16x4096": (B, H, H, T, None), "zaya_2x8on2x8192": (2, 8, 2, 8192, None),
          "laguna_2x64on8x4096_w512": (2, 64, 8, 4096, 512)}
+#: latent attention's heads: 192 query and key channels, 128 value channels
+D_K = 192
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16", "float32"])
@@ -88,6 +90,42 @@ def test_fused_fold_trains_at_the_cells_shapes(one_chip, dtype, fold):
     assert dk.shape == dv.shape == (b, h_kv, t, D)
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("batch", [1, 2])
+def test_fused_fold_trains_at_a_value_head_of_its_own_size(one_chip, dtype, batch):
+    """Forward and both backward kernels in one training graph at latent
+    attention's shape: 32 heads x 8,192 positions, queries and keys 192
+    channels wide, values 128 (``flash_available`` admits it with the value
+    size stated and refuses 192 / 192): Mosaic takes the 1.5-tile contraction,
+    K and V are staged at their own widths, ``dk`` comes out 192 wide and
+    ``dv`` 128."""
+    from flink_ml_tpu.parallel.flash import flash_available, fused_fold
+
+    h, t = 32, 8192
+    devices = list(one_chip.device_set)
+    assert flash_available(t, D_K, devices, Dv=D) and not flash_available(t, D_K, devices)
+
+    def grads(q, k, v):
+        def loss(q, k, v):
+            m0 = jnp.full((batch, h, t), -jnp.inf, jnp.float32)
+            l0 = jnp.zeros((batch, h, t), jnp.float32)
+            acc0 = jnp.zeros((batch, h, t, D), jnp.float32)
+            zero = jnp.int32(0)
+            _, l, acc = fused_fold(q, k, v, m0, l0, acc0, zero, zero, True, False, zero, D_K ** -0.5, False)
+            return jnp.sum(acc / l[..., None])
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    qk = jax.ShapeDtypeStruct((batch, h, t, D_K), dtype, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((batch, h, t, D), dtype, sharding=one_chip)
+    text = _compile(grads, qk, qk, v).as_text()
+    for kernel in ("flash_fold_fwd", "flash_fold_bwd_dq", "flash_fold_bwd_dkv"):
+        assert kernel in text
+    assert f"f32[{batch * h},{t},{t}]" not in text
+    dq, dk, dv = jax.eval_shape(grads, qk, qk, v)
+    assert dq.shape == dk.shape == (batch, h, t, D_K) and dv.shape == (batch, h, t, D)
+
+
 def test_the_ring_fold_trains_at_the_largest_admitted_shape(one_chip):
     """What ``ring_attention`` hands the fold, at the most ``flash_available``
     admits (T 8,192 x D 128, float32): ``causal`` with TRACED positions, so
@@ -96,7 +134,7 @@ def test_the_ring_fold_trains_at_the_largest_admitted_shape(one_chip):
     from flink_ml_tpu.parallel.flash import flash_available, fused_fold
 
     b, h, t = 1, 4, 8192
-    assert t * D == 1 << 20 and not flash_available(2 * t, D // 2, list(one_chip.device_set))
+    assert flash_available(t, D, list(one_chip.device_set)) and not flash_available(2 * t, D // 2, list(one_chip.device_set))
 
     def grads(q, k, v, q_pos0, k_pos0, n_valid):
         def loss(q, k, v):
@@ -342,6 +380,57 @@ def test_the_nemotron_step_program_at_the_cells_shapes(one_chip, monkeypatch):
     assert f"[{batch},{t},{cfg.ssm_heads},{cfg.ssm_head_dim},{cfg.ssm_state}]" not in text  # no state a position
 
 
+def _joyai_cut():
+    """``(the benchmark's joyai_llm_flash configuration, its LMConfig)``."""
+    from perfbench.systems import joyai_lm_fit
+
+    c = _cell_config("joyai_llm_flash")
+    return c, joyai_lm_fit.lm_config(c)
+
+
+def test_the_joyai_step_program_at_the_cells_shapes(one_chip):
+    """The whole jitted step of the ``joyai_llm_flash`` configuration at 1 x
+    8,192 tokens: five rematerialised layers of two kinds (latent attention
+    with the dense SwiGLU; latent attention with 16 held experts beside the
+    shared one) and the multi-token-prediction module's one more behind them,
+    two passes over the head. It fits the HBM ``fit`` compiles a step into:
+    XLA's analysis reads 7.26 GB of temporaries beside 8.17 GB of arguments,
+    15.42e9 B in all (at two sequences a step, ISSUE 44's first choice, the
+    compile fails: "Used 17.40G of 14.67G hbm"). The fold's three kernels are
+    Mosaic's at a head of 192 query and key channels and 128 value channels,
+    T 8,192, under their one set of names: K enters at ``[32, 8192, 192]`` and
+    V at ``[32, 8192, 128]`` once a head, and no score tensor is an array of
+    the program; the held experts' grouped matmuls are the grouped kernel in
+    both directions over a window of 8,192 sorted rows at a time."""
+    from flink_ml_tpu.models.lm.config import num_params
+
+    c, cfg = _joyai_cut()
+    assert num_params(cfg) == 680_441_088  # 10.89 GB of f32 state at 16 bytes a parameter: 68% of 16 GB
+    batch, t = c["global_batch_size"], c["sequence_length"]
+    compiled, memory = _compiled_step(c, cfg, one_chip)
+    assert memory.temp_size_in_bytes < 7.33e9, memory.temp_size_in_bytes  # what it reads and 1%
+    text = compiled.as_text()
+    for kernel in ("flash_fold_fwd", "flash_fold_bwd_dq", "flash_fold_bwd_dkv"):
+        assert kernel in text
+    assert "flash_fold_win_" not in text
+    heads, d_k, d_v = batch * cfg.n_heads, cfg.nope_dim + cfg.rope_dim, cfg.v_dim
+    assert f"bf16[{heads},{t},{d_k}]" in text and f"bf16[{heads},{t},{d_v}]" in text
+    assert f"f32[{batch},{cfg.n_heads},{t},{t}]" not in text and f"f32[{heads},{t},{t}]" not in text
+    kernels = [ln for ln in text.splitlines() if ln.lstrip().startswith("%ragged-dot") and "custom-call(" in ln]
+    sparse = cfg.n_layers - cfg.n_dense + cfg.mtp_depth
+    assert len(kernels) >= 8 * sparse
+    assert "convolution_select_fusion" not in text
+    routed = batch * t * cfg.top_k  # 65,536 routed rows a layer, a window of an eighth of them at a time
+    assert f"f32[{routed},{cfg.hidden}]" not in text and f"bf16[{routed // 8},{cfg.hidden}]" in text
+    # the module is in the step, by name: its layer's latents and its pass over the head under ``lm.mtp``
+    import re
+
+    from perfbench.op_scopes import classify
+
+    scopes = {classify(name, "lm.")[0] for name in set(re.findall(r'op_name="([^"]*lm\.mtp[^"]*)"', text))}
+    assert {("lm.mtp", "lm.block", "latent"), ("lm.mtp", "lm.head"), ("lm.mtp", "proj")} <= scopes
+
+
 def test_the_ouro_step_program_at_the_cells_shapes(one_chip):
     """The whole jitted step of the ``ouro_2_6b`` configuration at 2 x 4,096
     tokens: six rematerialised dense blocks inside one scanned pass run four
@@ -367,8 +456,8 @@ def test_the_ouro_step_program_at_the_cells_shapes(one_chip):
     assert f"f32[{batch},{cfg.n_heads},{t},{t}]" not in text and f"f32[{batch * cfg.n_heads},{t},{t}]" not in text
 
 
-@pytest.mark.parametrize("cut", [_zaya_cut, _ouro_cut, _laguna_cut, _nemotron_cut],
-                         ids=["zaya", "ouro", "laguna", "nemotron"])
+@pytest.mark.parametrize("cut", [_zaya_cut, _ouro_cut, _laguna_cut, _nemotron_cut, _joyai_cut],
+                         ids=["zaya", "ouro", "laguna", "nemotron", "joyai"])
 def test_the_state_program_at_the_cells_shapes(one_chip, cut):
     """AdamW's state as ``DecoderLM._fit`` makes it, ``optimizer.init`` jitted,
     at the cells' parameter trees: one program whose outputs are the whole

@@ -3,13 +3,15 @@ description of a layer (``config.layers``) took over from a leaves function a
 kind: leaf for leaf the names, shapes, init kinds and - the initialiser numbers
 leaves by position - the ORDER that 512ebfa (PR 42) gave. A moved leaf is
 another model from the same seed. Toys by literal; the benchmark's five LM
-configurations by the digest of the list and by their parameter counts."""
+configurations by the digest of the list and by their parameter counts. The
+``joyai`` kind (PR 44) is pinned beside them as it came: its stack's leaves,
+then ``final_norm`` and ``lm_head``, then the multi-token-prediction module."""
 import hashlib
 
 import pytest
 
 from flink_ml_tpu.models.lm.config import LMConfig, layers, num_params, param_shapes
-from tests.test_lm_chip_compile import _cell_config, _laguna_cut, _nemotron_cut, _ouro_cut, _zaya_cut
+from tests.test_lm_chip_compile import _cell_config, _joyai_cut, _laguna_cut, _nemotron_cut, _ouro_cut, _zaya_cut
 
 TOYS = {
     "olmoe": LMConfig(n_layers=1, hidden=128, n_heads=4, n_experts=8, top_k=2, expert_width=64, vocab=512),
@@ -27,6 +29,10 @@ TOYS = {
                            n_kv_heads=2, head_size=16, shared_width=48, routed_scale=2.5,
                            layer_kinds=tuple("M*E"), ssm_heads=8, ssm_head_dim=8, ssm_groups=2, ssm_state=16,
                            conv_kernel=4, chunk=64),
+    "joyai": LMConfig(n_layers=2, hidden=64, n_heads=4, n_experts=16, top_k=2, expert_width=32, vocab=512,
+                      rope_theta=3.2e7, norm_eps=1e-6, aux_coef=0.0, block="joyai", experts_held=2, first_held=2,
+                      n_dense=1, dense_width=96, shared_width=32, routed_scale=2.5, q_rank=48, kv_rank=32, nope_dim=16,
+                      rope_dim=8, v_dim=12, mtp_depth=1, mtp_coef=0.3),
 }
 #: ``(dotted path, shape, init)`` of every leaf in ``param_shapes`` order, printed by 512ebfa's ``param_shapes``
 TOY_TREES = {
@@ -109,6 +115,33 @@ TOY_TREES = {
         ('layers.2.w_up', (2, 64, 32), 'normal'), ('layers.2.w_down', (2, 32, 64), 'normal'),
         ('final_norm', (64,), 'ones'), ('lm_head', (64, 512), 'normal'),
     ],
+    "joyai": [
+        ('embed', (512, 64), 'normal'), ('layers.0.attn_norm', (64,), 'ones'), ('layers.0.wq_a', (64, 48), 'normal'),
+        ('layers.0.q_a_norm', (48,), 'ones'), ('layers.0.wq_b', (48, 96), 'normal'),
+        ('layers.0.wkv_a', (64, 40), 'normal'), ('layers.0.kv_a_norm', (32,), 'ones'),
+        ('layers.0.wkv_b', (32, 112), 'normal'), ('layers.0.wo', (48, 64), 'normal'),
+        ('layers.0.ffn_norm', (64,), 'ones'), ('layers.0.w_gate', (64, 96), 'normal'),
+        ('layers.0.w_up', (64, 96), 'normal'), ('layers.0.w_down', (96, 64), 'normal'),
+        ('layers.1.attn_norm', (64,), 'ones'), ('layers.1.wq_a', (64, 48), 'normal'),
+        ('layers.1.q_a_norm', (48,), 'ones'), ('layers.1.wq_b', (48, 96), 'normal'),
+        ('layers.1.wkv_a', (64, 40), 'normal'), ('layers.1.kv_a_norm', (32,), 'ones'),
+        ('layers.1.wkv_b', (32, 112), 'normal'), ('layers.1.wo', (48, 64), 'normal'),
+        ('layers.1.ffn_norm', (64,), 'ones'), ('layers.1.router', (64, 16), 'normal'),
+        ('layers.1.router_bias', (16,), 'zeros'), ('layers.1.shared_gate', (64, 32), 'normal'),
+        ('layers.1.shared_up', (64, 32), 'normal'), ('layers.1.shared_down', (32, 64), 'normal'),
+        ('layers.1.w_gate', (2, 64, 32), 'normal'), ('layers.1.w_up', (2, 64, 32), 'normal'),
+        ('layers.1.w_down', (2, 32, 64), 'normal'), ('final_norm', (64,), 'ones'), ('lm_head', (64, 512), 'normal'),
+        ('mtp.enorm', (64,), 'ones'), ('mtp.hnorm', (64,), 'ones'), ('mtp.eh_proj', (128, 64), 'normal'),
+        ('mtp.layer.attn_norm', (64,), 'ones'), ('mtp.layer.wq_a', (64, 48), 'normal'),
+        ('mtp.layer.q_a_norm', (48,), 'ones'), ('mtp.layer.wq_b', (48, 96), 'normal'),
+        ('mtp.layer.wkv_a', (64, 40), 'normal'), ('mtp.layer.kv_a_norm', (32,), 'ones'),
+        ('mtp.layer.wkv_b', (32, 112), 'normal'), ('mtp.layer.wo', (48, 64), 'normal'),
+        ('mtp.layer.ffn_norm', (64,), 'ones'), ('mtp.layer.router', (64, 16), 'normal'),
+        ('mtp.layer.router_bias', (16,), 'zeros'), ('mtp.layer.shared_gate', (64, 32), 'normal'),
+        ('mtp.layer.shared_up', (64, 32), 'normal'), ('mtp.layer.shared_down', (32, 64), 'normal'),
+        ('mtp.layer.w_gate', (2, 64, 32), 'normal'), ('mtp.layer.w_up', (2, 64, 32), 'normal'),
+        ('mtp.layer.w_down', (2, 32, 64), 'normal'), ('mtp.norm', (64,), 'ones'),
+    ],
 }
 
 
@@ -129,6 +162,8 @@ CELLS = {
     "laguna_xs2": (_laguna_cut, 691_624_960, "19afbae2c071718b1b34260a15131e6907f4cae9e28a3561119774cbf244fc01", 3),
     "nemotron3_nano_30b": (_nemotron_cut, 666_963_456,
                            "f41d67cb520ef871addd10ae18b186dcc768f777eb3c201e86c700ff33bd1c08", 3),
+    # as PR 44 brought it: the stack's two records, the module's layer being the second again
+    "joyai_llm_flash": (_joyai_cut, 680_441_088, "4d26d90bc1061ad9243f991a5a9928089e0e0c91df5c30ca033534a38a14b211", 2),
 }
 
 

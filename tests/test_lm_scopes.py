@@ -43,6 +43,18 @@ KINDS = {
                             conv_kernel=4, chunk=64), True,
                    {"lm.block/scan", "lm.block/gnorm", "lm.block/conv", "lm.block/route", "lm.block/permute",
                     "lm.block/experts", "lm.block/shared"}),
+    # the multi-token-prediction module's parts all lie under ``lm.mtp``: its layer's as ``lm.mtp/lm.block/...``,
+    # its pass over the head as ``lm.mtp/lm.head``
+    "joyai": (LMConfig(n_layers=2, hidden=64, n_heads=4, n_experts=8, top_k=2, expert_width=32, vocab=512,
+                       rope_theta=3.2e7, norm_eps=1e-6, aux_coef=0.0, block="joyai", experts_held=4, first_held=2,
+                       n_dense=1, dense_width=96, shared_width=32, routed_scale=2.5, q_rank=48, kv_rank=32,
+                       nope_dim=16, rope_dim=8, v_dim=12, mtp_depth=1, mtp_coef=0.3), True,
+              {"lm.block/latent", "lm.block/latent/norm", "lm.block/ffn", "lm.block/route", "lm.block/permute",
+               "lm.block/experts", "lm.block/shared", "lm.mtp/proj", "lm.mtp/proj/norm", "lm.mtp/norm",
+               "lm.mtp/lm.head", "lm.mtp/lm.block/norm", "lm.mtp/lm.block/latent", "lm.mtp/lm.block/latent/norm",
+               "lm.mtp/lm.block/rope", "lm.mtp/lm.block/fold", "lm.mtp/lm.block/proj", "lm.mtp/lm.block/mix",
+               "lm.mtp/lm.block/route", "lm.mtp/lm.block/permute", "lm.mtp/lm.block/experts",
+               "lm.mtp/lm.block/shared"}),
 }
 EVERY_KIND = {"lm.embed", "lm.block/norm", "lm.block/proj", "lm.block/fold", "lm.block/mix",
               "lm.final_norm/norm", "lm.head", "lm.opt"}
@@ -118,6 +130,11 @@ def test_the_head_runs_in_three_directions_and_a_block_is_recomputed_where_it_is
         assert {("lm.block/experts", BWD), ("lm.block/permute", BWD)} <= found
     if kind == "nemotron_h":  # the scan is AD's: each of its parts in all three directions
         assert {d for scope, d in found if scope == "lm.block/scan"} == {FWD, REMAT, BWD}
+    if kind == "joyai":  # the module's parts in every direction, its layer recomputed like the stack's
+        for scope in ("lm.mtp/lm.head", "lm.mtp/lm.block/latent", "lm.mtp/lm.block/fold"):
+            assert {d for s, d in found if s == scope} == {FWD, REMAT, BWD}, scope
+        assert {d for s, d in found if s == "lm.mtp/proj"} == {FWD, BWD}
+        assert {d for s, d in found if s == "lm.block/latent"} == {FWD, REMAT, BWD}
     if kind == "laguna":  # the windowed layer's kernels under names of their own, beside the full layers'
         names = {n.split("/fold/")[1].split("/")[0] for _, n in step if n and "/fold/flash_fold" in n}
         assert names == {f"flash_fold_{w}{k}" for w in ("", "win_") for k in ("fwd", "bwd_dq", "bwd_dkv")}
@@ -137,7 +154,8 @@ def test_the_update_is_outside_the_gradient(programs, kind):
 #: A looped stack's ``lax.scan`` stacks each pass's outputs and sums the shared
 #: weights' cotangents over the passes (``dynamic_update_slice``, ``add_any``)
 #: in code that is JAX's own, under no scope of the program.
-COVERED = {"olmoe": 0.95, "olmoe_stacked": 0.95, "zaya": 0.95, "ouro": 0.90, "laguna": 0.95, "nemotron_h": 0.95}
+COVERED = {"olmoe": 0.95, "olmoe_stacked": 0.95, "zaya": 0.95, "ouro": 0.90, "laguna": 0.95, "nemotron_h": 0.95,
+           "joyai": 0.95}
 
 
 @pytest.mark.parametrize("kind", sorted(KINDS))
@@ -159,5 +177,6 @@ def test_the_scoring_program_shares_the_scopes_and_has_no_backward(programs, kin
     scopes = {scope for scope, _ in found}
     assert {"lm.embed", "lm.block/norm", "lm.block/fold", "lm.final_norm/norm", "lm.head"} <= scopes
     assert "lm.opt" not in scopes and "lm.aux" not in scopes
+    assert not [s for s in scopes if s.startswith("lm.mtp")]  # the module is a training objective
     assert {d for _, d in found} == {FWD}
     assert not [n for _, n in score if n and "transpose(" in n]

@@ -67,6 +67,24 @@ def test_flash_gate_rejects_unverified_boundary_shapes():
     assert not flash_available(TQ_TILE - 1, 64, devs)  # tiling still enforced
 
 
+def test_flash_gate_budgets_keys_and_values_at_their_own_widths():
+    """A value head of its own size: the gate budgets what a cell stages, K at
+    ``D`` plus V at ``Dv``. Latent attention's 192 / 128 at T 8,192 is the
+    largest shape compiled in a training graph on the chip (PERF.md, PR 44) and
+    is the budget; the same keys with values as wide as them are past it."""
+    from flink_ml_tpu.parallel.flash import flash_available
+
+    class FakeTpu:
+        device_kind = "TPU v5 lite"
+
+    devs = [FakeTpu()]
+    assert flash_available(8192, 192, devs, Dv=128)
+    assert not flash_available(8192, 192, devs) and not flash_available(8192, 192, devs, Dv=192)
+    assert not flash_available(8192, 192, devs, Dv=256) and not flash_available(16384, 96, devs, Dv=64)
+    assert flash_available(4096, 256, devs) == flash_available(4096, 256, devs, Dv=256) is True  # None: as wide as K
+    assert not flash_available(8192, 192, [object()], Dv=128)  # and the devices are still asked
+
+
 def test_padded_sequence_with_n_valid_matches_dense():
     rng = np.random.default_rng(2)
     B, T_real, H, D = 1, 50, 2, 8
@@ -324,7 +342,18 @@ class TestCausalChunks:
         "grouped-queries": (1024, 0, None, 4, 1, "-inf"),
         "n-valid-inside-a-chunk": (2048, 0, 1300, 2, 2, "-inf"),  # chunk 1 is full under causal, cut by n_valid
         "tie-in-two-chunks": (3072, 0, None, 1, 1, "-inf"),
+        # a value head of its own size (HEADS): the names sort last, so the cases above keep their seeds
+        "value-head-192-128": (1024, 0, None, 2, 2, "-inf"),  # latent attention's head
+        "value-head-narrower": (1637, 100, None, 2, 2, "finite"),
+        "value-head-narrower-grouped": (1024, 0, None, 4, 1, "-inf"),
+        "value-head-wider-n-valid": (2048, 0, 1300, 2, 2, "finite"),
     }
+    #: case -> (channels of q and k, channels of v and acc) where they differ from ``D``, ``D``
+    HEADS = {"value-head-192-128": (192, 128), "value-head-narrower": (24, 16),
+             "value-head-narrower-grouped": (24, 16), "value-head-wider-n-valid": (8, 40)}
+
+    def _scale(self, case):
+        return 1.0 / np.sqrt(self.HEADS.get(case, (self.D,))[0])
 
     def _inputs(self, case):
         import jax.numpy as jnp
@@ -332,17 +361,18 @@ class TestCausalChunks:
         qp, kp, nv, H, Hkv, m_kind = self.CASES[case]
         rng = np.random.default_rng(sorted(self.CASES).index(case))
         r = lambda *sh: rng.normal(size=sh).astype(np.float32)
-        q, kb, vb = r(1, H, self.TQ, self.D), r(1, Hkv, self.TK, self.D), r(1, Hkv, self.TK, self.D)
+        d, dv = self.HEADS.get(case, (self.D, self.D))
+        q, kb, vb = r(1, H, self.TQ, d), r(1, Hkv, self.TK, d), r(1, Hkv, self.TK, dv)
         if case == "tie-in-two-chunks":
             # keys 100 (chunk 0) and 1500 (chunk 1) are one vector, and the max of rows 5 and 700
             kb[0, 0, 100] = kb[0, 0, 1500] = 3.0
             q[0, 0, 5] = q[0, 0, 700] = 3.0
         if m_kind == "-inf":
             m, l, acc = np.full((1, H, self.TQ), -np.inf, np.float32), np.zeros((1, H, self.TQ), np.float32), \
-                np.zeros((1, H, self.TQ, self.D), np.float32)
+                np.zeros((1, H, self.TQ, dv), np.float32)
         else:
-            m, l, acc = r(1, H, self.TQ) * 0.5, np.abs(r(1, H, self.TQ)) + 0.5, r(1, H, self.TQ, self.D)
-        cot = r(1, H, self.TQ), r(1, H, self.TQ), r(1, H, self.TQ, self.D)
+            m, l, acc = r(1, H, self.TQ) * 0.5, np.abs(r(1, H, self.TQ)) + 0.5, r(1, H, self.TQ, dv)
+        cot = r(1, H, self.TQ), r(1, H, self.TQ), r(1, H, self.TQ, dv)
         as_j = lambda *xs: tuple(jnp.asarray(x) for x in xs)
         return as_j(q, kb, vb), as_j(m, l, acc), as_j(*cot), (qp, kp, nv, H // Hkv)
 
@@ -353,7 +383,7 @@ class TestCausalChunks:
         from flink_ml_tpu.parallel.flash import _fold_pallas, reference_fold
 
         (q, kb, vb), state, _, (qp, kp, nv, group) = self._inputs(case)
-        scale = 1.0 / np.sqrt(self.D)
+        scale = self._scale(case)
         got = _fold_pallas(q, kb, vb, *state, qp, kp, True, nv, scale, interpret=True)
         want = reference_fold(
             q, jnp.repeat(kb, group, axis=1), jnp.repeat(vb, group, axis=1), *state, qp, kp, True, nv, scale
@@ -373,7 +403,7 @@ class TestCausalChunks:
         from flink_ml_tpu.parallel.flash import _fold_bwd_pallas, reference_fold, reference_fold_bwd
 
         (q, kb, vb), state, cot, (qp, kp, nv, group) = self._inputs(case)
-        scale = 1.0 / np.sqrt(self.D)
+        scale = self._scale(case)
         k_rep, v_rep = jnp.repeat(kb, group, axis=1), jnp.repeat(vb, group, axis=1)
         _, vjp = jax.vjp(
             lambda q_, k_, v_, m_, l_, a_: reference_fold(q_, k_, v_, m_, l_, a_, qp, kp, True, nv, scale),
@@ -393,6 +423,7 @@ class TestCausalChunks:
         for i, name in enumerate(["dq", "dk", "dv", "dm", "dl", "dacc"]):
             for want, whose in ((by_ad[i], "jax AD"), (by_hand[i], "reference_fold_bwd")):
                 want = per_kv_head(want) if name in ("dk", "dv") else want
+                assert got[i].shape == want.shape, f"{case}/{name}"  # dk at q's channels, dv and dacc at v's
                 _assert_close(name, got[i], want, 2e-5, 2e-5, f"{case}/{name} against {whose}")
 
     def test_hidden_chunks_are_never_read(self):
@@ -493,3 +524,34 @@ def test_a_block_of_one_chunk_is_taken_whole():
     assert fold_chunk_counts(1024, 1024, 0, True) == (4 + 16 + 1, 4 + 16 + 1)
     assert fold_chunk_counts(1024, 1024, -1024, True) == (4 + 16 + 0, 4 + 16 + 1)
     assert _fold_tiles(1024, 2048, True)[:2] == (512, 512)  # two chunks: walked
+
+
+#: window -> sha256 of ``str(make_jaxpr(value_and_grad(fold)))`` with addresses blanked, at 3738dc6 (PR 43), before
+#: the fold took a value head of its own size: 4 query heads on 2 key/value heads x 2,048 x 16, two key chunks
+FOLD_JAXPRS = {None: "672b7326f961a89fb465717d93b2d8e62b7921680905d388b94ecd3ae7b1e261",
+               300: "0a92f0254a896f585ff298a1d753122f08d63f19b7332091ef5b09fc23cc7639"}
+
+
+@pytest.mark.parametrize("window", sorted(FOLD_JAXPRS, key=str))
+def test_with_equal_head_sizes_the_fold_traces_to_what_it_was(window):
+    """A call whose values are as wide as its keys lowers to the kernels it lowered to before ``vb`` could be
+    narrower: the three kernels' block specs, grids and bodies, character for character."""
+    import hashlib
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from flink_ml_tpu.parallel.flash import fused_fold
+
+    def fold(q, k, v):
+        b, h, t, d = q.shape
+        m0, l0 = jnp.full((b, h, t), -jnp.inf, jnp.float32), jnp.zeros((b, h, t), jnp.float32)
+        acc0 = jnp.zeros((b, h, t, v.shape[-1]), jnp.float32)
+        _, l, acc = fused_fold(q, k, v, m0, l0, acc0, jnp.int32(0), jnp.int32(0), True, False, jnp.int32(0),
+                               d ** -0.5, True, window)
+        return jnp.sum(acc / l[..., None])
+
+    shape = lambda heads: jax.ShapeDtypeStruct((1, heads, 2048, 16), jnp.float32)  # noqa: E731
+    text = str(jax.make_jaxpr(jax.value_and_grad(fold, argnums=(0, 1, 2)))(shape(4), shape(2), shape(2)))
+    assert hashlib.sha256(re.sub(r"0x[0-9a-f]+", "0x", text).encode()).hexdigest() == FOLD_JAXPRS[window]
