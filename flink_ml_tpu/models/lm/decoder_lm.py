@@ -66,11 +66,17 @@ float32 regardless.
 Memory (what lets 626 M parameters and 16,384 tokens a step share one 16 GB
 chip): the experts' backward recomputes their two hidden projections from
 the sorted rows (``parallel/moe.py``), the head's ``[tokens, vocabulary]``
-logits exist one token chunk at a time, forward and backward, and where there
-is more than one block each is rematerialised in the backward
-(``jax.checkpoint``). The looped stack is a ``lax.scan`` over its passes (the
-traced program is one pass): what it holds for the backward is the input of
-each of its ``layers x loops`` block applications and each pass's output.
+logits exist one token chunk at a time and ONCE a step: every objective here
+is a weighted sum of per-token cross-entropies with weights known before the
+head runs (the mean's ``1 / (B (T - 1))``, a module's coefficient over its
+targets, a looped stack's exit distribution), so the pass that holds a chunk's
+logits forms ``w (softmax - onehot)``, that chunk's ``dh`` and its share of
+``dW`` there and then, and the backward only scales them (``_next_token_nll``,
+``_weighted_nll``); and where there is more than one block each is
+rematerialised in the backward (``jax.checkpoint``). The looped stack is a
+``lax.scan`` over its passes (the traced program is one pass): what it holds
+for the backward is the input of each of its ``layers x loops`` block
+applications and each pass's output.
 On the TPU the step is compiled into a stated size (``STEP_HBM_MIB``): XLA
 rematerialises further, toward arguments and temporaries that fit it.
 
@@ -865,28 +871,99 @@ def _head(params, cfg: LMConfig):
         return params["embed"].T
 
 
+def _chunk_nll(logits, tc):
+    """``(logsumexp, nll)`` of one chunk's logits against its targets ``tc``."""
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    return lse, lse - jnp.take_along_axis(logits, tc[:, None], axis=1)[:, 0]
+
+
+def _chunked_nll(h, lm_head, targets, cd):
+    """``[chunks, chunk]`` f32: the per-token negative log-likelihoods of ``h
+    [chunks, chunk, d]`` against ``targets``, a chunk's logits at a time."""
+    w = lm_head.astype(cd)
+
+    def one(args):
+        hc, tc = args
+        return _chunk_nll(_matmul(hc, w, cd), tc)[1]
+
+    return jax.lax.map(one, (h, targets))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _weighted_nll(h, lm_head, targets, weight, cd):
+    """``(sum_i weight_i nll_i, nll)`` over ``h [chunks, chunk, d]``: the
+    training head. Differentiated, the pass that holds a chunk's logits forms
+    that chunk's ``dh`` and its share of ``dW`` there and then (``_weighted_nll_fwd``),
+    so the logits are computed once a step; the backward scales them. ``nll``
+    carries no gradient: its cotangent is not read."""
+    nll = _chunked_nll(h, lm_head, targets, cd)
+    return jnp.sum(weight * nll), nll
+
+
+def _weighted_nll_fwd(h, lm_head, targets, weight, cd):
+    w = lm_head.astype(cd)
+
+    def one(dw, args):
+        hc, tc, wc = args
+        # a chunk's rows are rounded inside the loop: left free, XLA hoists the cast into one [chunks, chunk, d] stack
+        # in the compute type, and past the 8 chunks (64 MB) it can keep in VMEM the matmuls that read it stream it from
+        # HBM a tile of their output at a time (Ouro's 16 chunks: ``dW`` 3.7 ms a chunk where 2.7 is what it takes)
+        hc = jax.lax.optimization_barrier(hc.astype(cd))
+        logits = _matmul(hc, w, cd)
+        lse, nll = _chunk_nll(logits, tc)
+        hit = jax.lax.broadcasted_iota(tc.dtype, logits.shape, 1) == tc[:, None]
+        # d total / d logits, rounded where the two matmuls below take it in
+        d = (wc[:, None] * (jnp.exp(logits - lse[:, None]) - hit.astype(jnp.float32))).astype(cd)
+        precision = _HIGHEST if cd == jnp.float32 else None
+        dh = jax.lax.dot_general(d, w, (((1,), (1,)), ((), ())), precision=precision,
+                                 preferred_element_type=jnp.float32)
+        dw = dw + jax.lax.dot_general(hc, d, (((0,), (0,)), ((), ())), precision=precision,
+                                      preferred_element_type=jnp.float32)
+        return dw, (nll, dh)
+
+    dw, (nll, dh) = jax.lax.scan(one, jnp.zeros(lm_head.shape, jnp.float32), (h, targets, weight))
+    return (jnp.sum(weight * nll), nll), (dh, dw, nll)
+
+
+def _weighted_nll_bwd(cd, residuals, cotangents):
+    dh, dw, nll = residuals
+    g, _ = cotangents
+    return g * dh, g * dw, None, g * nll
+
+
+_weighted_nll.defvjp(_weighted_nll_fwd, _weighted_nll_bwd)
+
+
 def _next_token_nll(h, lm_head, tok, cd):
-    """``[B, T]`` f32: minus the log-probability of token ``t + 1`` at position
-    ``t`` (0 at the last position, which has no target). The ``[chunk, V]``
-    logits exist one chunk of token rows at a time, here and - recomputed -
-    in the backward."""
+    """The head over ``h [B, T, d]``, bound to its inputs: what comes back is
+    called with nothing, to score, and gives ``nll [B, T]`` f32, minus the
+    log-probability of token ``t + 1`` at position ``t`` (0 at the last
+    position, which has no target); or with token weights ``[B, T]`` f32, to
+    train, and gives ``(sum_i weight_i nll_i, nll)``, whose first is the
+    objective's part through the head - every caller's is linear in the
+    per-token ``nll`` - and whose ``nll`` is for reporting and carries no
+    gradient. The ``[chunk, V]`` logits exist one chunk of token rows at a
+    time and are computed ONCE a step: the pass that has them forms ``w_i
+    (softmax_i - onehot_i)`` and from it that chunk's ``dh`` and its share of
+    ``dW`` (float32 over the chunks), and the backward multiplies both by the
+    incoming scalar (``_weighted_nll``). The weights are differentiable (their
+    cotangent is ``nll``'s); the last position's is not read."""
     b, t, d = h.shape
     n = b * t
     chunk = _LOSS_CHUNK if n % _LOSS_CHUNK == 0 else t
     with jax.named_scope("lm.head"):
-        targets = jnp.concatenate([tok[:, 1:], jnp.zeros((b, 1), tok.dtype)], axis=1).reshape(n)
-        w = lm_head.astype(cd)
+        targets = jnp.concatenate([tok[:, 1:], jnp.zeros((b, 1), tok.dtype)], axis=1).reshape(n // chunk, chunk)
+        rows = h.reshape(n // chunk, chunk, d)
 
-        @jax.checkpoint
-        def one(args):
-            hc, tc = args
-            logits = jnp.dot(hc.astype(cd), w, preferred_element_type=jnp.float32,
-                             precision=_HIGHEST if cd == jnp.float32 else None)
-            picked = jnp.take_along_axis(logits, tc[:, None], axis=1)[:, 0]
-            return jax.nn.logsumexp(logits, axis=-1) - picked
+    def score(weight=None):
+        with jax.named_scope("lm.head"):
+            if weight is None:
+                return _chunked_nll(rows, lm_head, targets, cd).reshape(b, t).at[:, -1].set(0.0)
+            weight = weight.astype(jnp.float32).at[:, -1].set(0.0).reshape(n // chunk, chunk)
+            total, nll = _weighted_nll(rows, lm_head, targets, weight, cd)
+            return total, jax.lax.stop_gradient(nll).reshape(b, t).at[:, -1].set(0.0)
 
-        nll = jax.lax.map(one, (h.reshape(n // chunk, chunk, d), targets.reshape(n // chunk, chunk)))
-        return nll.reshape(b, t).at[:, -1].set(0.0)
+    return score
 
 
 def _load_balancing(routed, cfg: LMConfig):
@@ -911,20 +988,23 @@ def _exit_distribution(gate):
 def _exit_loss(passes, gate, lm_head, tok, cfg: LMConfig, cd):
     """The looped stack's objective: the mean over target positions of ``sum_r
     p_r nll_r - beta H(p)``, every pass through the one head in its one chunked
-    loop; and what ``train.drain`` reports: each pass's own mean cross-entropy
-    (``trip_nll``) and, summed over the step's tokens, the expected exit pass,
-    the mass left to the last pass and the exit distribution's entropy."""
+    loop under the weights ``p / targets`` (the gate's gradient reaches it
+    through them); and what ``train.drain`` reports: each pass's own mean
+    cross-entropy (``trip_nll``) and, summed over the step's tokens, the
+    expected exit pass, the mass left to the last pass and the exit
+    distribution's entropy."""
     r, b, t, d = passes.shape
+    targets = b * (t - 1)
     with jax.named_scope("lm.exit"):
         flat, tiled = passes.reshape(r * b, t, d), jnp.tile(tok, (r, 1))
-    nll = _next_token_nll(flat, lm_head, tiled, cd)
-    with jax.named_scope("lm.exit"):
-        nll = nll.reshape(r, b, t)
         log_p = _exit_distribution(gate)
         every = jnp.exp(log_p)
         p = every.at[:, :, -1].set(0.0)  # the last position has no target
-        targets = b * (t - 1)
-        loss = (jnp.sum(p * nll) + cfg.exit_beta * jnp.sum(p * log_p)) / targets
+        weight = (p / targets).reshape(r * b, t)
+    weighted, nll = _next_token_nll(flat, lm_head, tiled, cd)(weight)
+    with jax.named_scope("lm.exit"):
+        nll = nll.reshape(r, b, t)
+        loss = weighted + cfg.exit_beta * jnp.sum(p * log_p) / targets
         trip = jnp.arange(1, r + 1, dtype=jnp.float32)[:, None, None]
         return loss, {"trip_nll": jnp.sum(nll, axis=(1, 2)) / targets, "exit_trip_sum": jnp.sum(trip * every),
                       "exit_last_mass": jnp.sum(every[-1]), "gate_entropy_sum": -jnp.sum(every * log_p)}
@@ -935,22 +1015,29 @@ def _loss(params, tok, cfg: LMConfig, cd, interpret: bool):
     rows each expert took (``rows``), the rows each expert layer carried
     (``carried``), the exits' sums (``_exit_loss``), the multi-token-prediction
     module's summed cross-entropy and the positions it scored (``mtp_nll_sum``,
-    ``mtp_targets``). The module's term is ``mtp_coef`` times its mean."""
+    ``mtp_targets``). The module's term is ``mtp_coef`` times its mean. Each
+    term through the head is a weighted sum of per-token ``nll`` whose weights
+    the head takes in (``_next_token_nll``): the mean's ``1 / (B (T - 1))``,
+    the module's ``mtp_coef / mtp_targets`` on the positions it scores, the
+    exits' ``p / (B (T - 1))``."""
     h, routed, gate, ahead = _hidden(params, tok, cfg, cd, interpret, mtp=bool(cfg.mtp_depth))
+    b, t = tok.shape
     if gate is None:
-        nll = _next_token_nll(h, _head(params, cfg), tok, cd)
         with jax.named_scope("lm.head"):
-            loss, stats = jnp.sum(nll) / (tok.shape[0] * (tok.shape[1] - 1)), {}
+            mean = jnp.full((b, t), 1.0 / (b * (t - 1)), jnp.float32)
+        loss, _ = _next_token_nll(h, _head(params, cfg), tok, cd)(mean)
+        stats = {}
     else:
         loss, stats = _exit_loss(h, gate, _head(params, cfg), tok, cfg, cd)
     if ahead is not None:
         with jax.named_scope("lm.mtp"):
             # the same head over targets one further on: position i scores token i + 2, the last two nothing
-            nll = _next_token_nll(ahead, _head(params, cfg), jnp.roll(tok, -1, axis=1), cd)
-            scored = jnp.arange(tok.shape[1]) < tok.shape[1] - 2
+            scored = jnp.arange(t) < t - 2
+            stats["mtp_targets"] = b * jnp.sum(scored.astype(jnp.int32))
+            weight = jnp.broadcast_to(cfg.mtp_coef * scored / stats["mtp_targets"], (b, t))
+            term, nll = _next_token_nll(ahead, _head(params, cfg), jnp.roll(tok, -1, axis=1), cd)(weight)
             stats["mtp_nll_sum"] = jnp.sum(jnp.where(scored, nll, 0.0))
-            stats["mtp_targets"] = tok.shape[0] * jnp.sum(scored.astype(jnp.int32))
-            loss = loss + cfg.mtp_coef * stats["mtp_nll_sum"] / stats["mtp_targets"]
+            loss = loss + term
     if cfg.aux_coef:
         with jax.named_scope("lm.aux"):
             loss = loss + cfg.aux_coef * _load_balancing(routed, cfg)
@@ -993,19 +1080,40 @@ def _train_program(cfg: LMConfig, compute_type: str, lr: float, batch: int, inte
             jax.jit(step, donate_argnums=(0, 1), compiler_options=budget))
 
 
-#: What ``_conv_positions_kernel`` counted, by step program and window shape.
-_CONV_POSITIONS_KERNEL: dict = {}
+def _head_logit_matmuls(jaxpr, vocab: int, in_head: bool = False) -> int:
+    """The matmuls ``[chunk, d] @ [d, vocab]`` under ``lm.head`` among
+    ``jaxpr``'s equations and those of every jaxpr inside them (a loop's body,
+    what a ``checkpoint`` recomputes): each is one pass over a chunk's logits."""
+    found = 0
+    for eqn in jaxpr.eqns:
+        inside = in_head or "lm.head" in str(eqn.source_info.name_stack)
+        if (inside and eqn.primitive.name == "dot_general" and eqn.outvars[0].aval.shape[1:] == (vocab,)
+                and eqn.params["dimension_numbers"][0] == ((1,), (0,))):
+            found += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _head_logit_matmuls(sub, vocab, inside)
+    return found
 
 
-def _conv_positions_kernel(step, params, opt_state, window) -> int:
-    """The positions x channels that the convolution's forward kernels cover
-    in one ``step`` on ``window``, from the kernel calls of the step as traced
-    (``jit`` keeps the trace: the first step's call finds it): a Mamba-2 layer
-    that went around the kernels would not be counted."""
+#: What ``_traced_counts`` read off a step as traced, by step program and window shape.
+_TRACED_COUNTS: dict = {}
+
+
+def _traced_counts(step, params, opt_state, window, cfg: LMConfig) -> dict:
+    """What one ``step`` on ``window`` does, from the step as traced (``jit``
+    keeps the trace: the first step's call finds it). ``conv_positions_kernel``:
+    the positions x channels that the convolution's forward kernels cover, from
+    their calls (a Mamba-2 layer that went around the kernels would not be
+    counted). ``head_logit_matmuls``: the matmuls that make a chunk's ``[chunk,
+    V]`` logits, a head call (the stack's; a multi-token-prediction module's):
+    1 where the head forms its gradients in the pass that holds the logits, 2
+    where a backward computes them again."""
     key = (step, window.shape)
-    if key not in _CONV_POSITIONS_KERNEL:
-        _CONV_POSITIONS_KERNEL[key] = forward_positions(step.trace(params, opt_state, window, jnp.int32(0)).jaxpr.jaxpr)
-    return _CONV_POSITIONS_KERNEL[key]
+    if key not in _TRACED_COUNTS:
+        jaxpr = step.trace(params, opt_state, window, jax.ShapeDtypeStruct((), jnp.int32)).jaxpr.jaxpr  # runs nothing
+        _TRACED_COUNTS[key] = {"conv_positions_kernel": forward_positions(jaxpr),
+                               "head_logit_matmuls": _head_logit_matmuls(jaxpr, cfg.vocab) // (1 + cfg.mtp_depth)}
+    return _TRACED_COUNTS[key]
 
 
 @functools.cache
@@ -1016,7 +1124,7 @@ def _log_likelihood_program(cfg: LMConfig, compute_type: str, interpret: bool):
         h, _, gate, _ = _hidden(params, tok, cfg, cd, interpret)  # no module: it is a training objective
         if gate is not None:
             h = h[-1]  # the exit threshold is 1: no token leaves before the last pass
-        nll = _next_token_nll(h, _head(params, cfg), tok, cd)
+        nll = _next_token_nll(h, _head(params, cfg), tok, cd)()
         return -jnp.sum(nll, axis=1) / (tok.shape[1] - 1)
 
     return jax.jit(run)
@@ -1115,7 +1223,12 @@ class DecoderLMModel(Model, _LMParams):
 
 
 class DecoderLM(Estimator, _LMParams):
-    """AdamW training of a decoder-only language model on token-id vectors.
+    """AdamW training of a decoder-only language model on token-id vectors: the objective is a weighted sum of per-token next-token cross-entropies, whose gradients the head forms in the one pass that holds its logits.
+
+    The objective's terms through the head differ only in the weights they
+    hand it (the mean over the targets; ``mtpLossCoef`` over the positions a
+    multi-token-prediction module scores; a looped stack's exit distribution),
+    so the ``[chunk, vocabulary]`` logits are computed once a step.
 
     The features column holds equal-length token-id vectors (length a
     multiple of 256). ``globalBatchSize`` counts ROWS (sequences): tokens a step =
@@ -1180,11 +1293,13 @@ class DecoderLM(Estimator, _LMParams):
             # positions x channels of the Mamba-2 layers' convolutions in one step's forward, and those of them the
             # convolution's kernels cover: their calls' grids in the step as traced
             conv_positions = sum(batch * t * (m.heads * m.head_dim + 2 * m.groups * m.state) for m in scans)
-            conv_positions_kernel = _conv_positions_kernel(step, params, opt_state, window) if scans else 0
+            traced = _traced_counts(step, params, opt_state, window, cfg)
+            conv_positions_kernel = traced["conv_positions_kernel"]
             phase.set_metadata(built=int(_train_program.cache_info().misses > misses),
                                fold_chunks=int(chunks[:, 1].sum()), fold_chunks_visited=int(chunks[:, 0].sum()),
                                loop_trips=cfg.loops, layer_applications=applications,
-                               state_leaves=len(state), state_bytes=sum(x.nbytes for x in state))
+                               state_leaves=len(state), state_bytes=sum(x.nbytes for x in state),
+                               head_logit_matmuls=traced["head_logit_matmuls"])
             if any(w for _, w in folds):
                 phase.set_metadata(layers_windowed=sum(w > 0 for _, w in folds),
                                    layers_full=sum(w == 0 for _, w in folds),

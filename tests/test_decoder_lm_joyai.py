@@ -242,8 +242,8 @@ def test_the_module_scores_token_i_plus_two_and_the_last_two_positions_nothing(p
     assert _rel(stats["mtp_nll_sum"] / stats["mtp_targets"], want) < 1e-5
 
     def module_sum(ahead, head=params["lm_head"]):  # the module's term as ``_loss`` writes it
-        nll = decoder_lm._next_token_nll(ahead, head, jnp.roll(tok, -1, axis=1), F32)
-        return jnp.sum(jnp.where(jnp.arange(T) < T - 2, nll, 0.0))
+        scored = jnp.broadcast_to((jnp.arange(T) < T - 2).astype(jnp.float32), tok.shape)
+        return decoder_lm._next_token_nll(ahead, head, jnp.roll(tok, -1, axis=1), F32)(scored)[0]
 
     _, _, _, ahead = decoder_lm._hidden(params, tok, CFG, F32, True, mtp=True)
     np.testing.assert_allclose(float(module_sum(ahead)), float(stats["mtp_nll_sum"]), rtol=1e-6)

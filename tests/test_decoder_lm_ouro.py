@@ -298,22 +298,26 @@ def test_bad_sizes_are_refused(df):
 
 
 #: kind -> (configuration, compute type, sha256 of ``str(make_jaxpr(step))`` with addresses blanked, its ``while``
-#: loops). ``zaya``, ``laguna`` and ``nemotron_h`` read what they read at 512ebfa (PR 42), ``zaya`` what it read at
-#: 7f7c451, before the ``ouro`` kind: character for character what the benchmark's cells measured. The two ``olmoe``
-#: cases and ``ouro`` were re-pinned at PR 43, where one attention function took over from one a kind: the same
-#: equations (primitive, shapes, params: the multiset is 512ebfa's) with the RoPE tables built after the head split
-#: (82124bb1..., 3409e783... and 75253e70... at 512ebfa; CHANGES.md, PR 43).
+#: loops). All six were re-pinned at PR 45, where the head took to forming its gradients in the pass that holds its
+#: logits (``decoder_lm._weighted_nll``): every kind's step changed under ``lm.head`` (and, the looped stack's
+#: weights being ``p / targets``, in ``lm.exit``'s own arithmetic) and nowhere else. How that was shown: each kind's
+#: step jaxpr flattened into the multiset of its equations (primitive, operand and result types, params; the bodies
+#: of loops, checkpoints and calls walked, each equation filed under the scopes on its name stack) is, outside
+#: ``lm.head`` and ``lm.exit``, the multiset of 31a0d25 (PR 44) less ONE equation a head call, the unscoped
+#: ``broadcast_in_dim 0.0 -> f32[chunk]`` that ``logsumexp`` makes and the parent's ``lax.map`` hoisted out of its
+#: body (CHANGES.md, PR 45). Before that ``zaya``, ``laguna`` and ``nemotron_h`` read what they read at 512ebfa
+#: (PR 42) and the two ``olmoe`` cases and ``ouro`` what PR 43 pinned (one attention function for every kind).
 STEP_JAXPRS = {
     "olmoe": (LMConfig(n_layers=2, hidden=128, n_heads=4, n_experts=8, top_k=2, expert_width=64, vocab=512),
-              "float32", "4c267466e4f5cae48873208bfaa17ed0faa1fe45508ba236a64019ab27e65177", 0),
+              "float32", "4de0577ecfd1707f16c69c5fed4c8763ce639840c2296689a8ae9c241270359e", 0),
     "olmoe_one_layer_bfloat16": (
         LMConfig(n_layers=1, hidden=128, n_heads=4, n_experts=8, top_k=2, expert_width=64, vocab=512),
-        "bfloat16", "80df8b8718a2db6575604b5510919120715111898b0a3f59f4dad000374e338a", 0),
+        "bfloat16", "5ff9cf49b3f7e20813a42266b37893137e130cc4cd4b6ae8e2e334266843133e", 0),
     "zaya": (LMConfig(n_layers=2, hidden=128, n_heads=4, n_experts=8, top_k=1, expert_width=64, vocab=512,
                       rope_theta=5e6, aux_coef=0.0, block="zaya", tied=True, experts_held=4, first_held=2,
                       n_kv_heads=2, head_size=16, rope_fraction=0.5, router_width=32),
-             "float32", "b3660134986d9ce005017cb9f3518e18637f02f10bad3d632f90016477897064", 0),
-    "ouro": (CFG, "float32", "a9c8d0abb4ce0b165dfb859f1c123e052db094be8f3674daee1b7b88e371f23f", 0),
+             "float32", "af2628de97e7ddf88010e48bac4abdea2cb0e6800923e5242594e6c340324fc5", 0),
+    "ouro": (CFG, "float32", "cd0a02b82cd2f4720c5f0f12d1d4141aac225251b78b69e1fbc1b2dd4a80c66f", 0),
     # an eighth of the experts held, so the expert layer takes its sorted rows a window at a time, a loop of a
     # traced length a direction (``parallel/moe.py``; the recomputed forward's is unused, and gone), which the
     # kinds above, with every expert or a half of them held, must not hold
@@ -321,14 +325,14 @@ STEP_JAXPRS = {
                         aux_coef=0.0, block="laguna", experts_held=2, first_held=2, n_kv_heads=2, head_size=16,
                         rope_fraction=0.5, layer_heads=(4, 8), layer_windows=(0, 96), n_dense=1, dense_width=96,
                         shared_width=32, routed_scale=2.5, window_rope_theta=1e4),
-               "float32", "be04a0a41f46a62eb0ac22f998295b8ecb6142816e0e88ec98e2e0b344d7251f", 2),
+               "float32", "00a1a4d2383a522673af9c6afbe9f998ecc5e1e18d32dd129d3cefcd3eee8e51", 2),
     # its four expert layers take their rows in windows too: 5 ``while``s in the text, as at 512ebfa
     "nemotron_h": (LMConfig(n_layers=9, hidden=64, n_heads=4, n_experts=16, top_k=2, expert_width=32, vocab=512,
                             norm_eps=1e-5, aux_coef=0.0, block="nemotron_h", experts_held=2, first_held=2,
                             n_kv_heads=2, head_size=16, shared_width=48, routed_scale=2.5,
                             layer_kinds=tuple("MEMEM*EME"), ssm_heads=8, ssm_head_dim=8, ssm_groups=2, ssm_state=16,
                             conv_kernel=4, chunk=64),
-                   "float32", "e594fb9b409624b91e6a244778d05063cae4919e10924461c82f5b9524372967", 5),
+                   "float32", "294a8953202f192224a0ba0cf870dbe670c7caa44c9325c628480f1962668287", 5),
 }
 
 

@@ -76,7 +76,7 @@ def test_counts_are_what_the_job_says(traced_fit):
     assert one["train.program"] == {"built": 0, "fold_chunks": pairs, "fold_chunks_visited": pairs,
                                     "loop_trips": 1, "layer_applications": LAYERS,
                                     "state_leaves": 2 * len(param_shapes(cfg)) + 1,
-                                    "state_bytes": 8 * num_params(cfg) + 4}
+                                    "state_bytes": 8 * num_params(cfg) + 4, "head_logit_matmuls": 1}
     assert one["train.dispatch"] == {"steps": STEPS}
     drain = one["train.drain"]
     assert drain["steps"] == STEPS and drain["tokens"] == STEPS * BATCH * T
@@ -148,7 +148,7 @@ def test_a_looped_stack_reports_its_trips_and_its_exits():
     assert one["train.program"] == {"built": 1, "fold_chunks": pairs, "fold_chunks_visited": pairs,
                                     "loop_trips": loops, "layer_applications": layers * loops,
                                     "state_leaves": 2 * len(param_shapes(cfg)) + 1,
-                                    "state_bytes": 8 * num_params(cfg) + 4}
+                                    "state_bytes": 8 * num_params(cfg) + 4, "head_logit_matmuls": 1}
     drain = one["train.drain"]
     assert set(drain) == {"steps", "tokens", "exit_trip_sum", "exit_last_mass", "gate_entropy_sum", "trip_nll"}
     tokens = steps * BATCH * T

@@ -118,10 +118,12 @@ def test_every_scope_the_kind_has_appears(programs, kind):
 
 
 @pytest.mark.parametrize("kind", sorted(KINDS))
-def test_the_head_runs_in_three_directions_and_a_block_is_recomputed_where_it_is_checkpointed(programs, kind):
+def test_the_head_is_never_recomputed_and_a_block_is_where_it_is_checkpointed(programs, kind):
     step, _ = programs(kind)
     found = _found(step)
-    assert {d for scope, d in found if scope == "lm.head"} == {FWD, REMAT, BWD}
+    # the head forms its gradients in the pass that holds the logits (``decoder_lm._weighted_nll``): that pass is the
+    # forward, the backward scales what it left (and transposes what lies around it: a tied head, the weights' mask)
+    assert FWD in {d for scope, d in found if scope == "lm.head"} <= {FWD, BWD}
     block = {d for scope, d in found if scope.startswith("lm.block")}
     assert block == ({FWD, REMAT, BWD} if KINDS[kind][1] else {FWD, BWD})
     # a hand-written VJP's backward keeps the scope its forward was traced under
@@ -131,8 +133,9 @@ def test_the_head_runs_in_three_directions_and_a_block_is_recomputed_where_it_is
     if kind == "nemotron_h":  # the scan is AD's: each of its parts in all three directions
         assert {d for scope, d in found if scope == "lm.block/scan"} == {FWD, REMAT, BWD}
     if kind == "joyai":  # the module's parts in every direction, its layer recomputed like the stack's
-        for scope in ("lm.mtp/lm.head", "lm.mtp/lm.block/latent", "lm.mtp/lm.block/fold"):
+        for scope in ("lm.mtp/lm.block/latent", "lm.mtp/lm.block/fold"):
             assert {d for s, d in found if s == scope} == {FWD, REMAT, BWD}, scope
+        assert FWD in {d for s, d in found if s == "lm.mtp/lm.head"} <= {FWD, BWD}
         assert {d for s, d in found if s == "lm.mtp/proj"} == {FWD, BWD}
         assert {d for s, d in found if s == "lm.block/latent"} == {FWD, REMAT, BWD}
     if kind == "laguna":  # the windowed layer's kernels under names of their own, beside the full layers'

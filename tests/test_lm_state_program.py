@@ -65,6 +65,7 @@ class _FirstState:
                 self.states.append(jax.device_get(opt_state))
             return step(params, opt_state, window, lo)
 
+        first.trace = step.trace  # ``train.program`` reads its counts off the step as traced
         return optimizer, first
 
 
