@@ -130,7 +130,14 @@ from flink_ml_tpu.params.shared import (
     HasSeed,
 )
 from flink_ml_tpu.parallel.causal_conv import causal_conv, forward_positions
-from flink_ml_tpu.parallel.flash import TQ_TILE, flash_available, fold_chunk_counts, fused_fold
+from flink_ml_tpu.parallel.flash import (
+    ONE_BLOCK_ROW_STATS,
+    TQ_TILE,
+    flash_available,
+    fold_chunk_counts,
+    fold_kernel_calls,
+    fused_attention,
+)
 from flink_ml_tpu.parallel.mesh import is_tpu_backend
 from flink_ml_tpu.parallel.moe import dense_swiglu, moe_dropless
 from flink_ml_tpu.parallel.ssd import scan_kernel_chunks, ssd_scan
@@ -536,18 +543,13 @@ def _proj(a, w, cd):
 
 def _fold(q, k, v, cd, interpret: bool, window: Optional[int] = None):
     """Causal softmax attention of ``q [B, H, T, D]`` on ``k [B, H_kv, T, D]``
-    and ``v [B, H_kv, T, D_v]`` (``[B, H, T, D_v]`` out; ``D_v`` is ``D``
-    but under latent attention) at scale ``D^-1/2`` through the fused fold: a
-    ring of one, the whole sequence is the resident KV block. Under a
-    ``window`` each query keeps the ``window`` keys that end at itself."""
+    and ``v [B, H_kv, T, D_v]`` (``[B, H, T, D_v]`` out in float32; ``D_v`` is
+    ``D`` but under latent attention) at scale ``D^-1/2`` through the fused
+    fold's one-block form: a ring of one, the whole sequence is the resident
+    KV block. Under a ``window`` each query keeps the ``window`` keys that end
+    at itself."""
     with jax.named_scope("fold"):
-        b, h, t, hd = q.shape
-        m0 = jnp.full((b, h, t), -jnp.inf, jnp.float32)
-        l0 = jnp.zeros((b, h, t), jnp.float32)
-        acc0 = jnp.zeros((b, h, t, v.shape[-1]), jnp.float32)
-        _, l, acc = fused_fold(q.astype(cd), k.astype(cd), v.astype(cd), m0, l0, acc0, jnp.int32(0),
-                               jnp.int32(0), True, False, jnp.int32(0), float(hd) ** -0.5, interpret, window)
-        return acc / l[..., None]  # causal: every row attends at least to itself, l > 0
+        return fused_attention(q.astype(cd), k.astype(cd), v.astype(cd), float(q.shape[-1]) ** -0.5, window, interpret)
 
 
 def _heads(z, n: int):
@@ -1107,12 +1109,22 @@ def _traced_counts(step, params, opt_state, window, cfg: LMConfig) -> dict:
     counted). ``head_logit_matmuls``: the matmuls that make a chunk's ``[chunk,
     V]`` logits, a head call (the stack's; a multi-token-prediction module's):
     1 where the head forms its gradients in the pass that holds the logits, 2
-    where a backward computes them again."""
+    where a backward computes them again. ``fold_one_block``: the attention
+    layers as traced (a looped stack's once) whose fold is the one-block form,
+    by their dq kernel's calls; ``fold_row_stats``: the float32 row statistics
+    that one layer's three fold kernels take and hand back, the most of any
+    call (5 in the one-block form, 17 where the ring's carried state goes
+    through them)."""
     key = (step, window.shape)
     if key not in _TRACED_COUNTS:
         jaxpr = step.trace(params, opt_state, window, jax.ShapeDtypeStruct((), jnp.int32)).jaxpr.jaxpr  # runs nothing
-        _TRACED_COUNTS[key] = {"conv_positions_kernel": forward_positions(jaxpr),
-                               "head_logit_matmuls": _head_logit_matmuls(jaxpr, cfg.vocab) // (1 + cfg.mtp_depth)}
+        folds = fold_kernel_calls(jaxpr)
+        _TRACED_COUNTS[key] = {
+            "conv_positions_kernel": forward_positions(jaxpr),
+            "head_logit_matmuls": _head_logit_matmuls(jaxpr, cfg.vocab) // (1 + cfg.mtp_depth),
+            "fold_one_block": sum(part == "bwd_dq" and stats == ONE_BLOCK_ROW_STATS[part] for part, stats in folds),
+            "fold_row_stats": sum(max((stats for p, stats in folds if p == part), default=0)
+                                  for part in ONE_BLOCK_ROW_STATS)}
     return _TRACED_COUNTS[key]
 
 
@@ -1300,6 +1312,8 @@ class DecoderLM(Estimator, _LMParams):
                                loop_trips=cfg.loops, layer_applications=applications,
                                state_leaves=len(state), state_bytes=sum(x.nbytes for x in state),
                                head_logit_matmuls=traced["head_logit_matmuls"])
+            if folds:  # a stack that attends: how its step as traced calls the fold
+                phase.set_metadata(fold_one_block=traced["fold_one_block"], fold_row_stats=traced["fold_row_stats"])
             if any(w for _, w in folds):
                 phase.set_metadata(layers_windowed=sum(w > 0 for _, w in folds),
                                    layers_full=sum(w == 0 for _, w in folds),

@@ -63,6 +63,30 @@ pairs on either side. The kernels of a windowed fold are named
 ``flash_fold_win_*``; without a window every kernel is traced as it was before
 the fold knew of one.
 
+The one-block form: ``fused_attention`` is the same fold where the ring has
+ONE step: a whole sequence on itself (``q_pos0 = k_pos0 = 0``, ``Tq = Tk``,
+nothing carried in). Its forward hands back the normalised ``o = acc / l`` in
+float32 and one log-sum-exp a row, ``lse = m + log l``; its residuals are ``q,
+k, v, o, lse``; its backward is the softmax's own VJP, ``P = exp(s - lse)``,
+``ds = P (dP - delta)`` with ``delta = rowsum(dO * o)``. There are no tie terms:
+the gradient through the row maximum, ``dsafe = -(dl l + dacc . acc)``, is zero
+once the output is normalised (``dacc = dO / l``, ``dl = -dO . o / l``), so
+what the ring form computes there (``take_m``, ``is_max``, the tie count,
+``dbc``) is rounding noise of a term that cancels. With ``lse`` and ``delta``
+known before the walk the dq kernel makes ONE walk of the visible chunks and
+parks nothing; the dkv kernel forms a pair's scores transposed (``[keys,
+rows]``), so the two statistics broadcast down the sublanes and ``P^T``,
+``ds^T`` are the left operands of ``dv``'s and ``dk``'s matmuls as they stand.
+Two float32 row statistics cross HBM where the ring form's kernels move
+seventeen, and they lie along the lanes (``[B x H, 1, T]``; a ``[B x H, T,
+1]`` column is padded 128 times by the device's tiling). The tiles, the walk's
+helpers and the kernels' names are the ring form's. Who calls which:
+``models/lm/decoder_lm._fold`` (training, ``transform``, ``log_likelihood``)
+calls ``fused_attention``; ``ring.py`` (state carried across ring steps,
+``n_valid``, steps without ``causal``) and ``SelfAttentionClassifier`` need
+the general contract and keep ``fused_fold``. The two share no contract, and
+the choice is made by who calls, never by an option.
+
 Availability: TPU compiled, or any backend under ``interpret=True``. The
 caller (``ring.py``) falls back to the jnp fold when the local length does
 not tile or the devices have no Mosaic backend.
@@ -76,10 +100,13 @@ import jax.numpy as jnp
 
 __all__ = [
     "fused_fold",
+    "fused_attention",
     "flash_available",
     "flash_train_available",
     "reference_fold",
     "fold_chunk_counts",
+    "fold_kernel_calls",
+    "ONE_BLOCK_ROW_STATS",
     "TQ_TILE",
 ]
 
@@ -279,14 +306,14 @@ def _window_chunks(q_first, n_rows: int, k_pos0, chunk: int, n_chunks: int, wind
     return n_lo, lo_end, _least(_most(n_full, lo_end), n_vis)
 
 
-def _mask_chunk(s, q_first, k_first, causal: bool, n_valid=None, window=None):
+def _mask_chunk(s, q_first, k_first, causal: bool, n_valid=None, window=None, q_axis: int = 0):
     """``s [rows, keys]`` with ``-inf`` where the causal mask (when ``causal``),
     the sliding ``window`` under it (when given: a query keeps the ``window``
     keys ending at itself) or ``n_valid`` (when given; ``causal`` or it is)
     drops the entry; ``q_first``/``k_first`` are the global positions of row 0
-    and key 0."""
-    q_pos = q_first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) if causal else None
-    k_pos = k_first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    and key 0. ``q_axis=1``: ``s`` is ``[keys, rows]``, the scores transposed."""
+    q_pos = q_first + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis) if causal else None
+    k_pos = k_first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
     keep = q_pos >= k_pos if causal else k_pos < n_valid
     if causal and n_valid is not None:
         keep &= k_pos < n_valid
@@ -311,6 +338,17 @@ def _key_rows(ref, c, kc: int):
     return ref[0, pl.ds(pl.multiple_of(c * kc, kc), kc), :]
 
 
+def _walk_chunks(walk, carry, n_full, n_vis, window=None, n_lo=0, lo_end=0):
+    """One walk of a causal cell over its visible key chunks: ``walk(masked)``
+    is a ``fori_loop`` body ``(chunk, carry) -> carry``, taken with the mask on
+    the chunks an edge crosses and without it on those kept whole."""
+    if window is not None:  # the window's edge first; "diagonal" there means masked, by both edges
+        carry = jax.lax.fori_loop(n_lo, lo_end, walk(True), carry)
+        n_lo = lo_end
+    carry = jax.lax.fori_loop(n_lo, n_full, walk(False), carry)
+    return jax.lax.fori_loop(n_full, n_vis, walk(True), carry)
+
+
 def _park_scores(qt, k_ref, s_scr, q_first, k_pos0, n_valid, scale, kc: int, n_full, n_vis, window=None,
                  n_lo=0, lo_end=0):
     """The first walk of a causal forward or dq cell: the scores of ``qt
@@ -332,12 +370,8 @@ def _park_scores(qt, k_ref, s_scr, q_first, k_pos0, n_valid, scale, kc: int, n_f
 
         return body
 
-    mx = jnp.full((qt.shape[0], _LANES), -jnp.inf, jnp.float32)
-    if window is not None:  # the window's edge first; "diagonal" there means masked, by both edges
-        mx = jax.lax.fori_loop(n_lo, lo_end, walk(True), mx)
-        n_lo = lo_end
-    mx = jax.lax.fori_loop(n_lo, n_full, walk(False), mx)
-    return jax.lax.fori_loop(n_full, n_vis, walk(True), mx)
+    return _walk_chunks(walk, jnp.full((qt.shape[0], _LANES), -jnp.inf, jnp.float32), n_full, n_vis, window,
+                        n_lo, lo_end)
 
 
 def fold_chunk_counts(Tq: int, Tk: int, q_off: int, causal: bool, window=None):
@@ -410,6 +444,30 @@ def _kernel_name(part: str, window) -> str:
     """A windowed fold's kernels carry names of their own, so that a device
     trace tells a stack's windowed layers from its full ones."""
     return f"flash_fold_{part}" if window is None else f"flash_fold_win_{part}"
+
+
+#: The float32 row statistics (``[B x H, T, 1]`` columns or ``[B x H, 1, T]`` rows) among a kernel's operands and
+#: results in the one-block form: ``lse`` out of the forward; ``lse`` into and ``delta`` out of the dq kernel; both
+#: into the dkv kernel. (The ring form's kernels carry 4, 9 and 4: ``m``, ``l``, their cotangents and the tie terms.)
+ONE_BLOCK_ROW_STATS = {"fwd": 1, "bwd_dq": 2, "bwd_dkv": 2}
+
+
+def fold_kernel_calls(jaxpr) -> list:
+    """``[(part, row statistics)]``, one entry a call of a fold kernel
+    (``part`` one of ``fwd``, ``bwd_dq``, ``bwd_dkv``; windowed or not) among
+    ``jaxpr``'s equations and those of every jaxpr inside them (a loop's body,
+    what a ``checkpoint`` recomputes), with the float32 row statistics among
+    the call's operands and results: what a step as traced hands its fold."""
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.params.get("name") if eqn.primitive.name == "pallas_call" else None
+        if name is not None and name.startswith("flash_fold_"):
+            avals = [v.aval for v in (*eqn.invars, *eqn.outvars)]
+            found.append((name.removeprefix("flash_fold_").removeprefix("win_"),
+                          sum(a.dtype == jnp.float32 and a.ndim == 3 and 1 in a.shape[1:] for a in avals)))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += fold_kernel_calls(sub)
+    return found
 
 
 def _fold_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
@@ -935,3 +993,290 @@ def _fold_bwd_pallas(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid,
         dl_o.reshape(B_, H, Tq),
         dacc_o.reshape(B_, H, Tq, Dv),
     )
+
+
+# ---------------------------------------------------------------------------
+# The one-block form ("The one-block form" in the module docstring): the LM's
+# fold, a ring of one. The walk's helpers, tiles and kernel names are the ring
+# form's above; the contract, the kernels' bodies and the VJP are its own.
+# ---------------------------------------------------------------------------
+
+
+def _col_to_row(col):
+    """``[rows, 1] -> [1, rows]``: a row statistic leaves a cell along the
+    lanes (``[B x H, 1, T]`` in HBM is dense; a ``[B x H, T, 1]`` column is
+    padded 128 times). Mosaic takes the move as a 32-bit tile transpose."""
+    return jnp.transpose(jnp.broadcast_to(col, (col.shape[0], _LANES)))[:1]
+
+
+def _row_to_col(row):
+    """``[1, rows] -> [rows, 1]``, the way back."""
+    return jnp.transpose(jnp.broadcast_to(row, (_LANES, row.shape[1])))[:, :1]
+
+
+def _nt_dot(a, b):
+    """``a [n, d] , b [m, d] -> a b^T [n, m]`` in float32."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _attention_tiles(T: int):
+    """``_fold_tiles`` of the LM's fold; the dq kernel takes the forward's rows:
+    the walk's are equal, and a block of one chunk (T up to 1,024) holds no
+    three whole-block buffers here, and a 64-row tile of a statistic that lies
+    along the lanes would be half a lane tile. ``fold_chunk_counts`` counts
+    such a block at the ring form's 64 rows: every pair visited either way."""
+    tq, _, tq_dkv, tk_dkv, kc = _fold_tiles(T, T, True)
+    return tq, tq_dkv, tk_dkv, kc
+
+
+def _cell_chunks(q_first, n_rows: int, chunk: int, n_chunks: int, window):
+    """``(n_lo, lo_end, n_full, n_vis)`` of a cell whose rows start at
+    ``q_first`` on keys from 0: chunks ``[n_lo, lo_end)`` and ``[n_full,
+    n_vis)`` are crossed by the window's edge and the diagonal, ``[lo_end,
+    n_full)`` kept whole, the rest hidden (``_visible_chunks``,
+    ``_window_chunks``); without a ``window`` nothing lies below it."""
+    n_full, n_vis = _visible_chunks(q_first, n_rows, 0, chunk, n_chunks)
+    if window is None:
+        return 0, 0, n_full, n_vis
+    return (*_window_chunks(q_first, n_rows, 0, chunk, n_chunks, window, n_full, n_vis), n_vis)
+
+
+def _attention_specs(T: int, tq: int, kv_of):
+    """The forward's and the dq kernel's block specs on a ``(B x H, T / tq)``
+    grid: a query tile ``rows(width)``, a head's whole K or V ``keys(width)``,
+    a row statistic's ``[1, tq]`` piece."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def rows(width):
+        return pl.BlockSpec((1, tq, width), lambda i, j: (i, j, 0), memory_space=pltpu.VMEM)
+
+    def keys(width):
+        return pl.BlockSpec((1, T, width), lambda i, j: (kv_of(i), 0, 0), memory_space=pltpu.VMEM)
+
+    return rows, keys, pl.BlockSpec((1, 1, tq), lambda i, j: (i, 0, j), memory_space=pltpu.VMEM)
+
+
+def _attention_pallas(q, k, v, scale, window, interpret):
+    """``(o [B, H, T, D_v] float32, lse [B x H, 1, T])`` of the one-block form."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from flink_ml_tpu.parallel.mesh import vma_of
+
+    if window is not None and window <= 0:
+        raise ValueError(f"a sliding window ({window}) holds at least the query")
+    B, H, T, D = q.shape
+    Hkv, Dv = k.shape[1], v.shape[3]
+    BH = B * H
+    kv_of = _kv_block_of(H, Hkv)
+    tq, _, _, kc = _attention_tiles(T)
+    n_chunks = T // kc
+
+    def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *s_scr):
+        q_first = pl.program_id(1) * tq
+        if n_chunks == 1:  # the block in one piece
+            s = _mask_chunk(_nt_dot(q_ref[0], k_ref[0]) * scale, q_first, 0, True, None, window)
+            m = jnp.max(s, axis=1, keepdims=True)
+            p = jnp.exp(s - m)  # every row keeps itself: m is finite and a masked score's exp(-inf) is 0
+            l = jnp.sum(p, axis=1, keepdims=True)
+            o_ref[0] = jnp.dot(p.astype(v_ref.dtype), v_ref[0], preferred_element_type=jnp.float32) / l
+        else:  # ``walking_kernel`` with no carried state to read or correct
+            n_lo, lo_end, n_full, n_vis = _cell_chunks(q_first, tq, kc, n_chunks, window)
+            mx = _park_scores(q_ref[0], k_ref, s_scr[0], q_first, 0, None, scale, kc, n_full, n_vis,
+                              window, n_lo, lo_end)
+            m = jnp.max(mx, axis=1, keepdims=True)
+            o_ref[0] = jnp.zeros_like(o_ref[0])
+
+            def accumulate(c, l_lanes):
+                p = jnp.exp(s_scr[0][c] - m)
+                o_ref[0] += jnp.dot(p.astype(v_ref.dtype), _key_rows(v_ref, c, kc),
+                                    preferred_element_type=jnp.float32)
+                return l_lanes + _fold_lanes(p, jnp.add)
+
+            l_lanes = jax.lax.fori_loop(n_lo, n_vis, accumulate, jnp.zeros((tq, _LANES), jnp.float32))
+            l = jnp.sum(l_lanes, axis=1, keepdims=True)
+            o_ref[0] = o_ref[0] / l
+        lse_ref[0] = _col_to_row(m + jnp.log(l))
+
+    rows, keys, stat = _attention_specs(T, tq, kv_of)
+    vma = vma_of(q)
+    o, lse = pl.pallas_call(
+        kernel,
+        grid=(BH, T // tq),
+        in_specs=[rows(D), keys(D), keys(Dv)],
+        out_specs=[rows(Dv), stat],
+        scratch_shapes=[pltpu.VMEM((n_chunks, tq, kc), jnp.float32)] if n_chunks > 1 else [],
+        out_shape=[jax.ShapeDtypeStruct((BH, T, Dv), jnp.float32, vma=vma),
+                   jax.ShapeDtypeStruct((BH, 1, T), jnp.float32, vma=vma)],
+        interpret=interpret,
+        compiler_params=_compiler_params(),
+        name=_kernel_name("fwd", window),
+    )(q.reshape(BH, T, D), k.reshape(B * Hkv, T, D), v.reshape(B * Hkv, T, Dv))
+    return o.reshape(B, H, T, Dv), lse
+
+
+def _attention_bwd_pallas(q, k, v, o, lse, do, scale, window, interpret):
+    """``(dq, dk, dv)`` of the one-block form, each in its operand's type."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from flink_ml_tpu.parallel.mesh import vma_of
+
+    B, H, T, D = q.shape
+    Hkv, Dv = k.shape[1], v.shape[3]
+    BH, BHkv = B * H, B * Hkv
+    group = H // Hkv
+    kv_of = _kv_block_of(H, Hkv)
+    tq, tq_dkv, tk_dkv, kc = _attention_tiles(T)
+    n_chunks, n_q_dkv, n_k_dkv = T // kc, T // tq_dkv, T // tk_dkv
+
+    def dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, delta_ref, dq_scr):
+        # one walk of the visible chunks: with the row's log-sum-exp and delta in hand a chunk's P and ds are
+        # final as they are formed, and no score waits in VMEM
+        q_first = pl.program_id(1) * tq
+        qt, do = q_ref[0], do_ref[0]
+        delta = jnp.sum(do * o_ref[0], axis=1, keepdims=True)  # float32, from the unrounded dO and o
+        lse = _row_to_col(lse_ref[0])
+        do_t = do.astype(v_ref.dtype)
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
+        def chunk(kt, vt, k_first, masked):
+            s = _nt_dot(qt, kt) * scale  # [rows, kc]
+            if masked:
+                s = _mask_chunk(s, q_first, k_first, True, None, window)
+            p = jnp.exp(s - lse)  # lse is finite: a masked score's exp(-inf) is exactly 0
+            ds = p * (_nt_dot(do_t, vt) - delta)
+            dq_scr[...] += jnp.dot(ds.astype(kt.dtype), kt, preferred_element_type=jnp.float32)
+
+        def walk(masked):
+            def body(c, carry):
+                chunk(_key_rows(k_ref, c, kc), _key_rows(v_ref, c, kc), c * kc, masked)
+                return carry
+
+            return body
+
+        if n_chunks == 1:  # the block in one piece
+            chunk(k_ref[0], v_ref[0], 0, True)
+        else:
+            n_lo, lo_end, n_full, n_vis = _cell_chunks(q_first, tq, kc, n_chunks, window)
+            _walk_chunks(walk, 0, n_full, n_vis, window, n_lo, lo_end)
+        dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
+        delta_ref[0] = _col_to_row(delta)
+
+    def dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr):
+        # grid (B*H_kv, ktiles, group*qtiles) as the ring form's; a pair's scores are formed TRANSPOSED, [keys,
+        # rows]: the two row statistics broadcast down the sublanes as they arrive, and P^T and ds^T are the left
+        # operands of dv's and dk's matmuls as they stand
+        jk = pl.program_id(1)
+        jq = pl.program_id(2)
+        first, last = jq == 0, jq == group * n_q_dkv - 1
+        if group > 1:
+            jq = jq % n_q_dkv
+        q_first = jq * tq_dkv
+        k_first = jk * tk_dkv
+
+        def accumulate(mask):
+            s = _nt_dot(k_ref[0], q_ref[0]) * scale  # [TK, TQ]
+            if mask:
+                s = _mask_chunk(s, q_first, k_first, True, None, window, q_axis=1)
+            p = jnp.exp(s - lse_ref[0])
+            do_t = do_ref[0].astype(v_ref.dtype)
+            ds = p * (_nt_dot(v_ref[0], do_t) - delta_ref[0])
+            dk_scr[...] += jnp.dot(ds.astype(q_ref.dtype), q_ref[0], preferred_element_type=jnp.float32)
+            dv_scr[...] += jnp.dot(p.astype(v_ref.dtype), do_t, preferred_element_type=jnp.float32)
+
+        @pl.when(first)
+        def _():
+            dk_scr[...] = jnp.zeros_like(dk_scr)
+            dv_scr[...] = jnp.zeros_like(dv_scr)
+
+        n_lo, lo_end, n_full, n_vis = _cell_chunks(q_first, tq_dkv, tk_dkv, n_k_dkv, window)
+        if window is None:
+            pl.when(jk < n_full)(lambda: accumulate(False))
+            pl.when((jk >= n_full) & (jk < n_vis))(lambda: accumulate(True))
+        else:  # below the window nothing; the pairs either edge crosses masked, by both
+            pl.when((jk >= lo_end) & (jk < n_full))(lambda: accumulate(False))
+            pl.when(((jk >= n_lo) & (jk < lo_end)) | ((jk >= n_full) & (jk < n_vis)))(lambda: accumulate(True))
+
+        @pl.when(last)
+        def _():
+            dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
+            dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+    vma = vma_of(q)
+    rows, keys, stat = _attention_specs(T, tq, kv_of)
+    q3, k3, v3 = q.reshape(BH, T, D), k.reshape(BHkv, T, D), v.reshape(BHkv, T, Dv)
+    do3 = do.reshape(BH, T, Dv)
+    dq, delta = pl.pallas_call(
+        dq_kernel,
+        grid=(BH, T // tq),
+        in_specs=[rows(D), keys(D), keys(Dv), rows(Dv), rows(Dv), stat],
+        out_specs=[rows(D), stat],
+        scratch_shapes=[pltpu.VMEM((tq, D), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((BH, T, D), q.dtype, vma=vma),
+                   jax.ShapeDtypeStruct((BH, 1, T), jnp.float32, vma=vma)],
+        interpret=interpret,
+        compiler_params=_compiler_params(),
+        name=_kernel_name("bwd_dq", window),
+    )(q3, k3, v3, o.reshape(BH, T, Dv), do3, lse)
+
+    def q_tile(i, jk, jq):
+        head = i
+        if group > 1:  # the group's query heads one after another
+            head, jq = i * group + jq // n_q_dkv, jq % n_q_dkv
+        # q tiles before the first that sees this k tile are hidden: they name that first tile's block, so
+        # nothing is fetched for them; under a window so do those past the last that sees it
+        jq = jnp.maximum(jq, _chunks_upto(jk * tk_dkv, tq_dkv, n_q_dkv - 1))
+        if window is not None:
+            jq = jnp.minimum(jq, _chunks_upto((jk + 1) * tk_dkv + window - 2, tq_dkv, n_q_dkv - 1))
+        return head, jq
+
+    def kmat(width):
+        return pl.BlockSpec((1, tk_dkv, width), lambda i, jk, jq: (i, jk, 0), memory_space=pltpu.VMEM)
+
+    def qmat(width):
+        return pl.BlockSpec((1, tq_dkv, width), lambda *ids: (*q_tile(*ids), 0), memory_space=pltpu.VMEM)
+
+    def qstat(*ids):
+        head, jq = q_tile(*ids)
+        return head, 0, jq
+
+    qrow = pl.BlockSpec((1, 1, tq_dkv), qstat, memory_space=pltpu.VMEM)
+    dk, dv = pl.pallas_call(
+        dkv_kernel,
+        grid=(BHkv, n_k_dkv, group * n_q_dkv),
+        in_specs=[kmat(D), kmat(Dv), qmat(D), qmat(Dv), qrow, qrow],
+        out_specs=[kmat(D), kmat(Dv)],
+        scratch_shapes=[pltpu.VMEM((tk_dkv, D), jnp.float32), pltpu.VMEM((tk_dkv, Dv), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((BHkv, T, D), k.dtype, vma=vma),
+                   jax.ShapeDtypeStruct((BHkv, T, Dv), v.dtype, vma=vma)],
+        interpret=interpret,
+        compiler_params=_compiler_params(),
+        name=_kernel_name("bwd_dkv", window),
+    )(k3, v3, q3, do3, lse, delta)
+    return dq.reshape(B, H, T, D), dk.reshape(B, Hkv, T, D), dv.reshape(B, Hkv, T, Dv)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def fused_attention(q, k, v, scale, window=None, interpret=False):
+    """Causal softmax attention of a whole sequence on itself, fused: ``q [B,
+    H, T, D]`` on ``k [B, H_kv, T, D]`` and ``v [B, H_kv, T, D_v]`` ``-> o [B,
+    H, T, D_v]`` in float32 (grouped queries, a value head of its own size and
+    a static sliding ``window`` as ``fused_fold`` takes them). The fold of a
+    ring of one: no ``m``, ``l``, ``acc``, positions or ``n_valid``; a caller
+    that carries state across blocks is the ring and keeps ``fused_fold``.
+    ``scale``, ``window`` and ``interpret`` are static."""
+    return _attention_pallas(q, k, v, scale, window, interpret)[0]
+
+
+def _fused_attention_fwd(q, k, v, scale, window, interpret):
+    o, lse = _attention_pallas(q, k, v, scale, window, interpret)
+    return o, (q, k, v, o, lse)
+
+
+def _fused_attention_bwd(scale, window, interpret, res, do):
+    return _attention_bwd_pallas(*res, do, scale, window, interpret)
+
+
+fused_attention.defvjp(_fused_attention_fwd, _fused_attention_bwd)

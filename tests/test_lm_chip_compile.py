@@ -126,6 +126,64 @@ def test_fused_fold_trains_at_a_value_head_of_its_own_size(one_chip, dtype, batc
     assert dq.shape == dk.shape == (batch, h, t, D_K) and dv.shape == (batch, h, t, D)
 
 
+#: ``(batch, query heads, key/value heads, T, D, D_v, window)`` of the six LM cells' folds (Laguna's two kinds of layer)
+ONE_BLOCK = {"olmoe_4x16x4096": (B, H, H, T, D, D, None), "ouro_2x16x4096": (2, H, H, T, D, D, None),
+             "zaya_2x8on2x8192": (2, 8, 2, 8192, D, D, None), "laguna_2x64on8x4096_w512": (2, 64, 8, 4096, D, D, 512),
+             "laguna_2x48on8x4096": (2, 48, 8, 4096, D, D, None), "nemotron_2x32on2x8192": (2, 32, 2, 8192, D, D, None),
+             "joyai_1x32x8192_192_128": (1, 32, 32, 8192, D_K, D, None)}
+
+
+def _one_block_shapes(fold, dtype, one_chip):
+    b, h, h_kv, t, d, d_v, window = ONE_BLOCK[fold]
+    return (jax.ShapeDtypeStruct((b, h, t, d), dtype, sharding=one_chip),
+            jax.ShapeDtypeStruct((b, h_kv, t, d), dtype, sharding=one_chip),
+            jax.ShapeDtypeStruct((b, h_kv, t, d_v), dtype, sharding=one_chip)), d ** -0.5, window
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("fold", sorted(ONE_BLOCK))
+def test_fused_attention_trains_at_the_cells_shapes(one_chip, dtype, fold):
+    """The one-block form's forward and both backward kernels in one training
+    graph at every LM cell's fold: Mosaic takes the two row statistics along
+    the lanes (``[B x H, 1, T]``: the column-to-row move inside the cells),
+    the dkv kernel's transposed pair and the dq kernel's one walk; the kernels
+    keep the ring form's names; ``dq``, ``dk``, ``dv`` leave in the operands'
+    type; no statistic is a ``[B x H, T, 1]`` column (128 times its bytes)."""
+    from flink_ml_tpu.parallel.flash import fused_attention
+
+    shapes, scale, window = _one_block_shapes(fold, dtype, one_chip)
+    b, h, h_kv, t, d, d_v, _ = ONE_BLOCK[fold]
+
+    def grads(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(fused_attention(q, k, v, scale, window, False)),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    text = _compile(grads, *shapes).as_text()
+    named = "flash_fold_win_" if window else "flash_fold_"
+    for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
+        assert named + kernel in text
+    assert window is None or "flash_fold_fwd" not in text
+    assert f"f32[{b * h},{t},{t}]" not in text and f"f32[{b * h},{t},1]" not in text
+    assert f"f32[{b * h},1,{t}]" in text  # lse and delta
+    dq, dk, dv = jax.eval_shape(grads, *shapes)
+    assert (dq.shape, dk.shape, dv.shape) == ((b, h, t, d), (b, h_kv, t, d), (b, h_kv, t, d_v))
+    assert dq.dtype == dk.dtype == dv.dtype == dtype
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16", "float32"])
+def test_the_forward_alone_compiles_at_8192(one_chip, dtype):
+    """What ``transform`` and ``log_likelihood`` run: the forward kernel with
+    nothing behind it, 16 heads at T 8,192. The ring entry's forward alone did
+    not compile there (XLA put its ``[16, 8192, 1]`` statistic ``l`` in VMEM
+    beside the kernel's scoped 96 MiB: PERF.md section 7, PR 30); the
+    one-block form's one lane-dense ``lse`` is 0.5 MB and it does."""
+    from flink_ml_tpu.parallel.flash import fused_attention
+
+    x = jax.ShapeDtypeStruct((1, 16, 8192, D), dtype, sharding=one_chip)
+    text = _compile(lambda q, k, v: fused_attention(q, k, v, D ** -0.5, None, False), x, x, x).as_text()
+    assert "flash_fold_fwd" in text and "flash_fold_bwd" not in text
+
+
 def test_the_ring_fold_trains_at_the_largest_admitted_shape(one_chip):
     """What ``ring_attention`` hands the fold, at the most ``flash_available``
     admits (T 8,192 x D 128, float32): ``causal`` with TRACED positions, so
@@ -285,12 +343,14 @@ def test_the_laguna_step_program_at_the_cells_shapes(one_chip):
     """The whole jitted step of the ``laguna_xs2`` configuration at 2 x 4,096
     tokens: five rematerialised layers of three kinds (full attention on 48
     heads with the dense SwiGLU; windowed on 64 with the experts; full on 48
-    with the experts). It fits the chip (XLA's analysis): 6.41 GB of
-    temporaries beside 8.30 GB of arguments, 14.71e9 B in all (6.31 GB and
-    14.61e9 B until PR 45: the head's ``dW`` is one float32 ``[d, V]`` from
+    with the experts). It fits the chip (XLA's analysis): 5.32 GB of
+    temporaries beside 8.30 GB of arguments, 13.62e9 B in all (6.41 GB and
+    14.71e9 B until PR 46, while the fold's kernels moved seventeen ``[128,
+    4096, 1]`` float32 row statistics a layer, each padded 128 times to 256 MB,
+    where the one-block form moves two along the lanes; 6.31 GB and 14.61e9 B
+    until PR 45: the head's ``dW`` is one float32 ``[d, V]`` from
     the head's forward to AdamW where the parent's backward kept it in
-    bfloat16 and XLA fused the cast into its readers; what is live at the peak
-    is the attention backward's, not the experts'); the windowed layers' three kernels are there under their own
+    bfloat16 and XLA fused the cast into its readers); the windowed layers' three kernels are there under their own
     names beside the full layers'; K and V enter once per key/value head; the
     32 held experts' grouped matmuls are the grouped kernel in both
     directions, over a window of 16,384 sorted rows at a time: no float32
@@ -301,7 +361,8 @@ def test_the_laguna_step_program_at_the_cells_shapes(one_chip):
     assert num_params(cfg) == 691_624_960  # 11.07 GB of f32 state at 16 bytes a parameter: 69% of 16 GB
     batch, t = c["global_batch_size"], c["sequence_length"]
     compiled, memory = _compiled_step(c, cfg, one_chip)
-    assert memory.temp_size_in_bytes < 6.48e9, memory.temp_size_in_bytes  # what it reads and 1%; 6.31e9 until PR 45
+    # what it reads and 1%; 6.41e9 until PR 46 (the fold's padded row statistics), 6.31e9 until PR 45
+    assert memory.temp_size_in_bytes < 5.38e9, memory.temp_size_in_bytes
     text = compiled.as_text()
     for kernel in ("flash_fold_fwd", "flash_fold_bwd_dq", "flash_fold_bwd_dkv",
                    "flash_fold_win_fwd", "flash_fold_win_bwd_dq", "flash_fold_win_bwd_dkv"):
@@ -330,8 +391,14 @@ def test_the_nemotron_step_program_at_the_cells_shapes(one_chip, monkeypatch):
     layers, one attention layer on 32 query heads over 2 key/value heads, four
     expert layers of 8 held relu² experts beside the shared one). It fits the
     HBM ``fit`` compiles a step into (``decoder_lm.STEP_HBM_MIB``: 15,020 MiB,
-    the 15.75e9 B below): XLA's analysis reads 7.15 GB of temporaries beside
-    8.00 GB of arguments, 15.15e9 B in all (7.05 GB and 15.06e9 B until PR 45,
+    the 15.75e9 B below): XLA's analysis reads 7.35 GB of temporaries beside
+    8.00 GB of arguments, 15.35e9 B in all, and since PR 46 NOTHING in the
+    compiled step is one of XLA's own rematerialisations (no ``.remat``
+    instruction; the parent's held 14): without the fold's padded row
+    statistics the step fits the stated size as scheduled, so the pass that
+    recomputes until it fits has nothing to do, and what it reads is the
+    schedule's own peak, above the 7.15 GB and 15.15e9 B that the parent was
+    squeezed to (7.05 GB and 15.06e9 B until PR 45,
     whose head holds ``dW`` as a float32 ``[d, V]`` from its forward on where
     the parent's backward held it in bfloat16; 7.39 GB and 15.39e9 B while the
     Mamba-2 layers' convolution was a padded copy and four shifted slices, PR
@@ -360,8 +427,10 @@ def test_the_nemotron_step_program_at_the_cells_shapes(one_chip, monkeypatch):
     for module in (ssd, causal_conv):  # the backend here is the CPU; the target is the chip
         monkeypatch.setattr(module, "_interpreted", lambda: False)
     compiled, memory = _compiled_step(c, cfg, one_chip)
-    # what it reads and 1%; 7.13e9 until PR 45, 7.45e9 until PR 42
-    assert memory.temp_size_in_bytes < 7.23e9, memory.temp_size_in_bytes
+    # what it reads and 1%: the schedule's own peak, nothing rematerialised by XLA to fit (PR 46); squeezed to fit it
+    # read 7.15e9 until then, 7.13e9 until PR 45, 7.45e9 until PR 42
+    assert memory.temp_size_in_bytes < 7.43e9, memory.temp_size_in_bytes
+    assert ".remat" not in compiled.as_text()
     step, shapes = _step_and_shapes(c, cfg, one_chip)
     unbudgeted = jax.jit(step.__wrapped__, donate_argnums=(0, 1)).lower(*shapes).compile().memory_analysis()
     # of the chip's 15.75 GiB (16.91e9 B): what it reads and 1%; 15.86e9 until PR 45, 16.69e9 until PR 42
@@ -400,11 +469,14 @@ def test_the_joyai_step_program_at_the_cells_shapes(one_chip):
     with the dense SwiGLU; latent attention with 16 held experts beside the
     shared one) and the multi-token-prediction module's one more behind them,
     two passes over the head. It fits the HBM ``fit`` compiles a step into:
-    XLA's analysis reads 7.39 GB of temporaries beside 8.17 GB of arguments,
-    15.55e9 B in all (7.26 GB and 15.42e9 B until PR 45, whose head holds its
+    XLA's analysis reads 6.18 GB of temporaries beside 8.17 GB of arguments,
+    14.34e9 B in all (7.39 GB and 15.55e9 B until PR 46, while the fold's
+    kernels moved seventeen ``[32, 8192, 1]`` float32 row statistics a layer,
+    each padded 128 times to 128 MB, where the one-block form moves two along
+    the lanes; 7.26 GB and 15.42e9 B until PR 45, whose head holds its
     ``dW`` as one float32 ``[d, V]`` from the first head call's forward on
     where the parent's backward held it in bfloat16; at two sequences a step, ISSUE 44's first choice, the
-    compile fails: "Used 17.40G of 14.67G hbm"). The fold's three kernels are
+    compile still fails, by less: "Used 15.47G of 14.67G hbm", 17.40G until PR 46). The fold's three kernels are
     Mosaic's at a head of 192 query and key channels and 128 value channels,
     T 8,192, under their one set of names: K enters at ``[32, 8192, 192]`` and
     V at ``[32, 8192, 128]`` once a head, and no score tensor is an array of
@@ -416,7 +488,8 @@ def test_the_joyai_step_program_at_the_cells_shapes(one_chip):
     assert num_params(cfg) == 680_441_088  # 10.89 GB of f32 state at 16 bytes a parameter: 68% of 16 GB
     batch, t = c["global_batch_size"], c["sequence_length"]
     compiled, memory = _compiled_step(c, cfg, one_chip)
-    assert memory.temp_size_in_bytes < 7.47e9, memory.temp_size_in_bytes  # what it reads and 1%; 7.33e9 until PR 45
+    # what it reads and 1%; 7.39e9 until PR 46 (the fold's padded row statistics), 7.26e9 until PR 45
+    assert memory.temp_size_in_bytes < 6.24e9, memory.temp_size_in_bytes
     text = compiled.as_text()
     for kernel in ("flash_fold_fwd", "flash_fold_bwd_dq", "flash_fold_bwd_dkv"):
         assert kernel in text

@@ -72,11 +72,13 @@ def test_counts_are_what_the_job_says(traced_fit):
     # 256 keys are one chunk, taken whole: the forward's one tile, the dq kernel's four
     # of 64 rows and the dkv kernel's one pair, nothing to hide
     pairs = (1 + 4 + 1) * LAYERS * 2 * BATCH
-    # AdamW's state: mu and nu, a float32 leaf a parameter each, and the int32 count
+    # AdamW's state: mu and nu, a float32 leaf a parameter each, and the int32 count; every layer's fold in the
+    # one-block form, five row statistics through its three kernels
     assert one["train.program"] == {"built": 0, "fold_chunks": pairs, "fold_chunks_visited": pairs,
                                     "loop_trips": 1, "layer_applications": LAYERS,
                                     "state_leaves": 2 * len(param_shapes(cfg)) + 1,
-                                    "state_bytes": 8 * num_params(cfg) + 4, "head_logit_matmuls": 1}
+                                    "state_bytes": 8 * num_params(cfg) + 4, "head_logit_matmuls": 1,
+                                    "fold_one_block": LAYERS, "fold_row_stats": 5}
     assert one["train.dispatch"] == {"steps": STEPS}
     drain = one["train.drain"]
     assert drain["steps"] == STEPS and drain["tokens"] == STEPS * BATCH * T
@@ -148,7 +150,8 @@ def test_a_looped_stack_reports_its_trips_and_its_exits():
     assert one["train.program"] == {"built": 1, "fold_chunks": pairs, "fold_chunks_visited": pairs,
                                     "loop_trips": loops, "layer_applications": layers * loops,
                                     "state_leaves": 2 * len(param_shapes(cfg)) + 1,
-                                    "state_bytes": 8 * num_params(cfg) + 4, "head_logit_matmuls": 1}
+                                    "state_bytes": 8 * num_params(cfg) + 4, "head_logit_matmuls": 1,
+                                    "fold_one_block": layers, "fold_row_stats": 5}  # the traced pass's layers, once
     drain = one["train.drain"]
     assert set(drain) == {"steps", "tokens", "exit_trip_sum", "exit_last_mass", "gate_entropy_sum", "trip_nll"}
     tokens = steps * BATCH * T
