@@ -60,6 +60,9 @@ class MLMetrics:
     TRAIN_LM_FOLD_CHUNKS_VISITED = "ml.train.lm.fold.chunks_visited"  # those the mask does not hide: the ones computed, counter
     TRAIN_LM_FOLD_WIN_CHUNKS = "ml.train.lm.fold.win_chunks"  # the windowed layers' share of lm.fold.chunks, counter
     TRAIN_LM_FOLD_WIN_CHUNKS_VISITED = "ml.train.lm.fold.win_chunks_visited"  # those neither the mask nor the window hides, counter
+    TRAIN_LM_FOLD_BD_CHUNKS = "ml.train.lm.fold.bd_chunks"  # the share of lm.fold.chunks of folds under the block-diffusion mask (doubled sequences), counter
+    TRAIN_LM_FOLD_BD_CHUNKS_VISITED = "ml.train.lm.fold.bd_chunks_visited"  # those the block-diffusion mask does not hide, counter
+    TRAIN_LM_DIFFUSION_TARGETS = "ml.train.lm.diffusion.targets"  # masked positions the block-diffusion objective scored, counter
     TRAIN_LM_LOOP_TRIPS = "ml.train.lm.loop.trips"  # passes of the whole stack the fit's steps ran (steps x numLoops), counter
     TRAIN_LM_LOOP_LAYER_APPLICATIONS = "ml.train.lm.loop.layer_applications"  # block applications (layers x passes x steps), counter
     TRAIN_LM_SCAN_CHUNKS = "ml.train.lm.scan.chunks"  # chunks of the state-space scan (chunks x heads x sequences x Mamba-2 layers x steps), counter

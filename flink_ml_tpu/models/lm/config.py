@@ -1,8 +1,8 @@
 """The decoder LM's sizes, what each layer of its stack IS, and its parameter
 tree, shared by the stage (``decoder_lm.py``) and the plain references
 (``reference.py``, ``reference_zaya.py``, ``reference_ouro.py``,
-``reference_laguna.py``, ``reference_nemotron.py``, ``reference_joyai.py``) so
-that one set of weights can be handed to both.
+``reference_laguna.py``, ``reference_nemotron.py``, ``reference_joyai.py``,
+``reference_sdar.py``) so that one set of weights can be handed to both.
 
 ``layers(cfg)`` gives one hashable record a layer (``Layer``): a MIXER, a
 FEED-FORWARD (either may be absent), the stream's width and the norms'
@@ -11,7 +11,7 @@ learned per-channel scale and bias on both. The parameter tree
 (``param_shapes``), the forward (``decoder_lm._layer``), the fit's counts and
 the stage's checks all read that record and nothing else about a layer.
 
-``LMConfig.block`` names one of six PRESETS over that description
+``LMConfig.block`` names one of seven PRESETS over that description
 (``_PRESETS``: the only place that names a model), each a published stack:
 
 ========== ================================================= ==========================================
@@ -29,7 +29,16 @@ nemotron_h ONE sublayer a layer behind the norm ``norm``, by ``layer_kinds[i]``:
 joyai      latent attention: queries, keys and values      dense (the first ``n_dense`` layers), then
            rebuilt from low-rank latents, one rotary key a   experts with sigmoid gates beside a shared
            token under every head, RoPE on interleaved pairs expert (laguna's record)
+sdar       attention on grouped queries, a QK-norm over EACH experts, linear router, the chosen softmax
+           head's channels, RoPE on the whole head, under    gates renormalised over all ``top_k``
+           the block-diffusion mask of a doubled sequence
 ========== ================================================= ==========================================
+
+``sdar`` is also the one kind whose OBJECTIVE is not next-token prediction:
+``LMConfig.block_length`` > 0 trains by block diffusion (``reference_sdar.py``):
+the stack runs over ``[x ; x~]``, the ``T`` tokens and a copy whose tokens are
+masked (``mask_id``) with a probability drawn a sequence, and the noised
+half's positions are scored on their own tokens.
 
 The tree: ``{"embed": [V, d], "layers": [layer, ...], "final_norm": [d],
 "lm_head": [d, V]}``; a tied head (``LMConfig.tied``) has no ``lm_head``: the
@@ -51,10 +60,14 @@ The sublayers, with ``a = heads * head_dim`` and ``c = kv_heads * head_dim``:
 
 - ``Attention`` (causal softmax attention of ``heads`` query heads on
   ``kv_heads`` key/value heads through the fused fold; a ``Rotation`` of q and
-  k or none; a sliding ``window`` of keys or 0): ``"wq": [d, a], "wk"/"wv":
-  [d, c]``, ``"head_gate": [d, heads]`` under a sigmoid gate a head on the
-  output, ``"wo": [a, d]``, ``"q_norm": [a], "k_norm": [c]`` under a QK-norm, the
-  output's norm ``[d]`` if any (``attn_out_norm``).
+  k or none; a sliding ``window`` of keys or 0; or, ``diffusion_block`` > 0, the
+  block-diffusion mask over a doubled sequence in blocks of that many
+  positions): ``"wq": [d, a], "wk"/"wv": [d, c]``, ``"head_gate": [d, heads]``
+  under a sigmoid gate a head on the output, ``"wo": [a, d]``, ``"q_norm": [a],
+  "k_norm": [c]`` under a QK-norm over the whole projection (``qk_norm``
+  ``"projection"``) or ``"q_norm"/"k_norm": [head_dim]`` under one over each
+  head's channels (``"head"``), the output's norm ``[d]`` if any
+  (``attn_out_norm``).
 - ``LatentAttention`` (multi-head latent attention, ``reference_joyai.py``:
   ``heads`` heads whose queries and keys are ``nope_dim + rope_dim`` wide and
   whose values ``v_dim``, through the same fold), ``r = rope_dim``: ``"wq_a":
@@ -83,7 +96,8 @@ The sublayers, with ``a = heads * head_dim`` and ``c = kv_heads * head_dim``:
   a layer hands the next, ``"router_in": [d, r], "router_gamma": [r]`` (where
   there is a layer before), ``"router_norm": [r], "router_w1"/"router_w2": [r,
   r], "router_w3": [r, E]``; the softmax probabilities of the chosen are kept
-  as they are or, under ``routed_scale``, sigmoid gates renormalised and scaled
+  as they are, or (``renormalise``) divided by their sum over all ``top_k``
+  chosen, or, under ``routed_scale``, sigmoid gates renormalised and scaled
   with ``"router_bias": [E]`` added to the scores where the experts are CHOSEN
   and nowhere else (no gradient reaches it); a shared expert every token
   passes, ``"shared_gate"/"shared_up": [d, s], "shared_down": [s, d]``; the held
@@ -99,7 +113,7 @@ from typing import List, NamedTuple, Optional, Tuple, Union
 __all__ = ["LMConfig", "BLOCKS", "MIXERS", "Rotation", "Attention", "LatentAttention", "CCA", "Mamba2", "Dense",
            "Experts", "Layer", "layers", "exit_gate", "mtp_layer", "leaves", "param_shapes", "num_params", "ONES",
            "ZEROS", "NORMAL", "SMALL",
-           "SMALL_SCALE", "DT_BIAS", "A_LOG", "DT_RANGE", "DT_FLOOR", "A_RANGE"]
+           "SMALL_SCALE", "DT_BIAS", "A_LOG", "DT_RANGE", "DT_FLOOR", "A_RANGE", "NOISE_EPS"]
 
 #: The ``nemotron_h`` stack's layer kinds, as its published pattern spells them:
 #: a Mamba-2 scan, attention without a position encoding, relu² experts.
@@ -121,6 +135,9 @@ SMALL_SCALE = 0.02
 #: at ``log(A_RANGE[0] + u (A_RANGE[1] - A_RANGE[0]))``.
 DT_BIAS, A_LOG = "dt_bias", "a_log"
 DT_RANGE, DT_FLOOR, A_RANGE = (1e-3, 1e-1), 1e-4, (1.0, 16.0)
+#: Block diffusion's linear schedule: a sequence's tokens are masked with probability ``p = (1 - NOISE_EPS) t +
+#: NOISE_EPS``, ``t`` uniform in [0, 1): the floor keeps the loss's ``1 / p`` finite.
+NOISE_EPS = 1e-3
 
 
 class LMConfig(NamedTuple):
@@ -181,6 +198,10 @@ class LMConfig(NamedTuple):
     v_dim: int = 0
     mtp_depth: int = 0
     mtp_coef: float = 0.0
+    # the block-diffusion objective ('sdar'): positions a block (0: next-token prediction under the causal mask) and
+    # the id a masked token is replaced with
+    block_length: int = 0
+    mask_id: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -218,10 +239,11 @@ class Attention:
     head_dim: int
     rotation: Optional[Rotation] = None
     window: int = 0  # keys each query keeps, its own among them; 0: all before it
-    qk_norm: bool = False
+    qk_norm: str = ""  # an RMS norm on q and k: "projection" over all heads' channels together, "head" over each's
     head_gate: bool = False
     norm: str = "attn_norm"
     out_norm: str = ""  # the leaf of the norm on the output; empty: none
+    diffusion_block: int = 0  # > 0: the sequence is doubled, [x ; x~], under the block-diffusion mask in such blocks
 
 
 @dataclasses.dataclass(frozen=True)
@@ -277,6 +299,7 @@ class Experts:
     router_width: int = 0  # 0: a linear router; else the width of the MLP that routes
     carried: bool = False  # the layer before hands the router its hidden state
     routed_scale: float = 0.0  # 0: softmax probabilities kept as they are; else sigmoid gates renormalised, times this
+    renormalise: bool = False  # softmax gates over their sum over all top_k chosen (with routed_scale 0)
     gated: bool = True  # SwiGLU experts; ungated: down(relu(up(x))^2)
     shared_width: Optional[int] = None  # None: no shared expert
     norm: str = "ffn_norm"
@@ -296,7 +319,8 @@ def _experts(cfg: LMConfig, **own) -> Experts:
 
 
 def _olmoe(cfg: LMConfig):
-    mixer = Attention(cfg.n_heads, cfg.kv_heads, cfg.head_dim, Rotation(cfg.head_dim, cfg.rope_theta), qk_norm=True)
+    mixer = Attention(cfg.n_heads, cfg.kv_heads, cfg.head_dim, Rotation(cfg.head_dim, cfg.rope_theta),
+                      qk_norm="projection")
     return (Layer(cfg.hidden, cfg.norm_eps, mixer, _experts(cfg)),) * cfg.n_layers
 
 
@@ -344,9 +368,15 @@ def _joyai(cfg: LMConfig):
                  for i in range(cfg.n_layers))
 
 
+def _sdar(cfg: LMConfig):
+    mixer = Attention(cfg.n_heads, cfg.kv_heads, cfg.head_dim, Rotation(cfg.head_dim, cfg.rope_theta), qk_norm="head",
+                      diffusion_block=cfg.block_length)
+    return (Layer(cfg.hidden, cfg.norm_eps, mixer, _experts(cfg, renormalise=True)),) * cfg.n_layers
+
+
 #: kind -> (its layers, whether every pass of the stack ends in an exit gate: a linear with a bias)
 _PRESETS = {"olmoe": (_olmoe, False), "zaya": (_zaya, False), "ouro": (_ouro, True), "laguna": (_laguna, False),
-            "nemotron_h": (_nemotron_h, False), "joyai": (_joyai, False)}
+            "nemotron_h": (_nemotron_h, False), "joyai": (_joyai, False), "sdar": (_sdar, False)}
 BLOCKS = tuple(_PRESETS)
 
 
@@ -378,7 +408,8 @@ def _attention_own(m: Attention, d: int):
     a, c = m.heads * m.head_dim, m.kv_heads * m.head_dim
     return ((("wq", (d, a), NORMAL), ("wk", (d, c), NORMAL), ("wv", (d, c), NORMAL))
             + ((("head_gate", (d, m.heads), NORMAL),) if m.head_gate else ()) + (("wo", (a, d), NORMAL),)
-            + ((("q_norm", (a,), ONES), ("k_norm", (c,), ONES)) if m.qk_norm else ())
+            + tuple((name, (m.head_dim if m.qk_norm == "head" else width,), ONES)
+                    for name, width in (("q_norm", a), ("k_norm", c)) if m.qk_norm)
             + (((m.out_norm, (d,), ONES),) if m.out_norm else ()))
 
 

@@ -1,6 +1,7 @@
 """``DecoderLM`` - a decoder-only language-model stage that trains through
 ``Estimator.fit``: an embedding, a stack of pre-norm residual layers, a head,
-next-token cross-entropy. What a layer IS is one record (``config.Layer``, from
+next-token cross-entropy (or, ``blockKind`` ``sdar``, the block-diffusion
+objective below). What a layer IS is one record (``config.Layer``, from
 ``config.layers``): a mixer - causal self-attention through the fused fold of
 ``parallel/flash.py`` with the rotation, window, QK-norm, head gate and output
 norm its record names (``_attend``); latent attention, whose queries, keys and
@@ -10,7 +11,7 @@ attention (``_cca``); a Mamba-2 scan behind its convolution (``_mamba2``:
 ``parallel/causal_conv.py``, ``parallel/ssd.py``) - and a feed-forward - one
 dense SwiGLU or a dropless mixture of experts, ``parallel/moe.py``
 (``_feed_forward``) - either of which may be absent, each joined to the
-residual stream (``_layer``). ``blockKind`` names one of six presets over that
+residual stream (``_layer``). ``blockKind`` names one of seven presets over that
 description, each a published stack with its plain reference beside it
 (``config.py`` has the table and every leaf): ``olmoe`` (``reference.py``),
 ``zaya`` (ZAYA1-8B, ``reference_zaya.py``), ``ouro`` (Ouro-2.6B's looped LM,
@@ -30,8 +31,19 @@ beside a shared one, and behind the stack a multi-token-prediction module,
 token's embedding and predicts the token after it through the same head; the
 loss is the next-token cross-entropy plus ``mtpLossCoef`` times the module's;
 ``transform`` scores with the main head alone, the module is a training
-objective). The fit loop, the head, the loss's chunking, the clip and the AdamW
-program are one.
+objective) and ``sdar`` (SDAR-30B-A3B-Chat, ``reference_sdar.py``: the
+Qwen3-MoE layer - grouped queries under a QK-norm over each head's channels,
+softmax gates renormalised over the chosen experts - trained by BLOCK
+DIFFUSION: a step draws a masking probability a sequence and masks each token
+with it (``lm.noise``: on the device, from a key folded from the stage's seed
+and the job's step index), the stack runs ONCE over the doubled sequence ``[x
+; x~]``, both halves at positions ``0 .. T - 1``, under a mask that is
+block-causal on the clean half, strictly block-causal from the noised half
+onto it and block-diagonal inside the noised half (``parallel/flash.py``, "The
+block-diffusion mask"), and the head scores the noised half's masked positions
+on their OWN tokens with weight ``1 / p``; ``transform`` reports a one-draw
+estimate of that bound a row). The fit loop, the head, the loss's chunking, the
+clip and the AdamW program are one.
 
 Any expert kind may tie the head to the embedding (``tieEmbeddings``: one leaf)
 and hold a range of each block's experts (``expertsHeld``,
@@ -69,7 +81,8 @@ the sorted rows (``parallel/moe.py``), the head's ``[tokens, vocabulary]``
 logits exist one token chunk at a time and ONCE a step: every objective here
 is a weighted sum of per-token cross-entropies with weights known before the
 head runs (the mean's ``1 / (B (T - 1))``, a module's coefficient over its
-targets, a looped stack's exit distribution), so the pass that holds a chunk's
+targets, a looped stack's exit distribution, block diffusion's ``1 / (p B T)``
+on the masked positions), so the pass that holds a chunk's
 logits forms ``w (softmax - onehot)``, that chunk's ``dh`` and its share of
 ``dW`` there and then, and the backward only scales them (``_next_token_nll``,
 ``_weighted_nll``); and where there is more than one block each is
@@ -80,8 +93,8 @@ applications and each pass's output.
 On the TPU the step is compiled into a stated size (``STEP_HBM_MIB``): XLA
 rematerialises further, toward arguments and temporaries that fit it.
 
-Names: the step program's parts carry ``jax.named_scope``s (``lm.embed``,
-``lm.block`` with each sublayer's parts under it, ``lm.final_norm``,
+Names: the step program's parts carry ``jax.named_scope``s (``lm.noise``,
+``lm.embed``, ``lm.block`` with each sublayer's parts under it, ``lm.final_norm``,
 ``lm.head``, ``lm.mtp``, ``lm.exit``, ``lm.aux``, ``lm.opt``; docs/observability.md, "The
 step's scopes", has every path and what opens it), which reach each device
 operation's name beside what JAX's transformations write there, so a profile
@@ -107,7 +120,7 @@ from flink_ml_tpu.api.core import Estimator, Model
 from flink_ml_tpu.api.types import DataTypes
 from flink_ml_tpu.metrics import MLMetrics, metrics
 from flink_ml_tpu.models.lm.config import (
-    A_LOG, A_RANGE, BLOCKS, CCA, DT_BIAS, DT_FLOOR, DT_RANGE, MIXERS, NORMAL, ONES, SMALL, SMALL_SCALE, Attention,
+    A_LOG, A_RANGE, BLOCKS, CCA, DT_BIAS, DT_FLOOR, DT_RANGE, MIXERS, NOISE_EPS, NORMAL, ONES, SMALL, SMALL_SCALE, Attention,
     Dense, Experts, LatentAttention, Layer, LMConfig, Mamba2, exit_gate, layers, mtp_layer, num_params, param_shapes,
 )
 from flink_ml_tpu.params.param import (
@@ -133,6 +146,7 @@ from flink_ml_tpu.parallel.causal_conv import causal_conv, forward_positions
 from flink_ml_tpu.parallel.flash import (
     ONE_BLOCK_ROW_STATS,
     TQ_TILE,
+    BlockDiffusion,
     flash_available,
     fold_chunk_counts,
     fold_kernel_calls,
@@ -175,7 +189,8 @@ class _LMParams(
     )
     NUM_EXPERTS = IntParam("numExperts", "Experts per block.", 8, ParamValidators.gt(0))
     EXPERTS_PER_TOKEN = IntParam(
-        "expertsPerToken", "Experts each token is routed to (top-k, not renormalised).", 2,
+        "expertsPerToken", "Experts each token is routed to (top-k; the softmax gates of the chosen are kept as they "
+        "are, 'sdar' renormalises them over the chosen; the sigmoid-gated kinds: routedScale).", 2,
         ParamValidators.gt(0),
     )
     EXPERT_WIDTH = IntParam(
@@ -183,7 +198,7 @@ class _LMParams(
         ParamValidators.gt(0),
     )
     VOCAB_SIZE = IntParam(
-        "vocabSize", "Vocabulary size; 0 infers max(token) + 1 from the training data.", 0,
+        "vocabSize", "Vocabulary size; 0 infers max(token) + 1 from the training data, and one id more, the mask's, under an objective that masks ('sdar').", 0,
         ParamValidators.gt_eq(0),
     )
     ROPE_THETA = FloatParam("ropeTheta", "Base of the rotary embedding.", 10000.0, ParamValidators.gt(0))
@@ -202,7 +217,9 @@ class _LMParams(
         "'nemotron_h' (each layer one mixer, by layerPattern: a Mamba-2 scan, attention without a "
         "position encoding, or relu² experts beside a shared one) or "
         "'joyai' (latent attention on low-rank queries, keys and values, leading dense layers, sigmoid-gated "
-        "experts beside a shared one, a multi-token-prediction module behind the stack).",
+        "experts beside a shared one, a multi-token-prediction module behind the stack) or "
+        "'sdar' (grouped-query attention with a QK-norm a head, experts whose softmax gates are renormalised over the "
+        "chosen, trained by block diffusion over the doubled sequence).",
         "olmoe", ParamValidators.in_array(list(BLOCKS)),
     )
     TIE_EMBEDDINGS = BoolParam("tieEmbeddings", "The head is the embedding table transposed.", False)
@@ -218,11 +235,11 @@ class _LMParams(
     FIRST_EXPERT_HELD = IntParam("firstExpertHeld", "First expert of the held range.", 0,
                                  ParamValidators.gt_eq(0))
     NUM_KV_HEADS = IntParam(
-        "numKvHeads", "Key/value heads ('zaya', 'laguna', 'nemotron_h'; the query heads divide evenly over them). "
+        "numKvHeads", "Key/value heads ('zaya', 'laguna', 'nemotron_h', 'sdar'; the query heads divide evenly over them). "
         "0: numHeads.", 0,
         ParamValidators.gt_eq(0),
     )
-    HEAD_SIZE = IntParam("headSize", "Channels per head ('zaya', 'laguna', 'nemotron_h'). 0: hiddenSize / numHeads.", 0,
+    HEAD_SIZE = IntParam("headSize", "Channels per head ('zaya', 'laguna', 'nemotron_h', 'sdar'). 0: hiddenSize / numHeads.", 0,
                          ParamValidators.gt_eq(0))
     ROPE_FRACTION = FloatParam(
         "ropeFraction", "Share of each head's channels the rotary embedding turns ('zaya'; 'laguna': in "
@@ -288,6 +305,13 @@ class _LMParams(
                          "transform does not run it.", 0, ParamValidators.in_array([0, 1]))
     MTP_LOSS_COEF = FloatParam("mtpLossCoef", "Weight of the multi-token-prediction module's loss ('joyai').", 0.3,
                                ParamValidators.gt_eq(0))
+    BLOCK_LENGTH = IntParam(
+        "blockLength", "Positions a block of the block-diffusion objective ('sdar'): a power of two that divides the "
+        "sequence length. A masked position sees the clean blocks before its own and its own block's noised copy.", 4,
+        ParamValidators.gt(0))
+    MASK_TOKEN_ID = IntParam(
+        "maskTokenId", "The id a masked token is replaced with ('sdar'). -1: the vocabulary's last id.", -1,
+        ParamValidators.gt_eq(-1))
     COMPUTE_TYPE = StringParam(
         "computeType",
         "Matmul input dtype: 'bfloat16' runs every matmul and the attention "
@@ -302,9 +326,10 @@ class _LMParams(
     #: The other kinds leave it at ``LMConfig``'s default (no experts; no balancing loss: a bias rule outside the
     #: gradient balances every kind but 'olmoe', reference_zaya.py).
     _OWN = {
-        ("olmoe", "zaya", "laguna", "nemotron_h", "joyai"): (("n_experts", NUM_EXPERTS), ("top_k", EXPERTS_PER_TOKEN)),
+        ("olmoe", "zaya", "laguna", "nemotron_h", "joyai", "sdar"): (("n_experts", NUM_EXPERTS),
+                                                                     ("top_k", EXPERTS_PER_TOKEN)),
         ("olmoe",): (("aux_coef", AUX_LOSS_COEF),),
-        ("zaya", "laguna", "nemotron_h"): (("n_kv_heads", NUM_KV_HEADS), ("head_size", HEAD_SIZE)),
+        ("zaya", "laguna", "nemotron_h", "sdar"): (("n_kv_heads", NUM_KV_HEADS), ("head_size", HEAD_SIZE)),
         ("zaya", "laguna"): (("rope_fraction", ROPE_FRACTION),),
         ("zaya",): (("router_width", ROUTER_WIDTH),),
         ("ouro",): (("loops", NUM_LOOPS), ("exit_beta", EXIT_ENTROPY_COEF)),
@@ -315,14 +340,18 @@ class _LMParams(
         ("joyai",): (("q_rank", Q_LORA_RANK), ("kv_rank", KV_LORA_RANK), ("nope_dim", QK_NOPE_HEAD_SIZE),
                      ("rope_dim", QK_ROPE_HEAD_SIZE), ("v_dim", V_HEAD_SIZE), ("mtp_depth", MTP_DEPTH),
                      ("mtp_coef", MTP_LOSS_COEF)),
+        ("sdar",): (("block_length", BLOCK_LENGTH), ("mask_id", MASK_TOKEN_ID)),
         ("nemotron_h",): (("layer_kinds", LAYER_PATTERN), ("ssm_heads", SSM_NUM_HEADS), ("ssm_head_dim", SSM_HEAD_SIZE),
                           ("ssm_groups", SSM_NUM_GROUPS), ("ssm_state", SSM_STATE_SIZE),
                           ("conv_kernel", SSM_CONV_KERNEL), ("chunk", SSM_CHUNK_SIZE)),
     }
     #: The params refused where they are given a value under a kind they do not belong to.
     _REFUSED = (
-        ((NUM_KV_HEADS, HEAD_SIZE, ROPE_FRACTION), ("zaya", "laguna", "nemotron_h"),
-         "numKvHeads, headSize and ropeFraction belong to blockKind 'zaya', 'laguna' or 'nemotron_h'"),
+        ((NUM_KV_HEADS, HEAD_SIZE), ("zaya", "laguna", "nemotron_h", "sdar"),
+         "numKvHeads and headSize belong to blockKind 'zaya', 'laguna', 'nemotron_h' or 'sdar'"),
+        ((ROPE_FRACTION,), ("zaya", "laguna", "nemotron_h"),
+         "ropeFraction belongs to blockKind 'zaya', 'laguna' or 'nemotron_h'"),
+        ((BLOCK_LENGTH, MASK_TOKEN_ID), ("sdar",), "blockLength and maskTokenId belong to blockKind 'sdar'"),
         ((NUM_HEADS_PER_LAYER, WINDOW_PER_LAYER), ("laguna",),
          "numHeadsPerLayer and windowPerLayer belong to blockKind 'laguna'"),
         ((DENSE_LAYERS,), ("laguna", "joyai"), "denseLayers belongs to blockKind 'laguna' or 'joyai'"),
@@ -332,9 +361,9 @@ class _LMParams(
          "qLoraRank, kvLoraRank, qkNopeHeadSize, qkRopeHeadSize, vHeadSize and mtpDepth belong to blockKind 'joyai'"),
         ((LAYER_PATTERN, SSM_NUM_HEADS), ("nemotron_h",), "layerPattern and the ssm sizes belong to blockKind 'nemotron_h'"),
         ((NUM_LOOPS,), ("ouro",), "numLoops belongs to blockKind 'ouro'"),
-        ((TIE_EMBEDDINGS, EXPERTS_HELD, FIRST_EXPERT_HELD), ("olmoe", "zaya", "laguna", "nemotron_h", "joyai"),
+        ((TIE_EMBEDDINGS, EXPERTS_HELD, FIRST_EXPERT_HELD), ("olmoe", "zaya", "laguna", "nemotron_h", "joyai", "sdar"),
          "tieEmbeddings, expertsHeld and firstExpertHeld do not belong to blockKind 'ouro'"),
-        ((TIE_EMBEDDINGS,), ("olmoe", "zaya", "ouro", "laguna", "joyai"),
+        ((TIE_EMBEDDINGS,), ("olmoe", "zaya", "ouro", "laguna", "joyai", "sdar"),
          "tieEmbeddings does not belong to blockKind 'nemotron_h'"),
     )
 
@@ -365,6 +394,13 @@ class _LMParams(
             raise ValueError(f"denseLayers {cfg.n_dense} is at most numLayers {cfg.n_layers}")
         if not cfg.head_size and cfg.hidden % cfg.n_heads:
             raise ValueError(f"hiddenSize {cfg.hidden} must divide evenly by numHeads {cfg.n_heads}")
+        if cfg.block_length:  # the objective's sizes
+            if cfg.block_length & (cfg.block_length - 1):
+                raise ValueError(f"blockLength is a power of two, got {cfg.block_length}")
+            if cfg.mask_id < 0:
+                cfg = cfg._replace(mask_id=cfg.vocab - 1)  # the vocabulary's last id (of an inferred vocabulary: in fit)
+            if cfg.vocab and cfg.mask_id >= cfg.vocab:
+                raise ValueError(f"maskTokenId {cfg.mask_id} is not among the vocabulary's {cfg.vocab} ids")
         for spec in dict.fromkeys(layers(cfg)):  # each distinct layer once: its mixer, then its feed-forward
             _check_mixer(spec.mixer)
             _check_feed_forward(spec.ffn)
@@ -541,15 +577,19 @@ def _proj(a, w, cd):
         return _matmul(a, w, cd)
 
 
-def _fold(q, k, v, cd, interpret: bool, window: Optional[int] = None):
-    """Causal softmax attention of ``q [B, H, T, D]`` on ``k [B, H_kv, T, D]``
-    and ``v [B, H_kv, T, D_v]`` (``[B, H, T, D_v]`` out in float32; ``D_v`` is
-    ``D`` but under latent attention) at scale ``D^-1/2`` through the fused
-    fold's one-block form: a ring of one, the whole sequence is the resident
-    KV block. Under a ``window`` each query keeps the ``window`` keys that end
-    at itself."""
+def _fold(q, k, v, cd, interpret: bool, window: Optional[int] = None, blocks: Optional[BlockDiffusion] = None):
+    """Softmax attention of ``q [B, H, T, D]`` on ``k [B, H_kv, T, D]`` and ``v
+    [B, H_kv, T, D_v]`` (``[B, H, T, D_v]`` out in float32; ``D_v`` is ``D``
+    but under latent attention) at scale ``D^-1/2`` through the fused fold's
+    one-block form: a ring of one, the whole sequence is the resident KV
+    block. The mask is one of the fold's three forms, passed on as given:
+    causal (neither ``window`` nor ``blocks``); under a ``window`` each query
+    keeps the ``window`` keys that end at itself; under ``blocks`` the ``T``
+    positions are a clean sequence and its noised copy, and the mask is block
+    diffusion's (``flash.BlockDiffusion``)."""
     with jax.named_scope("fold"):
-        return fused_attention(q.astype(cd), k.astype(cd), v.astype(cd), float(q.shape[-1]) ** -0.5, window, interpret)
+        return fused_attention(q.astype(cd), k.astype(cd), v.astype(cd), float(q.shape[-1]) ** -0.5, window, interpret,
+                               blocks)
 
 
 def _heads(z, n: int):
@@ -708,20 +748,32 @@ def _mamba2(x, layer, m: Mamba2, eps: float, cd, interpret: bool):
 
 def _attend(x, layer, m: Attention, eps: float, cd, interpret: bool):
     """Causal attention on (grouped) queries through the fused fold, with what
-    the record asks for around it: a QK-norm on the projections, a rotation of
-    q and k, a window, a sigmoid gate per head on the output, a norm on the
-    projection back."""
+    the record asks for around it: a QK-norm on the projections (over all
+    heads' channels together, or over each head's), a rotation of q and k, a
+    window, a sigmoid gate per head on the output, a norm on the projection
+    back. Under ``diffusion_block`` the positions are a sequence and its noised
+    copy, ``[x ; x~]``: each half turns at positions ``0 .. T - 1`` and the
+    mask is block diffusion's."""
     a = _rms_norm(x, layer[m.norm], eps)
+    t = x.shape[1] // 2 if m.diffusion_block else x.shape[1]
 
     def head(w: str, n: int, norm: str):
         z = _proj(a, layer[w], cd)
-        return _heads(_rms_norm(z, layer[norm], eps) if m.qk_norm and norm else z, n)
+        if m.qk_norm == "head" and norm:  # each head's channels by themselves, one weight for every head
+            z = _rms_norm(z.reshape(*z.shape[:2], n, -1), layer[norm], eps)
+        elif m.qk_norm and norm:
+            z = _rms_norm(z, layer[norm], eps)
+        return _heads(z, n)
 
     q, k, v = head("wq", m.heads, "q_norm"), head("wk", m.kv_heads, "k_norm"), head("wv", m.kv_heads, "")
     if m.rotation is not None:
-        cos, sin = _yarn_tables(x.shape[1], m.rotation.channels, m.rotation.theta, m.rotation.yarn)
+        cos, sin = _yarn_tables(t, m.rotation.channels, m.rotation.theta, m.rotation.yarn)
+        if m.diffusion_block:
+            with jax.named_scope("rope"):  # the T positions' tables twice
+                cos, sin = jnp.tile(cos, (2, 1)), jnp.tile(sin, (2, 1))
         q, k = _rope_part(q, cos, sin), _rope_part(k, cos, sin)
-    o = _fold(q, k, v, cd, interpret, m.window or None)
+    mask = {"blocks": BlockDiffusion(t, m.diffusion_block)} if m.diffusion_block else {}
+    o = _fold(q, k, v, cd, interpret, m.window or None, **mask)
     if m.head_gate:
         with jax.named_scope("gate"):
             g = jax.nn.sigmoid(_matmul(a, layer["head_gate"], cd))  # [B, T, H]
@@ -777,7 +829,8 @@ def _feed_forward(x, carry, layer, f, eps: float, cd):
     else:
         router = layer["router"]
     y, stats = moe_dropless(u, router, layer["w_gate"] if f.gated else None, layer["w_up"], layer["w_down"], f.top_k,
-                            cd, f.first_held, f.routed_scale, layer["router_bias"] if f.routed_scale else None)
+                            cd, f.first_held, f.routed_scale, layer["router_bias"] if f.routed_scale else None,
+                            f.renormalise)
     if f.shared_width is not None:
         with jax.named_scope("shared"):
             y = y + dense_swiglu(u, layer["shared_gate"] if f.gated else None, layer["shared_up"],
@@ -805,8 +858,10 @@ def _layer(x, carry, layer, spec: Layer, cd, interpret: bool):
     return x, carry, stats
 
 
-def _hidden(params, tok, cfg: LMConfig, cd, interpret: bool, mtp: bool = False):
-    """The final-normed hidden states, each expert layer's router statistics
+def _hidden(params, tok, cfg: LMConfig, cd, interpret: bool, mtp: bool = False, tail: int = 0):
+    """The final-normed hidden states (``tail`` > 0: of the last ``tail``
+    positions alone, the noised half of a doubled sequence: the others' states
+    are read by no head), each expert layer's router statistics
     and the exit gate's logits. A stack without an exit gate passes once:
     ``[B, T, d]``, no logits. With one, the stack runs ``cfg.loops`` times over
     the same leaves, each pass's normed state feeding the next: ``[R, B, T,
@@ -834,7 +889,7 @@ def _hidden(params, tok, cfg: LMConfig, cd, interpret: bool, mtp: bool = False):
             if stats:  # a layer without experts has nothing to report
                 routed.append(stats)
         with jax.named_scope("lm.final_norm"):
-            h = _rms_norm(x, params["final_norm"], cfg.norm_eps)
+            h = _rms_norm(x[:, -tail:] if tail else x, params["final_norm"], cfg.norm_eps)
         if not mtp:
             return h, routed, None
         # position i's stream beside the embedding of token i + 1, through one more layer: the state that predicts
@@ -936,36 +991,90 @@ def _weighted_nll_bwd(cd, residuals, cotangents):
 _weighted_nll.defvjp(_weighted_nll_fwd, _weighted_nll_bwd)
 
 
-def _next_token_nll(h, lm_head, tok, cd):
-    """The head over ``h [B, T, d]``, bound to its inputs: what comes back is
-    called with nothing, to score, and gives ``nll [B, T]`` f32, minus the
-    log-probability of token ``t + 1`` at position ``t`` (0 at the last
-    position, which has no target); or with token weights ``[B, T]`` f32, to
-    train, and gives ``(sum_i weight_i nll_i, nll)``, whose first is the
-    objective's part through the head - every caller's is linear in the
-    per-token ``nll`` - and whose ``nll`` is for reporting and carries no
-    gradient. The ``[chunk, V]`` logits exist one chunk of token rows at a
-    time and are computed ONCE a step: the pass that has them forms ``w_i
-    (softmax_i - onehot_i)`` and from it that chunk's ``dh`` and its share of
-    ``dW`` (float32 over the chunks), and the backward multiplies both by the
-    incoming scalar (``_weighted_nll``). The weights are differentiable (their
-    cotangent is ``nll``'s); the last position's is not read."""
+def _target_nll(h, lm_head, targets, cd, last: bool = True):
+    """The head over ``h [B, T, d]`` against handed ``targets [B, T]``, bound to
+    its inputs: what comes back is called with nothing, to score, and gives
+    ``nll [B, T]`` f32, minus the log-probability of position ``t``'s target;
+    or with token weights ``[B, T]`` f32, to train, and gives ``(sum_i weight_i
+    nll_i, nll)``, whose first is the objective's part through the head - every
+    caller's is linear in the per-token ``nll`` - and whose ``nll`` is for
+    reporting and carries no gradient. ``last`` false: the last position has no
+    target (its ``nll`` and its weight are 0, whatever is handed in). The
+    ``[chunk, V]`` logits exist one chunk of token rows at a time and are
+    computed ONCE a step: the pass that has them forms ``w_i (softmax_i -
+    onehot_i)`` and from it that chunk's ``dh`` and its share of ``dW``
+    (float32 over the chunks), and the backward multiplies both by the incoming
+    scalar (``_weighted_nll``). The weights are differentiable (their cotangent
+    is ``nll``'s)."""
     b, t, d = h.shape
     n = b * t
     chunk = _LOSS_CHUNK if n % _LOSS_CHUNK == 0 else t
     with jax.named_scope("lm.head"):
-        targets = jnp.concatenate([tok[:, 1:], jnp.zeros((b, 1), tok.dtype)], axis=1).reshape(n // chunk, chunk)
+        targets = targets.reshape(n // chunk, chunk)
         rows = h.reshape(n // chunk, chunk, d)
+
+    def whole(a):
+        a = a.reshape(b, t)
+        return a if last else a.at[:, -1].set(0.0)
 
     def score(weight=None):
         with jax.named_scope("lm.head"):
             if weight is None:
-                return _chunked_nll(rows, lm_head, targets, cd).reshape(b, t).at[:, -1].set(0.0)
-            weight = weight.astype(jnp.float32).at[:, -1].set(0.0).reshape(n // chunk, chunk)
+                return whole(_chunked_nll(rows, lm_head, targets, cd))
+            weight = whole(weight.astype(jnp.float32)).reshape(n // chunk, chunk)
             total, nll = _weighted_nll(rows, lm_head, targets, weight, cd)
-            return total, jax.lax.stop_gradient(nll).reshape(b, t).at[:, -1].set(0.0)
+            return total, whole(jax.lax.stop_gradient(nll))
 
     return score
+
+
+def _next_token_nll(h, lm_head, tok, cd):
+    """``_target_nll`` of next-token prediction: position ``t``'s target is
+    token ``t + 1``, and the last position has none."""
+    with jax.named_scope("lm.head"):
+        targets = jnp.concatenate([tok[:, 1:], jnp.zeros((tok.shape[0], 1), tok.dtype)], axis=1)
+    return _target_nll(h, lm_head, targets, cd, last=False)
+
+
+#: The stream of the stage's seed that block diffusion's corruption draws from: ``fold_in(key(seed), NOISE_STREAM)``,
+#: then ``fold_in(., step index)`` a step. The parameters' streams are ``fold_in(key(seed), leaf index)``, far below.
+NOISE_STREAM = 2 ** 30
+
+
+def _corrupt(tok, noise, cfg: LMConfig):
+    """Block diffusion's corruption of ``tok [B, T]`` from ``noise = (key,
+    index)``, the stage's noise key (``NOISE_STREAM``) and the job's step
+    index: with ``k = fold_in(key, index)`` and ``k_t, k_m = split(k)``, a
+    level ``t = uniform(k_t, [B])`` a sequence, ``p = (1 - eps) t + eps`` (``config.NOISE_EPS``), and
+    token ``i`` of sequence ``s`` masked where ``uniform(k_m, [B, T])[s, i] <
+    p[s]`` (float32 draws). Returns the doubled input ``[x ; x~] [B, 2 T]``
+    (``x~`` is ``x`` with ``mask_id`` at the masked positions), the mask ``[B,
+    T]`` and ``p [B]``."""
+    key, index = noise
+    k_t, k_m = jax.random.split(jax.random.fold_in(key, index))
+    level = jax.random.uniform(k_t, tok.shape[:1], jnp.float32)
+    p = (1.0 - NOISE_EPS) * level + NOISE_EPS
+    masked = jax.random.uniform(k_m, tok.shape, jnp.float32) < p[:, None]
+    noised = jnp.where(masked, jnp.asarray(cfg.mask_id, tok.dtype), tok)
+    return jnp.concatenate([tok, noised], axis=1), masked, p
+
+
+def _diffusion_loss(params, tok, noise, cfg: LMConfig, cd, interpret: bool):
+    """Block diffusion's objective (arXiv:2503.09573 section 3; ``reference_sdar.py``): ``1 / (B T) sum over masked i
+    of (1 / p) x -log softmax(h~_i W_head)[x_i]``, a one-draw estimate of a bound on the sequences' negative
+    log-likelihood a token. The stack runs once over ``[x ; x~]``; the head takes the noised half's ``T`` rows, the
+    position's own token as its target and ``masked / (p B T)`` as its weight: nothing is read from the clean half's
+    rows or from unmasked positions. Returns ``(loss, per-token weighted nll [B, T], routed, stats)``."""
+    b, t = tok.shape
+    with jax.named_scope("lm.noise"):
+        both, masked, p = _corrupt(tok, noise, cfg)
+    h, routed, _, _ = _hidden(params, both, cfg, cd, interpret, tail=t)
+    with jax.named_scope("lm.head"):
+        weight = masked.astype(jnp.float32) / (p[:, None] * (b * t))
+    loss, nll = _target_nll(h, _head(params, cfg), tok, cd)(weight)
+    with jax.named_scope("lm.noise"):
+        stats = {"targets_masked": jnp.sum(masked.astype(jnp.int32)), "noise_level_sum": jnp.sum(p)}
+    return loss, weight * nll, routed, stats
 
 
 def _load_balancing(routed, cfg: LMConfig):
@@ -1012,7 +1121,7 @@ def _exit_loss(passes, gate, lm_head, tok, cfg: LMConfig, cd):
                       "exit_last_mass": jnp.sum(every[-1]), "gate_entropy_sum": -jnp.sum(every * log_p)}
 
 
-def _loss(params, tok, cfg: LMConfig, cd, interpret: bool):
+def _loss(params, tok, cfg: LMConfig, cd, interpret: bool, noise=None):
     """``(loss, stats)``: ``stats`` holds what the blocks have to report - the
     rows each expert took (``rows``), the rows each expert layer carried
     (``carried``), the exits' sums (``_exit_loss``), the multi-token-prediction
@@ -1021,16 +1130,23 @@ def _loss(params, tok, cfg: LMConfig, cd, interpret: bool):
     term through the head is a weighted sum of per-token ``nll`` whose weights
     the head takes in (``_next_token_nll``): the mean's ``1 / (B (T - 1))``,
     the module's ``mtp_coef / mtp_targets`` on the positions it scores, the
-    exits' ``p / (B (T - 1))``."""
-    h, routed, gate, ahead = _hidden(params, tok, cfg, cd, interpret, mtp=bool(cfg.mtp_depth))
+    exits' ``p / (B (T - 1))``. Under ``cfg.block_length`` the objective is
+    block diffusion's (``_diffusion_loss``, from ``noise``): its statistics
+    are the positions it scored (``targets_masked``) and the sequences' summed
+    masking probabilities (``noise_level_sum``)."""
     b, t = tok.shape
-    if gate is None:
-        with jax.named_scope("lm.head"):
-            mean = jnp.full((b, t), 1.0 / (b * (t - 1)), jnp.float32)
-        loss, _ = _next_token_nll(h, _head(params, cfg), tok, cd)(mean)
-        stats = {}
+    ahead = None
+    if cfg.block_length:
+        loss, _, routed, stats = _diffusion_loss(params, tok, noise, cfg, cd, interpret)
     else:
-        loss, stats = _exit_loss(h, gate, _head(params, cfg), tok, cfg, cd)
+        h, routed, gate, ahead = _hidden(params, tok, cfg, cd, interpret, mtp=bool(cfg.mtp_depth))
+        if gate is None:
+            with jax.named_scope("lm.head"):
+                mean = jnp.full((b, t), 1.0 / (b * (t - 1)), jnp.float32)
+            loss, _ = _next_token_nll(h, _head(params, cfg), tok, cd)(mean)
+            stats = {}
+        else:
+            loss, stats = _exit_loss(h, gate, _head(params, cfg), tok, cfg, cd)
     if ahead is not None:
         with jax.named_scope("lm.mtp"):
             # the same head over targets one further on: position i scores token i + 2, the last two nothing
@@ -1062,16 +1178,19 @@ def _train_program(cfg: LMConfig, compute_type: str, lr: float, batch: int, inte
     """``(optimizer, step)``; ``step(params, opt_state, window, lo)`` trains on
     rows ``lo .. lo + batch`` of the device-resident window and returns the new
     state, the loss, every parameter's gradient norm before clipping (in
-    ``param_shapes`` order) and the step's statistics (``_loss``).
+    ``param_shapes`` order) and the step's statistics (``_loss``). A stage
+    that trains by block diffusion calls it with one more argument, ``noise =
+    (the stage's noise key, the job's step index)``: what the step's
+    corruption is drawn from (``_corrupt``).
     ``optimizer.init`` is jitted: the state a fit starts from is one device
     program's output however many leaves the tree has (eager, optax fills
     ``mu`` and ``nu`` a leaf at a time, the device idle between the fills)."""
     cd = jnp.dtype(compute_type)
     optimizer = _optimizer(lr)
 
-    def step(params, opt_state, window, lo):
+    def step(params, opt_state, window, lo, noise=None):
         tok = jax.lax.dynamic_slice_in_dim(window, lo, batch, axis=0)
-        (loss, stats), grads = jax.value_and_grad(_loss, has_aux=True)(params, tok, cfg, cd, interpret)
+        (loss, stats), grads = jax.value_and_grad(_loss, has_aux=True)(params, tok, cfg, cd, interpret, noise)
         with jax.named_scope("lm.opt"):
             norms = jnp.stack([jnp.sqrt(jnp.sum(g * g)) for g in _ordered(grads, cfg)])
             updates, opt_state = optimizer.update(grads, opt_state, params)
@@ -1101,7 +1220,12 @@ def _head_logit_matmuls(jaxpr, vocab: int, in_head: bool = False) -> int:
 _TRACED_COUNTS: dict = {}
 
 
-def _traced_counts(step, params, opt_state, window, cfg: LMConfig) -> dict:
+def _noise_key(seed: int):
+    """The stage's noise key: the stream of its seed that the corruption's draws come from (``_corrupt``)."""
+    return jax.random.fold_in(jax.random.key(seed), NOISE_STREAM)
+
+
+def _traced_counts(step, params, opt_state, window, cfg: LMConfig, *noise) -> dict:
     """What one ``step`` on ``window`` does, from the step as traced (``jit``
     keeps the trace: the first step's call finds it). ``conv_positions_kernel``:
     the positions x channels that the convolution's forward kernels cover, from
@@ -1117,7 +1241,8 @@ def _traced_counts(step, params, opt_state, window, cfg: LMConfig) -> dict:
     through them)."""
     key = (step, window.shape)
     if key not in _TRACED_COUNTS:
-        jaxpr = step.trace(params, opt_state, window, jax.ShapeDtypeStruct((), jnp.int32)).jaxpr.jaxpr  # runs nothing
+        jaxpr = step.trace(params, opt_state, window, jax.ShapeDtypeStruct((), jnp.int32),
+                           *noise).jaxpr.jaxpr  # runs nothing
         folds = fold_kernel_calls(jaxpr)
         _TRACED_COUNTS[key] = {
             "conv_positions_kernel": forward_positions(jaxpr),
@@ -1132,7 +1257,10 @@ def _traced_counts(step, params, opt_state, window, cfg: LMConfig) -> dict:
 def _log_likelihood_program(cfg: LMConfig, compute_type: str, interpret: bool):
     cd = jnp.dtype(compute_type)
 
-    def run(params, tok):
+    def run(params, tok, noise=None):
+        if cfg.block_length:  # one draw's estimate of the bound the fit minimises, a row: minus its weighted nll
+            _, weighted, _, _ = _diffusion_loss(params, tok, noise, cfg, cd, interpret)
+            return -jnp.sum(weighted, axis=1) * tok.shape[0]
         h, _, gate, _ = _hidden(params, tok, cfg, cd, interpret)  # no module: it is a training objective
         if gate is not None:
             h = h[-1]  # the exit threshold is 1: no token leaves before the last pass
@@ -1146,7 +1274,12 @@ def _fold_mode(t: int, cfg: LMConfig) -> bool:
     """Whether the fused fold runs interpreted (off the TPU), after checking
     that it (and a stack with Mamba-2 layers' scan, in chunks of ``cfg.chunk``)
     can serve this sequence at every layer's head sizes; there is no other
-    attention path."""
+    attention path. Under block diffusion the fold's sequence is the doubled
+    one, ``2 t`` positions in whole blocks."""
+    if cfg.block_length:
+        if t % cfg.block_length:
+            raise ValueError(f"sequence length {t} must be whole blocks of blockLength {cfg.block_length}")
+        t = 2 * t
     chunk = cfg.chunk
     if t % TQ_TILE or (chunk and t % chunk):
         raise ValueError(f"sequence length {t} must be a multiple of {TQ_TILE} (the fused fold's Q tile)"
@@ -1176,7 +1309,12 @@ def _token_matrix(df, col: str) -> np.ndarray:
 class DecoderLMModel(Model, _LMParams):
     """Serving side: per-row mean next-token log-likelihood through the same
     forward, by the main head alone: a multi-token-prediction module is a
-    training objective, saved and loaded with the tree and not run here.
+    training objective, saved and loaded with the tree and not run here. A
+    model trained by block diffusion (``blockKind`` ``sdar``) has no next-token
+    likelihood: its row's prediction is the one-draw estimate of the bound the
+    fit minimises, ``-(1 / T) sum over masked i of (1 / p) nll_i``, batch ``i``
+    of ``globalBatchSize`` rows corrupted from the stage's seed as step ``i``
+    of a fit's would be.
     ``params`` holds device arrays after a fit, host arrays after
     ``load``/``set_model_data``; either is placed once per call."""
 
@@ -1195,9 +1333,11 @@ class DecoderLMModel(Model, _LMParams):
         params = jax.tree_util.tree_map(jnp.asarray, self.params)
         batch = min(self.get_global_batch_size(), n)
         out = np.empty(n, np.float64)
-        for lo in range(0, n, batch):
+        noise_key = _noise_key(self.get_seed()) if cfg.block_length else None
+        for i, lo in enumerate(range(0, n, batch)):
             at = min(lo, n - batch)  # the tail re-reads the rows before it
-            out[at: at + batch] = np.asarray(program(params, jnp.asarray(tok[at: at + batch])))
+            noise = ((noise_key, jnp.int32(i)),) if cfg.block_length else ()  # batch i is corrupted as step i's is
+            out[at: at + batch] = np.asarray(program(params, jnp.asarray(tok[at: at + batch]), *noise))
         result = df.clone()
         result.add_column(self.get_prediction_col(), DataTypes.DOUBLE, out)
         return result
@@ -1235,7 +1375,7 @@ class DecoderLMModel(Model, _LMParams):
 
 
 class DecoderLM(Estimator, _LMParams):
-    """AdamW training of a decoder-only language model on token-id vectors: the objective is a weighted sum of per-token next-token cross-entropies, whose gradients the head forms in the one pass that holds its logits.
+    """AdamW training of a decoder-only language model on token-id vectors: the objective is a weighted sum of per-token cross-entropies (next-token prediction; blockKind 'sdar': block diffusion over the sequence and its masked copy, and transform then reports a one-draw estimate of the bound a row), whose gradients the head forms in the one pass that holds its logits.
 
     The objective's terms through the head differ only in the weights they
     hand it (the mean over the targets; ``mtpLossCoef`` over the positions a
@@ -1252,7 +1392,15 @@ class DecoderLM(Estimator, _LMParams):
     the stack's), ``trip_loss_history`` (``[steps, passes]``: each pass's own mean
     cross-entropy; a stack passed once has no columns) and ``mtp_loss_history``
     (the module's own mean cross-entropy; no module, empty; ``loss_history`` holds
-    the whole objective)."""
+    the whole objective) and ``targets_masked_history`` (the positions block
+    diffusion's objective scored; another objective, empty).
+
+    ``blockKind`` ``sdar`` trains by block diffusion instead: each step masks
+    its sequences' tokens with a probability drawn a sequence (from the seed
+    and the step's index), runs the stack over ``[x ; x~]`` and scores the
+    masked positions of ``x~`` on their own tokens with weight ``1 / p``
+    (``blockLength``, ``maskTokenId``); the sequence length is
+    then a multiple of 128 and of ``blockLength``."""
 
     def fit(self, *inputs) -> DecoderLMModel:
         (df,) = inputs
@@ -1263,7 +1411,8 @@ class DecoderLM(Estimator, _LMParams):
         with tracer.phase("train.tokens_put", CAT_INGEST, rows=df.num_rows) as phase:
             tok = _token_matrix(df, self.get_features_col())
             n, t = tok.shape
-            vocab = self.get(self.VOCAB_SIZE) or int(tok.max()) + 1
+            # left out, the vocabulary is the ids seen, and one more for the mask where the objective has one
+            vocab = self.get(self.VOCAB_SIZE) or int(tok.max()) + 1 + (self.lm_config().block_length > 0)
             if tok.max() >= vocab:
                 raise ValueError(f"token id {tok.max()} >= vocabSize {vocab}")
             window = jax.device_put(tok)
@@ -1273,6 +1422,8 @@ class DecoderLM(Estimator, _LMParams):
         interpret = _fold_mode(t, cfg)
         batch = min(self.get_global_batch_size(), n)
         steps = self.get_max_iter()
+        positions = 2 * t if cfg.block_length else t  # of a sequence through the stack: block diffusion doubles it
+        noise_key = _noise_key(self.get_seed()) if cfg.block_length else None
 
         with tracer.phase("train.init", CAT_COMPILE, params=num_params(cfg)) as phase:
             params = init_params(cfg, self.get_seed())
@@ -1289,23 +1440,27 @@ class DecoderLM(Estimator, _LMParams):
             specs = layers(cfg) + ((mtp_layer(cfg),) if cfg.mtp_depth else ())  # the module's layer folds too
             mixers = [spec.mixer for spec in specs]
             latents = [m for m in mixers if isinstance(m, LatentAttention)]
-            folds = [(m.heads, getattr(m, "window", 0)) for m in mixers
+            # each fold's heads and its mask: a window's keys and block diffusion's block (neither: causal)
+            folds = [(m.heads, getattr(m, "window", 0), getattr(m, "diffusion_block", 0)) for m in mixers
                      if isinstance(m, (Attention, LatentAttention, CCA))]
             scans = [m for m in mixers if isinstance(m, Mamba2)]
             # a step's chunks of the scan (chunks x heads x sequences, every Mamba-2 layer), and those of them the
             # scan's kernel pair walks: its grid's cells x the heads of a cell
             scan_chunks = sum(batch * m.heads * (t // m.chunk) for m in scans)
             scan_chunks_kernel = sum(scan_kernel_chunks(batch, t, m.heads, m.groups, m.chunk) for m in scans)
-            one_head = {w: np.asarray(fold_chunk_counts(t, t, 0, True, w or None)) for w in {w for _, w in folds}}
-            chunks = np.zeros((2, 2), np.int64)  # [full, windowed] x [visited, all]
-            for h, w in folds:
-                chunks[int(w > 0)] += cfg.loops * h * batch * one_head[w]
+            one_head = {(w, block): np.asarray(fold_chunk_counts(positions, positions, 0, True, w or None,
+                                                                 BlockDiffusion(t, block) if block else None))
+                        for _, w, block in folds}
+            chunks = np.zeros((3, 2), np.int64)  # [full, windowed, block diffusion] x [visited, all]
+            for h, w, block in folds:
+                chunks[2 if block else int(w > 0)] += cfg.loops * h * batch * one_head[w, block]
             opt_state = optimizer.init(params)  # one dispatch: fresh buffers, which the step donates
             state = jax.tree_util.tree_leaves(opt_state)
             # positions x channels of the Mamba-2 layers' convolutions in one step's forward, and those of them the
             # convolution's kernels cover: their calls' grids in the step as traced
             conv_positions = sum(batch * t * (m.heads * m.head_dim + 2 * m.groups * m.state) for m in scans)
-            traced = _traced_counts(step, params, opt_state, window, cfg)
+            traced = _traced_counts(step, params, opt_state, window, cfg,
+                                    *([(noise_key, jax.ShapeDtypeStruct((), jnp.int32))] if cfg.block_length else []))
             conv_positions_kernel = traced["conv_positions_kernel"]
             phase.set_metadata(built=int(_train_program.cache_info().misses > misses),
                                fold_chunks=int(chunks[:, 1].sum()), fold_chunks_visited=int(chunks[:, 0].sum()),
@@ -1314,9 +1469,14 @@ class DecoderLM(Estimator, _LMParams):
                                head_logit_matmuls=traced["head_logit_matmuls"])
             if folds:  # a stack that attends: how its step as traced calls the fold
                 phase.set_metadata(fold_one_block=traced["fold_one_block"], fold_row_stats=traced["fold_row_stats"])
-            if any(w for _, w in folds):
-                phase.set_metadata(layers_windowed=sum(w > 0 for _, w in folds),
-                                   layers_full=sum(w == 0 for _, w in folds),
+            if chunks[2, 1]:  # the doubled sequences' folds, and the positions a step takes through the stack
+                phase.set_metadata(layers_diffusion=sum(block > 0 for _, _, block in folds),
+                                   diffusion_block=cfg.block_length,
+                                   fold_bd_chunks=int(chunks[2, 1]), fold_bd_chunks_visited=int(chunks[2, 0]),
+                                   positions=batch * positions)
+            if chunks[1, 1]:
+                phase.set_metadata(layers_windowed=sum(w > 0 for _, w, _ in folds),
+                                   layers_full=sum(w == 0 for _, w, _ in folds),
                                    fold_win_chunks=int(chunks[1, 1]), fold_win_chunks_visited=int(chunks[1, 0]))
             if latents:  # beside the count, the float32 latents and rotary keys a step's layers rebuild k and v from
                 phase.set_metadata(layers_latent=len(latents), mtp_depth=cfg.mtp_depth,
@@ -1332,9 +1492,10 @@ class DecoderLM(Estimator, _LMParams):
         losses, leaf_norms, stats = [], [], []  # device values, fetched once after the loop
         with tracer.phase("train.dispatch", CAT_PRODUCTIVE, steps=steps):
             offset = 0
-            for _ in range(steps):
+            for i in range(steps):
                 lo = min(offset, n - batch)
-                params, opt_state, loss, norms, step_stats = step(params, opt_state, window, jnp.int32(lo))
+                noise = ((noise_key, jnp.int32(i)),) if cfg.block_length else ()
+                params, opt_state, loss, norms, step_stats = step(params, opt_state, window, jnp.int32(lo), *noise)
                 losses.append(loss)
                 leaf_norms.append(norms)
                 stats.append(step_stats)
@@ -1350,8 +1511,8 @@ class DecoderLM(Estimator, _LMParams):
                 rows_absent = int(loads.sum()) - rows_held
                 phase.set_metadata(
                     expert_rows_max=int(loads.max()),
-                    expert_rows_mean=batch * t * cfg.top_k // cfg.n_experts,
-                    dropped=int(steps * batch * t * cfg.top_k * loads.shape[1] - loads.sum()),
+                    expert_rows_mean=batch * positions * cfg.top_k // cfg.n_experts,
+                    dropped=int(steps * batch * positions * cfg.top_k * loads.shape[1] - loads.sum()),
                     rows_held=rows_held,
                     rows_absent=rows_absent,
                     held_rows_max=int(held.max()),
@@ -1359,7 +1520,7 @@ class DecoderLM(Estimator, _LMParams):
                 )
             carried = stats.get("carried")
             if carried is not None:  # [steps, layers with experts]: the rows each layer-step's windows took
-                routed = batch * t * cfg.top_k
+                routed = batch * positions * cfg.top_k
                 layer_steps_compact, rows_carried = int((carried < routed).sum()), int(carried.sum())
                 phase.set_metadata(
                     moe_layer_steps=carried.size,
@@ -1379,6 +1540,11 @@ class DecoderLM(Estimator, _LMParams):
             if ahead.size:  # [steps]: the module's summed cross-entropy over the positions it scored
                 mtp_targets = int(stats["mtp_targets"].sum())
                 phase.set_metadata(mtp_targets=mtp_targets, mtp_nll_sum=float(ahead.sum()))
+            scored = stats.get("targets_masked")
+            if scored is not None:  # [steps]: the positions block diffusion's objective scored
+                targets_masked = int(scored.sum())
+                phase.set_metadata(targets_masked=targets_masked, positions=steps * batch * positions,
+                                   noise_level_sum=float(stats["noise_level_sum"].sum()))
         with tracer.phase("train.readback", CAT_READBACK, bytes=4 * steps * (1 + len(param_shapes(cfg)))):
             self.loss_history = [float(x) for x in jax.device_get(losses)]
             self.param_grad_norm_history = np.asarray(jax.device_get(jnp.stack(leaf_norms)), np.float64)
@@ -1387,6 +1553,7 @@ class DecoderLM(Estimator, _LMParams):
         self.expert_rows_history = loads
         self.trip_loss_history = trips
         self.mtp_loss_history = [float(x) for x in ahead / stats["mtp_targets"]] if ahead.size else []
+        self.targets_masked_history = [] if scored is None else [int(x) for x in scored]
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_TOKENS, steps * batch * t)
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_CHUNKS, steps * int(chunks[:, 1].sum()))
         metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_CHUNKS_VISITED, steps * int(chunks[:, 0].sum()))
@@ -1394,6 +1561,12 @@ class DecoderLM(Estimator, _LMParams):
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_WIN_CHUNKS, steps * int(chunks[1, 1]))
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_WIN_CHUNKS_VISITED,
                             steps * int(chunks[1, 0]))
+        if chunks[2, 1]:
+            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_BD_CHUNKS, steps * int(chunks[2, 1]))
+            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_FOLD_BD_CHUNKS_VISITED,
+                            steps * int(chunks[2, 0]))
+        if scored is not None:
+            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_DIFFUSION_TARGETS, targets_masked)
         if scans:
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_SCAN_CHUNKS, steps * scan_chunks)
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_SCAN_KERNEL_CHUNKS, steps * scan_chunks_kernel)

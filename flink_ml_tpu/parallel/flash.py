@@ -63,6 +63,31 @@ pairs on either side. The kernels of a windowed fold are named
 ``flash_fold_win_*``; without a window every kernel is traced as it was before
 the fold knew of one.
 
+The block-diffusion mask: ``blocks`` (static: a ``BlockDiffusion`` of ``T``
+tokens in blocks of ``L``; the one-block form alone takes it) is the third
+mask form, in the causal one's place. The ``2 T`` positions are a sequence and
+its noised copy behind it, ``[x ; x~]``, and with ``b(i) = i // L`` of a
+position in its own half a clean query keeps the clean keys of ``b(j) <=
+b(i)``, a noised query the clean keys of ``b(j) < b(i)`` and the noised keys of
+``b(j) = b(i)``, and no clean query a noised key: block-causal on the clean
+half, strictly block-causal from the noised half onto it, block-diagonal inside
+the noised half. The clean half leads so that everything kept lies under the
+(block) diagonal of the ``2 T x 2 T`` square, and a cell walks TWO key ranges
+(``_bd_chunks``): the clean chunks that hold a key its rows keep - for a tile
+of clean rows the causal walk's own chunks, for a tile of noised rows the
+chunks before its first block - whole where every row keeps them and masked
+where not, and then the band, the one or two chunks that hold the tile's own
+noised positions, masked. At ``T`` 4,096 and the tiles below the three kernels
+visit 37.5% of their (tile, chunk) pairs (``fold_chunk_counts``; the mask
+keeps ``T^2 + T L`` of the ``4 T^2`` entries, 25.02%) where a causal walk of
+the same 8,192 positions visits 56.25%. A crossed chunk's mask is two compares
+of block ids (``_bd_keep``: shifts, ``L`` a power of two) worked out on one
+column of rows and one row of keys. The dkv kernel's grid skips the hidden
+pairs and names, for each, a query tile it has already fetched
+(``_bd_q_tile``). The kernels are named ``flash_fold_bd_*``; the causal and the
+windowed forms trace to what they traced to before the fold knew of it. The
+ring's entry (``fused_fold``) does not take it; ``reference_fold`` does.
+
 The one-block form: ``fused_attention`` is the same fold where the ring has
 ONE step: a whole sequence on itself (``q_pos0 = k_pos0 = 0``, ``Tq = Tk``,
 nothing carried in). Its forward hands back the normalised ``o = acc / l`` in
@@ -94,6 +119,7 @@ not tile or the devices have no Mosaic backend.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -101,6 +127,7 @@ import jax.numpy as jnp
 __all__ = [
     "fused_fold",
     "fused_attention",
+    "BlockDiffusion",
     "flash_available",
     "flash_train_available",
     "reference_fold",
@@ -306,12 +333,73 @@ def _window_chunks(q_first, n_rows: int, k_pos0, chunk: int, n_chunks: int, wind
     return n_lo, lo_end, _least(_most(n_full, lo_end), n_vis)
 
 
-def _mask_chunk(s, q_first, k_first, causal: bool, n_valid=None, window=None, q_axis: int = 0):
+class BlockDiffusion(NamedTuple):
+    """The block-diffusion mask of ``fused_attention`` ("The block-diffusion
+    mask" in the module docstring) over ``2 x tokens`` positions: the clean
+    sequence first, its noised copy behind it, both in blocks of ``block``
+    positions (a power of two that divides ``tokens`` and every query tile)."""
+    tokens: int
+    block: int
+
+
+def _bd_keep(q_pos, k_pos, blocks):
+    """Which (query, key) entries the block-diffusion mask keeps, from their
+    positions in the doubled sequence (int32 arrays that broadcast against each
+    other): with ``b`` a position's block in its own half, a clean key is kept
+    by a clean query of ``b(key) <= b(query)`` and by a noised query of
+    ``b(key) < b(query)``; a noised key by the noised queries of its own block
+    and by no clean query (whose block id lies under every noised key's)."""
+    tokens, block = blocks
+    shift = block.bit_length() - 1
+    half = tokens >> shift  # the first block of the noised half
+    qb, kb = jnp.right_shift(q_pos, shift), jnp.right_shift(k_pos, shift)
+    newest_clean = jnp.where(qb >= half, qb - (half + 1), qb)  # the last clean block a query keeps: under ``half``
+    return (kb <= newest_clean) | (kb == qb)  # a clean key up to it, or (a noised key) the query's own block
+
+
+def _pick(cond, a, b):
+    return (a if cond else b) if isinstance(cond, bool) else jnp.where(cond, a, b)
+
+
+def _bd_chunks(q_first, n_rows: int, chunk: int, n_chunks: int, blocks):
+    """``(n_full, n_vis, b_lo, b_hi)`` for query rows ``q_first .. q_first +
+    n_rows - 1`` of the doubled sequence (``q_first`` and ``n_rows`` whole
+    blocks) against ``n_chunks`` key chunks from key 0 under the
+    block-diffusion mask. Chunks ``[0, n_full)`` lie among the clean keys every
+    row keeps and need no mask; ``[n_full, n_vis)`` hold a clean key some row
+    keeps and not all; ``[b_lo, b_hi)``, past them, hold the rows' own noised
+    positions (the band: each noised row keeps its own block there); the rest
+    hold nothing kept. A tile of clean rows walks what the causal mask would
+    give it; a tile of noised rows the clean chunks before its first block and
+    the one or two chunks of its band. Works on ints (``fold_chunk_counts``)
+    and on the kernels' scalars."""
+    tokens, block = blocks
+    q_end = q_first + n_rows
+    clean, noised = q_first < tokens, q_end > tokens  # which halves the rows lie in: one, or both
+    # the clean keys EVERY row keeps end where the first row's do (a clean row keeps its own block, a noised row
+    # the blocks before its own), and those SOME row keeps where the last row's do
+    full_end = _pick(clean, _pick(noised, 0, q_first + block), q_first - tokens)
+    vis_end = _most(_pick(clean, _least(q_end, tokens), 0), _pick(noised, q_end - tokens - block, 0))
+    n_full = _chunks_upto(full_end, chunk, n_chunks)  # last key < full_end
+    n_vis = _chunks_upto(vis_end + chunk - 1, chunk, n_chunks)  # first key < vis_end
+    b_lo = _most(_chunks_upto(_most(q_first, tokens), chunk, n_chunks), n_vis)
+    return n_full, n_vis, b_lo, _most(_chunks_upto(q_end + chunk - 1, chunk, n_chunks), b_lo)
+
+
+def _mask_chunk(s, q_first, k_first, causal: bool, n_valid=None, window=None, q_axis: int = 0, blocks=None):
     """``s [rows, keys]`` with ``-inf`` where the causal mask (when ``causal``),
     the sliding ``window`` under it (when given: a query keeps the ``window``
     keys ending at itself) or ``n_valid`` (when given; ``causal`` or it is)
     drops the entry; ``q_first``/``k_first`` are the global positions of row 0
-    and key 0. ``q_axis=1``: ``s`` is ``[keys, rows]``, the scores transposed."""
+    and key 0. ``q_axis=1``: ``s`` is ``[keys, rows]``, the scores transposed.
+    ``blocks``: the block-diffusion mask instead (``_bd_keep``), its block ids
+    worked out on one column of rows and one row of keys."""
+    if blocks is not None:
+        q_shape = tuple(n if axis == q_axis else 1 for axis, n in enumerate(s.shape))
+        k_shape = tuple(n if axis != q_axis else 1 for axis, n in enumerate(s.shape))
+        keep = _bd_keep(q_first + jax.lax.broadcasted_iota(jnp.int32, q_shape, q_axis),
+                        k_first + jax.lax.broadcasted_iota(jnp.int32, k_shape, 1 - q_axis), blocks)
+        return jnp.where(keep, s, -jnp.inf)
     q_pos = q_first + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis) if causal else None
     k_pos = k_first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
     keep = q_pos >= k_pos if causal else k_pos < n_valid
@@ -338,24 +426,29 @@ def _key_rows(ref, c, kc: int):
     return ref[0, pl.ds(pl.multiple_of(c * kc, kc), kc), :]
 
 
-def _walk_chunks(walk, carry, n_full, n_vis, window=None, n_lo=0, lo_end=0):
+def _walk_chunks(walk, carry, n_full, n_vis, window=None, n_lo=0, lo_end=0, band=None):
     """One walk of a causal cell over its visible key chunks: ``walk(masked)``
     is a ``fori_loop`` body ``(chunk, carry) -> carry``, taken with the mask on
-    the chunks an edge crosses and without it on those kept whole."""
+    the chunks an edge crosses and without it on those kept whole. Under the
+    block-diffusion mask a second range follows, ``band``: the chunks that hold
+    the cell's own noised rows (``_bd_chunks``), masked."""
     if window is not None:  # the window's edge first; "diagonal" there means masked, by both edges
         carry = jax.lax.fori_loop(n_lo, lo_end, walk(True), carry)
         n_lo = lo_end
     carry = jax.lax.fori_loop(n_lo, n_full, walk(False), carry)
-    return jax.lax.fori_loop(n_full, n_vis, walk(True), carry)
+    carry = jax.lax.fori_loop(n_full, n_vis, walk(True), carry)
+    return carry if band is None else jax.lax.fori_loop(*band, walk(True), carry)
 
 
 def _park_scores(qt, k_ref, s_scr, q_first, k_pos0, n_valid, scale, kc: int, n_full, n_vis, window=None,
-                 n_lo=0, lo_end=0):
+                 n_lo=0, lo_end=0, blocks=None, band=None):
     """The first walk of a causal forward or dq cell: the scores of ``qt
     [rows, D]`` on key chunks ``[0, n_vis)``, masked on ``[n_full, n_vis)``,
     parked in ``s_scr [chunks, rows, kc]``. Under a ``window`` the walk starts
     at chunk ``n_lo`` and ``[n_lo, lo_end)`` are masked too
-    (``_window_chunks``). Returns their running row max, still 128 lanes wide."""
+    (``_window_chunks``); under the block-diffusion mask (``blocks``) the chunks
+    of ``band`` follow, masked. Returns their running row max, still 128 lanes
+    wide."""
 
     def walk(on_diagonal):
         def body(c, mx):
@@ -364,17 +457,17 @@ def _park_scores(qt, k_ref, s_scr, q_first, k_pos0, n_valid, scale, kc: int, n_f
                 preferred_element_type=jnp.float32,
             ) * scale  # [rows, kc]
             if on_diagonal:
-                s = _mask_chunk(s, q_first, k_pos0 + c * kc, True, n_valid, window)
+                s = _mask_chunk(s, q_first, k_pos0 + c * kc, True, n_valid, window, blocks=blocks)
             s_scr[c] = s
             return jnp.maximum(mx, _fold_lanes(s, jnp.maximum))
 
         return body
 
     return _walk_chunks(walk, jnp.full((qt.shape[0], _LANES), -jnp.inf, jnp.float32), n_full, n_vis, window,
-                        n_lo, lo_end)
+                        n_lo, lo_end, band)
 
 
-def fold_chunk_counts(Tq: int, Tk: int, q_off: int, causal: bool, window=None):
+def fold_chunk_counts(Tq: int, Tk: int, q_off: int, causal: bool, window=None, blocks=None):
     """``(visited, total)`` chunk pairs of ONE fold of ``Tq`` queries on ``Tk``
     keys, one head, the three kernels together at the tiles they use: the
     forward's and the dq kernel's (query tile, key chunk) pairs and the dkv
@@ -382,11 +475,16 @@ def fold_chunk_counts(Tq: int, Tk: int, q_off: int, causal: bool, window=None):
     that takes the block in one piece visits every pair: all three without
     ``causal``, the forward and the dq kernel on a block of one chunk. Under a
     sliding ``window`` (``causal`` with it) a walk also skips the chunks below
-    the window's edge."""
+    the window's edge; under the block-diffusion mask (``blocks``, of a doubled
+    sequence on itself: ``Tq = Tk = 2 x blocks.tokens``, ``q_off`` 0) a tile
+    visits what ``_bd_chunks`` gives it."""
     tq_fwd, tq_dq, tq_dkv, tk_dkv, kc = _fold_tiles(Tq, Tk, causal)
     visited = total = 0
 
     def seen(q_first, rows, keys, n_keys):
+        if blocks is not None:
+            _, n_vis, b_lo, b_hi = _bd_chunks(q_first, rows, keys, n_keys, blocks)
+            return n_vis + b_hi - b_lo
         n_full, n_vis = _visible_chunks(q_first, rows, 0, keys, n_keys)
         if window is None:
             return n_vis
@@ -399,10 +497,12 @@ def fold_chunk_counts(Tq: int, Tk: int, q_off: int, causal: bool, window=None):
     return visited, total
 
 
-def _kept(Tq: int, Tk: int, q_pos0, k_pos0, causal, n_valid, window):
+def _kept(Tq: int, Tk: int, q_pos0, k_pos0, causal, n_valid, window, blocks=None):
     """The references' mask ``[Tq, Tk]``: which (query, key) entries are kept."""
     q_pos = q_pos0 + jnp.arange(Tq)
     k_pos = k_pos0 + jnp.arange(Tk)
+    if blocks is not None:  # the block-diffusion mask in the causal one's place: it keeps a query's whole block
+        return _bd_keep(q_pos[:, None], k_pos[None, :], blocks)
     keep = jnp.ones((Tq, Tk), bool)
     if causal:
         keep &= q_pos[:, None] >= k_pos[None, :]
@@ -413,7 +513,7 @@ def _kept(Tq: int, Tk: int, q_pos0, k_pos0, causal, n_valid, window):
     return keep
 
 
-def reference_fold(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale, window=None):
+def reference_fold(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale, window=None, blocks=None):
     """The jnp fold in [B, H, ...] layout (ring.py numerics) — the source of
     truth the kernel is tested against and the backward recomputes through.
 
@@ -422,12 +522,14 @@ def reference_fold(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
     [B, H, Tq]; ``acc`` [B, H, Tq, D_v]. ``q_pos0``/``k_pos0`` are the global positions of
     query/key 0 (traced scalars); ``n_valid`` masks keys at global positions
     >= it (None = unmasked); ``window`` (with ``causal``) keeps of each
-    query's keys the ``window`` that end at it.
+    query's keys the ``window`` that end at it; ``blocks`` (a
+    ``BlockDiffusion``, with ``causal``, of a doubled sequence on itself from
+    position 0) puts the block-diffusion mask in the causal one's place.
     """
     Tq, Tk = q.shape[2], kb.shape[2]
     s = jnp.einsum("bhqd,bhkd->bhqk", q, kb) * scale
     if causal or n_valid is not None:
-        mask = _kept(Tq, Tk, q_pos0, k_pos0, causal, n_valid, window)
+        mask = _kept(Tq, Tk, q_pos0, k_pos0, causal, n_valid, window, blocks)
         s = jnp.where(mask[None, None, :, :], s, -jnp.inf)
     block_max = jnp.max(s, axis=-1)
     new_m = jnp.maximum(m, block_max)
@@ -440,10 +542,12 @@ def reference_fold(q, kb, vb, m, l, acc, q_pos0, k_pos0, causal, n_valid, scale,
     return new_m, new_l, new_acc
 
 
-def _kernel_name(part: str, window) -> str:
-    """A windowed fold's kernels carry names of their own, so that a device
-    trace tells a stack's windowed layers from its full ones."""
-    return f"flash_fold_{part}" if window is None else f"flash_fold_win_{part}"
+def _kernel_name(part: str, window, blocks=None) -> str:
+    """A windowed fold's kernels, and those of a fold under the block-diffusion
+    mask, carry names of their own, so that a device trace tells a stack's
+    windowed layers from its full ones and either from a doubled sequence's."""
+    mask = "bd_" if blocks is not None else "win_" if window is not None else ""
+    return f"flash_fold_{mask}{part}"
 
 
 #: The float32 row statistics (``[B x H, T, 1]`` columns or ``[B x H, 1, T]`` rows) among a kernel's operands and
@@ -454,7 +558,7 @@ ONE_BLOCK_ROW_STATS = {"fwd": 1, "bwd_dq": 2, "bwd_dkv": 2}
 
 def fold_kernel_calls(jaxpr) -> list:
     """``[(part, row statistics)]``, one entry a call of a fold kernel
-    (``part`` one of ``fwd``, ``bwd_dq``, ``bwd_dkv``; windowed or not) among
+    (``part`` one of ``fwd``, ``bwd_dq``, ``bwd_dkv``; whatever its mask) among
     ``jaxpr``'s equations and those of every jaxpr inside them (a loop's body,
     what a ``checkpoint`` recomputes), with the float32 row statistics among
     the call's operands and results: what a step as traced hands its fold."""
@@ -463,7 +567,7 @@ def fold_kernel_calls(jaxpr) -> list:
         name = eqn.params.get("name") if eqn.primitive.name == "pallas_call" else None
         if name is not None and name.startswith("flash_fold_"):
             avals = [v.aval for v in (*eqn.invars, *eqn.outvars)]
-            found.append((name.removeprefix("flash_fold_").removeprefix("win_"),
+            found.append((name.removeprefix("flash_fold_").removeprefix("win_").removeprefix("bd_"),
                           sum(a.dtype == jnp.float32 and a.ndim == 3 and 1 in a.shape[1:] for a in avals)))
         for sub in jax.core.jaxprs_in_params(eqn.params):
             found += fold_kernel_calls(sub)
@@ -1029,16 +1133,39 @@ def _attention_tiles(T: int):
     return tq, tq_dkv, tk_dkv, kc
 
 
-def _cell_chunks(q_first, n_rows: int, chunk: int, n_chunks: int, window):
-    """``(n_lo, lo_end, n_full, n_vis)`` of a cell whose rows start at
+def _cell_chunks(q_first, n_rows: int, chunk: int, n_chunks: int, window, blocks=None):
+    """``(n_lo, lo_end, n_full, n_vis, band)`` of a cell whose rows start at
     ``q_first`` on keys from 0: chunks ``[n_lo, lo_end)`` and ``[n_full,
     n_vis)`` are crossed by the window's edge and the diagonal, ``[lo_end,
     n_full)`` kept whole, the rest hidden (``_visible_chunks``,
-    ``_window_chunks``); without a ``window`` nothing lies below it."""
+    ``_window_chunks``); without a ``window`` nothing lies below it. ``band``
+    is None but under the block-diffusion mask, whose counts are its own
+    (``_bd_chunks``): the chunks ``(b_lo, b_hi)`` of the rows' own noised
+    positions, crossed too."""
+    if blocks is not None:
+        n_full, n_vis, b_lo, b_hi = _bd_chunks(q_first, n_rows, chunk, n_chunks, blocks)
+        return 0, 0, n_full, n_vis, (b_lo, b_hi)
     n_full, n_vis = _visible_chunks(q_first, n_rows, 0, chunk, n_chunks)
     if window is None:
-        return 0, 0, n_full, n_vis
-    return (*_window_chunks(q_first, n_rows, 0, chunk, n_chunks, window, n_full, n_vis), n_vis)
+        return 0, 0, n_full, n_vis, None
+    return (*_window_chunks(q_first, n_rows, 0, chunk, n_chunks, window, n_full, n_vis), n_vis, None)
+
+
+def _bd_q_tile(jk, jq, tq: int, tk: int, blocks):
+    """The query tile the dkv kernel's grid step ``(jk, jq)`` names under the
+    block-diffusion mask: ``jq`` itself where the tile sees key tile ``jk``,
+    else the tile last seen before it (or the first to come), so that nothing is
+    fetched for a hidden pair. A tile of clean keys is seen by the clean
+    tiles from the diagonal on and, past a gap, by the noised tiles from its
+    own place in their half on; a tile of noised keys by the one tile of its
+    own rows. Where the tiles do not divide the halves evenly the map is the
+    identity: a hidden pair is fetched and not computed."""
+    tokens, block = blocks
+    if tq != tk or tokens % tq:
+        return jq
+    half = tokens // tq  # the first noised tile
+    again = half + jk + (block == tq)  # the first noised tile that keeps a key of clean tile jk
+    return jnp.where(jk >= half, jk, jnp.where(jq < again, jnp.clip(jq, jk, half - 1), jq))
 
 
 def _attention_specs(T: int, tq: int, kv_of):
@@ -1057,7 +1184,21 @@ def _attention_specs(T: int, tq: int, kv_of):
     return rows, keys, pl.BlockSpec((1, 1, tq), lambda i, j: (i, 0, j), memory_space=pltpu.VMEM)
 
 
-def _attention_pallas(q, k, v, scale, window, interpret):
+def _check_blocks(T: int, blocks, window, tiles) -> None:
+    """Refuse a block-diffusion mask the kernels' counts are not written for."""
+    if blocks is None:
+        return
+    tokens, block = blocks
+    if window is not None:
+        raise ValueError("the block-diffusion mask takes no sliding window")
+    if block <= 0 or block & (block - 1) or tokens % block or T != 2 * tokens:
+        raise ValueError(f"the block-diffusion mask lies over twice {tokens} tokens in blocks of a power of two that "
+                         f"divides them; got {T} positions in blocks of {block}")
+    if any(tile % block for tile in tiles):
+        raise ValueError(f"blocks of {block} do not divide the fold's query tiles {tiles}")
+
+
+def _attention_pallas(q, k, v, scale, window, interpret, blocks=None):
     """``(o [B, H, T, D_v] float32, lse [B x H, 1, T])`` of the one-block form."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -1070,21 +1211,22 @@ def _attention_pallas(q, k, v, scale, window, interpret):
     Hkv, Dv = k.shape[1], v.shape[3]
     BH = B * H
     kv_of = _kv_block_of(H, Hkv)
-    tq, _, _, kc = _attention_tiles(T)
+    tq, tq_dkv, _, kc = _attention_tiles(T)
+    _check_blocks(T, blocks, window, (tq, tq_dkv))
     n_chunks = T // kc
 
     def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *s_scr):
         q_first = pl.program_id(1) * tq
         if n_chunks == 1:  # the block in one piece
-            s = _mask_chunk(_nt_dot(q_ref[0], k_ref[0]) * scale, q_first, 0, True, None, window)
+            s = _mask_chunk(_nt_dot(q_ref[0], k_ref[0]) * scale, q_first, 0, True, None, window, blocks=blocks)
             m = jnp.max(s, axis=1, keepdims=True)
             p = jnp.exp(s - m)  # every row keeps itself: m is finite and a masked score's exp(-inf) is 0
             l = jnp.sum(p, axis=1, keepdims=True)
             o_ref[0] = jnp.dot(p.astype(v_ref.dtype), v_ref[0], preferred_element_type=jnp.float32) / l
         else:  # ``walking_kernel`` with no carried state to read or correct
-            n_lo, lo_end, n_full, n_vis = _cell_chunks(q_first, tq, kc, n_chunks, window)
+            n_lo, lo_end, n_full, n_vis, band = _cell_chunks(q_first, tq, kc, n_chunks, window, blocks)
             mx = _park_scores(q_ref[0], k_ref, s_scr[0], q_first, 0, None, scale, kc, n_full, n_vis,
-                              window, n_lo, lo_end)
+                              window, n_lo, lo_end, blocks, band)
             m = jnp.max(mx, axis=1, keepdims=True)
             o_ref[0] = jnp.zeros_like(o_ref[0])
 
@@ -1095,6 +1237,8 @@ def _attention_pallas(q, k, v, scale, window, interpret):
                 return l_lanes + _fold_lanes(p, jnp.add)
 
             l_lanes = jax.lax.fori_loop(n_lo, n_vis, accumulate, jnp.zeros((tq, _LANES), jnp.float32))
+            if band is not None:
+                l_lanes = jax.lax.fori_loop(*band, accumulate, l_lanes)
             l = jnp.sum(l_lanes, axis=1, keepdims=True)
             o_ref[0] = o_ref[0] / l
         lse_ref[0] = _col_to_row(m + jnp.log(l))
@@ -1111,12 +1255,12 @@ def _attention_pallas(q, k, v, scale, window, interpret):
                    jax.ShapeDtypeStruct((BH, 1, T), jnp.float32, vma=vma)],
         interpret=interpret,
         compiler_params=_compiler_params(),
-        name=_kernel_name("fwd", window),
+        name=_kernel_name("fwd", window, blocks),
     )(q.reshape(BH, T, D), k.reshape(B * Hkv, T, D), v.reshape(B * Hkv, T, Dv))
     return o.reshape(B, H, T, Dv), lse
 
 
-def _attention_bwd_pallas(q, k, v, o, lse, do, scale, window, interpret):
+def _attention_bwd_pallas(q, k, v, o, lse, do, scale, window, interpret, blocks=None):
     """``(dq, dk, dv)`` of the one-block form, each in its operand's type."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -1144,7 +1288,7 @@ def _attention_bwd_pallas(q, k, v, o, lse, do, scale, window, interpret):
         def chunk(kt, vt, k_first, masked):
             s = _nt_dot(qt, kt) * scale  # [rows, kc]
             if masked:
-                s = _mask_chunk(s, q_first, k_first, True, None, window)
+                s = _mask_chunk(s, q_first, k_first, True, None, window, blocks=blocks)
             p = jnp.exp(s - lse)  # lse is finite: a masked score's exp(-inf) is exactly 0
             ds = p * (_nt_dot(do_t, vt) - delta)
             dq_scr[...] += jnp.dot(ds.astype(kt.dtype), kt, preferred_element_type=jnp.float32)
@@ -1159,8 +1303,8 @@ def _attention_bwd_pallas(q, k, v, o, lse, do, scale, window, interpret):
         if n_chunks == 1:  # the block in one piece
             chunk(k_ref[0], v_ref[0], 0, True)
         else:
-            n_lo, lo_end, n_full, n_vis = _cell_chunks(q_first, tq, kc, n_chunks, window)
-            _walk_chunks(walk, 0, n_full, n_vis, window, n_lo, lo_end)
+            n_lo, lo_end, n_full, n_vis, band = _cell_chunks(q_first, tq, kc, n_chunks, window, blocks)
+            _walk_chunks(walk, 0, n_full, n_vis, window, n_lo, lo_end, band)
         dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
         delta_ref[0] = _col_to_row(delta)
 
@@ -1179,7 +1323,7 @@ def _attention_bwd_pallas(q, k, v, o, lse, do, scale, window, interpret):
         def accumulate(mask):
             s = _nt_dot(k_ref[0], q_ref[0]) * scale  # [TK, TQ]
             if mask:
-                s = _mask_chunk(s, q_first, k_first, True, None, window, q_axis=1)
+                s = _mask_chunk(s, q_first, k_first, True, None, window, q_axis=1, blocks=blocks)
             p = jnp.exp(s - lse_ref[0])
             do_t = do_ref[0].astype(v_ref.dtype)
             ds = p * (_nt_dot(v_ref[0], do_t) - delta_ref[0])
@@ -1191,8 +1335,11 @@ def _attention_bwd_pallas(q, k, v, o, lse, do, scale, window, interpret):
             dk_scr[...] = jnp.zeros_like(dk_scr)
             dv_scr[...] = jnp.zeros_like(dv_scr)
 
-        n_lo, lo_end, n_full, n_vis = _cell_chunks(q_first, tq_dkv, tk_dkv, n_k_dkv, window)
-        if window is None:
+        n_lo, lo_end, n_full, n_vis, band = _cell_chunks(q_first, tq_dkv, tk_dkv, n_k_dkv, window, blocks)
+        if band is not None:  # the clean keys some row keeps and not all, and the rows' own band
+            pl.when(jk < n_full)(lambda: accumulate(False))
+            pl.when(((jk >= n_full) & (jk < n_vis)) | ((jk >= band[0]) & (jk < band[1])))(lambda: accumulate(True))
+        elif window is None:
             pl.when(jk < n_full)(lambda: accumulate(False))
             pl.when((jk >= n_full) & (jk < n_vis))(lambda: accumulate(True))
         else:  # below the window nothing; the pairs either edge crosses masked, by both
@@ -1218,13 +1365,15 @@ def _attention_bwd_pallas(q, k, v, o, lse, do, scale, window, interpret):
                    jax.ShapeDtypeStruct((BH, 1, T), jnp.float32, vma=vma)],
         interpret=interpret,
         compiler_params=_compiler_params(),
-        name=_kernel_name("bwd_dq", window),
+        name=_kernel_name("bwd_dq", window, blocks),
     )(q3, k3, v3, o.reshape(BH, T, Dv), do3, lse)
 
     def q_tile(i, jk, jq):
         head = i
         if group > 1:  # the group's query heads one after another
             head, jq = i * group + jq // n_q_dkv, jq % n_q_dkv
+        if blocks is not None:
+            return head, _bd_q_tile(jk, jq, tq_dkv, tk_dkv, blocks)
         # q tiles before the first that sees this k tile are hidden: they name that first tile's block, so
         # nothing is fetched for them; under a window so do those past the last that sees it
         jq = jnp.maximum(jq, _chunks_upto(jk * tk_dkv, tq_dkv, n_q_dkv - 1))
@@ -1253,30 +1402,34 @@ def _attention_bwd_pallas(q, k, v, o, lse, do, scale, window, interpret):
                    jax.ShapeDtypeStruct((BHkv, T, Dv), v.dtype, vma=vma)],
         interpret=interpret,
         compiler_params=_compiler_params(),
-        name=_kernel_name("bwd_dkv", window),
+        name=_kernel_name("bwd_dkv", window, blocks),
     )(k3, v3, q3, do3, lse, delta)
     return dq.reshape(B, H, T, D), dk.reshape(B, Hkv, T, D), dv.reshape(B, Hkv, T, Dv)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def fused_attention(q, k, v, scale, window=None, interpret=False):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def fused_attention(q, k, v, scale, window=None, interpret=False, blocks=None):
     """Causal softmax attention of a whole sequence on itself, fused: ``q [B,
     H, T, D]`` on ``k [B, H_kv, T, D]`` and ``v [B, H_kv, T, D_v]`` ``-> o [B,
     H, T, D_v]`` in float32 (grouped queries, a value head of its own size and
     a static sliding ``window`` as ``fused_fold`` takes them). The fold of a
     ring of one: no ``m``, ``l``, ``acc``, positions or ``n_valid``; a caller
     that carries state across blocks is the ring and keeps ``fused_fold``.
-    ``scale``, ``window`` and ``interpret`` are static."""
-    return _attention_pallas(q, k, v, scale, window, interpret)[0]
+    ``blocks`` (a ``BlockDiffusion``; no ``window`` with it) puts the
+    block-diffusion mask in the causal one's place: the ``T`` positions are a
+    clean sequence and its noised copy behind it ("The block-diffusion mask" in
+    the module docstring). ``scale``, ``window``, ``interpret`` and ``blocks``
+    are static."""
+    return _attention_pallas(q, k, v, scale, window, interpret, blocks)[0]
 
 
-def _fused_attention_fwd(q, k, v, scale, window, interpret):
-    o, lse = _attention_pallas(q, k, v, scale, window, interpret)
+def _fused_attention_fwd(q, k, v, scale, window, interpret, blocks):
+    o, lse = _attention_pallas(q, k, v, scale, window, interpret, blocks)
     return o, (q, k, v, o, lse)
 
 
-def _fused_attention_bwd(scale, window, interpret, res, do):
-    return _attention_bwd_pallas(*res, do, scale, window, interpret)
+def _fused_attention_bwd(scale, window, interpret, blocks, res, do):
+    return _attention_bwd_pallas(*res, do, scale, window, interpret, blocks)
 
 
 fused_attention.defvjp(_fused_attention_fwd, _fused_attention_bwd)

@@ -6,7 +6,11 @@ vectors). One routing path, and no token is ever dropped:
 
 - the router runs in float32 whatever the compute type: logits ``x @ router``,
   a softmax over ALL experts, the ``k`` largest probabilities chosen and used
-  as they are (not renormalised - OLMoE's ``norm_topk_prob`` false);
+  as they are (not renormalised - OLMoE's ``norm_topk_prob`` false) or, told
+  so (``renormalise``: Qwen3-MoE's and SDAR's ``norm_topk_prob`` true),
+  divided by their sum over ALL ``k`` chosen, held here or not: a row whose
+  chosen experts are mostly absent keeps small gates, and the router's
+  gradient flows through numerator and denominator;
 - the ``tokens x k`` (token, expert) rows are sorted by expert (a stable
   argsort of the chosen expert ids), so each expert's rows are one contiguous
   group whose size is whatever the router made it - there is no capacity;
@@ -408,7 +412,7 @@ _in_windows.defvjp(_in_windows_fwd, _in_windows_bwd)
 
 
 def moe_dropless(x, router, w_gate, w_up, w_down, k: int, compute_dtype=jnp.float32,
-                 first_held: int = 0, routed_scale: float = 0.0, select_bias=None):
+                 first_held: int = 0, routed_scale: float = 0.0, select_bias=None, renormalise: bool = False):
     """Top-``k`` experts for tokens ``x [t, d]``: SwiGLU, or with ``w_gate``
     None ``down(relu(up(x))^2)``.
 
@@ -418,7 +422,8 @@ def moe_dropless(x, router, w_gate, w_up, w_down, k: int, compute_dtype=jnp.floa
     the ``E`` the router chooses among. ``compute_dtype`` is the grouped
     matmuls' input type (accumulation is f32); the router is f32 regardless.
     ``routed_scale`` > 0 asks for sigmoid gates, chosen with ``select_bias``,
-    renormalised and scaled (``route_sigmoid_top_k``).
+    renormalised and scaled (``route_sigmoid_top_k``); ``renormalise`` for
+    softmax gates divided by their sum over all ``k`` chosen, held here or not.
     Returns ``(y [t, d] f32, stats)`` with ``stats = {"f": [E], "P": [E],
     "rows": [E] int32}`` as the module docstring defines them: ``y`` is the
     part of the layer's result that the held experts give. Where the rows go
@@ -431,6 +436,9 @@ def moe_dropless(x, router, w_gate, w_up, w_down, k: int, compute_dtype=jnp.floa
         p, top_p, top_e = route_sigmoid_top_k(x, router, k, routed_scale, select_bias)
     else:
         p, top_p, top_e = route_top_k(x, router, k)
+        if renormalise:
+            with jax.named_scope("route"):
+                top_p = top_p / jnp.sum(top_p, axis=1, keepdims=True)
     n_experts = p.shape[1]
     covered = n_held == n_experts
     if not 0 <= first_held <= n_experts - n_held:

@@ -180,6 +180,14 @@ def _traced_step(cfg, compute_type="float32"):
     return step, params, jax.eval_shape(optimizer.init, params), jax.ShapeDtypeStruct((4, 256), jnp.int32)
 
 
+def _noise(cfg):
+    """What a stage that trains by block diffusion hands its step beside the others' arguments: ``((the noise key,
+    the step's index),)``; another objective, nothing."""
+    if not cfg.block_length:
+        return ()
+    return ((jax.eval_shape(lambda: jax.random.key(0)), jax.ShapeDtypeStruct((), jnp.int32)),)
+
+
 @pytest.mark.parametrize("compute_type", ["float32", "bfloat16"])
 @pytest.mark.parametrize("kind", sorted(test_lm_scopes.KINDS))
 def test_one_logits_matmul_a_chunk_and_head_call_in_every_kinds_step(kind, compute_type):
@@ -188,8 +196,8 @@ def test_one_logits_matmul_a_chunk_and_head_call_in_every_kinds_step(kind, compu
     (``joyai``: two calls, two matmuls)."""
     cfg = test_lm_scopes.KINDS[kind][0]
     step, *shapes = _traced_step(cfg, compute_type)
-    assert decoder_lm._traced_counts(step, *shapes, cfg)["head_logit_matmuls"] == 1
-    jaxpr = step.trace(*shapes, jax.ShapeDtypeStruct((), jnp.int32)).jaxpr.jaxpr
+    assert decoder_lm._traced_counts(step, *shapes, cfg, *_noise(cfg))["head_logit_matmuls"] == 1
+    jaxpr = step.trace(*shapes, jax.ShapeDtypeStruct((), jnp.int32), *_noise(cfg)).jaxpr.jaxpr
     assert decoder_lm._head_logit_matmuls(jaxpr, cfg.vocab) == 1 + cfg.mtp_depth
 
 

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from tests import test_lm_scopes
-from tests.test_decoder_lm_head import _traced_step
+from tests.test_decoder_lm_head import _noise, _traced_step
 from flink_ml_tpu.models.lm import decoder_lm
 from flink_ml_tpu.parallel import flash
 
@@ -158,10 +158,10 @@ def test_every_attending_layer_goes_through_the_one_block_form(kind, compute_typ
     state made seventeen."""
     cfg = test_lm_scopes.KINDS[kind][0]
     step, *shapes = _traced_step(cfg, compute_type)
-    counts = decoder_lm._traced_counts(step, *shapes, cfg)
+    counts = decoder_lm._traced_counts(step, *shapes, cfg, *_noise(cfg))
     assert counts["fold_one_block"] == _attention_layers(cfg) > 0
     assert counts["fold_row_stats"] == sum(flash.ONE_BLOCK_ROW_STATS.values()) == 5
-    jaxpr = step.trace(*shapes, jax.ShapeDtypeStruct((), jnp.int32)).jaxpr.jaxpr
+    jaxpr = step.trace(*shapes, jax.ShapeDtypeStruct((), jnp.int32), *_noise(cfg)).jaxpr.jaxpr
     calls = flash.fold_kernel_calls(jaxpr)
     assert {part for part, _ in calls} == set(flash.ONE_BLOCK_ROW_STATS)
     assert all(stats == flash.ONE_BLOCK_ROW_STATS[part] for part, stats in calls)
@@ -209,3 +209,146 @@ def test_the_ring_entry_still_counts_seventeen(monkeypatch):
         assert (counts["fold_one_block"], counts["fold_row_stats"]) == (0, 17)
     finally:
         decoder_lm._train_program.cache_clear()
+
+
+# -- the block-diffusion mask (the third form; the doubled sequence of ``blockKind`` ``sdar``) ---------------------
+
+
+def _bd_rules(t, block, defect=None):
+    """The dense ``[2T, 2T]`` mask from the four rules, one of them defective on request."""
+    b = np.arange(t) // block
+    clean_clean, noised_clean, noised_noised = b[None, :] <= b[:, None], b[None, :] < b[:, None], b[None, :] == b[:, None]
+    clean_noised = np.zeros((t, t), bool)
+    if defect == "noised_sees_its_own_clean_block":
+        noised_clean = clean_clean
+    elif defect == "clean_is_strictly_causal":
+        clean_clean = np.tril(np.ones((t, t), bool))
+    elif defect == "noised_is_causal_in_its_half":
+        noised_noised = clean_clean
+    elif defect == "clean_sees_its_noised_block":
+        clean_noised = noised_noised
+    return np.block([[clean_clean, clean_noised], [noised_clean, noised_noised]])
+
+
+def _masked(q, k, v, scale, keep):
+    """Softmax attention under a dense boolean mask, K and V repeated over their groups."""
+    group = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    s = jnp.where(jnp.asarray(keep), jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
+
+
+#: name -> (batch, query heads, key/value heads, tokens T, D, block, shrunken tiles): the fold runs over 2 T positions.
+#: At the kernels' own tiles T 768 is 1,536 positions in three tiles and chunks of 512, the middle ones half clean and
+#: half noised; T 1,024 has tiles of 512 on two chunks of 1,024 that end where the halves do; with tiles of 256 T 384
+#: has a tile across the halves, T 512 none
+BD_CASES = {
+    "tiles-across-the-halves-block-4": (1, 4, 2, 768, 16, 4, False),
+    "tiles-across-the-halves-block-32": (1, 4, 2, 768, 16, 32, False),
+    "halves-of-whole-chunks-block-4": (1, 2, 1, 1024, 16, 4, False),
+    "halves-of-whole-chunks-block-32": (1, 2, 2, 1024, 16, 32, False),
+    "small-tiles-across-block-4": (2, 4, 1, 384, 16, 4, True),
+    "small-tiles-across-block-32": (1, 8, 2, 384, 8, 32, True),
+    "small-tiles-whole-block-4": (1, 2, 2, 512, 16, 4, True),
+    "a-block-is-a-tile": (1, 2, 2, 512, 16, 256, True),
+    "one-chunk": (1, 2, 1, 256, 16, 4, False),  # 512 positions in one piece: masked there, nothing walked
+}
+
+
+@pytest.mark.parametrize("case", sorted(BD_CASES))
+def test_the_block_diffusion_fold_is_dense_masked_softmax_and_its_gradients(case, monkeypatch):
+    b, h, h_kv, t, d, block, shrunken = BD_CASES[case]
+    if shrunken:
+        for name in ("_TQ_CAUSAL", "_KEY_CHUNK", "_DKV_CAUSAL"):
+            monkeypatch.setattr(flash, name, CHUNK)
+    qkv, cot = _inputs(b, h, h_kv, 2 * t, d, d, seed=sorted(BD_CASES).index(case))
+    scale, blocks = d ** -0.5, flash.BlockDiffusion(t, block)
+    keep = _bd_rules(t, block)
+    assert keep.diagonal().all() and keep.sum() == t * t + t * block  # every row keeps itself; T^2 + T L pairs
+    np.testing.assert_array_equal(np.asarray(flash._kept(2 * t, 2 * t, 0, 0, True, None, None, blocks)), keep)
+    with jax.default_matmul_precision("highest"):
+        want = _out_and_grads(lambda q, k, v: _masked(q, k, v, scale, keep), qkv, cot)
+    got = _out_and_grads(lambda q, k, v: flash.fused_attention(q, k, v, scale, None, True, blocks), qkv, cot)
+    assert got[0].dtype == jnp.float32
+    for name, g, w in zip(("o", "dq", "dk", "dv"), got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        assert _worst([g], [w]) < 1e-5, name
+    # and the ring's reference, told the same mask, is the same fold
+    m0, l0 = jnp.full((b, h, 2 * t), -jnp.inf, jnp.float32), jnp.zeros((b, h, 2 * t), jnp.float32)
+    k_rep, v_rep = (jnp.repeat(x, h // h_kv, axis=1) for x in qkv[1:])
+    _, l, acc = flash.reference_fold(qkv[0], k_rep, v_rep, m0, l0, jnp.zeros_like(qkv[0]), 0, 0, True, None, scale,
+                                     None, blocks)
+    assert _worst([acc / l[..., None]], [want[0]]) < 1e-5
+
+
+@pytest.mark.parametrize("rule", ["noised_sees_its_own_clean_block", "clean_is_strictly_causal",
+                                  "noised_is_causal_in_its_half", "clean_sees_its_noised_block"])
+def test_each_rule_of_the_block_diffusion_mask_is_told_apart(rule):
+    """The four rules one by one: a mask with one of them wrong gives another
+    output than the fold's, far past the tolerance the sound mask is held to."""
+    b, h, h_kv, t, d, block, _ = BD_CASES["tiles-across-the-halves-block-4"]
+    qkv, _ = _inputs(b, h, h_kv, 2 * t, d, d, seed=11)
+    scale = d ** -0.5
+    got = flash.fused_attention(*qkv, scale, None, True, flash.BlockDiffusion(t, block))
+    with jax.default_matmul_precision("highest"):
+        sound, wrong = (_masked(*qkv, scale, _bd_rules(t, block, defect)) for defect in (None, rule))
+    assert _worst([got], [sound]) < 1e-5 < 1e-2 < _worst([got], [wrong])
+
+
+def test_bfloat16_operands_under_the_block_diffusion_mask():
+    b, h, h_kv, t, d, block, _ = BD_CASES["tiles-across-the-halves-block-32"]
+    qkv, cot = _inputs(b, h, h_kv, 2 * t, d, d, seed=7)
+    scale, blocks = d ** -0.5, flash.BlockDiffusion(t, block)
+    low = tuple(x.astype(jnp.bfloat16) for x in qkv)
+    with jax.default_matmul_precision("highest"):
+        want = _out_and_grads(lambda q, k, v: _masked(q, k, v, scale, _bd_rules(t, block)), qkv, cot)
+    got = _out_and_grads(lambda q, k, v: flash.fused_attention(q, k, v, scale, None, True, blocks), low, cot)
+    assert got[0].dtype == jnp.float32 and all(g.dtype == jnp.bfloat16 for g in got[1:])
+    assert _worst(got, want) < 3e-2
+
+
+#: (tokens T, block): the cell's fold first, then tiles across the halves, a block as long as a tile, short sequences
+@pytest.mark.parametrize("t,block", [(4096, 4), (4096, 32), (2048, 4), (768, 4), (768, 32), (1536, 32), (384, 4),
+                                     (1024, 512), (256, 4), (128, 4)])
+def test_fold_chunk_counts_under_the_block_diffusion_mask_is_a_count_of_the_mask(t, block):
+    """``fold_chunk_counts`` against the mask itself, brute force: a (query
+    tile, key chunk) pair is visited iff the mask keeps an entry of it, at each
+    kernel's tiles (a block of one chunk is taken whole by the forward and the
+    dq kernel). At the cell's size the walk visits 37.5% of the pairs where a
+    causal walk of the same 8,192 positions visits 56.25%."""
+    p = 2 * t
+    keep = _bd_rules(t, block)
+    tq_fwd, tq_dq, tq_dkv, tk_dkv, kc = flash._fold_tiles(p, p, True)
+    visited = total = 0
+    for rows, keys, skips in ((tq_fwd, kc, kc < p), (tq_dq, kc, kc < p), (tq_dkv, tk_dkv, True)):
+        pairs = keep.reshape(p // rows, rows, p // keys, keys).any(axis=(1, 3))
+        visited, total = visited + (int(pairs.sum()) if skips else pairs.size), total + pairs.size
+    assert flash.fold_chunk_counts(p, p, 0, True, None, flash.BlockDiffusion(t, block)) == (visited, total)
+    if t == 4096:
+        assert visited / total == 0.375 and flash.fold_chunk_counts(p, p, 0, True)[0] / total == 0.5625
+
+
+def test_the_dkv_kernels_query_map_names_only_tiles_the_key_tile_sees():
+    """Under the block-diffusion mask the dkv kernel's grid step ``(jk, jq)``
+    fetches the query tile ``_bd_q_tile`` names: ``jq`` itself wherever the
+    pair holds a kept entry, and elsewhere a tile that does (so a hidden pair
+    fetches nothing new); where the tiles do not divide the halves, ``jq``."""
+    for t, block, tile in ((4096, 4, 1024), (2048, 32, 1024), (1024, 1024, 1024), (512, 4, 256)):
+        blocks, n = flash.BlockDiffusion(t, block), 2 * t // tile
+        seen = _bd_rules(t, block).reshape(n, tile, n, tile).any(axis=(1, 3))  # [query tile, key tile]
+        for jk in range(n):
+            named = [int(flash._bd_q_tile(jnp.int32(jk), jnp.int32(jq), tile, tile, blocks)) for jq in range(n)]
+            assert all(seen[named[jq], jk] for jq in range(n)), (t, block, jk, named)
+            assert all(named[jq] == jq for jq in range(n) if seen[jq, jk]), (t, block, jk, named)
+            assert named == sorted(named)  # a run of equal names is fetched once
+    assert int(flash._bd_q_tile(jnp.int32(1), jnp.int32(0), 512, 512, flash.BlockDiffusion(768, 4))) == 0
+
+
+def test_a_block_diffusion_mask_the_counts_are_not_written_for_is_refused():
+    (q, k, v), _ = _inputs(1, 1, 1, 512, 8, 8)
+    for blocks, window, match in ((flash.BlockDiffusion(256, 6), None, "power of two"),
+                                  (flash.BlockDiffusion(128, 4), None, "twice 128 tokens"),
+                                  (flash.BlockDiffusion(256, 512), None, "power of two that"),
+                                  (flash.BlockDiffusion(256, 4), 64, "no sliding window")):
+        with pytest.raises(ValueError, match=match):
+            flash.fused_attention(q, k, v, 1.0, window, True, blocks)

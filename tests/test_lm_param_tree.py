@@ -5,13 +5,16 @@ leaves by position - the ORDER that 512ebfa (PR 42) gave. A moved leaf is
 another model from the same seed. Toys by literal; the benchmark's five LM
 configurations by the digest of the list and by their parameter counts. The
 ``joyai`` kind (PR 44) is pinned beside them as it came: its stack's leaves,
-then ``final_norm`` and ``lm_head``, then the multi-token-prediction module."""
+then ``final_norm`` and ``lm_head``, then the multi-token-prediction module;
+the ``sdar`` kind (PR 47) likewise: OLMoE's order, its QK-norms a head wide."""
 import hashlib
 
 import pytest
 
 from flink_ml_tpu.models.lm.config import LMConfig, layers, num_params, param_shapes
-from tests.test_lm_chip_compile import _cell_config, _joyai_cut, _laguna_cut, _nemotron_cut, _ouro_cut, _zaya_cut
+from tests.test_lm_chip_compile import (
+    _cell_config, _joyai_cut, _laguna_cut, _nemotron_cut, _ouro_cut, _sdar_cut, _zaya_cut,
+)
 
 TOYS = {
     "olmoe": LMConfig(n_layers=1, hidden=128, n_heads=4, n_experts=8, top_k=2, expert_width=64, vocab=512),
@@ -33,6 +36,9 @@ TOYS = {
                       rope_theta=3.2e7, norm_eps=1e-6, aux_coef=0.0, block="joyai", experts_held=2, first_held=2,
                       n_dense=1, dense_width=96, shared_width=32, routed_scale=2.5, q_rank=48, kv_rank=32, nope_dim=16,
                       rope_dim=8, v_dim=12, mtp_depth=1, mtp_coef=0.3),
+    "sdar": LMConfig(n_layers=1, hidden=64, n_heads=4, n_experts=16, top_k=2, expert_width=32, vocab=512,
+                     rope_theta=1e6, norm_eps=1e-6, aux_coef=0.0, block="sdar", experts_held=2, first_held=2,
+                     n_kv_heads=2, head_size=16, block_length=4, mask_id=511),
 }
 #: ``(dotted path, shape, init)`` of every leaf in ``param_shapes`` order, printed by 512ebfa's ``param_shapes``
 TOY_TREES = {
@@ -142,6 +148,14 @@ TOY_TREES = {
         ('mtp.layer.w_gate', (2, 64, 32), 'normal'), ('mtp.layer.w_up', (2, 64, 32), 'normal'),
         ('mtp.layer.w_down', (2, 32, 64), 'normal'), ('mtp.norm', (64,), 'ones'),
     ],
+    "sdar": [
+        ('embed', (512, 64), 'normal'), ('layers.0.attn_norm', (64,), 'ones'), ('layers.0.wq', (64, 64), 'normal'),
+        ('layers.0.wk', (64, 32), 'normal'), ('layers.0.wv', (64, 32), 'normal'), ('layers.0.wo', (64, 64), 'normal'),
+        ('layers.0.q_norm', (16,), 'ones'), ('layers.0.k_norm', (16,), 'ones'), ('layers.0.ffn_norm', (64,), 'ones'),
+        ('layers.0.router', (64, 16), 'normal'), ('layers.0.w_gate', (2, 64, 32), 'normal'),
+        ('layers.0.w_up', (2, 64, 32), 'normal'), ('layers.0.w_down', (2, 32, 64), 'normal'),
+        ('final_norm', (64,), 'ones'), ('lm_head', (64, 512), 'normal'),
+    ],
 }
 
 
@@ -164,6 +178,8 @@ CELLS = {
                            "f41d67cb520ef871addd10ae18b186dcc768f777eb3c201e86c700ff33bd1c08", 3),
     # as PR 44 brought it: the stack's two records, the module's layer being the second again
     "joyai_llm_flash": (_joyai_cut, 680_441_088, "4d26d90bc1061ad9243f991a5a9928089e0e0c91df5c30ca033534a38a14b211", 2),
+    # as PR 47 brought it: six layers of one record
+    "sdar_30b_a3b": (_sdar_cut, 645_623_296, "2c858655427e58fe0af64a14d0340659d780d9481f3c4b732df96af708c7e554", 1),
 }
 
 

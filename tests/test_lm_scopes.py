@@ -55,6 +55,11 @@ KINDS = {
                "lm.mtp/lm.block/rope", "lm.mtp/lm.block/fold", "lm.mtp/lm.block/proj", "lm.mtp/lm.block/mix",
                "lm.mtp/lm.block/route", "lm.mtp/lm.block/permute", "lm.mtp/lm.block/experts",
                "lm.mtp/lm.block/shared"}),
+    # block diffusion: the corruption and the doubled input under ``lm.noise``, before the embedding
+    "sdar": (LMConfig(n_layers=2, hidden=64, n_heads=4, n_experts=8, top_k=2, expert_width=32, vocab=512,
+                      rope_theta=1e6, norm_eps=1e-6, aux_coef=0.0, block="sdar", experts_held=4, first_held=2,
+                      n_kv_heads=2, head_size=16, block_length=4, mask_id=511), True,
+             {"lm.noise", "lm.block/route", "lm.block/permute", "lm.block/experts"}),
 }
 EVERY_KIND = {"lm.embed", "lm.block/norm", "lm.block/proj", "lm.block/fold", "lm.block/mix",
               "lm.final_norm/norm", "lm.head", "lm.opt"}
@@ -86,9 +91,13 @@ def programs():
             optimizer, step = decoder_lm._train_program(cfg, "float32", 1e-3, 2, True)
             params = jax.eval_shape(lambda: decoder_lm._init_program(cfg)(jax.random.key(0)))
             state = jax.eval_shape(optimizer.init, params)
-            step_text = step.lower(params, state, TOKENS, jax.ShapeDtypeStruct((), jnp.int32)).compile().as_text()
+            # a stage that trains by block diffusion hands its step and its scoring the noise key and an index
+            noise = ((jax.eval_shape(lambda: jax.random.key(0)), jax.ShapeDtypeStruct((), jnp.int32)),) \
+                if cfg.block_length else ()
+            step_text = step.lower(params, state, TOKENS, jax.ShapeDtypeStruct((), jnp.int32),
+                                   *noise).compile().as_text()
             score_text = decoder_lm._log_likelihood_program(cfg, "float32", True).lower(
-                params, jax.ShapeDtypeStruct((2, 256), jnp.int32)).compile().as_text()
+                params, jax.ShapeDtypeStruct((2, 256), jnp.int32), *noise).compile().as_text()
             made[kind] = list(_instructions(step_text)), list(_instructions(score_text))
         return made[kind]
 
@@ -138,6 +147,10 @@ def test_the_head_is_never_recomputed_and_a_block_is_where_it_is_checkpointed(pr
         assert FWD in {d for s, d in found if s == "lm.mtp/lm.head"} <= {FWD, BWD}
         assert {d for s, d in found if s == "lm.mtp/proj"} == {FWD, BWD}
         assert {d for s, d in found if s == "lm.block/latent"} == {FWD, REMAT, BWD}
+    if kind == "sdar":  # the doubled sequence's kernels under names of their own, and the corruption outside AD
+        names = {n.split("/fold/")[1].split("/")[0] for _, n in step if n and "/fold/flash_fold" in n}
+        assert names == {f"flash_fold_bd_{k}" for k in ("fwd", "bwd_dq", "bwd_dkv")}
+        assert {d for s, d in found if s == "lm.noise"} == {FWD}
     if kind == "laguna":  # the windowed layer's kernels under names of their own, beside the full layers'
         names = {n.split("/fold/")[1].split("/")[0] for _, n in step if n and "/fold/flash_fold" in n}
         assert names == {f"flash_fold_{w}{k}" for w in ("", "win_") for k in ("fwd", "bwd_dq", "bwd_dkv")}
@@ -158,7 +171,7 @@ def test_the_update_is_outside_the_gradient(programs, kind):
 #: weights' cotangents over the passes (``dynamic_update_slice``, ``add_any``)
 #: in code that is JAX's own, under no scope of the program.
 COVERED = {"olmoe": 0.95, "olmoe_stacked": 0.95, "zaya": 0.95, "ouro": 0.90, "laguna": 0.95, "nemotron_h": 0.95,
-           "joyai": 0.95}
+           "joyai": 0.95, "sdar": 0.95}
 
 
 @pytest.mark.parametrize("kind", sorted(KINDS))
