@@ -1235,21 +1235,26 @@ def _traced_counts(step, params, opt_state, window, cfg: LMConfig, *noise) -> di
     1 where the head forms its gradients in the pass that holds the logits, 2
     where a backward computes them again. ``fold_one_block``: the attention
     layers as traced (a looped stack's once) whose fold is the one-block form,
-    by their dq kernel's calls; ``fold_row_stats``: the float32 row statistics
-    that one layer's three fold kernels take and hand back, the most of any
-    call (5 in the one-block form, 17 where the ring's carried state goes
-    through them)."""
+    by their backward kernel's calls, whatever it is called;
+    ``fold_bwd_kernels``: the backward fold kernels of one layer, by their
+    names (1: one walk gives ``dq``, ``dk`` and ``dv``; 2 where the ring form's
+    dq and dkv kernels each walk); ``fold_row_stats``: the float32 row
+    statistics that one layer's fold kernels take and hand back, the most of any
+    call (2 in the one-block form: ``lse`` out of the forward and into the
+    backward; 17 where the ring's carried state goes through three kernels)."""
     key = (step, window.shape)
     if key not in _TRACED_COUNTS:
         jaxpr = step.trace(params, opt_state, window, jax.ShapeDtypeStruct((), jnp.int32),
                            *noise).jaxpr.jaxpr  # runs nothing
         folds = fold_kernel_calls(jaxpr)
+        parts = {part for part, _ in folds}  # the fold's kernels by name: fwd, bwd_dkv (and the ring form's bwd_dq)
         _TRACED_COUNTS[key] = {
             "conv_positions_kernel": forward_positions(jaxpr),
             "head_logit_matmuls": _head_logit_matmuls(jaxpr, cfg.vocab) // (1 + cfg.mtp_depth),
-            "fold_one_block": sum(part == "bwd_dq" and stats == ONE_BLOCK_ROW_STATS[part] for part, stats in folds),
-            "fold_row_stats": sum(max((stats for p, stats in folds if p == part), default=0)
-                                  for part in ONE_BLOCK_ROW_STATS)}
+            "fold_one_block": sum(part.startswith("bwd") and stats == ONE_BLOCK_ROW_STATS.get(part)
+                                  for part, stats in folds),
+            "fold_bwd_kernels": sum(part.startswith("bwd") for part in parts),
+            "fold_row_stats": sum(max(stats for p, stats in folds if p == part) for part in parts)}
     return _TRACED_COUNTS[key]
 
 
@@ -1433,7 +1438,7 @@ class DecoderLM(Estimator, _LMParams):
             optimizer, step = _train_program(
                 cfg, self.get_compute_type(), float(self.get_learning_rate()), batch, interpret
             )
-            # what the causal fold's three kernels walk in one step, each counted once
+            # what the fold's two kernels (the forward, the one backward) walk in one step, each counted once
             # (a rematerialised forward not again), and what the mask lets them skip;
             # the windowed layers' share of both beside them
             applications = cfg.n_layers * cfg.loops
@@ -1449,7 +1454,7 @@ class DecoderLM(Estimator, _LMParams):
             scan_chunks = sum(batch * m.heads * (t // m.chunk) for m in scans)
             scan_chunks_kernel = sum(scan_kernel_chunks(batch, t, m.heads, m.groups, m.chunk) for m in scans)
             one_head = {(w, block): np.asarray(fold_chunk_counts(positions, positions, 0, True, w or None,
-                                                                 BlockDiffusion(t, block) if block else None))
+                                                                 BlockDiffusion(t, block) if block else None, True))
                         for _, w, block in folds}
             chunks = np.zeros((3, 2), np.int64)  # [full, windowed, block diffusion] x [visited, all]
             for h, w, block in folds:
@@ -1468,7 +1473,8 @@ class DecoderLM(Estimator, _LMParams):
                                state_leaves=len(state), state_bytes=sum(x.nbytes for x in state),
                                head_logit_matmuls=traced["head_logit_matmuls"])
             if folds:  # a stack that attends: how its step as traced calls the fold
-                phase.set_metadata(fold_one_block=traced["fold_one_block"], fold_row_stats=traced["fold_row_stats"])
+                phase.set_metadata(fold_one_block=traced["fold_one_block"], fold_row_stats=traced["fold_row_stats"],
+                                   fold_bwd_kernels=traced["fold_bwd_kernels"])
             if chunks[2, 1]:  # the doubled sequences' folds, and the positions a step takes through the stack
                 phase.set_metadata(layers_diffusion=sum(block > 0 for _, _, block in folds),
                                    diffusion_block=cfg.block_length,

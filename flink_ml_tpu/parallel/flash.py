@@ -77,16 +77,15 @@ the noised half. The clean half leads so that everything kept lies under the
 of clean rows the causal walk's own chunks, for a tile of noised rows the
 chunks before its first block - whole where every row keeps them and masked
 where not, and then the band, the one or two chunks that hold the tile's own
-noised positions, masked. At ``T`` 4,096 and the tiles below the three kernels
-visit 37.5% of their (tile, chunk) pairs (``fold_chunk_counts``; the mask
-keeps ``T^2 + T L`` of the ``4 T^2`` entries, 25.02%) where a causal walk of
-the same 8,192 positions visits 56.25%. A crossed chunk's mask is two compares
-of block ids (``_bd_keep``: shifts, ``L`` a power of two) worked out on one
-column of rows and one row of keys. The dkv kernel's grid skips the hidden
-pairs and names, for each, a query tile it has already fetched
-(``_bd_q_tile``). The kernels are named ``flash_fold_bd_*``; the causal and the
-windowed forms trace to what they traced to before the fold knew of it. The
-ring's entry (``fused_fold``) does not take it; ``reference_fold`` does.
+noised positions, masked. At ``T`` 4,096 and the tiles below the forward
+visits 37.5% of its (tile, chunk) pairs and the backward kernel, at chunks half
+as long, 31.25% (``fold_chunk_counts``; the mask keeps ``T^2 + T L`` of the ``4
+T^2`` entries, 25.02%) where a causal walk of the same 8,192 positions visits
+56.25% and 53.1%. A crossed chunk's mask is two compares of block ids (``_bd_keep``: shifts, ``L``
+a power of two) worked out on one column of rows and one row of keys. The
+kernels are named ``flash_fold_bd_*``; the causal and the windowed forms trace
+to what they traced to before the fold knew of it. The ring's entry
+(``fused_fold``) does not take it; ``reference_fold`` does.
 
 The one-block form: ``fused_attention`` is the same fold where the ring has
 ONE step: a whole sequence on itself (``q_pos0 = k_pos0 = 0``, ``Tq = Tk``,
@@ -97,14 +96,33 @@ k, v, o, lse``; its backward is the softmax's own VJP, ``P = exp(s - lse)``,
 the gradient through the row maximum, ``dsafe = -(dl l + dacc . acc)``, is zero
 once the output is normalised (``dacc = dO / l``, ``dl = -dO . o / l``), so
 what the ring form computes there (``take_m``, ``is_max``, the tie count,
-``dbc``) is rounding noise of a term that cancels. With ``lse`` and ``delta``
-known before the walk the dq kernel makes ONE walk of the visible chunks and
-parks nothing; the dkv kernel forms a pair's scores transposed (``[keys,
-rows]``), so the two statistics broadcast down the sublanes and ``P^T``,
-``ds^T`` are the left operands of ``dv``'s and ``dk``'s matmuls as they stand.
-Two float32 row statistics cross HBM where the ring form's kernels move
-seventeen, and they lie along the lanes (``[B x H, 1, T]``; a ``[B x H, T,
-1]`` column is padded 128 times by the device's tiling). The tiles, the walk's
+``dbc``) is rounding noise of a term that cancels. With ``lse`` known and
+``delta`` formed in the cell before the walk, a chunk's ``P`` and ``ds`` are
+final as they are formed, so the backward is ONE kernel and ONE walk: a query
+tile visits its visible key chunks once (the forward's walk and helpers at
+chunks of its own, 512 keys: it parks nothing, and a window's or a band's edge
+wastes less of a shorter chunk) and each chunk's ``s``, ``P``, ``dP`` and
+``ds`` feed all three gradients, five matmuls and one ``exp`` a visited pair
+where a dq kernel and a dkv kernel, each forming the scores for itself, made
+seven and two. A chunk's scores are formed transposed (``s^T = k q^T``,
+``[keys, rows]``), so ``lse`` and ``delta`` broadcast down the sublanes as
+``[1, rows]`` rows, ``dk += ds^T q`` and ``dv += P^T dO`` are plain matmuls
+whose left operand stands as it was made, and ``dq += (ds^T)^T k`` alone has
+its left operand transposed (one of the three has, whichever way the scores
+lie), which Mosaic lowers itself. ``dq`` leaves a tile at the end of its walk.
+``dk`` and ``dv`` of a key/value head wait whole in VMEM in float32 (``T x (D +
+D_v) x 4`` B: 10.5 MB at 8,192 x (192 + 128)) from the first tile of the
+head's first query head to the last tile of its last, added into at each
+visited chunk's rows and written once, scaled and cast: the grid is ``(B x H,
+T / rows)``, a group's query heads follow one another, and their cells name
+the same ``dk`` and ``dv`` block, so grouped queries are summed with no second
+pass.
+The kernel keeps the dkv kernel's name (``flash_fold[_win|_bd]_bwd_dkv``:
+what reads the fold's time by name goes on reading all of it). ONE float32
+row statistic crosses HBM, twice (``lse`` out of the forward and into the
+backward; ``delta`` never leaves a cell), where the ring form's kernels move
+seventeen, and it lies along the lanes (``[B x H, 1, T]``; a ``[B x H, T, 1]``
+column is padded 128 times by the device's tiling). The tiles, the walk's
 helpers and the kernels' names are the ring form's. Who calls which:
 ``models/lm/decoder_lm._fold`` (training, ``transform``, ``log_likelihood``)
 calls ``fused_attention``; ``ring.py`` (state carried across ring steps,
@@ -252,10 +270,16 @@ _LANES = 128
 # next chunk's matmul), so chunks are long; the hidden share falls with the tile
 # (44% of the pairs at 512 x 1024 on T 8,192, 47% at 256 x 512) and the time
 # falls faster. The dkv pair is square: at 256 keys a pair the kernel ran at a
-# third of this speed whatever it skipped.
-_TQ_CAUSAL = 512  # Q rows per forward and per dq cell
-_KEY_CHUNK = 1024  # keys per chunk of their walks
+# third of this speed whatever it skipped. The one-block form's ONE backward
+# kernel (PERF.md, PR 48) walks shorter chunks: it parks nothing, so a chunk
+# costs it less, and 512 x 512 beat 512 x 1024, 256 x 512, 512 x 256 and 1024
+# x 256 at all eight of the LM cells' folds (by 1-5% where the mask is causal,
+# by 26% under a 512-key window and 13% under the block-diffusion mask, whose
+# bands a 1,024-key chunk covers twice over).
+_TQ_CAUSAL = 512  # Q rows per forward, per dq and per one-block backward cell
+_KEY_CHUNK = 1024  # keys per chunk of the forward's and the dq kernel's walks
 _DKV_CAUSAL = 1024  # Q rows and K rows of a dkv pair
+_BWD_KEY_CHUNK = 512  # keys per chunk of the one-block backward's walk
 
 
 def _tile(T: int, most: int) -> int:
@@ -467,18 +491,27 @@ def _park_scores(qt, k_ref, s_scr, q_first, k_pos0, n_valid, scale, kc: int, n_f
                         n_lo, lo_end, band)
 
 
-def fold_chunk_counts(Tq: int, Tk: int, q_off: int, causal: bool, window=None, blocks=None):
+def fold_chunk_counts(Tq: int, Tk: int, q_off: int, causal: bool, window=None, blocks=None, one_block: bool = False):
     """``(visited, total)`` chunk pairs of ONE fold of ``Tq`` queries on ``Tk``
-    keys, one head, the three kernels together at the tiles they use: the
-    forward's and the dq kernel's (query tile, key chunk) pairs and the dkv
-    kernel's (key tile, query tile) pairs. ``q_off = q_pos0 - k_pos0``. A kernel
-    that takes the block in one piece visits every pair: all three without
-    ``causal``, the forward and the dq kernel on a block of one chunk. Under a
+    keys, one head, its kernels together at the tiles they use. The ring form
+    (``fused_fold``) has three: the forward's and the dq kernel's (query tile,
+    key chunk) pairs and the dkv kernel's (key tile, query tile) pairs.
+    ``one_block`` (``fused_attention``; ``blocks`` implies it) has two, the
+    forward and the ONE backward kernel, whose cells walk (query tile, key
+    chunk) pairs as the forward's do, at shorter chunks (``_attention_tiles``):
+    no third term. ``q_off = q_pos0 - k_pos0``. A kernel that takes the block
+    in one piece visits every pair: all three without ``causal``, every
+    kernel but the ring's dkv on a block of one chunk of its own. Under a
     sliding ``window`` (``causal`` with it) a walk also skips the chunks below
     the window's edge; under the block-diffusion mask (``blocks``, of a doubled
     sequence on itself: ``Tq = Tk = 2 x blocks.tokens``, ``q_off`` 0) a tile
     visits what ``_bd_chunks`` gives it."""
-    tq_fwd, tq_dq, tq_dkv, tk_dkv, kc = _fold_tiles(Tq, Tk, causal)
+    if one_block or blocks is not None:
+        tq, kc, tq_bwd, kc_bwd = _attention_tiles(Tk)
+        kernels = ((tq, kc, kc < Tk), (tq_bwd, kc_bwd, kc_bwd < Tk))
+    else:
+        tq_fwd, tq_dq, tq_dkv, tk_dkv, kc = _fold_tiles(Tq, Tk, causal)
+        kernels = ((tq_fwd, kc, kc < Tk), (tq_dq, kc, kc < Tk), (tq_dkv, tk_dkv, causal))
     visited = total = 0
 
     def seen(q_first, rows, keys, n_keys):
@@ -490,7 +523,7 @@ def fold_chunk_counts(Tq: int, Tk: int, q_off: int, causal: bool, window=None, b
             return n_vis
         return n_vis - _window_chunks(q_first, rows, 0, keys, n_keys, window, n_full, n_vis)[0]
 
-    for rows, keys, skips in ((tq_fwd, kc, kc < Tk), (tq_dq, kc, kc < Tk), (tq_dkv, tk_dkv, causal)):
+    for rows, keys, skips in kernels:
         n_keys = Tk // keys
         total += (Tq // rows) * n_keys
         visited += sum(seen(q_off + j * rows, rows, keys, n_keys) if skips else n_keys for j in range(Tq // rows))
@@ -551,9 +584,10 @@ def _kernel_name(part: str, window, blocks=None) -> str:
 
 
 #: The float32 row statistics (``[B x H, T, 1]`` columns or ``[B x H, 1, T]`` rows) among a kernel's operands and
-#: results in the one-block form: ``lse`` out of the forward; ``lse`` into and ``delta`` out of the dq kernel; both
-#: into the dkv kernel. (The ring form's kernels carry 4, 9 and 4: ``m``, ``l``, their cotangents and the tie terms.)
-ONE_BLOCK_ROW_STATS = {"fwd": 1, "bwd_dq": 2, "bwd_dkv": 2}
+#: results in the one-block form, by the kernel's part of its name: ``lse`` out of the forward and into the one
+#: backward kernel (``delta`` is formed in its cells and crosses nothing). The ring form's three kernels carry 4, 9
+#: and 4: ``m``, ``l``, their cotangents and the tie terms.
+ONE_BLOCK_ROW_STATS = {"fwd": 1, "bwd_dkv": 1}
 
 
 def fold_kernel_calls(jaxpr) -> list:
@@ -1113,24 +1147,19 @@ def _col_to_row(col):
     return jnp.transpose(jnp.broadcast_to(col, (col.shape[0], _LANES)))[:1]
 
 
-def _row_to_col(row):
-    """``[1, rows] -> [rows, 1]``, the way back."""
-    return jnp.transpose(jnp.broadcast_to(row, (_LANES, row.shape[1])))[:, :1]
-
-
 def _nt_dot(a, b):
     """``a [n, d] , b [m, d] -> a b^T [n, m]`` in float32."""
     return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
 
 def _attention_tiles(T: int):
-    """``_fold_tiles`` of the LM's fold; the dq kernel takes the forward's rows:
-    the walk's are equal, and a block of one chunk (T up to 1,024) holds no
-    three whole-block buffers here, and a 64-row tile of a statistic that lies
-    along the lanes would be half a lane tile. ``fold_chunk_counts`` counts
-    such a block at the ring form's 64 rows: every pair visited either way."""
-    tq, _, tq_dkv, tk_dkv, kc = _fold_tiles(T, T, True)
-    return tq, tq_dkv, tk_dkv, kc
+    """``(forward rows, forward key chunk, backward rows, backward key chunk)``
+    of the LM's fold: the forward's are ``_fold_tiles``' walk (a block of one
+    chunk, T up to 1,024, in one piece at 256 rows); the backward walks chunks
+    of its own (``_BWD_KEY_CHUNK``) and takes a block of one of them in one
+    piece."""
+    tq, _, _, _, kc = _fold_tiles(T, T, True)
+    return tq, kc, _tile(T, _TQ_CAUSAL), _tile(T, _BWD_KEY_CHUNK)
 
 
 def _cell_chunks(q_first, n_rows: int, chunk: int, n_chunks: int, window, blocks=None):
@@ -1151,27 +1180,10 @@ def _cell_chunks(q_first, n_rows: int, chunk: int, n_chunks: int, window, blocks
     return (*_window_chunks(q_first, n_rows, 0, chunk, n_chunks, window, n_full, n_vis), n_vis, None)
 
 
-def _bd_q_tile(jk, jq, tq: int, tk: int, blocks):
-    """The query tile the dkv kernel's grid step ``(jk, jq)`` names under the
-    block-diffusion mask: ``jq`` itself where the tile sees key tile ``jk``,
-    else the tile last seen before it (or the first to come), so that nothing is
-    fetched for a hidden pair. A tile of clean keys is seen by the clean
-    tiles from the diagonal on and, past a gap, by the noised tiles from its
-    own place in their half on; a tile of noised keys by the one tile of its
-    own rows. Where the tiles do not divide the halves evenly the map is the
-    identity: a hidden pair is fetched and not computed."""
-    tokens, block = blocks
-    if tq != tk or tokens % tq:
-        return jq
-    half = tokens // tq  # the first noised tile
-    again = half + jk + (block == tq)  # the first noised tile that keeps a key of clean tile jk
-    return jnp.where(jk >= half, jk, jnp.where(jq < again, jnp.clip(jq, jk, half - 1), jq))
-
-
 def _attention_specs(T: int, tq: int, kv_of):
-    """The forward's and the dq kernel's block specs on a ``(B x H, T / tq)``
-    grid: a query tile ``rows(width)``, a head's whole K or V ``keys(width)``,
-    a row statistic's ``[1, tq]`` piece."""
+    """The forward's and the backward's block specs on a ``(B x H, T / tq)``
+    grid: a query tile ``rows(width)``, a head's whole K or V (or ``dk``, ``dv``)
+    ``keys(width)``, a row statistic's ``[1, tq]`` piece."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1211,8 +1223,8 @@ def _attention_pallas(q, k, v, scale, window, interpret, blocks=None):
     Hkv, Dv = k.shape[1], v.shape[3]
     BH = B * H
     kv_of = _kv_block_of(H, Hkv)
-    tq, tq_dkv, _, kc = _attention_tiles(T)
-    _check_blocks(T, blocks, window, (tq, tq_dkv))
+    tq, kc, tq_bwd, _ = _attention_tiles(T)
+    _check_blocks(T, blocks, window, (tq, tq_bwd))
     n_chunks = T // kc
 
     def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *s_scr):
@@ -1260,8 +1272,15 @@ def _attention_pallas(q, k, v, scale, window, interpret, blocks=None):
     return o.reshape(B, H, T, Dv), lse
 
 
+def _tn_dot(a, b):
+    """``a [n, d] , b [n, m] -> a^T b [d, m]`` in float32: the left operand transposed."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+
 def _attention_bwd_pallas(q, k, v, o, lse, do, scale, window, interpret, blocks=None):
-    """``(dq, dk, dv)`` of the one-block form, each in its operand's type."""
+    """``(dq, dk, dv)`` of the one-block form, each in its operand's type, from
+    ONE kernel: a query tile walks its visible key chunks once and every chunk's
+    ``s``, ``P``, ``dP`` and ``ds`` feed all three gradients."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1272,138 +1291,76 @@ def _attention_bwd_pallas(q, k, v, o, lse, do, scale, window, interpret, blocks=
     BH, BHkv = B * H, B * Hkv
     group = H // Hkv
     kv_of = _kv_block_of(H, Hkv)
-    tq, tq_dkv, tk_dkv, kc = _attention_tiles(T)
-    n_chunks, n_q_dkv, n_k_dkv = T // kc, T // tq_dkv, T // tk_dkv
+    _, _, tq, kc = _attention_tiles(T)
+    n_chunks, n_tiles = T // kc, T // tq
 
-    def dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, delta_ref, dq_scr):
-        # one walk of the visible chunks: with the row's log-sum-exp and delta in hand a chunk's P and ds are
-        # final as they are formed, and no score waits in VMEM
-        q_first = pl.program_id(1) * tq
+    def kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr):
+        # grid (B*H, tiles): the cells of one key/value head's query heads follow one another, and the head's dk and
+        # dv wait in VMEM, whole and in float32, from the first tile of its first query head to the last of its last.
+        # With the row's log-sum-exp and delta in hand a chunk's P and ds are final as they are formed: no score
+        # waits in VMEM, and nothing is formed twice. The scores are formed TRANSPOSED, [keys, rows]: the two
+        # statistics broadcast down the sublanes (lse as it arrives), P^T and ds^T are the left operands of dv's and
+        # dk's matmuls as they stand, and dq's alone has its left operand transposed
+        head, tile = pl.program_id(0), pl.program_id(1)
+        q_first = tile * tq
         qt, do = q_ref[0], do_ref[0]
-        delta = jnp.sum(do * o_ref[0], axis=1, keepdims=True)  # float32, from the unrounded dO and o
-        lse = _row_to_col(lse_ref[0])
+        delta = _col_to_row(jnp.sum(do * o_ref[0], axis=1, keepdims=True))  # float32, from the unrounded dO and o
+        lse = lse_ref[0]  # [1, rows], as delta
         do_t = do.astype(v_ref.dtype)
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-        def chunk(kt, vt, k_first, masked):
-            s = _nt_dot(qt, kt) * scale  # [rows, kc]
+        @pl.when((head % group == 0) & (tile == 0))
+        def _():
+            dk_scr[...] = jnp.zeros_like(dk_scr)
+            dv_scr[...] = jnp.zeros_like(dv_scr)
+
+        def chunk(kt, vt, k_first, keys, masked):
+            s = _nt_dot(kt, qt) * scale  # [kc, rows]
             if masked:
-                s = _mask_chunk(s, q_first, k_first, True, None, window, blocks=blocks)
+                s = _mask_chunk(s, q_first, k_first, True, None, window, q_axis=1, blocks=blocks)
             p = jnp.exp(s - lse)  # lse is finite: a masked score's exp(-inf) is exactly 0
-            ds = p * (_nt_dot(do_t, vt) - delta)
-            dq_scr[...] += jnp.dot(ds.astype(kt.dtype), kt, preferred_element_type=jnp.float32)
+            ds = (p * (_nt_dot(vt, do_t) - delta)).astype(kt.dtype)
+            dk_scr[keys, :] += jnp.dot(ds, qt, preferred_element_type=jnp.float32)
+            dv_scr[keys, :] += jnp.dot(p.astype(vt.dtype), do_t, preferred_element_type=jnp.float32)
+            dq_scr[...] += _tn_dot(ds, kt)
 
         def walk(masked):
             def body(c, carry):
-                chunk(_key_rows(k_ref, c, kc), _key_rows(v_ref, c, kc), c * kc, masked)
+                chunk(_key_rows(k_ref, c, kc), _key_rows(v_ref, c, kc), c * kc,
+                      pl.ds(pl.multiple_of(c * kc, kc), kc), masked)
                 return carry
 
             return body
 
         if n_chunks == 1:  # the block in one piece
-            chunk(k_ref[0], v_ref[0], 0, True)
+            chunk(k_ref[0], v_ref[0], 0, slice(None), True)
         else:
             n_lo, lo_end, n_full, n_vis, band = _cell_chunks(q_first, tq, kc, n_chunks, window, blocks)
             _walk_chunks(walk, 0, n_full, n_vis, window, n_lo, lo_end, band)
         dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
-        delta_ref[0] = _col_to_row(delta)
 
-    def dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr):
-        # grid (B*H_kv, ktiles, group*qtiles) as the ring form's; a pair's scores are formed TRANSPOSED, [keys,
-        # rows]: the two row statistics broadcast down the sublanes as they arrive, and P^T and ds^T are the left
-        # operands of dv's and dk's matmuls as they stand
-        jk = pl.program_id(1)
-        jq = pl.program_id(2)
-        first, last = jq == 0, jq == group * n_q_dkv - 1
-        if group > 1:
-            jq = jq % n_q_dkv
-        q_first = jq * tq_dkv
-        k_first = jk * tk_dkv
-
-        def accumulate(mask):
-            s = _nt_dot(k_ref[0], q_ref[0]) * scale  # [TK, TQ]
-            if mask:
-                s = _mask_chunk(s, q_first, k_first, True, None, window, q_axis=1, blocks=blocks)
-            p = jnp.exp(s - lse_ref[0])
-            do_t = do_ref[0].astype(v_ref.dtype)
-            ds = p * (_nt_dot(v_ref[0], do_t) - delta_ref[0])
-            dk_scr[...] += jnp.dot(ds.astype(q_ref.dtype), q_ref[0], preferred_element_type=jnp.float32)
-            dv_scr[...] += jnp.dot(p.astype(v_ref.dtype), do_t, preferred_element_type=jnp.float32)
-
-        @pl.when(first)
-        def _():
-            dk_scr[...] = jnp.zeros_like(dk_scr)
-            dv_scr[...] = jnp.zeros_like(dv_scr)
-
-        n_lo, lo_end, n_full, n_vis, band = _cell_chunks(q_first, tq_dkv, tk_dkv, n_k_dkv, window, blocks)
-        if band is not None:  # the clean keys some row keeps and not all, and the rows' own band
-            pl.when(jk < n_full)(lambda: accumulate(False))
-            pl.when(((jk >= n_full) & (jk < n_vis)) | ((jk >= band[0]) & (jk < band[1])))(lambda: accumulate(True))
-        elif window is None:
-            pl.when(jk < n_full)(lambda: accumulate(False))
-            pl.when((jk >= n_full) & (jk < n_vis))(lambda: accumulate(True))
-        else:  # below the window nothing; the pairs either edge crosses masked, by both
-            pl.when((jk >= lo_end) & (jk < n_full))(lambda: accumulate(False))
-            pl.when(((jk >= n_lo) & (jk < lo_end)) | ((jk >= n_full) & (jk < n_vis)))(lambda: accumulate(True))
-
-        @pl.when(last)
+        @pl.when((head % group == group - 1) & (tile == n_tiles - 1))
         def _():
             dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
             dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
     vma = vma_of(q)
     rows, keys, stat = _attention_specs(T, tq, kv_of)
-    q3, k3, v3 = q.reshape(BH, T, D), k.reshape(BHkv, T, D), v.reshape(BHkv, T, Dv)
-    do3 = do.reshape(BH, T, Dv)
-    dq, delta = pl.pallas_call(
-        dq_kernel,
-        grid=(BH, T // tq),
+    dq, dk, dv = pl.pallas_call(
+        kernel,
+        grid=(BH, n_tiles),
         in_specs=[rows(D), keys(D), keys(Dv), rows(Dv), rows(Dv), stat],
-        out_specs=[rows(D), stat],
-        scratch_shapes=[pltpu.VMEM((tq, D), jnp.float32)],
+        out_specs=[rows(D), keys(D), keys(Dv)],
+        scratch_shapes=[pltpu.VMEM((tq, D), jnp.float32), pltpu.VMEM((T, D), jnp.float32),
+                        pltpu.VMEM((T, Dv), jnp.float32)],
         out_shape=[jax.ShapeDtypeStruct((BH, T, D), q.dtype, vma=vma),
-                   jax.ShapeDtypeStruct((BH, 1, T), jnp.float32, vma=vma)],
-        interpret=interpret,
-        compiler_params=_compiler_params(),
-        name=_kernel_name("bwd_dq", window, blocks),
-    )(q3, k3, v3, o.reshape(BH, T, Dv), do3, lse)
-
-    def q_tile(i, jk, jq):
-        head = i
-        if group > 1:  # the group's query heads one after another
-            head, jq = i * group + jq // n_q_dkv, jq % n_q_dkv
-        if blocks is not None:
-            return head, _bd_q_tile(jk, jq, tq_dkv, tk_dkv, blocks)
-        # q tiles before the first that sees this k tile are hidden: they name that first tile's block, so
-        # nothing is fetched for them; under a window so do those past the last that sees it
-        jq = jnp.maximum(jq, _chunks_upto(jk * tk_dkv, tq_dkv, n_q_dkv - 1))
-        if window is not None:
-            jq = jnp.minimum(jq, _chunks_upto((jk + 1) * tk_dkv + window - 2, tq_dkv, n_q_dkv - 1))
-        return head, jq
-
-    def kmat(width):
-        return pl.BlockSpec((1, tk_dkv, width), lambda i, jk, jq: (i, jk, 0), memory_space=pltpu.VMEM)
-
-    def qmat(width):
-        return pl.BlockSpec((1, tq_dkv, width), lambda *ids: (*q_tile(*ids), 0), memory_space=pltpu.VMEM)
-
-    def qstat(*ids):
-        head, jq = q_tile(*ids)
-        return head, 0, jq
-
-    qrow = pl.BlockSpec((1, 1, tq_dkv), qstat, memory_space=pltpu.VMEM)
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=(BHkv, n_k_dkv, group * n_q_dkv),
-        in_specs=[kmat(D), kmat(Dv), qmat(D), qmat(Dv), qrow, qrow],
-        out_specs=[kmat(D), kmat(Dv)],
-        scratch_shapes=[pltpu.VMEM((tk_dkv, D), jnp.float32), pltpu.VMEM((tk_dkv, Dv), jnp.float32)],
-        out_shape=[jax.ShapeDtypeStruct((BHkv, T, D), k.dtype, vma=vma),
+                   jax.ShapeDtypeStruct((BHkv, T, D), k.dtype, vma=vma),
                    jax.ShapeDtypeStruct((BHkv, T, Dv), v.dtype, vma=vma)],
         interpret=interpret,
         compiler_params=_compiler_params(),
         name=_kernel_name("bwd_dkv", window, blocks),
-    )(k3, v3, q3, do3, lse, delta)
+    )(q.reshape(BH, T, D), k.reshape(BHkv, T, D), v.reshape(BHkv, T, Dv), o.reshape(BH, T, Dv),
+      do.reshape(BH, T, Dv), lse)
     return dq.reshape(B, H, T, D), dk.reshape(B, Hkv, T, D), dv.reshape(B, Hkv, T, Dv)
 
 

@@ -287,7 +287,7 @@ def test_the_fit_counts_its_latent_layers_and_the_modules_targets(fitted, df):
     folds too), ``train.drain``'s module counts and held rows, the counters."""
     est, _, spans = fitted
     program, drain = spans["train.program"], spans["train.drain"]
-    full = np.asarray(flash.fold_chunk_counts(T, T, 0, True))
+    full = np.asarray(flash.fold_chunk_counts(T, T, 0, True, one_block=True))
     assert (program["layers_latent"], program["mtp_depth"]) == (CFG.n_layers + 1, 1)
     assert (program["fold_chunks_visited"], program["fold_chunks"]) == tuple((CFG.n_layers + 1) * 4 * BATCH * full)
     assert program["latent_bytes"] == (CFG.n_layers + 1) * BATCH * T * (32 + 8) * 4

@@ -236,8 +236,8 @@ def test_the_fit_counts_its_windowed_layers_and_its_held_rows(fitted, df):
     held and absent rows and what the three expert layers carried, and the counters."""
     est, _, spans = fitted
     program, drain = spans["train.program"], spans["train.drain"]
-    full = np.asarray(flash.fold_chunk_counts(T, T, 0, True))
-    win = np.asarray(flash.fold_chunk_counts(T, T, 0, True, 96))
+    full = np.asarray(flash.fold_chunk_counts(T, T, 0, True, one_block=True))
+    win = np.asarray(flash.fold_chunk_counts(T, T, 0, True, 96, one_block=True))
     assert (program["layers_windowed"], program["layers_full"]) == (2, 2)
     assert (program["fold_win_chunks_visited"], program["fold_win_chunks"]) == tuple(2 * 8 * BATCH * win)
     assert (program["fold_chunks_visited"], program["fold_chunks"]) == tuple(2 * 8 * BATCH * win + 2 * 4 * BATCH * full)

@@ -243,7 +243,7 @@ def test_the_fit_counts_its_mixers_its_chunks_and_its_held_rows(fitted, df):
     assert (program["layers_scan"], program["layers_attn"], program["layers_moe"]) == (4, 1, 4)
     assert program["scan_chunks"] == 4 * BATCH * CFG.ssm_heads * (T // CFG.chunk)
     assert program["scan_state_bytes"] == 4 * BATCH * (T // CFG.chunk) * CFG.ssm_heads * CFG.ssm_head_dim * CFG.ssm_state
-    full = np.asarray(flash.fold_chunk_counts(T, T, 0, True))
+    full = np.asarray(flash.fold_chunk_counts(T, T, 0, True, one_block=True))
     assert (program["fold_chunks_visited"], program["fold_chunks"]) == tuple(CFG.n_heads * BATCH * full)
     assert "layers_windowed" not in program
     layers = 4

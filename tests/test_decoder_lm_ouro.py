@@ -298,7 +298,12 @@ def test_bad_sizes_are_refused(df):
 
 
 #: kind -> (configuration, compute type, sha256 of ``str(make_jaxpr(step))`` with addresses blanked, its ``while``
-#: loops). All six were re-pinned at PR 46, where ``decoder_lm._fold`` took the fold's one-block form
+#: loops). All six were re-pinned at PR 48, where the one-block form's backward became ONE kernel
+#: (``flash._attention_bwd_pallas``): every kind's step changed under the ``fold`` scope (a layer's two backward
+#: ``pallas_call``s and the ``delta`` between them are one call) and nowhere else: flattened into the multiset of its
+#: equations as below, each kind's step outside ``fold`` is the multiset of 5276c5f (PR 47), equation for equation
+#: (1,739 / 908 / 3,642 / 1,646 / 1,788 / 5,128 equations: CHANGES.md, PR 48). Before that they
+#: were re-pinned at PR 46, where ``decoder_lm._fold`` took the fold's one-block form
 #: (``flash.fused_attention``): every kind's step changed under the ``fold`` scope (180 equations to 88 in the two-layer
 #: ``olmoe``: the zero fills, ``acc / l``, the statistics' reshapes and the float32 ``dacc``, ``dl`` are gone) and
 #: nowhere else: flattened into the multiset of its equations as below, each kind's step outside ``fold`` is the
@@ -314,15 +319,15 @@ def test_bad_sizes_are_refused(df):
 #: (PR 42) and the two ``olmoe`` cases and ``ouro`` what PR 43 pinned (one attention function for every kind).
 STEP_JAXPRS = {
     "olmoe": (LMConfig(n_layers=2, hidden=128, n_heads=4, n_experts=8, top_k=2, expert_width=64, vocab=512),
-              "float32", "caeaacf5c766af80eff1cf7432ae73f56bc36545f4a08c04a558eb1e45252f11", 0),
+              "float32", "1a13326309ee8838e98d42a672c1b5c1f19fe7838c87cfe3a4d688299a313179", 0),
     "olmoe_one_layer_bfloat16": (
         LMConfig(n_layers=1, hidden=128, n_heads=4, n_experts=8, top_k=2, expert_width=64, vocab=512),
-        "bfloat16", "db4c12e59dde10c915b43813761c415df7307d3296c07a98bdc9998b33259047", 0),
+        "bfloat16", "db859f0d20b1365949b422457da32fe857d05760d54a5e2cf01d514bd141d869", 0),
     "zaya": (LMConfig(n_layers=2, hidden=128, n_heads=4, n_experts=8, top_k=1, expert_width=64, vocab=512,
                       rope_theta=5e6, aux_coef=0.0, block="zaya", tied=True, experts_held=4, first_held=2,
                       n_kv_heads=2, head_size=16, rope_fraction=0.5, router_width=32),
-             "float32", "213421232334cf7bb72d9d0e9d482bcd868a84f376c1e834dfd65dbbe5b4b559", 0),
-    "ouro": (CFG, "float32", "8a9c162382c445998b87daedde5e76be826b0fb3a60d8492b11dfc08d3ac6fc2", 0),
+             "float32", "b4c50cf761b0ddc0cf2e631af33c2f4aa258e5f8c79810330038fe6bcd32d68e", 0),
+    "ouro": (CFG, "float32", "46afbb6119933b540a67af0cb711a282c28d6ad5a0332baa698a5631175f74b8", 0),
     # an eighth of the experts held, so the expert layer takes its sorted rows a window at a time, a loop of a
     # traced length a direction (``parallel/moe.py``; the recomputed forward's is unused, and gone), which the
     # kinds above, with every expert or a half of them held, must not hold
@@ -330,14 +335,14 @@ STEP_JAXPRS = {
                         aux_coef=0.0, block="laguna", experts_held=2, first_held=2, n_kv_heads=2, head_size=16,
                         rope_fraction=0.5, layer_heads=(4, 8), layer_windows=(0, 96), n_dense=1, dense_width=96,
                         shared_width=32, routed_scale=2.5, window_rope_theta=1e4),
-               "float32", "84bed627cb401217235a48a7ff03ce96caf2006dd3b321d4af03ad17e47ed96e", 2),
+               "float32", "ecfe65ff537b1fa78beda24079f68e87b06ef78e75a56670e3c3c40d9755f0e5", 2),
     # its four expert layers take their rows in windows too: 5 ``while``s in the text, as at 512ebfa
     "nemotron_h": (LMConfig(n_layers=9, hidden=64, n_heads=4, n_experts=16, top_k=2, expert_width=32, vocab=512,
                             norm_eps=1e-5, aux_coef=0.0, block="nemotron_h", experts_held=2, first_held=2,
                             n_kv_heads=2, head_size=16, shared_width=48, routed_scale=2.5,
                             layer_kinds=tuple("MEMEM*EME"), ssm_heads=8, ssm_head_dim=8, ssm_groups=2, ssm_state=16,
                             conv_kernel=4, chunk=64),
-                   "float32", "09eda968eb876fe4c118a947ddcfa522cfaafc4acf3b0d1f9044c98f17f7311c", 5),
+                   "float32", "316b7c70ddc39b459032250cdf89f9bb59cffe281f90b4e058326870683b4e8c", 5),
 }
 
 

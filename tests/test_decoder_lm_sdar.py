@@ -303,7 +303,8 @@ def test_the_fit_counts_its_doubled_folds_and_the_positions_it_scored(fitted, df
     assert (program["layers_diffusion"], program["diffusion_block"]) == (CFG.n_layers, 4)
     assert (program["fold_bd_chunks_visited"], program["fold_bd_chunks"]) == (scale * visited, scale * total)
     assert (program["fold_chunks_visited"], program["fold_chunks"]) == (scale * visited, scale * total)
-    assert program["positions"] == BATCH * 2 * T and program["fold_one_block"] >= 1 and program["fold_row_stats"] == 5
+    assert program["positions"] == BATCH * 2 * T and program["fold_one_block"] >= 1 and program["fold_row_stats"] == 2
+    assert program["fold_bwd_kernels"] == 1
     assert "layers_windowed" not in program and "layers_latent" not in program
     assert drain["tokens"] == STEPS * BATCH * T and drain["positions"] == 2 * drain["tokens"]
     assert drain["targets_masked"] == sum(est.targets_masked_history) and 0 < drain["targets_masked"] < drain["tokens"]

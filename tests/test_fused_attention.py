@@ -2,7 +2,8 @@
 of one), interpreted on the CPU at toy sizes and shrunken tiles: against
 ``jax.grad`` of plain float32 softmax attention (scores, mask, ``softmax``,
 ``@ v``), against the parent's ``_fold`` (the ring entry fed zeros, ``acc / l``
-outside), and the two counts ``train.program`` reads off the step as traced.
+outside), the ONE backward kernel over every mask form, group, head size and
+walk, and the counts ``train.program`` reads off the step as traced.
 """
 import jax
 import jax.numpy as jnp
@@ -14,12 +15,13 @@ from tests.test_decoder_lm_head import _noise, _traced_step
 from flink_ml_tpu.models.lm import decoder_lm
 from flink_ml_tpu.parallel import flash
 
-CHUNK = 256  # the shrunken tiles: forward and dq rows, key chunk, the dkv pair
+CHUNK = 256  # the shrunken tiles: the forward's and the backward's rows and key chunks (and the ring form's dkv pair)
+TILES = ("_TQ_CAUSAL", "_KEY_CHUNK", "_BWD_KEY_CHUNK", "_DKV_CAUSAL")
 
 
 @pytest.fixture
 def small_tiles(monkeypatch):
-    for name in ("_TQ_CAUSAL", "_KEY_CHUNK", "_DKV_CAUSAL"):
+    for name in TILES:
         monkeypatch.setattr(flash, name, CHUNK)
 
 
@@ -140,6 +142,64 @@ def test_a_window_holds_at_least_the_query():
         flash.fused_attention(q, k, v, 1.0, 0, True)
 
 
+def _dense(q, k, v, scale, keep):
+    """Softmax attention under a dense boolean mask, K and V repeated over their groups."""
+    group = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    s = jnp.where(jnp.asarray(keep), jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
+
+
+#: walk -> (positions, shrunken tiles): 512 positions are one tile and one chunk of the backward's own (the block in one
+#: piece); 1,024 at tiles and chunks of 256 are four tiles that walk one to four chunks each
+WALKS = {"one-chunk": (512, False), "four-chunks": (1024, True)}
+#: mask -> (window, block-diffusion block) at ``positions``: the window is 512 keys on the walk of four chunks
+MASKS = {"causal": lambda n: (None, None), "window-512": lambda n: (n // 2, None),
+         "block-diffusion": lambda n: (None, flash.BlockDiffusion(n // 2, 4))}
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5), (jnp.bfloat16, 3e-2)], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("walk", sorted(WALKS))
+@pytest.mark.parametrize("d,d_v", [(16, 16), (192, 128)], ids=["equal-heads", "heads-192-128"])
+@pytest.mark.parametrize("group", [1, 8])
+@pytest.mark.parametrize("mask", sorted(MASKS))
+def test_the_one_backward_kernel_is_jax_ad_of_dense_masked_softmax(mask, group, d, d_v, walk, dtype, tol, monkeypatch):
+    """``dq``, ``dk`` and ``dv`` out of the ONE walk against ``jax.vjp`` of the
+    dense mask's softmax in float32: every mask form, a group of one and of
+    eight query heads on a key/value head (whose ``dk`` and ``dv`` are summed
+    in the kernel over the group's cells), a value head of its own size, the
+    block in one piece and a walk of up to four chunks (the hidden ones
+    skipped, the head's accumulators added into at each chunk's rows)."""
+    positions, shrunken = WALKS[walk]
+    if shrunken:
+        for name in TILES:
+            monkeypatch.setattr(flash, name, CHUNK)
+    window, blocks = MASKS[mask](positions)
+    qkv, cot = _inputs(1, group, 1, positions, d, d_v, seed=group + d)
+    scale = d ** -0.5
+    keep = np.asarray(flash._kept(positions, positions, 0, 0, True, None, window, blocks))
+    with jax.default_matmul_precision("highest"):
+        want = _out_and_grads(lambda q, k, v: _dense(q, k, v, scale, keep), qkv, cot)
+    low = tuple(x.astype(dtype) for x in qkv)
+    got = _out_and_grads(lambda q, k, v: flash.fused_attention(q, k, v, scale, window, True, blocks), low, cot)
+    assert got[0].dtype == jnp.float32 and all(g.dtype == dtype for g in got[1:])
+    for name, g, w in zip(("o", "dq", "dk", "dv"), got, want):
+        assert g.shape == w.shape, name  # dk at q's channels, dv at v's, one key/value head
+        assert _worst([g], [w]) < tol, name
+
+
+@pytest.mark.parametrize("mask", sorted(MASKS))
+def test_the_backward_is_one_kernel_call(mask):
+    """The VJP as traced: one ``pallas_call``, named as the dkv kernel was (the
+    benchmark's patterns read the fold's time by name), that takes ``lse`` and
+    hands back no statistic: ``delta`` is formed in the cells."""
+    window, blocks = MASKS[mask](1024)
+    qkv, cot = _inputs(1, 2, 1, 1024, 16, 16)
+    _, vjp = jax.vjp(lambda q, k, v: flash.fused_attention(q, k, v, 0.25, window, True, blocks), *qkv)
+    calls = flash.fold_kernel_calls(jax.make_jaxpr(vjp)(cot).jaxpr)
+    assert calls == [("bwd_dkv", 1)]
+
+
 def _attention_layers(cfg) -> int:
     """The layers of ``cfg`` that attend, as a step traces them (a looped stack's once; the module's layer too)."""
     from flink_ml_tpu.models.lm.config import Attention, CCA, LatentAttention, layers, mtp_layer
@@ -151,16 +211,17 @@ def _attention_layers(cfg) -> int:
 @pytest.mark.parametrize("compute_type", ["float32", "bfloat16"])
 @pytest.mark.parametrize("kind", sorted(test_lm_scopes.KINDS))
 def test_every_attending_layer_goes_through_the_one_block_form(kind, compute_type):
-    """``train.program``'s ``fold_one_block`` and ``fold_row_stats``, read off
-    the step as traced: every attention layer, and the five row statistics of
-    the one-block form (``lse`` out of the forward; ``lse`` into and ``delta``
-    out of the dq kernel; both into the dkv kernel) where the ring's carried
-    state made seventeen."""
+    """``train.program``'s ``fold_one_block``, ``fold_bwd_kernels`` and
+    ``fold_row_stats``, read off the step as traced: every attention layer,
+    ONE backward kernel a layer, and the two row statistics of the one-block
+    form (``lse`` out of the forward and into the backward; ``delta`` never
+    leaves a cell) where the ring's carried state made seventeen."""
     cfg = test_lm_scopes.KINDS[kind][0]
     step, *shapes = _traced_step(cfg, compute_type)
     counts = decoder_lm._traced_counts(step, *shapes, cfg, *_noise(cfg))
     assert counts["fold_one_block"] == _attention_layers(cfg) > 0
-    assert counts["fold_row_stats"] == sum(flash.ONE_BLOCK_ROW_STATS.values()) == 5
+    assert counts["fold_row_stats"] == sum(flash.ONE_BLOCK_ROW_STATS.values()) == 2
+    assert counts["fold_bwd_kernels"] == 1
     jaxpr = step.trace(*shapes, jax.ShapeDtypeStruct((), jnp.int32), *_noise(cfg)).jaxpr.jaxpr
     calls = flash.fold_kernel_calls(jaxpr)
     assert {part for part, _ in calls} == set(flash.ONE_BLOCK_ROW_STATS)
@@ -230,14 +291,6 @@ def _bd_rules(t, block, defect=None):
     return np.block([[clean_clean, clean_noised], [noised_clean, noised_noised]])
 
 
-def _masked(q, k, v, scale, keep):
-    """Softmax attention under a dense boolean mask, K and V repeated over their groups."""
-    group = q.shape[1] // k.shape[1]
-    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
-    s = jnp.where(jnp.asarray(keep), jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale, -jnp.inf)
-    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
-
-
 #: name -> (batch, query heads, key/value heads, tokens T, D, block, shrunken tiles): the fold runs over 2 T positions.
 #: At the kernels' own tiles T 768 is 1,536 positions in three tiles and chunks of 512, the middle ones half clean and
 #: half noised; T 1,024 has tiles of 512 on two chunks of 1,024 that end where the halves do; with tiles of 256 T 384
@@ -259,7 +312,7 @@ BD_CASES = {
 def test_the_block_diffusion_fold_is_dense_masked_softmax_and_its_gradients(case, monkeypatch):
     b, h, h_kv, t, d, block, shrunken = BD_CASES[case]
     if shrunken:
-        for name in ("_TQ_CAUSAL", "_KEY_CHUNK", "_DKV_CAUSAL"):
+        for name in TILES:
             monkeypatch.setattr(flash, name, CHUNK)
     qkv, cot = _inputs(b, h, h_kv, 2 * t, d, d, seed=sorted(BD_CASES).index(case))
     scale, blocks = d ** -0.5, flash.BlockDiffusion(t, block)
@@ -267,7 +320,7 @@ def test_the_block_diffusion_fold_is_dense_masked_softmax_and_its_gradients(case
     assert keep.diagonal().all() and keep.sum() == t * t + t * block  # every row keeps itself; T^2 + T L pairs
     np.testing.assert_array_equal(np.asarray(flash._kept(2 * t, 2 * t, 0, 0, True, None, None, blocks)), keep)
     with jax.default_matmul_precision("highest"):
-        want = _out_and_grads(lambda q, k, v: _masked(q, k, v, scale, keep), qkv, cot)
+        want = _out_and_grads(lambda q, k, v: _dense(q, k, v, scale, keep), qkv, cot)
     got = _out_and_grads(lambda q, k, v: flash.fused_attention(q, k, v, scale, None, True, blocks), qkv, cot)
     assert got[0].dtype == jnp.float32
     for name, g, w in zip(("o", "dq", "dk", "dv"), got, want):
@@ -291,7 +344,7 @@ def test_each_rule_of_the_block_diffusion_mask_is_told_apart(rule):
     scale = d ** -0.5
     got = flash.fused_attention(*qkv, scale, None, True, flash.BlockDiffusion(t, block))
     with jax.default_matmul_precision("highest"):
-        sound, wrong = (_masked(*qkv, scale, _bd_rules(t, block, defect)) for defect in (None, rule))
+        sound, wrong = (_dense(*qkv, scale, _bd_rules(t, block, defect)) for defect in (None, rule))
     assert _worst([got], [sound]) < 1e-5 < 1e-2 < _worst([got], [wrong])
 
 
@@ -301,7 +354,7 @@ def test_bfloat16_operands_under_the_block_diffusion_mask():
     scale, blocks = d ** -0.5, flash.BlockDiffusion(t, block)
     low = tuple(x.astype(jnp.bfloat16) for x in qkv)
     with jax.default_matmul_precision("highest"):
-        want = _out_and_grads(lambda q, k, v: _masked(q, k, v, scale, _bd_rules(t, block)), qkv, cot)
+        want = _out_and_grads(lambda q, k, v: _dense(q, k, v, scale, _bd_rules(t, block)), qkv, cot)
     got = _out_and_grads(lambda q, k, v: flash.fused_attention(q, k, v, scale, None, True, blocks), low, cot)
     assert got[0].dtype == jnp.float32 and all(g.dtype == jnp.bfloat16 for g in got[1:])
     assert _worst(got, want) < 3e-2
@@ -312,36 +365,22 @@ def test_bfloat16_operands_under_the_block_diffusion_mask():
                                      (1024, 512), (256, 4), (128, 4)])
 def test_fold_chunk_counts_under_the_block_diffusion_mask_is_a_count_of_the_mask(t, block):
     """``fold_chunk_counts`` against the mask itself, brute force: a (query
-    tile, key chunk) pair is visited iff the mask keeps an entry of it, at each
-    kernel's tiles (a block of one chunk is taken whole by the forward and the
-    dq kernel). At the cell's size the walk visits 37.5% of the pairs where a
-    causal walk of the same 8,192 positions visits 56.25%."""
+    tile, key chunk) pair is visited iff the mask keeps an entry of it, at the
+    forward's tiles and at the one backward kernel's (a block of one chunk of
+    its own is taken whole by either). At the cell's size the forward visits
+    37.5% of its pairs and the backward, at chunks half as long, 31.25%, where
+    a causal walk of the same 8,192 positions visits 56.25% and 53.1%."""
     p = 2 * t
     keep = _bd_rules(t, block)
-    tq_fwd, tq_dq, tq_dkv, tk_dkv, kc = flash._fold_tiles(p, p, True)
+    tq, kc, tq_bwd, kc_bwd = flash._attention_tiles(p)
     visited = total = 0
-    for rows, keys, skips in ((tq_fwd, kc, kc < p), (tq_dq, kc, kc < p), (tq_dkv, tk_dkv, True)):
+    for rows, keys in ((tq, kc), (tq_bwd, kc_bwd)):
         pairs = keep.reshape(p // rows, rows, p // keys, keys).any(axis=(1, 3))
-        visited, total = visited + (int(pairs.sum()) if skips else pairs.size), total + pairs.size
+        visited, total = visited + (int(pairs.sum()) if keys < p else pairs.size), total + pairs.size
     assert flash.fold_chunk_counts(p, p, 0, True, None, flash.BlockDiffusion(t, block)) == (visited, total)
     if t == 4096:
-        assert visited / total == 0.375 and flash.fold_chunk_counts(p, p, 0, True)[0] / total == 0.5625
-
-
-def test_the_dkv_kernels_query_map_names_only_tiles_the_key_tile_sees():
-    """Under the block-diffusion mask the dkv kernel's grid step ``(jk, jq)``
-    fetches the query tile ``_bd_q_tile`` names: ``jq`` itself wherever the
-    pair holds a kept entry, and elsewhere a tile that does (so a hidden pair
-    fetches nothing new); where the tiles do not divide the halves, ``jq``."""
-    for t, block, tile in ((4096, 4, 1024), (2048, 32, 1024), (1024, 1024, 1024), (512, 4, 256)):
-        blocks, n = flash.BlockDiffusion(t, block), 2 * t // tile
-        seen = _bd_rules(t, block).reshape(n, tile, n, tile).any(axis=(1, 3))  # [query tile, key tile]
-        for jk in range(n):
-            named = [int(flash._bd_q_tile(jnp.int32(jk), jnp.int32(jq), tile, tile, blocks)) for jq in range(n)]
-            assert all(seen[named[jq], jk] for jq in range(n)), (t, block, jk, named)
-            assert all(named[jq] == jq for jq in range(n) if seen[jq, jk]), (t, block, jk, named)
-            assert named == sorted(named)  # a run of equal names is fetched once
-    assert int(flash._bd_q_tile(jnp.int32(1), jnp.int32(0), 512, 512, flash.BlockDiffusion(768, 4))) == 0
+        assert (visited, total) == (48 + 80, 128 + 256)
+        assert flash.fold_chunk_counts(p, p, 0, True, one_block=True) == (72 + 136, 128 + 256)
 
 
 def test_a_block_diffusion_mask_the_counts_are_not_written_for_is_refused():

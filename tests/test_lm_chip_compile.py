@@ -8,6 +8,8 @@ scoped-VMEM limit - at no chip time. Nothing runs; no time is measured.
 All such tests live in this one file, and the topology is described inside a
 fixture: only the worker that is given this file loads the TPU's library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -143,12 +145,15 @@ def _one_block_shapes(fold, dtype, one_chip):
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16", "float32"])
 @pytest.mark.parametrize("fold", sorted(ONE_BLOCK))
 def test_fused_attention_trains_at_the_cells_shapes(one_chip, dtype, fold):
-    """The one-block form's forward and both backward kernels in one training
-    graph at every LM cell's fold: Mosaic takes the two row statistics along
-    the lanes (``[B x H, 1, T]``: the column-to-row move inside the cells),
-    the dkv kernel's transposed pair and the dq kernel's one walk; the kernels
-    keep the ring form's names; ``dq``, ``dk``, ``dv`` leave in the operands'
-    type; no statistic is a ``[B x H, T, 1]`` column (128 times its bytes)."""
+    """The one-block form's forward and its ONE backward kernel in one training
+    graph at every LM cell's fold: Mosaic takes the row statistic along the
+    lanes (``[B x H, 1, T]``: the column-to-row move inside the cells), the
+    backward's one walk on transposed scores with its one transposed left
+    operand (``dq += (ds^T)^T k``), the key/value head's whole ``dk`` and ``dv`` waiting in
+    VMEM in float32 (10.5 MB at 8,192 x (192 + 128)) under the kernels' stated
+    limit; the backward keeps the dkv kernel's name and there is no dq kernel;
+    ``dq``, ``dk``, ``dv`` leave in the operands' type; no statistic is a ``[B
+    x H, T, 1]`` column (128 times its bytes), and ``lse`` is the only one."""
     from flink_ml_tpu.parallel.flash import fused_attention
 
     shapes, scale, window = _one_block_shapes(fold, dtype, one_chip)
@@ -160,11 +165,11 @@ def test_fused_attention_trains_at_the_cells_shapes(one_chip, dtype, fold):
 
     text = _compile(grads, *shapes).as_text()
     named = "flash_fold_win_" if window else "flash_fold_"
-    for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
+    for kernel in ("fwd", "bwd_dkv"):
         assert named + kernel in text
-    assert window is None or "flash_fold_fwd" not in text
+    assert "bwd_dq" not in text and (window is None or "flash_fold_fwd" not in text)
     assert f"f32[{b * h},{t},{t}]" not in text and f"f32[{b * h},{t},1]" not in text
-    assert f"f32[{b * h},1,{t}]" in text  # lse and delta
+    assert len(set(re.findall(rf"%\S+ = f32\[{b * h},1,{t}\]", text))) == 1  # lse, and no delta beside it
     dq, dk, dv = jax.eval_shape(grads, *shapes)
     assert (dq.shape, dk.shape, dv.shape) == ((b, h, t, d), (b, h_kv, t, d), (b, h_kv, t, d_v))
     assert dq.dtype == dk.dtype == dv.dtype == dtype
@@ -177,8 +182,9 @@ def test_fused_attention_trains_under_the_block_diffusion_mask(one_chip, dtype, 
     heads on 4 key/value heads x 8,192 positions (4,096 tokens and their
     noised copies) x 128. Mosaic takes the mask's block ids worked out on one
     column of rows and one row of keys, the walk's two ranges (the clean
-    chunks, the band) and the dkv kernel's two-interval query map; the kernels
-    carry names of their own and the row statistics lie along the lanes."""
+    chunks, the band) in the forward and in the one backward kernel, a group of
+    eight query heads' cells adding into one key/value head's ``dk`` and
+    ``dv``; the kernels carry names of their own and ``lse`` lies along the lanes."""
     from flink_ml_tpu.parallel.flash import BlockDiffusion, fused_attention
 
     b, h, h_kv, t = 2, 32, 4, 8192
@@ -191,11 +197,11 @@ def test_fused_attention_trains_under_the_block_diffusion_mask(one_chip, dtype, 
                         argnums=(0, 1, 2))(q, k, v)
 
     text = _compile(grads, q, kv, kv).as_text()
-    for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
+    for kernel in ("fwd", "bwd_dkv"):
         assert "flash_fold_bd_" + kernel in text
-    assert "flash_fold_fwd" not in text and "flash_fold_win_" not in text
+    assert "flash_fold_fwd" not in text and "flash_fold_win_" not in text and "bwd_dq" not in text
     assert f"f32[{b * h},{t},{t}]" not in text and f"f32[{b * h},{t},1]" not in text
-    assert f"f32[{b * h},1,{t}]" in text  # lse and delta
+    assert f"f32[{b * h},1,{t}]" in text  # lse
     dq, dk, dv = jax.eval_shape(grads, q, kv, kv)
     assert (dq.shape, dk.shape, dv.shape) == ((b, h, t, D), (b, h_kv, t, D), (b, h_kv, t, D))
     assert dq.dtype == dk.dtype == dv.dtype == dtype
@@ -348,7 +354,7 @@ def test_the_zaya_step_program_at_the_cells_shapes(one_chip):
     blocks) of the ``zaya1_8b`` configuration at 2 x 8,192 tokens: it fits the
     chip (XLA's analysis, which counts what ``peak_bytes_in_use`` does not), the
     held range's grouped matmuls are the grouped kernel in both directions and
-    the three fold kernels are there at ``H_kv`` 2, T 8,192."""
+    the fold's two kernels (the forward, the one backward) are there at ``H_kv`` 2, T 8,192."""
     from flink_ml_tpu.models.lm.config import num_params
 
     c, cfg = _zaya_cut()
@@ -359,8 +365,9 @@ def test_the_zaya_step_program_at_the_cells_shapes(one_chip):
     kernels = [ln for ln in text.splitlines() if ln.lstrip().startswith("%ragged-dot") and "custom-call(" in ln]
     assert len(kernels) >= 8 * cfg.n_layers
     assert "convolution_select_fusion" not in text
-    for kernel in ("flash_fold_fwd", "flash_fold_bwd_dq", "flash_fold_bwd_dkv"):
+    for kernel in ("flash_fold_fwd", "flash_fold_bwd_dkv"):
         assert kernel in text
+    assert "bwd_dq" not in text  # one backward kernel a fold
     kv = f"bf16[{batch * cfg.kv_heads},{t},{cfg.head_dim}]"
     assert kv in text  # K and V enter the kernels once per key/value head
     assert f"f32[{batch},{cfg.n_heads},{t},{t}]" not in text and f"f32[{batch * cfg.n_heads},{t},{t}]" not in text
@@ -385,7 +392,7 @@ def test_the_laguna_step_program_at_the_cells_shapes(one_chip):
     where the one-block form moves two along the lanes; 6.31 GB and 14.61e9 B
     until PR 45: the head's ``dW`` is one float32 ``[d, V]`` from
     the head's forward to AdamW where the parent's backward kept it in
-    bfloat16 and XLA fused the cast into its readers); the windowed layers' three kernels are there under their own
+    bfloat16 and XLA fused the cast into its readers); the windowed layers' two kernels are there under their own
     names beside the full layers'; K and V enter once per key/value head; the
     32 held experts' grouped matmuls are the grouped kernel in both
     directions, over a window of 16,384 sorted rows at a time: no float32
@@ -396,12 +403,12 @@ def test_the_laguna_step_program_at_the_cells_shapes(one_chip):
     assert num_params(cfg) == 691_624_960  # 11.07 GB of f32 state at 16 bytes a parameter: 69% of 16 GB
     batch, t = c["global_batch_size"], c["sequence_length"]
     compiled, memory = _compiled_step(c, cfg, one_chip)
-    # what it reads and 1%; 6.41e9 until PR 46 (the fold's padded row statistics), 6.31e9 until PR 45
-    assert memory.temp_size_in_bytes < 5.38e9, memory.temp_size_in_bytes
+    # what it reads and 1%: 5.27e9 since PR 48 (one backward kernel a fold); 5.32e9 until then, 6.41e9 until PR 46
+    assert memory.temp_size_in_bytes < 5.33e9, memory.temp_size_in_bytes
     text = compiled.as_text()
-    for kernel in ("flash_fold_fwd", "flash_fold_bwd_dq", "flash_fold_bwd_dkv",
-                   "flash_fold_win_fwd", "flash_fold_win_bwd_dq", "flash_fold_win_bwd_dkv"):
+    for kernel in ("flash_fold_fwd", "flash_fold_bwd_dkv", "flash_fold_win_fwd", "flash_fold_win_bwd_dkv"):
         assert kernel in text
+    assert "bwd_dq" not in text  # one backward kernel a fold, windowed or full
     kernels = [ln for ln in text.splitlines() if ln.lstrip().startswith("%ragged-dot") and "custom-call(" in ln]
     assert len(kernels) >= 8 * (cfg.n_layers - cfg.n_dense)
     assert "convolution_select_fusion" not in text
@@ -448,7 +455,7 @@ def test_the_nemotron_step_program_at_the_cells_shapes(one_chip, monkeypatch):
     array of the program. The scan is its two kernels by name: no ``[chunk,
     chunk]`` block of decays of 64 chunks a head is an array of the program,
     nor is a state a POSITION, and the state a chunk starts from is saved once
-    a Mamba-2 layer, for the backward; the fold's three kernels take K and V
+    a Mamba-2 layer, for the backward; the fold's two kernels take K and V
     once per key/value head; the held experts' grouped matmuls are the grouped
     kernel in both directions (two matrices an expert: a forward, a recomputed
     forward, two ``dX`` and two ``dW`` a layer at the least), over a window of
@@ -462,8 +469,9 @@ def test_the_nemotron_step_program_at_the_cells_shapes(one_chip, monkeypatch):
     for module in (ssd, causal_conv):  # the backend here is the CPU; the target is the chip
         monkeypatch.setattr(module, "_interpreted", lambda: False)
     compiled, memory = _compiled_step(c, cfg, one_chip)
-    # what it reads and 1%: the schedule's own peak, nothing rematerialised by XLA to fit (PR 46); squeezed to fit it
-    # read 7.15e9 until then, 7.13e9 until PR 45, 7.45e9 until PR 42
+    # what it reads and 1%: the schedule's own peak, nothing rematerialised by XLA to fit (PR 46; 7,349,152,768 B with
+    # PR 48's one backward kernel, 32 KB under its parent); squeezed to fit it read 7.15e9 until then, 7.13e9 until
+    # PR 45, 7.45e9 until PR 42
     assert memory.temp_size_in_bytes < 7.43e9, memory.temp_size_in_bytes
     assert ".remat" not in compiled.as_text()
     step, shapes = _step_and_shapes(c, cfg, one_chip)
@@ -471,9 +479,10 @@ def test_the_nemotron_step_program_at_the_cells_shapes(one_chip, monkeypatch):
     # of the chip's 15.75 GiB (16.91e9 B): what it reads and 1%; 15.86e9 until PR 45, 16.69e9 until PR 42
     assert unbudgeted.argument_size_in_bytes + unbudgeted.temp_size_in_bytes < 15.72e9
     text = compiled.as_text()
-    for kernel in ("flash_fold_fwd", "flash_fold_bwd_dq", "flash_fold_bwd_dkv", "ssd_scan_fwd", "ssd_scan_bwd",
+    for kernel in ("flash_fold_fwd", "flash_fold_bwd_dkv", "ssd_scan_fwd", "ssd_scan_bwd",
                    "causal_conv_fwd", "causal_conv_bwd"):
         assert kernel in text
+    assert "flash_fold_bwd_dq" not in text  # one backward kernel a fold
     assert "flash_fold_win_" not in text
     convolved = cfg.ssm_heads * cfg.ssm_head_dim + 2 * cfg.ssm_groups * cfg.ssm_state
     assert f"f32[{batch},{t + cfg.conv_kernel - 1},{convolved}]" not in text  # no padded copy of x, B and C
@@ -511,7 +520,7 @@ def test_the_joyai_step_program_at_the_cells_shapes(one_chip):
     the lanes; 7.26 GB and 15.42e9 B until PR 45, whose head holds its
     ``dW`` as one float32 ``[d, V]`` from the first head call's forward on
     where the parent's backward held it in bfloat16; at two sequences a step, ISSUE 44's first choice, the
-    compile still fails, by less: "Used 15.47G of 14.67G hbm", 17.40G until PR 46). The fold's three kernels are
+    compile still fails, by less: "Used 15.47G of 14.67G hbm", 17.40G until PR 46). The fold's two kernels are
     Mosaic's at a head of 192 query and key channels and 128 value channels,
     T 8,192, under their one set of names: K enters at ``[32, 8192, 192]`` and
     V at ``[32, 8192, 128]`` once a head, and no score tensor is an array of
@@ -523,11 +532,13 @@ def test_the_joyai_step_program_at_the_cells_shapes(one_chip):
     assert num_params(cfg) == 680_441_088  # 10.89 GB of f32 state at 16 bytes a parameter: 68% of 16 GB
     batch, t = c["global_batch_size"], c["sequence_length"]
     compiled, memory = _compiled_step(c, cfg, one_chip)
-    # what it reads and 1%; 7.39e9 until PR 46 (the fold's padded row statistics), 7.26e9 until PR 45
-    assert memory.temp_size_in_bytes < 6.24e9, memory.temp_size_in_bytes
+    # what it reads and 1%: 6.04e9 since PR 48 (the one backward kernel: no ``delta``, no second kernel's operands
+    # alive beside the first's); 6.18e9 until then, 7.39e9 until PR 46 (the fold's padded row statistics)
+    assert memory.temp_size_in_bytes < 6.10e9, memory.temp_size_in_bytes
     text = compiled.as_text()
-    for kernel in ("flash_fold_fwd", "flash_fold_bwd_dq", "flash_fold_bwd_dkv"):
+    for kernel in ("flash_fold_fwd", "flash_fold_bwd_dkv"):
         assert kernel in text
+    assert "bwd_dq" not in text  # one backward kernel a fold
     assert "flash_fold_win_" not in text
     heads, d_k, d_v = batch * cfg.n_heads, cfg.nope_dim + cfg.rope_dim, cfg.v_dim
     assert f"bf16[{heads},{t},{d_k}]" in text and f"bf16[{heads},{t},{d_v}]" in text
@@ -539,8 +550,6 @@ def test_the_joyai_step_program_at_the_cells_shapes(one_chip):
     routed = batch * t * cfg.top_k  # 65,536 routed rows a layer, a window of an eighth of them at a time
     assert f"f32[{routed},{cfg.hidden}]" not in text and f"bf16[{routed // 8},{cfg.hidden}]" in text
     # the module is in the step, by name: its layer's latents and its pass over the head under ``lm.mtp``
-    import re
-
     from perfbench.op_scopes import classify
 
     scopes = {classify(name, "lm.")[0] for name in set(re.findall(r'op_name="([^"]*lm\.mtp[^"]*)"', text))}
@@ -567,7 +576,7 @@ def test_the_sdar_step_program_at_the_cells_shapes(one_chip):
     15,020 MiB; told 14,800 MiB it comes back with the same 8.05 GB, its
     floor), 0.05e9 over the 15.75e9 the other cells' steps are held to, and
     the compiler does not refuse it: the chip run is what says that it fits
-    (PERF.md, PR 47). The fold's three kernels are the block-diffusion form's
+    (PERF.md, PR 47). The fold's two kernels are the block-diffusion form's
     alone, K and V enter once a key/value head at ``[8, 8192, 128]``, no score
     tensor is an array of the program, and the head's logits are ``[2048,
     18992]`` a chunk of the noised rows."""
@@ -577,11 +586,11 @@ def test_the_sdar_step_program_at_the_cells_shapes(one_chip):
     assert num_params(cfg) == 645_623_296  # 10.33 GB of f32 state at 16 bytes a parameter: 65% of 16 GB
     batch, t = c["global_batch_size"], c["sequence_length"]
     compiled, memory = _compiled_step(c, cfg, one_chip, held_to=15.85e9)
-    assert memory.temp_size_in_bytes < 8.13e9, memory.temp_size_in_bytes  # what it reads and 1%
+    assert memory.temp_size_in_bytes < 8.10e9, memory.temp_size_in_bytes  # what it reads (8.02e9; 8.05e9 until PR 48) and 1%
     text = compiled.as_text()
-    for kernel in ("flash_fold_bd_fwd", "flash_fold_bd_bwd_dq", "flash_fold_bd_bwd_dkv"):
+    for kernel in ("flash_fold_bd_fwd", "flash_fold_bd_bwd_dkv"):
         assert kernel in text
-    assert "flash_fold_fwd" not in text and "flash_fold_win_" not in text
+    assert "flash_fold_fwd" not in text and "flash_fold_win_" not in text and "bwd_dq" not in text
     positions = 2 * t
     assert f"bf16[{batch * cfg.kv_heads},{positions},{cfg.head_dim}]" in text  # K and V once per key/value head
     assert f"f32[{batch},{cfg.n_heads},{positions},{positions}]" not in text
@@ -592,8 +601,6 @@ def test_the_sdar_step_program_at_the_cells_shapes(one_chip):
     routed = batch * positions * cfg.top_k  # 131,072 routed rows a layer, a window of a quarter of them at a time
     assert f"f32[{routed},{cfg.hidden}]" not in text and f"bf16[{routed // 4},{cfg.hidden}]" in text
     assert f"[2048,{cfg.vocab}]" in text and f"[{batch * positions},{cfg.vocab}]" not in text
-    import re
-
     from perfbench.op_scopes import classify
 
     scopes = {classify(name, "lm.")[0] for name in set(re.findall(r'op_name="([^"]*lm\.noise[^"]*)"', text))}
@@ -605,9 +612,7 @@ def test_the_ouro_step_program_at_the_cells_shapes(one_chip):
     tokens: six rematerialised dense blocks inside one scanned pass run four
     times, four passes of the head. It fits the chip (XLA's analysis), the
     program holds ONE pass and not four (a pass's 24 kernel calls, the block
-    inputs stacked over the four trips), and the three fold kernels are there."""
-    import re
-
+    inputs stacked over the four trips), and the fold's two kernels are there."""
     from flink_ml_tpu.models.lm.config import num_params
 
     c, cfg = _ouro_cut()
@@ -615,13 +620,44 @@ def test_the_ouro_step_program_at_the_cells_shapes(one_chip):
     batch, t = c["global_batch_size"], c["sequence_length"]
     compiled, memory = _compiled_step(c, cfg, one_chip)
     text = compiled.as_text()
-    for kernel in ("flash_fold_fwd", "flash_fold_bwd_dq", "flash_fold_bwd_dkv"):
+    for kernel in ("flash_fold_fwd", "flash_fold_bwd_dkv"):
         assert kernel in text
+    assert "bwd_dq" not in text  # one backward kernel a fold
     calls = len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text))
-    # one pass's kernels (a layer's forward, recomputed forward, dq and dkv), not four passes'
-    assert 4 * cfg.n_layers <= calls < 2 * 4 * cfg.n_layers
+    # one pass's kernels (a layer's forward, recomputed forward and the one backward), not four passes'
+    assert 3 * cfg.n_layers <= calls < 2 * 3 * cfg.n_layers
     stacked = f"f32[{cfg.loops},{batch},{t},{cfg.hidden}]"  # what the forward loop holds for the backward, per pass
     assert stacked in text
+    assert f"f32[{batch},{cfg.n_heads},{t},{t}]" not in text and f"f32[{batch * cfg.n_heads},{t},{t}]" not in text
+
+
+def _olmoe_cut():
+    """``(the benchmark's olmoe_1b_7b configuration, its LMConfig)``, through the stage's own params."""
+    from flink_ml_tpu.models.lm import DecoderLM
+
+    c = _cell_config("olmoe_1b_7b")
+    stage = (DecoderLM().set_num_layers(c["num_hidden_layers"]).set_hidden_size(c["hidden_size"])
+             .set_num_heads(c["num_attention_heads"]).set_num_experts(c["num_experts"])
+             .set_experts_per_token(c["num_experts_per_tok"]).set_expert_width(c["intermediate_size"])
+             .set_vocab_size(c["vocab_size"]).set_rope_theta(float(c["rope_theta"]))
+             .set_norm_eps(float(c["rms_norm_eps"])).set_aux_loss_coef(c["router_aux_loss_coef"]))
+    return c, stage.lm_config()
+
+
+def test_the_olmoe_step_program_at_the_cells_shapes(one_chip):
+    """The whole jitted step of the ``olmoe_1b_7b`` configuration at 4 x 4,096
+    tokens: one block, not checkpointed, so the fold is its forward and its ONE
+    backward kernel, two calls of the fold's kernels in all; it fits the chip."""
+    from flink_ml_tpu.models.lm.config import num_params
+
+    c, cfg = _olmoe_cut()
+    assert num_params(cfg) == 625_616_896  # 10.0 GB of f32 state at 16 bytes a parameter
+    batch, t = c["global_batch_size"], c["sequence_length"]
+    compiled, _ = _compiled_step(c, cfg, one_chip)
+    text = compiled.as_text()
+    for kernel in ("flash_fold_fwd", "flash_fold_bwd_dkv"):
+        assert kernel in text
+    assert "bwd_dq" not in text and len(re.findall(r"%flash_fold_[\w.]+ = ", text)) == 2
     assert f"f32[{batch},{cfg.n_heads},{t},{t}]" not in text and f"f32[{batch * cfg.n_heads},{t},{t}]" not in text
 
 

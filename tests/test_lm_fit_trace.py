@@ -69,16 +69,15 @@ def test_counts_are_what_the_job_says(traced_fit):
     assert one["train.fit"] == {"rows": N, "tokens": N * T}
     assert one["train.tokens_put"] == {"rows": N, "tokens": N * T, "bytes": N * T * 4}
     assert one["train.init"] == {"params": num_params(cfg), "bytes": 4 * num_params(cfg)}
-    # 256 keys are one chunk, taken whole: the forward's one tile, the dq kernel's four
-    # of 64 rows and the dkv kernel's one pair, nothing to hide
-    pairs = (1 + 4 + 1) * LAYERS * 2 * BATCH
+    # 256 keys are one chunk, taken whole: the forward's one tile and the backward kernel's, nothing to hide
+    pairs = (1 + 1) * LAYERS * 2 * BATCH
     # AdamW's state: mu and nu, a float32 leaf a parameter each, and the int32 count; every layer's fold in the
-    # one-block form, five row statistics through its three kernels
+    # one-block form, two row statistics through its two kernels, one of them the backward
     assert one["train.program"] == {"built": 0, "fold_chunks": pairs, "fold_chunks_visited": pairs,
                                     "loop_trips": 1, "layer_applications": LAYERS,
                                     "state_leaves": 2 * len(param_shapes(cfg)) + 1,
                                     "state_bytes": 8 * num_params(cfg) + 4, "head_logit_matmuls": 1,
-                                    "fold_one_block": LAYERS, "fold_row_stats": 5}
+                                    "fold_one_block": LAYERS, "fold_row_stats": 2, "fold_bwd_kernels": 1}
     assert one["train.dispatch"] == {"steps": STEPS}
     drain = one["train.drain"]
     assert drain["steps"] == STEPS and drain["tokens"] == STEPS * BATCH * T
@@ -116,7 +115,7 @@ def test_the_causal_fold_reports_the_chunks_it_skips():
     with trace.capture() as recorder:
         est.fit(df)
     (program,) = [s.attrs for s in recorder.snapshot() if s.name == "train.program"]
-    visited, pairs = fold_chunk_counts(t, t, 0, True)
+    visited, pairs = fold_chunk_counts(t, t, 0, True, one_block=True)
     assert 0 < program["fold_chunks_visited"] < program["fold_chunks"]
     assert (program["fold_chunks_visited"], program["fold_chunks"]) == (layers * heads * visited, layers * heads * pairs)
     counted = [metrics.get(MLMetrics.TRAIN_GROUP, name) - b for name, b in zip(names, before)]
@@ -145,13 +144,14 @@ def test_a_looped_stack_reports_its_trips_and_its_exits():
     parents = {s.span_id: s.name for s in spans}
     assert [(s.name, parents.get(s.parent_id), s.category) for s in spans] == TREE
     one = {s.name: s.attrs for s in spans}
-    pairs = (1 + 4 + 1) * layers * loops * heads * BATCH  # T 256 is one chunk, as above
+    pairs = (1 + 1) * layers * loops * heads * BATCH  # T 256 is one chunk, as above
     cfg = est.lm_config()
     assert one["train.program"] == {"built": 1, "fold_chunks": pairs, "fold_chunks_visited": pairs,
                                     "loop_trips": loops, "layer_applications": layers * loops,
                                     "state_leaves": 2 * len(param_shapes(cfg)) + 1,
                                     "state_bytes": 8 * num_params(cfg) + 4, "head_logit_matmuls": 1,
-                                    "fold_one_block": layers, "fold_row_stats": 5}  # the traced pass's layers, once
+                                    "fold_one_block": layers, "fold_row_stats": 2,  # the traced pass's layers, once
+                                    "fold_bwd_kernels": 1}
     drain = one["train.drain"]
     assert set(drain) == {"steps", "tokens", "exit_trip_sum", "exit_last_mass", "gate_entropy_sum", "trip_nll"}
     tokens = steps * BATCH * T

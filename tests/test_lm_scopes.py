@@ -149,11 +149,11 @@ def test_the_head_is_never_recomputed_and_a_block_is_where_it_is_checkpointed(pr
         assert {d for s, d in found if s == "lm.block/latent"} == {FWD, REMAT, BWD}
     if kind == "sdar":  # the doubled sequence's kernels under names of their own, and the corruption outside AD
         names = {n.split("/fold/")[1].split("/")[0] for _, n in step if n and "/fold/flash_fold" in n}
-        assert names == {f"flash_fold_bd_{k}" for k in ("fwd", "bwd_dq", "bwd_dkv")}
+        assert names == {f"flash_fold_bd_{k}" for k in ("fwd", "bwd_dkv")}  # the one backward kernel keeps the dkv's name
         assert {d for s, d in found if s == "lm.noise"} == {FWD}
     if kind == "laguna":  # the windowed layer's kernels under names of their own, beside the full layers'
         names = {n.split("/fold/")[1].split("/")[0] for _, n in step if n and "/fold/flash_fold" in n}
-        assert names == {f"flash_fold_{w}{k}" for w in ("", "win_") for k in ("fwd", "bwd_dq", "bwd_dkv")}
+        assert names == {f"flash_fold_{w}{k}" for w in ("", "win_") for k in ("fwd", "bwd_dkv")}
     # nothing of the loss is outside the gradient, nothing of the update inside it
     assert {d for scope, d in found if scope == "lm.opt"} == {FWD}
 
