@@ -583,6 +583,14 @@ def _block_of_chunk(chunks_of_block, n_chunks: int, model_axis):
     )
 
 
+def _class_scope(wdt: int, chunked) -> str:
+    """The ``jax.named_scope`` of one occupancy class inside a round,
+    RELATIVE like ``parallel/moe.py``'s: it nests under whatever the caller
+    opened (``lin.gather/light/w4``, ``lin.scatter/chunks``;
+    docs/observability.md, "The linear step's scopes")."""
+    return "chunks" if chunked else f"light/w{wdt}"
+
+
 def gather_round(coef_perm, lidx, class_meta, model_axis=None):
     """Per-entry coefficient read, g[e] = coef_perm[block(e)*BLOCK + lidx[e]],
     for every sub-batch at once (``lidx`` [n_sub, n_flat] -> [n_sub, n_flat]).
@@ -600,21 +608,22 @@ def gather_round(coef_perm, lidx, class_meta, model_axis=None):
     c2 = coef_perm.reshape(-1, BLOCK)
     n_sub = lidx.shape[0]
     for f_c, wdt, off, b0, *chunked in class_meta:
-        if chunked:  # [heavy blocks, BLOCK] -> [f_c chunks, BLOCK]
-            rows = jnp.take(
-                jax.lax.slice_in_dim(c2, b0, b0 + len(chunked[0][0])),
-                _block_of_chunk(chunked[0], f_c, model_axis),
-                axis=0, indices_are_sorted=True, mode="clip",
+        with jax.named_scope(_class_scope(wdt, chunked)):
+            if chunked:  # [heavy blocks, BLOCK] -> [f_c chunks, BLOCK]
+                rows = jnp.take(
+                    jax.lax.slice_in_dim(c2, b0, b0 + len(chunked[0][0])),
+                    _block_of_chunk(chunked[0], f_c, model_axis),
+                    axis=0, indices_are_sorted=True, mode="clip",
+                )
+            else:
+                rows = jax.lax.slice_in_dim(c2, b0, b0 + f_c)  # [f_c, BLOCK]
+            ids = jax.lax.slice_in_dim(lidx, off, off + f_c * wdt, axis=1).reshape(
+                n_sub, f_c, wdt
             )
-        else:
-            rows = jax.lax.slice_in_dim(c2, b0, b0 + f_c)  # [f_c, BLOCK]
-        ids = jax.lax.slice_in_dim(lidx, off, off + f_c * wdt, axis=1).reshape(
-            n_sub, f_c, wdt
-        )
-        oh = _lane_onehot(ids, BLOCK, jnp.float32)  # [n_sub, f_c, wdt, BLOCK]
-        parts.append(
-            jnp.sum(oh * rows[None, :, None, :], axis=3).reshape(n_sub, -1)
-        )
+            oh = _lane_onehot(ids, BLOCK, jnp.float32)  # [n_sub, f_c, wdt, BLOCK]
+            parts.append(
+                jnp.sum(oh * rows[None, :, None, :], axis=3).reshape(n_sub, -1)
+            )
     return jnp.concatenate(parts, axis=1)
 
 
@@ -628,21 +637,22 @@ def scatter_round(u, lidx, class_meta, nblk, model_axis=None):
     c2 = jnp.zeros((nblk, BLOCK), jnp.float32)
     n_sub = u.shape[0]
     for f_c, wdt, off, b0, *chunked in class_meta:
-        ids = jax.lax.slice_in_dim(lidx, off, off + f_c * wdt, axis=1).reshape(
-            n_sub, f_c, wdt
-        )
-        vals = jax.lax.slice_in_dim(u, off, off + f_c * wdt, axis=1).reshape(
-            n_sub, f_c, wdt
-        )
-        oh = _lane_onehot(ids, BLOCK, jnp.float32)
-        sums = jnp.sum(oh * vals[..., None], axis=(0, 2))  # [f_c, BLOCK]
-        if chunked:  # [f_c chunks, BLOCK] -> [heavy blocks, BLOCK]
-            sums = jax.ops.segment_sum(
-                sums, _block_of_chunk(chunked[0], f_c, model_axis),
-                num_segments=len(chunked[0][0]),
-                indices_are_sorted=True, mode="promise_in_bounds",
+        with jax.named_scope(_class_scope(wdt, chunked)):
+            ids = jax.lax.slice_in_dim(lidx, off, off + f_c * wdt, axis=1).reshape(
+                n_sub, f_c, wdt
             )
-        c2 = jax.lax.dynamic_update_slice(c2, sums, (b0, 0))
+            vals = jax.lax.slice_in_dim(u, off, off + f_c * wdt, axis=1).reshape(
+                n_sub, f_c, wdt
+            )
+            oh = _lane_onehot(ids, BLOCK, jnp.float32)
+            sums = jnp.sum(oh * vals[..., None], axis=(0, 2))  # [f_c, BLOCK]
+            if chunked:  # [f_c chunks, BLOCK] -> [heavy blocks, BLOCK]
+                sums = jax.ops.segment_sum(
+                    sums, _block_of_chunk(chunked[0], f_c, model_axis),
+                    num_segments=len(chunked[0][0]),
+                    indices_are_sorted=True, mode="promise_in_bounds",
+                )
+            c2 = jax.lax.dynamic_update_slice(c2, sums, (b0, 0))
     return c2.reshape(-1)
 
 
@@ -1114,43 +1124,53 @@ def onehot_batch_step(
     the premat section above)."""
     n_sub = lidx_w.shape[0]
     n_flat = lidx_w.shape[1]
-    lidx_w = lidx_w.astype(jnp.int32)
-    if premat is None:
-        dot_cross = dot_crossing_pallas if use_pallas else dot_crossing_xla
-        mult_cross = mult_crossing_pallas if use_pallas else mult_crossing_xla
-        rid = rowid_w.astype(jnp.int32)
-        rhi_w = rid // _ROW_LO
-        rlo_w = rid % _ROW_LO
+    # The step names its parts (``lin.*``; docs/observability.md, "The linear
+    # step's scopes"): trace-time metadata on each instruction's op_name.
+    with jax.named_scope("lin.unpack"):
+        lidx_w = lidx_w.astype(jnp.int32)
+        if premat is None:
+            dot_cross = dot_crossing_pallas if use_pallas else dot_crossing_xla
+            mult_cross = mult_crossing_pallas if use_pallas else mult_crossing_xla
+            rid = rowid_w.astype(jnp.int32)
+            rhi_w = rid // _ROW_LO
+            rlo_w = rid % _ROW_LO
     # Every stage processes ALL sub-batches in one invocation (the sub axis
     # is just a leading batch dim) — per-invocation floors, not per-entry
     # work, dominated the per-sub form (measured).
-    g = gather_round(coef_perm, lidx_w, class_meta, model_axis)  # [n_sub, n_flat]
-    q = lvals_w * g
-    if premat is not None:
-        oh_hi_w, oh_lo_w, wi = premat
-        dot3 = (
-            dot_crossing_premat_pallas(q, oh_hi_w, oh_lo_w, wi)
-            if use_pallas
-            else dot_crossing_premat_xla(q, oh_hi_w, oh_lo_w, wi)
-        )
-    else:
-        dot3 = dot_cross(q, rhi_w, rlo_w, row_hi)  # [n_sub, row_hi, 128]
-    if model_axis is not None:
-        dot3 = jax.lax.psum(dot3, model_axis)
-    dot = dot3.reshape(n_sub, row_hi * _ROW_LO)[:, :sub_batch].reshape(-1)
-    loss_sum, mult = loss_func.loss_and_mult(dot, yb, wb)
-    mult3 = jnp.pad(
-        mult.reshape(n_sub, sub_batch),
-        ((0, 0), (0, row_hi * _ROW_LO - sub_batch)),
-    ).reshape(n_sub, row_hi, _ROW_LO)
-    if premat is not None:
-        back = (
-            mult_crossing_premat_pallas(mult3, oh_hi_w, oh_lo_w, wi)
-            if use_pallas
-            else mult_crossing_premat_xla(mult3, oh_hi_w, oh_lo_w, wi)
-        )[:, :n_flat]
-    else:
-        back = mult_cross(mult3, rhi_w, rlo_w, row_hi)
-    u = lvals_w * back
-    grad = scatter_round(u, lidx_w, class_meta, nblk, model_axis)
-    return grad, loss_sum, jnp.sum(wb)
+    with jax.named_scope("lin.gather"):
+        g = gather_round(coef_perm, lidx_w, class_meta, model_axis)  # [n_sub, n_flat]
+        q = lvals_w * g
+    with jax.named_scope("lin.cross_dot"):
+        if premat is not None:
+            oh_hi_w, oh_lo_w, wi = premat
+            dot3 = (
+                dot_crossing_premat_pallas(q, oh_hi_w, oh_lo_w, wi)
+                if use_pallas
+                else dot_crossing_premat_xla(q, oh_hi_w, oh_lo_w, wi)
+            )
+        else:
+            dot3 = dot_cross(q, rhi_w, rlo_w, row_hi)  # [n_sub, row_hi, 128]
+        if model_axis is not None:
+            dot3 = jax.lax.psum(dot3, model_axis)
+        dot = dot3.reshape(n_sub, row_hi * _ROW_LO)[:, :sub_batch].reshape(-1)
+    with jax.named_scope("lin.loss"):
+        loss_sum, mult = loss_func.loss_and_mult(dot, yb, wb)
+        mult3 = jnp.pad(
+            mult.reshape(n_sub, sub_batch),
+            ((0, 0), (0, row_hi * _ROW_LO - sub_batch)),
+        ).reshape(n_sub, row_hi, _ROW_LO)
+    with jax.named_scope("lin.cross_mult"):
+        if premat is not None:
+            back = (
+                mult_crossing_premat_pallas(mult3, oh_hi_w, oh_lo_w, wi)
+                if use_pallas
+                else mult_crossing_premat_xla(mult3, oh_hi_w, oh_lo_w, wi)
+            )[:, :n_flat]
+        else:
+            back = mult_cross(mult3, rhi_w, rlo_w, row_hi)
+    with jax.named_scope("lin.scatter"):
+        u = lvals_w * back
+        grad = scatter_round(u, lidx_w, class_meta, nblk, model_axis)
+    with jax.named_scope("lin.loss"):  # the weight sum the mean loss divides by
+        weight_sum = jnp.sum(wb)
+    return grad, loss_sum, weight_sum

@@ -570,20 +570,25 @@ def _fused_onehot_program(
         def body(carry, sched):
             cp, done = carry
             wi, offset, act = sched
-            start = win_starts[wi]
-            sel = lambda a: jax.lax.dynamic_index_in_dim(a, wi, 0, keepdims=False)
-            yb = jax.lax.dynamic_slice_in_dim(y, start, lb)
-            tail_valid = (start + jnp.arange(lb) >= offset).astype(jnp.float32)
-            wb = (
-                jax.lax.dynamic_slice_in_dim(w, start, lb)
-                * jax.lax.dynamic_slice_in_dim(mask, start, lb)
-                * tail_valid
-            )
-            if padded_b > lb:
-                yb = jnp.pad(yb, (0, padded_b - lb))
-                wb = jnp.pad(wb, (0, padded_b - lb))
+            # The step names its parts (``lin.*``; docs/observability.md, "The
+            # linear step's scopes"); ``onehot_batch_step`` opens the rounds',
+            # the crossings' and the loss's.
+            with jax.named_scope("lin.unpack"):  # the minibatch's cut of the window
+                start = win_starts[wi]
+                sel = lambda a: jax.lax.dynamic_index_in_dim(a, wi, 0, keepdims=False)
+                yb = jax.lax.dynamic_slice_in_dim(y, start, lb)
+                tail_valid = (start + jnp.arange(lb) >= offset).astype(jnp.float32)
+                wb = (
+                    jax.lax.dynamic_slice_in_dim(w, start, lb)
+                    * jax.lax.dynamic_slice_in_dim(mask, start, lb)
+                    * tail_valid
+                )
+                if padded_b > lb:
+                    yb = jnp.pad(yb, (0, padded_b - lb))
+                    wb = jnp.pad(wb, (0, padded_b - lb))
+                lidx_w, rowid_w, lvals_w = sel(lidx), sel(rowid), sel(lvals)
             grad, loss_sum, wsum = onehot_batch_step(
-                cp, sel(lidx), sel(rowid), sel(lvals), yb, wb,
+                cp, lidx_w, rowid_w, lvals_w, yb, wb,
                 loss_func, class_meta, nblk_local, sub, row_hi, use_pallas,
                 model_axis=model_axis,
                 # full stacks + wi: the window is selected inside the premat
@@ -591,29 +596,31 @@ def _fused_onehot_program(
                 # dynamic_index that would copy a multi-GB window per step
                 premat=(oh_hi, oh_lo, wi) if premat else None,
             )
-            if model_sharded:
-                # The grad shard varies over the model axis while the scalar
-                # stats are replicated across it (computed from the
-                # model-psum'd dot) — keep their psums separate so the
-                # replication stays statically visible to shard_map.
-                grad = jax.lax.psum(grad, data_axes)
-                stats = jax.lax.psum(jnp.stack([wsum, loss_sum]), data_axes)
-                weight_sum, loss_sum = stats[0], stats[1]
-            else:
-                packed = jnp.concatenate(
-                    [grad, jnp.stack([wsum, loss_sum]).astype(grad.dtype)]
-                )
-                packed = jax.lax.psum(packed, data_axes)
-                grad, weight_sum, loss_sum = packed[:-2], packed[-2], packed[-1]
-            safe_w = jnp.maximum(weight_sum, 1e-30)
-            new_cp = jnp.where(weight_sum > 0, cp - (lr / safe_w) * grad, cp)
-            new_cp, _reg_loss = regularize(new_cp, reg, elastic_net, lr)
-            mean_loss = jnp.where(weight_sum > 0, loss_sum / safe_w, jnp.inf)
-            executed = ~done & act
-            new_cp = jnp.where(executed, new_cp, cp)
-            recorded = jnp.where(executed, mean_loss, jnp.inf)
-            if tol is not None:
-                done = done | (executed & (mean_loss < tol))
+            with jax.named_scope("lin.reduce"):
+                if model_sharded:
+                    # The grad shard varies over the model axis while the scalar
+                    # stats are replicated across it (computed from the
+                    # model-psum'd dot) — keep their psums separate so the
+                    # replication stays statically visible to shard_map.
+                    grad = jax.lax.psum(grad, data_axes)
+                    stats = jax.lax.psum(jnp.stack([wsum, loss_sum]), data_axes)
+                    weight_sum, loss_sum = stats[0], stats[1]
+                else:
+                    packed = jnp.concatenate(
+                        [grad, jnp.stack([wsum, loss_sum]).astype(grad.dtype)]
+                    )
+                    packed = jax.lax.psum(packed, data_axes)
+                    grad, weight_sum, loss_sum = packed[:-2], packed[-2], packed[-1]
+            with jax.named_scope("lin.update"):
+                safe_w = jnp.maximum(weight_sum, 1e-30)
+                new_cp = jnp.where(weight_sum > 0, cp - (lr / safe_w) * grad, cp)
+                new_cp, _reg_loss = regularize(new_cp, reg, elastic_net, lr)
+                mean_loss = jnp.where(weight_sum > 0, loss_sum / safe_w, jnp.inf)
+                executed = ~done & act
+                new_cp = jnp.where(executed, new_cp, cp)
+                recorded = jnp.where(executed, mean_loss, jnp.inf)
+                if tol is not None:
+                    done = done | (executed & (mean_loss < tol))
             return (new_cp, done), (recorded, executed)
 
         (coef_perm, done), (losses, executed) = jax.lax.scan(
