@@ -40,6 +40,14 @@ be replaced by dense one-hot algebra the MXU/VPU execute at full width:
   (~sqrt of the sub's row space) against padding (fewer rows per sub means
   sparser blocks and more padding up to a class width); 16384 measured best of
   {8192, 16384, 32768} at the Criteo shape.
+- **What a window's ids alone decide is made once.** The resident route
+  materializes the row one-hots before the training scan
+  (``premat_row_onehots``), and the lane ids are unpacked outside the scan
+  like them: ``unpack_lane_ids`` (int8 -> int32, one array a class as the
+  rounds read it) runs once a step program over all its windows wherever
+  the program visits a window more than once and the ids fit the route's
+  share of HBM beside the stacks and the one-hots
+  (``ops/optimizer.py::SGD._hoists_lane_ids``).
 
 The crossings run two ways: a pure-XLA form (works on any backend;
 one-hots are materialized through HBM) and Pallas kernels (TPU only;
@@ -67,7 +75,7 @@ from flink_ml_tpu.utils.arrays import next_pow2
 __all__ = [
     "OneHotSparseLayout", "OneHotSparsePlan", "onehot_batch_step",
     "block_counts", "validate_indices", "SUB_ROWS", "BLOCK",
-    "premat_row_onehots", "premat_bytes",
+    "premat_row_onehots", "premat_bytes", "unpack_lane_ids", "lane_ids_bytes",
 ]
 
 BLOCK = 128  # feature-block width: the VPU lane count
@@ -591,9 +599,45 @@ def _class_scope(wdt: int, chunked) -> str:
     return "chunks" if chunked else f"light/w{wdt}"
 
 
-def gather_round(coef_perm, lidx, class_meta, model_axis=None):
-    """Per-entry coefficient read, g[e] = coef_perm[block(e)*BLOCK + lidx[e]],
-    for every sub-batch at once (``lidx`` [n_sub, n_flat] -> [n_sub, n_flat]).
+def lane_ids_bytes(n_units: int, class_meta) -> int:
+    """HBM bytes of ``unpack_lane_ids``' arrays for ``n_units`` sub-batch
+    units in the layout the rounds read, at most: ``wdt`` minor, padded to
+    the 128 lanes, a class's rows to the 8 sublanes (a width-2 class takes 64
+    times its 4 B a slot; the compiler holds the narrow classes more
+    compactly between steps: the whole step program of the one-chip Criteo
+    cell compiles to 127 MB of temporaries where this reads 245). An upper
+    bound on purpose: it is what ``SGD._hoists_lane_ids`` sets against the
+    HBM budget before a program may hold every window's unpacked ids, and
+    over-counting costs the hoist in a narrow band, never the fit."""
+    tiles = sum(-(-f_c // 8) * 8 * -(-wdt // BLOCK) * BLOCK for f_c, wdt, *_ in class_meta)
+    return 4 * n_units * tiles
+
+
+def unpack_lane_ids(lidx, class_meta):
+    """Packed lane ids ``[..., n_sub, n_flat]`` int8 -> one int32 array a
+    class of ``class_meta``, ``[..., n_sub, f_c, wdt]``: the class's cut at
+    its ``flat_offset``, shaped as ``gather_round`` and ``scatter_round``
+    read it. A function of the ids alone, so a step program that visits a
+    window more than once calls it before its scan, once over all its
+    windows (``ops/optimizer.py::_fused_onehot_program``). The caller opens
+    ``lin.unpack``; a class's cut sits under it by the rounds' relative class
+    scope (``lin.unpack/light/w4``, ``lin.unpack/chunks``)."""
+    ids = lidx.astype(jnp.int32)
+    lead = ids.shape[:-1]
+    parts = []
+    for f_c, wdt, off, _b0, *chunked in class_meta:
+        with jax.named_scope(_class_scope(wdt, chunked)):
+            parts.append(
+                jax.lax.slice_in_dim(ids, off, off + f_c * wdt, axis=ids.ndim - 1)
+                .reshape(lead + (f_c, wdt))
+            )
+    return parts
+
+
+def gather_round(coef_perm, lane_ids, class_meta, model_axis=None):
+    """Per-entry coefficient read, g[e] = coef_perm[block(e)*BLOCK + lane(e)],
+    for every sub-batch at once (``lane_ids``: ``unpack_lane_ids``' list, a
+    class ``[n_sub, f_c, wdt]`` -> [n_sub, n_flat]).
 
     Per occupancy class: a 128-lane one-hot times the class's contiguous
     coefficient rows (a static slice — the class-major permutation exists
@@ -606,8 +650,7 @@ def gather_round(coef_perm, lidx, class_meta, model_axis=None):
     """
     parts = []
     c2 = coef_perm.reshape(-1, BLOCK)
-    n_sub = lidx.shape[0]
-    for f_c, wdt, off, b0, *chunked in class_meta:
+    for (f_c, wdt, _off, b0, *chunked), ids in zip(class_meta, lane_ids):
         with jax.named_scope(_class_scope(wdt, chunked)):
             if chunked:  # [heavy blocks, BLOCK] -> [f_c chunks, BLOCK]
                 rows = jnp.take(
@@ -617,30 +660,25 @@ def gather_round(coef_perm, lidx, class_meta, model_axis=None):
                 )
             else:
                 rows = jax.lax.slice_in_dim(c2, b0, b0 + f_c)  # [f_c, BLOCK]
-            ids = jax.lax.slice_in_dim(lidx, off, off + f_c * wdt, axis=1).reshape(
-                n_sub, f_c, wdt
-            )
             oh = _lane_onehot(ids, BLOCK, jnp.float32)  # [n_sub, f_c, wdt, BLOCK]
             parts.append(
-                jnp.sum(oh * rows[None, :, None, :], axis=3).reshape(n_sub, -1)
+                jnp.sum(oh * rows[None, :, None, :], axis=3).reshape(ids.shape[0], -1)
             )
     return jnp.concatenate(parts, axis=1)
 
 
-def scatter_round(u, lidx, class_meta, nblk, model_axis=None):
+def scatter_round(u, lane_ids, class_meta, nblk, model_axis=None):
     """Transposed gather_round: per-entry values summed into the permuted
-    gradient across every sub-batch (``u``/``lidx`` [n_sub, n_flat] ->
-    [nblk * BLOCK]) — the same exact-f32 VPU broadcast-sum form, reduced
+    gradient across every sub-batch (``u`` [n_sub, n_flat], ``lane_ids`` a
+    class ``[n_sub, f_c, wdt]`` -> [nblk * BLOCK]) — the same exact-f32 VPU
+    broadcast-sum form, reduced
     over the sub and width dims (the gradient accumulation). The chunked
     class's per-chunk row sums are added block by block, a sorted segment
     sum of whole rows."""
     c2 = jnp.zeros((nblk, BLOCK), jnp.float32)
     n_sub = u.shape[0]
-    for f_c, wdt, off, b0, *chunked in class_meta:
+    for (f_c, wdt, off, b0, *chunked), ids in zip(class_meta, lane_ids):
         with jax.named_scope(_class_scope(wdt, chunked)):
-            ids = jax.lax.slice_in_dim(lidx, off, off + f_c * wdt, axis=1).reshape(
-                n_sub, f_c, wdt
-            )
             vals = jax.lax.slice_in_dim(u, off, off + f_c * wdt, axis=1).reshape(
                 n_sub, f_c, wdt
             )
@@ -1084,7 +1122,7 @@ def mult_crossing_premat_pallas(mult3, oh_hi, oh_lo, wi=0, interpret: bool = Fal
 
 def onehot_batch_step(
     coef_perm,
-    lidx_w,
+    lane_ids_w,
     rowid_w,
     lvals_w,
     yb,
@@ -1102,10 +1140,13 @@ def onehot_batch_step(
     gradients accumulated, returning ``(grad_perm, loss_sum, weight_sum)``
     with exactly the scatter path's batch semantics.
 
-    ``lidx_w/rowid_w/lvals_w``: this window's ``[n_sub, n_flat]`` packed
-    stack slices (this model shard's, under TP; int8 lane / int16 rowid —
-    unpacked to int32 here, transient through XLA fusion, so the 7 B/slot
-    packed form is what rides HBM and the host->device link). ``yb/wb``:
+    ``lane_ids_w``: this window's lane ids as ``unpack_lane_ids`` makes them
+    from the packed int8 ``lidx`` stack, one int32 ``[n_sub, f_c, wdt]`` a
+    class (the caller unpacks: once a step program where it can, see there).
+    ``rowid_w/lvals_w``: this window's ``[n_sub, n_flat]`` packed stack
+    slices (this model shard's, under TP; the int16 rowid is unpacked to
+    int32 here, and only without the premat one-hots; the 7 B/slot packed
+    form is what rides the host->device link). ``yb/wb``:
     the window's label/weight rows ``[local_batch]`` (wb already carries
     the mask and tail gating — padded rows weigh 0, so their entries
     contribute nothing, and padded entries carry value 0 on top). ``nblk``
@@ -1122,15 +1163,13 @@ def onehot_batch_step(
     window via scalar-prefetch (Pallas) or a dynamic slice (XLA/test
     form), and ``rowid_w`` is never unpacked (the resident fast path; see
     the premat section above)."""
-    n_sub = lidx_w.shape[0]
-    n_flat = lidx_w.shape[1]
+    n_sub, n_flat = lvals_w.shape
     # The step names its parts (``lin.*``; docs/observability.md, "The linear
     # step's scopes"): trace-time metadata on each instruction's op_name.
-    with jax.named_scope("lin.unpack"):
-        lidx_w = lidx_w.astype(jnp.int32)
-        if premat is None:
-            dot_cross = dot_crossing_pallas if use_pallas else dot_crossing_xla
-            mult_cross = mult_crossing_pallas if use_pallas else mult_crossing_xla
+    if premat is None:
+        dot_cross = dot_crossing_pallas if use_pallas else dot_crossing_xla
+        mult_cross = mult_crossing_pallas if use_pallas else mult_crossing_xla
+        with jax.named_scope("lin.unpack"):
             rid = rowid_w.astype(jnp.int32)
             rhi_w = rid // _ROW_LO
             rlo_w = rid % _ROW_LO
@@ -1138,7 +1177,7 @@ def onehot_batch_step(
     # is just a leading batch dim) — per-invocation floors, not per-entry
     # work, dominated the per-sub form (measured).
     with jax.named_scope("lin.gather"):
-        g = gather_round(coef_perm, lidx_w, class_meta, model_axis)  # [n_sub, n_flat]
+        g = gather_round(coef_perm, lane_ids_w, class_meta, model_axis)  # [n_sub, n_flat]
         q = lvals_w * g
     with jax.named_scope("lin.cross_dot"):
         if premat is not None:
@@ -1170,7 +1209,7 @@ def onehot_batch_step(
             back = mult_cross(mult3, rhi_w, rlo_w, row_hi)
     with jax.named_scope("lin.scatter"):
         u = lvals_w * back
-        grad = scatter_round(u, lidx_w, class_meta, nblk, model_axis)
+        grad = scatter_round(u, lane_ids_w, class_meta, nblk, model_axis)
     with jax.named_scope("lin.loss"):  # the weight sum the mean loss divides by
         weight_sum = jnp.sum(wb)
     return grad, loss_sum, weight_sum

@@ -502,6 +502,7 @@ def _fused_onehot_program(
     tol: Optional[float],
     use_pallas: bool,
     premat: bool = False,
+    hoist: bool = False,
 ):
     """A chunk of sparse SGD epochs on the one-hot matmul path — the same
     scan/done/losses contract as ``_fused_sgd_program``, but the coefficient
@@ -535,15 +536,23 @@ def _fused_onehot_program(
     and the crossings run product+matmul-only kernels instead of
     rebuilding the one-hots every minibatch (measured 1.86x on the
     crossings at the headline unit shape; docs/benchmarks.md).
+
+    ``hoist=True`` (a program that visits a window more than once, HBM-gated
+    by the caller: ``SGD._hoists_lane_ids``): the lane ids are unpacked like
+    the row one-hots are built, outside the scan, once a program over all its
+    windows (``unpack_lane_ids``: int32, one array a class), and the body
+    picks the step's window from each class's array as it picks ``lvals`` and
+    ``rowid``. Otherwise the same function runs in the body on the step's
+    window and nothing but the packed stacks is held across the scan.
     """
-    from flink_ml_tpu.linalg.onehot_sparse import onehot_batch_step
+    from flink_ml_tpu.linalg.onehot_sparse import onehot_batch_step, unpack_lane_ids
 
     model_sharded = layout.n_model > 1
     key = (
         ctx.mesh, loss_func, "onehot", layout.class_meta, layout.n_flat,
         layout.n_sub, layout.nblk_local, layout.n_model, layout.sub_batch,
         layout.local_batch, tuple(layout.window_starts), chunk_len, lr, reg,
-        elastic_net, tol, use_pallas, premat,
+        elastic_net, tol, use_pallas, premat, hoist,
     )
     cached = _FUSED_CACHE.get(key)
     if cached is not None:
@@ -566,6 +575,9 @@ def _fused_onehot_program(
             oh_hi, oh_lo = oh_hi[0, 0], oh_lo[0, 0]  # [n_windows, n_sub, ., n_pad]
         else:
             y, w, mask = rest
+        if hoist:  # every window's ids, a class [n_windows, n_sub, f_c, wdt]
+            with jax.named_scope("lin.unpack"):
+                lane_ids = unpack_lane_ids(lidx, class_meta)
 
         def body(carry, sched):
             cp, done = carry
@@ -586,9 +598,14 @@ def _fused_onehot_program(
                 if padded_b > lb:
                     yb = jnp.pad(yb, (0, padded_b - lb))
                     wb = jnp.pad(wb, (0, padded_b - lb))
-                lidx_w, rowid_w, lvals_w = sel(lidx), sel(rowid), sel(lvals)
+                rowid_w, lvals_w = sel(rowid), sel(lvals)
+                lane_ids_w = (
+                    [sel(ids) for ids in lane_ids]
+                    if hoist
+                    else unpack_lane_ids(sel(lidx), class_meta)
+                )
             grad, loss_sum, wsum = onehot_batch_step(
-                cp, lidx_w, rowid_w, lvals_w, yb, wb,
+                cp, lane_ids_w, rowid_w, lvals_w, yb, wb,
                 loss_func, class_meta, nblk_local, sub, row_hi, use_pallas,
                 model_axis=model_axis,
                 # full stacks + wi: the window is selected inside the premat
@@ -1291,7 +1308,9 @@ class SGD(Optimizer):
     # coefficient with >40% headroom. A resident many-window run whose
     # whole-run one-hots exceed the budget falls back to the build-form
     # kernels; the STREAMED route materializes per window on device instead
-    # (`_premat_streamed` budgets the two prefetch-live windows).
+    # (`_premat_streamed` budgets the two prefetch-live windows). The lane ids
+    # a step program holds unpacked across its scan come out of the same
+    # share, after the one-hots (`_hoists_lane_ids`).
     _ONEHOT_PREMAT_HBM_FRACTION = 0.55
 
     def _premat_onehots(self, lay, stacks, ctx, train_data):
@@ -1344,6 +1363,30 @@ class SGD(Optimizer):
         previous 'on' fit's multi-GB arrays still resident on the cache."""
         if getattr(train_data, "_onehot_premat_memo", None) is not None:
             train_data._onehot_premat_memo = None
+
+    def _hoists_lane_ids(self, lay, chunk_len: int, premat: bool, ctx) -> bool:
+        """Whether the resident step program unpacks its windows' lane ids
+        before its scan (``_fused_onehot_program``'s ``hoist``) and not in the
+        scan's body. Two conditions, both read off the layout and the
+        schedule's shape: the program visits a window more than once (else
+        hoisting saves nothing), and the int32 ids it would hold across the
+        scan (``lane_ids_bytes``) fit, beside the packed stacks and, on the
+        premat route, the row one-hots, the share of HBM the one-hot route
+        may claim. Decided after premat and never against it: the one-hots
+        are worth 1.86x on the crossings, the hoist about a fifth of the
+        step, so a fit that cannot hold both keeps the one-hots and unpacks
+        in the body. A build-route fit whose stacks were admitted near
+        ``_ONEHOT_HBM_FRACTION`` is refused likewise: its ids would take what
+        that budget leaves to the CSR columns and the workspace."""
+        from flink_ml_tpu.linalg.onehot_sparse import lane_ids_bytes, premat_bytes
+
+        if chunk_len <= lay.n_windows:
+            return False
+        n_units = lay.n_windows * lay.n_sub
+        held = 7 * n_units * lay.n_flat + lane_ids_bytes(n_units, lay.class_meta)
+        if premat:
+            held += premat_bytes(n_units, lay.n_flat, lay.row_hi)
+        return held <= self._ONEHOT_PREMAT_HBM_FRACTION * _hbm_bytes_limit(ctx)
 
     def _premat_streamed(self, plan, n_mb, n_sub, ctx) -> bool:
         """The streamed route's premat decision. Unlike the resident gate,
@@ -1444,12 +1487,16 @@ class SGD(Optimizer):
         # Crossing MACs bound the dispatch length (split-bf16 doubles them).
         flops = 4.0 * lay.n_sub * lay.n_flat * (lay.sub_batch + 2 * BLOCK)
         chunk = fused_chunk_len(self.max_iter, check_loss, 0, flops)
-        with tracer.phase("train.program", CAT_COMPILE) as phase:
+        hoist = self._hoists_lane_ids(lay, chunk, premat, ctx)
+        with tracer.phase(
+            "train.program", CAT_COMPILE, steps=chunk,
+            lane_unpacks=lay.n_windows if hoist else chunk,  # of a window's ids, a program
+        ) as phase:
             known = tuple(_FUSED_CACHE.values())
             program = _fused_onehot_program(
                 ctx, loss_func, lay, chunk, self.learning_rate, self.reg,
                 self.elastic_net, self.tol if check_loss else None, use_pallas,
-                premat=premat,
+                premat=premat, hoist=hoist,
             )
             phase.set_metadata(built=int(program not in known))
         starts, offsets = offset_schedule(
@@ -1562,12 +1609,19 @@ class SGD(Optimizer):
         )
         premat = self._premat_streamed(plan, n_mb, n_sub, ctx)
         self.onehot_premat_active = premat
-        program = _fused_onehot_program(
-            ctx, loss_func, layout_view, sched.chunk_len, self.learning_rate,
-            self.reg, self.elastic_net, self.tol if check_loss else None,
-            use_pallas=is_tpu_backend(ctx.mesh.devices.flat),
-            premat=premat,
-        )
+        # At most one step a minibatch of the resident window (``WindowSchedule``):
+        # no window visited twice, so the ids are unpacked in the body.
+        with tracer.phase(
+            "train.program", CAT_COMPILE, steps=sched.chunk_len, lane_unpacks=sched.chunk_len
+        ) as phase:
+            known = tuple(_FUSED_CACHE.values())
+            program = _fused_onehot_program(
+                ctx, loss_func, layout_view, sched.chunk_len, self.learning_rate,
+                self.reg, self.elastic_net, self.tol if check_loss else None,
+                use_pallas=is_tpu_backend(ctx.mesh.devices.flat),
+                premat=premat,
+            )
+            phase.set_metadata(built=int(program not in known))
         stream = _OneHotWindowStream(
             cache, ctx, plan, W, b, n_sub, m, n_rows, premat=premat
         )

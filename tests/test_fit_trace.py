@@ -145,7 +145,8 @@ class TestSparseFitTree:
         assert one["train.layout_put"] == {"bytes": stack_bytes}
         assert one["train.premat"]["reused"] == 0 and one["train.premat"]["active"] == 1
         assert one["train.premat"]["bytes"] > stack_bytes
-        assert one["train.program"] == {"built": 0}
+        # 4 steps over a shard's 4 windows: none visited twice, so the ids are unpacked in the body
+        assert one["train.program"] == {"built": 0, "steps": STEPS, "lane_unpacks": STEPS}
         assert one["train.dispatch"] == {"steps": STEPS} == one["train.drain"]
         assert one["train.readback"]["bytes"] >= DIM * 4
 
@@ -377,6 +378,7 @@ PHASE_SITES = {
     "flink_ml_tpu/linalg/onehot_sparse.py": {"build"},
     "flink_ml_tpu/ops/optimizer.py": {
         "optimize", "_optimize_onehot", "_onehot_layout", "_premat_onehots",
+        "_optimize_streaming_onehot",  # ``train.program`` alone: its counts say where the lane ids are unpacked
     },
     # the LM fit's tree (docs/observability.md "The LM fit"; tests/test_lm_fit_trace.py)
     "flink_ml_tpu/models/lm/decoder_lm.py": {"fit", "_fit"},

@@ -7,8 +7,10 @@ and the ``op_name``s of its text are read with the classification the
 benchmark's reader uses
 (``perfbench/op_scopes.py::classify``, root ``lin.``): which scopes are there,
 which class of a round an instruction sits under, and how much of the scan's
-body they cover. A scope is metadata: each program's jaxpr is the one the
-parent of the PR that brought the scopes traced, held by its digest."""
+body they cover. Each program's jaxpr is held by its digest since PR 50, which
+moved the lane ids' unpack out of the scan's body (the digests before it were
+those of the parent of the PR that brought the scopes: a scope is metadata)."""
+import functools
 import hashlib
 import re
 
@@ -22,7 +24,7 @@ from flink_ml_tpu.ops import BinaryLogisticLoss
 from flink_ml_tpu.ops import optimizer
 from flink_ml_tpu.parallel.mesh import MeshContext
 from perfbench.op_scopes import FWD, classify
-from tests.test_lm_scopes import _instructions
+from tests.test_lm_scopes import INSTRUCTION, _instructions
 
 ROOT = "lin."
 LOSS = BinaryLogisticLoss.INSTANCE
@@ -52,7 +54,7 @@ def _onehot(premat, n_data=2, n_model=1, use_pallas=False):
     lay = _layout(n_data, n_model)
     assert [m[1] for m in lay.class_meta] == [2, 16, 64] and len(lay.class_meta[-1]) == 5
     program = optimizer._fused_onehot_program(ctx, LOSS, lay, CHUNK_LEN, LR, REG, ELASTIC_NET, None, use_pallas,
-                                              premat=premat)
+                                              premat=premat, hoist=True)
     stack = (n_data, n_model, lay.n_windows, lay.n_sub, lay.n_flat)
     n_pad = _premat_pad(lay.n_flat, lay.row_hi)
     oh = [jax.ShapeDtypeStruct(stack[:-1] + (w, n_pad), jnp.bfloat16) for w in (lay.row_hi, 128)] if premat else []
@@ -68,38 +70,46 @@ def _schedule():
             jax.ShapeDtypeStruct((CHUNK_LEN,), jnp.bool_))
 
 
-ROUNDS = {f"lin.{r}/{c}" for r in ("gather", "scatter") for c in ("light/w2", "light/w16", "chunks")}
+CLASSES = ("light/w2", "light/w16", "chunks")
+#: a class's scope under each round, and under ``lin.unpack``: its cut of the lane ids, made before the scan
+ROUNDS = {f"lin.{r}/{c}" for r in ("gather", "scatter", "unpack") for c in CLASSES}
 ONEHOT = {"lin.unpack", "lin.gather", "lin.cross_dot", "lin.loss", "lin.cross_mult", "lin.scatter", "lin.reduce",
           "lin.update"} | ROUNDS
 #: program -> (how it is made, the scopes it must show, sha256 of ``str(make_jaxpr(program))`` with addresses
-#: blanked, computed at a3c6254, the parent of the PR that opened the scopes (PR 49))
+#: blanked, as PR 50 left it)
 PROGRAMS = {
     "onehot_premat": (lambda: _onehot(True), ONEHOT,
-                      "33489c049cd3cd26d1b1bf0b0938e7e0e4abd6045cacb95f6c514269ebc88a43"),
+                      "7784dfa6f1f523d8a0a8804cb84fb6e0fa36bd1ce8a12a92f91f47b089ae6673"),
     "onehot_build": (lambda: _onehot(False), ONEHOT,
-                     "a1f135ef6368743b687b21a7a1b54f25bf89a0746d9c7280ecfea10d21fc4924"),
+                     "566b94ac6170dbe5be3d1586b7a971a83de0e28abad767a742b578af34804265"),
     "onehot_premat_tp": (lambda: _onehot(True, n_data=2, n_model=2), ONEHOT,
-                         "2d9a6636079a2ae3b44b71ed029d7472464542f30223890d2ce59f61474ea0ed"),
+                         "020283ca0bbf6c9b1ea77ba1fc210e1498eed04339e5bd5ab28007f29d914f63"),
     # the chip's form, the crossings as Pallas calls: traced here for its jaxpr, compiled only on the chip
     "onehot_premat_pallas": (lambda: _onehot(True, use_pallas=True), None,
-                             "3386bb46f4adbd62b45476ed384873fac5f9a2d83a380ade08a0cae238249ce8"),
+                             "a624b0345eb31ad08987a929f33f281696a09d52c03394a4f7aa589644399153"),
     "onehot_build_pallas": (lambda: _onehot(False, use_pallas=True), None,
-                            "c0a5ec1eb97b6d9d791d166f55d28e44d9cc7ccb112f1829137ccfce655341d6"),
+                            "346b35c3ba94ee01818a20a19c14848727a42c9674c8d77992f6afc2ba4df863"),
 }
 
 
 @pytest.fixture(scope="module")
-def compiled():
-    """program -> its instructions, compiled once."""
+def compiled_text():
+    """program -> its compiled text, compiled once."""
     made = {}
 
     def of(name):
         if name not in made:
             program, shapes = PROGRAMS[name][0]()
-            made[name] = list(_instructions(program.lower(*shapes).compile().as_text()))
+            made[name] = program.lower(*shapes).compile().as_text()
         return made[name]
 
     return of
+
+
+@pytest.fixture(scope="module")
+def compiled(compiled_text):
+    """program -> its instructions, read once."""
+    return functools.lru_cache(maxsize=None)(lambda name: list(_instructions(compiled_text(name))))
 
 
 def _scopes(instructions):
@@ -127,14 +137,15 @@ def test_no_scope_outside_the_table(compiled, name):
 
 @pytest.mark.parametrize("name", COMPILED)
 def test_a_class_sits_under_the_round_that_owns_it(compiled, name):
-    """``gather_round`` and ``scatter_round`` open their classes RELATIVE: a
-    class's instructions read ``lin.gather/light/w16`` or ``lin.scatter/chunks``,
-    never a class without its round, never one round's under the other's."""
+    """``gather_round``, ``scatter_round`` and ``unpack_lane_ids`` open their
+    classes RELATIVE: a class's instructions read ``lin.gather/light/w16``,
+    ``lin.scatter/chunks`` or ``lin.unpack/light/w2``, never a class without
+    its owner, never one round's under the other's."""
     for _, op_name in compiled(name):
         scope, _ = classify(op_name, ROOT)
         if scope is None or not {"light", "chunks"} & set(scope):
             continue
-        assert scope[0] in ("lin.gather", "lin.scatter"), op_name
+        assert scope[0] in ("lin.gather", "lin.scatter", "lin.unpack"), op_name
         assert scope[1:] in (("chunks",), ("light", "w2"), ("light", "w16")), op_name
     # the chunked class's row passes, each in its round: the sorted take of heavy rows, the segment sum back
     names = [n for _, n in compiled(name) if n]
@@ -153,6 +164,39 @@ def test_the_scopes_cover_the_step_bodys_heavy_instructions(compiled, name):
     named = [n for opcode, n in compiled(name) if n and "/while/body/closed_call/" in n and opcode in HEAVY]
     assert len(named) >= 8
     assert not [n for n in named if classify(n, ROOT)[0] is None]
+
+
+#: what moves integers without computing on them
+MOVES = {"slice", "dynamic-slice", "copy", "convert", "transpose", "reshape"}
+TYPED = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]")
+
+
+@pytest.mark.parametrize("name", COMPILED)
+def test_the_scans_body_neither_casts_nor_cuts_the_lane_ids(compiled_text, name):
+    """The unpack of the lane ids sits before the scan (these programs visit
+    a window more than once): inside the while body no ``convert`` reads an
+    ``s8`` array, and under a round's class scope no instruction merely cuts,
+    copies or casts an integer array of the ids' rank. The body's pick of the
+    step's window from each class's array sits under ``lin.unpack``."""
+    dtype_of, body = {}, []
+    for line in compiled_text(name).splitlines():
+        typed, opcode = TYPED.match(line), INSTRUCTION.match(line)
+        if not (typed and opcode):
+            continue
+        dtype_of[typed.group(1)] = typed.group(2)
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        if op_name and "/while/body/" in op_name.group(1):
+            operands = re.findall(r"%([\w.\-]+)", line.split("(", 1)[1].split(")", 1)[0])
+            body.append((opcode.group(1), typed.group(2), typed.group(3).count(",") + 1, operands, op_name.group(1)))
+    assert len(body) > 50
+    assert not [b for b in body if b[0] == "convert" and "s8" in {dtype_of.get(o) for o in b[3]}]
+    picks = 0
+    for opcode, dtype, rank, _, op_name in body:
+        scope, _ = classify(op_name, ROOT)
+        if opcode in MOVES and dtype in ("s8", "s32") and rank >= 2 and scope:
+            assert scope[0] not in ("lin.gather", "lin.scatter"), op_name
+            picks += scope == ("lin.unpack",) and opcode == "dynamic-slice" and rank == 4
+    assert picks >= len(CLASSES)
 
 
 def digest(text):

@@ -22,6 +22,7 @@ from flink_ml_tpu.linalg.onehot_sparse import (
     dot_crossing_premat_pallas,
     dot_crossing_premat_xla,
     dot_crossing_xla,
+    lane_ids_bytes,
     mult_crossing_pallas,
     mult_crossing_premat_pallas,
     mult_crossing_premat_xla,
@@ -29,6 +30,7 @@ from flink_ml_tpu.linalg.onehot_sparse import (
     onehot_batch_step,
     premat_bytes,
     premat_row_onehots,
+    unpack_lane_ids,
 )
 from flink_ml_tpu.ops import SGD, BinaryLogisticLoss
 from flink_ml_tpu.parallel.mesh import MeshContext, mesh_context
@@ -365,7 +367,7 @@ class TestChunkedClass:
         oh = premat_row_onehots(rowid, lay.row_hi) + (0,) if premat else None
         grad_p, ls, ws = jax.jit(
             lambda cp, lidx, rid, lv, yb, wb: onehot_batch_step(
-                cp, lidx, rid, lv, yb, wb, BinaryLogisticLoss.INSTANCE,
+                cp, unpack_lane_ids(lidx, lay.class_meta), rid, lv, yb, wb, BinaryLogisticLoss.INSTANCE,
                 lay.class_meta, lay.nblk_local, lay.sub_batch, lay.row_hi,
                 use_pallas=False, premat=oh,
             )
@@ -390,7 +392,7 @@ class TestChunkedClass:
         n, n_chunks = idx.shape[0], lay.class_meta[-1][0]
         jaxpr = jax.make_jaxpr(
             lambda cp, lidx, rid, lv, yb, wb: onehot_batch_step(
-                cp, lidx, rid, lv, yb, wb, BinaryLogisticLoss.INSTANCE,
+                cp, unpack_lane_ids(lidx, lay.class_meta), rid, lv, yb, wb, BinaryLogisticLoss.INSTANCE,
                 lay.class_meta, lay.nblk_local, lay.sub_batch, lay.row_hi,
                 use_pallas=False,
             )
@@ -466,7 +468,8 @@ class TestBatchStep:
             rows = slice(w0, w0 + lay.local_batch)
             grad_p, ls, ws = onehot_batch_step(
                 cp,
-                jnp.asarray(lay.lidx[0, 0, wi]), jnp.asarray(lay.rowid[0, 0, wi]),
+                unpack_lane_ids(jnp.asarray(lay.lidx[0, 0, wi]), lay.class_meta),
+                jnp.asarray(lay.rowid[0, 0, wi]),
                 jnp.asarray(lay.lvals[0, 0, wi]),
                 jnp.asarray(np.pad(y[rows], (0, pad))),
                 jnp.asarray(np.pad(w[rows], (0, pad))),
@@ -763,6 +766,161 @@ class TestPrematSgd:
     def test_invalid_param_raises(self):
         with pytest.raises(ValueError, match="onehot_premat"):
             SGD(onehot_premat="yes")
+
+
+class TestHoistedLaneIds:
+    """A step program that visits a window more than once unpacks the lane
+    ids before its scan, once over all windows (``unpack_lane_ids``,
+    ``_fused_onehot_program``'s ``hoist``), where the int32 ids fit the
+    one-hot route's share of HBM beside what it already holds
+    (``SGD._hoists_lane_ids``); any other runs the same function in the body
+    on the step's window. The ids are the same integers and no sum changes
+    its order: the two forms agree bit for bit."""
+
+    STEPS, N_DATA, SUB_ROWS, K, DIM = 6, 2, 64, 8, 30 * BLOCK
+    COUNTS = {3: CHUNK - 1, 5: CHUNK, 7: CHUNK + 1, 9: 150}
+
+    def _fit(self, ctx, lay, cols, chunk_len, premat, hoist):
+        """``STEPS`` steps through ``_fused_onehot_program`` in dispatches of
+        ``chunk_len``, as ``SGD._optimize_onehot`` makes them: the permuted
+        coefficient and the loss history."""
+        from flink_ml_tpu.ops import optimizer
+        from flink_ml_tpu.ops.schedule import chunked_schedule, offset_schedule
+        from flink_ml_tpu.parallel.mesh import MODEL_AXIS
+
+        program = optimizer._fused_onehot_program(
+            ctx, BinaryLogisticLoss.INSTANCE, lay, chunk_len, 0.3, 0.01, 0.5, None,
+            False, premat=premat, hoist=hoist,
+        )
+        sh = ctx.sharding(ctx.data_axes, MODEL_AXIS)
+        stacks = [jax.device_put(a, sh) for a in (lay.lidx, lay.rowid, lay.lvals)]
+        oh = optimizer._premat_materialize_jit(sh)(stacks[1], lay.row_hi) if premat else ()
+        rows = [jax.device_put(cols[c], ctx.sharding(ctx.data_axes)) for c in ("y", "w", "mask")]
+        starts, offsets = offset_schedule(len(cols["y"]) // self.N_DATA, lay.local_batch, self.STEPS)
+        win_idx = np.asarray([lay.window_starts.index(int(s)) for s in starts], np.int32)
+        coef = np.zeros(lay.nblk_local * lay.n_model * BLOCK, np.float32)
+        coef = jax.device_put(coef, ctx.model_dim) if lay.n_model > 1 else ctx.replicate(coef)
+        done, history = ctx.replicate(np.asarray(False)), []
+        for win_c, offsets_c, active_c, n_active in chunked_schedule(win_idx, offsets, self.STEPS, chunk_len):
+            coef, done, losses, n_exec = program(coef, done, win_c, offsets_c, active_c, *stacks, *oh, *rows)
+            assert int(n_exec) == n_active
+            history.extend(np.asarray(losses)[:n_active])
+        return np.asarray(coef), np.asarray(history)
+
+    @pytest.mark.parametrize("n_sub", [1, 4])
+    @pytest.mark.parametrize("n_model", [1, 2])
+    @pytest.mark.parametrize("premat", [True, False], ids=["premat", "build"])
+    def test_the_hoisted_program_is_the_body_form_bitwise(self, premat, n_model, n_sub):
+        rng = np.random.default_rng(50 + 10 * n_model + n_sub)
+        local_batch, n_windows = n_sub * self.SUB_ROWS, 2
+        idx = _heavy_rows(rng, self.N_DATA * n_windows * n_sub, self.SUB_ROWS, self.K, self.DIM, self.COUNTS)
+        n = idx.shape[0]
+        val = rng.normal(size=idx.shape).astype(np.float32)
+        cols = {"y": (rng.random(n) > 0.5).astype(np.float32), "w": rng.random(n).astype(np.float32),
+                "mask": np.ones(n, np.float32)}
+        lay = OneHotSparseLayout.build(
+            idx, val, self.DIM, self.N_DATA, local_batch, sub_rows=self.SUB_ROWS, n_model=n_model
+        )
+        assert (lay.n_windows, lay.n_sub) == (n_windows, n_sub) and len(lay.class_meta[-1]) == 5
+        with mesh_context(MeshContext(n_data=self.N_DATA, n_model=n_model)) as ctx:
+            coef_h, losses_h = self._fit(ctx, lay, cols, self.STEPS, premat, True)
+            coef_b, losses_b = self._fit(ctx, lay, cols, n_windows, premat, False)
+        assert len(losses_h) == self.STEPS and np.isfinite(losses_h).all() and np.any(coef_h)
+        np.testing.assert_array_equal(coef_h, coef_b)
+        np.testing.assert_array_equal(losses_h, losses_b)
+
+    @pytest.mark.parametrize("lead", [(), (3,)], ids=["a_window", "all_windows"])
+    def test_unpack_is_each_classs_cut_of_the_ids(self, lead):
+        rng = np.random.default_rng(51)
+        meta = ((5, 2, 0, 0), (3, 8, 10, 5), (2, CHUNK, 34, 8, ((2,),)))
+        lidx = rng.integers(0, BLOCK, size=lead + (4, 34 + 2 * CHUNK)).astype(np.int8)
+        parts = unpack_lane_ids(jnp.asarray(lidx), meta)
+        assert [p.shape for p in parts] == [lead + (4, f_c, wdt) for f_c, wdt, *_ in meta]
+        assert {p.dtype for p in parts} == {jnp.dtype(jnp.int32)}
+        for part, (f_c, wdt, off, *_) in zip(parts, meta):
+            np.testing.assert_array_equal(
+                np.asarray(part).reshape(lead + (4, -1)), lidx[..., off:off + f_c * wdt]
+            )
+
+    def test_a_resident_fit_hoists_and_a_streamed_one_unpacks_in_the_body(self):
+        """``train.program`` says which: ``lane_unpacks`` is the windows of a
+        program that unpacks before its scan and the steps of one that
+        unpacks in its body."""
+        from flink_ml_tpu import trace
+        from flink_ml_tpu.iteration import HostDataCache
+
+        rng = np.random.default_rng(52)
+        n, d = 512, 1 << 16
+        cols = TestPrematSgd()._cols(rng, n, d, 4)
+        with mesh_context(MeshContext(n_data=2, n_model=1)) as ctx:
+            kw = dict(global_batch_size=128, tol=0.0, learning_rate=0.3, ctx=ctx, sparse_kernel="onehot")
+            with trace.capture() as recorder:  # 256 local rows in 4 windows of 64, 8 steps in one program
+                SGD(max_iter=8, **kw).optimize(
+                    np.zeros(d, np.float32), DeviceDataCache(dict(cols), ctx=ctx), BinaryLogisticLoss.INSTANCE
+                )
+            (resident,) = [s.attrs for s in recorder.snapshot() if s.name == "train.program"]
+            assert (resident["steps"], resident["lane_unpacks"]) == (8, 4)
+            cache = HostDataCache()
+            for a in range(0, n, 64):
+                cache.append({k: v[a: a + 64] for k, v in cols.items()})
+            cache.finish()
+            with trace.capture() as recorder:  # two windows of 128 local rows: 2 minibatches each, 2 steps a program
+                SGD(max_iter=8, stream_window_rows=128, **kw).optimize(
+                    np.zeros(d, np.float32), cache, BinaryLogisticLoss.INSTANCE
+                )
+            (streamed,) = [s.attrs for s in recorder.snapshot() if s.name == "train.program"]
+            assert streamed["steps"] == streamed["lane_unpacks"] == 2
+
+    @pytest.mark.parametrize("premat", ["auto", "off"], ids=["premat", "build"])
+    def test_the_hoist_is_budgeted_after_premat_and_never_against_it(self, premat, monkeypatch):
+        """A many-window fit on either route: with room the program hoists;
+        where the ids beside what the route already holds (the packed stacks,
+        and the row one-hots on the premat route) would overrun the one-hot
+        route's share of HBM, the body form runs, premat stays what it was
+        with its memo, and the fit is the hoisted one's bit for bit."""
+        import flink_ml_tpu.ops.optimizer as opt
+        from flink_ml_tpu import trace
+
+        rng = np.random.default_rng(53)
+        cols = TestPrematSgd()._cols(rng, 1024, 800, 8)
+        steps = 12  # over a shard's 8 windows of 64 rows
+
+        def fit(sgd):
+            with trace.capture() as recorder:
+                coef = sgd.optimize(np.zeros(800, np.float32), cache, BinaryLogisticLoss.INSTANCE)
+            (program,) = [s.attrs for s in recorder.snapshot() if s.name == "train.program"]
+            return np.asarray(coef), np.asarray(sgd.loss_history), program
+
+        with mesh_context(MeshContext(n_data=2, n_model=1)) as ctx:
+            cache = DeviceDataCache(dict(cols), ctx=ctx)
+            kw = dict(global_batch_size=128, tol=0.0, ctx=ctx, sparse_kernel="onehot", onehot_premat=premat)
+            sgd = SGD(max_iter=steps, **kw)
+            coef_h, losses_h, program = fit(sgd)
+            lay = cache._onehot_memo[1]
+            assert (program["steps"], program["lane_unpacks"]) == (steps, lay.n_windows) == (steps, 8)
+            assert sgd.onehot_premat_active == (premat == "auto")
+            memo = getattr(cache, "_onehot_premat_memo", None)
+            n_units = lay.n_windows * lay.n_sub
+            held = 7 * n_units * lay.n_flat
+            if premat == "auto":
+                held += premat_bytes(n_units, lay.n_flat, lay.row_hi)
+            ids = lane_ids_bytes(n_units, lay.class_meta)
+            # a class's rows to the 8 sublanes, its width to the 128 lanes, 4 B an id
+            assert ids == 4 * n_units * sum(-(-f // 8) * 8 * BLOCK for f, *_ in lay.class_meta) > 4 * n_units * lay.n_flat
+            # a budget between the two sums: what the parent held fits, the ids beside it do not
+            limit = (held + ids // 2) / SGD._ONEHOT_PREMAT_HBM_FRACTION
+            monkeypatch.setattr(opt, "_hbm_bytes_limit", lambda ctx=None: limit)
+            assert not sgd._hoists_lane_ids(lay, steps, sgd.onehot_premat_active, ctx)
+            coef_b, losses_b, program = fit(sgd)
+            assert (program["steps"], program["lane_unpacks"]) == (steps, steps)
+            assert sgd.onehot_premat_active == (premat == "auto")
+            assert getattr(cache, "_onehot_premat_memo", None) is memo
+            np.testing.assert_array_equal(coef_b, coef_h)
+            np.testing.assert_array_equal(losses_b, losses_h)
+            # and with room again, only a program that visits a window twice hoists
+            monkeypatch.setattr(opt, "_hbm_bytes_limit", lambda ctx=None: (held + ids) / SGD._ONEHOT_PREMAT_HBM_FRACTION + 1)
+            assert sgd._hoists_lane_ids(lay, lay.n_windows + 1, sgd.onehot_premat_active, ctx)
+            assert not sgd._hoists_lane_ids(lay, lay.n_windows, sgd.onehot_premat_active, ctx)
 
 
 class TestSgdIntegration:
