@@ -70,6 +70,9 @@ class MLMetrics:
     TRAIN_LM_CONV_POSITIONS = "ml.train.lm.conv.positions"  # positions x channels of the Mamba-2 layers' causal convolutions (x layers x steps, forward), counter
     TRAIN_LM_CONV_KERNEL_POSITIONS = "ml.train.lm.conv.kernel_positions"  # those of them that the convolution's kernel pair covered (parallel/causal_conv.py), counter
     TRAIN_LM_SCAN_LAYERS = "ml.train.lm.scan.layers"  # Mamba-2 layer applications (layers x steps), counter
+    TRAIN_LM_KDA_CHUNKS = "ml.train.lm.kda.chunks"  # chunks of the gated delta rule (chunks x heads x sequences x delta-rule layers x steps), counter
+    TRAIN_LM_KDA_KERNEL_CHUNKS = "ml.train.lm.kda.kernel_chunks"  # those of them that passed through the delta rule's kernel pair (parallel/kda.py), counter
+    TRAIN_LM_KDA_LAYERS = "ml.train.lm.kda.layers"  # delta-rule layer applications (layers x steps), counter
     TRAIN_LM_MLA_LAYERS = "ml.train.lm.mla.layers"  # latent-attention layer applications (layers, a multi-token-prediction module's among them, x steps), counter
     TRAIN_LM_MTP_TARGETS = "ml.train.lm.mtp.targets"  # positions the multi-token-prediction module scored (sequences x (length - 2) x steps), counter
     TRAIN_MOE_ROWS = "ml.train.moe.rows"  # (token, expert) rows the experts held here ran, counter

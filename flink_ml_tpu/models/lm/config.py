@@ -2,7 +2,8 @@
 tree, shared by the stage (``decoder_lm.py``) and the plain references
 (``reference.py``, ``reference_zaya.py``, ``reference_ouro.py``,
 ``reference_laguna.py``, ``reference_nemotron.py``, ``reference_joyai.py``,
-``reference_sdar.py``) so that one set of weights can be handed to both.
+``reference_sdar.py``, ``reference_solar.py``) so that one set of weights can
+be handed to both.
 
 ``layers(cfg)`` gives one hashable record a layer (``Layer``): a MIXER, a
 FEED-FORWARD (either may be absent), the stream's width and the norms'
@@ -11,7 +12,7 @@ learned per-channel scale and bias on both. The parameter tree
 (``param_shapes``), the forward (``decoder_lm._layer``), the fit's counts and
 the stage's checks all read that record and nothing else about a layer.
 
-``LMConfig.block`` names one of seven PRESETS over that description
+``LMConfig.block`` names one of eight PRESETS over that description
 (``_PRESETS``: the only place that names a model), each a published stack:
 
 ========== ================================================= ==========================================
@@ -32,6 +33,10 @@ joyai      latent attention: queries, keys and values      dense (the first ``n_
 sdar       attention on grouped queries, a QK-norm over EACH experts, linear router, the chosen softmax
            head's channels, RoPE on the whole head, under    gates renormalised over all ``top_k``
            the block-diffusion mask of a doubled sequence
+solar_open2 the gated delta rule with a decay a key channel   experts with sigmoid gates beside a shared
+           (``KDA``); in the layers ``gqa_layers`` names,    expert (laguna's record), in every layer
+           attention on grouped queries without rotation
+           under an element-wise sigmoid gate on its output
 ========== ================================================= ==========================================
 
 ``sdar`` is also the one kind whose OBJECTIVE is not next-token prediction:
@@ -63,7 +68,9 @@ The sublayers, with ``a = heads * head_dim`` and ``c = kv_heads * head_dim``:
   k or none; a sliding ``window`` of keys or 0; or, ``diffusion_block`` > 0, the
   block-diffusion mask over a doubled sequence in blocks of that many
   positions): ``"wq": [d, a], "wk"/"wv": [d, c]``, ``"head_gate": [d, heads]``
-  under a sigmoid gate a head on the output, ``"wo": [a, d]``, ``"q_norm": [a],
+  under a sigmoid gate a head on the output, ``"wg": [d, a]`` under an
+  element-wise sigmoid gate on it (``out_gate``: read from the normed input,
+  applied before ``wo``), ``"wo": [a, d]``, ``"q_norm": [a],
   "k_norm": [c]`` under a QK-norm over the whole projection (``qk_norm``
   ``"projection"``) or ``"q_norm"/"k_norm": [head_dim]`` under one over each
   head's channels (``"head"``), the output's norm ``[d]`` if any
@@ -88,6 +95,16 @@ The sublayers, with ``a = heads * head_dim`` and ``c = kv_heads * head_dim``:
   ``"conv_w": [conv_kernel, c]`` (tap ``conv_kernel - 1`` reads the position
   itself), ``"conv_b": [c]``, ``"dt_bias"``, ``"A_log"``, ``"D"``: ``[heads]``,
   ``"gate_norm": [i]``, ``"out_proj": [i, d]``.
+- ``KDA`` (the gated delta rule with a log-decay of its own on every key
+  channel behind a short causal convolution; ``reference_solar.py``), ``i =
+  heads * head_dim`` and the gates' rank ``r = head_dim``: ``"wq"/"wk"/"wv": [d,
+  i]``, ``"conv_q"/"conv_k"/"conv_v": [conv_kernel, i]`` (no bias; tap
+  ``conv_kernel - 1`` reads the position itself), the decay gate ``"Fa": [d,
+  r], "Fb": [r, i], "A_log": [heads], "dt_bias": [i]``, the correction's
+  strength ``"Wb": [d, heads]``, the output gate ``"Ga": [d, r], "Gb": [r,
+  i]``, the output's norm over each head's channels ``"o_norm": [head_dim]``,
+  ``"wo": [i, d]``. ``heads`` may be a chip's share of the layer's: ``wo``'s
+  output is then the held heads' part of the sum.
 - ``Dense`` (one SwiGLU): ``"w_gate"/"w_up": [d, width], "w_down": [width,
   d]``, the output's norm ``[d]`` if any (``ffn_out_norm``).
 - ``Experts`` (``top_k`` of ``E`` routed experts, ``H`` of them HELD here:
@@ -110,7 +127,7 @@ import dataclasses
 import math
 from typing import List, NamedTuple, Optional, Tuple, Union
 
-__all__ = ["LMConfig", "BLOCKS", "MIXERS", "Rotation", "Attention", "LatentAttention", "CCA", "Mamba2", "Dense",
+__all__ = ["LMConfig", "BLOCKS", "MIXERS", "Rotation", "Attention", "LatentAttention", "CCA", "Mamba2", "KDA", "Dense",
            "Experts", "Layer", "layers", "exit_gate", "mtp_layer", "leaves", "param_shapes", "num_params", "ONES",
            "ZEROS", "NORMAL", "SMALL",
            "SMALL_SCALE", "DT_BIAS", "A_LOG", "DT_RANGE", "DT_FLOOR", "A_RANGE", "NOISE_EPS"]
@@ -128,7 +145,7 @@ MIXERS = ("M", "*", "E")
 #: 30). At a fiftieth the two are level and what is left is the data's own skew.
 ONES, ZEROS, NORMAL, SMALL = "ones", "zeros", "normal", "small"
 SMALL_SCALE = 0.02
-#: A Mamba-2 layer's two leaves that start from a uniform draw ``u`` of the
+#: A Mamba-2 layer's (and a delta-rule layer's) two leaves that start from a uniform draw ``u`` of the
 #: leaf's own stream: the step size's bias at the inverse softplus of ``dt =
 #: max(exp(log DT_RANGE[0] + u log(DT_RANGE[1] / DT_RANGE[0])), DT_FLOOR)``
 #: (``time_step_min``, ``_max``, ``_floor`` of the published file), and ``A_log``
@@ -202,6 +219,11 @@ class LMConfig(NamedTuple):
     # the id a masked token is replaced with
     block_length: int = 0
     mask_id: int = 0
+    # the solar_open2 block's own: the layers that attend (the others run the gated delta rule), and the delta rule's
+    # heads and channels a head (its convolution's taps and its chunk are ``conv_kernel`` and ``chunk``)
+    gqa_layers: Tuple[int, ...] = ()
+    kda_heads: int = 0
+    kda_head_dim: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -241,6 +263,7 @@ class Attention:
     window: int = 0  # keys each query keeps, its own among them; 0: all before it
     qk_norm: str = ""  # an RMS norm on q and k: "projection" over all heads' channels together, "head" over each's
     head_gate: bool = False
+    out_gate: bool = False  # an element-wise sigmoid gate on the heads' output, read from the normed input
     norm: str = "attn_norm"
     out_norm: str = ""  # the leaf of the norm on the output; empty: none
     diffusion_block: int = 0  # > 0: the sequence is doubled, [x ; x~], under the block-diffusion mask in such blocks
@@ -283,6 +306,15 @@ class Mamba2:
 
 
 @dataclasses.dataclass(frozen=True)
+class KDA:
+    heads: int  # held here: all of the layer's, or a chip's share of them
+    head_dim: int  # key and value channels a head, and the rank of the decay and output gates
+    conv_kernel: int
+    chunk: int
+    norm: str = "attn_norm"
+
+
+@dataclasses.dataclass(frozen=True)
 class Dense:
     width: int
     norm: str = "ffn_norm"
@@ -309,7 +341,7 @@ class Experts:
 class Layer:
     hidden: int
     eps: float
-    mixer: Union[Attention, LatentAttention, CCA, Mamba2, None]
+    mixer: Union[Attention, LatentAttention, CCA, Mamba2, KDA, None]
     ffn: Union[Dense, Experts, None]
     scaled: bool = False  # a learned scale and bias on the residual and on each sublayer's output
 
@@ -374,9 +406,18 @@ def _sdar(cfg: LMConfig):
     return (Layer(cfg.hidden, cfg.norm_eps, mixer, _experts(cfg, renormalise=True)),) * cfg.n_layers
 
 
+def _solar_open2(cfg: LMConfig):
+    attention = Attention(cfg.n_heads, cfg.kv_heads, cfg.head_size, out_gate=True)
+    delta = KDA(cfg.kda_heads, cfg.kda_head_dim, cfg.conv_kernel, cfg.chunk)
+    sparse = _experts(cfg, routed_scale=cfg.routed_scale, shared_width=cfg.shared_width)
+    return tuple(Layer(cfg.hidden, cfg.norm_eps, attention if i in cfg.gqa_layers else delta, sparse)
+                 for i in range(cfg.n_layers))
+
+
 #: kind -> (its layers, whether every pass of the stack ends in an exit gate: a linear with a bias)
 _PRESETS = {"olmoe": (_olmoe, False), "zaya": (_zaya, False), "ouro": (_ouro, True), "laguna": (_laguna, False),
-            "nemotron_h": (_nemotron_h, False), "joyai": (_joyai, False), "sdar": (_sdar, False)}
+            "nemotron_h": (_nemotron_h, False), "joyai": (_joyai, False), "sdar": (_sdar, False),
+            "solar_open2": (_solar_open2, False)}
 BLOCKS = tuple(_PRESETS)
 
 
@@ -407,7 +448,8 @@ def _matrices(prefix: str, lead: tuple, d: int, h: int, gated: bool):
 def _attention_own(m: Attention, d: int):
     a, c = m.heads * m.head_dim, m.kv_heads * m.head_dim
     return ((("wq", (d, a), NORMAL), ("wk", (d, c), NORMAL), ("wv", (d, c), NORMAL))
-            + ((("head_gate", (d, m.heads), NORMAL),) if m.head_gate else ()) + (("wo", (a, d), NORMAL),)
+            + ((("head_gate", (d, m.heads), NORMAL),) if m.head_gate else ())
+            + ((("wg", (d, a), NORMAL),) if m.out_gate else ()) + (("wo", (a, d), NORMAL),)
             + tuple((name, (m.head_dim if m.qk_norm == "head" else width,), ONES)
                     for name, width in (("q_norm", a), ("k_norm", c)) if m.qk_norm)
             + (((m.out_norm, (d,), ONES),) if m.out_norm else ()))
@@ -437,6 +479,17 @@ def _mamba2_own(m: Mamba2, d: int):
             ("D", (m.heads,), ONES), ("gate_norm", (inner,), ONES), ("out_proj", (inner, d), NORMAL))
 
 
+def _kda_own(m: KDA, d: int):
+    inner, rank = m.heads * m.head_dim, m.head_dim
+    return (("wq", (d, inner), NORMAL), ("wk", (d, inner), NORMAL), ("wv", (d, inner), NORMAL),
+            ("conv_q", (m.conv_kernel, inner), NORMAL), ("conv_k", (m.conv_kernel, inner), NORMAL),
+            ("conv_v", (m.conv_kernel, inner), NORMAL),
+            ("Fa", (d, rank), NORMAL), ("Fb", (rank, inner), NORMAL), ("A_log", (m.heads,), A_LOG),
+            ("dt_bias", (inner,), DT_BIAS), ("Wb", (d, m.heads), NORMAL),
+            ("Ga", (d, rank), NORMAL), ("Gb", (rank, inner), NORMAL), ("o_norm", (m.head_dim,), ONES),
+            ("wo", (inner, d), NORMAL))
+
+
 def _dense_own(f: Dense, d: int):
     return _matrices("w", (), d, f.width, True) + (((f.out_norm, (d,), ONES),) if f.out_norm else ())
 
@@ -455,7 +508,7 @@ def _experts_own(f: Experts, d: int):
 
 
 _OWN_LEAVES = {Attention: _attention_own, LatentAttention: _latent_own, CCA: _cca_own, Mamba2: _mamba2_own,
-               Dense: _dense_own,
+               KDA: _kda_own, Dense: _dense_own,
                Experts: _experts_own}
 
 

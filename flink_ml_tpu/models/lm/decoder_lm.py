@@ -8,10 +8,12 @@ norm its record names (``_attend``); latent attention, whose queries, keys and
 values are rebuilt from low-rank latents and whose heads are wider in their
 keys than in their values (``_latent_attend``); ZAYA's compressed convolutional
 attention (``_cca``); a Mamba-2 scan behind its convolution (``_mamba2``:
-``parallel/causal_conv.py``, ``parallel/ssd.py``) - and a feed-forward - one
+``parallel/causal_conv.py``, ``parallel/ssd.py``); the gated delta rule with a
+decay a key channel behind the same convolution (``_kda``:
+``parallel/kda.py``) - and a feed-forward - one
 dense SwiGLU or a dropless mixture of experts, ``parallel/moe.py``
 (``_feed_forward``) - either of which may be absent, each joined to the
-residual stream (``_layer``). ``blockKind`` names one of seven presets over that
+residual stream (``_layer``). ``blockKind`` names one of eight presets over that
 description, each a published stack with its plain reference beside it
 (``config.py`` has the table and every leaf): ``olmoe`` (``reference.py``),
 ``zaya`` (ZAYA1-8B, ``reference_zaya.py``), ``ouro`` (Ouro-2.6B's looped LM,
@@ -42,7 +44,15 @@ block-causal on the clean half, strictly block-causal from the noised half
 onto it and block-diagonal inside the noised half (``parallel/flash.py``, "The
 block-diffusion mask"), and the head scores the noised half's masked positions
 on their OWN tokens with weight ``1 / p``; ``transform`` reports a one-draw
-estimate of that bound a row). The fit loop, the head, the loss's chunking, the
+estimate of that bound a row) and ``solar_open2`` (Solar-Open2-250B,
+``reference_solar.py``: three layers in four run the gated delta rule on
+normalised queries and keys with a low-rank decay gate a key channel, a
+correction strength in (0, 2) and a gated norm on the output; the layers
+``gqaLayers`` names attend on grouped queries without a position encoding under
+an element-wise sigmoid gate; every layer has sigmoid-gated experts beside a
+shared one; ``kdaNumHeads``, ``numHeads`` and ``numKvHeads`` may be ONE chip's
+share of each layer's heads: ``wo``'s output is then the held heads' part of
+the sum, and nothing stands in for the absent chips). The fit loop, the head, the loss's chunking, the
 clip and the AdamW program are one.
 
 Any expert kind may tie the head to the embedding (``tieEmbeddings``: one leaf)
@@ -120,7 +130,7 @@ from flink_ml_tpu.api.core import Estimator, Model
 from flink_ml_tpu.api.types import DataTypes
 from flink_ml_tpu.metrics import MLMetrics, metrics
 from flink_ml_tpu.models.lm.config import (
-    A_LOG, A_RANGE, BLOCKS, CCA, DT_BIAS, DT_FLOOR, DT_RANGE, MIXERS, NOISE_EPS, NORMAL, ONES, SMALL, SMALL_SCALE, Attention,
+    A_LOG, A_RANGE, BLOCKS, CCA, DT_BIAS, DT_FLOOR, DT_RANGE, KDA, MIXERS, NOISE_EPS, NORMAL, ONES, SMALL, SMALL_SCALE, Attention,
     Dense, Experts, LatentAttention, Layer, LMConfig, Mamba2, exit_gate, layers, mtp_layer, num_params, param_shapes,
 )
 from flink_ml_tpu.params.param import (
@@ -152,6 +162,7 @@ from flink_ml_tpu.parallel.flash import (
     fold_kernel_calls,
     fused_attention,
 )
+from flink_ml_tpu.parallel.kda import kda_kernel_chunks, kda_scan
 from flink_ml_tpu.parallel.mesh import is_tpu_backend
 from flink_ml_tpu.parallel.moe import dense_swiglu, moe_dropless
 from flink_ml_tpu.parallel.ssd import scan_kernel_chunks, ssd_scan
@@ -219,7 +230,9 @@ class _LMParams(
         "'joyai' (latent attention on low-rank queries, keys and values, leading dense layers, sigmoid-gated "
         "experts beside a shared one, a multi-token-prediction module behind the stack) or "
         "'sdar' (grouped-query attention with a QK-norm a head, experts whose softmax gates are renormalised over the "
-        "chosen, trained by block diffusion over the doubled sequence).",
+        "chosen, trained by block diffusion over the doubled sequence) or "
+        "'solar_open2' (the gated delta rule with a decay a key channel in three layers of four, gated attention "
+        "without a position encoding in the layers gqaLayers names, sigmoid-gated experts beside a shared one).",
         "olmoe", ParamValidators.in_array(list(BLOCKS)),
     )
     TIE_EMBEDDINGS = BoolParam("tieEmbeddings", "The head is the embedding table transposed.", False)
@@ -235,11 +248,11 @@ class _LMParams(
     FIRST_EXPERT_HELD = IntParam("firstExpertHeld", "First expert of the held range.", 0,
                                  ParamValidators.gt_eq(0))
     NUM_KV_HEADS = IntParam(
-        "numKvHeads", "Key/value heads ('zaya', 'laguna', 'nemotron_h', 'sdar'; the query heads divide evenly over them). "
+        "numKvHeads", "Key/value heads ('zaya', 'laguna', 'nemotron_h', 'sdar', 'solar_open2'; the query heads divide evenly over them). "
         "0: numHeads.", 0,
         ParamValidators.gt_eq(0),
     )
-    HEAD_SIZE = IntParam("headSize", "Channels per head ('zaya', 'laguna', 'nemotron_h', 'sdar'). 0: hiddenSize / numHeads.", 0,
+    HEAD_SIZE = IntParam("headSize", "Channels per head ('zaya', 'laguna', 'nemotron_h', 'sdar', 'solar_open2'). 0: hiddenSize / numHeads.", 0,
                          ParamValidators.gt_eq(0))
     ROPE_FRACTION = FloatParam(
         "ropeFraction", "Share of each head's channels the rotary embedding turns ('zaya'; 'laguna': in "
@@ -267,11 +280,11 @@ class _LMParams(
                            ParamValidators.gt_eq(0))
     SHARED_EXPERT_WIDTH = IntParam(
         "sharedExpertWidth", "Hidden width of the expert every token passes beside the routed ones ('laguna', "
-        "'nemotron_h', 'joyai').", 0,
+        "'nemotron_h', 'joyai', 'solar_open2').", 0,
         ParamValidators.gt_eq(0))
     ROUTED_SCALE = FloatParam(
         "routedScale", "The sigmoid gates of the chosen experts are renormalised to sum to one and scaled by "
-        "this ('laguna', 'nemotron_h', 'joyai').", 1.0, ParamValidators.gt(0))
+        "this ('laguna', 'nemotron_h', 'joyai', 'solar_open2').", 1.0, ParamValidators.gt(0))
     WINDOW_ROPE_THETA = FloatParam("windowRopeTheta", "Base of the rotary embedding in windowed layers, which "
                                    "turn every channel ('laguna').", 10000.0, ParamValidators.gt(0))
     ROPE_YARN = FloatArrayParam(
@@ -288,9 +301,17 @@ class _LMParams(
     SSM_STATE_SIZE = IntParam("ssmStateSize", "Width of a scan head's state ('nemotron_h').", 0,
                               ParamValidators.gt_eq(0))
     SSM_CONV_KERNEL = IntParam("ssmConvKernel", "Taps of the causal depthwise convolution before the scan "
-                               "('nemotron_h').", 4, ParamValidators.gt(0))
-    SSM_CHUNK_SIZE = IntParam("ssmChunkSize", "Positions a chunk of the scan; the sequence length is a multiple "
-                              "('nemotron_h').", 128, ParamValidators.gt(0))
+                               "('nemotron_h') or the delta rule ('solar_open2').", 4, ParamValidators.gt(0))
+    SSM_CHUNK_SIZE = IntParam("ssmChunkSize", "Positions a chunk of the scan ('nemotron_h') or of the delta rule "
+                              "('solar_open2': a power of two); the sequence length is a multiple.", 128,
+                              ParamValidators.gt(0))
+    GQA_LAYERS = IntArrayParam(
+        "gqaLayers", "The layers that attend (grouped queries, no position encoding, a gated output); every other "
+        "layer runs the gated delta rule ('solar_open2').", [])
+    KDA_NUM_HEADS = IntParam("kdaNumHeads", "Heads of a delta-rule layer held here: all of them, or one chip's share "
+                             "('solar_open2').", 0, ParamValidators.gt_eq(0))
+    KDA_HEAD_SIZE = IntParam("kdaHeadSize", "Key and value channels of a delta-rule head, and the rank of its decay "
+                             "and output gates ('solar_open2').", 0, ParamValidators.gt_eq(0))
     Q_LORA_RANK = IntParam("qLoraRank", "Width of the latent the queries are rebuilt from ('joyai').", 0,
                            ParamValidators.gt_eq(0))
     KV_LORA_RANK = IntParam("kvLoraRank", "Width of the latent a token's keys and values are rebuilt from ('joyai').",
@@ -326,44 +347,50 @@ class _LMParams(
     #: The other kinds leave it at ``LMConfig``'s default (no experts; no balancing loss: a bias rule outside the
     #: gradient balances every kind but 'olmoe', reference_zaya.py).
     _OWN = {
-        ("olmoe", "zaya", "laguna", "nemotron_h", "joyai", "sdar"): (("n_experts", NUM_EXPERTS),
-                                                                     ("top_k", EXPERTS_PER_TOKEN)),
+        ("olmoe", "zaya", "laguna", "nemotron_h", "joyai", "sdar", "solar_open2"): (("n_experts", NUM_EXPERTS),
+                                                                                    ("top_k", EXPERTS_PER_TOKEN)),
         ("olmoe",): (("aux_coef", AUX_LOSS_COEF),),
-        ("zaya", "laguna", "nemotron_h", "sdar"): (("n_kv_heads", NUM_KV_HEADS), ("head_size", HEAD_SIZE)),
+        ("zaya", "laguna", "nemotron_h", "sdar", "solar_open2"): (("n_kv_heads", NUM_KV_HEADS),
+                                                                  ("head_size", HEAD_SIZE)),
         ("zaya", "laguna"): (("rope_fraction", ROPE_FRACTION),),
         ("zaya",): (("router_width", ROUTER_WIDTH),),
         ("ouro",): (("loops", NUM_LOOPS), ("exit_beta", EXIT_ENTROPY_COEF)),
         ("laguna",): (("layer_heads", NUM_HEADS_PER_LAYER), ("layer_windows", WINDOW_PER_LAYER),
                       ("window_rope_theta", WINDOW_ROPE_THETA), ("yarn", ROPE_YARN)),
         ("laguna", "joyai"): (("n_dense", DENSE_LAYERS), ("dense_width", DENSE_WIDTH)),
-        ("laguna", "nemotron_h", "joyai"): (("shared_width", SHARED_EXPERT_WIDTH), ("routed_scale", ROUTED_SCALE)),
+        ("laguna", "nemotron_h", "joyai", "solar_open2"): (("shared_width", SHARED_EXPERT_WIDTH),
+                                                           ("routed_scale", ROUTED_SCALE)),
         ("joyai",): (("q_rank", Q_LORA_RANK), ("kv_rank", KV_LORA_RANK), ("nope_dim", QK_NOPE_HEAD_SIZE),
                      ("rope_dim", QK_ROPE_HEAD_SIZE), ("v_dim", V_HEAD_SIZE), ("mtp_depth", MTP_DEPTH),
                      ("mtp_coef", MTP_LOSS_COEF)),
         ("sdar",): (("block_length", BLOCK_LENGTH), ("mask_id", MASK_TOKEN_ID)),
         ("nemotron_h",): (("layer_kinds", LAYER_PATTERN), ("ssm_heads", SSM_NUM_HEADS), ("ssm_head_dim", SSM_HEAD_SIZE),
-                          ("ssm_groups", SSM_NUM_GROUPS), ("ssm_state", SSM_STATE_SIZE),
-                          ("conv_kernel", SSM_CONV_KERNEL), ("chunk", SSM_CHUNK_SIZE)),
+                          ("ssm_groups", SSM_NUM_GROUPS), ("ssm_state", SSM_STATE_SIZE)),
+        ("nemotron_h", "solar_open2"): (("conv_kernel", SSM_CONV_KERNEL), ("chunk", SSM_CHUNK_SIZE)),
+        ("solar_open2",): (("gqa_layers", GQA_LAYERS), ("kda_heads", KDA_NUM_HEADS), ("kda_head_dim", KDA_HEAD_SIZE)),
     }
     #: The params refused where they are given a value under a kind they do not belong to.
     _REFUSED = (
-        ((NUM_KV_HEADS, HEAD_SIZE), ("zaya", "laguna", "nemotron_h", "sdar"),
-         "numKvHeads and headSize belong to blockKind 'zaya', 'laguna', 'nemotron_h' or 'sdar'"),
+        ((NUM_KV_HEADS, HEAD_SIZE), ("zaya", "laguna", "nemotron_h", "sdar", "solar_open2"),
+         "numKvHeads and headSize belong to blockKind 'zaya', 'laguna', 'nemotron_h', 'sdar' or 'solar_open2'"),
+        ((GQA_LAYERS, KDA_NUM_HEADS, KDA_HEAD_SIZE), ("solar_open2",),
+         "gqaLayers, kdaNumHeads and kdaHeadSize belong to blockKind 'solar_open2'"),
         ((ROPE_FRACTION,), ("zaya", "laguna", "nemotron_h"),
          "ropeFraction belongs to blockKind 'zaya', 'laguna' or 'nemotron_h'"),
         ((BLOCK_LENGTH, MASK_TOKEN_ID), ("sdar",), "blockLength and maskTokenId belong to blockKind 'sdar'"),
         ((NUM_HEADS_PER_LAYER, WINDOW_PER_LAYER), ("laguna",),
          "numHeadsPerLayer and windowPerLayer belong to blockKind 'laguna'"),
         ((DENSE_LAYERS,), ("laguna", "joyai"), "denseLayers belongs to blockKind 'laguna' or 'joyai'"),
-        ((SHARED_EXPERT_WIDTH,), ("laguna", "nemotron_h", "joyai"),
-         "sharedExpertWidth belongs to blockKind 'laguna', 'nemotron_h' or 'joyai'"),
+        ((SHARED_EXPERT_WIDTH,), ("laguna", "nemotron_h", "joyai", "solar_open2"),
+         "sharedExpertWidth belongs to blockKind 'laguna', 'nemotron_h', 'joyai' or 'solar_open2'"),
         ((Q_LORA_RANK, KV_LORA_RANK, QK_NOPE_HEAD_SIZE, QK_ROPE_HEAD_SIZE, V_HEAD_SIZE, MTP_DEPTH), ("joyai",),
          "qLoraRank, kvLoraRank, qkNopeHeadSize, qkRopeHeadSize, vHeadSize and mtpDepth belong to blockKind 'joyai'"),
         ((LAYER_PATTERN, SSM_NUM_HEADS), ("nemotron_h",), "layerPattern and the ssm sizes belong to blockKind 'nemotron_h'"),
         ((NUM_LOOPS,), ("ouro",), "numLoops belongs to blockKind 'ouro'"),
-        ((TIE_EMBEDDINGS, EXPERTS_HELD, FIRST_EXPERT_HELD), ("olmoe", "zaya", "laguna", "nemotron_h", "joyai", "sdar"),
+        ((TIE_EMBEDDINGS, EXPERTS_HELD, FIRST_EXPERT_HELD),
+         ("olmoe", "zaya", "laguna", "nemotron_h", "joyai", "sdar", "solar_open2"),
          "tieEmbeddings, expertsHeld and firstExpertHeld do not belong to blockKind 'ouro'"),
-        ((TIE_EMBEDDINGS,), ("olmoe", "zaya", "ouro", "laguna", "joyai", "sdar"),
+        ((TIE_EMBEDDINGS,), ("olmoe", "zaya", "ouro", "laguna", "joyai", "sdar", "solar_open2"),
          "tieEmbeddings does not belong to blockKind 'nemotron_h'"),
     )
 
@@ -390,6 +417,8 @@ class _LMParams(
         if set(cfg.layer_kinds) - set(MIXERS):
             raise ValueError(f"layerPattern names each of the {cfg.n_layers} layers by one of {MIXERS}; got "
                              f"{''.join(cfg.layer_kinds)!r}")
+        if cfg.gqa_layers and not all(0 <= i < cfg.n_layers for i in cfg.gqa_layers):
+            raise ValueError(f"gqaLayers names layers among the {cfg.n_layers}; got {list(cfg.gqa_layers)}")
         if cfg.n_dense > cfg.n_layers:
             raise ValueError(f"denseLayers {cfg.n_dense} is at most numLayers {cfg.n_layers}")
         if not cfg.head_size and cfg.hidden % cfg.n_heads:
@@ -425,6 +454,9 @@ def _check_mixer(m) -> None:
     if isinstance(m, Mamba2) and not (m.heads and m.head_dim and m.state and m.groups and m.heads % m.groups == 0):
         raise ValueError(f"a Mamba-2 layer needs ssmNumHeads ({m.heads}) in whole ssmNumGroups ({m.groups}), "
                          f"ssmHeadSize ({m.head_dim}) and ssmStateSize ({m.state})")
+    if isinstance(m, KDA) and not (m.heads > 0 and m.head_dim > 0 and m.chunk & (m.chunk - 1) == 0):
+        raise ValueError(f"a delta-rule layer needs kdaNumHeads ({m.heads}), kdaHeadSize ({m.head_dim}) and a chunk "
+                         f"(ssmChunkSize) that is a power of two ({m.chunk})")
     rotation = getattr(m, "rotation", None)
     if rotation is not None and rotation.interleaved != isinstance(m, LatentAttention):
         raise ValueError("latent attention turns interleaved pairs of its rotary channels, the other mixers the two "
@@ -746,12 +778,51 @@ def _mamba2(x, layer, m: Mamba2, eps: float, cd, interpret: bool):
     return _proj(y, layer["out_proj"], cd)
 
 
+#: Added to a query's or key's squared length before its root is taken (the delta rule's unit vectors).
+UNIT_EPS = 1e-6
+
+
+def _kda(x, layer, m: KDA, eps: float, cd, interpret: bool):
+    """A delta-rule layer (``reference_solar.py`` has the equations): q, k and
+    v through ONE projection, a causal depthwise convolution and SiLU; q and k
+    at unit length a head (q over ``sqrt(head_dim)`` more); a log-decay a key
+    channel from a low-rank gate, ``-exp(A_log) softplus(.)``, and the
+    correction's strength ``2 sigmoid(.)`` a head; the gated delta rule in
+    chunks; each head's output RMS-normed and gated by a low-rank sigmoid
+    gate; one projection back (of the heads held here: a share of the layer's
+    sum where they are a share of its heads). The decays, the strengths, the
+    unit vectors and the rule's state are float32 whatever ``cd`` is."""
+    b, t, _ = x.shape
+    h, d = m.heads, m.head_dim
+    inner = h * d
+    a = _rms_norm(x, layer[m.norm], eps)
+    with jax.named_scope("proj"):
+        u = _matmul(a, jnp.concatenate([layer["wq"], layer["wk"], layer["wv"]], axis=1), cd)
+    with jax.named_scope("conv"):
+        # the convolution's kernels read q, k and v where they lie in u and write each activated as an array of its own
+        taps = jnp.concatenate([layer["conv_q"], layer["conv_k"], layer["conv_v"]], axis=1)
+        _, (q, k, v), _ = causal_conv(u, taps, jnp.zeros((3 * inner,), jnp.float32), (inner,) * 3)
+    with jax.named_scope("kgate"):
+        dt = jax.nn.softplus(_matmul(_matmul(a, layer["Fa"], cd), layer["Fb"], cd) + layer["dt_bias"])
+        g = -(jnp.exp(layer["A_log"])[:, None] * dt.reshape(b, t, h, d))
+        beta = 2.0 * jax.nn.sigmoid(_matmul(a, layer["Wb"], cd))  # [B, T, H] in (0, 2)
+    with jax.named_scope("kda"):
+        q, k, v = (z.reshape(b, t, h, d) for z in (q, k, v))
+        q = q * (jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + UNIT_EPS) * d ** -0.5)
+        k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + UNIT_EPS)
+        o = kda_scan(q, k, v, g, beta, m.chunk, cd)
+    with jax.named_scope("gnorm"):
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps) * layer["o_norm"]
+        y = o.reshape(b, t, inner) * jax.nn.sigmoid(_matmul(_matmul(a, layer["Ga"], cd), layer["Gb"], cd))
+    return _proj(y, layer["wo"], cd)
+
+
 def _attend(x, layer, m: Attention, eps: float, cd, interpret: bool):
     """Causal attention on (grouped) queries through the fused fold, with what
     the record asks for around it: a QK-norm on the projections (over all
     heads' channels together, or over each head's), a rotation of q and k, a
-    window, a sigmoid gate per head on the output, a norm on the projection
-    back. Under ``diffusion_block`` the positions are a sequence and its noised
+    window, a sigmoid gate per head or per channel on the output, a norm on
+    the projection back. Under ``diffusion_block`` the positions are a sequence and its noised
     copy, ``[x ; x~]``: each half turns at positions ``0 .. T - 1`` and the
     mask is block diffusion's."""
     a = _rms_norm(x, layer[m.norm], eps)
@@ -778,7 +849,11 @@ def _attend(x, layer, m: Attention, eps: float, cd, interpret: bool):
         with jax.named_scope("gate"):
             g = jax.nn.sigmoid(_matmul(a, layer["head_gate"], cd))  # [B, T, H]
             o = o * jnp.transpose(g, (0, 2, 1))[..., None]
-    o = _proj(_merged(o), layer["wo"], cd)
+    o = _merged(o)
+    if m.out_gate:
+        with jax.named_scope("gate"):
+            o = o * jax.nn.sigmoid(_matmul(a, layer["wg"], cd))  # [B, T, H D], before the projection back
+    o = _proj(o, layer["wo"], cd)
     return _rms_norm(o, layer[m.out_norm], eps) if m.out_norm else o
 
 
@@ -808,7 +883,7 @@ def _latent_attend(x, layer, m: LatentAttention, eps: float, cd, interpret: bool
     return _proj(_merged(_fold(q, k, v, cd, interpret)), layer["wo"], cd)
 
 
-_MIX = {Attention: _attend, LatentAttention: _latent_attend, CCA: _cca, Mamba2: _mamba2}
+_MIX = {Attention: _attend, LatentAttention: _latent_attend, CCA: _cca, Mamba2: _mamba2, KDA: _kda}
 
 
 def _feed_forward(x, carry, layer, f, eps: float, cd):
@@ -1277,7 +1352,7 @@ def _log_likelihood_program(cfg: LMConfig, compute_type: str, interpret: bool):
 
 def _fold_mode(t: int, cfg: LMConfig) -> bool:
     """Whether the fused fold runs interpreted (off the TPU), after checking
-    that it (and a stack with Mamba-2 layers' scan, in chunks of ``cfg.chunk``)
+    that it (and a stack with Mamba-2 layers' scan or delta-rule layers, in chunks of ``cfg.chunk``)
     can serve this sequence at every layer's head sizes; there is no other
     attention path. Under block diffusion the fold's sequence is the doubled
     one, ``2 t`` positions in whole blocks."""
@@ -1405,7 +1480,14 @@ class DecoderLM(Estimator, _LMParams):
     and the step's index), runs the stack over ``[x ; x~]`` and scores the
     masked positions of ``x~`` on their own tokens with weight ``1 / p``
     (``blockLength``, ``maskTokenId``); the sequence length is
-    then a multiple of 128 and of ``blockLength``."""
+    then a multiple of 128 and of ``blockLength``.
+
+    ``blockKind`` ``solar_open2`` runs the gated delta rule in the layers
+    ``gqaLayers`` does not name (``kdaNumHeads`` heads of ``kdaHeadSize``
+    channels, chunks of ``ssmChunkSize`` positions) and gated attention
+    without a position encoding in those it names; the sequence length is a
+    multiple of the chunk too. The head counts may be one chip's share of each
+    layer's heads: the layer's output is then the held heads' part of it."""
 
     def fit(self, *inputs) -> DecoderLMModel:
         (df,) = inputs
@@ -1449,6 +1531,11 @@ class DecoderLM(Estimator, _LMParams):
             folds = [(m.heads, getattr(m, "window", 0), getattr(m, "diffusion_block", 0)) for m in mixers
                      if isinstance(m, (Attention, LatentAttention, CCA))]
             scans = [m for m in mixers if isinstance(m, Mamba2)]
+            deltas = [m for m in mixers if isinstance(m, KDA)]
+            # a step's chunks of the delta rule (chunks x heads x sequences, every such layer), and those of them its
+            # kernel pair walks: its grid's cells
+            kda_chunks = sum(batch * m.heads * (t // m.chunk) for m in deltas)
+            kda_chunks_kernel = sum(kda_kernel_chunks(batch, t, m.heads, m.chunk) for m in deltas)
             # a step's chunks of the scan (chunks x heads x sequences, every Mamba-2 layer), and those of them the
             # scan's kernel pair walks: its grid's cells x the heads of a cell
             scan_chunks = sum(batch * m.heads * (t // m.chunk) for m in scans)
@@ -1463,7 +1550,8 @@ class DecoderLM(Estimator, _LMParams):
             state = jax.tree_util.tree_leaves(opt_state)
             # positions x channels of the Mamba-2 layers' convolutions in one step's forward, and those of them the
             # convolution's kernels cover: their calls' grids in the step as traced
-            conv_positions = sum(batch * t * (m.heads * m.head_dim + 2 * m.groups * m.state) for m in scans)
+            conv_positions = (sum(batch * t * (m.heads * m.head_dim + 2 * m.groups * m.state) for m in scans)
+                              + sum(batch * t * 3 * m.heads * m.head_dim for m in deltas))
             traced = _traced_counts(step, params, opt_state, window, cfg,
                                     *([(noise_key, jax.ShapeDtypeStruct((), jnp.int32))] if cfg.block_length else []))
             conv_positions_kernel = traced["conv_positions_kernel"]
@@ -1494,6 +1582,14 @@ class DecoderLM(Estimator, _LMParams):
                                    conv_positions=conv_positions, conv_positions_kernel=conv_positions_kernel,
                                    scan_state_bytes=max(4 * batch * m.heads * (t // m.chunk) * m.head_dim * m.state
                                                         for m in scans))
+
+            if deltas:  # beside the counts, the float32 chunk states one layer's rule carries
+                phase.set_metadata(layers_kda=len(deltas), layers_attn=sum(isinstance(m, Attention) for m in mixers),
+                                   layers_moe=sum(isinstance(spec.ffn, Experts) for spec in specs),
+                                   kda_chunks=kda_chunks, kda_chunks_kernel=kda_chunks_kernel,
+                                   conv_positions=conv_positions, conv_positions_kernel=conv_positions_kernel,
+                                   kda_state_bytes=max(4 * batch * m.heads * (t // m.chunk) * m.head_dim ** 2
+                                                       for m in deltas))
 
         losses, leaf_norms, stats = [], [], []  # device values, fetched once after the loop
         with tracer.phase("train.dispatch", CAT_PRODUCTIVE, steps=steps):
@@ -1577,6 +1673,13 @@ class DecoderLM(Estimator, _LMParams):
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_SCAN_CHUNKS, steps * scan_chunks)
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_SCAN_KERNEL_CHUNKS, steps * scan_chunks_kernel)
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_SCAN_LAYERS, steps * len(scans))
+            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_CONV_POSITIONS, steps * conv_positions)
+            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_CONV_KERNEL_POSITIONS,
+                            steps * conv_positions_kernel)
+        if deltas:
+            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_KDA_CHUNKS, steps * kda_chunks)
+            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_KDA_KERNEL_CHUNKS, steps * kda_chunks_kernel)
+            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_KDA_LAYERS, steps * len(deltas))
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_CONV_POSITIONS, steps * conv_positions)
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_CONV_KERNEL_POSITIONS,
                             steps * conv_positions_kernel)

@@ -607,6 +607,110 @@ def test_the_sdar_step_program_at_the_cells_shapes(one_chip):
     assert ("lm.noise",) in scopes
 
 
+def _solar_cut():
+    """``(the benchmark's solar_open2_250b configuration, its LMConfig)``."""
+    from perfbench.systems import solar_lm_fit
+
+    c = _cell_config("solar_open2_250b")
+    return c, solar_lm_fit.lm_config(c)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("batch", [1, 2])
+def test_the_delta_rule_trains_at_the_cells_shape(one_chip, monkeypatch, dtype, batch):
+    """The delta rule's kernel pair in one training graph at the cell's shape:
+    8 held heads of 128 key and 128 value channels, T 4,096 in chunks of 64.
+    Mosaic takes the sub-chunks' 16-row slices, the ``[64, 64]`` blockwise
+    inversion at the highest precision and the transposed carried state; the
+    state every chunk starts from is saved once, ``[B, 64, 8, 128, 128]``
+    float32, and no ``[chunk, chunk]`` block a chunk is an array of the
+    program."""
+    from flink_ml_tpu.parallel import kda
+
+    monkeypatch.setattr(kda, "_interpreted", lambda: False)  # the backend here is the CPU; the target is the chip
+    heads, t, d, chunk = 8, 4096, 128, 64
+    wide = jax.ShapeDtypeStruct((batch, t, heads, d), jnp.float32, sharding=one_chip)
+    narrow = jax.ShapeDtypeStruct((batch, t, heads), jnp.float32, sharding=one_chip)
+
+    def grads(q, k, v, g, beta):
+        return jax.grad(lambda *a: jnp.sum(kda.kda_scan(*a, chunk, dtype)), argnums=range(5))(q, k, v, g, beta)
+
+    compiled = _compile(grads, wide, wide, wide, wide, narrow)
+    text = compiled.as_text()
+    assert kda.FWD_NAME in text and kda.BWD_NAME in text
+    assert f"f32[{batch},{t // chunk},{heads},{d},{d}]" in text  # the chunks' starting states, once
+    assert f"{chunk},{chunk}]" not in text.replace(f"[{chunk},{chunk}]", "")  # no stack of chunk blocks
+    assert f"[{batch},{t},{heads},{d},{d}]" not in text  # no state a position
+    dq, dk, dv, dg, dbeta = jax.eval_shape(grads, wide, wide, wide, wide, narrow)
+    assert dq.shape == dk.shape == dv.shape == dg.shape == wide.shape and dbeta.shape == narrow.shape
+
+
+def test_the_solar_step_program_at_the_cells_shapes(one_chip, monkeypatch):
+    """The whole jitted step of the ``solar_open2_250b`` configuration at ONE
+    4,096-token sequence: four rematerialised layers of two records (the
+    attention layer on 8 held query heads over 1 held key/value head under its
+    output gate, three delta-rule layers on 8 held heads, every layer with 8
+    held experts beside the shared one), 840,872,600 parameters. It fits the
+    HBM ``fit`` compiles a step into (``decoder_lm.STEP_HBM_MIB``: 15,020 MiB,
+    the 15.75e9 B below): XLA's analysis reads 5.02 GB of temporaries beside
+    10.09 GB of arguments, 15.11e9 B in all, and nothing in the compiled step is
+    one of XLA's own rematerialisations. TWO sequences a step - ISSUE 51's
+    first choice - compile to 6.21 GB of temporaries, 16.30e9 B, 0.55e9 over
+    (with ``.remat`` instructions: XLA was squeezing already; the same with a
+    1,024-row head chunk): the largest of them are the experts' five parked
+    buffers over all 68,096 padded routed rows (``parallel/moe.py``: 1.64 GB),
+    so the cell takes the fallback the issue names. The delta rule and the
+    convolution are their kernel pairs by name, the convolution reading q, k and
+    v where they lie in ONE projection's output; the fold's two kernels take K
+    and V once for the one held key/value head; the held experts' grouped
+    matmuls are the grouped kernel in both directions over a window of 2,048
+    sorted rows at a time."""
+    from flink_ml_tpu.models.lm.config import num_params
+    from flink_ml_tpu.parallel import causal_conv, kda
+
+    c, cfg = _solar_cut()
+    assert num_params(cfg) == 840_872_600  # 13.45 GB of f32 state at 16 bytes a parameter: 84% of 16 GB
+    batch, t = c["global_batch_size"], c["sequence_length"]
+    assert batch == 1
+    for module in (kda, causal_conv):  # the backend here is the CPU; the target is the chip
+        monkeypatch.setattr(module, "_interpreted", lambda: False)
+    compiled, memory = _compiled_step(c, cfg, one_chip)
+    assert memory.temp_size_in_bytes < 5.07e9, memory.temp_size_in_bytes  # what it reads (5.018e9) and 1%
+    text = compiled.as_text()
+    assert ".remat" not in text
+    for kernel in ("flash_fold_fwd", "flash_fold_bwd_dkv", "kda_scan_fwd", "kda_scan_bwd", "causal_conv_fwd",
+                   "causal_conv_bwd"):
+        assert kernel in text
+    assert "flash_fold_bwd_dq" not in text and "flash_fold_win_" not in text and "ssd_scan" not in text
+    inner = cfg.kda_heads * cfg.kda_head_dim
+    assert f"f32[{batch},{t},{3 * inner}]" in text  # q, k and v out of one projection
+    assert f"f32[{batch},{t + cfg.conv_kernel - 1},{3 * inner}]" not in text  # and no padded copy of them
+    assert f"bf16[{batch * cfg.kv_heads},{t},{cfg.head_dim}]" in text  # K and V once for the held key/value head
+    assert f"f32[{batch},{cfg.n_heads},{t},{t}]" not in text and f"f32[{batch * cfg.n_heads},{t},{t}]" not in text
+    kernels = [ln for ln in text.splitlines() if ln.lstrip().startswith("%ragged-dot") and "custom-call(" in ln]
+    assert len(kernels) >= 8 * cfg.n_layers
+    routed = batch * t * cfg.top_k  # 32,768 routed rows a layer, a window of a sixteenth of them at a time
+    assert f"f32[{routed},{cfg.hidden}]" not in text and f"bf16[{routed // 16},{cfg.hidden}]" in text
+    chunks = t // cfg.chunk
+    assert f"f32[{batch},{chunks},{cfg.kda_heads},{cfg.kda_head_dim},{cfg.kda_head_dim}]" in text  # the chunks' states
+    assert f"[{batch},{t},{cfg.kda_heads},{cfg.kda_head_dim},{cfg.kda_head_dim}]" not in text  # no state a position
+    assert f"[2048,{cfg.vocab}]" in text  # the head's logits a chunk of 2,048 rows ([4096, 24576] is the head itself)
+
+
+def test_two_sequences_a_step_of_the_solar_cut_do_not_fit(one_chip, monkeypatch):
+    """ISSUE 51's first choice, two sequences a step, compiled as ``fit`` would
+    compile it: arguments and temporaries pass the 15.75e9 B a step is held to
+    (16.30e9 read), which is why the cell runs one."""
+    from flink_ml_tpu.parallel import causal_conv, kda
+
+    c, cfg = _solar_cut()
+    for module in (kda, causal_conv):
+        monkeypatch.setattr(module, "_interpreted", lambda: False)
+    step, shapes = _step_and_shapes({**c, "global_batch_size": 2}, cfg, one_chip)
+    memory = step.lower(*shapes).compile().memory_analysis()
+    assert 15.75e9 < memory.argument_size_in_bytes + memory.temp_size_in_bytes < 16.6e9
+
+
 def test_the_ouro_step_program_at_the_cells_shapes(one_chip):
     """The whole jitted step of the ``ouro_2_6b`` configuration at 2 x 4,096
     tokens: six rematerialised dense blocks inside one scanned pass run four
@@ -661,8 +765,8 @@ def test_the_olmoe_step_program_at_the_cells_shapes(one_chip):
     assert f"f32[{batch},{cfg.n_heads},{t},{t}]" not in text and f"f32[{batch * cfg.n_heads},{t},{t}]" not in text
 
 
-@pytest.mark.parametrize("cut", [_zaya_cut, _ouro_cut, _laguna_cut, _nemotron_cut, _joyai_cut, _sdar_cut],
-                         ids=["zaya", "ouro", "laguna", "nemotron", "joyai", "sdar"])
+@pytest.mark.parametrize("cut", [_zaya_cut, _ouro_cut, _laguna_cut, _nemotron_cut, _joyai_cut, _sdar_cut, _solar_cut],
+                         ids=["zaya", "ouro", "laguna", "nemotron", "joyai", "sdar", "solar"])
 def test_the_state_program_at_the_cells_shapes(one_chip, cut):
     """AdamW's state as ``DecoderLM._fit`` makes it, ``optimizer.init`` jitted,
     at the cells' parameter trees: one program whose outputs are the whole

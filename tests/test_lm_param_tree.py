@@ -6,14 +6,16 @@ another model from the same seed. Toys by literal; the benchmark's five LM
 configurations by the digest of the list and by their parameter counts. The
 ``joyai`` kind (PR 44) is pinned beside them as it came: its stack's leaves,
 then ``final_norm`` and ``lm_head``, then the multi-token-prediction module;
-the ``sdar`` kind (PR 47) likewise: OLMoE's order, its QK-norms a head wide."""
+the ``sdar`` kind (PR 47) likewise: OLMoE's order, its QK-norms a head wide;
+the ``solar_open2`` kind (PR 51) as it came: an attention layer's leaves with
+``wg`` before ``wo``, a delta-rule layer's fifteen, laguna's expert leaves."""
 import hashlib
 
 import pytest
 
 from flink_ml_tpu.models.lm.config import LMConfig, layers, num_params, param_shapes
 from tests.test_lm_chip_compile import (
-    _cell_config, _joyai_cut, _laguna_cut, _nemotron_cut, _ouro_cut, _sdar_cut, _zaya_cut,
+    _cell_config, _joyai_cut, _laguna_cut, _nemotron_cut, _ouro_cut, _sdar_cut, _solar_cut, _zaya_cut,
 )
 
 TOYS = {
@@ -39,6 +41,10 @@ TOYS = {
     "sdar": LMConfig(n_layers=1, hidden=64, n_heads=4, n_experts=16, top_k=2, expert_width=32, vocab=512,
                      rope_theta=1e6, norm_eps=1e-6, aux_coef=0.0, block="sdar", experts_held=2, first_held=2,
                      n_kv_heads=2, head_size=16, block_length=4, mask_id=511),
+    "solar_open2": LMConfig(n_layers=2, hidden=64, n_heads=4, n_experts=16, top_k=2, expert_width=32, vocab=512,
+                            norm_eps=1e-5, aux_coef=0.0, block="solar_open2", experts_held=2, first_held=2,
+                            n_kv_heads=2, head_size=16, shared_width=32, routed_scale=1.0, conv_kernel=4, chunk=64,
+                            gqa_layers=(0,), kda_heads=2, kda_head_dim=16),
 }
 #: ``(dotted path, shape, init)`` of every leaf in ``param_shapes`` order, printed by 512ebfa's ``param_shapes``
 TOY_TREES = {
@@ -156,6 +162,29 @@ TOY_TREES = {
         ('layers.0.w_up', (2, 64, 32), 'normal'), ('layers.0.w_down', (2, 32, 64), 'normal'),
         ('final_norm', (64,), 'ones'), ('lm_head', (64, 512), 'normal'),
     ],
+    "solar_open2": [
+        ('embed', (512, 64), 'normal'), ('layers.0.attn_norm', (64,), 'ones'), ('layers.0.wq', (64, 64), 'normal'),
+        ('layers.0.wk', (64, 32), 'normal'), ('layers.0.wv', (64, 32), 'normal'), ('layers.0.wg', (64, 64), 'normal'),
+        ('layers.0.wo', (64, 64), 'normal'),
+        ('layers.0.ffn_norm', (64,), 'ones'), ('layers.0.router', (64, 16), 'normal'),
+        ('layers.0.router_bias', (16,), 'zeros'), ('layers.0.shared_gate', (64, 32), 'normal'),
+        ('layers.0.shared_up', (64, 32), 'normal'), ('layers.0.shared_down', (32, 64), 'normal'),
+        ('layers.0.w_gate', (2, 64, 32), 'normal'), ('layers.0.w_up', (2, 64, 32), 'normal'),
+        ('layers.0.w_down', (2, 32, 64), 'normal'),
+        ('layers.1.attn_norm', (64,), 'ones'), ('layers.1.wq', (64, 32), 'normal'),
+        ('layers.1.wk', (64, 32), 'normal'), ('layers.1.wv', (64, 32), 'normal'),
+        ('layers.1.conv_q', (4, 32), 'normal'), ('layers.1.conv_k', (4, 32), 'normal'),
+        ('layers.1.conv_v', (4, 32), 'normal'), ('layers.1.Fa', (64, 16), 'normal'),
+        ('layers.1.Fb', (16, 32), 'normal'), ('layers.1.A_log', (2,), 'a_log'), ('layers.1.dt_bias', (32,), 'dt_bias'),
+        ('layers.1.Wb', (64, 2), 'normal'), ('layers.1.Ga', (64, 16), 'normal'), ('layers.1.Gb', (16, 32), 'normal'),
+        ('layers.1.o_norm', (16,), 'ones'), ('layers.1.wo', (32, 64), 'normal'),
+        ('layers.1.ffn_norm', (64,), 'ones'), ('layers.1.router', (64, 16), 'normal'),
+        ('layers.1.router_bias', (16,), 'zeros'), ('layers.1.shared_gate', (64, 32), 'normal'),
+        ('layers.1.shared_up', (64, 32), 'normal'), ('layers.1.shared_down', (32, 64), 'normal'),
+        ('layers.1.w_gate', (2, 64, 32), 'normal'), ('layers.1.w_up', (2, 64, 32), 'normal'),
+        ('layers.1.w_down', (2, 32, 64), 'normal'),
+        ('final_norm', (64,), 'ones'), ('lm_head', (64, 512), 'normal'),
+    ],
 }
 
 
@@ -180,6 +209,8 @@ CELLS = {
     "joyai_llm_flash": (_joyai_cut, 680_441_088, "4d26d90bc1061ad9243f991a5a9928089e0e0c91df5c30ca033534a38a14b211", 2),
     # as PR 47 brought it: six layers of one record
     "sdar_30b_a3b": (_sdar_cut, 645_623_296, "2c858655427e58fe0af64a14d0340659d780d9481f3c4b732df96af708c7e554", 1),
+    # as PR 51 brought it: the layer that attends, then three delta-rule layers of one record
+    "solar_open2_250b": (_solar_cut, 840_872_600, "9dcffb5157ddd100e16d0d5f3cef8878906ec50434592e42479b60df07681406", 2),
 }
 
 

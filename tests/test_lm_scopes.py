@@ -60,10 +60,18 @@ KINDS = {
                       rope_theta=1e6, norm_eps=1e-6, aux_coef=0.0, block="sdar", experts_held=4, first_held=2,
                       n_kv_heads=2, head_size=16, block_length=4, mask_id=511), True,
              {"lm.noise", "lm.block/route", "lm.block/permute", "lm.block/experts"}),
+    # the delta rule's kernel pair under ``kda``, its decay and strength under ``kgate``, the attention layer's
+    # output gate under ``gate`` (laguna's name for its head gates), the gated norm and the convolution under nemotron's
+    "solar_open2": (LMConfig(n_layers=2, hidden=64, n_heads=4, n_experts=8, top_k=2, expert_width=32, vocab=512,
+                             norm_eps=1e-5, aux_coef=0.0, block="solar_open2", experts_held=4, first_held=2,
+                             n_kv_heads=2, head_size=16, shared_width=32, routed_scale=1.0, conv_kernel=4, chunk=64,
+                             gqa_layers=(0,), kda_heads=2, kda_head_dim=16), True,
+                    {"lm.block/kda", "lm.block/kgate", "lm.block/gnorm", "lm.block/conv", "lm.block/gate",
+                     "lm.block/route", "lm.block/permute", "lm.block/experts", "lm.block/shared"}),
 }
 EVERY_KIND = {"lm.embed", "lm.block/norm", "lm.block/proj", "lm.block/fold", "lm.block/mix",
               "lm.final_norm/norm", "lm.head", "lm.opt"}
-#: every kind but ``nemotron_h``, whose attention has no position encoding
+#: every kind but ``nemotron_h`` and ``solar_open2``, whose attention has no position encoding
 ROPE = "lm.block/rope"
 #: ``%name = shape opcode(``: the opcode is the first word followed by a parenthesis
 INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = .*? ([a-z][\w\-]*)\(")
@@ -120,7 +128,7 @@ def test_every_scope_the_kind_has_appears(programs, kind):
     scopes = {scope for scope, _ in _found(step)}
     own = KINDS[kind][2]
     assert (EVERY_KIND | own) <= scopes, sorted((EVERY_KIND | own) - scopes)
-    assert (ROPE in scopes) == (kind != "nemotron_h")
+    assert (ROPE in scopes) == (kind not in ("nemotron_h", "solar_open2"))
     # and none another kind alone has
     others = set().union(*(k[2] for k in KINDS.values())) - own
     assert not others & scopes, sorted(others & scopes)
@@ -141,6 +149,12 @@ def test_the_head_is_never_recomputed_and_a_block_is_where_it_is_checkpointed(pr
         assert {("lm.block/experts", BWD), ("lm.block/permute", BWD)} <= found
     if kind == "nemotron_h":  # the scan is AD's: each of its parts in all three directions
         assert {d for scope, d in found if scope == "lm.block/scan"} == {FWD, REMAT, BWD}
+    if kind == "solar_open2":  # the delta rule's two kernels by name under its scope, in all three directions
+        names = {n.split("/kda/")[1].split("/")[0] for _, n in step if n and "/kda/kda_scan" in n}
+        assert names == {"kda_scan_fwd", "kda_scan_bwd"}
+        for scope in ("lm.block/kda", "lm.block/kgate", "lm.block/gnorm", "lm.block/gate"):
+            assert {d for s, d in found if s == scope} == {FWD, REMAT, BWD}, scope
+        assert {REMAT, BWD} <= {d for s, d in found if s == "lm.block/conv"}  # (interpreted, its forward call fuses away)
     if kind == "joyai":  # the module's parts in every direction, its layer recomputed like the stack's
         for scope in ("lm.mtp/lm.block/latent", "lm.mtp/lm.block/fold"):
             assert {d for s, d in found if s == scope} == {FWD, REMAT, BWD}, scope
@@ -171,7 +185,7 @@ def test_the_update_is_outside_the_gradient(programs, kind):
 #: weights' cotangents over the passes (``dynamic_update_slice``, ``add_any``)
 #: in code that is JAX's own, under no scope of the program.
 COVERED = {"olmoe": 0.95, "olmoe_stacked": 0.95, "zaya": 0.95, "ouro": 0.90, "laguna": 0.95, "nemotron_h": 0.95,
-           "joyai": 0.95, "sdar": 0.95}
+           "joyai": 0.95, "sdar": 0.95, "solar_open2": 0.95}
 
 
 @pytest.mark.parametrize("kind", sorted(KINDS))
