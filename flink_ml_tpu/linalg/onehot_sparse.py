@@ -62,7 +62,11 @@ and the VMEM notes below reproduce to the digit (docs/kernels.md).
 """
 from __future__ import annotations
 
-from typing import List, Optional
+import os
+import threading
+import time
+from concurrent.futures import ThreadPoolExecutor, wait
+from typing import Callable, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -88,7 +92,53 @@ SUB_ROWS = 16384  # sub-batch rows per crossing (gradient accumulation grain)
 # 18.7 ms at 64 against 19.3 at 32 and 19.6 at 128, from 22.3 with no chunks
 # (TPU v5e; PERF.md, PR 29).
 CHUNK = 64
+# Host threads one layout build spreads its units over, at most (_over_units).
+# The fill of 32 Criteo units of 16,384 x 40 entries on a four-chip TPU v5e
+# host's 30 cores, median of twenty builds: 0.457 s on 1 worker, 0.291 on 2,
+# 0.188 on 4, 0.131 on 8, 0.113 on 16, 0.115 on 30 (PERF.md, PR 52). A unit
+# takes 14 ms alone, 31 among 8 and 53 among 16: past 8 the units slow each
+# other as fast as they are added, and twice the threads buy 2% of a fit.
+UNIT_WORKERS = 8
 _ROW_LO = 128  # row-id split minor width
+
+_POOL: Optional[ThreadPoolExecutor] = None
+_POOL_LOCK = threading.Lock()
+
+
+def _unit_pool() -> ThreadPoolExecutor:
+    """Process-wide pool for the layout build's units (lazy: a build of one
+    unit, or on one core, never makes it). Its threads idle between builds."""
+    global _POOL
+    with _POOL_LOCK:
+        if _POOL is None:
+            _POOL = ThreadPoolExecutor(
+                max_workers=UNIT_WORKERS, thread_name_prefix="onehot-layout"
+            )
+        return _POOL
+
+
+def _over_units(stripe_fn: Callable[[range], object], n_units: int) -> list:
+    """``stripe_fn`` over the units ``0 .. n_units - 1`` cut into stripes
+    (worker ``w`` takes units ``w, w + workers, ...``), one stripe a worker;
+    returns the stripes' results in stripe order. The workers are
+    ``min(units, cores the process may run on, UNIT_WORKERS)``: one runs
+    in-line on the calling thread, more on the ``onehot-layout`` pool.
+
+    What makes this safe without a lock: a unit reads its own row range of
+    the indices and values and the plan's tables, which nothing writes after
+    the plan is made, and writes its own ``[n_model, n_flat]`` slices of the
+    preallocated stacks (or, in the counting pass, an array of its stripe's
+    own), so no two workers touch the same bytes. Every stripe has ended
+    when this returns or raises; of several errors the first in stripe order
+    is raised."""
+    workers = min(n_units, len(os.sched_getaffinity(0)), UNIT_WORKERS)
+    stripes = [range(w, n_units, workers) for w in range(workers)]
+    if workers <= 1:
+        return [stripe_fn(stripe) for stripe in stripes]
+    pool = _unit_pool()
+    futures = [pool.submit(stripe_fn, stripe) for stripe in stripes]
+    wait(futures)
+    return [f.result() for f in futures]
 
 
 def validate_indices(indices: np.ndarray, dim: int) -> None:
@@ -473,22 +523,31 @@ class OneHotSparseLayout:
             validate_indices(indices, dim)
             n_units = n_shards * n_windows * n_sub
 
-        # Pass 1 (counting): per-block max entry count over every unit.
+        # Pass 1 (counting): per-block max entry count over every unit, a
+        # stripe of units folded by each worker, the stripes folded here
+        # (np.maximum is exact and order-free: the plan cannot change).
         with tracer.phase("train.layout.count", CAT_INGEST, units=n_units):
-            max_count = np.zeros(nblk, np.int64)
-            bounds = []  # unit -> (r0, r1) row range
+            bounds = []  # unit -> (r0, r1) row range, (shard, window, sub) order
             for s in range(n_shards):
                 lo_s = s * m
                 for w0 in window_starts:
                     for b0 in range(0, local_batch, sub):
                         r0 = lo_s + w0 + b0
                         r1 = min(r0 + sub, lo_s + min(w0 + local_batch, m), n)
-                        np.maximum(
-                            max_count,
-                            block_counts(indices[r0:r1], values[r0:r1], nblk),
-                            out=max_count,
-                        )
                         bounds.append((r0, r1))
+
+            def count_stripe(units: range) -> np.ndarray:
+                stripe_max = np.zeros(nblk, np.int64)
+                for u in units:
+                    r0, r1 = bounds[u]
+                    np.maximum(
+                        stripe_max,
+                        block_counts(indices[r0:r1], values[r0:r1], nblk),
+                        out=stripe_max,
+                    )
+                return stripe_max
+
+            max_count = np.maximum.reduce(_over_units(count_stripe, n_units))
 
         with tracer.phase("train.layout.plan", CAT_INGEST) as phase:
             plan = OneHotSparsePlan.from_max_counts(max_count, dim, sub, n_model)
@@ -510,18 +569,30 @@ class OneHotSparseLayout:
         with tracer.phase(
             "train.layout.fill", CAT_INGEST, units=n_units, key_bits=plan.key_bits
         ) as phase:
-            unit_iter = iter(bounds)
-            masked = 0  # units that held a zero value and took the mask
-            for s in range(n_shards):
-                for wi in range(n_windows):
-                    for bi in range(n_sub):
-                        r0, r1 = next(unit_iter)
-                        masked += plan.fill_unit(
-                            indices[r0:r1], values[r0:r1],
-                            lidx[s, :, wi, bi], rowid[s, :, wi, bi],
-                            lvals[s, :, wi, bi],
-                        )
-            phase.set_metadata(masked=masked)
+            def fill_stripe(units: range) -> tuple:
+                masked = unit_ns = 0
+                for u in units:
+                    t0 = time.perf_counter_ns()
+                    s, wb = divmod(u, n_windows * n_sub)
+                    wi, bi = divmod(wb, n_sub)
+                    r0, r1 = bounds[u]
+                    masked += plan.fill_unit(
+                        indices[r0:r1], values[r0:r1],
+                        lidx[s, :, wi, bi], rowid[s, :, wi, bi],
+                        lvals[s, :, wi, bi],
+                    )
+                    unit_ns += time.perf_counter_ns() - t0
+                return masked, unit_ns
+
+            t0 = time.perf_counter_ns()
+            stripes = _over_units(fill_stripe, n_units)
+            wall_ns = time.perf_counter_ns() - t0
+            # masked: units that held a zero value and took the mask; unit_us
+            # over wall_us is the units in flight on average (1.0 in-line)
+            phase.set_metadata(
+                masked=sum(took for took, _ in stripes), workers=len(stripes),
+                unit_us=sum(ns for _, ns in stripes) // 1000, wall_us=wall_ns // 1000,
+            )
 
         return cls(
             plan=plan, dim=int(dim), n_shards=n_shards, n_windows=n_windows,

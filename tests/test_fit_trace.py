@@ -106,6 +106,15 @@ def _by_name(spans):
     return out
 
 
+def _fill_choices(attrs):
+    """``train.layout.fill``'s counts less the three its loop times itself
+    (``workers``, ``unit_us``, ``wall_us``: tests/test_onehot_sparse.py)."""
+    attrs = dict(attrs)
+    assert 1 <= attrs.pop("workers") <= attrs["units"]
+    assert attrs.pop("unit_us") > 0 and attrs.pop("wall_us") > 0
+    return attrs
+
+
 def _children_s(spans, parent):
     return sum(s.duration for s in spans if s.parent_id == parent.span_id)
 
@@ -132,7 +141,7 @@ class TestSparseFitTree:
         assert layout["reused"] == 0 and layout["rows"] == N and layout["units"] >= 1
         assert one["train.layout.count"] == {"units": layout["units"]}
         # the fill's two choices: 16-bit sort keys (DIM / 128 blocks), no unit masked
-        assert one["train.layout.fill"] == {
+        assert _fill_choices(one["train.layout.fill"]) == {
             "units": layout["units"], "key_bits": 16, "masked": 0,
         }
         stack_bytes = one["train.layout.alloc"]["bytes"]
@@ -190,7 +199,7 @@ class TestSparseFitTree:
         spans = recorder.snapshot()
         by = _by_name(spans)
         (layout,), (fill,) = by["train.layout"], by["train.layout.fill"]
-        assert fill.attrs == {
+        assert _fill_choices(fill.attrs) == {
             "units": layout.attrs["units"], "key_bits": 16, "masked": layout.attrs["units"],
         }
         children = [s for s in spans if s.parent_id == layout.span_id]
@@ -344,7 +353,7 @@ class TestPhaseContract:
         }
         assert events["train.layout"][0][2]["reused"] == 0
         units = events["train.layout"][0][2]["units"]
-        assert events["train.layout.fill"][0][2] == {
+        assert _fill_choices(events["train.layout.fill"][0][2]) == {
             "units": units, "key_bits": 16, "masked": 0,
         }
         for name, group in events.items():

@@ -6,13 +6,19 @@ tail-batch/window clamping semantics — only the execution strategy differs
 (dense one-hot algebra instead of serialized gather/scatter instructions).
 """
 import functools
+import os
+import sys
+import threading
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from flink_ml_tpu import trace
 from flink_ml_tpu.iteration import DeviceDataCache
+from flink_ml_tpu.linalg import onehot_sparse
 from flink_ml_tpu.linalg.onehot_sparse import (
     BLOCK,
     CHUNK,
@@ -263,6 +269,153 @@ def _heavy_rows(rng, n_units, unit_rows, k, dim, counts=HEAVY_COUNTS):
         ids.append(rng.integers(first_light, dim, size=rest))
         unit[:] = rng.permutation(np.concatenate(ids))
     return idx.reshape(n_units * unit_rows, k).astype(np.int32)
+
+
+def _build_with_workers(monkeypatch, workers, *args, cores=8, **kw):
+    """``OneHotSparseLayout.build`` held to ``workers`` (of ``cores`` the
+    process may run on), with the spans it wrote by name."""
+    monkeypatch.setattr(onehot_sparse, "UNIT_WORKERS", workers)
+    monkeypatch.setattr(os, "sched_getaffinity", lambda pid: set(range(cores)))
+    with trace.capture() as recorder:
+        lay = OneHotSparseLayout.build(*args, **kw)
+    return lay, {s.name: s for s in recorder.snapshot()}
+
+
+class TestUnitsSideBySide:
+    """Both passes of the build run their units on the ``onehot-layout`` pool
+    (``_over_units``): the same bytes as one unit after another."""
+
+    @pytest.mark.parametrize("heavy", [False, True], ids=["light", "heavy_block"])
+    @pytest.mark.parametrize("zero", [False, True], ids=["no_zero", "zero_value"])
+    @pytest.mark.parametrize("n_model", [1, 2])
+    @pytest.mark.parametrize("n_shards", [1, 4])
+    def test_four_workers_build_what_one_builds(
+        self, monkeypatch, n_shards, n_model, zero, heavy
+    ):
+        rng = np.random.default_rng(1000 * n_shards + 100 * n_model + 10 * zero + heavy)
+        dim = 30 * BLOCK
+        if heavy:  # 6 units a shard: 3 windows of 2
+            counts = {b: c for b, c in HEAVY_COUNTS.items() if c <= 300}
+            idx = _heavy_rows(rng, 6 * n_shards, 128, 8, dim, counts)
+        else:
+            idx = rng.integers(0, dim, size=(768 * n_shards, 8)).astype(np.int32)
+        val = rng.normal(size=idx.shape).astype(np.float32)
+        if zero:  # in some units only: they alone take the mask
+            val[: 128 * 3, 2] = 0.0
+        args = (idx, val, dim, n_shards, 256)
+        kw = dict(sub_rows=128, n_model=n_model)
+        one, one_spans = _build_with_workers(monkeypatch, 1, *args, **kw)
+        four, four_spans = _build_with_workers(monkeypatch, 4, *args, **kw)
+        assert one.n_windows * one.n_sub == 6 and bool(one.plan.chunks_of_block) == heavy
+        assert one_spans["train.layout.fill"].attrs["workers"] == 1
+        assert four_spans["train.layout.fill"].attrs["workers"] == 4
+        for name in ("lidx", "rowid", "lvals", "perm"):
+            got, ref = getattr(four, name), getattr(one, name)
+            assert got.dtype == ref.dtype
+            np.testing.assert_array_equal(got, ref)
+        assert four.class_meta == one.class_meta
+        assert four.plan.program_key() == one.plan.program_key()
+        masked = one_spans["train.layout.fill"].attrs["masked"]
+        assert masked == (3 if zero else 0)
+        assert four_spans["train.layout.fill"].attrs["masked"] == masked
+
+    def test_more_workers_than_cores_under_a_short_switch_interval(self, monkeypatch):
+        """Sixteen stripes on however few cores this host has, the interpreter
+        switching threads every 10 us: a unit that wrote a neighbour's slots,
+        or a stripe's maximum lost in the fold, would change the stacks."""
+        rng = np.random.default_rng(24)
+        dim = 30 * BLOCK
+        idx = rng.integers(0, dim, size=(64 * 48, 8)).astype(np.int32)
+        val = rng.normal(size=idx.shape).astype(np.float32)
+        val[rng.random(idx.shape) < 0.1] = 0.0
+        args, kw = (idx, val, dim, 4, 256), dict(sub_rows=64, n_model=2)
+        one, _ = _build_with_workers(monkeypatch, 1, *args, **kw)
+        assert one.n_shards * one.n_windows * one.n_sub == 48
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            deadline = time.monotonic() + 20
+            for _ in range(5):
+                many, spans = _build_with_workers(monkeypatch, 16, *args, cores=16, **kw)
+                assert spans["train.layout.fill"].attrs["workers"] == 16
+                for name in ("lidx", "rowid", "lvals"):
+                    np.testing.assert_array_equal(getattr(many, name), getattr(one, name))
+                assert many.plan.program_key() == one.plan.program_key()
+                if time.monotonic() > deadline:
+                    break
+        finally:
+            sys.setswitchinterval(interval)
+
+    def test_a_unit_outside_the_plan_raises_from_a_pooled_fill(self, monkeypatch):
+        rng = np.random.default_rng(21)
+        idx = rng.integers(0, 2 * BLOCK, size=(512, 4)).astype(np.int32)
+        val = np.ones(idx.shape, np.float32)
+        # a counting pass that saw at most one entry a block: every unit is outside its plan
+        counted = onehot_sparse.block_counts
+        monkeypatch.setattr(
+            onehot_sparse, "block_counts", lambda *a: np.minimum(counted(*a), 1)
+        )
+        filled = []
+        fill_unit = OneHotSparsePlan.fill_unit
+
+        def recording(self, *a):
+            filled.append(threading.current_thread().name)
+            return fill_unit(self, *a)
+
+        monkeypatch.setattr(OneHotSparsePlan, "fill_unit", recording)
+        with pytest.raises(ValueError, match="per-block occupancy"):
+            _build_with_workers(monkeypatch, 4, idx, val, 2 * BLOCK, 1, 128, sub_rows=64)
+        # 8 units in 4 stripes; each stripe ended at its first unit before the error left
+        assert len(filled) == 4
+        assert all(name.startswith("onehot-layout") for name in filled)
+
+    def test_one_unit_or_one_core_never_makes_the_pool(self, monkeypatch):
+        monkeypatch.setattr(onehot_sparse, "_POOL", None)
+        rng = np.random.default_rng(22)
+        idx = rng.integers(0, 4000, size=(256, 4)).astype(np.int32)
+        val = np.ones(idx.shape, np.float32)
+        one_unit, spans = _build_with_workers(monkeypatch, 8, idx, val, 4000, 1, 256)
+        assert one_unit.n_windows * one_unit.n_sub == 1
+        assert spans["train.layout.fill"].attrs["workers"] == 1
+        one_core, spans = _build_with_workers(
+            monkeypatch, 8, idx, val, 4000, 2, 64, cores=1, sub_rows=32
+        )
+        fill = spans["train.layout.fill"].attrs
+        assert fill["units"] == 8 and fill["workers"] == 1
+        assert onehot_sparse._POOL is None
+        pooled, spans = _build_with_workers(
+            monkeypatch, 8, idx, val, 4000, 2, 64, cores=2, sub_rows=32
+        )
+        assert spans["train.layout.fill"].attrs["workers"] == 2
+        pool = onehot_sparse._POOL
+        try:
+            assert {t.name.rsplit("_", 1)[0] for t in pool._threads} == {"onehot-layout"}
+            for name in ("lidx", "rowid", "lvals"):
+                np.testing.assert_array_equal(getattr(pooled, name), getattr(one_core, name))
+        finally:
+            pool.shutdown()
+
+    @pytest.mark.parametrize("workers", [1, 4])
+    def test_the_fill_is_one_phase_with_its_three_counts(self, monkeypatch, workers):
+        rng = np.random.default_rng(23)
+        idx = rng.integers(0, 4000, size=(512, 4)).astype(np.int32)
+        val = np.ones(idx.shape, np.float32)
+        _, spans = _build_with_workers(
+            monkeypatch, workers, idx, val, 4000, 1, 128, sub_rows=64
+        )
+        assert set(spans) == {
+            "train.layout." + part for part in ("prepare", "count", "plan", "alloc", "fill")
+        }  # no span a unit, no child of a phase
+        assert {s.parent_id for s in spans.values()} == {None}
+        assert {s.thread_id for s in spans.values()} == {threading.get_ident()}
+        fill = spans["train.layout.fill"].attrs
+        assert set(fill) == {"units", "key_bits", "masked", "workers", "unit_us", "wall_us"}
+        assert fill["units"] == 8 and fill["workers"] == workers
+        assert 0 < fill["unit_us"] and 0 < fill["wall_us"]
+        assert fill["wall_us"] <= spans["train.layout.fill"].duration * 1e6 + 1
+        if workers == 1:  # in-line the units' own times lie inside the loop's
+            assert fill["unit_us"] <= fill["wall_us"]
+        assert spans["train.layout.count"].attrs == {"units": 8}
 
 
 class TestChunkedClass:
