@@ -8,6 +8,11 @@ materialized form: each crossing contracts ``n_flat`` entries against the
 (``row_hi + 128`` wide per entry) from HBM, with ``q`` in / ``u`` out (f32 per
 entry) and the ``[row_hi, 128]`` f32 result. The kernel's own padding of the
 entry axis is not counted: it is not work the algorithm needs.
+
+``model`` is what one SGD step of the one-hot path needs on the MXU: its two
+crossings. The rounds around them (the per-entry coefficient read, the sums
+into the gradient, the update) are selects, sums and element-wise work and
+count nothing, as an LM step's element-wise work counts nothing.
 """
 
 
@@ -15,3 +20,6 @@ def onehot_crossing_premat(n_sub, n_flat, sub_batch, row_hi, **_):
     flops = 8.0 * n_sub * n_flat * sub_batch
     nbytes = n_sub * (2.0 * n_flat * (row_hi + 128) * 2 + 2.0 * n_flat * 4 + 2.0 * sub_batch * 4)
     return flops, nbytes
+
+
+model = onehot_crossing_premat

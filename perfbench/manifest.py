@@ -141,7 +141,9 @@ class Manifest:
 
 
 def load_module(package: str, name: str):
-    """``perfbench/<package>/<name>.py``, found by the name a data file gives."""
+    """``perfbench/<package>/<name>.py``, found by the name a data file gives;
+    ``perfbench/<name>.py`` (a configuration's cost module) where ``package``
+    is empty."""
     if not NAME.match(name):
         raise ValueError(f"bad module name {name!r}")
-    return importlib.import_module(f"perfbench.{package}.{name}")
+    return importlib.import_module(".".join(filter(None, ("perfbench", package, name))))

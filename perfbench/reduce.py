@@ -4,6 +4,14 @@ The window is the host span ``window`` that every traffic kind writes around
 what it measures. Each per-layer metric is read by the reducer its own file
 names (``layer_metrics/<name>.json`` -> ``reducers/<reducer>.py``); a reducer
 that finds nothing to read returns None and the metric is left out.
+
+A metric is one quantity, whatever configuration runs it. What differs between
+configurations (the operation that marks the step program, the kernels the
+compiler renames, the name of a part's cost function) stands in the
+configuration's ``perf`` block, and a metric's file points at it: a parameter
+``{"perf": "step_holds"}`` is ``config["perf"]["step_holds"]``, ``{"perf":
+"cost_of.attn"}`` descends. A configuration that lacks the key has nothing to
+read there, and the metric is left out of its line.
 """
 from __future__ import annotations
 
@@ -40,6 +48,30 @@ class Context:
         return n if n else None
 
 
+def resolved(params: dict, config: dict):
+    """``params`` with every ``{"perf": "<key>"}`` replaced by what the
+    configuration's ``perf`` block holds under that key; None where it holds
+    nothing."""
+    out = {}
+    for name, value in params.items():
+        if isinstance(value, dict) and set(value) == {"perf"}:
+            key, value = value["perf"], config.get("perf", {})
+            for part in key.split("."):
+                value = value.get(part) if isinstance(value, dict) else None
+            if value is None:
+                return None
+        out[name] = value
+    return out
+
+
+def metric_value(ctx, spec: dict):
+    """What the reducer a metric's file names reads of this run, or None."""
+    params = resolved(spec.get("params", {}), ctx.config)
+    if params is None:
+        return None
+    return load_module("reducers", spec["reducer"]).reduce(ctx, **params)
+
+
 def _top(seconds_by: dict, n: int = 10) -> list:
     return [[k, v] for k, v in sorted(seconds_by.items(), key=lambda kv: -kv[1])[:n]]
 
@@ -63,7 +95,7 @@ def per_layer(run):
     metrics = {}
     for name in run.manifest.cell_metrics("per_layer", run.cell["name"]):
         spec = run.manifest.layer_metric(name)
-        value = load_module("reducers", spec["reducer"]).reduce(ctx, **spec.get("params", {}))
+        value = metric_value(ctx, spec)
         if value is not None:
             metrics[name] = {"value": float(value), "unit": spec["unit"]}
 

@@ -18,9 +18,10 @@ from perfbench.systems import joyai_lm_fit
 
 CELL = "joyai_llm_flash.fit_mla8k"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-NEW = ("joyai_step_ms", "joyai_mfu_pct", "mla_attn_ms", "mla_attn_roofline", "mla_latent_ms", "mtp_ms",
-       "joyai_expert_ms", "joyai_expert_roofline", "joyai_held_share_pct", "joyai_rows_carried_pct",
-       "mtp_targets_pct", "joyai_scope_coverage_pct")
+#: What the cell reports: its own three, and the quantities it shares (before PR 53 under ``joyai_*`` and ``mla_attn_*``).
+OWN = ("mla_latent_ms", "mtp_ms", "mtp_targets_pct")
+SHARED = ("lm_step_ms", "lm_mfu_pct", "attn_ms", "attn_roofline", "moe_expert_ms", "moe_expert_roofline",
+          "moe_held_share_pct", "moe_rows_carried_pct", "lm_scope_coverage_pct")
 PUBLISHED = dict(seq=8192, hidden=2048, layers=5, dense_layers=1, dense_width=7168, heads=32, q_rank=1536,
                  kv_rank=512, nope_dim=128, rope_dim=64, v_dim=128, experts=256, experts_held=16, width=768,
                  shared_width=768, mtp_depth=1, vocab=16160)
@@ -54,10 +55,10 @@ def test_the_manifest_has_no_problems_and_every_new_metric_file_matches_its_entr
     assert manifest.problems() == []
     assert manifest.cell_metrics("end_to_end", CELL) == ["fit_rows_per_s", "setup_s"]
     listed = manifest.cell_metrics("per_layer", CELL)
-    assert listed == ["fit_idle_pct", "fit_peak_hbm_gb", *NEW]
-    for name in NEW:
+    assert set(listed) == {"fit_idle_pct", "fit_peak_hbm_gb", *OWN, *SHARED}
+    for name in OWN + SHARED:
         entry, own = manifest.per_layer[name], manifest.layer_metric(name)
-        assert entry["workloads"] == [CELL] and entry["moves"] == "fit_rows_per_s"
+        assert (entry["workloads"] == [CELL]) == (name in OWN) and entry["moves"] == "fit_rows_per_s"
         for key in ("name", "unit", "better", "source", "layer", "moves"):
             assert own[key] == entry[key], (name, key)
         assert os.path.exists(os.path.join(manifest.dir, "reducers", own["reducer"] + ".py"))
@@ -121,29 +122,34 @@ def test_the_new_reducers_on_recorded_counts(capsys):
     over the tokens; a run of another layout, or of a program that writes no
     such count, gives nothing to read."""
     from perfbench import program_spans
-    from perfbench.reducers import joyai_roofline_pct, program_span_pct
+    from perfbench.reducers import lm_roofline_pct, program_span_pct
 
     peaks = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
     layout = dict(PUBLISHED, tokens=8192, batch=1)
+    config = Manifest().config("joyai_llm_flash")
 
     def ctx_of(shapes, stats, op="flash_fold_bwd_dq.7"):
         table = program_spans.Table([program_spans.Span("train.drain", 10.0, 1.0, stats=stats)])
         return types.SimpleNamespace(
-            run=types.SimpleNamespace(program_spans=table), w0=0.0, w1=100.0, facts={"layout": shapes, "steps": 4},
+            run=types.SimpleNamespace(program_spans=table), config=config, w0=0.0, w1=100.0,
+            facts={"layout": shapes, "steps": 4},
             peaks=peaks, per=lambda unit: 4, ops=lambda: [(op, 20.0, 800e6)])  # 0.8 s over 4 steps
 
     fold = "flash_fold_(fwd|bwd_dq|bwd_dkv)"
     drained = {"rows_held": 4 * 20_000, "steps": 4, "tokens": 4 * 8192, "mtp_targets": 4 * 8190}
-    got = joyai_roofline_pct.reduce(ctx_of(layout, drained), fold, "mla_fold")
+    got = got_fold = lm_roofline_pct.reduce(ctx_of(layout, drained), "mla_fold", pattern=fold)
     flops, nbytes = joyai_costs.mla_fold(**layout)
     assert got == pytest.approx(100 * (flops / 197e12) / 0.2) and 0 < got < 100 and flops / 197e12 > nbytes / 819e9
     assert "bound by mxu" in capsys.readouterr().out
-    got = joyai_roofline_pct.reduce(ctx_of(layout, drained, "ragged-dot-none.3"), "^ragged-dot", "held_experts")
+    got = lm_roofline_pct.reduce(ctx_of(layout, drained, "ragged-dot-none.3"), "held_experts", pattern="^ragged-dot")
     flops, nbytes = joyai_costs.held_experts(rows_held=20_000, **layout)
     assert got == pytest.approx(100 * max(flops / 197e12, nbytes / 819e9) / 0.2) and 0 < got < 100
-    assert joyai_roofline_pct.reduce(ctx_of({"tokens": 8192, "ssm_heads": 64}, drained), fold, "mla_fold") is None
-    assert joyai_roofline_pct.reduce(ctx_of(layout, {"steps": 4}), fold, "mla_fold") is None
-    assert joyai_roofline_pct.reduce(ctx_of(layout, drained), "^no_such_kernel", "mla_fold") is None
+    assert lm_roofline_pct.reduce(ctx_of({"tokens": 8192, "ssm_heads": 64}, drained), "mla_fold", pattern=fold) is None
+    # no held-row count: nothing for the count that takes it; the fold's takes none and reads
+    assert lm_roofline_pct.reduce(ctx_of(layout, {"steps": 4}, "ragged-dot-none.3"), "held_experts",
+                                  pattern="^ragged-dot") is None
+    assert lm_roofline_pct.reduce(ctx_of(layout, {"steps": 4}), "mla_fold", pattern=fold) == pytest.approx(got_fold)
+    assert lm_roofline_pct.reduce(ctx_of(layout, drained), "mla_fold", pattern="^no_such_kernel") is None
     assert program_span_pct.reduce(ctx_of(layout, drained), "train.drain", "mtp_targets", "tokens") == \
         pytest.approx(100 * 8190 / 8192)
     assert program_span_pct.reduce(ctx_of(layout, {"steps": 4, "tokens": 9}), "train.drain", "mtp_targets",

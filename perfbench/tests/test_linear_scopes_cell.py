@@ -79,8 +79,8 @@ def test_the_manifest_holds_with_the_new_entries_behind_the_accepted_ones():
     assert m.problems() == []
     names = [e["name"] for e in m.data["per_layer"]]
     first = names.index("lin_scope_coverage_pct")
-    # appended in one block behind the 103 entries a3c6254 had; a later PR appends behind them
-    assert first >= 103 and names[first:first + len(NEW)] == list(NEW)
+    # appended in one block behind the entries a3c6254 had (103 then; PR 53 folded those before it)
+    assert names[first:first + len(NEW)] == list(NEW)
 
 
 @pytest.mark.parametrize("name", sorted(NEW))

@@ -271,7 +271,7 @@ def test_the_roofline_reducer_on_recorded_counts(config):
     import types
 
     from perfbench import program_spans
-    from perfbench.reducers import nemotron_roofline_pct
+    from perfbench.reducers import lm_roofline_pct
 
     shapes = nemotron_lm_fit.create(config, 1, 1).layout_dims
     peaks = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
@@ -279,13 +279,13 @@ def test_the_roofline_reducer_on_recorded_counts(config):
     def ctx_of(layout, stats):
         table = program_spans.Table([program_spans.Span("train.drain", 10.0, 1.0, stats=stats)])
         return types.SimpleNamespace(
-            run=types.SimpleNamespace(program_spans=table), w0=0.0, w1=100.0, facts={"layout": layout, "steps": 4},
-            peaks=peaks, per=lambda unit: 4, ops=lambda: [("ragged-dot-none.3", 20.0, 160e6)])
+            run=types.SimpleNamespace(program_spans=table), config=config, w0=0.0, w1=100.0,
+            facts={"layout": layout, "steps": 4}, peaks=peaks, per=lambda unit: 4, ops=lambda: [("ragged-dot-none.3", 20.0, 160e6)])
 
     drained = {"rows_held": 4 * 12_288, "steps": 4}
-    got = nemotron_roofline_pct.reduce(ctx_of(shapes, drained), "held_experts", pattern="^ragged-dot")
+    got = lm_roofline_pct.reduce(ctx_of(shapes, drained), "held_experts", pattern="^ragged-dot")
     flops, nbytes = nemotron_costs.held_experts(rows_held=12_288, **shapes)
     assert got == pytest.approx(100 * max(flops / 197e12, nbytes / 819e9) / 0.040) and 0 < got < 100
-    assert nemotron_roofline_pct.reduce(ctx_of({"tokens": 8192}, drained), "held_experts", pattern="^ragged-dot") is None
-    assert nemotron_roofline_pct.reduce(ctx_of(shapes, {"steps": 4}), "held_experts", pattern="^ragged-dot") is None
-    assert nemotron_roofline_pct.reduce(ctx_of(shapes, drained), "held_experts", pattern="^no_such_kernel") is None
+    assert lm_roofline_pct.reduce(ctx_of({"tokens": 8192}, drained), "held_experts", pattern="^ragged-dot") is None
+    assert lm_roofline_pct.reduce(ctx_of(shapes, {"steps": 4}), "held_experts", pattern="^ragged-dot") is None
+    assert lm_roofline_pct.reduce(ctx_of(shapes, drained), "held_experts", pattern="^no_such_kernel") is None

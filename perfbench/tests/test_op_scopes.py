@@ -18,8 +18,7 @@ from perfbench.tools import scopes as tool
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 RENAMED = {"^ragged-dot": "lm.block/experts"}
-NEW = ("lm_scope_coverage_pct", "lm_head_ms", "lm_head_remat_ms", "lm_stream_ms", "lm_block_remat_ms",
-       "lm_permute_ms", "lm_opt_ms")
+NEW = ("lm_scope_coverage_pct", "lm_head_ms", "lm_stream_ms", "lm_block_remat_ms", "lm_permute_ms", "lm_opt_ms")
 
 
 def _table():
@@ -308,7 +307,7 @@ def test_the_tool_prints_a_written_traces_table(tmp_path, capsys):
     where = tmp_path / "plugins" / "profile" / "x"
     where.mkdir(parents=True)
     (where / "vm.xplane.pb").write_bytes(host + device)
-    assert tool.main(["--trace-dir", str(tmp_path)]) == 0
+    assert tool.main(["--workload", "olmoe_1b_7b.fit_packed4k", "--trace-dir", str(tmp_path)]) == 0
     lines = [ln.split(" ", 1) for ln in capsys.readouterr().out.splitlines()]
     rows = [json.loads(body) for kind, body in lines if kind == "scope"]
     assert [r["scope"] for r in rows] == ["lm.block/fold/flash_fold_fwd", "lm.opt", "lm.block/experts"]
@@ -320,15 +319,19 @@ def test_the_tool_prints_a_written_traces_table(tmp_path, capsys):
     assert step["coverage_pct"] == 87.5
 
 
-def test_the_seven_metrics_are_listed_for_the_lm_cells_and_read_one_step_marker():
+def test_the_six_metrics_are_listed_for_the_lm_cells_and_read_the_configurations_step_marker():
+    """(Seven until PR 53: nothing under ``lm.head`` is recomputed since PR 45, so its remat reading went.)"""
     m = Manifest()
     assert m.problems() == []
     lm = ["olmoe_1b_7b.fit_packed4k", "zaya1_8b.fit_packed8k", "ouro_2_6b.fit_looped4k"]
-    assert [e["name"] for e in m.data["per_layer"][-7:]] == list(NEW)
     for name in NEW:
         spec, entry = m.layer_metric(name), m.per_layer[name]
-        assert set(entry["workloads"]) <= set(lm) and entry["source"] == "device_trace"
-        assert spec["params"]["holds"] == m.layer_metric("lm_step_ms")["params"]["holds"]
-        assert spec["params"]["renamed"] == RENAMED
+        assert set(entry["workloads"]) & set(lm) and entry["source"] == "device_trace"
+        assert spec["params"]["holds"] == m.layer_metric("lm_step_ms")["params"]["holds"] == {"perf": "step_holds"}
+        assert spec["params"]["renamed"] == {"perf": "renamed"}
+    for cell in lm:
+        perf = m.config(m.cells[cell]["config"])["perf"]
+        assert perf["step_holds"] == "flash_fold_fwd" and perf["renamed"] == RENAMED
     assert m.per_layer["lm_block_remat_ms"]["workloads"] == lm[1:]  # OLMoE's lone block is not checkpointed
-    assert m.per_layer["lm_permute_ms"]["workloads"] == lm[:2]  # the ouro block has no experts
+    assert m.per_layer["lm_permute_ms"]["workloads"][:2] == lm[:2]  # the ouro block has no experts
+    assert lm[2] not in m.per_layer["lm_permute_ms"]["workloads"]

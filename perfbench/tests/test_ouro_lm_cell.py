@@ -19,7 +19,7 @@ from perfbench.tests.test_rehearse import rehearsal_of, run_cell
 
 CELL = "ouro_2_6b.fit_looped4k"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-NEW = ("ouro_step_ms", "ouro_mfu_pct", "loop_attn_ms", "loop_attn_roofline", "loop_exit_mean_trip")
+SHARED = ("lm_step_ms", "lm_mfu_pct", "attn_ms", "attn_roofline")  # before PR 53: ouro_step_ms, ..., loop_attn_roofline
 
 
 @pytest.fixture(scope="module")
@@ -219,13 +219,15 @@ def test_the_new_reducers_on_recorded_counts():
     (the parent) gives nothing to read and no error; the two cost reducers give
     nothing for a layout without ``loops``."""
     from perfbench import program_spans
-    from perfbench.reducers import ouro_mfu_pct, ouro_roofline_pct, program_span_ratio
+    from perfbench.reducers import lm_mfu_pct, lm_roofline_pct, program_span_ratio
+
+    config = Manifest().config("ouro_2_6b")
 
     def ctx_of(stats, layout=None):
         table = program_spans.Table([program_spans.Span("train.drain", 10.0 + i, 1.0, stats=s)
                                      for i, s in enumerate(stats)])
         run = types.SimpleNamespace(program_spans=table)
-        return types.SimpleNamespace(run=run, w0=0.0, w1=100.0, facts={"layout": layout, "steps": 4},
+        return types.SimpleNamespace(run=run, config=config, w0=0.0, w1=100.0, facts={"layout": layout, "steps": 4},
                                      peaks={"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
                                      per=lambda unit: 4, ops=lambda: [], trace=types.SimpleNamespace(modules={}),
                                      dev=None)
@@ -236,15 +238,15 @@ def test_the_new_reducers_on_recorded_counts():
     parent = ctx_of([{"steps": 8, "tokens": 131072, "expert_rows_max": 9, "expert_rows_mean": 3}])
     assert program_span_ratio.reduce(parent, "train.drain", "exit_trip_sum", "tokens") is None
     other = ctx_of([], layout={"tokens": 16384, "layers": 1})
-    assert ouro_mfu_pct.reduce(other, "flash_fold_fwd") is None
-    assert ouro_roofline_pct.reduce(other, "flash_fold_(fwd|bwd_dq|bwd_dkv)", "attention_fold") is None
+    assert lm_mfu_pct.reduce(other, "flash_fold_fwd") is None
+    assert lm_roofline_pct.reduce(other, "attention_fold", pattern="flash_fold_(fwd|bwd_dq|bwd_dkv)") is None
 
 
 def test_every_new_metric_file_matches_its_entry():
     m = Manifest()
     names = [n for n, e in m.per_layer.items() if e.get("workloads") == [CELL]]
-    assert tuple(names) == NEW
-    for name in names:
+    assert names == ["loop_exit_mean_trip"]  # what only a looped stack has; the rest it shares
+    for name in names + list(SHARED):
         with open(os.path.join(HERE, "layer_metrics", f"{name}.json"), encoding="utf-8") as f:
             spec = json.load(f)
         entry = m.per_layer[name]
@@ -252,8 +254,8 @@ def test_every_new_metric_file_matches_its_entry():
             {k: entry[k] for k in ("unit", "better", "source", "layer", "moves")}
     assert m.problems() == []
     assert len(m.cells[CELL]["why"]) <= 200 and m.cells[CELL]["chips"] == 1
-    assert m.end_to_end["fit_rows_per_s"]["workloads"][-1] == CELL
-    assert set(m.cell_metrics("per_layer", CELL)) == set(NEW) | {"fit_idle_pct", "fit_peak_hbm_gb"}
+    assert CELL in m.end_to_end["fit_rows_per_s"]["workloads"]
+    assert set(SHARED) | {"loop_exit_mean_trip", "fit_idle_pct", "fit_peak_hbm_gb"} <= set(m.cell_metrics("per_layer", CELL))
 
 
 #: ``perfbench.run`` with this cell's own system class broken underneath: the

@@ -20,9 +20,10 @@ from perfbench.systems import sdar_lm_fit
 
 CELL = "sdar_30b_a3b.fit_bd4k"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-NEW = ("sdar_step_ms", "sdar_mfu_pct", "bd_attn_ms", "bd_attn_roofline", "bd_chunks_visited_pct", "bd_noise_ms",
-       "sdar_expert_ms", "sdar_expert_roofline", "sdar_held_share_pct", "sdar_rows_carried_pct", "bd_targets_pct",
-       "sdar_scope_coverage_pct", "sdar_proj_ms", "sdar_permute_ms", "sdar_head_ms", "sdar_opt_ms")
+#: What the cell reports: what only block diffusion has, and the quantities it shares (before PR 53 under ``sdar_*``).
+OWN = ("bd_attn_ms", "bd_attn_roofline", "bd_chunks_visited_pct", "bd_noise_ms", "bd_targets_pct", "lm_proj_ms")
+SHARED = ("lm_step_ms", "lm_mfu_pct", "moe_expert_ms", "moe_expert_roofline", "moe_held_share_pct",
+          "moe_rows_carried_pct", "lm_scope_coverage_pct", "lm_permute_ms", "lm_head_ms", "lm_opt_ms")
 PUBLISHED = dict(seq=4096, block=4, hidden=2048, layers=6, heads=32, kv_heads=4, head_dim=128, experts=128,
                  experts_held=16, width=768, vocab=18992)
 
@@ -55,10 +56,10 @@ def test_the_manifest_has_no_problems_and_every_new_metric_file_matches_its_entr
     assert manifest.problems() == []
     assert manifest.cell_metrics("end_to_end", CELL) == ["fit_rows_per_s", "setup_s"]
     listed = manifest.cell_metrics("per_layer", CELL)
-    assert listed == ["fit_idle_pct", "fit_peak_hbm_gb", *NEW]
-    for name in NEW:
+    assert set(listed) == {"fit_idle_pct", "fit_peak_hbm_gb", *OWN, *SHARED}
+    for name in OWN + SHARED:
         entry, own = manifest.per_layer[name], manifest.layer_metric(name)
-        assert entry["workloads"] == [CELL] and entry["moves"] == "fit_rows_per_s"
+        assert (entry["workloads"] == [CELL]) == (name in OWN) and entry["moves"] == "fit_rows_per_s"
         for key in ("name", "unit", "better", "source", "layer", "moves"):
             assert own[key] == entry[key], (name, key)
         assert os.path.exists(os.path.join(manifest.dir, "reducers", own["reducer"] + ".py"))
@@ -66,7 +67,7 @@ def test_the_manifest_has_no_problems_and_every_new_metric_file_matches_its_entr
     # the new kernels' names are read by this cell's metrics alone: no accepted pattern finds them
     for name, entry in manifest.per_layer.items():
         pattern = manifest.layer_metric(name)["params"].get("pattern", "")
-        if name not in NEW and "flash_fold" in pattern:
+        if name not in OWN and "flash_fold" in str(pattern):
             import re
 
             assert not any(re.search(pattern, f"flash_fold_bd_{part}") for part in ("fwd", "bwd_dq", "bwd_dkv")), name
@@ -143,35 +144,37 @@ def test_the_new_reducers_on_recorded_counts(capsys):
     other names (the parent), or of one that writes no such count, gives
     nothing to read and does not raise."""
     from perfbench import program_spans
-    from perfbench.reducers import program_span_pct, sdar_mfu_pct, sdar_roofline_pct
+    from perfbench.reducers import lm_mfu_pct, lm_roofline_pct, program_span_pct
 
     peaks = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
     layout = dict(PUBLISHED, tokens=8192, batch=2)
+    config = Manifest().config("sdar_30b_a3b")
 
     def ctx_of(shapes, stats, op="flash_fold_bd_bwd_dq.7"):
         table = program_spans.Table([program_spans.Span("train.drain", 10.0, 1.0, stats=stats)])
         return types.SimpleNamespace(
-            run=types.SimpleNamespace(program_spans=table), w0=0.0, w1=100.0, facts={"layout": shapes, "steps": 4},
-            peaks=peaks, per=lambda unit: 4, ops=lambda: [(op, 20.0, 800e6)],  # 0.8 s over 4 steps
+            run=types.SimpleNamespace(program_spans=table), config=config, w0=0.0, w1=100.0,
+            facts={"layout": shapes, "steps": 4}, peaks=peaks, per=lambda unit: 4, ops=lambda: [(op, 20.0, 800e6)],  # 0.8 s over 4 steps
             trace=types.SimpleNamespace(modules={0: [("jit_step", 19.0, 4000e6)]}), dev=0)
 
     fold = "flash_fold_bd_(fwd|bwd_dq|bwd_dkv)"
     drained = {"rows_held": 4 * 100_000, "steps": 4, "tokens": 4 * 8192, "targets_masked": 4 * 4000}
-    got = sdar_roofline_pct.reduce(ctx_of(layout, drained), fold, "block_diffusion_fold")
+    got = lm_roofline_pct.reduce(ctx_of(layout, drained), "block_diffusion_fold", pattern=fold)
     flops, nbytes = sdar_costs.block_diffusion_fold(**layout)
     assert got == pytest.approx(100 * (flops / 197e12) / 0.2) and 0 < got < 100
     assert "bound by mxu" in capsys.readouterr().out
-    got = sdar_roofline_pct.reduce(ctx_of(layout, drained, "ragged-dot-none.3"), "^ragged-dot", "held_experts")
+    got = lm_roofline_pct.reduce(ctx_of(layout, drained, "ragged-dot-none.3"), "held_experts", pattern="^ragged-dot")
     flops, nbytes = sdar_costs.held_experts(rows_held=100_000, **layout)
     assert got == pytest.approx(100 * max(flops / 197e12, nbytes / 819e9) / 0.2) and 0 < got < 100
-    got = sdar_mfu_pct.reduce(ctx_of(layout, drained, "flash_fold_bd_fwd.2"), "flash_fold_bd_fwd")
+    got = lm_mfu_pct.reduce(ctx_of(layout, drained, "flash_fold_bd_fwd.2"), "flash_fold_bd_fwd")
     flops, _ = sdar_costs.model(rows_held=100_000, **layout)
     assert got == pytest.approx(100 * flops / 197e12 / 1.0) and 0 < got < 100  # a 4 s module over 4 steps
     # another layout; the parent's kernel names; no held-row count: nothing to read
-    assert sdar_roofline_pct.reduce(ctx_of({"tokens": 8192, "q_rank": 1536}, drained), fold, "block_diffusion_fold") is None
-    assert sdar_roofline_pct.reduce(ctx_of(layout, drained, "flash_fold_bwd_dq.7"), fold, "block_diffusion_fold") is None
-    assert sdar_roofline_pct.reduce(ctx_of(layout, {"steps": 4}), fold, "block_diffusion_fold") is None
-    assert sdar_mfu_pct.reduce(ctx_of(layout, drained, "flash_fold_fwd.2"), "flash_fold_bd_fwd") is None
+    assert lm_roofline_pct.reduce(ctx_of({"tokens": 8192, "q_rank": 1536}, drained), "block_diffusion_fold", pattern=fold) is None
+    assert lm_roofline_pct.reduce(ctx_of(layout, drained, "flash_fold_bwd_dq.7"), "block_diffusion_fold", pattern=fold) is None
+    assert lm_roofline_pct.reduce(ctx_of(layout, {"steps": 4}, "ragged-dot-none.3"), "held_experts",
+                                  pattern="^ragged-dot") is None
+    assert lm_mfu_pct.reduce(ctx_of(layout, drained, "flash_fold_fwd.2"), "flash_fold_bd_fwd") is None
     assert program_span_pct.reduce(ctx_of(layout, drained), "train.drain", "targets_masked", "tokens") == \
         pytest.approx(100 * 4000 / 8192)
     assert program_span_pct.reduce(ctx_of(layout, {"steps": 4, "tokens": 9}), "train.drain", "targets_masked",

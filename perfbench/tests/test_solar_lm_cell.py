@@ -17,11 +17,11 @@ from perfbench.systems import solar_lm_fit
 
 CELL = "solar_open2_250b.fit_kda4k"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-NEW_METRICS = ("solar_step_ms", "solar_mfu_pct", "kda_scan_ms", "kda_scan_roofline", "kda_scan_kernel_pct",
-               "kda_conv_ms", "kda_gate_ms", "gated_attn_ms", "gated_attn_roofline", "solar_expert_ms",
-               "solar_expert_roofline", "solar_head_ms", "solar_rows_carried_pct", "solar_scope_coverage_pct")
-# the held share is read by the accepted metric of the same reducer and counts: per_layer holds 128 entries at most
-JOINED_METRICS = ("moe_held_share_pct",)
+#: What only a delta-rule stack has, and the quantities the cell shares with the others (before PR 53 under ``solar_*``
+#: and ``gated_attn_*``; the held share joined ``moe_held_share_pct`` at PR 51 already, when ``per_layer`` was full).
+NEW_METRICS = ("kda_scan_ms", "kda_scan_roofline", "kda_scan_kernel_pct", "kda_conv_ms", "kda_gate_ms")
+JOINED_METRICS = ("lm_step_ms", "lm_mfu_pct", "attn_ms", "attn_roofline", "moe_expert_ms", "moe_expert_roofline",
+                  "lm_head_ms", "moe_rows_carried_pct", "lm_scope_coverage_pct", "moe_held_share_pct")
 
 
 @pytest.fixture(scope="module")
@@ -93,7 +93,10 @@ def test_the_cell_reports_every_new_metric_and_nothing_else_changed():
         assert entry["workloads"] == [CELL] and entry["moves"] == "fit_rows_per_s", name
         assert (entry["unit"], entry["better"], entry["source"], entry["layer"]) == \
             (spec["unit"], spec["better"], spec["source"], spec["layer"]), name
-    assert set(NEW_METRICS + JOINED_METRICS) <= set(manifest.cell_metrics("per_layer", CELL))
+    assert set(NEW_METRICS + JOINED_METRICS) | {"fit_idle_pct", "fit_peak_hbm_gb"} == \
+        set(manifest.cell_metrics("per_layer", CELL))
+    assert all(CELL in manifest.per_layer[name]["workloads"] and len(manifest.per_layer[name]["workloads"]) > 1
+               for name in JOINED_METRICS)
     assert len(manifest.per_layer) <= 128
     held = manifest.layer_metric("moe_held_share_pct")
     assert (held["reducer"], held["params"]) == (
@@ -101,8 +104,8 @@ def test_the_cell_reports_every_new_metric_and_nothing_else_changed():
     assert manifest.layer_metric("kda_scan_ms")["params"]["scopes"] == ["lm.block/kda"]
     assert manifest.layer_metric("kda_scan_roofline")["params"]["cost"] == "kda_scan"
     # what reaches the trace without a name and is billed to the experts' scope by instruction name
-    renamed = manifest.layer_metric("solar_scope_coverage_pct")["params"]["renamed"]
-    assert set(renamed) == {"^ragged-dot", "^broadcast\\.\\d+"}
+    assert manifest.layer_metric("lm_scope_coverage_pct")["params"]["renamed"] == {"perf": "renamed"}
+    assert set(manifest.config("solar_open2_250b")["perf"]["renamed"]) == {"^ragged-dot", "^broadcast\\.\\d+"}
 
 
 def test_the_nameless_zero_fills_are_billed_to_the_experts_scope():
@@ -111,7 +114,7 @@ def test_the_nameless_zero_fills_are_billed_to_the_experts_scope():
     scopes."""
     from perfbench import op_scopes
 
-    renamed = Manifest().layer_metric("solar_scope_coverage_pct")["params"]["renamed"]
+    renamed = Manifest().config("solar_open2_250b")["perf"]["renamed"]
     rows = [("fusion.7", 1.0, 5.0, "jit(step)/jvp(lm.block)/experts/mul"), ("ragged-dot-none.3", 7.0, 2.0, None),
             ("broadcast.306.clone.4", 10.0, 3.0, None), ("copy-done.12", 14.0, 4.0, None)]
     ops = {op.name: op.scope for op in op_scopes.step_ops(rows, [(0.0, 100.0)], "lm.", renamed)}
@@ -315,7 +318,7 @@ def test_the_reducers_on_recorded_counts(config):
     import types
 
     from perfbench import program_spans
-    from perfbench.reducers import solar_roofline_pct
+    from perfbench.reducers import lm_roofline_pct
 
     shapes = solar_lm_fit.create(config, 1, 1).layout_dims
     peaks = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
@@ -323,13 +326,13 @@ def test_the_reducers_on_recorded_counts(config):
     def ctx_of(layout, stats):
         table = program_spans.Table([program_spans.Span("train.drain", 10.0, 1.0, stats=stats)])
         return types.SimpleNamespace(
-            run=types.SimpleNamespace(program_spans=table), w0=0.0, w1=100.0, facts={"layout": layout, "steps": 8},
-            peaks=peaks, per=lambda unit: 8, ops=lambda: [("ragged-dot-none.3", 20.0, 160e6)])
+            run=types.SimpleNamespace(program_spans=table), config=config, w0=0.0, w1=100.0,
+            facts={"layout": layout, "steps": 8}, peaks=peaks, per=lambda unit: 8, ops=lambda: [("ragged-dot-none.3", 20.0, 160e6)])
 
     drained = {"rows_held": 8 * 3_000, "steps": 8}
-    got = solar_roofline_pct.reduce(ctx_of(shapes, drained), "held_experts", pattern="^ragged-dot")
+    got = lm_roofline_pct.reduce(ctx_of(shapes, drained), "held_experts", pattern="^ragged-dot")
     flops, nbytes = solar_costs.held_experts(rows_held=3_000, **shapes)
     assert got == pytest.approx(100 * max(flops / 197e12, nbytes / 819e9) / 0.020) and 0 < got < 100
-    assert solar_roofline_pct.reduce(ctx_of({"tokens": 4096}, drained), "held_experts", pattern="^ragged-dot") is None
-    assert solar_roofline_pct.reduce(ctx_of(shapes, {"steps": 8}), "held_experts", pattern="^ragged-dot") is None
-    assert solar_roofline_pct.reduce(ctx_of(shapes, drained), "held_experts", pattern="^no_such_kernel") is None
+    assert lm_roofline_pct.reduce(ctx_of({"tokens": 4096}, drained), "held_experts", pattern="^ragged-dot") is None
+    assert lm_roofline_pct.reduce(ctx_of(shapes, {"steps": 8}), "held_experts", pattern="^ragged-dot") is None
+    assert lm_roofline_pct.reduce(ctx_of(shapes, drained), "held_experts", pattern="^no_such_kernel") is None

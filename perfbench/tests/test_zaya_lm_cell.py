@@ -179,7 +179,7 @@ def test_the_new_reducers_on_recorded_counts(config):
     """``rows_held`` a step and the held share from ``train.drain`` counts; a
     program that writes none (the parent) gives nothing to read and no error."""
     from perfbench import program_spans
-    from perfbench.reducers import program_span_ratio_max, program_span_share_pct, zaya_roofline_pct
+    from perfbench.reducers import lm_roofline_pct, program_span_ratio_max, program_span_share_pct
 
     def ctx_of(stats):
         table = program_spans.Table([program_spans.Span("train.drain", 10.0 + i, 1.0, stats=s)
@@ -191,27 +191,24 @@ def test_the_new_reducers_on_recorded_counts(config):
                        "held_rows_mean": 1041.7},
                       {"steps": 8, "rows_held": 380_000, "rows_absent": 406_432, "held_rows_max": 2500,
                        "held_rows_mean": 989.6}])
-    assert zaya_roofline_pct.rows_held_per_step(counted) == 780_000 / 16
+    assert lm_roofline_pct.rows_held_per_step(counted) == 780_000 / 16
     share = program_span_share_pct.reduce(counted, "train.drain", "rows_held", "rows_absent")
     assert abs(share - 100 * 780_000 / (2 * 786_432)) < 1e-9
     ratio = program_span_ratio_max.reduce(counted, "train.drain", "held_rows_max", "held_rows_mean")
     assert abs(ratio - 3000 / 1041.7) < 1e-9
     parent = ctx_of([{"steps": 8, "expert_rows_max": 9, "expert_rows_mean": 3}])
-    assert zaya_roofline_pct.rows_held_per_step(parent) is None
+    assert lm_roofline_pct.rows_held_per_step(parent) is None
     assert program_span_share_pct.reduce(parent, "train.drain", "rows_held", "rows_absent") is None
     assert program_span_ratio_max.reduce(parent, "train.drain", "held_rows_max", "held_rows_mean") is None
 
 
-def test_every_new_metric_file_matches_its_entry():
+def test_the_cell_reports_the_shared_quantities_under_their_one_name():
+    """(``test_manifest.py`` holds every listed metric to its file, its
+    reducer and this cell's configuration keys.)"""
     m = Manifest()
-    names = [n for n, e in m.per_layer.items() if e.get("workloads") == [CELL]]
-    assert len(names) == 8
-    for name in names:
-        with open(os.path.join(HERE, "layer_metrics", f"{name}.json"), encoding="utf-8") as f:
-            spec = json.load(f)
-        entry = m.per_layer[name]
-        assert {k: spec[k] for k in ("unit", "better", "source", "layer", "moves")} == \
-            {k: entry[k] for k in ("unit", "better", "source", "layer", "moves")}
+    assert {"lm_step_ms", "lm_mfu_pct", "attn_ms", "attn_roofline", "moe_expert_ms", "moe_expert_roofline",
+            "moe_held_share_pct", "held_load_max_over_mean"} <= set(m.cell_metrics("per_layer", CELL))
+    assert m.config("zaya1_8b")["perf"]["costs"] == "zaya_costs"
     assert len(m.cells[CELL]["why"]) <= 200 and "twice its share" in m.cells[CELL]["why"]
     assert CELL in m.end_to_end["fit_rows_per_s"]["workloads"]
 

@@ -11,8 +11,10 @@ instruction kinds that took most of it (``flash_fold_bwd_dkv``, ``fusion``,
 ...: a name without its number); then the ``unscoped`` operations by
 instruction name and, summed, by kind, and a ``step`` line with the sums beside
 the step programs' own time. The marker of a step program, the scopes' root and the renamed
-kernels are those of one metric's file (``--metric``). The result line of a
-traced run gives a few sums; this gives every row.
+kernels are those of one metric's file (``--metric``), read as a traced run
+reads them: what the file leaves to the configuration comes from the
+``perf`` block of ``--workload``'s. The result line of a traced run gives a
+few sums; this gives every row.
 """
 from __future__ import annotations
 
@@ -24,7 +26,7 @@ import sys
 
 from perfbench import op_scopes, xplane
 from perfbench.manifest import HERE, Manifest
-from perfbench.reduce import WINDOW_SPAN
+from perfbench.reduce import WINDOW_SPAN, resolved
 
 KIND = re.compile(r"[.\d]+$")
 
@@ -48,17 +50,20 @@ def table(ops, steps: int) -> list:
 
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    parser.add_argument("--workload")
-    parser.add_argument("--trace-dir")
+    parser.add_argument("--workload", required=True)
+    parser.add_argument("--trace-dir", help="another trace of that cell than the last traced run's")
     parser.add_argument("--metric", default="lm_scope_coverage_pct",
                         help="the layer metric whose file names the step programs' marker, the root and the renamed kernels")
     parser.add_argument("--top", type=int, default=4, help="instruction kinds printed per scope")
     parser.add_argument("--unscoped", type=int, default=25, help="unscoped operations printed")
     args = parser.parse_args(argv)
-    if not (args.workload or args.trace_dir):
-        parser.error("give --workload or --trace-dir")
     trace_dir = args.trace_dir or os.path.join(HERE, ".trace", args.workload)
-    params = Manifest().layer_metric(args.metric)["params"]
+    manifest = Manifest()
+    config = manifest.config(manifest.cell(args.workload)["config"])
+    params = resolved(manifest.layer_metric(args.metric)["params"], config)
+    if params is None:
+        print(f"scopes: {args.workload}'s configuration lacks a 'perf' key that {args.metric} reads", file=sys.stderr)
+        return 1
     root = params.get("root", "lm.")
 
     trace = xplane.read_trace(trace_dir, {WINDOW_SPAN})
