@@ -72,6 +72,7 @@ class MLMetrics:
     TRAIN_LM_SCAN_LAYERS = "ml.train.lm.scan.layers"  # Mamba-2 layer applications (layers x steps), counter
     TRAIN_LM_KDA_CHUNKS = "ml.train.lm.kda.chunks"  # chunks of the gated delta rule (chunks x heads x sequences x delta-rule layers x steps), counter
     TRAIN_LM_KDA_KERNEL_CHUNKS = "ml.train.lm.kda.kernel_chunks"  # those of them that passed through the delta rule's kernel pair (parallel/kda.py), counter
+    TRAIN_LM_KDA_SCALAR_CHUNKS = "ml.train.lm.kda.scalar_chunks"  # those of them that took the kernel pair's one-decay-a-head form (a [chunk, chunk] decay factor, no sub-chunks), counter
     TRAIN_LM_KDA_LAYERS = "ml.train.lm.kda.layers"  # delta-rule layer applications (layers x steps), counter
     TRAIN_LM_MLA_LAYERS = "ml.train.lm.mla.layers"  # latent-attention layer applications (layers, a multi-token-prediction module's among them, x steps), counter
     TRAIN_LM_MTP_TARGETS = "ml.train.lm.mtp.targets"  # positions the multi-token-prediction module scored (sequences x (length - 2) x steps), counter

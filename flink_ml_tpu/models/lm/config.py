@@ -2,17 +2,19 @@
 tree, shared by the stage (``decoder_lm.py``) and the plain references
 (``reference.py``, ``reference_zaya.py``, ``reference_ouro.py``,
 ``reference_laguna.py``, ``reference_nemotron.py``, ``reference_joyai.py``,
-``reference_sdar.py``, ``reference_solar.py``) so that one set of weights can
-be handed to both.
+``reference_sdar.py``, ``reference_solar.py``, ``reference_olmo_hybrid.py``) so
+that one set of weights can be handed to both.
 
 ``layers(cfg)`` gives one hashable record a layer (``Layer``): a MIXER, a
 FEED-FORWARD (either may be absent), the stream's width and the norms'
 epsilon, and how a sublayer joins the residual: ``x + y``, or (``scaled``) a
-learned per-channel scale and bias on both. The parameter tree
+learned per-channel scale and bias on both; a sublayer reads the stream
+behind its norm (``norm``) or, no such leaf named, as it is, and its output
+joins as it is or behind a norm of its own (``out_norm``). The parameter tree
 (``param_shapes``), the forward (``decoder_lm._layer``), the fit's counts and
 the stage's checks all read that record and nothing else about a layer.
 
-``LMConfig.block`` names one of eight PRESETS over that description
+``LMConfig.block`` names one of nine PRESETS over that description
 (``_PRESETS``: the only place that names a model), each a published stack:
 
 ========== ================================================= ==========================================
@@ -37,6 +39,11 @@ solar_open2 the gated delta rule with a decay a key channel   experts with sigmo
            (``KDA``); in the layers ``gqa_layers`` names,    expert (laguna's record), in every layer
            attention on grouped queries without rotation
            under an element-wise sigmoid gate on its output
+olmo_hybrid the gated delta rule with ONE decay a head on     dense; NO norm before either sublayer:
+           heads wider in their values than in their keys    each one's OUTPUT is normed before it joins
+           (``GatedDelta``); in the layers ``gqa_layers``
+           names, attention without rotation under a
+           QK-norm over the whole projection
 ========== ================================================= ==========================================
 
 ``sdar`` is also the one kind whose OBJECTIVE is not next-token prediction:
@@ -105,6 +112,17 @@ The sublayers, with ``a = heads * head_dim`` and ``c = kv_heads * head_dim``:
   i]``, the output's norm over each head's channels ``"o_norm": [head_dim]``,
   ``"wo": [i, d]``. ``heads`` may be a chip's share of the layer's: ``wo``'s
   output is then the held heads' part of the sum.
+- ``GatedDelta`` (the gated delta rule with ONE log-decay a head and position
+  on heads of ``key_dim`` key and ``value_dim`` value channels behind a short
+  causal convolution; ``reference_olmo_hybrid.py``), ``a = heads * key_dim``
+  and ``c = heads * value_dim``: ``"wq"/"wk": [d, a], "wv": [d, c]``,
+  ``"conv_q"/"conv_k": [conv_kernel, a], "conv_v": [conv_kernel, c]`` (no bias;
+  tap ``conv_kernel - 1`` reads the position itself), the decay ``"Wa": [d,
+  heads], "A_log"/"dt_bias": [heads]``, the correction's strength ``"Wb": [d,
+  heads]``, the output gate ``"wg": [d, c]``, the output's norm over each
+  head's channels ``"o_norm": [value_dim]``, ``"wo": [c, d]``, the norm of what
+  joins the stream ``[d]`` (``out_norm``). ``heads`` may be a chip's share of
+  the layer's, as ``KDA``'s.
 - ``Dense`` (one SwiGLU): ``"w_gate"/"w_up": [d, width], "w_down": [width,
   d]``, the output's norm ``[d]`` if any (``ffn_out_norm``).
 - ``Experts`` (``top_k`` of ``E`` routed experts, ``H`` of them HELD here:
@@ -127,8 +145,8 @@ import dataclasses
 import math
 from typing import List, NamedTuple, Optional, Tuple, Union
 
-__all__ = ["LMConfig", "BLOCKS", "MIXERS", "Rotation", "Attention", "LatentAttention", "CCA", "Mamba2", "KDA", "Dense",
-           "Experts", "Layer", "layers", "exit_gate", "mtp_layer", "leaves", "param_shapes", "num_params", "ONES",
+__all__ = ["LMConfig", "BLOCKS", "MIXERS", "Rotation", "Attention", "LatentAttention", "CCA", "Mamba2", "KDA", "GatedDelta",
+           "Dense", "Experts", "Layer", "layers", "exit_gate", "mtp_layer", "leaves", "param_shapes", "num_params", "ONES",
            "ZEROS", "NORMAL", "SMALL",
            "SMALL_SCALE", "DT_BIAS", "A_LOG", "DT_RANGE", "DT_FLOOR", "A_RANGE", "NOISE_EPS"]
 
@@ -224,6 +242,9 @@ class LMConfig(NamedTuple):
     gqa_layers: Tuple[int, ...] = ()
     kda_heads: int = 0
     kda_head_dim: int = 0
+    # the olmo_hybrid block's own beside those: a delta-rule head's value channels (``kda_head_dim`` counts its key
+    # channels)
+    kda_value_dim: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -313,6 +334,33 @@ class KDA:
     chunk: int
     norm: str = "attn_norm"
 
+    @property
+    def conv_channels(self) -> int:  # a head's convolved channels: its queries', keys' and values'
+        return 3 * self.head_dim
+
+    @property
+    def state_size(self) -> int:  # the entries of a head's state
+        return self.head_dim ** 2
+
+
+@dataclasses.dataclass(frozen=True)
+class GatedDelta:
+    heads: int  # held here: all of the layer's, or a chip's share of them
+    key_dim: int  # key (and query) channels a head
+    value_dim: int  # value channels a head: the state is [key_dim, value_dim]
+    conv_kernel: int
+    chunk: int
+    norm: str = ""  # the leaf of the norm before the mixer; empty: it reads the stream as it is
+    out_norm: str = ""  # the leaf of the norm on the output; empty: none
+
+    @property
+    def conv_channels(self) -> int:
+        return 2 * self.key_dim + self.value_dim
+
+    @property
+    def state_size(self) -> int:
+        return self.key_dim * self.value_dim
+
 
 @dataclasses.dataclass(frozen=True)
 class Dense:
@@ -341,7 +389,7 @@ class Experts:
 class Layer:
     hidden: int
     eps: float
-    mixer: Union[Attention, LatentAttention, CCA, Mamba2, KDA, None]
+    mixer: Union[Attention, LatentAttention, CCA, Mamba2, KDA, GatedDelta, None]
     ffn: Union[Dense, Experts, None]
     scaled: bool = False  # a learned scale and bias on the residual and on each sublayer's output
 
@@ -414,10 +462,20 @@ def _solar_open2(cfg: LMConfig):
                  for i in range(cfg.n_layers))
 
 
+def _olmo_hybrid(cfg: LMConfig):
+    attention = Attention(cfg.n_heads, cfg.kv_heads, cfg.head_size, qk_norm="projection", norm="",
+                          out_norm="attn_out_norm")
+    delta = GatedDelta(cfg.kda_heads, cfg.kda_head_dim, cfg.kda_value_dim, cfg.conv_kernel, cfg.chunk,
+                       out_norm="attn_out_norm")
+    dense = Dense(cfg.expert_width, norm="", out_norm="ffn_out_norm")
+    return tuple(Layer(cfg.hidden, cfg.norm_eps, attention if i in cfg.gqa_layers else delta, dense)
+                 for i in range(cfg.n_layers))
+
+
 #: kind -> (its layers, whether every pass of the stack ends in an exit gate: a linear with a bias)
 _PRESETS = {"olmoe": (_olmoe, False), "zaya": (_zaya, False), "ouro": (_ouro, True), "laguna": (_laguna, False),
             "nemotron_h": (_nemotron_h, False), "joyai": (_joyai, False), "sdar": (_sdar, False),
-            "solar_open2": (_solar_open2, False)}
+            "solar_open2": (_solar_open2, False), "olmo_hybrid": (_olmo_hybrid, False)}
 BLOCKS = tuple(_PRESETS)
 
 
@@ -490,6 +548,16 @@ def _kda_own(m: KDA, d: int):
             ("wo", (inner, d), NORMAL))
 
 
+def _gated_delta_own(m: GatedDelta, d: int):
+    keys, values = m.heads * m.key_dim, m.heads * m.value_dim
+    return (("wq", (d, keys), NORMAL), ("wk", (d, keys), NORMAL), ("wv", (d, values), NORMAL),
+            ("conv_q", (m.conv_kernel, keys), NORMAL), ("conv_k", (m.conv_kernel, keys), NORMAL),
+            ("conv_v", (m.conv_kernel, values), NORMAL),
+            ("Wa", (d, m.heads), NORMAL), ("A_log", (m.heads,), A_LOG), ("dt_bias", (m.heads,), DT_BIAS),
+            ("Wb", (d, m.heads), NORMAL), ("wg", (d, values), NORMAL), ("o_norm", (m.value_dim,), ONES),
+            ("wo", (values, d), NORMAL)) + (((m.out_norm, (d,), ONES),) if m.out_norm else ())
+
+
 def _dense_own(f: Dense, d: int):
     return _matrices("w", (), d, f.width, True) + (((f.out_norm, (d,), ONES),) if f.out_norm else ())
 
@@ -508,19 +576,20 @@ def _experts_own(f: Experts, d: int):
 
 
 _OWN_LEAVES = {Attention: _attention_own, LatentAttention: _latent_own, CCA: _cca_own, Mamba2: _mamba2_own,
-               KDA: _kda_own, Dense: _dense_own,
+               KDA: _kda_own, GatedDelta: _gated_delta_own, Dense: _dense_own,
                Experts: _experts_own}
 
 
 def leaves(layer: Layer):
     """One layer's leaves ``(name, shape, init)`` in the tree's order: the mixer's, then the feed-forward's; of each
-    its norm, under ``scaled`` joins the residual scaling, then its own."""
+    its norm (where it has one before it), under ``scaled`` joins the residual scaling, then its own."""
     d, out = layer.hidden, ()
     for sub, part in (("attn", layer.mixer), ("ffn", layer.ffn)):
         if part is not None:
             scaling = tuple((f"{sub}_{name}", (d,), init) for name, init in (
                 ("res_scale", ONES), ("res_bias", ZEROS), ("out_scale", ONES), ("out_bias", ZEROS)))
-            out += ((part.norm, (d,), ONES),) + (scaling if layer.scaled else ()) + _OWN_LEAVES[type(part)](part, d)
+            out += ((((part.norm, (d,), ONES),) if part.norm else ()) + (scaling if layer.scaled else ())
+                    + _OWN_LEAVES[type(part)](part, d))
     return out
 
 

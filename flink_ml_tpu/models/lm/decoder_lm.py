@@ -10,10 +10,11 @@ keys than in their values (``_latent_attend``); ZAYA's compressed convolutional
 attention (``_cca``); a Mamba-2 scan behind its convolution (``_mamba2``:
 ``parallel/causal_conv.py``, ``parallel/ssd.py``); the gated delta rule with a
 decay a key channel behind the same convolution (``_kda``:
-``parallel/kda.py``) - and a feed-forward - one
+``parallel/kda.py``), or with ONE decay a head on heads wider in their values
+than in their keys (``_gated_delta``: the same kernels' one-decay form) - and a feed-forward - one
 dense SwiGLU or a dropless mixture of experts, ``parallel/moe.py``
 (``_feed_forward``) - either of which may be absent, each joined to the
-residual stream (``_layer``). ``blockKind`` names one of eight presets over that
+residual stream (``_layer``). ``blockKind`` names one of nine presets over that
 description, each a published stack with its plain reference beside it
 (``config.py`` has the table and every leaf): ``olmoe`` (``reference.py``),
 ``zaya`` (ZAYA1-8B, ``reference_zaya.py``), ``ouro`` (Ouro-2.6B's looped LM,
@@ -52,7 +53,15 @@ correction strength in (0, 2) and a gated norm on the output; the layers
 an element-wise sigmoid gate; every layer has sigmoid-gated experts beside a
 shared one; ``kdaNumHeads``, ``numHeads`` and ``numKvHeads`` may be ONE chip's
 share of each layer's heads: ``wo``'s output is then the held heads' part of
-the sum, and nothing stands in for the absent chips). The fit loop, the head, the loss's chunking, the
+the sum, and nothing stands in for the absent chips) and ``olmo_hybrid``
+(Olmo-Hybrid-7B, ``reference_olmo_hybrid.py``: the layers ``gqaLayers`` does not
+name run the gated delta rule with ONE log-decay a head on ``kdaNumHeads``
+heads of ``kdaHeadSize`` key and ``kdaValueHeadSize`` value channels under a
+gated norm, those it names attend without a position encoding under a QK-norm
+over the whole projection; every layer has a dense SwiGLU; NO norm stands
+before a sublayer: each one's output is normed before it joins the stream; the
+head counts may be a chip's share, as ``solar_open2``'s: the held heads' part
+of ``wo``'s sum is then normed as it is). The fit loop, the head, the loss's chunking, the
 clip and the AdamW program are one.
 
 Any expert kind may tie the head to the embedding (``tieEmbeddings``: one leaf)
@@ -131,7 +140,7 @@ from flink_ml_tpu.api.types import DataTypes
 from flink_ml_tpu.metrics import MLMetrics, metrics
 from flink_ml_tpu.models.lm.config import (
     A_LOG, A_RANGE, BLOCKS, CCA, DT_BIAS, DT_FLOOR, DT_RANGE, KDA, MIXERS, NOISE_EPS, NORMAL, ONES, SMALL, SMALL_SCALE, Attention,
-    Dense, Experts, LatentAttention, Layer, LMConfig, Mamba2, exit_gate, layers, mtp_layer, num_params, param_shapes,
+    Dense, Experts, GatedDelta, LatentAttention, Layer, LMConfig, Mamba2, exit_gate, layers, mtp_layer, num_params, param_shapes,
 )
 from flink_ml_tpu.params.param import (
     BoolParam,
@@ -205,7 +214,7 @@ class _LMParams(
         ParamValidators.gt(0),
     )
     EXPERT_WIDTH = IntParam(
-        "expertWidth", "Hidden width of one SwiGLU expert ('ouro': of the block's one dense SwiGLU).", 64,
+        "expertWidth", "Hidden width of one SwiGLU expert ('ouro', 'olmo_hybrid': of the block's one dense SwiGLU).", 64,
         ParamValidators.gt(0),
     )
     VOCAB_SIZE = IntParam(
@@ -232,7 +241,10 @@ class _LMParams(
         "'sdar' (grouped-query attention with a QK-norm a head, experts whose softmax gates are renormalised over the "
         "chosen, trained by block diffusion over the doubled sequence) or "
         "'solar_open2' (the gated delta rule with a decay a key channel in three layers of four, gated attention "
-        "without a position encoding in the layers gqaLayers names, sigmoid-gated experts beside a shared one).",
+        "without a position encoding in the layers gqaLayers names, sigmoid-gated experts beside a shared one) or "
+        "'olmo_hybrid' (the gated delta rule with one decay a head on heads wider in their values than in their "
+        "keys, attention without a position encoding under a QK-norm in the layers gqaLayers names, a dense SwiGLU "
+        "of expertWidth, each sublayer's output normed before it joins the stream).",
         "olmoe", ParamValidators.in_array(list(BLOCKS)),
     )
     TIE_EMBEDDINGS = BoolParam("tieEmbeddings", "The head is the embedding table transposed.", False)
@@ -248,11 +260,11 @@ class _LMParams(
     FIRST_EXPERT_HELD = IntParam("firstExpertHeld", "First expert of the held range.", 0,
                                  ParamValidators.gt_eq(0))
     NUM_KV_HEADS = IntParam(
-        "numKvHeads", "Key/value heads ('zaya', 'laguna', 'nemotron_h', 'sdar', 'solar_open2'; the query heads divide evenly over them). "
+        "numKvHeads", "Key/value heads ('zaya', 'laguna', 'nemotron_h', 'sdar', 'solar_open2', 'olmo_hybrid'; the query heads divide evenly over them). "
         "0: numHeads.", 0,
         ParamValidators.gt_eq(0),
     )
-    HEAD_SIZE = IntParam("headSize", "Channels per head ('zaya', 'laguna', 'nemotron_h', 'sdar', 'solar_open2'). 0: hiddenSize / numHeads.", 0,
+    HEAD_SIZE = IntParam("headSize", "Channels per head ('zaya', 'laguna', 'nemotron_h', 'sdar', 'solar_open2', 'olmo_hybrid'). 0: hiddenSize / numHeads.", 0,
                          ParamValidators.gt_eq(0))
     ROPE_FRACTION = FloatParam(
         "ropeFraction", "Share of each head's channels the rotary embedding turns ('zaya'; 'laguna': in "
@@ -301,17 +313,20 @@ class _LMParams(
     SSM_STATE_SIZE = IntParam("ssmStateSize", "Width of a scan head's state ('nemotron_h').", 0,
                               ParamValidators.gt_eq(0))
     SSM_CONV_KERNEL = IntParam("ssmConvKernel", "Taps of the causal depthwise convolution before the scan "
-                               "('nemotron_h') or the delta rule ('solar_open2').", 4, ParamValidators.gt(0))
+                               "('nemotron_h') or the delta rule ('solar_open2', 'olmo_hybrid').", 4, ParamValidators.gt(0))
     SSM_CHUNK_SIZE = IntParam("ssmChunkSize", "Positions a chunk of the scan ('nemotron_h') or of the delta rule "
-                              "('solar_open2': a power of two); the sequence length is a multiple.", 128,
+                              "('solar_open2', 'olmo_hybrid': a power of two); the sequence length is a multiple.", 128,
                               ParamValidators.gt(0))
     GQA_LAYERS = IntArrayParam(
-        "gqaLayers", "The layers that attend (grouped queries, no position encoding, a gated output); every other "
-        "layer runs the gated delta rule ('solar_open2').", [])
+        "gqaLayers", "The layers that attend (no position encoding; 'solar_open2': grouped queries, a gated output; "
+        "'olmo_hybrid': a QK-norm); every other layer runs the gated delta rule.", [])
     KDA_NUM_HEADS = IntParam("kdaNumHeads", "Heads of a delta-rule layer held here: all of them, or one chip's share "
-                             "('solar_open2').", 0, ParamValidators.gt_eq(0))
+                             "('solar_open2', 'olmo_hybrid').", 0, ParamValidators.gt_eq(0))
     KDA_HEAD_SIZE = IntParam("kdaHeadSize", "Key and value channels of a delta-rule head, and the rank of its decay "
-                             "and output gates ('solar_open2').", 0, ParamValidators.gt_eq(0))
+                             "and output gates ('solar_open2'); a head's key channels ('olmo_hybrid').", 0,
+                             ParamValidators.gt_eq(0))
+    KDA_VALUE_HEAD_SIZE = IntParam("kdaValueHeadSize", "Value channels of a delta-rule head ('olmo_hybrid').", 0,
+                                   ParamValidators.gt_eq(0))
     Q_LORA_RANK = IntParam("qLoraRank", "Width of the latent the queries are rebuilt from ('joyai').", 0,
                            ParamValidators.gt_eq(0))
     KV_LORA_RANK = IntParam("kvLoraRank", "Width of the latent a token's keys and values are rebuilt from ('joyai').",
@@ -350,8 +365,8 @@ class _LMParams(
         ("olmoe", "zaya", "laguna", "nemotron_h", "joyai", "sdar", "solar_open2"): (("n_experts", NUM_EXPERTS),
                                                                                     ("top_k", EXPERTS_PER_TOKEN)),
         ("olmoe",): (("aux_coef", AUX_LOSS_COEF),),
-        ("zaya", "laguna", "nemotron_h", "sdar", "solar_open2"): (("n_kv_heads", NUM_KV_HEADS),
-                                                                  ("head_size", HEAD_SIZE)),
+        ("zaya", "laguna", "nemotron_h", "sdar", "solar_open2", "olmo_hybrid"): (("n_kv_heads", NUM_KV_HEADS),
+                                                                                 ("head_size", HEAD_SIZE)),
         ("zaya", "laguna"): (("rope_fraction", ROPE_FRACTION),),
         ("zaya",): (("router_width", ROUTER_WIDTH),),
         ("ouro",): (("loops", NUM_LOOPS), ("exit_beta", EXIT_ENTROPY_COEF)),
@@ -366,15 +381,19 @@ class _LMParams(
         ("sdar",): (("block_length", BLOCK_LENGTH), ("mask_id", MASK_TOKEN_ID)),
         ("nemotron_h",): (("layer_kinds", LAYER_PATTERN), ("ssm_heads", SSM_NUM_HEADS), ("ssm_head_dim", SSM_HEAD_SIZE),
                           ("ssm_groups", SSM_NUM_GROUPS), ("ssm_state", SSM_STATE_SIZE)),
-        ("nemotron_h", "solar_open2"): (("conv_kernel", SSM_CONV_KERNEL), ("chunk", SSM_CHUNK_SIZE)),
-        ("solar_open2",): (("gqa_layers", GQA_LAYERS), ("kda_heads", KDA_NUM_HEADS), ("kda_head_dim", KDA_HEAD_SIZE)),
+        ("nemotron_h", "solar_open2", "olmo_hybrid"): (("conv_kernel", SSM_CONV_KERNEL), ("chunk", SSM_CHUNK_SIZE)),
+        ("solar_open2", "olmo_hybrid"): (("gqa_layers", GQA_LAYERS), ("kda_heads", KDA_NUM_HEADS),
+                                         ("kda_head_dim", KDA_HEAD_SIZE)),
+        ("olmo_hybrid",): (("kda_value_dim", KDA_VALUE_HEAD_SIZE),),
     }
     #: The params refused where they are given a value under a kind they do not belong to.
     _REFUSED = (
-        ((NUM_KV_HEADS, HEAD_SIZE), ("zaya", "laguna", "nemotron_h", "sdar", "solar_open2"),
-         "numKvHeads and headSize belong to blockKind 'zaya', 'laguna', 'nemotron_h', 'sdar' or 'solar_open2'"),
-        ((GQA_LAYERS, KDA_NUM_HEADS, KDA_HEAD_SIZE), ("solar_open2",),
-         "gqaLayers, kdaNumHeads and kdaHeadSize belong to blockKind 'solar_open2'"),
+        ((NUM_KV_HEADS, HEAD_SIZE), ("zaya", "laguna", "nemotron_h", "sdar", "solar_open2", "olmo_hybrid"),
+         "numKvHeads and headSize belong to blockKind 'zaya', 'laguna', 'nemotron_h', 'sdar', 'solar_open2' or "
+         "'olmo_hybrid'"),
+        ((GQA_LAYERS, KDA_NUM_HEADS, KDA_HEAD_SIZE), ("solar_open2", "olmo_hybrid"),
+         "gqaLayers, kdaNumHeads and kdaHeadSize belong to blockKind 'solar_open2' or 'olmo_hybrid'"),
+        ((KDA_VALUE_HEAD_SIZE,), ("olmo_hybrid",), "kdaValueHeadSize belongs to blockKind 'olmo_hybrid'"),
         ((ROPE_FRACTION,), ("zaya", "laguna", "nemotron_h"),
          "ropeFraction belongs to blockKind 'zaya', 'laguna' or 'nemotron_h'"),
         ((BLOCK_LENGTH, MASK_TOKEN_ID), ("sdar",), "blockLength and maskTokenId belong to blockKind 'sdar'"),
@@ -389,8 +408,8 @@ class _LMParams(
         ((NUM_LOOPS,), ("ouro",), "numLoops belongs to blockKind 'ouro'"),
         ((TIE_EMBEDDINGS, EXPERTS_HELD, FIRST_EXPERT_HELD),
          ("olmoe", "zaya", "laguna", "nemotron_h", "joyai", "sdar", "solar_open2"),
-         "tieEmbeddings, expertsHeld and firstExpertHeld do not belong to blockKind 'ouro'"),
-        ((TIE_EMBEDDINGS,), ("olmoe", "zaya", "ouro", "laguna", "joyai", "sdar", "solar_open2"),
+         "tieEmbeddings, expertsHeld and firstExpertHeld do not belong to blockKind 'ouro' or 'olmo_hybrid'"),
+        ((TIE_EMBEDDINGS,), ("olmoe", "zaya", "ouro", "laguna", "joyai", "sdar", "solar_open2", "olmo_hybrid"),
          "tieEmbeddings does not belong to blockKind 'nemotron_h'"),
     )
 
@@ -457,6 +476,11 @@ def _check_mixer(m) -> None:
     if isinstance(m, KDA) and not (m.heads > 0 and m.head_dim > 0 and m.chunk & (m.chunk - 1) == 0):
         raise ValueError(f"a delta-rule layer needs kdaNumHeads ({m.heads}), kdaHeadSize ({m.head_dim}) and a chunk "
                          f"(ssmChunkSize) that is a power of two ({m.chunk})")
+    if isinstance(m, GatedDelta) and not (m.heads > 0 and m.key_dim > 0 and m.value_dim > 0
+                                          and m.chunk & (m.chunk - 1) == 0):
+        raise ValueError(f"a delta-rule layer needs kdaNumHeads ({m.heads}), kdaHeadSize ({m.key_dim}), "
+                         f"kdaValueHeadSize ({m.value_dim}) and a chunk (ssmChunkSize) that is a power of two "
+                         f"({m.chunk})")
     rotation = getattr(m, "rotation", None)
     if rotation is not None and rotation.interleaved != isinstance(m, LatentAttention):
         raise ValueError("latent attention turns interleaved pairs of its rotary channels, the other mixers the two "
@@ -558,6 +582,11 @@ def _rms_norm(x, w, eps):
     with jax.named_scope("norm"):
         x = x.astype(jnp.float32)
         return w * (x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps))
+
+
+def _read(x, layer, part, eps):
+    """What a sublayer reads of the stream: behind the norm its record names, or as it is."""
+    return _rms_norm(x, layer[part.norm], eps) if part.norm else x
 
 
 def _rope_tables(t: int, d: int, theta: float):
@@ -817,6 +846,48 @@ def _kda(x, layer, m: KDA, eps: float, cd, interpret: bool):
     return _proj(y, layer["wo"], cd)
 
 
+def _gated_delta(x, layer, m: GatedDelta, eps: float, cd, interpret: bool):
+    """A delta-rule layer with ONE decay a head (``reference_olmo_hybrid.py``
+    has the equations): q, k and v through one projection, a causal depthwise
+    convolution and SiLU; q and k at unit length a head (q over
+    ``sqrt(key_dim)`` more); a log-decay a head, ``-exp(A_log) softplus(.)``,
+    and the correction's strength ``2 sigmoid(.)``; the gated delta rule in
+    chunks on a state ``[key_dim, value_dim]`` a head; each head's output
+    RMS-normed and gated by ``silu`` of a full projection; one projection back
+    (of the heads held here: a share of the layer's sum where they are a share
+    of its heads), normed as it is where the record names a norm. The decays,
+    the strengths, the unit vectors and the rule's state are float32 whatever
+    ``cd`` is."""
+    b, t, _ = x.shape
+    h, dk, dv = m.heads, m.key_dim, m.value_dim
+    keys, values = h * dk, h * dv
+    a = _read(x, layer, m, eps)
+    with jax.named_scope("proj"):
+        u = _matmul(a, jnp.concatenate([layer["wq"], layer["wk"], layer["wv"]], axis=1), cd)
+        gate = _matmul(a, layer["wg"], cd)  # a matmul of its own: behind q, k and v in ONE projection the gate has to be
+        # cut out of the wider array and the wider gradient concatenated, which measured no faster (PERF.md, PR 54)
+    with jax.named_scope("conv"):
+        # the convolution's kernels walk q, k and v where they lie in u, as ONE part: their edges (15 heads of 96:
+        # 1,440) tile no lane where the whole (5,760) does; what cuts them apart is fused into the unit vectors' pass
+        taps = jnp.concatenate([layer["conv_q"], layer["conv_k"], layer["conv_v"]], axis=1)
+        _, (qkv,), _ = causal_conv(u, taps, jnp.zeros((2 * keys + values,), jnp.float32), (2 * keys + values,))
+    with jax.named_scope("kgate"):
+        both = _matmul(a, jnp.concatenate([layer["Wa"], layer["Wb"]], axis=1), cd)  # [B, T, 2 H]
+        g = -jnp.exp(layer["A_log"]) * jax.nn.softplus(both[..., :h] + layer["dt_bias"])  # one log-decay a head
+        beta = 2.0 * jax.nn.sigmoid(both[..., h:])  # [B, T, H] in (0, 2)
+    with jax.named_scope("kda"):
+        q, k = qkv[..., :keys].reshape(b, t, h, dk), qkv[..., keys: 2 * keys].reshape(b, t, h, dk)
+        v = qkv[..., 2 * keys:].reshape(b, t, h, dv)
+        q = q * (jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + UNIT_EPS) * dk ** -0.5)
+        k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + UNIT_EPS)
+        o = kda_scan(q, k, v, g, beta, m.chunk, cd)
+    with jax.named_scope("gnorm"):
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps) * layer["o_norm"]
+        y = o.reshape(b, t, values) * jax.nn.silu(gate)
+    y = _proj(y, layer["wo"], cd)
+    return _rms_norm(y, layer[m.out_norm], eps) if m.out_norm else y
+
+
 def _attend(x, layer, m: Attention, eps: float, cd, interpret: bool):
     """Causal attention on (grouped) queries through the fused fold, with what
     the record asks for around it: a QK-norm on the projections (over all
@@ -825,7 +896,7 @@ def _attend(x, layer, m: Attention, eps: float, cd, interpret: bool):
     the projection back. Under ``diffusion_block`` the positions are a sequence and its noised
     copy, ``[x ; x~]``: each half turns at positions ``0 .. T - 1`` and the
     mask is block diffusion's."""
-    a = _rms_norm(x, layer[m.norm], eps)
+    a = _read(x, layer, m, eps)
     t = x.shape[1] // 2 if m.diffusion_block else x.shape[1]
 
     def head(w: str, n: int, norm: str):
@@ -883,7 +954,8 @@ def _latent_attend(x, layer, m: LatentAttention, eps: float, cd, interpret: bool
     return _proj(_merged(_fold(q, k, v, cd, interpret)), layer["wo"], cd)
 
 
-_MIX = {Attention: _attend, LatentAttention: _latent_attend, CCA: _cca, Mamba2: _mamba2, KDA: _kda}
+_MIX = {Attention: _attend, LatentAttention: _latent_attend, CCA: _cca, Mamba2: _mamba2, KDA: _kda,
+        GatedDelta: _gated_delta}
 
 
 def _feed_forward(x, carry, layer, f, eps: float, cd):
@@ -892,7 +964,7 @@ def _feed_forward(x, carry, layer, f, eps: float, cd):
     where there is one. An MLP router's hidden state is the ``carry`` handed
     to the next layer's router."""
     b, t, d = x.shape
-    u = _rms_norm(x, layer[f.norm], eps)
+    u = _read(x, layer, f, eps)
     if isinstance(f, Dense):
         with jax.named_scope("ffn"):
             y = dense_swiglu(u, layer["w_gate"], layer["w_up"], layer["w_down"], cd)
@@ -1487,7 +1559,14 @@ class DecoderLM(Estimator, _LMParams):
     channels, chunks of ``ssmChunkSize`` positions) and gated attention
     without a position encoding in those it names; the sequence length is a
     multiple of the chunk too. The head counts may be one chip's share of each
-    layer's heads: the layer's output is then the held heads' part of it."""
+    layer's heads: the layer's output is then the held heads' part of it.
+
+    ``blockKind`` ``olmo_hybrid`` runs the gated delta rule with ONE decay a
+    head in the layers ``gqaLayers`` does not name (``kdaNumHeads`` heads of
+    ``kdaHeadSize`` key and ``kdaValueHeadSize`` value channels) and attention
+    without a position encoding under a QK-norm in those it names, a dense
+    SwiGLU of ``expertWidth`` in every layer, each sublayer's output normed
+    before it joins the stream; the head counts may be a chip's share too."""
 
     def fit(self, *inputs) -> DecoderLMModel:
         (df,) = inputs
@@ -1531,11 +1610,13 @@ class DecoderLM(Estimator, _LMParams):
             folds = [(m.heads, getattr(m, "window", 0), getattr(m, "diffusion_block", 0)) for m in mixers
                      if isinstance(m, (Attention, LatentAttention, CCA))]
             scans = [m for m in mixers if isinstance(m, Mamba2)]
-            deltas = [m for m in mixers if isinstance(m, KDA)]
-            # a step's chunks of the delta rule (chunks x heads x sequences, every such layer), and those of them its
-            # kernel pair walks: its grid's cells
+            deltas = [m for m in mixers if isinstance(m, (KDA, GatedDelta))]
+            # a step's chunks of the delta rule (chunks x heads x sequences, every such layer), those of them its
+            # kernel pair walks (its grid's cells x the heads of a cell), and those that took the one-decay form
             kda_chunks = sum(batch * m.heads * (t // m.chunk) for m in deltas)
             kda_chunks_kernel = sum(kda_kernel_chunks(batch, t, m.heads, m.chunk) for m in deltas)
+            kda_chunks_scalar = sum(kda_kernel_chunks(batch, t, m.heads, m.chunk) for m in deltas
+                                    if isinstance(m, GatedDelta))
             # a step's chunks of the scan (chunks x heads x sequences, every Mamba-2 layer), and those of them the
             # scan's kernel pair walks: its grid's cells x the heads of a cell
             scan_chunks = sum(batch * m.heads * (t // m.chunk) for m in scans)
@@ -1551,7 +1632,7 @@ class DecoderLM(Estimator, _LMParams):
             # positions x channels of the Mamba-2 layers' convolutions in one step's forward, and those of them the
             # convolution's kernels cover: their calls' grids in the step as traced
             conv_positions = (sum(batch * t * (m.heads * m.head_dim + 2 * m.groups * m.state) for m in scans)
-                              + sum(batch * t * 3 * m.heads * m.head_dim for m in deltas))
+                              + sum(batch * t * m.heads * m.conv_channels for m in deltas))
             traced = _traced_counts(step, params, opt_state, window, cfg,
                                     *([(noise_key, jax.ShapeDtypeStruct((), jnp.int32))] if cfg.block_length else []))
             conv_positions_kernel = traced["conv_positions_kernel"]
@@ -1587,8 +1668,9 @@ class DecoderLM(Estimator, _LMParams):
                 phase.set_metadata(layers_kda=len(deltas), layers_attn=sum(isinstance(m, Attention) for m in mixers),
                                    layers_moe=sum(isinstance(spec.ffn, Experts) for spec in specs),
                                    kda_chunks=kda_chunks, kda_chunks_kernel=kda_chunks_kernel,
+                                   kda_chunks_scalar=kda_chunks_scalar,
                                    conv_positions=conv_positions, conv_positions_kernel=conv_positions_kernel,
-                                   kda_state_bytes=max(4 * batch * m.heads * (t // m.chunk) * m.head_dim ** 2
+                                   kda_state_bytes=max(4 * batch * m.heads * (t // m.chunk) * m.state_size
                                                        for m in deltas))
 
         losses, leaf_norms, stats = [], [], []  # device values, fetched once after the loop
@@ -1679,6 +1761,7 @@ class DecoderLM(Estimator, _LMParams):
         if deltas:
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_KDA_CHUNKS, steps * kda_chunks)
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_KDA_KERNEL_CHUNKS, steps * kda_chunks_kernel)
+            metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_KDA_SCALAR_CHUNKS, steps * kda_chunks_scalar)
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_KDA_LAYERS, steps * len(deltas))
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_CONV_POSITIONS, steps * conv_positions)
             metrics.counter(MLMetrics.TRAIN_GROUP, MLMetrics.TRAIN_LM_CONV_KERNEL_POSITIONS,

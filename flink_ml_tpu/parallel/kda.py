@@ -1,7 +1,9 @@
-"""The gated delta rule with a decay of its own on every key channel (Kimi
+"""The gated delta rule - with a decay of its own on every key channel (Kimi
 Delta Attention, arXiv:2510.26692 section 3; ``models/lm``'s ``solar_open2``
-block kind) in its chunked form as a pair of Pallas kernels, and the plain
-step-by-step recurrence it has to agree with. ``parallel/ssd.py`` is its
+block kind) or with ONE decay a head (Gated DeltaNet, arXiv:2412.06464 section
+3; the ``olmo_hybrid`` block kind: "One decay a head" below) - in its chunked
+form as a pair of Pallas kernels, and the plain step-by-step recurrence it has
+to agree with. ``parallel/ssd.py`` is its
 sibling: there the decay is one scalar a head and the state takes a plain sum;
 here the decay is a vector over the key channels and the state takes a
 delta-rule correction, so inside a chunk there is a triangular system to solve
@@ -70,14 +72,33 @@ the rest of ``dk``, ``dv``) and the cumulative sum inside a chunk (one matmul
 with a triangle of ones, float32 at the highest precision; its transpose gives
 ``dg``).
 
+**One decay a head** (``g [B, T, H]``: the same recurrence with ``g`` alike on
+a head's key channels). The scalar leaves the contraction: ``A = (kb k^T) *
+Delta`` and ``P = (q k^T) * Delta`` with ONE ``[chunk, chunk]`` factor
+``Delta_ij = exp(G_i - G_j)``, the ``exp`` of a masked difference that is never
+positive: no sub-chunks, no references, no ``[chunk, D]`` exponentials, one
+``[2 chunk, D_k] x [D_k, chunk]`` matmul a chunk for both products. The solve,
+the state's terms and both walks are the per-channel form's, line for line
+(``_Cell.chunk`` and the kernels' bodies are shared; ``_Cell.pairs`` and
+``pairs_grads`` branch); ``dG`` is the per-channel expression summed over the
+lanes. Heads need not be square nor tile a lane (Olmo-Hybrid's 96 key and 192
+value channels; 15 heads x 96 = 1,440 flat channels tile none either), so the
+arrays are HEAD-MAJOR, ``[B, H, T, D]``: a head's channels are a block's whole
+last dimension, which Mosaic takes at any width. The chunk sums reach a cell
+as ``[1, chunk]`` rows a head (``[B, H, T / chunk, 1, chunk]``); the column
+the state's terms want is that row turned through the diagonal of a ``[chunk,
+chunk]`` tile (``_Cell.turned``). A cell takes ``_HEADS_A_CELL`` heads: a cell
+is latency, not work, and the heads' chains interleave.
+
 Precision, the configuration's: the log-decays, their sums, every ``exp``, the
 solve, the carried state and ``dS`` are float32 whatever the compute type; the
 other matmuls take their inputs in the compute type (``bfloat16``: the MXU's
 path; float32 at the highest precision otherwise) and accumulate in float32.
 
 Compiled by Mosaic on a TPU backend, interpreted elsewhere (the CPU mesh of
-the tests), decided here from the backend. On the TPU a head's channels have
-to tile the 128 lanes and a chunk the sublanes; a shape that does not is
+the tests), decided here from the backend. On the TPU a chunk has to tile
+the sublanes and, under a decay a key channel (token-major arrays, a head cut
+out of the lanes), a head's channels the 128 lanes; a shape that does not is
 refused, there is no other path.
 """
 from __future__ import annotations
@@ -98,6 +119,11 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 _LANES = 128
 #: Positions of a sub-chunk: the span over which a decay's exponent may be positive (half of it, either way).
 _SUB = 16
+#: Heads a cell of the one-decay form: a cell is latency, not work (a chain of small matmuls and a solve), and
+#: heads are independent, so a cell's heads fill each other's waits: 15 heads of 96 x 192 at T 8,192, chunk 64, forward
+#: and backward 14.16 ms a layer at one head a cell, 13.39 at three, 13.19 at five (chip runs, PERF.md PR 54); fifteen
+#: pass the kernels' 16 MB of scoped VMEM.
+_HEADS_A_CELL = 5
 FWD_NAME, BWD_NAME = "kda_scan_fwd", "kda_scan_bwd"
 
 
@@ -128,11 +154,35 @@ def _interpreted() -> bool:
 
 
 class _Cell:
-    """What both kernels compute alike on one (sequence, head, chunk) cell."""
+    """What both kernels compute alike on one (sequence, head, chunk) cell.
+    ``heads`` 0: a decay a key channel, a cell one head of token-major arrays
+    (``[B, T, H D]``); ``heads`` > 0: ONE decay a head, a cell that many heads
+    of head-major arrays (``[B, H, T, D]``), the decays a row a head."""
 
-    def __init__(self, q: int, cd):
-        self.q, self.sub, self.cd = q, min(_SUB, q), jnp.dtype(cd)
+    def __init__(self, q: int, cd, heads: int = 0):
+        self.q, self.sub, self.cd, self.heads = q, min(_SUB, q), jnp.dtype(cd), heads
         self.precision = _HIGHEST if self.cd == jnp.float32 else None
+
+    def tok(self, j: int):
+        """Where head ``j`` of the cell lies in a token array's block."""
+        return (0, j) if self.heads else (0,)
+
+    def turned(self, x):
+        """A row ``[1, q]`` as a column ``[q, 1]`` or the other way, through the diagonal of a ``[q, q]`` tile."""
+        diagonal = (jax.lax.broadcasted_iota(jnp.int32, (self.q, self.q), 0)
+                    == jax.lax.broadcasted_iota(jnp.int32, (self.q, self.q), 1))
+        return jnp.sum(jnp.where(diagonal, x, 0.0), axis=int(x.shape[0] == 1), keepdims=True)
+
+    def factor(self, g):
+        """One decay a head: ``exp(G_i - G_j)`` over the pairs ``j <= i`` (never past 1) and 0 elsewhere, ``[q, q]``,
+        from the column ``g [q, 1]``."""
+        _, upto = self.ordered()
+        return jnp.exp(jnp.where(upto, g - self.turned(g), -jnp.inf))
+
+    def decays(self, g_ref, j: int):
+        """Head ``j``'s cumulative log-decays as ``chunk`` takes them: ``[q, D_k]``, or of one decay a head the
+        column ``[q, 1]``."""
+        return self.turned(g_ref[0, j, 0]) if self.heads else g_ref[0]
 
     def dot(self, lhs, rhs, contract=((1,), (0,))):
         """``lhs @ rhs`` (or the contraction named) on compute-type inputs into float32."""
@@ -169,13 +219,35 @@ class _Cell:
 
     def pairs(self, q, k, kb, g):
         """``(A, P)``: the masked pair matrices ``[i, j]`` of ``kb`` and of ``q`` on ``k`` under the decays."""
+        before, upto = self.ordered()
+        if self.heads:  # one decay a head: one [q, q] factor on both products
+            both = self.dot_nt(jnp.concatenate([kb, q], axis=0), k)  # [2 q, q]
+            decay = self.factor(g)
+            return jnp.where(before, both[: self.q] * decay, 0.0), both[self.q:] * decay
         a, p = [], []
         for rows, rise, fall in self.sub_chunks(g):
             both = self.dot_nt(jnp.concatenate([kb[rows] * rise, q[rows] * rise], axis=0), k * fall)  # [2 sub, q]
             a.append(both[: self.sub])
             p.append(both[self.sub:])
-        before, upto = self.ordered()
         return jnp.where(before, jnp.concatenate(a, axis=0), 0.0), jnp.where(upto, jnp.concatenate(p, axis=0), 0.0)
+
+    def pairs_grads(self, q, k, kb, g, da, dp):
+        """What ``(dA, dP)`` (masked) ask of the pair matrices' own inputs: ``(dq, d kb, dk)``, made as ``pairs``
+        made them."""
+        if self.heads:
+            decay = self.factor(g)
+            asked = jnp.concatenate([da * decay, dp * decay], axis=0)  # [2 q, q]
+            dleft = self.dot(asked, k)
+            return dleft[self.q:], dleft[: self.q], self.dot_tn(asked, jnp.concatenate([kb, q], axis=0))
+        dq, dkb, dk = [], [], jnp.zeros(k.shape, jnp.float32)
+        for rows, rise, fall in self.sub_chunks(g):
+            asked = jnp.concatenate([da[rows], dp[rows]], axis=0)  # [2 sub, q]
+            left = jnp.concatenate([kb[rows] * rise, q[rows] * rise], axis=0)
+            dleft = self.dot(asked, k * fall)
+            dkb.append(dleft[: self.sub] * rise)
+            dq.append(dleft[self.sub:] * rise)
+            dk = dk + self.dot_tn(asked, left) * fall
+        return jnp.concatenate(dq, axis=0), jnp.concatenate(dkb, axis=0), dk
 
     def inverse(self, a):
         """``(I + a)^-1`` of a strictly lower-triangular ``a [q, q]``, block size by block size."""
@@ -208,94 +280,106 @@ def _fwd_kernel(cell: _Cell, save: bool, q_ref, k_ref, kb_ref, vb_ref, g_ref, o_
     def _():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    state = state_ref[0, 0]  # [D_v, D_k]: what this chunk starts from, transposed
-    if save:
-        starts_ref[0][0, 0, 0] = state
-    q, k = q_ref[0], k_ref[0]
-    p, _, w, grow, to_end, through = cell.chunk(q, k, kb_ref[0], vb_ref[0], g_ref[0], state)
-    o_ref[0] = cell.dot_nt(q * grow, state) + cell.dot(p, w)
-    state_ref[0, 0] = through * state + cell.dot_tn(w, k * to_end)
+    for j in range(max(cell.heads, 1)):  # a cell's heads are independent: their chains interleave
+        tok = cell.tok(j)
+        state = state_ref[0, j]  # [D_v, D_k]: what this chunk starts from, transposed
+        if save:
+            starts_ref[0][0, 0, j] = state
+        q, k = q_ref[tok], k_ref[tok]
+        p, _, w, grow, to_end, through = cell.chunk(q, k, kb_ref[tok], vb_ref[tok], cell.decays(g_ref, j), state)
+        o_ref[tok] = cell.dot_nt(q * grow, state) + cell.dot(p, w)
+        state_ref[0, j] = through * state + cell.dot_tn(w, k * to_end)
 
 
 def _bwd_kernel(cell: _Cell, q_ref, k_ref, kb_ref, vb_ref, g_ref, starts_ref, do_ref,
                 dq_ref, dk_ref, dkb_ref, dvb_ref, dg_ref, dstate_ref):
-    f32, n = jnp.float32, cell.q
+    n = cell.q
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         dstate_ref[...] = jnp.zeros_like(dstate_ref)
 
-    dstate = dstate_ref[0, 0]  # [D_v, D_k]: the gradient of the (transposed) state this chunk ENDS with
-    state = starts_ref[0, 0, 0]  # the state it started from
-    q, k, kb, vb, g, do = q_ref[0], k_ref[0], kb_ref[0], vb_ref[0], g_ref[0], do_ref[0]
-    p, t, w, grow, to_end, through = cell.chunk(q, k, kb, vb, g, state)
-    q_in, kb_in, k_end = q * grow, kb * grow, k * to_end  # what reads the state, and what is written into it
-    # o = q_in S + P W;  S' = through S + k_end^T W
-    dw = cell.dot_tn(p, do) + cell.dot_nt(k_end, dstate)
-    before, upto = cell.ordered()
-    dp = jnp.where(upto, cell.dot_nt(do, w), 0.0)
-    dq_in = cell.dot(do, state)
-    dk_end = cell.dot(w, dstate)
-    # W = T (vb - kb_in S),  T = (I + A)^-1
-    drhs = cell.exact(t, dw, ((0,), (0,)))
-    da = jnp.where(before, -cell.dot_nt(drhs, w), 0.0)
-    dkb_in = -cell.dot(drhs, state)
-    dstate_ref[0, 0] = through * dstate + cell.dot_tn(do, q_in) - cell.dot_tn(drhs, kb_in)
-    # the pair matrices' own inputs, sub-chunk by sub-chunk as they were made
-    dq, dkb, dk = [], [], jnp.zeros(k.shape, f32)
-    for rows, rise, fall in cell.sub_chunks(g):
-        asked = jnp.concatenate([da[rows], dp[rows]], axis=0)  # [2 sub, q]
-        left = jnp.concatenate([kb[rows] * rise, q[rows] * rise], axis=0)
-        dleft = cell.dot(asked, k * fall)
-        dkb.append(dleft[: cell.sub] * rise)
-        dq.append(dleft[cell.sub:] * rise)
-        dk = dk + cell.dot_tn(asked, left) * fall
-    dq_pairs, dkb_pairs = jnp.concatenate(dq, axis=0), jnp.concatenate(dkb, axis=0)
-    # the log-decays: each exponent's own gradient is its factor's times the factor
-    dlast = (jnp.sum(dk_end * k_end, axis=0, keepdims=True)
-             + through * jnp.sum(state * dstate, axis=0, keepdims=True))
-    dg = (kb * dkb_pairs + q * dq_pairs - k * dk + dq_in * q_in + dkb_in * kb_in - dk_end * k_end
-          + jnp.where(jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0) == n - 1, dlast, 0.0))
-    dq_ref[0] = dq_pairs + dq_in * grow
-    dk_ref[0] = dk + dk_end * to_end
-    dkb_ref[0] = dkb_pairs + dkb_in * grow
-    dvb_ref[0] = drhs
-    dg_ref[0] = dg
+    for j in range(max(cell.heads, 1)):
+        tok = cell.tok(j)
+        dstate = dstate_ref[0, j]  # [D_v, D_k]: the gradient of the (transposed) state this chunk ENDS with
+        state = starts_ref[0, 0, j]  # the state it started from
+        q, k, kb, vb, g, do = q_ref[tok], k_ref[tok], kb_ref[tok], vb_ref[tok], cell.decays(g_ref, j), do_ref[tok]
+        p, t, w, grow, to_end, through = cell.chunk(q, k, kb, vb, g, state)
+        q_in, kb_in, k_end = q * grow, kb * grow, k * to_end  # what reads the state, and what is written into it
+        # o = q_in S + P W;  S' = through S + k_end^T W
+        dw = cell.dot_tn(p, do) + cell.dot_nt(k_end, dstate)
+        before, upto = cell.ordered()
+        dp = jnp.where(upto, cell.dot_nt(do, w), 0.0)
+        dq_in = cell.dot(do, state)
+        dk_end = cell.dot(w, dstate)
+        # W = T (vb - kb_in S),  T = (I + A)^-1
+        drhs = cell.exact(t, dw, ((0,), (0,)))
+        da = jnp.where(before, -cell.dot_nt(drhs, w), 0.0)
+        dkb_in = -cell.dot(drhs, state)
+        dstate_ref[0, j] = through * dstate + cell.dot_tn(do, q_in) - cell.dot_tn(drhs, kb_in)
+        dq_pairs, dkb_pairs, dk = cell.pairs_grads(q, k, kb, g, da, dp)
+        # the log-decays: each exponent's own gradient is its factor's times the factor; one decay a head takes the
+        # sum over its channels
+        dlast = (jnp.sum(dk_end * k_end, axis=0, keepdims=True)
+                 + through * jnp.sum(state * dstate, axis=0, keepdims=True))
+        dg = (kb * dkb_pairs + q * dq_pairs - k * dk + dq_in * q_in + dkb_in * kb_in - dk_end * k_end
+              + jnp.where(jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0) == n - 1, dlast, 0.0))
+        dq_ref[tok] = dq_pairs + dq_in * grow
+        dk_ref[tok] = dk + dk_end * to_end
+        dkb_ref[tok] = dkb_pairs + dkb_in * grow
+        dvb_ref[tok] = drhs
+        if cell.heads:
+            dg_ref[0, j, 0] = cell.turned(jnp.sum(dg, axis=1, keepdims=True))
+        else:
+            dg_ref[0] = dg
 
 
-def _specs(shape, heads: int, q: int, walk):
-    """``(the grid, a token array's block spec, the carried state's, the chunk
-    starts')`` for arrays ``[B, T, H D]``, the chunk axis read through ``walk``
-    (the backward's runs last to first)."""
-    batch, t, width = shape
-    d = width // heads
-    tokens = pl.BlockSpec((1, q, d), lambda i, h, z: (i, walk(z), h), memory_space=pltpu.VMEM)
-    carried = pl.BlockSpec((1, 1, d, d), lambda i, h, z: (i, h, 0, 0), memory_space=pltpu.VMEM)
-    starts = pl.BlockSpec((1, 1, 1, d, d), lambda i, h, z: (i, walk(z), h, 0, 0), memory_space=pltpu.VMEM)
-    return (batch, heads, t // q), tokens, carried, starts
+def _specs(dims, q: int, per_cell: int, walk):
+    """``(the grid, a token array's block spec by its width, the decays', the
+    carried state's, the chunk starts')`` for ``dims = (B, T, H, D_k, D_v)``,
+    the chunk axis read through ``walk`` (the backward's runs last to first).
+    ``per_cell`` 0: a decay a key channel on token-major arrays ``[B, T, H D]``;
+    else one decay a head, ``per_cell`` heads a cell of head-major arrays ``[B,
+    H, T, D]``, the decays ``[B, H, T / q, 1, q]``."""
+    batch, t, heads, dk, dv = dims
+    vmem = pltpu.VMEM
+    if per_cell:
+        def tokens(d):
+            return pl.BlockSpec((1, per_cell, q, d), lambda i, h, z: (i, h, walk(z), 0), memory_space=vmem)
+
+        decays = pl.BlockSpec((1, per_cell, 1, 1, q), lambda i, h, z: (i, h, walk(z), 0, 0), memory_space=vmem)
+    else:
+        def tokens(d):
+            return pl.BlockSpec((1, q, d), lambda i, h, z: (i, walk(z), h), memory_space=vmem)
+
+        decays = tokens(dk)
+    n = per_cell or 1
+    carried = pl.BlockSpec((1, n, dv, dk), lambda i, h, z: (i, h, 0, 0), memory_space=vmem)
+    starts = pl.BlockSpec((1, 1, n, dv, dk), lambda i, h, z: (i, walk(z), h, 0, 0), memory_space=vmem)
+    return (batch, heads // n, t // q), tokens, decays, carried, starts
 
 
 _PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _scan(q, k, kb, vb, g, heads, chunk, cd, interpret):
-    return _scan_fwd(q, k, kb, vb, g, heads, chunk, cd, interpret, save=False)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _scan(q, k, kb, vb, g, dims, chunk, per_cell, cd, interpret):
+    return _scan_fwd(q, k, kb, vb, g, dims, chunk, per_cell, cd, interpret, save=False)[0]
 
 
-def _scan_fwd(q, k, kb, vb, g, heads, chunk, cd, interpret, save=True):
-    """``q``, ``k``, ``kb``, ``vb`` and the cumulative log-decays ``g``: ``[B, T, H D]`` float32."""
-    (batch, _, nc), tokens, carried, starts = _specs(q.shape, heads, chunk, lambda z: z)
-    d, f32 = q.shape[2] // heads, jnp.float32
-    out_shape = [jax.ShapeDtypeStruct(q.shape, f32), jax.ShapeDtypeStruct((batch, heads, d, d), f32)]
-    out_specs = [tokens, carried]
+def _scan_fwd(q, k, kb, vb, g, dims, chunk, per_cell, cd, interpret, save=True):
+    """``q``, ``k``, ``kb``, ``vb`` and the cumulative log-decays ``g``, float32, laid out as ``_specs`` says."""
+    grid, tokens, decays, carried, starts = _specs(dims, chunk, per_cell, lambda z: z)
+    (batch, _, heads, dk, dv), f32 = dims, jnp.float32
+    out_shape = [jax.ShapeDtypeStruct(vb.shape, f32), jax.ShapeDtypeStruct((batch, heads, dv, dk), f32)]
+    out_specs = [tokens(dv), carried]
     if save:
-        out_shape.append(jax.ShapeDtypeStruct((batch, nc, heads, d, d), f32))
+        out_shape.append(jax.ShapeDtypeStruct((batch, grid[2], heads, dv, dk), f32))
         out_specs.append(starts)
     o, _, *saved = pl.pallas_call(
-        functools.partial(_fwd_kernel, _Cell(chunk, cd), save),
-        grid=(batch, heads, nc),
-        in_specs=[tokens] * 5,
+        functools.partial(_fwd_kernel, _Cell(chunk, cd, per_cell), save),
+        grid=grid,
+        in_specs=[tokens(dk)] * 3 + [tokens(dv), decays],
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
@@ -305,17 +389,18 @@ def _scan_fwd(q, k, kb, vb, g, heads, chunk, cd, interpret, save=True):
     return o, (q, k, kb, vb, g, *saved)
 
 
-def _scan_bwd(heads, chunk, cd, interpret, res, do):
+def _scan_bwd(dims, chunk, per_cell, cd, interpret, res, do):
     q, k, kb, vb, g, state_starts = res
-    nc = q.shape[1] // chunk
-    (batch, _, _), tokens, carried, starts = _specs(q.shape, heads, chunk, lambda z: nc - 1 - z)
-    d, f32 = q.shape[2] // heads, jnp.float32
+    nc = dims[1] // chunk
+    grid, tokens, decays, carried, starts = _specs(dims, chunk, per_cell, lambda z: nc - 1 - z)
+    (batch, _, heads, dk, dv), f32 = dims, jnp.float32
     *grads, _ = pl.pallas_call(
-        functools.partial(_bwd_kernel, _Cell(chunk, cd)),
-        grid=(batch, heads, nc),
-        in_specs=[tokens] * 5 + [starts, tokens],
-        out_specs=[tokens] * 5 + [carried],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, f32)] * 5 + [jax.ShapeDtypeStruct((batch, heads, d, d), f32)],
+        functools.partial(_bwd_kernel, _Cell(chunk, cd, per_cell)),
+        grid=grid,
+        in_specs=[tokens(dk)] * 3 + [tokens(dv), decays, starts, tokens(dv)],
+        out_specs=[tokens(dk)] * 3 + [tokens(dv), decays, carried],
+        out_shape=[jax.ShapeDtypeStruct(m.shape, f32) for m in (q, k, kb, vb, g)]
+        + [jax.ShapeDtypeStruct((batch, heads, dv, dk), f32)],
         interpret=interpret,
         compiler_params=_PARAMS,
         name=BWD_NAME,
@@ -327,28 +412,47 @@ _scan.defvjp(_scan_fwd, _scan_bwd)
 
 
 def kda_kernel_chunks(batch: int, t: int, heads: int, chunk: int) -> int:
-    """The (chunk, head) pairs one walk of either kernel covers: its grid's cells."""
+    """The (chunk, head) pairs one walk of either kernel covers: its grid's cells times the heads a cell takes."""
     return batch * heads * (t // chunk)
 
 
+def _heads_a_cell(heads: int) -> int:
+    """How many heads a cell of the one-decay form takes: the largest divisor of ``heads`` up to ``_HEADS_A_CELL``."""
+    return max(n for n in range(1, _HEADS_A_CELL + 1) if heads % n == 0)
+
+
 def kda_scan(q, k, v, g, beta, chunk: int, compute_dtype=jnp.float32):
-    """``o [B, T, H, D]`` float32 of the recurrence above through chunks of
+    """``o [B, T, H, D_v]`` float32 of the recurrence above through chunks of
     ``chunk`` positions (``T`` a multiple of it); arguments as
-    ``reference_delta``'s with ``D_v = D_k``, ``compute_dtype`` the matmuls'
+    ``reference_delta``'s, or ``g [B, T, H]``: ONE log-decay a head and
+    position, which takes the one-decay form; ``compute_dtype`` the matmuls'
     input type. Differentiable in all five (the backward is the second
     kernel)."""
-    batch, t, heads, d = q.shape
-    if t % chunk or chunk & (chunk - 1) or v.shape != q.shape:
-        raise ValueError(f"the delta rule takes whole chunks of a power of two of positions and values as wide as "
-                         f"the keys; got T {t}, chunk {chunk}, keys {q.shape}, values {v.shape}")
+    batch, t, heads, dk = q.shape
+    dv = v.shape[-1]
+    per_head = g.ndim == 3
+    if (t % chunk or chunk & (chunk - 1) or k.shape != q.shape or v.shape[:3] != q.shape[:3]
+            or g.shape != (q.shape[:3] if per_head else q.shape) or beta.shape != q.shape[:3]):
+        raise ValueError(f"the delta rule takes whole chunks of a power of two of positions, keys as the queries, "
+                         f"values a head and position, a log-decay a key channel or a head, a strength a head; got "
+                         f"T {t}, chunk {chunk}, queries {q.shape}, keys {k.shape}, values {v.shape}, "
+                         f"log-decays {g.shape}, strengths {beta.shape}")
     interpret = _interpreted()
-    if not interpret and (d % _LANES or chunk % _SUB):
-        raise ValueError(f"on the TPU the delta rule's kernels take heads of a multiple of {_LANES} channels and "
-                         f"chunks of a multiple of {_SUB} positions; got {d} channels, chunk {chunk}")
+    if not interpret and (chunk % _SUB or (not per_head and (dk % _LANES or dv % _LANES))):
+        raise ValueError(f"on the TPU the delta rule's kernels take chunks of a multiple of {_SUB} positions and, "
+                         f"under a decay a key channel, heads of a multiple of {_LANES} channels; got chunk {chunk}, "
+                         f"{dk} key and {dv} value channels")
     f32 = jnp.float32
     q, k, v, g = (m.astype(f32) for m in (q, k, v, g))
     scale = beta.astype(f32)[..., None]
-    flat = lambda m: m.reshape(batch, t, heads * d)  # noqa: E731
-    o = _scan(flat(q), flat(k), flat(scale * k), flat(scale * v), _chunk_sums(flat(g), chunk), heads, chunk,
-              jnp.dtype(compute_dtype).name, interpret)
-    return o.reshape(batch, t, heads, d)
+    dims, cd = (batch, t, heads, dk, dv), jnp.dtype(compute_dtype).name
+    if per_head:  # head-major: a head's channels are a block's whole last dimension, whatever their count
+        lead = lambda m: jnp.transpose(m, (0, 2, 1, 3))  # noqa: E731
+        rows = jnp.transpose(_chunk_sums(g, chunk), (0, 2, 1)).reshape(batch, heads, t // chunk, 1, chunk)
+        o = _scan(lead(q), lead(k), lead(scale * k), lead(scale * v), rows, dims, chunk, _heads_a_cell(heads), cd,
+                  interpret)
+        return jnp.transpose(o, (0, 2, 1, 3))
+    flat = lambda m: m.reshape(batch, t, -1)  # noqa: E731
+    o = _scan(flat(q), flat(k), flat(scale * k), flat(scale * v), _chunk_sums(flat(g), chunk), dims, chunk, 0, cd,
+              interpret)
+    return o.reshape(batch, t, heads, dv)

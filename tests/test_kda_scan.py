@@ -10,6 +10,13 @@ and of four, decays as strong as the initialiser's strongest (``A_log = log
 past float32's range if it were ever inverted) over 256 positions, and a
 correction strength near 2, where ``I - beta k k^T`` has an eigenvalue near -1.
 
+Both forms of the rule (a log-decay a key channel, ``g [B, T, H, D_k]``: the
+sub-chunked pair matrices on token-major arrays; ONE log-decay a head, ``g [B,
+T, H]``: a ``[chunk, chunk]`` decay factor on head-major arrays, several heads
+a cell) at heads that are square and not, up to Olmo-Hybrid-7B's 96 key and 192
+value channels: against the recurrence forward and in all five gradients,
+whatever the chunk, and against each other under a broadcast decay.
+
 Tolerances. float32: the two compute one sum in different orders (a chunk's
 triangular solve and pair matrices against a running state), read here at 6e-7
 of the largest entry forward and 2e-6 backward; the limits are 2e-5 and 1e-4.
@@ -167,7 +174,93 @@ def test_the_kernels_cover_every_chunk():
     assert names == [kda.FWD_NAME, kda.BWD_NAME]
 
 
-@pytest.mark.parametrize("bad", ["ragged", "chunk_not_a_power_of_two", "values_of_another_width"])
+#: a head's ``(key channels, value channels)``: square; neither a multiple of the other's tile; Olmo-Hybrid-7B's
+WIDTHS = ((16, 16), (24, 48), (96, 192))
+DECAYS = ("channel", "head")
+
+
+def _wide_inputs(decay: str, widths, seed=0, t=128, heads=2, rate=4.0):
+    """As ``_inputs`` at ``widths = (D_k, D_v)``; ``decay`` ``head``: one log-decay a head and position."""
+    dk, dv = widths
+    ks = jax.random.split(jax.random.key(seed), 5)
+    q, k = (jax.random.normal(key, (1, t, heads, dk)) for key in ks[:2])
+    v = jax.random.normal(ks[2], (1, t, heads, dv))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) / np.sqrt(dk)
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    g = -rate * jax.random.uniform(ks[3], (1, t, heads) + ((dk,) if decay == "channel" else ()), minval=0.09,
+                                   maxval=0.1)
+    return q, k, v, g, 2.0 * jax.nn.sigmoid(jax.random.normal(ks[4], (1, t, heads)))
+
+
+def _recurrence(q, k, v, g, beta):
+    """``reference_delta``, a head's one decay laid over its key channels: the ONE step-by-step loop."""
+    return reference_delta(q, k, v, g if g.ndim == 4 else jnp.broadcast_to(g[..., None], q.shape), beta)
+
+
+@pytest.mark.parametrize("widths", WIDTHS, ids=lambda w: f"{w[0]}x{w[1]}")
+@pytest.mark.parametrize("decay", DECAYS)
+def test_either_decay_at_either_width_is_the_recurrence(decay, widths):
+    """Forward and every gradient, four chunks of 32: the state ``[D_k, D_v]`` is carried across three edges."""
+    args = _wide_inputs(decay, widths)
+    want = _recurrence(*args)
+    got = kda_scan(*args, 32)
+    assert got.shape == want.shape == args[2].shape and got.dtype == jnp.float32
+    assert _worst(got, want) < 2e-5
+    probe = jax.random.normal(jax.random.key(9), want.shape)
+    gw = jax.grad(lambda *a: jnp.sum(_recurrence(*a) * probe), argnums=range(5))(*args)
+    gg = jax.grad(lambda *a: jnp.sum(kda_scan(*a, 32) * probe), argnums=range(5))(*args)
+    for name, g, w in zip(NAMES, gg, gw):
+        assert g.shape == w.shape and float(jnp.max(jnp.abs(w))) > 0, name
+        assert _worst(g, w) < 1e-4, name
+
+
+@pytest.mark.parametrize("chunk", [8, 16, 64, 128])
+@pytest.mark.parametrize("decay", DECAYS)
+def test_the_chunk_size_changes_nothing_at_heads_that_are_not_square(decay, chunk):
+    args = _wide_inputs(decay, (24, 48), seed=2)
+    assert _worst(kda_scan(*args, chunk), _recurrence(*args)) < 2e-5
+
+
+@pytest.mark.parametrize("widths", WIDTHS, ids=lambda w: f"{w[0]}x{w[1]}")
+def test_the_one_decay_form_is_the_channel_form_under_a_broadcast_decay(widths):
+    """The same numbers through both forms - a ``[chunk, chunk]`` factor on the
+    products here, sub-chunk references inside the contraction there - forward
+    and in the decay's gradient, summed over a head's channels."""
+    q, k, v, g, beta = _wide_inputs("head", widths, seed=3, rate=16.0)  # the initialiser's strongest decay
+    wide = jnp.broadcast_to(g[..., None], q.shape)
+    one, channel = kda_scan(q, k, v, g, beta, 64), kda_scan(q, k, v, wide, beta, 64)
+    assert _worst(one, channel) < 2e-5
+    probe = jax.random.normal(jax.random.key(9), one.shape)
+    d_one = jax.grad(lambda g: jnp.sum(kda_scan(q, k, v, g, beta, 64) * probe))(g)
+    d_channel = jax.grad(lambda g: jnp.sum(kda_scan(q, k, v, g, beta, 64) * probe))(wide)
+    assert _worst(d_one, jnp.sum(d_channel, axis=-1)) < 1e-4
+
+
+def test_a_cells_heads_are_independent(monkeypatch):
+    """Several heads a cell of the one-decay form give what one a cell gives: six heads in cells of three."""
+    monkeypatch.setattr(kda, "_HEADS_A_CELL", 3)
+    assert kda._heads_a_cell(6) == 3 and kda._heads_a_cell(15) == 3 and kda._heads_a_cell(7) == 1
+    args = _wide_inputs("head", (24, 48), seed=4, heads=6)
+    assert _worst(kda_scan(*args, 32), _recurrence(*args)) < 2e-5
+    grads = jax.grad(lambda *a: jnp.sum(kda_scan(*a, 32) ** 2), argnums=range(5))(*args)
+    wants = jax.grad(lambda *a: jnp.sum(_recurrence(*a) ** 2), argnums=range(5))(*args)
+    for name, g, w in zip(NAMES, grads, wants):
+        assert _worst(g, w) < 1e-4, name
+
+
+def test_one_decay_a_head_takes_no_exponential_of_a_channel():
+    """The one-decay form's kernels hold the decays as ``[1, chunk]`` rows a
+    head: the chunk sums they are handed are ``[B, H, T / chunk, 1, chunk]``,
+    and nothing ``[.., D_k]`` wide carries a decay."""
+    args = _wide_inputs("head", (24, 48))
+    jaxpr = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(kda_scan(*a, 32)), argnums=range(5)))(*args)
+    call, _ = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]  # the forward walk, the backward
+    shapes = [tuple(v.aval.shape) for v in call.invars]
+    assert shapes.count((1, 2, 128, 24)) == 3 and (1, 2, 128, 48) in shapes  # q, k, beta k; beta v: head-major
+    assert (1, 2, 4, 1, 32) in shapes and len(shapes) == 5
+
+
+@pytest.mark.parametrize("bad", ["ragged", "chunk_not_a_power_of_two", "values_of_other_heads"])
 def test_shapes_the_kernels_cannot_take_are_refused(bad):
     q, k, v, g, beta = _inputs("one_chunk")
     with pytest.raises(ValueError, match="whole chunks"):
@@ -176,10 +269,15 @@ def test_shapes_the_kernels_cannot_take_are_refused(bad):
         elif bad == "chunk_not_a_power_of_two":
             kda_scan(q[:, :24], k[:, :24], v[:, :24], g[:, :24], beta[:, :24], 24)
         else:
-            kda_scan(q, k, v[..., :8], g, beta, 32)
+            kda_scan(q, k, v[:, :, :1], g, beta, 32)
 
 
 def test_on_the_tpu_narrow_heads_are_refused(monkeypatch):
+    """Under a decay a key channel, whose blocks cut a head out of the lanes; one decay a head takes heads of any
+    width (``tests/test_lm_chip_compile.py`` compiles it at 96 and 192)."""
     monkeypatch.setattr(kda, "_interpreted", lambda: False)
     with pytest.raises(ValueError, match="128"):
         kda_scan(*_inputs("one_chunk"), 32)
+    q, k, v, g, beta = _inputs("one_chunk")
+    with pytest.raises(ValueError, match="chunks of a multiple of 16"):
+        kda_scan(q, k, v, g[..., 0], beta, 8)

@@ -1,6 +1,6 @@
 """The LM step's kernels compiled for the real chip at OLMoE's published
 shape, and the whole step program at ZAYA1-8B's, Ouro's, Laguna's and
-Nemotron-3-Nano's, JoyAI-LLM-Flash's and SDAR-30B-A3B's cuts, without the chip: libtpu's compiler runs here against a described
+Nemotron-3-Nano's, JoyAI-LLM-Flash's, SDAR-30B-A3B's, Solar-Open2-250B's and Olmo-Hybrid-7B's cuts, without the chip: libtpu's compiler runs here against a described
 v5e (docs and recipe: the ``on-chip-measurement`` guide, section 2). It
 catches what interpret mode cannot - Mosaic's lowering rules and the
 scoped-VMEM limit - at no chip time. Nothing runs; no time is measured.
@@ -711,6 +711,87 @@ def test_two_sequences_a_step_of_the_solar_cut_do_not_fit(one_chip, monkeypatch)
     assert 15.75e9 < memory.argument_size_in_bytes + memory.temp_size_in_bytes < 16.6e9
 
 
+def _olmo_hybrid_cut():
+    """``(the benchmark's olmo_hybrid_7b configuration, its LMConfig)``."""
+    from perfbench.systems import olmo_hybrid_lm_fit
+
+    c = _cell_config("olmo_hybrid_7b")
+    return c, olmo_hybrid_lm_fit.lm_config(c)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16", "float32"])
+def test_the_one_decay_delta_rule_trains_at_the_cells_shape(one_chip, monkeypatch, dtype):
+    """The delta rule's kernel pair in its one-decay form in one training graph
+    at the Olmo-Hybrid cut's shape: 15 held heads of 96 key and 192 value
+    channels - neither tiles the 128 lanes, nor do the 1,440 flat key channels -
+    T 8,192 in chunks of 64. Mosaic takes the head-major blocks whose last
+    dimension is a head's whole 96 or 192 channels, the ``[1, 64]`` decay rows
+    turned through a ``[64, 64]`` tile's diagonal, the blockwise inversion and
+    the transposed ``[192, 96]`` carried state; the state every chunk starts
+    from is saved once, ``[1, 128, 15, 192, 96]`` float32; and the decays reach
+    the kernels as rows a head: nothing ``[.., 96]`` wide carries one."""
+    from flink_ml_tpu.parallel import kda
+
+    monkeypatch.setattr(kda, "_interpreted", lambda: False)  # the backend here is the CPU; the target is the chip
+    heads, t, dk, dv, chunk = 15, 8192, 96, 192, 64
+    keys = jax.ShapeDtypeStruct((1, t, heads, dk), jnp.float32, sharding=one_chip)
+    values = jax.ShapeDtypeStruct((1, t, heads, dv), jnp.float32, sharding=one_chip)
+    narrow = jax.ShapeDtypeStruct((1, t, heads), jnp.float32, sharding=one_chip)
+
+    def grads(q, k, v, g, beta):
+        return jax.grad(lambda *a: jnp.sum(kda.kda_scan(*a, chunk, dtype)), argnums=range(5))(q, k, v, g, beta)
+
+    compiled = _compile(grads, keys, keys, values, narrow, narrow)
+    text = compiled.as_text()
+    assert kda.FWD_NAME in text and kda.BWD_NAME in text
+    assert f"f32[1,{t // chunk},{heads},{dv},{dk}]" in text  # the chunks' starting states, once
+    assert f"f32[1,{heads},{t // chunk},1,{chunk}]" in text  # the decays: a row a head and chunk
+    assert f"[1,{t},{heads},{dk},{dv}]" not in text and f"[1,{t},{heads},{dv},{dk}]" not in text  # no state a position
+    dq, dk_, dv_, dg, dbeta = jax.eval_shape(grads, keys, keys, values, narrow, narrow)
+    assert dq.shape == dk_.shape == keys.shape and dv_.shape == values.shape and dg.shape == dbeta.shape == narrow.shape
+
+
+def test_the_olmo_hybrid_step_program_at_the_cells_shapes(one_chip, monkeypatch):
+    """The whole jitted step of the ``olmo_hybrid_7b`` configuration at ONE
+    8,192-token sequence: four rematerialised layers of two records (three
+    delta-rule layers on 15 held heads of 96 x 192, the attention layer on 15
+    held heads of 128 under its QK-norm, every layer with the whole 11,008-wide
+    SwiGLU and two output norms), 766,241,946 parameters. ISSUE 54's FIRST
+    choice, 15 of 30 heads, fits the HBM ``fit`` compiles a step into
+    (``decoder_lm.STEP_HBM_MIB``: the 15.75e9 B below): XLA's analysis reads
+    4.78 GB of temporaries beside 9.20 GB of arguments, 13.98e9 B in all, 1.77e9
+    under, and nothing in the compiled step is one of XLA's own
+    rematerialisations; the 10-head fallback is not needed. The delta rule and
+    the convolution are their kernel pairs by name, the convolution walking q,
+    k and v as ONE part of 5,760 channels (1,440 tiles no lane); the fold's two
+    kernels are there at T 8,192."""
+    from flink_ml_tpu.models.lm.config import num_params
+    from flink_ml_tpu.parallel import causal_conv, kda
+
+    c, cfg = _olmo_hybrid_cut()
+    assert num_params(cfg) == 766_241_946  # 12.26 GB of f32 state at 16 bytes a parameter: 77% of 16 GB
+    batch, t = c["global_batch_size"], c["sequence_length"]
+    assert (batch, t) == (1, 8192)
+    for module in (kda, causal_conv):  # the backend here is the CPU; the target is the chip
+        monkeypatch.setattr(module, "_interpreted", lambda: False)
+    compiled, memory = _compiled_step(c, cfg, one_chip)
+    assert memory.temp_size_in_bytes < 4.83e9, memory.temp_size_in_bytes  # what it reads (4.780e9) and 1%
+    text = compiled.as_text()
+    assert ".remat" not in text
+    for kernel in ("flash_fold_fwd", "flash_fold_bwd_dkv", "kda_scan_fwd", "kda_scan_bwd", "causal_conv_fwd",
+                   "causal_conv_bwd"):
+        assert kernel in text
+    assert "flash_fold_bwd_dq" not in text and "flash_fold_win_" not in text and "ssd_scan" not in text
+    channels = cfg.kda_heads * (2 * cfg.kda_head_dim + cfg.kda_value_dim)
+    assert channels == 5760 and f"f32[{batch},{t},{channels}]" in text  # q, k and v out of one projection, one part
+    assert f"f32[{batch},{t + cfg.conv_kernel - 1},{channels}]" not in text  # and no padded copy of them
+    assert f"f32[{batch},{cfg.n_heads},{t},{t}]" not in text and f"f32[{batch * cfg.n_heads},{t},{t}]" not in text
+    chunks = t // cfg.chunk
+    assert f"f32[{batch},{chunks},{cfg.kda_heads},{cfg.kda_value_dim},{cfg.kda_head_dim}]" in text  # the chunks' states
+    assert f"[{batch},{t},{cfg.kda_heads},{cfg.kda_head_dim},{cfg.kda_value_dim}]" not in text  # no state a position
+    assert f"[2048,{cfg.vocab}]" in text and f"[{t},{cfg.vocab}]" not in text  # the head's logits a chunk of 2,048 rows
+
+
 def test_the_ouro_step_program_at_the_cells_shapes(one_chip):
     """The whole jitted step of the ``ouro_2_6b`` configuration at 2 x 4,096
     tokens: six rematerialised dense blocks inside one scanned pass run four
@@ -765,8 +846,9 @@ def test_the_olmoe_step_program_at_the_cells_shapes(one_chip):
     assert f"f32[{batch},{cfg.n_heads},{t},{t}]" not in text and f"f32[{batch * cfg.n_heads},{t},{t}]" not in text
 
 
-@pytest.mark.parametrize("cut", [_zaya_cut, _ouro_cut, _laguna_cut, _nemotron_cut, _joyai_cut, _sdar_cut, _solar_cut],
-                         ids=["zaya", "ouro", "laguna", "nemotron", "joyai", "sdar", "solar"])
+@pytest.mark.parametrize("cut", [_zaya_cut, _ouro_cut, _laguna_cut, _nemotron_cut, _joyai_cut, _sdar_cut, _solar_cut,
+                                 _olmo_hybrid_cut],
+                         ids=["zaya", "ouro", "laguna", "nemotron", "joyai", "sdar", "solar", "olmo_hybrid"])
 def test_the_state_program_at_the_cells_shapes(one_chip, cut):
     """AdamW's state as ``DecoderLM._fit`` makes it, ``optimizer.init`` jitted,
     at the cells' parameter trees: one program whose outputs are the whole

@@ -8,14 +8,17 @@ configurations by the digest of the list and by their parameter counts. The
 then ``final_norm`` and ``lm_head``, then the multi-token-prediction module;
 the ``sdar`` kind (PR 47) likewise: OLMoE's order, its QK-norms a head wide;
 the ``solar_open2`` kind (PR 51) as it came: an attention layer's leaves with
-``wg`` before ``wo``, a delta-rule layer's fifteen, laguna's expert leaves."""
+``wg`` before ``wo``, a delta-rule layer's fifteen, laguna's expert leaves;
+the ``olmo_hybrid`` kind (PR 54) as it came: no norm before a sublayer, a
+one-decay delta-rule layer's thirteen leaves and then ``attn_out_norm``, the
+dense SwiGLU's three and ``ffn_out_norm``."""
 import hashlib
 
 import pytest
 
 from flink_ml_tpu.models.lm.config import LMConfig, layers, num_params, param_shapes
 from tests.test_lm_chip_compile import (
-    _cell_config, _joyai_cut, _laguna_cut, _nemotron_cut, _ouro_cut, _sdar_cut, _solar_cut, _zaya_cut,
+    _cell_config, _joyai_cut, _laguna_cut, _nemotron_cut, _olmo_hybrid_cut, _ouro_cut, _sdar_cut, _solar_cut, _zaya_cut,
 )
 
 TOYS = {
@@ -45,6 +48,9 @@ TOYS = {
                             norm_eps=1e-5, aux_coef=0.0, block="solar_open2", experts_held=2, first_held=2,
                             n_kv_heads=2, head_size=16, shared_width=32, routed_scale=1.0, conv_kernel=4, chunk=64,
                             gqa_layers=(0,), kda_heads=2, kda_head_dim=16),
+    "olmo_hybrid": LMConfig(n_layers=2, hidden=64, n_heads=4, n_experts=0, top_k=0, expert_width=96, vocab=512,
+                            norm_eps=1e-6, aux_coef=0.0, block="olmo_hybrid", n_kv_heads=4, head_size=16, conv_kernel=4,
+                            chunk=64, gqa_layers=(1,), kda_heads=3, kda_head_dim=8, kda_value_dim=16),
 }
 #: ``(dotted path, shape, init)`` of every leaf in ``param_shapes`` order, printed by 512ebfa's ``param_shapes``
 TOY_TREES = {
@@ -185,6 +191,22 @@ TOY_TREES = {
         ('layers.1.w_down', (2, 32, 64), 'normal'),
         ('final_norm', (64,), 'ones'), ('lm_head', (64, 512), 'normal'),
     ],
+    "olmo_hybrid": [
+        ('embed', (512, 64), 'normal'), ('layers.0.wq', (64, 24), 'normal'), ('layers.0.wk', (64, 24), 'normal'),
+        ('layers.0.wv', (64, 48), 'normal'), ('layers.0.conv_q', (4, 24), 'normal'),
+        ('layers.0.conv_k', (4, 24), 'normal'), ('layers.0.conv_v', (4, 48), 'normal'),
+        ('layers.0.Wa', (64, 3), 'normal'), ('layers.0.A_log', (3,), 'a_log'), ('layers.0.dt_bias', (3,), 'dt_bias'),
+        ('layers.0.Wb', (64, 3), 'normal'), ('layers.0.wg', (64, 48), 'normal'), ('layers.0.o_norm', (16,), 'ones'),
+        ('layers.0.wo', (48, 64), 'normal'), ('layers.0.attn_out_norm', (64,), 'ones'),
+        ('layers.0.w_gate', (64, 96), 'normal'), ('layers.0.w_up', (64, 96), 'normal'),
+        ('layers.0.w_down', (96, 64), 'normal'), ('layers.0.ffn_out_norm', (64,), 'ones'),
+        ('layers.1.wq', (64, 64), 'normal'), ('layers.1.wk', (64, 64), 'normal'),
+        ('layers.1.wv', (64, 64), 'normal'), ('layers.1.wo', (64, 64), 'normal'), ('layers.1.q_norm', (64,), 'ones'),
+        ('layers.1.k_norm', (64,), 'ones'), ('layers.1.attn_out_norm', (64,), 'ones'),
+        ('layers.1.w_gate', (64, 96), 'normal'), ('layers.1.w_up', (64, 96), 'normal'),
+        ('layers.1.w_down', (96, 64), 'normal'), ('layers.1.ffn_out_norm', (64,), 'ones'),
+        ('final_norm', (64,), 'ones'), ('lm_head', (64, 512), 'normal'),
+    ],
 }
 
 
@@ -211,6 +233,9 @@ CELLS = {
     "sdar_30b_a3b": (_sdar_cut, 645_623_296, "2c858655427e58fe0af64a14d0340659d780d9481f3c4b732df96af708c7e554", 1),
     # as PR 51 brought it: the layer that attends, then three delta-rule layers of one record
     "solar_open2_250b": (_solar_cut, 840_872_600, "9dcffb5157ddd100e16d0d5f3cef8878906ec50434592e42479b60df07681406", 2),
+    # as PR 54 brought it: three one-decay delta-rule layers of one record, then the layer that attends
+    "olmo_hybrid_7b": (_olmo_hybrid_cut, 766_241_946,
+                       "9fb96f77b0d6f00880b3c9951f5d5067d666e5e90620db86bd938fa6e2b212cd", 2),
 }
 
 

@@ -68,10 +68,15 @@ KINDS = {
                              gqa_layers=(0,), kda_heads=2, kda_head_dim=16), True,
                     {"lm.block/kda", "lm.block/kgate", "lm.block/gnorm", "lm.block/conv", "lm.block/gate",
                      "lm.block/route", "lm.block/permute", "lm.block/experts", "lm.block/shared"}),
+    # the one-decay delta rule under solar's names (``kda``, ``kgate``, ``gnorm``, ``conv``), the dense SwiGLU under ``ffn``
+    "olmo_hybrid": (LMConfig(n_layers=2, hidden=64, n_heads=4, n_experts=0, top_k=0, expert_width=96, vocab=512,
+                             norm_eps=1e-6, aux_coef=0.0, block="olmo_hybrid", n_kv_heads=4, head_size=16, conv_kernel=4,
+                             chunk=64, gqa_layers=(1,), kda_heads=3, kda_head_dim=8, kda_value_dim=16), True,
+                    {"lm.block/kda", "lm.block/kgate", "lm.block/gnorm", "lm.block/conv", "lm.block/ffn"}),
 }
 EVERY_KIND = {"lm.embed", "lm.block/norm", "lm.block/proj", "lm.block/fold", "lm.block/mix",
               "lm.final_norm/norm", "lm.head", "lm.opt"}
-#: every kind but ``nemotron_h`` and ``solar_open2``, whose attention has no position encoding
+#: every kind but ``nemotron_h``, ``solar_open2`` and ``olmo_hybrid``, whose attention has no position encoding
 ROPE = "lm.block/rope"
 #: ``%name = shape opcode(``: the opcode is the first word followed by a parenthesis
 INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = .*? ([a-z][\w\-]*)\(")
@@ -128,7 +133,7 @@ def test_every_scope_the_kind_has_appears(programs, kind):
     scopes = {scope for scope, _ in _found(step)}
     own = KINDS[kind][2]
     assert (EVERY_KIND | own) <= scopes, sorted((EVERY_KIND | own) - scopes)
-    assert (ROPE in scopes) == (kind not in ("nemotron_h", "solar_open2"))
+    assert (ROPE in scopes) == (kind not in ("nemotron_h", "solar_open2", "olmo_hybrid"))
     # and none another kind alone has
     others = set().union(*(k[2] for k in KINDS.values())) - own
     assert not others & scopes, sorted(others & scopes)
@@ -155,6 +160,11 @@ def test_the_head_is_never_recomputed_and_a_block_is_where_it_is_checkpointed(pr
         for scope in ("lm.block/kda", "lm.block/kgate", "lm.block/gnorm", "lm.block/gate"):
             assert {d for s, d in found if s == scope} == {FWD, REMAT, BWD}, scope
         assert {REMAT, BWD} <= {d for s, d in found if s == "lm.block/conv"}  # (interpreted, its forward call fuses away)
+    if kind == "olmo_hybrid":  # the same two kernels under the same scope: the one-decay form is theirs
+        names = {n.split("/kda/")[1].split("/")[0] for _, n in step if n and "/kda/kda_scan" in n}
+        assert names == {"kda_scan_fwd", "kda_scan_bwd"}
+        for scope in ("lm.block/kda", "lm.block/kgate", "lm.block/gnorm", "lm.block/ffn"):
+            assert {d for s, d in found if s == scope} == {FWD, REMAT, BWD}, scope
     if kind == "joyai":  # the module's parts in every direction, its layer recomputed like the stack's
         for scope in ("lm.mtp/lm.block/latent", "lm.mtp/lm.block/fold"):
             assert {d for s, d in found if s == scope} == {FWD, REMAT, BWD}, scope
@@ -185,7 +195,7 @@ def test_the_update_is_outside_the_gradient(programs, kind):
 #: weights' cotangents over the passes (``dynamic_update_slice``, ``add_any``)
 #: in code that is JAX's own, under no scope of the program.
 COVERED = {"olmoe": 0.95, "olmoe_stacked": 0.95, "zaya": 0.95, "ouro": 0.90, "laguna": 0.95, "nemotron_h": 0.95,
-           "joyai": 0.95, "sdar": 0.95, "solar_open2": 0.95}
+           "joyai": 0.95, "sdar": 0.95, "solar_open2": 0.95, "olmo_hybrid": 0.95}
 
 
 @pytest.mark.parametrize("kind", sorted(KINDS))
